@@ -30,7 +30,7 @@ sys.path.insert(
 
 from paddlefleetx_tpu.utils.device import apply_platform_env
 
-apply_platform_env()  # PFX_PLATFORM=cpu etc., before backend init
+apply_platform_env()  # tpu unless a CPU pin is set; before backend init
 
 
 def main(argv=None):

@@ -95,6 +95,7 @@ from paddlefleetx_tpu.core.request_queue import (
 )
 from paddlefleetx_tpu.ops.decode_attention import kv_cache_dtype
 from paddlefleetx_tpu.ops.speculative import SpecConfig, ngram_propose_host
+from paddlefleetx_tpu.parallel.sharding import place_on_mesh
 from paddlefleetx_tpu.utils.log import logger
 from paddlefleetx_tpu.utils.resilience import maybe_fire
 from paddlefleetx_tpu.core.tenancy import (
@@ -231,7 +232,6 @@ class PagedDecodeEngine:
                  prefix_cache_blocks: int = 0,
                  prefill_chunk: int = 0,
                  prefix_spill_bytes: int = 0) -> None:
-        from paddlefleetx_tpu.models.gpt.generation import init_paged_pools
         from paddlefleetx_tpu.parallel.mesh import data_parallel_world
 
         self.server = server
@@ -299,20 +299,13 @@ class PagedDecodeEngine:
         if self.cache.spill.enabled:
             self.cache.prefix.spill_hook = self._spill_block
         self._spill_probes = 0
-        self.pools = init_paged_pools(
-            self.mcfg, num_blocks, self.block, kv_dtype=self.kv_dtype
-        )
-
         import jax
         import jax.numpy as jnp
 
         self._jnp = jnp
         self._jax = jax
-        vocab = int(self.mcfg.vocab_size)
+        self._init_device_state()
         B = self.capacity
-        self._logits = jnp.zeros((B, vocab), jnp.float32)
-        self._counts = jnp.zeros((B, vocab), jnp.int32)
-        self._reject = jnp.full((B,), -1, jnp.int32)
         self.positions = np.zeros((B,), np.int32)
         self.gen_steps = np.zeros((B,), np.int32)
         self.max_news = np.zeros((B,), np.int32)
@@ -374,6 +367,27 @@ class PagedDecodeEngine:
         self.dispatch_ahead = False
         self._inflight: Optional[Dict[str, Any]] = None
         self._t_results: Optional[float] = None
+
+    def _init_device_state(self) -> None:
+        """Fresh arena + per-row device state (boot and every ArenaReset),
+        placed on the mesh like the step's own outputs so the compiled
+        families key ONE compile each (``place_on_mesh``)."""
+        from paddlefleetx_tpu.models.gpt.generation import init_paged_pools
+
+        jnp = self._jnp
+        B, vocab = self.capacity, int(self.mcfg.vocab_size)
+        self.pools, self._logits, self._counts, self._reject = place_on_mesh(
+            (
+                init_paged_pools(
+                    self.mcfg, self.cache.allocator.num_blocks, self.block,
+                    kv_dtype=self.kv_dtype,
+                ),
+                jnp.zeros((B, vocab), jnp.float32),
+                jnp.zeros((B, vocab), jnp.int32),
+                jnp.full((B,), -1, jnp.int32),
+            ),
+            self.mesh,
+        )
 
     # -- capacity queries ----------------------------------------------
     def row_capacity_tokens(self, prompt_len: int, max_new: int) -> int:
@@ -1432,7 +1446,6 @@ class PagedDecodeEngine:
         Callers that mutate row membership or host row state
         (admit/adopt/release/evict) between steps must :meth:`flush`
         first."""
-        jnp = self._jnp
         pending = [
             i for i, r in enumerate(self.slots)
             if r is not None and not r.prefill_done
@@ -1477,8 +1490,7 @@ class PagedDecodeEngine:
         if not self.active.any():
             return finished
         fl = self._dispatch(
-            jnp.asarray(self.positions), jnp.asarray(self.gen_steps),
-            jnp.asarray(self.active), overlapped=False,
+            self.positions, self.gen_steps, self.active, overlapped=False,
         )
         fl["was_active"] = self.active.copy()
         self._inflight = fl
@@ -1501,7 +1513,6 @@ class PagedDecodeEngine:
         donation-invalidated inputs.  Returns the in-flight record
         whose window/ncommit/row-state handles :meth:`_commit` fetches;
         the caller fills ``was_active`` with its dispatch-time view."""
-        jnp = self._jnp
         M = self.table_width_bucket()
         tables = np.full((self.capacity, M), NULL_BLOCK, np.int32)
         for i, r in enumerate(self.slots):
@@ -1525,16 +1536,24 @@ class PagedDecodeEngine:
             )
             self.stats["gap_steps"] += 1
         t_disp = time.monotonic()
+        # host-fed row state and a chained dispatch's device-side handles
+        # must type alike, or each width bucket keys TWO compiles — the
+        # warmed host-fed one and a chained one first paid mid-traffic
+        # (place_on_mesh: ONE transfer for the host mirrors, a no-op for
+        # the handles)
+        (tables, positions, gen_steps, max_news, active, forced_steps,
+         drafts) = place_on_mesh(
+            (tables, positions, gen_steps, self.max_news, active,
+             self.forced_steps, drafts), self.mesh,
+        )
         try:
             with self.mesh:
                 (window, ncommit, pools_t, logits, counts, positions_t,
                  gen_steps_t, active_t, reject) = fn(
                     self.server.params, self._pools_tuple(),
-                    jnp.asarray(tables), self._logits, self._counts,
-                    positions, gen_steps,
-                    jnp.asarray(self.max_news), active,
-                    jnp.asarray(self.forced_steps), self._reject,
-                    jnp.asarray(drafts), sub,
+                    tables, self._logits, self._counts,
+                    positions, gen_steps, max_news, active,
+                    forced_steps, self._reject, drafts, sub,
                 )
         except BaseException as exc:
             dead = self.reset()
@@ -1723,8 +1742,6 @@ class PagedDecodeEngine:
         pools may be donation-invalidated and must never be reused.
         Returns the rows that were live (the caller fails their
         requests)."""
-        from paddlefleetx_tpu.models.gpt.generation import init_paged_pools
-
         dead = [r for r in self.slots if r is not None]
         # any in-flight dispatched step chains on the poisoned pools:
         # drop its handles, its results must never be committed
@@ -1747,14 +1764,7 @@ class PagedDecodeEngine:
         self.gen_steps[:] = 0
         self.max_news[:] = 0
         self.forced_steps[:] = 0
-        self.pools = init_paged_pools(
-            self.mcfg, self.cache.allocator.num_blocks, self.block,
-            kv_dtype=self.kv_dtype,
-        )
-        jnp = self._jnp
-        self._logits = jnp.zeros_like(self._logits)
-        self._counts = jnp.zeros_like(self._counts)
-        self._reject = jnp.full_like(self._reject, -1)
+        self._init_device_state()
         return dead
 
     def warmup_prefill(self, prompt_lens: Sequence[int]) -> Dict[str, float]:

@@ -16,9 +16,10 @@ the accelerator does):
     ``flap_budget`` times inside ``flap_window_s`` is QUARANTINED loudly
     (ERROR log + ``pfx_replica_quarantines_total``) instead of being
     restarted forever — a broken image must page a human, not burn a
-    port.  **Warm boot**: spawned replicas get ``--compile-cache-dir``
-    appended (``tools/serve.py`` seeds jax's persistent compile cache
-    from it), so scale-up is seconds of process boot, not a cold trace.
+    port.  **Warm boot**: spawned replicas get the compile cache directory
+    through ``JAX_COMPILATION_CACHE_DIR`` (jax reads it itself and
+    ``tools/serve.py`` then sets no cache of its own), so scale-up is
+    seconds of process boot, not a cold trace.
   - :class:`ElasticController` — one control loop consuming the router's
     replica snapshots and emitting scale decisions: **breach-driven fast
     scale-up** (any serving replica reporting an SLO burn-rate breach,
@@ -252,10 +253,10 @@ class ReplicaSupervisor:
         python tools/serve.py -c cfg.yaml --port {port} --replica-id {replica_id}
 
     Slot ``i`` listens on ``base_port + i`` with replica_id ``m<i>``.
-    When ``compile_cache_dir`` is set, ``--compile-cache-dir <dir>`` is
-    appended so every spawn (first boot, crash-restart, scale-up) seeds
-    jax's persistent compile cache — scale-up cost is process boot, not
-    a cold trace.  ``spawn_fn`` is injectable for tests; the default
+    When ``compile_cache_dir`` is set, every spawn (first boot,
+    crash-restart, scale-up) gets it as ``JAX_COMPILATION_CACHE_DIR`` in
+    its environment and shares jax's persistent compile cache there —
+    scale-up cost is process boot, not a cold trace.  ``spawn_fn`` is injectable for tests; the default
     Popen routes stdout+stderr to ``<log_dir>/<replica_id>.log`` so a
     crash-looping replica leaves evidence instead of a blocked pipe."""
 
@@ -290,6 +291,11 @@ class ReplicaSupervisor:
         self.flap_budget = int(flap_budget)
         self.flap_window_s = float(flap_window_s)
         self.env = dict(env) if env is not None else None
+        if compile_cache_dir:
+            self.env = dict(os.environ if self.env is None else self.env)
+            self.env["JAX_COMPILATION_CACHE_DIR"] = os.path.abspath(
+                compile_cache_dir
+            )
         self._spawn_fn = spawn_fn
         self._registry = registry or get_registry()
         # optional control-plane journal (core.router.FleetJournal —
@@ -314,8 +320,6 @@ class ReplicaSupervisor:
             cmd = shlex.split(
                 self.cmd_template.format(port=port, replica_id=replica_id)
             )
-            if self.compile_cache_dir:
-                cmd += ["--compile-cache-dir", self.compile_cache_dir]
             log_path = (os.path.join(self.log_dir, f"{replica_id}.log")
                         if self.log_dir else "")
             m = ManagedReplica(

@@ -26,6 +26,7 @@ from paddlefleetx_tpu.models.gpt.generation import (
 )
 from paddlefleetx_tpu.ops.decode_attention import kv_cache_dtype
 from paddlefleetx_tpu.ops.speculative import spec_config_from
+from paddlefleetx_tpu.parallel.sharding import place_on_mesh
 from paddlefleetx_tpu.utils.log import logger
 from paddlefleetx_tpu.utils.resilience import maybe_fire
 from paddlefleetx_tpu.utils.telemetry import StatsView, get_registry
@@ -251,11 +252,14 @@ class GenerationServer:
                     # verify chunk's rejected tail; kv_dtype int8
                     # allocates the quantized pair + scale planes
                     slack = self.spec.draft_k if self.spec else 0
-                    cache = init_cache(
+                    # on the mesh like the decode fn's returned cache, so
+                    # the first and every later same-bucket request share
+                    # ONE compile (parallel/sharding.place_on_mesh)
+                    cache = place_on_mesh(init_cache(
                         self.module.config, prompt.shape[0],
                         prompt.shape[1] + gen.max_dec_len + slack,
                         kv_dtype=self.kv_dtype,
-                    )
+                    ), self.mesh)
             try:
                 # serving fault sites (tests/test_serve_drills.py): both
                 # fire after the cache pop so an injected failure lands on

@@ -33,6 +33,7 @@ from paddlefleetx_tpu.ops.decode_attention import (
     decode_attn_mode,
     dense_cache_attention,
     kv_cache_dtype,
+    kv_cache_len,
     paged_decode_attention,
     quantize_kv,
 )
@@ -64,10 +65,17 @@ def init_cache(
     """``kv_dtype``: "" resolves PFX_KV_DTYPE (the serving path passes the
     ``Generation.speculative.kv_dtype`` config value through); "bf16"
     keeps the cache in the model dtype, "int8" allocates the quantized
-    pair plus its scale planes (HBM bytes per slot halve vs bf16)."""
+    pair plus its scale planes (HBM bytes per slot halve vs bf16).
+
+    ``max_len`` is the number of slots the caller needs; the buffer is
+    :func:`~paddlefleetx_tpu.ops.decode_attention.kv_cache_len` of it —
+    rounded up to the alignment the decode kernel's block loads must be
+    provably on.  The slack is never visited."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    shape = (cfg.num_layers, batch, cfg.num_attention_heads, max_len, cfg.head_dim)
-    if kv_cache_dtype(kv_dtype) == "int8":
+    quant = kv_cache_dtype(kv_dtype) == "int8"
+    shape = (cfg.num_layers, batch, cfg.num_attention_heads,
+             kv_cache_len(max_len, quant), cfg.head_dim)
+    if quant:
         sshape = shape[:-1]
         return KVCache(
             jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
@@ -449,7 +457,8 @@ def generate(
     if cache is None:
         cache = init_cache(cfg, b, cache_len)
     else:
-        want = (cfg.num_layers, b, cfg.num_attention_heads, cache_len,
+        want = (cfg.num_layers, b, cfg.num_attention_heads,
+                kv_cache_len(cache_len, cache.k_scale is not None),
                 cfg.head_dim)
         if cache.k.shape != want:
             raise ValueError(
@@ -1024,9 +1033,12 @@ def paged_prefill(
     last = jax.lax.dynamic_index_in_dim(
         logits[0], prompt_len - 1, axis=0, keepdims=False
     ).astype(jnp.float32)
-    # repack [layers, 1, n, L, d] -> per-block [layers, PB, n, bs, d]
+    # repack [layers, 1, n, L(+alignment slack), d] -> per-block
+    # [layers, PB, n, bs, d]
     def pack(c):
-        return c[:, 0].reshape(layers, n, PB, bs, d).transpose(0, 2, 1, 3, 4)
+        return (
+            c[:, 0, :, :L].reshape(layers, n, PB, bs, d).transpose(0, 2, 1, 3, 4)
+        )
 
     counts = jnp.zeros((cfg.vocab_size,), jnp.int32).at[prompt[0]].add(
         (jnp.arange(P) < prompt_len).astype(jnp.int32)

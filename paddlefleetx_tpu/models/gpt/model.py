@@ -63,6 +63,14 @@ class ShardingCtx:
 
         return with_logical_constraint(x, logical, self.rules, self.mesh)
 
+    def shard_kernel(self, fn, in_logical, out_logical):
+        """``fn`` (a Pallas kernel) under this mesh: inside ``shard_map``
+        over the axes its logical dims are sharded along
+        (``parallel/sharding.shard_kernel``)."""
+        from paddlefleetx_tpu.parallel.sharding import shard_kernel
+
+        return shard_kernel(fn, self.mesh, self.rules, in_logical, out_logical)
+
 
 def _constrain(ctx: Optional[ShardingCtx], x: jax.Array, logical) -> jax.Array:
     return ctx.constrain(x, logical) if ctx is not None else x
@@ -136,12 +144,22 @@ def gpt_logical_axes(cfg: GPTConfig) -> Dict[str, Any]:
 
 
 def layer_norm(
-    x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-5, fused: bool = False
+    x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-5,
+    fused: bool = False, ctx: Optional[ShardingCtx] = None,
 ):
+    """LayerNorm over the last dim of x [b, s, h].  ``fused`` selects the
+    Pallas kernel; under a mesh (``ctx``) the kernel is row-independent, so
+    it runs inside ``shard_map`` over the batch and seq axes."""
     if fused:
         from paddlefleetx_tpu.ops.fused_layernorm import fused_layer_norm
 
-        return fused_layer_norm(x, scale, bias, eps=eps)
+        def kernel(x, scale, bias):
+            return fused_layer_norm(x, scale, bias, eps=eps)
+
+        if ctx is not None:
+            act = ("batch", "seq", "embed")
+            kernel = ctx.shard_kernel(kernel, (act, (None,), (None,)), act)
+        return kernel(x, scale, bias)
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
@@ -235,6 +253,7 @@ def _attention_block(
             train=train,
             flash_block=cfg.flash_block,
             flash_bwd=cfg.flash_bwd,
+            ctx=ctx,
         )
 
     if cfg.use_recompute and cfg.recompute_granularity == "core_attn":
@@ -284,7 +303,9 @@ def _decoder_layer(
     k_attn, k_mlp = (jax.random.split(key) if key is not None else (None, None))
 
     def attn_part(p, x, k):
-        y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"], fused=cfg.use_fused_ln)
+        y = layer_norm(
+            x, p["ln_1"]["scale"], p["ln_1"]["bias"], fused=cfg.use_fused_ln, ctx=ctx
+        )
         y = _constrain(ctx, y, ("batch", "seq", "embed"))
         return _attention_block(p["attn"], y, cfg, ctx, k, train)
 
@@ -294,7 +315,9 @@ def _decoder_layer(
     x = x + attn_part(p, x, k_attn)
     x = _constrain(ctx, x, ("batch", "seq", "embed"))
 
-    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"], fused=cfg.use_fused_ln)
+    y = layer_norm(
+        x, p["ln_2"]["scale"], p["ln_2"]["bias"], fused=cfg.use_fused_ln, ctx=ctx
+    )
     y, aux = _mlp_block(p["mlp"], y, cfg, ctx, k_mlp, train)
     x = x + y
     return _constrain(ctx, x, ("batch", "seq", "embed")), aux
@@ -406,7 +429,8 @@ def forward_hidden(
 
     x, aux = transformer_stack(params["layers"], x, cfg, ctx, k_layers, train)
     x = layer_norm(
-        x, params["final_ln"]["scale"], params["final_ln"]["bias"], fused=cfg.use_fused_ln
+        x, params["final_ln"]["scale"], params["final_ln"]["bias"],
+        fused=cfg.use_fused_ln, ctx=ctx,
     )
     return _constrain(ctx, x, ("batch", "seq", "embed")), aux
 
@@ -545,7 +569,7 @@ def _pipeline_train_loss(
     def head_fn(hparams, y_mb, mb, mbi):
         y = layer_norm(
             y_mb, hparams["final_ln"]["scale"], hparams["final_ln"]["bias"],
-            fused=cfg.use_fused_ln,
+            fused=cfg.use_fused_ln, ctx=ctx,
         )
         y = _constrain(ctx, y, ("batch", "seq", "embed"))
         word = hparams["word"].astype(y.dtype)

@@ -74,11 +74,16 @@ def attention(
     scale: Optional[float] = None,
     flash_block: int = 0,
     flash_bwd: str = "",
+    ctx=None,
 ) -> jax.Array:
     """Dispatching attention entry point used by all models.
 
     ``flash_block`` / ``flash_bwd`` pass through to the Pallas kernels
-    (0/"" = auto); surfaced as ``Model.flash_block`` / ``Model.flash_bwd``."""
+    (0/"" = auto); surfaced as ``Model.flash_block`` / ``Model.flash_bwd``.
+    ``ctx`` (a model ``ShardingCtx``) is the mesh the call runs under: the
+    flash kernel is independent per (batch, head), so under a mesh it runs
+    inside ``shard_map`` over the batch and heads axes — GSPMD cannot
+    partition a Mosaic kernel."""
     if impl == "flash" and bias is None and causal and scale is None:
         from paddlefleetx_tpu.ops.flash_attention import flash_attention, flash_supported
 
@@ -94,9 +99,15 @@ def attention(
             # NB: attention-prob dropout is skipped on the flash path (the
             # reference likewise disables dropout when flash is active,
             # hybrid_model.py:284-301)
-            return flash_attention(
-                q, k, v, causal=True, block=flash_block, bwd_schedule=flash_bwd
-            )
+            def kernel(q, k, v):
+                return flash_attention(
+                    q, k, v, causal=True, block=flash_block, bwd_schedule=flash_bwd
+                )
+
+            if ctx is not None:
+                qkv = ("batch", None, "heads", "kv")
+                kernel = ctx.shard_kernel(kernel, (qkv, qkv, qkv), qkv)
+            return kernel(q, k, v)
     out = xla_attention(
         q,
         k,

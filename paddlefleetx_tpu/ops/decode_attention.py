@@ -18,7 +18,13 @@ Two implementations behind one entry point:
 
   - ``pallas``: one grid program per (batch, head); the kernel fori-loops
     over visited blocks with a runtime trip count read from a scalar
-    input.  Runs on TPU; interpret mode elsewhere (tests force it).
+    input.  Compiled by Mosaic on a TPU (``impl="auto"`` there ALWAYS
+    means this spelling — it never quietly becomes ``lax``), interpreted
+    on the CPU when a test asks for it.  Mosaic must be able to PROVE
+    every dynamic block load tile-aligned, so the cache length has to be
+    a multiple of :data:`KV_ALIGN` (:data:`KV_ALIGN_INT8` for int8, whose
+    scale rows are sliced along lanes) — :func:`kv_cache_len` is what
+    ``init_cache`` allocates; a misaligned cache is refused, not rerouted.
     KNOWN LIMIT (contiguous spelling only): the BlockSpec streams the
     full [max_len, d] cache row into VMEM per program, so the length
     scaling applies to FLOPs but NOT to the HBM reads; note the partial
@@ -30,9 +36,10 @@ Two implementations behind one entry point:
     exactly one pool block per grid step, so HBM reads scale with each
     row's real length.
   - ``lax``: the same blocked loop as ``lax.fori_loop`` +
-    ``dynamic_slice`` — CPU fallback and the path used under GSPMD
-    sharding (a pallas_call inside a partitioned jit would need
-    shard_map; XLA partitions the lax loop for free).
+    ``dynamic_slice`` — what ``auto`` means on the CPU, and the spelling
+    the generation layer picks under GSPMD sharding (a pallas_call inside
+    a partitioned jit would need shard_map; XLA partitions the lax loop
+    for free).
 
 Cache layout is [batch, heads, max_len, head_dim] (heads-major) so the
 Pallas block tiling keeps (seq, head_dim) as the minor dims — see
@@ -73,13 +80,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddlefleetx_tpu.utils import device as _device
+
 NEG_INF = -1e30
 
 _DEFAULT_BLOCK = 256
 
+# Alignment Mosaic can prove for the contiguous kernel's dynamic block
+# loads: the clamped start min(j*block, max_len-block) is a multiple of
+# the alignment exactly when block and max_len both are.  Sublane slices
+# (K/V rows) need 8; the int8 spelling also slices its [1, max_len] scale
+# rows along LANES, which need 128.
+KV_ALIGN = 8
+KV_ALIGN_INT8 = 128
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+
+def kv_cache_len(slots: int, quantized: bool = False) -> int:
+    """Allocated length of a contiguous cache that must hold ``slots``:
+    rounded up to the kernel's alignment.  The slack is never visited
+    (every spelling stops at ``pos + t``)."""
+    align = KV_ALIGN_INT8 if quantized else KV_ALIGN
+    return -(-int(slots) // align) * align
 
 
 def _parse_int_env(name: str) -> int:
@@ -103,10 +124,9 @@ def decode_block(max_len: int, block: int = 0) -> int:
     invalid override fails loudly in both spellings.  When the CLAMP
     breaks alignment (a cache shorter than the requested block and not
     itself a multiple of 8, e.g. max_len 20) the block rounds DOWN to the
-    nearest multiple of 8 so the Pallas tiling invariant survives; only a
-    cache shorter than 8 slots yields a sub-8 block, and
-    :func:`decode_attention` routes that degenerate case to the lax
-    spelling (Mosaic could not tile it)."""
+    nearest multiple of 8; only a cache shorter than 8 slots yields a
+    sub-8 block (lax spelling only — the pallas spelling refuses a cache
+    that is not :func:`kv_cache_len`-aligned)."""
     force = int(block) or _parse_int_env("PFX_DECODE_BLOCK")
     if force:
         if force < 0 or force % 8:
@@ -269,6 +289,7 @@ def _decode_kernel(
     def body(j, carry):
         m, l, acc = carry
         start = jnp.maximum(jnp.minimum(j * block, max_len - block), 0)
+        start = pl.multiple_of(start, KV_ALIGN)
         k = k_ref[0, 0, pl.dslice(start, block), :]
         v = v_ref[0, 0, pl.dslice(start, block), :]
         s = scale * jax.lax.dot_general(
@@ -297,10 +318,12 @@ def _decode_kernel_q8(
     *, scale, block, max_len, t
 ):
     """int8 spelling of :func:`_decode_kernel`: the kv refs stream the
-    cache as int8 and the per-slot scales ride two [max_len] f32 rows —
-    scores absorb the key scale per COLUMN, probabilities absorb the
-    value scale per column, so the dequantized cache never exists and
-    the block's HBM bytes are half the bf16 kernel's."""
+    cache as int8 and the per-slot scales ride two [1, max_len] f32 rows
+    (lane-major, so a block's scales come out as the [1, block] row the
+    score columns need) — scores absorb the key scale per COLUMN,
+    probabilities absorb the value scale per column, so the dequantized
+    cache never exists and the block's HBM bytes are half the bf16
+    kernel's."""
     q = q_ref[0, 0].astype(jnp.float32)  # [t, d]
     d = q.shape[-1]
     limit = limit_ref[0, 0]
@@ -314,13 +337,14 @@ def _decode_kernel_q8(
     def body(j, carry):
         m, l, acc = carry
         start = jnp.maximum(jnp.minimum(j * block, max_len - block), 0)
+        start = pl.multiple_of(start, KV_ALIGN_INT8)
         k = k_ref[0, 0, pl.dslice(start, block), :].astype(jnp.float32)
         v = v_ref[0, 0, pl.dslice(start, block), :].astype(jnp.float32)
-        ksl = ks_ref[0, 0, pl.dslice(start, block)]
-        vsl = vs_ref[0, 0, pl.dslice(start, block)]
+        ksl = ks_ref[0, 0, :, pl.dslice(start, block)]  # [1, block]
+        vsl = vs_ref[0, 0, :, pl.dslice(start, block)]
         s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * ksl[None, :]  # [t, block]
+        ) * ksl  # [t, block]
         col = start + jax.lax.broadcasted_iota(jnp.int32, (t, block), 1)
         mask = (col <= row_pos) & (col >= j * block) & (col >= vf)
         s = jnp.where(mask, s, NEG_INF)
@@ -329,7 +353,7 @@ def _decode_kernel_q8(
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + p.sum(axis=-1)
         acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p * vsl[None, :], v, (((1,), (0,)), ((), ())),
+            p * vsl, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         return m_new, l_new, acc_new
@@ -343,6 +367,17 @@ def _decode_pallas(q_t, k_cache, v_cache, limit, valid_from, block, scale,
                    k_scale=None, v_scale=None):
     b, n, t, d = q_t.shape
     max_len = k_cache.shape[2]
+    align = KV_ALIGN_INT8 if k_scale is not None else KV_ALIGN
+    if max_len % align or block % align:
+        # the in-kernel pl.multiple_of hint would be a lie: refuse rather
+        # than hand Mosaic a misaligned load (or quietly take the lax path)
+        raise ValueError(
+            f"pallas decode attention needs cache length {max_len} and "
+            f"block {block} to be multiples of {align} "
+            f"({'int8' if k_scale is not None else 'native'} cache); "
+            "allocate with init_cache / kv_cache_len, fix PFX_DECODE_BLOCK, "
+            "or pass impl='lax'"
+        )
     limit_arr = jnp.full((1, 1), limit, jnp.int32)
     vf_arr = (
         jnp.zeros((b, 1, 1), jnp.int32)
@@ -350,8 +385,12 @@ def _decode_pallas(q_t, k_cache, v_cache, limit, valid_from, block, scale,
         else valid_from.astype(jnp.int32).reshape(b, 1, 1)
     )
     kv_spec = pl.BlockSpec((1, 1, max_len, d), lambda i, j: (i, j, 0, 0))
-    scl_spec = pl.BlockSpec((1, 1, max_len), lambda i, j: (i, j, 0))
     if k_scale is not None:
+        # scale planes enter as [b, n, 1, max_len]: a (1, max_len) block
+        # equals the array's last two dims, which the (8, 128) tiling rule
+        # accepts — a bare [b, n, max_len] plane's (1, max_len) block does
+        # not (its second-to-last dim is the heads axis)
+        scl_spec = pl.BlockSpec((1, 1, 1, max_len), lambda i, j: (i, j, 0, 0))
         kernel = functools.partial(
             _decode_kernel_q8, scale=scale, block=block, max_len=max_len, t=t
         )
@@ -366,8 +405,9 @@ def _decode_pallas(q_t, k_cache, v_cache, limit, valid_from, block, scale,
             ],
             out_specs=pl.BlockSpec((1, 1, t, d), lambda i, j: (i, j, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((b, n, t, d), jnp.float32),
-            interpret=_interpret(),
-        )(q_t, k_cache, v_cache, k_scale, v_scale, limit_arr, vf_arr)
+            interpret=_device.pallas_interpret(),
+        )(q_t, k_cache, v_cache, k_scale[:, :, None], v_scale[:, :, None],
+          limit_arr, vf_arr)
     kernel = functools.partial(
         _decode_kernel, scale=scale, block=block, max_len=max_len, t=t
     )
@@ -382,7 +422,7 @@ def _decode_pallas(q_t, k_cache, v_cache, limit, valid_from, block, scale,
         ],
         out_specs=pl.BlockSpec((1, 1, t, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n, t, d), jnp.float32),
-        interpret=_interpret(),
+        interpret=_device.pallas_interpret(),
     )(q_t, k_cache, v_cache, limit_arr, vf_arr)
     return out
 
@@ -416,7 +456,9 @@ def decode_attention(
     both spellings dequantize IN-KERNEL (scores absorb the key scale,
     probabilities the value scale) — pass both or neither.
 
-    ``impl``: "auto" (pallas on TPU, lax elsewhere) | "pallas" | "lax".
+    ``impl``: "auto" (pallas on a TPU, lax on the CPU) | "pallas" | "lax".
+    The pallas spelling needs a :func:`kv_cache_len`-aligned cache and
+    refuses any other — on a TPU "auto" never degrades to lax.
     """
     if impl not in ("auto", "pallas", "lax"):
         raise ValueError(f"decode_attention impl {impl!r}; valid: auto, pallas, lax")
@@ -428,11 +470,7 @@ def decode_attention(
     scale = float(1.0 / (d**0.5))
     limit = pos + t
     q_t = q.transpose(0, 2, 1, 3)  # [b, n, t, d]
-    # a sub-8 block only happens for a degenerate cache shorter than 8
-    # slots (decode_block rounds down otherwise): Mosaic cannot sublane-
-    # tile it, so route to the lax spelling
-    use_pallas = impl == "pallas" or (impl == "auto" and not _interpret())
-    if use_pallas and bs % 8 == 0:
+    if impl == "pallas" or (impl == "auto" and not _device.pallas_interpret()):
         out = _decode_pallas(q_t, k_cache, v_cache, limit, kv_valid_from,
                              bs, scale, k_scale, v_scale)
     else:
@@ -558,7 +596,7 @@ def _paged_kernel(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # [t, bs]
     if quant:
-        s = s * ks_ref[0, 0][None, :]
+        s = s * ks_ref[0, 0]  # [1, bs] row
     col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (t, bs), 1)
     # query qi's own causal bound: slot pos + qi
     qrow = pos + jax.lax.broadcasted_iota(jnp.int32, (t, bs), 0)
@@ -570,7 +608,7 @@ def _paged_kernel(
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_new = l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-    pv = p * vs_ref[0, 0][None, :] if quant else p.astype(v.dtype)
+    pv = p * vs_ref[0, 0] if quant else p.astype(v.dtype)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         pv, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -605,9 +643,11 @@ def _paged_pallas(q_t, k_pool, v_pool, tables, positions, scale,
         return tables_ref[i, jnp.minimum(k, last)], j, 0, 0
 
     def scl_index(i, j, k, tables_ref, pos_ref):
-        # same clamped pool-block address, scale tile [1, 1, bs]
+        # same clamped pool-block address; the scale planes enter as
+        # [nb, n, 1, bs] so the (1, bs) tile equals the array's last two
+        # dims (the (8, 128) rule refuses a (1, bs) tile of [nb, n, bs])
         last = jnp.maximum(pos_ref[i] + (t - 1), 0) // bs
-        return tables_ref[i, jnp.minimum(k, last)], j, 0
+        return tables_ref[i, jnp.minimum(k, last)], j, 0, 0
 
     in_specs = [
         pl.BlockSpec((1, 1, t, d), lambda i, j, k, *_: (i, j, 0, 0)),
@@ -617,10 +657,10 @@ def _paged_pallas(q_t, k_pool, v_pool, tables, positions, scale,
     operands = [q_t, k_pool, v_pool]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, 1, bs), scl_index),
-            pl.BlockSpec((1, 1, bs), scl_index),
+            pl.BlockSpec((1, 1, 1, bs), scl_index),
+            pl.BlockSpec((1, 1, 1, bs), scl_index),
         ]
-        operands += [k_scale, v_scale]
+        operands += [k_scale[:, :, None], v_scale[:, :, None]]
 
         def kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                    o_ref, acc_ref, m_ref, l_ref):
@@ -647,7 +687,7 @@ def _paged_pallas(q_t, k_pool, v_pool, tables, positions, scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n, t, d), jnp.float32),
-        interpret=_interpret(),
+        interpret=_device.pallas_interpret(),
     )(tables, positions, *operands)
 
 
@@ -681,7 +721,7 @@ def paged_decode_attention(
     spelling rides the same scalar-prefetch-clamped index map, so the
     scale tiles DMA with their block) — pass both or neither.
 
-    ``impl``: "auto" (pallas on TPU, lax elsewhere) | "pallas" | "lax".
+    ``impl``: "auto" (pallas on a TPU, lax on the CPU) | "pallas" | "lax".
     The pallas spelling DMAs exactly one pool block per grid step with a
     scalar-prefetch-clamped index map — the HBM reads scale with each
     row's real length, retiring the known limit of `_decode_pallas`
@@ -698,18 +738,19 @@ def paged_decode_attention(
     if t < 1:
         raise ValueError(f"paged_decode_attention needs t >= 1; got t={t}")
     bs = k_pool.shape[2]
-    if impl == "pallas" and bs % 8:
-        # an explicit pallas request must run pallas or fail LOUDLY — a
-        # silent lax fallback would mislabel A/B evidence
+    use_pallas = impl == "pallas" or (impl == "auto" and not _device.pallas_interpret())
+    if use_pallas and bs % 8:
+        # pallas (asked for, or what "auto" means on a TPU) runs pallas
+        # or fails LOUDLY — a silent lax fallback would mislabel A/B
+        # evidence and hide a slow serving path
         raise ValueError(
             f"paged block size {bs} is not a multiple of 8 (TPU sublane "
-            "tiling); impl='pallas' cannot honor it — use impl='lax' or "
-            "a multiple-of-8 PFX_KV_BLOCK"
+            "tiling); the pallas spelling cannot honor it — use "
+            "impl='lax' or a multiple-of-8 PFX_KV_BLOCK"
         )
     scale = float(1.0 / (d**0.5))
     q_t = q.transpose(0, 2, 1, 3)  # [b, n, t, d]
-    use_pallas = impl == "pallas" or (impl == "auto" and not _interpret())
-    if use_pallas and bs % 8 == 0:
+    if use_pallas:
         out = _paged_pallas(q_t, k_pool, v_pool, block_tables, positions,
                             scale, k_scale, v_scale)
     else:

@@ -25,9 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddlefleetx_tpu.utils import device as _device
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _row_block(n_rows: int) -> int:
@@ -117,7 +116,7 @@ def _run_fwd(x2, res2, scale, bias, eps):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        interpret=_interpret(),
+        interpret=_device.pallas_interpret(),
     )(*args)
 
 
@@ -165,7 +164,7 @@ def _fused_ln_bwd(eps, has_res, saved, g):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        interpret=_interpret(),
+        interpret=_device.pallas_interpret(),
     )(*args)
     dscale = dscale_p.astype(scale.dtype)
     dbias = dbias_p.astype(scale.dtype)

@@ -15,6 +15,7 @@ import jax
 
 from paddlefleetx_tpu.parallel.mesh import MeshConfig, build_mesh, set_mesh
 from paddlefleetx_tpu.parallel.seed import init_seed
+from paddlefleetx_tpu.utils.device import device_identity
 from paddlefleetx_tpu.utils.log import logger
 
 
@@ -39,6 +40,14 @@ def init_dist_env(cfg, devices=None) -> jax.sharding.Mesh:
             f"jax.distributed initialised: process {jax.process_index()}/{jax.process_count()}"
         )
 
+    # the one line that says which device this run is on: every entry point
+    # (train / serve / eval / export) comes through here first
+    ident = device_identity()
+    logger.info(
+        f"device: platform={ident['platform']} "
+        f"device_kind={ident['device_kind']!r} "
+        f"device_count={ident['device_count']}"
+    )
     mesh_cfg = MeshConfig.from_config(cfg)
     mesh = build_mesh(mesh_cfg, devices)
     set_mesh(mesh)

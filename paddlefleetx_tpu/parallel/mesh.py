@@ -35,6 +35,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from paddlefleetx_tpu.utils.log import logger
+
 AXIS_DATA = "data"
 AXIS_FSDP = "fsdp"
 AXIS_STAGES = "stages"
@@ -137,17 +139,18 @@ def build_mesh(
                     arr = mesh_utils.create_hybrid_device_mesh(
                         ici, dcn, devices=devices
                     )
+                    logger.info("mesh placement: topology-aware (hybrid ICI x DCN)")
                     return Mesh(arr, MESH_AXES)
             else:
                 arr = mesh_utils.create_device_mesh(shape, devices=devices)
+                logger.info("mesh placement: topology-aware (create_device_mesh)")
                 return Mesh(arr, MESH_AXES)
         except Exception as e:  # topology helper rejected the shape
-            from paddlefleetx_tpu.utils.log import logger
-
             logger.warning(
                 f"topology-aware mesh placement failed ({e!r}); "
                 "falling back to row-major device assignment"
             )
+    logger.info("mesh placement: row-major")
     arr = np.asarray(devices, dtype=object).reshape(shape)
     return Mesh(arr, MESH_AXES)
 

@@ -176,18 +176,27 @@ def pipelined_stack(
 # Each tick every device runs, per local chunk slot: one forward
 # (embed|recv -> chunk) and one VJP (recompute embed+chunk+head from the
 # stashed input, pull back the cotangent arriving from the next chunk).
-# Out-of-range events compute on zeros and are masked out of every
-# accumulator.  Activations and cotangents ride neighbour-to-neighbour
+# Out-of-range events compute on zeros and are SELECTED out of every
+# accumulator (``_masked``: never multiplied out).  Activations and cotangents ride neighbour-to-neighbour
 # ppermutes in the compute dtype; the only stage-psums are parameter
 # gradients and the scalar loss numerator.
 
 
-def _tree_axpy(acc, new, w):
-    # cast back to the accumulator dtype: w is fp32 (a liveness mask), so
-    # the product would silently promote a bf16 grad accumulator to fp32
-    # and break the scan carry's dtype invariant under multi_precision=
-    # False / main_grad=False (bf16 params or grads)
-    return jax.tree.map(lambda a, g: a + (w * g).astype(a.dtype), acc, new)
+def _masked(live, g, dtype):
+    """``g`` where the event is live, 0 elsewhere — a SELECT, never a
+    product.  A dead event pulls a real cotangent back through a stage fed
+    all-zero activations; LayerNorm's backward at zero variance scales by
+    rsqrt(eps) ~ 316 per layer, and a dozen layers deep that overflows
+    bf16: 0 * inf is NaN, where(False, inf, 0) is 0.  (Seen on the chip at
+    GPT-345M dp2·pp2: NaN parameter grads in the first four layers of the
+    second stage, loss exact — PR 21.)  Cast to the accumulator dtype so a
+    bf16 grad accumulator keeps the scan carry's dtype invariant under
+    multi_precision=False / main_grad=False."""
+    return jnp.where(live, g, jnp.zeros_like(g)).astype(dtype)
+
+
+def _tree_add_live(acc, new, live):
+    return jax.tree.map(lambda a, g: a + _masked(live, g, a.dtype), acc, new)
 
 
 def _run_1f1b(fns, pcfg: PipelineConfig, mesh, params, batch):
@@ -287,11 +296,10 @@ def _run_1f1b(fns, pcfg: PipelineConfig, mesh, params, batch):
                 gy = jnp.where(is_last, jnp.zeros_like(bwd_buf[v]), bwd_buf[v])
                 gn = jnp.where(is_last, 1.0, 0.0).astype(jnp.float32)
                 gep, glv, ghp, gx = vjp((gy, gn))
-                w = b_live.astype(jnp.float32)
-                ge = _tree_axpy(ge, gep, w)
-                gh = _tree_axpy(gh, ghp, w)
+                ge = _tree_add_live(ge, gep, b_live)
+                gh = _tree_add_live(gh, ghp, b_live)
                 gl = jax.tree.map(
-                    lambda a, g, _v=v: a.at[_v].add((w * g).astype(a.dtype)),
+                    lambda a, g, _v=v: a.at[_v].add(_masked(b_live, g, a.dtype)),
                     gl, glv,
                 )
                 numer = numer + jnp.where(is_last & b_live, nr, 0.0).astype(jnp.float32)

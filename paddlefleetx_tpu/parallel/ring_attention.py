@@ -8,18 +8,11 @@ shards rotate around the ring (``ppermute`` hops over ICI), with
 online-softmax accumulation so no device ever materialises the full
 sequence — memory O(s/P), compute O(s²/P) per device.
 
-Implemented as a ``sep``-manual ``shard_map`` through the version-split
-adapter (``parallel/shard_map_compat.py``), with ``lax.scan`` over ring
-steps so reverse-mode autodiff produces the reverse-ring backward
-automatically.  On jax >= 0.9 the map is partially manual
-(batch/heads/model axes stay GSPMD-auto inside); on jax 0.4.x it runs
-full-manual with batch/heads sharded *at the map boundary* where the
-shapes divide (the per-(batch, head) math needs no in-body communication,
-so richer boundary specs keep DP/TP live without partial-auto), and when
-nested inside another manual region (the 1F1B pipeline on 0.4.x, where a
-second shard_map cannot open) the ring runs on the *ambient* manual
-``sep`` axis: slice the locally-replicated sequence by ``axis_index``,
-rotate K/V with ``ppermute``, ``all_gather`` the outputs back.
+Implemented as a ``sep``-manual ``shard_map`` through the adapter
+(``parallel/shard_map_compat.py``), with ``lax.scan`` over ring steps so
+reverse-mode autodiff produces the reverse-ring backward automatically.
+The map is partially manual: batch/heads/model axes stay GSPMD-auto
+inside, and it nests inside the 1F1B pipeline's ``stages``-manual map.
 Complements Ulysses (sharding.py heads/(model,sep) rule): Ulysses reshards
 seq<->heads with all-to-alls and needs heads >= sep degree; ring has no
 head-count constraint and overlaps compute with neighbour exchange.
@@ -35,12 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from paddlefleetx_tpu.parallel import shard_map_compat
-from paddlefleetx_tpu.parallel.mesh import (
-    AXIS_DATA,
-    AXIS_FSDP,
-    AXIS_MODEL,
-    AXIS_SEP,
-)
+from paddlefleetx_tpu.parallel.mesh import AXIS_SEP
 
 NEG_INF = -1e30
 
@@ -209,150 +197,18 @@ def ring_attention(
         out = acc / l_safe.transpose(0, 2, 1)[..., None]
         return out.astype(q.dtype)
 
-    if AXIS_SEP in shard_map_compat.current_manual_axes():
-        # 0.4.x nesting: the enclosing full-manual map (1F1B pipeline)
-        # already made ``sep`` manual and a second shard_map cannot open
-        # (its axes are already manual) — run the ring on the ambient axis.
-        return _ring_nested_manual(q, k, v, positions, local_fn, ring, causal, scale)
+    # nested-map support (ring inside the 1F1B pipeline's stages-manual
+    # shard_map): the inner map must be built against the AMBIENT abstract
+    # mesh — passing the concrete Mesh from inside a manual context trips a
+    # context-mesh mismatch
+    from jax.sharding import get_abstract_mesh
 
-    if shard_map_compat.HAS_JAX09_SHARD_MAP:
-        # nested-map support (ring inside the 1F1B pipeline's stages-manual
-        # shard_map): the inner map must be built against the AMBIENT
-        # abstract mesh — passing the concrete Mesh from inside a manual
-        # context trips a context-mesh mismatch in jax 0.9
-        from jax.sharding import get_abstract_mesh
-
-        amesh = get_abstract_mesh()
-        inner_mesh = amesh if AXIS_SEP in amesh.axis_names else mesh
-        full_specs = None
-    else:
-        inner_mesh = mesh
-        # 0.4.x full-manual: the body is elementwise-independent over batch
-        # and heads, so those dims can stay sharded at the map boundary
-        # (no in-body communication needed) instead of being gathered —
-        # keeps DP/TP live under full-manual.  Only axes whose sizes
-        # divide the dims are taken (shard_map requires exact splits).
-        b_axes = _divisible_axes(q.shape[0], (AXIS_DATA, AXIS_FSDP), mesh)
-        h_axes = _divisible_axes(q.shape[2], (AXIS_MODEL,), mesh)
-        qkv_spec = P(b_axes, AXIS_SEP, h_axes, None)
-        full_specs = (
-            (qkv_spec, qkv_spec, qkv_spec, P(AXIS_SEP)),
-            qkv_spec,
-        )
+    amesh = get_abstract_mesh()
+    inner_mesh = amesh if AXIS_SEP in amesh.axis_names else mesh
     return shard_map_compat.shard_map(
         local_fn,
         inner_mesh,
         in_specs=(P(None, AXIS_SEP), P(None, AXIS_SEP), P(None, AXIS_SEP), P(AXIS_SEP)),
         out_specs=P(None, AXIS_SEP),
         manual_axes={AXIS_SEP},
-        full_specs=full_specs,
     )(q, k, v, positions)
-
-
-def _divisible_axes(dim: int, axes, mesh):
-    """Greedy prefix of ``axes`` whose combined size divides ``dim`` (and
-    is > 1) — the shardable portion of a dim under full-manual specs."""
-    chosen = []
-    prod = 1
-    for ax in axes:
-        size = mesh.shape.get(ax, 1)
-        if size > 1 and dim % (prod * size) == 0:
-            chosen.append(ax)
-            prod *= size
-    if not chosen:
-        return None
-    return tuple(chosen) if len(chosen) > 1 else chosen[0]
-
-
-@jax.custom_vjp
-def _enter_replicated(x):
-    """Replicated -> rank-local frame seam (identity forward).
-
-    Inside the enclosing full-manual map the inputs are replicated over
-    ``sep`` and each rank computes only its sequence block's
-    contribution, so the raw cotangent arriving here is the rank's
-    zero-padded partial (rank-varying).  The replicated input's true
-    cotangent is the SUM of those disjoint partials, identical on every
-    rank — a ``psum`` over the ring.  Without this seam the enclosing
-    schedule's parameter grads inherit one arbitrary rank's partial
-    (verified wrong by ~1e3 rel on pp2 x sep2 before the fix)."""
-    return x
-
-
-def _enter_replicated_fwd(x):
-    return x, None
-
-
-def _enter_replicated_bwd(_, ct):
-    return (jax.lax.psum(ct, AXIS_SEP),)
-
-
-_enter_replicated.defvjp(_enter_replicated_fwd, _enter_replicated_bwd)
-
-
-@jax.custom_vjp
-def _gather_replicated(out_l):
-    """Rank-local -> replicated frame seam: all_gather forward, OWN-SLICE
-    backward.
-
-    Every sep rank redundantly consumes the gathered (replicated) output
-    downstream, but those copies are ONE logical consumer — the enclosing
-    map's out_specs claim sep-replication.  jax's default all_gather
-    transpose (psum_scatter) would sum the identical per-rank cotangents
-    and over-count each block by the ring size; the true cotangent of
-    rank i's local block is simply its own slice of the (replicated)
-    downstream cotangent, counted once."""
-    return jax.lax.all_gather(out_l, AXIS_SEP, axis=1, tiled=True)
-
-
-def _gather_replicated_fwd(out_l):
-    return _gather_replicated(out_l), out_l.shape[1]  # static local length
-
-
-def _gather_replicated_bwd(sl, ct):
-    start = jax.lax.axis_index(AXIS_SEP) * sl
-    return (jax.lax.dynamic_slice_in_dim(ct, start, sl, axis=1),)
-
-
-_gather_replicated.defvjp(_gather_replicated_fwd, _gather_replicated_bwd)
-
-
-def _ring_nested_manual(q, k, v, positions, local_fn, ring, causal, scale):
-    """Ring attention on the *ambient* manual ``sep`` axis (jax 0.4.x,
-    inside the pipeline's full-manual map).
-
-    The enclosing map replicates non-``stages`` axes at its boundary, so
-    every sep coordinate holds the full sequence.  Context parallelism is
-    re-introduced explicitly: each sep rank slices out its sequence block,
-    runs the ring schedule (``ppermute`` hops on the already-manual axis),
-    and an ``all_gather`` rebuilds the full — genuinely replicated —
-    output the rest of the (replicated) layer consumes.  The two frame
-    seams carry custom VJPs (``_enter_replicated`` /
-    ``_gather_replicated``) so the backward counts each block's cotangent
-    exactly once and psums the disjoint per-rank input grads back to the
-    replicated frame — the manual reverse ring, with sep-INVARIANT
-    results (the enclosing map's out_specs assert sep-replication, so a
-    rank-varying grad would silently emit one rank's partial)."""
-    s = q.shape[1]
-    if s % ring:
-        # indivisible sequence: no balanced ring exists — run the dense
-        # online-softmax locally (every rank replicated, mask by positions)
-        b, _, n, d = q.shape
-        m0 = jnp.full((b, n, s), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((b, n, s), jnp.float32)
-        acc0 = jnp.zeros((b, s, n, d), jnp.float32)
-        m, l, acc = _softmax_update(
-            q, k, v, m0, l0, acc0, positions[:, None], positions[None, :],
-            causal, scale,
-        )
-        l_safe = jnp.maximum(l, 1e-30)
-        return (acc / l_safe.transpose(0, 2, 1)[..., None]).astype(q.dtype)
-    sl = s // ring
-    start = jax.lax.axis_index(AXIS_SEP) * sl
-    q, k, v = _enter_replicated(q), _enter_replicated(k), _enter_replicated(v)
-    q_l = jax.lax.dynamic_slice_in_dim(q, start, sl, axis=1)
-    k_l = jax.lax.dynamic_slice_in_dim(k, start, sl, axis=1)
-    v_l = jax.lax.dynamic_slice_in_dim(v, start, sl, axis=1)
-    pos_l = jax.lax.dynamic_slice_in_dim(positions, start, sl, axis=0)
-    out_l = local_fn(q_l, k_l, v_l, pos_l)
-    return _gather_replicated(out_l)

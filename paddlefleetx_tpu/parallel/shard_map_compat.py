@@ -1,85 +1,38 @@
-"""Version-split ``shard_map`` adapter: one entry point, two lowerings.
+"""``shard_map`` adapter: one entry point for the repo's manual regions.
 
 The parallel schedules (``parallel/pipeline.py`` 1F1B/GPipe,
-``parallel/ring_attention.py``) are written against the jax>=0.9
-``jax.shard_map(axis_names=, check_vma=)`` *partially-manual* API: manual
-over the schedule's own axis (``stages`` / ``sep``) with every other mesh
-axis left to GSPMD.  jax 0.4.x only ships ``jax.experimental.shard_map``,
-and its partial-auto mode (``auto=``) is unusable for these schedules: the
-lowering emits a ``PartitionId`` instruction XLA's SPMD partitioner rejects
-(UNIMPLEMENTED), and with a sharding constraint in the body it dies in a
-hard ``spmd_partitioner.cc`` CHECK (``target.IsManualSubgroup() ==
-sharding().IsManualSubgroup()``) — verified on jax 0.4.37, see
-docs/parallelism.md.  A shim cannot paper over that; the port contract is:
+``parallel/ring_attention.py``) and the Pallas kernels under a mesh
+(``parallel/sharding.shard_kernel``) are *partially manual*: manual over
+their own axes (``stages`` / ``sep`` / the batch and heads axes) with
+every other mesh axis left to GSPMD — ``jax.shard_map(axis_names=,
+check_vma=False)``.  Specs may only name the manual axes.
 
-* **jax >= 0.9** — route to ``jax.shard_map`` with ``axis_names=
-  manual_axes`` (partial manual, the original spelling).  Specs pass
-  through verbatim: they may only name manual axes.
-
-* **jax 0.4.x** — route to ``jax.experimental.shard_map.shard_map`` in
-  **full-manual** mode (every mesh axis manual, ``check_rep=False``).
-  Mapped bodies must then be *valid full-manual programs*: all cross-shard
-  communication is explicit in-body collectives (``ppermute`` neighbour
-  hops, ``psum``/``all_gather`` seams), and no in-body sharding constraint
-  may name a mesh axis (``sharding.with_logical_constraint`` drops such
-  constraints inside manual regions — constrain at the in_specs/out_specs
-  boundary instead).  Mesh axes a spec does not name are *replicated at
-  the boundary*: XLA gathers inputs sharded along them, the body computes
-  identically at every coordinate of those axes, and outputs are truly
-  replicated (which is what makes ``check_rep=False`` sound here).
-  Callers that can shard more axes without in-body communication (ring
-  attention: batch/heads) pass richer ``full_specs`` used only on this
-  branch.
-
-Both branches record the body's manual axis set in a thread-local while
-the body traces, so code deep inside a mapped region (sharding
-constraints, nested ring attention) can ask :func:`current_manual_axes`
-instead of guessing from jax internals.  On 0.4.x nesting a second
-shard_map inside a full-manual region is impossible (the inner map's axes
-are already manual — jax raises); nested schedules use the ambient manual
-axes directly (``ring_attention._ring_nested_manual``).
+The adapter adds one thing jax does not give: it records the body's manual
+axis set in a thread-local while the body traces, so code deep inside a
+mapped region (sharding constraints, a nested kernel map) can ask
+:func:`current_manual_axes` instead of guessing from jax internals.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Any, Callable, FrozenSet, Iterable, Optional, Tuple
+from typing import Any, Callable, FrozenSet, Iterable
 
 import jax
 
 __all__ = [
-    "HAS_JAX09_SHARD_MAP",
     "shard_map",
     "current_manual_axes",
     "in_manual_region",
 ]
 
-
-def _has_jax09_shard_map() -> bool:
-    """True when this jax carries the 0.9-era ``jax.shard_map(axis_names=,
-    check_vma=)`` API (same detection the test harness uses)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        return False
-    try:
-        import inspect
-
-        return "check_vma" in inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # builtins/uninspectable: assume new
-        return True
-
-
-HAS_JAX09_SHARD_MAP: bool = _has_jax09_shard_map()
-
 _TLS = threading.local()
 
 
 def current_manual_axes() -> FrozenSet[str]:
-    """Mesh axes that are Manual in the innermost shard_map body currently
-    being traced on this thread (empty outside any mapped region).
-
-    On the 0.4.x branch this is *every* axis of the mapped mesh (full
-    manual); on >=0.9 it is the ``manual_axes`` the caller requested."""
+    """Mesh axes that are Manual in the shard_map bodies currently being
+    traced on this thread (empty outside any mapped region); nested maps
+    accumulate."""
     return getattr(_TLS, "axes", frozenset())
 
 
@@ -88,13 +41,12 @@ def in_manual_region() -> bool:
 
 
 def _with_manual_axes(body: Callable, axes: FrozenSet[str]) -> Callable:
-    """Wrap ``body`` so the thread-local manual set is ``axes`` while it
-    traces (restored on exit; nesting overwrites, which matches jax: the
-    innermost map's manual set is what in-body code must respect)."""
+    """Wrap ``body`` so the thread-local manual set grows by ``axes`` while
+    it traces (restored on exit)."""
 
     def wrapped(*args):
         prev = getattr(_TLS, "axes", frozenset())
-        _TLS.axes = frozenset(axes)
+        _TLS.axes = prev | frozenset(axes)
         try:
             return body(*args)
         finally:
@@ -109,44 +61,21 @@ def shard_map(
     in_specs: Any,
     out_specs: Any,
     manual_axes: Iterable[str],
-    *,
-    full_specs: Optional[Tuple[Any, Any]] = None,
 ) -> Callable:
-    """Map ``body`` over ``mesh`` manually along ``manual_axes``.
-
-    ``in_specs``/``out_specs`` name only ``manual_axes`` (the 0.9 partial
-    spelling).  ``full_specs``, when given, is an ``(in_specs, out_specs)``
-    pair that may additionally name non-manual axes along which the body is
-    elementwise-independent (no in-body communication needed); it is used
-    on the 0.4.x full-manual branch to keep those axes sharded instead of
-    boundary-replicated.  Returns the mapped callable.
-    """
+    """Map ``body`` over ``mesh`` manually along ``manual_axes``; every
+    other axis stays GSPMD-auto inside.  ``in_specs``/``out_specs`` name
+    only ``manual_axes``.  Returns the mapped callable."""
     manual = frozenset(manual_axes)
     missing = manual - set(mesh.axis_names)
     if missing:
         raise ValueError(
             f"manual axes {sorted(missing)} not in mesh axes {mesh.axis_names}"
         )
-    if HAS_JAX09_SHARD_MAP:
-        return jax.shard_map(
-            _with_manual_axes(body, manual),
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=set(manual),
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map_04x
-
-    if full_specs is not None:
-        in_specs, out_specs = full_specs
-    # Full manual: every mesh axis.  check_rep=False because out_specs
-    # deliberately leave replicated axes unnamed and the 0.4.x rep checker
-    # cannot see through the masked ppermute/psum schedules.
-    return _shard_map_04x(
-        _with_manual_axes(body, frozenset(mesh.axis_names)),
+    return jax.shard_map(
+        _with_manual_axes(body, manual),
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        axis_names=set(manual),
+        check_vma=False,
     )

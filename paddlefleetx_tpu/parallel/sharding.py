@@ -174,22 +174,24 @@ def drop_small_fsdp(shardings: Any, shapes: Any, min_size: int = 1 << 16) -> Any
     return jax.tree.map(fix, shardings, shapes)
 
 
-def _ambient_abstract_mesh():
-    """The active abstract mesh, or None when there is none.
+def place_on_mesh(tree: Any, mesh: Mesh) -> Any:
+    """Fresh (``jnp.zeros``-style) state, placed on ``mesh`` the way a jit
+    output under that mesh is: replicated ``NamedSharding``.
 
-    ``jax.sharding.get_abstract_mesh`` is only re-exported on jax >= 0.5;
-    older jaxlibs keep it under ``jax._src.mesh`` (and return an empty
-    placeholder instead of a real mesh when no context is active), so
-    normalize both spellings here instead of crashing every TP
-    constraint on the public-attribute lookup."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is None:
-        try:
-            from jax._src.mesh import get_abstract_mesh as getter
-        except ImportError:  # pragma: no cover — future jax w/o either
-            return None
-    mesh = getter()
-    return mesh if getattr(mesh, "axis_names", None) else None
+    jax types an array by the mesh it lives on, and that type is part of
+    jit's tracing-cache key.  State that a jitted step takes AND returns
+    (KV pools, decode row state, a donated cache) would otherwise key two
+    compiles per shape: one for the first call on the bare fresh arrays,
+    one for every later call on the step's own outputs — i.e. a warmup that
+    warms nothing traffic uses."""
+    return jax.device_put(tree, NamedSharding(mesh, P()))
+
+
+def _ambient_abstract_mesh():
+    """The active abstract mesh, or None when there is none (jax returns an
+    empty placeholder then)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 def _strip_manual_axes(spec: P, manual) -> P:
@@ -211,12 +213,11 @@ def with_logical_constraint(x: jax.Array, logical_axes, rules, mesh: Mesh):
     a NamedSharding would pin the all-Auto outer mesh and mismatch.
 
     Inside a *manual* mapped region (shard_map_compat), axes that are
-    Manual must not appear in the constraint at all: 0.4.x full-manual
-    shard_map rejects them outright, and on 0.9 they are meaningless (the
-    body already holds the per-shard block).  Such axes are stripped; a
-    constraint with nothing left is a no-op — the sharding moves to the
-    in_specs/out_specs boundary of the enclosing map, which is the 0.4.x
-    port contract (docs/parallelism.md)."""
+    Manual must not appear in the constraint at all: they are meaningless
+    there (the body already holds the per-shard block).  Such axes are
+    stripped; a constraint with nothing left is a no-op — the sharding
+    lives at the in_specs/out_specs boundary of the enclosing map
+    (docs/parallelism.md)."""
     spec = logical_to_spec(logical_axes, rules)
     from paddlefleetx_tpu.parallel.shard_map_compat import current_manual_axes
 
@@ -229,3 +230,77 @@ def with_logical_constraint(x: jax.Array, logical_axes, rules, mesh: Mesh):
     if _ambient_abstract_mesh() is not None:
         return jax.lax.with_sharding_constraint(x, spec)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def shard_kernel(fn, mesh: Mesh, rules, in_logical, out_logical):
+    """Run ``fn`` — a Pallas kernel — under a mesh: inside a ``shard_map``
+    that is Manual over EVERY mesh axis, its arguments split along the axes
+    their logical dims are sharded on.
+
+    Mosaic kernels cannot be partitioned by GSPMD: a bare ``pallas_call``
+    in a jit over more than one device is refused by the TPU lowering
+    ("wrap the call in a shard_map"), and so is one inside a *partially*
+    manual map — though the CPU interpreter partitions either like any XLA
+    code, which is how this stayed invisible to the CPU-mesh suite.  ``fn``
+    must be independent per shard along every named dim (flash attention
+    over batch and heads, LayerNorm over batch and seq) — no in-body
+    communication; cotangents of replicated arguments (LayerNorm
+    scale/bias) are psum'd by shard_map's own transpose.
+
+    ``in_logical`` / ``out_logical`` give each argument's / the result's
+    logical axis names (result names must appear among the arguments').
+    A mesh axis is named in the specs only where it divides every dim
+    carrying the logical name (``shard_map`` needs exact splits); along the
+    rest the argument is replicated at the boundary and every shard
+    computes the same thing.  Inside an enclosing manual map (the 1F1B
+    pipeline's ``stages``) the kernel map nests: built on the ambient
+    abstract mesh, Manual over the axes still Auto.  On a one-device mesh
+    the kernel runs bare."""
+    from paddlefleetx_tpu.parallel import shard_map_compat
+
+    table = dict(rules)
+
+    def call(*args):
+        if mesh.size == 1:
+            return fn(*args)
+        ambient = shard_map_compat.current_manual_axes()
+        dims: dict = {}
+        for logical, a in zip(in_logical, args):
+            for name, dim in zip(logical, a.shape):
+                if name is not None:
+                    dims.setdefault(name, []).append(dim)
+        missing = {n for n in out_logical if n is not None} - set(dims)
+        if missing:
+            raise ValueError(
+                f"shard_kernel result axes {sorted(missing)} name no argument dim"
+            )
+        chosen: dict = {}
+        used: set = set()
+        for name, sizes in dims.items():
+            axes = table.get(name) or ()
+            take, prod = [], 1
+            for ax in (axes,) if isinstance(axes, str) else axes:
+                n = mesh.shape[ax]
+                if n > 1 and ax not in ambient and ax not in used and all(
+                    d % (prod * n) == 0 for d in sizes
+                ):
+                    take.append(ax)
+                    prod *= n
+            used.update(take)
+            chosen[name] = tuple(take)
+
+        def spec(logical):
+            entries = [chosen[n] if n is not None else () for n in logical]
+            return P(*[
+                (e if len(e) > 1 else e[0]) if e else None for e in entries
+            ])
+
+        return shard_map_compat.shard_map(
+            fn,
+            _ambient_abstract_mesh() or mesh,
+            in_specs=tuple(spec(l) for l in in_logical),
+            out_specs=spec(out_logical),
+            manual_axes=set(mesh.axis_names) - ambient,
+        )(*args)
+
+    return call

@@ -433,8 +433,13 @@ def warn_headroom(wm: Dict[str, Any], threshold: Optional[float] = None) -> bool
 # retrace attribution: the compile-event log
 # ---------------------------------------------------------------------------
 
+# jax 0.9: "Compiling jit(train_step) with global shapes and types
+# (ShapedArray(...), ...). Argument mapping: (...)." — the avals print as a
+# tuple and the module name carries its jit(...) wrapper
 _COMPILING_RE = re.compile(
-    r"Compiling ([^\s]+) with global shapes and types (\[.*\])\.", re.DOTALL
+    r"Compiling (?:jit\()?([^\s)]+)\)? with global shapes and types "
+    r"(\(.*?\))\. Argument mapping",
+    re.DOTALL,
 )
 _CACHE_HIT_RE = re.compile(r"Persistent compilation cache hit")
 # the per-compile chatter jax_log_compiles turns on (suppressed from run
@@ -449,13 +454,13 @@ _COMPILE_CHATTER_RE = re.compile(
 
 
 def _split_avals(avals: str) -> List[str]:
-    """Split jax's ``[ShapedArray(f32[4]), ...]`` listing into per-arg
+    """Split jax's ``(ShapedArray(f32[4]), ...)`` listing into per-arg
     strings (best-effort: balanced-paren split, robust to nested
     parentheses inside an aval)."""
     body = avals.strip()
-    if body.startswith("["):
+    if body.startswith("("):
         body = body[1:]
-    if body.endswith("]"):
+    if body.endswith(")"):
         body = body[:-1]
     out, depth, cur = [], 0, []
     for ch in body:

@@ -16,7 +16,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspa
 
 from paddlefleetx_tpu.utils.device import apply_platform_env
 
-apply_platform_env()  # PFX_PLATFORM=cpu etc., before backend init
+apply_platform_env()  # tpu unless a CPU pin is set; before backend init
 
 from paddlefleetx_tpu.core.module import build_module
 from paddlefleetx_tpu.core.serving import GenerationServer

@@ -126,3 +126,34 @@ def test_overrides_for_attn_knobs():
     assert "Model.attn_impl=ring" in ov
     assert "Distributed.sep_zigzag=True" in ov
     assert "Distributed.sep_degree=2" in ov
+
+
+def test_tune_parent_never_touches_the_backend(monkeypatch, tmp_path, capsys):
+    """One process per chip: the sweeping parent learns the device count
+    from a child and hands every candidate to a tools/train.py child — it
+    must not initialize a backend itself (it would hold the chip)."""
+    import jax
+
+    import tools.auto as auto
+
+    def boom(*a, **k):
+        raise AssertionError("the tune parent touched the jax backend")
+
+    for name in ("devices", "device_count", "local_devices", "default_backend"):
+        monkeypatch.setattr(jax, name, boom)
+    monkeypatch.setattr(auto, "_device_count", lambda: 4)
+    seen = []
+
+    def fake_candidate(config, base, cand, steps, gbs):
+        seen.append(cand)
+        return {"layout": cand, "ok": True, "ips": 1000.0 + len(seen)}
+
+    monkeypatch.setattr(auto, "run_candidate", fake_candidate)
+    auto.main([
+        "-c", os.path.join(REPO, "configs/gpt/pretrain_gpt_345M_single.yaml"),
+        "--tune", "-o", f"Engine.save_load.output_dir={tmp_path}",
+        "-o", "Global.local_batch_size=4", "-o", "Global.micro_batch_size=4",
+    ])
+    assert {"dp": 4, "mp": 1, "pp": 1} in seen and {"dp": 2, "mp": 2, "pp": 1} in seen
+    assert "on 4 devices" in capsys.readouterr().out
+    assert (tmp_path / "auto_tune_results.json").exists()

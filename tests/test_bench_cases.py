@@ -1,9 +1,9 @@
 """Unit tests for benchmarks/bench_extra.py case configs.
 
 The GPT-1.3B single-chip fit hangs on three exact knobs
-(multi_precision=False, main_grad=False, bf16 first moment — see
-BENCH_NOTE.md round 4); a silent default regression would OOM the next
-chip window instead of benchmarking.  Lock the layered config frames.
+(multi_precision=False, main_grad=False, bf16 first moment: fp32 masters
++ two fp32 moments alone are ~15.6 GB of a 16 GB chip); a silent default
+regression would OOM the next chip run instead of benchmarking.  Lock the layered config frames.
 """
 
 import os

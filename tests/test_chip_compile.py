@@ -1,0 +1,399 @@
+"""Ask the TPU's compiler, without the chip.
+
+Every kernel and program on a default path is COMPILED here for a
+described ``v5e:2x2`` topology (shapes only; nothing runs), at the real
+GPT-345M (16 heads x 64) and GPT-1.3B (16 x 128) head sizes in bf16.
+Interpret mode — what every other kernel test in this suite runs — cannot
+see what Mosaic refuses: a dynamic slice it cannot prove aligned, a block
+that breaks the (8, 128) tiling rule, a kernel GSPMD cannot partition, a
+step that does not fit 16 GB.  A compile that passes is not a chip run
+(``chip_smoke.py`` is), but one that fails here would have failed there.
+
+``pallas_interpret`` is steered from the test (the code under test still
+sees the CPU backend); the persistent compile cache is off around these
+compiles (a TPU executable cannot be read back without a chip).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from paddlefleetx_tpu.utils import device as device_mod
+
+HEAD_DIMS = [
+    pytest.param(64, id="345M"),
+    pytest.param(128, id="1.3B"),
+]
+HEADS = 16
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu / unknown topology on this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc!r}")
+
+
+@pytest.fixture(autouse=True)
+def _compile_for_chip(monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(device_mod, "pallas_interpret", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(sharding, tree):
+    """ShapeDtypeStructs for a pytree of (shape, dtype) leaves / arrays /
+    ShapeDtypeStructs, all placed with ``sharding``."""
+    def leaf(x):
+        if isinstance(x, tuple):
+            return jax.ShapeDtypeStruct(x[0], x[1], sharding=sharding)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    return jax.tree.map(
+        leaf, tree,
+        is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
+        and isinstance(x[0], tuple),
+    )
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# Training kernels: flash fwd + both bwd schedules, fused LayerNorm
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("d", HEAD_DIMS)
+@pytest.mark.parametrize("bwd", ["split", "fused"])
+def test_flash_fwd_bwd_compiles(topo, d, bwd):
+    from paddlefleetx_tpu.ops.flash_attention import flash_attention
+
+    q = _shapes(_one_chip(topo), ((2, 1024, HEADS, d), BF16))
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, block=512, bwd_schedule=bwd)
+        return jnp.sum(out.astype(jnp.float32))
+
+    c = _compile(jax.grad(loss, (0, 1, 2)), q, q, q)
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("hidden", [pytest.param(1024, id="345M"),
+                                    pytest.param(2048, id="1.3B")])
+def test_fused_layernorm_fwd_bwd_compiles(topo, hidden):
+    from paddlefleetx_tpu.ops.fused_layernorm import fused_layer_norm
+
+    one = _one_chip(topo)
+    x = _shapes(one, ((4, 1024, hidden), BF16))
+    w = _shapes(one, ((hidden,), jnp.float32))
+
+    def loss(x, res, scale, bias):
+        return jnp.sum(fused_layer_norm(x, scale, bias, residual=res)
+                       .astype(jnp.float32))
+
+    c = _compile(jax.grad(loss, (0, 1, 2, 3)), x, x, w, w)
+    assert _has_kernel(c)
+
+
+# ---------------------------------------------------------------------------
+# Serving kernels: contiguous decode (t=1, spec chunk, prefill-sized t),
+# paged decode (t=1, spec chunk; the block sizes serve.py documents),
+# both int8 spellings
+# ---------------------------------------------------------------------------
+
+# an allocation init_cache would make for prompt 960 + 64 new + draft_k 4:
+# a multiple of 8 that is NOT a multiple of the 256 block (clamped tail)
+CACHE_LEN = 1032
+CACHE_LEN_INT8 = 1152
+
+
+@pytest.mark.parametrize("d", HEAD_DIMS)
+@pytest.mark.parametrize("t", [1, 5, 512])
+def test_contiguous_decode_compiles(topo, d, t):
+    from paddlefleetx_tpu.ops.decode_attention import decode_attention
+
+    one = _one_chip(topo)
+    q = _shapes(one, ((2, t, HEADS, d), BF16))
+    kv = _shapes(one, ((2, HEADS, CACHE_LEN, d), BF16))
+    pos = _shapes(one, ((), jnp.int32))
+    vf = _shapes(one, ((2,), jnp.int32))
+    c = _compile(
+        lambda q, k, v, pos, vf: decode_attention(q, k, v, pos, kv_valid_from=vf),
+        q, kv, kv, pos, vf,
+    )
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("d", HEAD_DIMS)
+@pytest.mark.parametrize("t", [1, 5])
+def test_contiguous_decode_int8_compiles(topo, d, t):
+    from paddlefleetx_tpu.ops.decode_attention import decode_attention
+
+    one = _one_chip(topo)
+    q = _shapes(one, ((2, t, HEADS, d), BF16))
+    kv = _shapes(one, ((2, HEADS, CACHE_LEN_INT8, d), jnp.int8))
+    sc = _shapes(one, ((2, HEADS, CACHE_LEN_INT8), jnp.float32))
+    pos = _shapes(one, ((), jnp.int32))
+    c = _compile(
+        lambda q, k, v, pos, ks, vs: decode_attention(
+            q, k, v, pos, k_scale=ks, v_scale=vs),
+        q, kv, kv, pos, sc, sc,
+    )
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("d", HEAD_DIMS)
+@pytest.mark.parametrize("t", [1, 5])
+@pytest.mark.parametrize("bs", [16, 32, 128])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_compiles(topo, d, t, bs, kv_dtype):
+    from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
+
+    one = _one_chip(topo)
+    nb, rows = 512, 8
+    quant = kv_dtype == "int8"
+    q = _shapes(one, ((rows, t, HEADS, d), BF16))
+    pool = _shapes(one, ((nb, HEADS, bs, d), jnp.int8 if quant else BF16))
+    tables = _shapes(one, ((rows, 1024 // bs), jnp.int32))
+    positions = _shapes(one, ((rows,), jnp.int32))
+    if quant:
+        sc = _shapes(one, ((nb, HEADS, bs), jnp.float32))
+        c = _compile(
+            lambda q, k, v, tb, ps, ks, vs: paged_decode_attention(
+                q, k, v, tb, ps, k_scale=ks, v_scale=vs),
+            q, pool, pool, tables, positions, sc, sc,
+        )
+    else:
+        c = _compile(paged_decode_attention, q, pool, pool, tables, positions)
+    assert _has_kernel(c)
+
+
+def test_misaligned_cache_is_refused_not_rerouted(topo):
+    """On a TPU ``impl="auto"`` is the kernel or an error — never lax."""
+    from paddlefleetx_tpu.ops.decode_attention import decode_attention
+
+    one = _one_chip(topo)
+    q = _shapes(one, ((2, 1, HEADS, 64), BF16))
+    kv = _shapes(one, ((2, HEADS, 1028, 64), BF16))
+    pos = _shapes(one, ((), jnp.int32))
+    with pytest.raises(ValueError, match="multiples of 8"):
+        _compile(decode_attention, q, kv, kv, pos)
+
+
+# ---------------------------------------------------------------------------
+# Whole serving programs at GPT-345M width and depth
+# ---------------------------------------------------------------------------
+
+
+def _gpt345m():
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    return GPTConfig(
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0, dtype="bfloat16"
+    )  # the dataclass defaults ARE 345M: 24 x 1024, 16 heads, vocab 50304
+
+
+def _param_shapes(cfg, sharding):
+    from paddlefleetx_tpu.models.gpt import model as gpt
+
+    return _shapes(
+        sharding, jax.eval_shape(lambda k: gpt.init(cfg, k), jax.random.key(0))
+    )
+
+
+def test_generate_345m_compiles(topo):
+    """``generate()`` — the coalesce scheduler, GenerationServer and
+    tools/inference.py all end here: prefill (t = bucket) and decode
+    (t = 1) through the contiguous kernel."""
+    from paddlefleetx_tpu.models.gpt.generation import GenerationConfig, generate
+
+    cfg = _gpt345m()
+    one = _one_chip(topo)
+    params = _param_shapes(cfg, one)
+    gen = GenerationConfig(max_dec_len=64, decode_strategy="greedy_search")
+    ids = _shapes(one, ((2, 128), jnp.int32))
+    lens = _shapes(one, ((2,), jnp.int32))
+    c = _compile(
+        lambda p, ids, lens: generate(p, ids, cfg, gen, prompt_lens=lens),
+        params, ids, lens,
+    )
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_prefill_and_step_345m_compile(topo, kv_dtype):
+    """The continuous scheduler's two programs: prefill-on-admit
+    (``paged_prefill``) and the decode step (``paged_forward_step``), at
+    the default PFX_KV_BLOCK of 16."""
+    from paddlefleetx_tpu.models.gpt.generation import (
+        init_paged_pools,
+        paged_forward_step,
+        paged_prefill,
+    )
+
+    cfg = _gpt345m()
+    one = _one_chip(topo)
+    params = _param_shapes(cfg, one)
+    nb, bs, rows, P_ = 256, 16, 8, 128
+    pools = _shapes(one, jax.eval_shape(
+        lambda: init_paged_pools(cfg, nb, bs, kv_dtype=kv_dtype)))
+
+    prompt = _shapes(one, ((1, P_), jnp.int32))
+    plen = _shapes(one, ((), jnp.int32))
+    row = _shapes(one, ((P_ // bs,), jnp.int32))
+    c = _compile(
+        lambda p, prompt, plen, pools, row: paged_prefill(
+            p, prompt, plen, pools, row, cfg),
+        params, prompt, plen, pools, row,
+    )
+    assert _has_kernel(c)
+
+    toks = _shapes(one, ((rows,), jnp.int32))
+    tables = _shapes(one, ((rows, 1024 // bs), jnp.int32))
+    positions = _shapes(one, ((rows,), jnp.int32))
+    active = _shapes(one, ((rows,), jnp.bool_))
+    c = _compile(
+        lambda p, toks, pools, tb, ps, act: paged_forward_step(
+            p, toks, pools, tb, ps, act, cfg),
+        params, toks, pools, tables, positions, active,
+    )
+    assert _has_kernel(c)
+
+
+# ---------------------------------------------------------------------------
+# Kernels under a mesh: Mosaic kernels cannot be partitioned by GSPMD, so
+# under a ShardingCtx they run inside shard_map (parallel/sharding.shard_kernel)
+# ---------------------------------------------------------------------------
+
+
+def _mesh_ctx(topo, **degrees):
+    from paddlefleetx_tpu.models.gpt.model import ShardingCtx
+    from paddlefleetx_tpu.parallel.mesh import MeshConfig, build_mesh
+    from paddlefleetx_tpu.parallel.sharding import make_rules
+
+    mesh = build_mesh(MeshConfig(**degrees), topo.devices)
+    return mesh, ShardingCtx(mesh, make_rules(mesh=mesh))
+
+
+@pytest.mark.parametrize("degrees", [
+    pytest.param({"dp_degree": 2, "mp_degree": 2}, id="dp2mp2"),
+    pytest.param({"dp_degree": 4}, id="dp4"),
+])
+def test_sharded_flash_and_fused_ln_compile(topo, degrees):
+    """One sharded flash call (+ fused LN) on the 2x2 mesh: fwd + bwd with
+    batch over ``data`` and heads over ``model``.  Bare, the compiler says
+    "Mosaic kernels cannot be automatically partitioned"."""
+    from paddlefleetx_tpu.models.gpt.model import layer_norm
+    from paddlefleetx_tpu.ops.attention import attention
+
+    mesh, ctx = _mesh_ctx(topo, **degrees)
+    act = NamedSharding(mesh, P(("data", "fsdp"), None, None))
+    x = _shapes(act, ((8, 1024, 1024), BF16))
+    w_qkv = _shapes(NamedSharding(mesh, P(None, None, "model", None)),
+                    ((1024, 3, HEADS, 64), BF16))
+    w_ln = _shapes(NamedSharding(mesh, P()), ((1024,), jnp.float32))
+
+    def loss(x, w_qkv, scale, bias):
+        y = layer_norm(x, scale, bias, fused=True, ctx=ctx)
+        qkv = jnp.einsum("bsh,htnd->bstnd", y, w_qkv)
+        out = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                        impl="flash", flash_block=512, flash_bwd="fused", ctx=ctx)
+        return jnp.sum(out.astype(jnp.float32))
+
+    c = _compile(jax.grad(loss, (0, 1, 2, 3)), x, w_qkv, w_ln, w_ln)
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    # q/k/v reach the kernel as they were computed (batch- and heads-
+    # sharded): nothing gathers them in front of it
+    assert "all-gather" not in text
+
+
+# ---------------------------------------------------------------------------
+# Whole train steps of the documented configs (Engine.abstract_init: no
+# state is materialized, the jitted step is lowered on described devices)
+# ---------------------------------------------------------------------------
+
+_SINGLE_YAML = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "configs", "gpt", "pretrain_gpt_345M_single.yaml",
+)
+
+
+def _compile_train_step(topo, n_devices, overrides=()):
+    from paddlefleetx_tpu.core.engine import Engine
+    from paddlefleetx_tpu.core.module import build_module
+    from paddlefleetx_tpu.parallel.env import init_dist_env
+    from paddlefleetx_tpu.utils.config import get_config
+
+    cfg = get_config(_SINGLE_YAML, overrides=list(overrides), num_devices=n_devices)
+    mesh = init_dist_env(cfg, devices=topo.devices[:n_devices])
+    with mesh:
+        engine = Engine(cfg, build_module(cfg), mesh, abstract_init=True)
+        b = int(cfg.Global.global_batch_size)
+        s = int(cfg.Model.max_position_embeddings)
+        batch = {
+            name: jax.ShapeDtypeStruct((b, s), dt, sharding=engine.batch_spec)
+            for name, dt in (("tokens", np.int64), ("labels", np.int64),
+                             ("loss_mask", np.float32), ("position_ids", np.int64))
+        }
+        return engine._train_step.lower(engine.state, batch).compile()
+
+
+def test_documented_single_chip_config_fits_one_chip(topo):
+    """README's first command — ``tools/train.py -c
+    configs/gpt/pretrain_gpt_345M_single.yaml`` AS COMMITTED (batch 16,
+    flash attention) — compiles for one 16 GB chip; a step that does not
+    fit is refused by the compiler with RESOURCE_EXHAUSTED."""
+    c = _compile_train_step(topo, 1)
+    assert _has_kernel(c)
+
+
+@pytest.mark.slow  # 20-35 s of TPU compile each; run when a layout changes
+@pytest.mark.parametrize("overrides", [
+    pytest.param(("Distributed.dp_degree=4", 4, 4), id="dp4"),
+    pytest.param(("Distributed.dp_degree=2", "Distributed.mp_degree=2", 8, 8),
+                 id="dp2mp2"),
+    pytest.param(("Distributed.dp_degree=2", "Distributed.mp_degree=2",
+                  "Distributed.sequence_parallel=True",
+                  "Model.sequence_parallel=True", 8, 8), id="dp2mp2sp"),
+    pytest.param(("Distributed.sharding.sharding_degree=4",
+                  "Distributed.sharding.sharding_stage=3", 4, 4), id="zero3x4"),
+    pytest.param(("Distributed.dp_degree=2", "Distributed.pp_degree=2", 8, 2),
+                 id="dp2pp2"),
+])
+def test_four_chip_train_step_compiles_with_kernel(topo, overrides):
+    """The single-chip config's global batch of 16 spread over four chips:
+    ``overrides`` ends with the (local, micro) batch sizes of the layout."""
+    *overrides, local, micro = overrides
+    overrides += [f"Global.local_batch_size={local}",
+                  f"Global.micro_batch_size={micro}"]
+    c = _compile_train_step(topo, 4, overrides)
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text

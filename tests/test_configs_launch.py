@@ -314,11 +314,20 @@ def test_file_utils_roundtrip(tmp_path):
     assert rows == [{"k": "x", "v": "1"}, {"k": "y", "v": "2"}]
 
 
-def test_check_version_passes_here():
-    from paddlefleetx_tpu.utils.check import check_device, check_version
+def test_device_identity_names_what_jax_found():
+    """The three fields both entry points log at start and /healthz
+    carries — as JAX reports them, never as the config wishes."""
+    import jax
 
-    check_version()
-    check_device("cpu")
+    from paddlefleetx_tpu.utils.device import device_identity
+
+    ident = device_identity()
+    assert ident == {
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
+    }
+    assert ident["platform"] == "cpu"  # this suite is pinned to the CPU
 
 
 @pytest.mark.slow

@@ -86,16 +86,36 @@ def test_blocked_matches_dense(impl, pos, t):
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("impl", ["lax", PALLAS])
-def test_unaligned_cache_length_parity(impl):
-    """max_len 20 with the default block: decode_block rounds the clamp
-    down to 16 and the clamped-start last block covers the 4-slot tail —
-    parity must hold (the Mosaic-unaligned-block regression case)."""
+@pytest.mark.parametrize("impl,max_len", [("lax", 20), pytest.param(
+    "pallas", 24, marks=pytest.mark.slow)])
+def test_unaligned_cache_length_parity(impl, max_len):
+    """A cache the block does not divide: decode_block rounds the clamp
+    down to 16 and the clamped-start last block covers the tail — parity
+    must hold.  The lax spelling takes any length (20); the pallas one
+    takes what init_cache allocates (kv_cache_len: 24)."""
     rng = np.random.default_rng(5)
-    q, kc, vc = _rand_case(rng, 2, 1, 4, 16, 20)
-    ref = dense_cache_attention(q, kc, vc, jnp.int32(19))
-    got = decode_attention(q, kc, vc, jnp.int32(19), impl=impl)
+    q, kc, vc = _rand_case(rng, 2, 1, 4, 16, max_len)
+    pos = jnp.int32(max_len - 1)
+    ref = dense_cache_attention(q, kc, vc, pos)
+    got = decode_attention(q, kc, vc, pos, impl=impl)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+def test_pallas_spelling_refuses_a_misaligned_cache():
+    """The kernel's block loads carry an alignment hint Mosaic trusts; a
+    cache length that would make it false is an error in the pallas
+    spelling (explicit, or "auto" on a TPU) — never a silent lax reroute.
+    init_cache allocates kv_cache_len slots, which always passes."""
+    from paddlefleetx_tpu.ops.decode_attention import kv_cache_len
+
+    rng = np.random.default_rng(6)
+    q, kc, vc = _rand_case(rng, 1, 1, 2, 8, 20)
+    with pytest.raises(ValueError, match="multiples of 8"):
+        decode_attention(q, kc, vc, jnp.int32(3), impl="pallas")
+    assert kv_cache_len(20) == 24 and kv_cache_len(24) == 24
+    assert kv_cache_len(20, quantized=True) == 128
+    assert init_cache(TINY, 2, 20).k.shape[3] == 24
+    assert init_cache(TINY, 2, 20, kv_dtype="int8").k_scale.shape[3] == 128
 
 
 @pytest.mark.parametrize("impl", ["lax", PALLAS])

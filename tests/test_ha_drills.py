@@ -381,8 +381,7 @@ def test_journal_deleted_heartbeats_rebuild_registry(tmp_path):
              "--replica-id", f"hb-{port}",
              "--warmup-buckets", "4", "--warmup-batches", "1",
              "--deadline", "60",
-             "--router-url", f"http://127.0.0.1:{rport}",
-             "--compile-cache-dir", CACHE_DIR],
+             "--router-url", f"http://127.0.0.1:{rport}"],
             env=_env({"PFX_REGISTER_INTERVAL_S": "0.5"}), cwd=REPO,
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         )

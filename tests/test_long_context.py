@@ -276,13 +276,10 @@ def test_pipeline_sep_ring_1f1b_grads_match(devices8):
     """1F1B pipeline COMPOSED with nested ring attention (pp2 x sep2 x
     dp2): loss AND per-parameter grads match the single-device reference.
 
-    Regression for the 0.4.x nested-manual backward (code review of the
-    shard_map-port PR): the naive all_gather/slice seams left gradients
-    sep-rank-varying (own block doubled, other blocks zero — worst rel
-    err ~1.2e3) while the LOSS was exact, so a loss-only assertion
-    (zigzag_pp_worker's) passed.  The frame-seam custom VJPs in
-    ring_attention (_enter_replicated / _gather_replicated) are what this
-    test pins — it must assert GRADS, not just loss."""
+    A nested map can get the LOSS exactly right while its parameter grads
+    are sep-rank-varying (a seam that counts a block's cotangent twice, or
+    not at all), so a loss-only assertion (zigzag_pp_worker's) is not
+    enough — this test must assert GRADS, not just loss."""
     from paddlefleetx_tpu.parallel.pipeline import PipelineConfig
 
     # 2 layers = 1 per stage: the smallest shape that runs both stages'

@@ -212,3 +212,33 @@ def test_indivisible_layers_raises(devices8):
     with pytest.raises(ValueError, match="not divisible"):
         with mesh:
             gpt.loss_fn(params, batch, cfg, ctx=ctx, train=False)
+
+
+def test_1f1b_dead_events_cannot_poison_grads_at_depth(devices8):
+    """Dead schedule events pull a real cotangent back through a stage fed
+    all-zero activations.  LayerNorm's backward at zero variance scales by
+    rsqrt(eps) ~ 316 per layer; a dozen layers deep that overflows, and an
+    accumulator that MULTIPLIES the dead event out turns inf into NaN
+    (0 * inf) while the loss stays exact.  Found on the chip at GPT-345M
+    dp2·pp2 — NaN grads in the first four layers of the second stage — and
+    reproduced here by depth (12 layers per stage) plus a hot init; the
+    accumulators now select (``pipeline._masked``)."""
+    cfg = GPTConfig(
+        vocab_size=128, hidden_size=64, num_layers=24, num_attention_heads=4,
+        max_position_embeddings=32, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0, dtype="bfloat16", attn_impl="xla",
+        initializer_range=0.1,
+    )
+    params = gpt.init(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (8, 32), 0, cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, 1),
+             "loss_mask": jnp.ones((8, 32), jnp.float32)}
+    mesh, _, ctx = _ctx(devices8, pp=2, microbatches=2)
+    with mesh:
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, batch, cfg, ctx=ctx, train=True)))(params)
+    assert np.isfinite(float(loss))
+    bad = [jax.tree_util.keystr(k)
+           for k, g in jax.tree_util.tree_leaves_with_path(grads)
+           if not bool(jnp.isfinite(g).all())]
+    assert not bad, bad

@@ -1,11 +1,9 @@
-"""Unit tests for the version-split shard_map adapter (shard_map_compat).
+"""Unit tests for the shard_map adapter (shard_map_compat).
 
-These pin the 0.4.x full-manual branch so a future jax bump cannot
-silently break either routing: the adapter must (a) run manual bodies
-whose collectives match the equivalent pjit/GSPMD computation, (b) expose
-the manual axis set to in-body code via the thread-local, and (c) strip
-manual axes from logical sharding constraints instead of tripping the
-0.4.x "axis also found in manual_axes" error."""
+The adapter must (a) run manual bodies whose collectives match the
+equivalent pjit/GSPMD computation, (b) expose the manual axis set to
+in-body code via the thread-local, and (c) strip manual axes from logical
+sharding constraints inside a mapped region."""
 
 import jax
 import jax.numpy as jnp
@@ -28,22 +26,24 @@ def _mesh(devices8, **kw):
     return build_mesh(MeshConfig(**kw), devices8)
 
 
-def test_branch_detection_matches_installed_jax():
-    """The adapter and the conftest gate must agree on which jax this is."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        assert not smc.HAS_JAX09_SHARD_MAP
-    else:
-        import inspect
+def test_adapter_is_partially_manual(devices8):
+    """One lowering: ``jax.shard_map`` manual over exactly the requested
+    axes — every other mesh axis stays GSPMD-auto inside the body, so a
+    constraint naming one is legal there."""
+    mesh = _mesh(devices8, pp_degree=2, dp_degree=4)
 
-        assert smc.HAS_JAX09_SHARD_MAP == (
-            "check_vma" in inspect.signature(fn).parameters
-        )
+    def body(x):
+        return jax.lax.with_sharding_constraint(x * 2.0, P(None, AXIS_DATA))
+
+    f = smc.shard_map(body, mesh, P(AXIS_STAGES), P(AXIS_STAGES), {AXIS_STAGES})
+    with mesh:
+        got = jax.jit(f)(jnp.arange(16.0).reshape(2, 8))
+    np.testing.assert_allclose(np.asarray(got), 2.0 * np.arange(16.0).reshape(2, 8))
 
 
 def test_manual_axes_thread_local_scoping(devices8):
-    """current_manual_axes(): empty outside, the body's set inside (all
-    mesh axes on the 0.4.x full-manual branch), restored after."""
+    """current_manual_axes(): empty outside, the body's set inside,
+    restored after."""
     mesh = _mesh(devices8, pp_degree=2, dp_degree=4)
     seen = {}
 
@@ -55,10 +55,7 @@ def test_manual_axes_thread_local_scoping(devices8):
     f = smc.shard_map(body, mesh, P(AXIS_STAGES), P(AXIS_STAGES), {AXIS_STAGES})
     with mesh:
         jax.jit(f)(jnp.arange(8.0).reshape(2, 4))
-    if smc.HAS_JAX09_SHARD_MAP:
-        assert seen["inside"] == frozenset({AXIS_STAGES})
-    else:
-        assert seen["inside"] == frozenset(mesh.axis_names)
+    assert seen["inside"] == frozenset({AXIS_STAGES})
     assert smc.current_manual_axes() == frozenset()
 
 
@@ -110,40 +107,9 @@ def test_grad_through_manual_body_matches_pjit(devices8):
     np.testing.assert_allclose(np.asarray(got_g), np.asarray(ref_g), rtol=1e-6)
 
 
-@pytest.mark.skipif(
-    smc.HAS_JAX09_SHARD_MAP, reason="full_specs is a 0.4.x-branch feature"
-)
-def test_full_specs_keep_extra_axes_sharded(devices8):
-    """On the full-manual branch, full_specs may shard axes the body is
-    elementwise-independent over; numerics must be unchanged and the
-    output must land sharded along them."""
-    mesh = _mesh(devices8, sep_degree=2, dp_degree=4)
-    x = jnp.arange(8.0 * 6).reshape(8, 6)
-
-    def body(xs):
-        y = jax.lax.ppermute(xs, AXIS_SEP, [(i, (i + 1) % 2) for i in range(2)])
-        return y + xs
-
-    base = smc.shard_map(body, mesh, P(None, AXIS_SEP), P(None, AXIS_SEP), {AXIS_SEP})
-    rich = smc.shard_map(
-        body,
-        mesh,
-        P(None, AXIS_SEP),
-        P(None, AXIS_SEP),
-        {AXIS_SEP},
-        full_specs=(P(AXIS_DATA, AXIS_SEP), P(AXIS_DATA, AXIS_SEP)),
-    )
-    with mesh:
-        a = jax.jit(base)(x)
-        b = jax.jit(rich)(x)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
-    assert AXIS_DATA in str(b.sharding.spec)
-
-
 def test_logical_constraint_stripped_inside_manual_region(devices8):
     """with_logical_constraint inside a manual body must not name manual
-    axes (0.4.x rejects them); the constraint is stripped/no-op'd and the
-    values flow through unchanged."""
+    axes; they are stripped and the values flow through unchanged."""
     from paddlefleetx_tpu.parallel.sharding import make_rules, with_logical_constraint
 
     mesh = _mesh(devices8, pp_degree=2, mp_degree=2, dp_degree=2)
@@ -198,3 +164,77 @@ def test_pytree_specs_and_multiple_outputs(devices8):
         float(jnp.sum(x + params["w"]) + 2 * jnp.sum(params["b"])),
         rtol=1e-6,
     )
+
+
+# ---------------------------------------------------------------------------
+# shard_kernel: Pallas kernels under a mesh (parallel/sharding.shard_kernel)
+# ---------------------------------------------------------------------------
+
+
+def _kernel_ctx(mesh):
+    from paddlefleetx_tpu.models.gpt.model import ShardingCtx
+    from paddlefleetx_tpu.parallel.sharding import make_rules
+
+    return ShardingCtx(mesh, make_rules(mesh=mesh))
+
+
+@pytest.mark.parametrize("degrees", [
+    pytest.param({"dp_degree": 2, "mp_degree": 2, "sep_degree": 2}, id="dp2mp2sep2"),
+    pytest.param({"sharding_degree": 4, "mp_degree": 2}, id="fsdp4mp2"),
+])
+def test_shard_kernel_matches_unsharded(devices8, degrees):
+    """Flash attention + fused LayerNorm through ``shard_kernel``: values
+    AND grads equal the bare kernels' — incl. the LayerNorm scale/bias
+    cotangents, which every shard contributes a partial sum to."""
+    from paddlefleetx_tpu.models.gpt.model import layer_norm
+    from paddlefleetx_tpu.ops.attention import attention
+
+    mesh = _mesh(devices8, **degrees)
+    ctx = _kernel_ctx(mesh)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(4, 64, 32)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(32, 3, 4, 8)) * 0.2, jnp.float32)
+    scale = jnp.asarray(rng.normal(size=(32,)) + 1.0, jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(32,)), jnp.float32)
+
+    def loss(x, w, scale, bias, ctx):
+        y = layer_norm(x, scale, bias, fused=True, ctx=ctx)
+        qkv = jnp.einsum("bsh,htnd->bstnd", y, w)
+        out = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                        impl="flash", ctx=ctx)
+        return jnp.sum(jnp.sin(out))
+
+    grad = jax.value_and_grad(loss, (0, 1, 2, 3))
+    ref_l, ref_g = jax.jit(lambda *a: grad(*a, None))(x, w, scale, bias)
+    with mesh:
+        got_l, got_g = jax.jit(lambda *a: grad(*a, ctx))(x, w, scale, bias)
+    np.testing.assert_allclose(float(got_l), float(ref_l), rtol=1e-5)
+    for g, r in zip(got_g, ref_g):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-3, atol=1e-3)
+
+
+def test_shard_kernel_skips_axes_that_do_not_divide(devices8):
+    """A dim the mesh axes do not divide stays whole (replicated compute)
+    instead of failing shard_map's exact-split check; a one-device mesh
+    runs the kernel bare."""
+    from paddlefleetx_tpu.parallel.sharding import make_rules, shard_kernel
+
+    mesh = _mesh(devices8, dp_degree=4, mp_degree=2)
+    seen = {}
+
+    def kernel(x):
+        seen["shape"] = x.shape
+        return x * 2.0
+
+    x = jnp.arange(6.0 * 4).reshape(6, 4)  # batch 6: data=4 does not divide
+    f = shard_kernel(kernel, mesh, make_rules(mesh=mesh),
+                     (("batch", "mlp"),), ("batch", "mlp"))
+    with mesh:
+        got = jax.jit(f)(x)
+    assert seen["shape"] == (6, 2)  # mlp split over model=2, batch whole
+    np.testing.assert_allclose(np.asarray(got), 2.0 * np.asarray(x))
+
+    one = build_mesh(MeshConfig(), devices8[:1])
+    assert shard_kernel(kernel, one, make_rules(), (("batch", "mlp"),),
+                        ("batch", "mlp"))(x).shape == (6, 4)
+    assert seen["shape"] == (6, 4)

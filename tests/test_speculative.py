@@ -418,7 +418,9 @@ def test_int8_attention_matches_native_within_tolerance():
     )
 
     rng = np.random.default_rng(0)
-    b, n, d, L = 2, 2, 8, 32
+    # L: what init_cache allocates for an int8 cache (kv_cache_len rounds to
+    # the 128 lanes the kernel slices its scale rows along)
+    b, n, d, L = 2, 2, 8, 128
     q = jnp.asarray(rng.normal(size=(b, 3, n, d)).astype(np.float32))
     kc = jnp.asarray(rng.normal(size=(b, n, L, d)).astype(np.float32))
     vc = jnp.asarray(rng.normal(size=(b, n, L, d)).astype(np.float32))

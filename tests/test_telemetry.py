@@ -544,8 +544,10 @@ def test_engine_step_records_carry_phases_compile_and_mfu(tmp_path, devices8):
     # mfu = tokens/s * flops/tok / (peak * devices), vs the same estimator
     per_tok = T.model_flops_per_token(module.config)
     peak = T.peak_flops()
+    # the record rounds mfu to 6 places: half a unit in the last place is
+    # part of the tolerance (at this toy size it can exceed rel=1e-3)
     assert first["mfu"] == pytest.approx(
-        first["ips"] * per_tok / (peak * mesh.size), rel=1e-3
+        first["ips"] * per_tok / (peak * mesh.size), rel=1e-3, abs=5.1e-7
     )
     assert first["host_s"] >= 0 and first["data_wait_s"] >= 0
     # the registry mirrors the logged values

@@ -24,19 +24,9 @@ def main() -> None:
 
     jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    # jax 0.9: the nested-map executable failed the persistent-cache
-    # serialization round-trip (warm rerun SIGABRT), so the cache was
-    # disabled here.  The 0.4.x full-manual lowering (shard_map_compat) is
-    # a different executable that round-trips fine — verified by repeated
-    # warm runs — and cache hits cut this worker from ~59s to ~28s of the
-    # tier-1 budget; keep the cache off only on the jax-0.9 branch.  ONE
-    # detection for the version split: the adapter's flag (its import only
-    # inspects jax.shard_map's signature — no backend/config state touched,
-    # so it is safe after the jax.config lines above).
-    from paddlefleetx_tpu.parallel.shard_map_compat import HAS_JAX09_SHARD_MAP
-
-    if HAS_JAX09_SHARD_MAP:
-        jax.config.update("jax_enable_compilation_cache", False)
+    # the nested-map executable fails the persistent-cache serialization
+    # round-trip (a warm rerun SIGABRTs), so the cache stays off here
+    jax.config.update("jax_enable_compilation_cache", False)
 
     import dataclasses
 

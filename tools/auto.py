@@ -16,6 +16,8 @@ Usage:
 
 The sweep runs each candidate as a tools/train.py subprocess (fresh XLA
 per layout) and writes auto_tune_results.json next to the config output.
+The sweeping parent never initializes a jax backend: a chip belongs to one
+process at a time, and a parent holding it would starve every candidate.
 """
 
 import argparse
@@ -29,7 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from paddlefleetx_tpu.utils.device import apply_platform_env
 
-apply_platform_env()  # PFX_PLATFORM=cpu etc., before backend init
+apply_platform_env()  # tpu unless a CPU pin is set; before backend init
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 IPS_RE = re.compile(r"ips: ([\d,]+) tokens/s")
@@ -166,6 +168,21 @@ def run_candidate(config: str, base_overrides: list, cand: dict, tune_steps: int
         return {"layout": cand, "ok": False, "ips": None}
 
 
+def _device_count() -> int:
+    """The device count as a short-lived CHILD sees it (same platform
+    resolution as every entry point); it has exited — and let go of the
+    chip — before the first candidate starts."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from paddlefleetx_tpu.utils.device import apply_platform_env; "
+         "apply_platform_env(); import jax; print(jax.device_count())"],
+        capture_output=True, text=True, cwd=ROOT, timeout=300,
+    )
+    if out.returncode != 0:
+        sys.exit(f"cannot count devices:\n{out.stderr[-2000:]}")
+    return int(out.stdout.split()[-1])
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("-c", "--config", required=True)
@@ -182,10 +199,8 @@ def main(argv=None):
 
     from paddlefleetx_tpu.utils.config import get_config
 
-    cfg = get_config(args.config, overrides=args.override)
-    import jax
-
-    n = jax.device_count()
+    n = _device_count()
+    cfg = get_config(args.config, overrides=args.override, num_devices=n)
     cands = cfg.get("Tuning", {}).get("candidates") or enumerate_layouts(n)
     gbs = int(cfg.Global.global_batch_size)
     print(f"tuning over {len(cands)} layouts on {n} devices (steps={args.tune_steps})")

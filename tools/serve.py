@@ -61,9 +61,9 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddlefleetx_tpu.utils.device import apply_platform_env
+from paddlefleetx_tpu.utils.device import apply_platform_env, device_identity
 
-apply_platform_env()  # PFX_PLATFORM=cpu etc., before backend init
+apply_platform_env()  # tpu unless a CPU pin is set; before backend init
 
 
 def build_server(config: str, overrides):
@@ -431,6 +431,9 @@ def serve_http(server, port: int, host: str = "127.0.0.1", *,
         "pid": os.getpid(),
         "boot_id": secrets.token_hex(8),
         "started_at": round(time.time(), 3),
+        # the device JAX found (platform / device_kind / device_count):
+        # a replica that came up on the host's CPU says so here
+        **device_identity(),
     }
     # label this process's spans for cross-process exports: the fleet's
     # stitched timelines name their Perfetto lanes off this identity
@@ -790,6 +793,13 @@ def serve_http(server, port: int, host: str = "127.0.0.1", *,
                     "gen_errors": int(server.stats["gen_errors"]),
                 }
                 dbg["flags"] = dict(flags)
+                # the newest compile events (fn, aval diff, seconds): which
+                # function a post-warmup request paid a compile for
+                from paddlefleetx_tpu.utils.model_stats import (
+                    get_compile_watcher,
+                )
+
+                dbg["compile_events"] = get_compile_watcher().snapshot()[-32:]
                 dbg["trace_buffer"] = {
                     "sample": trace_buffer.sample,
                     "cap": trace_buffer.cap,
@@ -2070,13 +2080,6 @@ def main(argv=None):
                     "lost journal, and deregisters on drain exit instead "
                     "of waiting out the router's --eject-after "
                     "(docs/serving.md 'Control-plane recovery')")
-    ap.add_argument("--compile-cache-dir", default="",
-                    help="seed jax's persistent compilation cache from "
-                    "this directory (warm boot: a scale-up replica "
-                    "spawned by the elastic control plane reuses the "
-                    "fleet's compiled artifacts instead of paying a "
-                    "cold trace — docs/serving.md 'Elastic control "
-                    "plane')")
     args = ap.parse_args(argv)
     # crash-loop fault site (PFX_FAULT=boot_crash:0, docs/
     # fault_tolerance.md): a replica that can never come up — drives
@@ -2084,15 +2087,6 @@ def main(argv=None):
     from paddlefleetx_tpu.utils.resilience import maybe_fire
 
     maybe_fire("boot_crash", 0)
-    if args.compile_cache_dir:
-        import jax
-
-        # same knobs as the test harness: cache even fast compiles so a
-        # warm-booted replica's whole family set comes from disk
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(args.compile_cache_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     # spec/quant CLI flags become plain config overrides so BOTH
     # schedulers (GenerationServer + PagedDecodeEngine read the same
     # Generation.speculative section) see one source of truth

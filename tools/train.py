@@ -13,7 +13,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from paddlefleetx_tpu.utils.device import apply_platform_env
 
-apply_platform_env()  # PFX_PLATFORM=cpu etc., before backend init
+apply_platform_env()  # tpu unless a CPU pin is set; before backend init
 
 from paddlefleetx_tpu.core.engine import Engine
 from paddlefleetx_tpu.core.module import build_module
@@ -24,6 +24,8 @@ from paddlefleetx_tpu.utils.log import advertise, logger
 
 
 def main(argv=None):
+    """Run the configured fit; returns the Engine (callers that drive this
+    in-process — tools/auto.py, chip_smoke.py — inspect it afterwards)."""
     args = parse_args(argv)
     cfg = get_config(args.config, overrides=args.override)
     advertise()
@@ -139,9 +141,10 @@ def main(argv=None):
             # final checkpoint already written (preemption / exit_after_save
             # path); exit 0 so the orchestrator relaunches with auto_resume
             logger.info("clean early exit: final checkpoint saved; exiting 0")
-            return
+            return engine
         if cfg.Engine.save_load.get("save_steps"):
             engine.save()
+    return engine
 
 
 if __name__ == "__main__":
