@@ -105,7 +105,12 @@ from paddlefleetx_tpu.core.tenancy import (
     TenantLabelCap,
     normalize_tenant,
 )
-from paddlefleetx_tpu.utils.telemetry import StatsView, _env_int, get_registry
+from paddlefleetx_tpu.utils.telemetry import (
+    StatsView,
+    _env_int,
+    get_registry,
+    ledger_span,
+)
 from paddlefleetx_tpu.utils.tracing import (
     attach_request_trace,
     discard_request_trace,
@@ -336,6 +341,11 @@ class PagedDecodeEngine:
         # iteration never enters the ledger.  "ledger_admitted" counts
         # tokens COMMITTED into scheduler-owned rows — the token
         # ledger's admission side, folded by _fold_admitted())
+        # (work counters, one update per committed decode step:
+        # "row_steps"/"slot_steps" = live rows / capacity, "kv_tokens" =
+        # the live rows' context lengths, "grid_tokens" = capacity x
+        # table-width bucket x block size, the KV tokens per head the
+        # paged kernel's grid walks whatever the rows hold)
         self.stats: Dict[str, Any] = {
             "traces": 0, "steps": 0, "prefills": 0,
             "spec_proposed": 0, "spec_accepted": 0,
@@ -346,6 +356,8 @@ class PagedDecodeEngine:
             "t_device_decode": 0.0, "t_device_prefill": 0.0,
             "t_readback": 0.0, "t_stream_flush": 0.0,
             "ledger_admitted": 0,
+            "row_steps": 0, "slot_steps": 0,
+            "kv_tokens": 0, "grid_tokens": 0,
         }
         # True only inside warmup(): warmup admits/steps are not traffic
         # and must not bump the traffic-facing registry counters (the
@@ -762,7 +774,8 @@ class PagedDecodeEngine:
                 ).inc(evicted)
 
     def _dispatch_donating(self, thunk, what: str,
-                           release_seq: Optional[int] = None):
+                           release_seq: Optional[int] = None,
+                           **span_args):
         """Run one donating dispatch under the arena error contract: any
         failure means the pools may be donation-invalidated — release a
         not-yet-slotted row's allocation first (``release_seq``; a row
@@ -770,23 +783,23 @@ class PagedDecodeEngine:
         rebuild the arena, and raise :class:`ArenaReset` carrying the
         dead rows.  ONE spelling for the COW-copy / monolithic-prefill /
         chunk dispatches so the recovery contract cannot drift between
-        them."""
-        t0 = time.monotonic()
-        try:
-            with self.mesh:
-                return thunk()
-        except BaseException as exc:
-            if release_seq is not None:
-                self.cache.release(release_seq)
-            dead = self.reset()
-            raise ArenaReset(
-                f"{what} failed ({type(exc).__name__}: {exc}); arena reset",
-                dead,
-            ) from exc
-        finally:
-            # time-ledger: every donating dispatch is prefill-side
-            # device work (decode steps go through _dispatch instead)
-            self.stats["t_device_prefill"] += time.monotonic() - t0
+        them.  Time ledger: every donating dispatch is prefill-side
+        device work (decode steps go through _dispatch instead);
+        ``span_args`` ride the ``pfx.sched.prefill`` trace span."""
+        with ledger_span("pfx.sched.prefill", self.stats, "t_device_prefill",
+                         what=what, **span_args):
+            try:
+                with self.mesh:
+                    return thunk()
+            except BaseException as exc:
+                if release_seq is not None:
+                    self.cache.release(release_seq)
+                dead = self.reset()
+                raise ArenaReset(
+                    f"{what} failed ({type(exc).__name__}: {exc}); "
+                    "arena reset",
+                    dead,
+                ) from exc
 
     def admit(self, prompt_ids: Sequence[int], max_new: int,
               entry: Optional[_CBEntry] = None, row_idx: int = 0) -> int:
@@ -856,6 +869,10 @@ class PagedDecodeEngine:
                     jnp.asarray(prefill_table, jnp.int32),
                 ),
                 "prefill", release_seq=seq_id,
+                slot=slot, prompt_len=plen, bucket=P,
+                # a sampled request's spans share one id across the
+                # /debug/traces timeline and the profiler's host plane
+                **({"trace_id": trace.trace_id} if trace is not None else {}),
             )
             from paddlefleetx_tpu.models.gpt.generation import PagedPools
 
@@ -949,6 +966,7 @@ class PagedDecodeEngine:
                 jnp.int32(max(take - 1, 0)),
             ),
             label, release_seq=release_seq,
+            position=pos, prompt_len=take, bucket=chunk,
         )
         from paddlefleetx_tpu.models.gpt.generation import PagedPools
 
@@ -1535,35 +1553,35 @@ class PagedDecodeEngine:
                 0.0, time.monotonic() - self._t_results
             )
             self.stats["gap_steps"] += 1
-        t_disp = time.monotonic()
         # host-fed row state and a chained dispatch's device-side handles
         # must type alike, or each width bucket keys TWO compiles — the
         # warmed host-fed one and a chained one first paid mid-traffic
         # (place_on_mesh: ONE transfer for the host mirrors, a no-op for
         # the handles)
-        (tables, positions, gen_steps, max_news, active, forced_steps,
-         drafts) = place_on_mesh(
-            (tables, positions, gen_steps, self.max_news, active,
-             self.forced_steps, drafts), self.mesh,
-        )
-        try:
-            with self.mesh:
-                (window, ncommit, pools_t, logits, counts, positions_t,
-                 gen_steps_t, active_t, reject) = fn(
-                    self.server.params, self._pools_tuple(),
-                    tables, self._logits, self._counts,
-                    positions, gen_steps, max_news, active,
-                    forced_steps, self._reject, drafts, sub,
-                )
-        except BaseException as exc:
-            dead = self.reset()
-            raise ArenaReset(
-                f"decode step failed ({type(exc).__name__}: {exc}); "
-                "arena reset",
-                dead,
-            ) from exc
-        finally:
-            self.stats["t_device_decode"] += time.monotonic() - t_disp
+        with ledger_span("pfx.sched.decode_dispatch", self.stats,
+                         "t_device_decode", width_bucket=M,
+                         chained=overlapped):
+            (tables, positions, gen_steps, max_news, active, forced_steps,
+             drafts) = place_on_mesh(
+                (tables, positions, gen_steps, self.max_news, active,
+                 self.forced_steps, drafts), self.mesh,
+            )
+            try:
+                with self.mesh:
+                    (window, ncommit, pools_t, logits, counts, positions_t,
+                     gen_steps_t, active_t, reject) = fn(
+                        self.server.params, self._pools_tuple(),
+                        tables, self._logits, self._counts,
+                        positions, gen_steps, max_news, active,
+                        forced_steps, self._reject, drafts, sub,
+                    )
+            except BaseException as exc:
+                dead = self.reset()
+                raise ArenaReset(
+                    f"decode step failed ({type(exc).__name__}: {exc}); "
+                    "arena reset",
+                    dead,
+                ) from exc
         from paddlefleetx_tpu.models.gpt.generation import PagedPools
 
         self.pools = PagedPools(*pools_t)
@@ -1573,7 +1591,7 @@ class PagedDecodeEngine:
             "window": window, "ncommit": ncommit,
             "positions": positions_t, "gen_steps": gen_steps_t,
             "active": active_t, "rows": list(self.slots), "k": k,
-            "was_active": None,
+            "was_active": None, "width_bucket": M,
         }
 
     def flush(self) -> List[int]:
@@ -1596,26 +1614,26 @@ class PagedDecodeEngine:
         failure, and the ArenaReset carries every live row — INCLUDING
         rows admitted while the step was in flight, whose pools chained
         onto the poisoned dispatch."""
-        t_rb = time.monotonic()
         try:
-            maybe_fire("cb_commit_crash", int(self.stats["steps"]) + 1)
-            window = np.array(fl["window"])
-            ncommit = np.array(fl["ncommit"])
-            new_active = np.array(fl["active"])
-            positions = np.array(fl["positions"])
-            gen_steps = np.array(fl["gen_steps"])
+            # the span closes (and books a failed fetch) before the reset:
+            # reset/requeue cost belongs to host_sched (the iterate
+            # residual), not readback
+            with ledger_span("pfx.sched.readback", self.stats,
+                             "t_readback") as rb:
+                maybe_fire("cb_commit_crash", int(self.stats["steps"]) + 1)
+                window = np.array(fl["window"])
+                ncommit = np.array(fl["ncommit"])
+                new_active = np.array(fl["active"])
+                positions = np.array(fl["positions"])
+                gen_steps = np.array(fl["gen_steps"])
         except BaseException as exc:
-            # stamp the failed fetch before the reset: reset/requeue cost
-            # belongs to host_sched (the iterate residual), not readback
-            self.stats["t_readback"] += time.monotonic() - t_rb
             dead = self.reset()
             raise ArenaReset(
                 f"decode step failed ({type(exc).__name__}: {exc}); "
                 "arena reset",
                 dead,
             ) from exc
-        self.stats["t_readback"] += time.monotonic() - t_rb
-        self._t_results = time.monotonic()
+        self._t_results = rb.t1
         was_active = fl["was_active"]
         # merge, never overwrite: slots that joined (admit/adopt) or
         # left (release/evict) after the dispatch were not part of it —
@@ -1627,6 +1645,15 @@ class PagedDecodeEngine:
         self.stats["steps"] += 1
         finished: List[int] = []
         n_act = int(was_active.sum())
+        # work counters: what this step needed (live rows, their context)
+        # against what its fixed-shape program walked (every slot, every
+        # block of the table-width bucket)
+        self.stats["row_steps"] += n_act
+        self.stats["slot_steps"] += self.capacity
+        self.stats["kv_tokens"] += int(self.positions[was_active].sum())
+        self.stats["grid_tokens"] += (
+            self.capacity * fl["width_bucket"] * self.block
+        )
         t_chunk = time.monotonic()
         for i, r in enumerate(fl["rows"]):
             if r is None or not was_active[i]:
@@ -1648,16 +1675,17 @@ class PagedDecodeEngine:
                 # token streaming: push this step's commits as they
                 # land.  A broken sink must never kill the batch — the
                 # tokens are committed either way.
-                t_sf = time.monotonic()
-                try:
-                    r.entry.emit_stream(r.row_idx, start, r.tokens[start:])
-                except Exception as sink_exc:
-                    logger.warning(
-                        f"stream sink failed for seq {r.seq_id}: "
-                        f"{type(sink_exc).__name__}: {sink_exc}"
-                    )
-                finally:
-                    self.stats["t_stream_flush"] += time.monotonic() - t_sf
+                with ledger_span("pfx.sched.stream_flush", self.stats,
+                                 "t_stream_flush"):
+                    try:
+                        r.entry.emit_stream(
+                            r.row_idx, start, r.tokens[start:]
+                        )
+                    except Exception as sink_exc:
+                        logger.warning(
+                            f"stream sink failed for seq {r.seq_id}: "
+                            f"{type(sink_exc).__name__}: {sink_exc}"
+                        )
             if r.trace is not None:
                 # per-chunk decode timeline: one event per iteration the
                 # row decoded in, carrying its commit + spec-accept
@@ -2146,6 +2174,15 @@ class ContinuousScheduler:
             "pfx_sched_host_gap_seconds_total", {},
             round(float(eng.stats["host_gap_s"]), 6),
         ))
+        # work counted at the commit of every decode step (engine stats)
+        for key, name in (
+            ("steps", "pfx_sched_decode_steps_total"),
+            ("row_steps", "pfx_sched_decode_row_steps_total"),
+            ("slot_steps", "pfx_sched_decode_slot_steps_total"),
+            ("kv_tokens", "pfx_sched_decode_kv_tokens_total"),
+            ("grid_tokens", "pfx_sched_decode_grid_tokens_total"),
+        ):
+            out.append((name, {}, float(eng.stats[key])))
         for d, v in sorted(self._tok_ledger.items()):
             out.append((
                 "pfx_token_ledger_total", {"disposition": d}, float(v),
@@ -2584,22 +2621,26 @@ class ContinuousScheduler:
     def _has_live_rows(self) -> bool:
         return any(r is not None for r in self.engine.slots)
 
-    def _run(self) -> None:
-        while True:
-            t_wait0 = time.monotonic()
-            with self._wake:
+    def _park(self) -> bool:
+        """Wait for work; False once the queue is closed and drained.
+        Time-ledger idle: the parked wait between iterations.  _iterate
+        accounts its own duration, so idle + the iterate folds cover this
+        thread's whole wall clock."""
+        idle = ledger_span("pfx.sched.idle", self._time_ledger, "idle")
+        try:
+            with idle, self._wake:
                 while (not self._entries and not self._admin_tasks
                        and not self._has_live_rows()):
                     if self._closed:
-                        return  # drained
+                        return False  # drained
                     self._wake.wait()
-                t_busy0 = time.monotonic()
-                self._busy_since = t_busy0
-                # time-ledger idle: the parked wait between iterations.
-                # _iterate accounts its own duration, so idle + the
-                # iterate folds cover this thread's whole wall clock.
-                self._time_ledger["idle"] += t_busy0 - t_wait0
-                self._sched_wall_s += t_busy0 - t_wait0
+                self._busy_since = time.monotonic()
+            return True
+        finally:
+            self._sched_wall_s += idle.seconds
+
+    def _run(self) -> None:
+        while self._park():
             try:
                 self._iterate()
             finally:
@@ -2701,18 +2742,24 @@ class ContinuousScheduler:
         # attributed — engine per-phase deltas plus a host_sched
         # residual — and the token columns are per-iteration deltas of
         # the same dicts the registry and /debug/state export
-        t_iter0 = time.monotonic()
         tdd0 = float(eng.stats["t_device_decode"])
         tdp0 = float(eng.stats["t_device_prefill"])
         trb0 = float(eng.stats["t_readback"])
         tsf0 = float(eng.stats["t_stream_flush"])
         tok0 = dict(self._tok_ledger)
         n_finished = 0
+        # the iterate's wall span; the engine's dispatch / readback /
+        # flush spans nest inside it and its self time is host_sched
+        wall = ledger_span(
+            "pfx.sched.iterate", iter=self._iter_counter + 1,
+            active=eng.active_rows(), width_bucket=eng.table_width_bucket(),
+        )
         try:
-            n_finished = self._iterate_inner()
+            with wall:
+                n_finished = self._iterate_inner()
         finally:
             self._fold_admitted()
-            dur = time.monotonic() - t_iter0
+            dur = wall.seconds
             dd = float(eng.stats["t_device_decode"]) - tdd0
             dp = float(eng.stats["t_device_prefill"]) - tdp0
             rb = float(eng.stats["t_readback"]) - trb0

@@ -236,6 +236,13 @@ class Engine:
 
         self._registry = get_registry()
         self._recorder = get_flight_recorder()
+        # trace windows over training steps: the config block's
+        # (reference Profiler block, eager_engine.py:250-272 +
+        # profiler.step :419) or one armed at run time with
+        # ``engine.profiler.arm(log_dir, steps)`` from the fit thread
+        from paddlefleetx_tpu.utils.profiler import ProfilerHook
+
+        self.profiler = ProfilerHook(cfg.get("Profiler"))
         # retrace attribution (utils/model_stats.py): structured compile
         # events (fn, aval diff vs the previous key, elapsed) into the
         # flight ring + pfx_compile_* — installed before the first jit so
@@ -1220,12 +1227,8 @@ class Engine:
         if loader_state and hasattr(train_loader, "load_state"):
             train_loader.load_state(loader_state)
 
-        # config-gated trace window (reference Profiler block,
-        # eager_engine.py:250-272 + profiler.step :419)
-        from paddlefleetx_tpu.utils.profiler import ProfilerHook
         from paddlefleetx_tpu.utils.resilience import PreemptionGuard
 
-        profiler = ProfilerHook(self.cfg.get("Profiler"))
         self.preempted = False
         # per-fit observatory state: stats stashed for the next logging
         # fetch, the memory watermark peak, and the once-per-fit headroom
@@ -1236,13 +1239,13 @@ class Engine:
         preempt = PreemptionGuard().install()
         try:
             return self._fit_loop(
-                train_loader, eval_iter, tokens_per_sample, profiler, t_last,
+                train_loader, eval_iter, tokens_per_sample, t_last,
                 window_tokens, preempt
             )
         finally:
             preempt.uninstall()
             # flush an in-flight trace even when a step raises
-            profiler.close()
+            self.profiler.close()
             # reclaim loader machinery (prefetch thread, worker pool)
             # before returning: an abandoned daemon thread blocked on a
             # fetch is a leak the interpreter drags to shutdown
@@ -1407,10 +1410,10 @@ class Engine:
         self._dump_flight(f"preempt_save: {cause}")
         self.preempted = True
 
-    def _fit_loop(self, train_loader, eval_iter, tokens_per_sample, profiler,
+    def _fit_loop(self, train_loader, eval_iter, tokens_per_sample,
                   t_last, window_tokens, preempt=None):
         from paddlefleetx_tpu.utils import resilience
-
+        from paddlefleetx_tpu.utils.telemetry import ledger_span
         from paddlefleetx_tpu.utils.tracing import get_trace_buffer
 
         guard = self._build_anomaly_guard()
@@ -1427,7 +1430,6 @@ class Engine:
         # device_step — dispatched device compute the async-dispatch loop
         # never blocks on.  Buckets are exhaustive by construction.
         loop_t0 = time.monotonic()
-        eval_total = 0.0
         # metrics of the previous step, observed AFTER the next step has
         # been dispatched: step N-1 necessarily finished before step N
         # runs on device, so the fetch resolves while step N computes and
@@ -1435,278 +1437,312 @@ class Engine:
         prev_metrics = None
         rollbacks = 0
         # per-phase accounting (docs/observability.md): cumulative seconds
-        # this fit spent blocked on data (consumer-side next()) and on the
-        # host path (batch placement + step dispatch).  Pure monotonic
-        # host clocks — no device sync is added to the hot path.
-        data_wait_total = 0.0
-        host_total = 0.0
+        # this fit spent blocked on data (consumer-side next()), on the
+        # host path (batch placement + step dispatch), in eval, in the
+        # blocking fetch of a logged step's metrics and in building and
+        # writing its record.  Each stamp pair is one ``ledger_span``, so
+        # the same interval is a ``pfx.train.*`` span in a profiler trace.
+        # Pure monotonic host clocks — no device sync is added to the hot
+        # path.  ("compile" only parks the first dispatch's seconds.)
+        ledger = {
+            "compile": 0.0, "data_wait": 0.0, "host": 0.0, "eval": 0.0,
+            "log_fetch": 0.0, "log_write": 0.0,
+        }
+        # host_gap: seconds from a blocking log fetch returning (the
+        # device has drained) to the next step's dispatch having returned
+        host_gap_total = 0.0
+        t_fetched = None
         steps_in_window = 0
         data_iter = iter(train_loader)
         while True:
-            try:
-                t_fetch = time.monotonic()
-                batch = next(data_iter)
-                data_wait_total += time.monotonic() - t_fetch
-            except StopIteration:
-                break
-            if self._step >= self.max_steps:
-                break
-            self._drain_skip_events(train_loader)
-            if resilience.maybe_fire("nan_grads", self._step + 1):
-                batch = resilience.poison_batch(batch)
-            t_host = time.monotonic()
-            dev_batch = self._put_batch(batch)
-            self.state, metrics = self._train_step(self.state, dev_batch)
-            host_dt = time.monotonic() - t_host
-            if (
-                self._group_spec is not None
-                and (self._step + 1) % self.model_stats_every == 0
+            with jax.profiler.StepTraceAnnotation(
+                "pfx.train.step", step_num=self._step + 1
             ):
-                # device REFS only (no sync): the stats branch just ran
-                # in-graph; the arrays are fetched with the next logging
-                # fetch and attached to that record
-                self._pending_stats = (self._step + 1, metrics["model_stats"])
-            if self._compile_s is None:
-                # the first dispatch traces + compiles synchronously inside
-                # the jit call: time it separately (compile_s) and restart
-                # the throughput window so ips/mfu never average the
-                # compile into the first window
-                self._compile_s = host_dt
-                t_last = time.time()
-            else:
-                host_total += host_dt
-            if guard is not None and prev_metrics is not None:
-                pm = jax.device_get(prev_metrics)
-                reason = guard.observe(
-                    float(pm["loss"]), float(pm["found_inf"]) > 0
-                )
-                if reason is not None:
-                    # the step just dispatched is discarded along with the
-                    # anomalous state: load() replaces self.state and
-                    # restores the step/consumed counters from the meta.
-                    # A rewindable loader is rewound to the checkpoint
-                    # position (token-for-token replay); otherwise the
-                    # stream keeps its live position — same contract as a
-                    # process restart mid-epoch.
-                    culprits = None
-                    if self._group_spec is not None and "group_nonfinite" in pm:
-                        from paddlefleetx_tpu.utils.model_stats import (
-                            nonfinite_group_names,
-                        )
-
-                        culprits = nonfinite_group_names(
-                            self._group_spec, pm["group_nonfinite"]
-                        ) or None
-                    rewound = self._rollback(
-                        self._step, reason, rollbacks,
-                        nonfinite_groups=culprits,
-                    )
-                    rollbacks += 1
-                    guard.reset()
-                    prev_metrics = None
-                    # stats stashed from the discarded window must not
-                    # label a post-rollback record
-                    self._pending_stats = None
-                    if rewound:
-                        # position is read at iter() time: restart the
-                        # iteration so the replay starts AT the checkpoint
-                        data_iter = iter(train_loader)
-                    continue
-            if guard is not None:
-                prev_metrics = {
-                    "loss": metrics["loss"], "found_inf": metrics["found_inf"]
-                }
-                if self._group_spec is not None:
-                    # provenance rides the guard's existing step-behind
-                    # fetch: [G] int32, no extra sync
-                    prev_metrics["group_nonfinite"] = metrics["group_nonfinite"]
-            self._consumed_samples += self.global_batch_size
-            window_tokens += self.global_batch_size * tokens_per_sample
-            steps_in_window += 1
-            self._step += 1
-            step = self._step
-            profiler.step(step)
-
-            if step % self.logging_freq == 0:
-                # ONE host fetch: the step metrics plus any pending
-                # model-stats arrays stashed at the last cadence step —
-                # the observatory's "stats ride the existing step-record
-                # device fetch" contract
-                pending_stats, self._pending_stats = self._pending_stats, None
-                if pending_stats is not None:
-                    metrics, stats_vals = jax.device_get(
-                        (metrics, pending_stats[1])
-                    )
-                else:
-                    metrics = jax.device_get(metrics)
-                dt = time.time() - t_last
-                ips = window_tokens / dt
-                logger.info(
-                    f"step {step}/{self.max_steps} loss: {float(metrics['loss']):.5f} "
-                    f"lr: {float(metrics['lr']):.3e} grad_norm: {float(metrics['grad_norm']):.3f} "
-                    f"ips: {ips:,.0f} tokens/s ({ips/self.mesh.size:,.0f}/device)"
-                )
-                record = {
-                    "step": step,
-                    "loss": float(metrics["loss"]),
-                    "lr": float(metrics["lr"]),
-                    "grad_norm": float(metrics["grad_norm"]),
-                    "ips": round(ips, 1),
-                    "consumed_samples": self._consumed_samples,
-                    # phase breakdown: cumulative consumer-side data wait
-                    # and host-side (placement+dispatch) seconds, plus the
-                    # average wall seconds per step over this window —
-                    # wall minus data/host is dispatched-device time
-                    "tokens_per_sec": round(ips, 1),
-                    "data_wait_s": round(data_wait_total, 3),
-                    "host_s": round(host_total, 3),
-                    "step_s": round(dt / max(1, steps_in_window), 4),
-                }
-                if self._compile_s is not None and not self._compile_emitted:
-                    # first logged window: trace+compile seconds, timed at
-                    # the first dispatch and excluded from the ips window
-                    record["compile_s"] = round(self._compile_s, 3)
-                    self._compile_emitted = True
-                if self._flops_per_token:
-                    model_fps = ips * self._flops_per_token
-                    record["model_flops"] = round(model_fps, 1)
-                    if self._peak_flops:
-                        record["mfu"] = round(
-                            model_fps / (self._peak_flops * self.mesh.size), 6
-                        )
-                # data-pipeline health (prefetch depth, cumulative seconds
-                # the loop sat starved, skip budget spent) rides the same
-                # stream so dashboards see starvation next to throughput.
-                # The loader's own data_wait_s (producer-side, sees stalls
-                # the prefetch buffer hides from the loop) overrides the
-                # engine's consumer-side measurement when available.
-                stats_fn = getattr(train_loader, "stats", None)
-                if callable(stats_fn):
-                    record.update(
-                        (k, v) for k, v in stats_fn().items()
-                        if k in ("data_wait_s", "prefetch_depth",
-                                 "stall_warnings", "skips")
-                    )
-                if pending_stats is not None:
-                    record["model_stats"] = self._format_model_stats(
-                        pending_stats[0], stats_vals
-                    )
+                try:
+                    with ledger_span("pfx.train.data_wait", ledger, "data_wait"):
+                        batch = next(data_iter)
+                except StopIteration:
+                    break
+                if self._step >= self.max_steps:
+                    break
+                self._drain_skip_events(train_loader)
+                if resilience.maybe_fire("nan_grads", self._step + 1):
+                    batch = resilience.poison_batch(batch)
+                first = self._compile_s is None
+                with ledger_span("pfx.train.put_dispatch", ledger,
+                                 "compile" if first else "host") as disp:
+                    dev_batch = self._put_batch(batch)
+                    self.state, metrics = self._train_step(self.state, dev_batch)
+                if t_fetched is not None:
+                    host_gap_total += disp.t1 - t_fetched
+                    t_fetched = None
                 if (
                     self._group_spec is not None
-                    and float(metrics.get("found_inf", 0.0)) > 0
+                    and (self._step + 1) % self.model_stats_every == 0
                 ):
-                    # non-finite provenance: this logged step was skipped;
-                    # name the offending group(s) right on the record
-                    from paddlefleetx_tpu.utils.model_stats import (
-                        nonfinite_group_names,
+                    # device REFS only (no sync): the stats branch just ran
+                    # in-graph; the arrays are fetched with the next logging
+                    # fetch and attached to that record
+                    self._pending_stats = (self._step + 1, metrics["model_stats"])
+                if first:
+                    # the first dispatch traces + compiles synchronously inside
+                    # the jit call: time it separately (compile_s) and restart
+                    # the throughput window so ips/mfu never average the
+                    # compile into the first window
+                    self._compile_s = disp.seconds
+                    t_last = time.time()
+                if guard is not None and prev_metrics is not None:
+                    pm = jax.device_get(prev_metrics)
+                    reason = guard.observe(
+                        float(pm["loss"]), float(pm["found_inf"]) > 0
                     )
+                    if reason is not None:
+                        # the step just dispatched is discarded along with the
+                        # anomalous state: load() replaces self.state and
+                        # restores the step/consumed counters from the meta.
+                        # A rewindable loader is rewound to the checkpoint
+                        # position (token-for-token replay); otherwise the
+                        # stream keeps its live position — same contract as a
+                        # process restart mid-epoch.
+                        culprits = None
+                        if self._group_spec is not None and "group_nonfinite" in pm:
+                            from paddlefleetx_tpu.utils.model_stats import (
+                                nonfinite_group_names,
+                            )
 
-                    record["found_inf"] = 1
-                    record["nonfinite_groups"] = nonfinite_group_names(
-                        self._group_spec, metrics["group_nonfinite"]
-                    )
-                # memory watermarks: host-side accounting only (device
-                # memory_stats where the backend has it, host RSS always)
-                self._sample_memory(record)
-                if fit_trace is not None:
-                    # mirror the record's phase fields as a trace span:
-                    # the step-record JSONL and the Perfetto timeline
-                    # describe the SAME window, linked by trace_id
-                    now_mono = time.monotonic()
-                    fit_trace.span(
-                        "step_window", t0=window_t0, t1=now_mono,
-                        step=step, loss=record["loss"],
-                        tokens_per_sec=record["tokens_per_sec"],
-                        data_wait_s=record["data_wait_s"],
-                        host_s=record["host_s"],
-                        step_s=record["step_s"],
-                    )
-                    window_t0 = now_mono
-                    record["trace_id"] = fit_trace.trace_id
-                self._update_registry(record, ips)
-                # time ledger: attribute the whole fit's wall clock from
-                # the loop's OWN accumulators (not the record — a loader
-                # stats() override swaps in producer-side data_wait_s,
-                # which would break closure against this thread's wall).
-                # Exporter-style .set(): totals stay monotonic per fit.
-                buckets = {
-                    "compile": self._compile_s or 0.0,
-                    "data_wait": data_wait_total,
-                    "host": host_total,
-                    "eval": eval_total,
-                }
-                buckets["device_step"] = max(
-                    0.0,
-                    (time.monotonic() - loop_t0) - sum(buckets.values()),
-                )
-                reg = self._registry
-                for bname, bval in sorted(buckets.items()):
-                    reg.counter(
-                        "pfx_train_time_seconds_total", bucket=bname
-                    ).set(round(bval, 4))
-                # the record carries the same ledger so tools/report.py
-                # renders the stacked breakdown from artifacts alone
-                record["time_ledger"] = {
-                    k: round(v, 3) for k, v in buckets.items()
-                }
-                self._write_metrics(record)
-                t_last = time.time()
-                window_tokens = 0
-                steps_in_window = 0
+                            culprits = nonfinite_group_names(
+                                self._group_spec, pm["group_nonfinite"]
+                            ) or None
+                        rewound = self._rollback(
+                            self._step, reason, rollbacks,
+                            nonfinite_groups=culprits,
+                        )
+                        rollbacks += 1
+                        guard.reset()
+                        prev_metrics = None
+                        # stats stashed from the discarded window must not
+                        # label a post-rollback record
+                        self._pending_stats = None
+                        if rewound:
+                            # position is read at iter() time: restart the
+                            # iteration so the replay starts AT the checkpoint
+                            data_iter = iter(train_loader)
+                        continue
+                if guard is not None:
+                    prev_metrics = {
+                        "loss": metrics["loss"], "found_inf": metrics["found_inf"]
+                    }
+                    if self._group_spec is not None:
+                        # provenance rides the guard's existing step-behind
+                        # fetch: [G] int32, no extra sync
+                        prev_metrics["group_nonfinite"] = metrics["group_nonfinite"]
+                self._consumed_samples += self.global_batch_size
+                window_tokens += self.global_batch_size * tokens_per_sample
+                steps_in_window += 1
+                self._step += 1
+                step = self._step
+                self.profiler.step(step)
 
-            if self.consistency_check_freq and step % self.consistency_check_freq == 0:
-                from paddlefleetx_tpu.parallel.check import check_replica_consistency
+                if step % self.logging_freq == 0:
+                    # ONE host fetch: the step metrics plus any pending
+                    # model-stats arrays stashed at the last cadence step —
+                    # the observatory's "stats ride the existing step-record
+                    # device fetch" contract
+                    pending_stats, self._pending_stats = self._pending_stats, None
+                    with ledger_span("pfx.train.log_fetch", ledger,
+                                     "log_fetch") as fetch:
+                        if pending_stats is not None:
+                            metrics, stats_vals = jax.device_get(
+                                (metrics, pending_stats[1])
+                            )
+                        else:
+                            metrics = jax.device_get(metrics)
+                    t_fetched = fetch.t1
+                    with ledger_span("pfx.train.log_write", ledger,
+                                     "log_write"):
+                        dt = time.time() - t_last
+                        ips = window_tokens / dt
+                        logger.info(
+                            f"step {step}/{self.max_steps} loss: {float(metrics['loss']):.5f} "
+                            f"lr: {float(metrics['lr']):.3e} grad_norm: {float(metrics['grad_norm']):.3f} "
+                            f"ips: {ips:,.0f} tokens/s ({ips/self.mesh.size:,.0f}/device)"
+                        )
+                        record = {
+                            "step": step,
+                            "loss": float(metrics["loss"]),
+                            "lr": float(metrics["lr"]),
+                            "grad_norm": float(metrics["grad_norm"]),
+                            "ips": round(ips, 1),
+                            "consumed_samples": self._consumed_samples,
+                            # phase breakdown: cumulative consumer-side data wait
+                            # and host-side (placement+dispatch) seconds, plus the
+                            # average wall seconds per step over this window —
+                            # wall minus data/host is dispatched-device time
+                            "tokens_per_sec": round(ips, 1),
+                            "data_wait_s": round(ledger["data_wait"], 3),
+                            "host_s": round(ledger["host"], 3),
+                            "step_s": round(dt / max(1, steps_in_window), 4),
+                            # cumulative, like the two above: the blocking fetch
+                            # of logged steps' metrics, building and writing their
+                            # records (through the previous record), and the
+                            # seconds the drained device then waited for its next
+                            # dispatch
+                            "log_fetch_s": round(ledger["log_fetch"], 4),
+                            "log_write_s": round(ledger["log_write"], 4),
+                            "host_gap_s": round(host_gap_total, 4),
+                        }
+                        if self._compile_s is not None and not self._compile_emitted:
+                            # first logged window: trace+compile seconds, timed at
+                            # the first dispatch and excluded from the ips window
+                            record["compile_s"] = round(self._compile_s, 3)
+                            self._compile_emitted = True
+                        if self._flops_per_token:
+                            model_fps = ips * self._flops_per_token
+                            record["model_flops"] = round(model_fps, 1)
+                            if self._peak_flops:
+                                record["mfu"] = round(
+                                    model_fps / (self._peak_flops * self.mesh.size), 6
+                                )
+                        # data-pipeline health (prefetch depth, cumulative seconds
+                        # the loop sat starved, skip budget spent) rides the same
+                        # stream so dashboards see starvation next to throughput.
+                        # The loader's own data_wait_s (producer-side, sees stalls
+                        # the prefetch buffer hides from the loop) overrides the
+                        # engine's consumer-side measurement when available.
+                        stats_fn = getattr(train_loader, "stats", None)
+                        if callable(stats_fn):
+                            record.update(
+                                (k, v) for k, v in stats_fn().items()
+                                if k in ("data_wait_s", "prefetch_depth",
+                                         "stall_warnings", "skips")
+                            )
+                        if pending_stats is not None:
+                            record["model_stats"] = self._format_model_stats(
+                                pending_stats[0], stats_vals
+                            )
+                        if (
+                            self._group_spec is not None
+                            and float(metrics.get("found_inf", 0.0)) > 0
+                        ):
+                            # non-finite provenance: this logged step was skipped;
+                            # name the offending group(s) right on the record
+                            from paddlefleetx_tpu.utils.model_stats import (
+                                nonfinite_group_names,
+                            )
 
-                fp = check_replica_consistency(self.state.params)
-                logger.info(f"consistency check OK @ step {step}: params fp {fp:#010x}")
-                t_last = time.time()
-                window_tokens = 0
-                steps_in_window = 0
+                            record["found_inf"] = 1
+                            record["nonfinite_groups"] = nonfinite_group_names(
+                                self._group_spec, metrics["group_nonfinite"]
+                            )
+                        # memory watermarks: host-side accounting only (device
+                        # memory_stats where the backend has it, host RSS always)
+                        self._sample_memory(record)
+                        if fit_trace is not None:
+                            # mirror the record's phase fields as a trace span:
+                            # the step-record JSONL and the Perfetto timeline
+                            # describe the SAME window, linked by trace_id
+                            now_mono = time.monotonic()
+                            fit_trace.span(
+                                "step_window", t0=window_t0, t1=now_mono,
+                                step=step, loss=record["loss"],
+                                tokens_per_sec=record["tokens_per_sec"],
+                                data_wait_s=record["data_wait_s"],
+                                host_s=record["host_s"],
+                                step_s=record["step_s"],
+                            )
+                            window_t0 = now_mono
+                            record["trace_id"] = fit_trace.trace_id
+                        self._update_registry(record, ips)
+                        # time ledger: attribute the whole fit's wall clock from
+                        # the loop's OWN accumulators (not the record — a loader
+                        # stats() override swaps in producer-side data_wait_s,
+                        # which would break closure against this thread's wall).
+                        # Exporter-style .set(): totals stay monotonic per fit.
+                        buckets = {
+                            "compile": self._compile_s or 0.0,
+                            "data_wait": ledger["data_wait"],
+                            "host": ledger["host"],
+                            "eval": ledger["eval"],
+                        }
+                        buckets["device_step"] = max(
+                            0.0,
+                            (time.monotonic() - loop_t0) - sum(buckets.values()),
+                        )
+                        reg = self._registry
+                        for bname, bval in sorted(buckets.items()):
+                            reg.counter(
+                                "pfx_train_time_seconds_total", bucket=bname
+                            ).set(round(bval, 4))
+                        reg.counter("pfx_train_host_gap_seconds_total").set(
+                            round(host_gap_total, 4)
+                        )
+                        # the record carries the same ledger so tools/report.py
+                        # renders the stacked breakdown from artifacts alone
+                        record["time_ledger"] = {
+                            k: round(v, 3) for k, v in buckets.items()
+                        }
+                        self._write_metrics(record)
+                    t_last = time.time()
+                    window_tokens = 0
+                    steps_in_window = 0
 
-            if self.eval_freq and eval_iter is not None and step % self.eval_freq == 0:
-                # on_empty="event": a finite eval stream exhausting mid-fit
-                # logs loudly + emits a structured event instead of either
-                # nan-poisoning silently or killing the training run
-                t_eval = time.monotonic()
-                self.evaluate(eval_iter, iters=self.eval_iters, on_empty="event")
-                eval_total += time.monotonic() - t_eval
-                t_last = time.time()
-                window_tokens = 0
-                steps_in_window = 0
+                if self.consistency_check_freq and step % self.consistency_check_freq == 0:
+                    from paddlefleetx_tpu.parallel.check import check_replica_consistency
 
-            if self.save_steps and step % self.save_steps == 0:
-                self.save()
-                # a save landing while the guard sees a healthy stream is
-                # proof of recovery: the budget guards against rollback
-                # THRASH, not against independent anomalies days apart in
-                # a long run.  The streak check matters — saves fire on
-                # skipped steps too, and resetting mid-streak would let a
-                # persistent anomaly roll back forever.
-                if guard is None or (
-                    guard.skip_streak == 0 and guard.spike_streak == 0
-                ):
-                    rollbacks = 0
-                t_last = time.time()
-                window_tokens = 0
-                steps_in_window = 0
-                if self.exit_after_save:
-                    # checkpoint-aligned clean exit: the save above is
-                    # durable once wait_for_save joins (fit's finally);
-                    # reuse the preempted flag so the launcher exits 0
-                    logger.info(
-                        f"exit_after_save: checkpoint at step {step} "
-                        "complete, exiting cleanly"
-                    )
-                    self.wait_for_save()
-                    self.preempted = True
+                    fp = check_replica_consistency(self.state.params)
+                    logger.info(f"consistency check OK @ step {step}: params fp {fp:#010x}")
+                    t_last = time.time()
+                    window_tokens = 0
+                    steps_in_window = 0
+                    t_fetched = None  # not a bare log-to-dispatch gap
+
+                if self.eval_freq and eval_iter is not None and step % self.eval_freq == 0:
+                    # on_empty="event": a finite eval stream exhausting mid-fit
+                    # logs loudly + emits a structured event instead of either
+                    # nan-poisoning silently or killing the training run
+                    with ledger_span("pfx.train.eval", ledger, "eval"):
+                        self.evaluate(
+                            eval_iter, iters=self.eval_iters, on_empty="event"
+                        )
+                    t_last = time.time()
+                    window_tokens = 0
+                    steps_in_window = 0
+                    t_fetched = None  # not a bare log-to-dispatch gap
+
+                if self.save_steps and step % self.save_steps == 0:
+                    self.save()
+                    # a save landing while the guard sees a healthy stream is
+                    # proof of recovery: the budget guards against rollback
+                    # THRASH, not against independent anomalies days apart in
+                    # a long run.  The streak check matters — saves fire on
+                    # skipped steps too, and resetting mid-streak would let a
+                    # persistent anomaly roll back forever.
+                    if guard is None or (
+                        guard.skip_streak == 0 and guard.spike_streak == 0
+                    ):
+                        rollbacks = 0
+                    t_last = time.time()
+                    window_tokens = 0
+                    steps_in_window = 0
+                    t_fetched = None  # not a bare log-to-dispatch gap
+                    if self.exit_after_save:
+                        # checkpoint-aligned clean exit: the save above is
+                        # durable once wait_for_save joins (fit's finally);
+                        # reuse the preempted flag so the launcher exits 0
+                        logger.info(
+                            f"exit_after_save: checkpoint at step {step} "
+                            "complete, exiting cleanly"
+                        )
+                        self.wait_for_save()
+                        self.preempted = True
+                        break
+
+                # fault injection: deliver a real SIGTERM to this process so
+                # the handler path itself is what the test exercises
+                sig_fired = resilience.maybe_fire("sigterm", step)
+                if (preempt is not None and preempt.requested) or sig_fired:
+                    self._preempt_save(step, "preemption signal")
                     break
-
-            # fault injection: deliver a real SIGTERM to this process so
-            # the handler path itself is what the test exercises
-            sig_fired = resilience.maybe_fire("sigterm", step)
-            if (preempt is not None and preempt.requested) or sig_fired:
-                self._preempt_save(step, "preemption signal")
-                break
 
         if fit_trace is not None:
             # finished cleanly; a crashed fit deliberately stays

@@ -406,6 +406,7 @@ def _decode_pallas(q_t, k_cache, v_cache, limit, valid_from, block, scale,
             out_specs=pl.BlockSpec((1, 1, t, d), lambda i, j: (i, j, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((b, n, t, d), jnp.float32),
             interpret=_device.pallas_interpret(),
+            name="pfx_decode_contig_q8",
         )(q_t, k_cache, v_cache, k_scale[:, :, None], v_scale[:, :, None],
           limit_arr, vf_arr)
     kernel = functools.partial(
@@ -423,6 +424,7 @@ def _decode_pallas(q_t, k_cache, v_cache, limit, valid_from, block, scale,
         out_specs=pl.BlockSpec((1, 1, t, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n, t, d), jnp.float32),
         interpret=_device.pallas_interpret(),
+        name="pfx_decode_contig",
     )(q_t, k_cache, v_cache, limit_arr, vf_arr)
     return out
 
@@ -688,6 +690,7 @@ def _paged_pallas(q_t, k_pool, v_pool, tables, positions, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n, t, d), jnp.float32),
         interpret=_device.pallas_interpret(),
+        name="pfx_decode_paged",
     )(tables, positions, *operands)
 
 

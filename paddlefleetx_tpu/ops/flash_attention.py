@@ -192,6 +192,7 @@ def _flash_fwd(q, k, v, scale, block):
             jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32),
         ],
         interpret=_device.pallas_interpret(),
+        name="pfx_flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -381,6 +382,7 @@ def _flash_bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k):
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=_device.pallas_interpret(),
+        name="pfx_flash_bwd_fused",
     )(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk, dv
 
@@ -410,6 +412,7 @@ def _flash_bwd(scale, block, bwd_mode, res, g):
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=_device.pallas_interpret(),
+        name="pfx_flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -434,6 +437,7 @@ def _flash_bwd(scale, block, bwd_mode, res, g):
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=_device.pallas_interpret(),
+        name="pfx_flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     return dq, dk, dv

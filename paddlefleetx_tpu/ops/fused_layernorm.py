@@ -117,6 +117,7 @@ def _run_fwd(x2, res2, scale, bias, eps):
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=_device.pallas_interpret(),
+        name="pfx_ln_fwd",
     )(*args)
 
 
@@ -165,6 +166,7 @@ def _fused_ln_bwd(eps, has_res, saved, g):
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=_device.pallas_interpret(),
+        name="pfx_ln_bwd",
     )(*args)
     dscale = dscale_p.astype(scale.dtype)
     dbias = dbias_p.astype(scale.dtype)

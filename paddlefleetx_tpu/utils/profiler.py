@@ -19,6 +19,12 @@ the reference's printed summary views (eager_engine.py:866-925):
 when the backend exposes them).  Conversion uses the xprof toolchain when
 importable and degrades to trace-only with a warning otherwise.
 
+``ProfilerHook.arm(log_dir, steps)`` starts a window at run time (the
+config block is the same path: it arms itself at ``scheduler[0]``), so a
+loader wrapper or callback can profile N steps of a running job without
+knowing its step numbers beforehand (docs/observability.md "On-demand
+profiling").
+
 The parsing layer is module-level (``newest_run_dir`` / ``hlo_stats_rows``
 / ``trace_event_rows`` / ``op_summary_rows`` / ``device_host_split``) so
 the on-demand serving capture (``capture_profile``, behind ``POST
@@ -141,27 +147,49 @@ def trace_event_rows(log_dir: str) -> List[Dict[str, Any]]:
     ]
 
 
+def _union_us(intervals) -> float:
+    """Total length of the union of ``(start, end)`` intervals."""
+    total, end = 0.0, None
+    for a, b in sorted(intervals):
+        if end is None or a > end:
+            total += b - a
+            end = b
+        elif b > end:
+            total += b - end
+            end = b
+    return total
+
+
 def device_host_split(log_dir: str) -> Tuple[float, float]:
-    """(device_us, host_us): summed complete-event durations split by
-    whether the emitting process is a device plane.  The chrome trace
-    names every pid via ``ph=="M"``/``process_name`` metadata; device
-    planes are the ``/device:...`` ones (TPU/GPU streams), everything
-    else (python threads, runtime) is host."""
-    device_pids = set()
+    """(device_us, host_us): BUSY microseconds, the union of the
+    complete-event intervals of each plane (overlapping and nested events
+    count once), summed over device planes and over host planes.  The
+    chrome trace names every pid via ``ph=="M"``/``process_name``
+    metadata; device planes are the ``/device:...`` ones (TPU/GPU
+    streams), everything else (python threads, runtime) is host.  A
+    device plane is read from its "XLA Ops" line when it has one (the
+    "Steps" and "XLA Modules" lines span the gaps between ops)."""
+    device_pids, ops_tids = set(), {}
     events = _newest_trace_events(log_dir)
     for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            pname = str((e.get("args") or {}).get("name", ""))
-            if pname.startswith("/device:"):
-                device_pids.add(e.get("pid"))
-    device_us = host_us = 0.0
+        if e.get("ph") != "M":
+            continue
+        name = str((e.get("args") or {}).get("name", ""))
+        if e.get("name") == "process_name" and name.startswith("/device:"):
+            device_pids.add(e.get("pid"))
+        elif e.get("name") == "thread_name" and name == "XLA Ops":
+            ops_tids[e.get("pid")] = e.get("tid")
+    per_plane: Dict[Any, list] = {}
     for e in events:
         if e.get("ph") != "X" or "dur" not in e:
             continue
-        if e.get("pid") in device_pids:
-            device_us += float(e["dur"])
-        else:
-            host_us += float(e["dur"])
+        pid = e.get("pid")
+        if pid in ops_tids and e.get("tid") != ops_tids[pid]:
+            continue  # a device plane's other lines
+        t = float(e.get("ts", 0.0))
+        per_plane.setdefault(pid, []).append((t, t + float(e["dur"])))
+    device_us = sum(_union_us(v) for k, v in per_plane.items() if k in device_pids)
+    host_us = sum(_union_us(v) for k, v in per_plane.items() if k not in device_pids)
     return device_us, host_us
 
 
@@ -183,13 +211,47 @@ def op_summary_rows(log_dir: str, hlo_fn=None, trace_fn=None) -> Tuple[List[Dict
     return rows, source
 
 
-def capture_profile(seconds: float, log_dir: str, top: int = 20) -> Dict[str, Any]:
+def start_trace(log_dir: str, python_tracer: bool = True) -> Dict[str, int]:
+    """``jax.profiler.start_trace`` plus the two clock stamps taken at the
+    start, so timelines on the monotonic clock (``/debug/traces``, the
+    time ledgers) can be laid over the device trace afterwards.
+    ``python_tracer=False`` keeps the profiler's Python call tracer off:
+    the host plane then holds the runtime's own events and the program's
+    ``pfx.*`` spans only, and the capture costs the traced threads far
+    less (the Python tracer writes an event per call)."""
+    os.makedirs(log_dir, exist_ok=True)
+    options = None
+    if not python_tracer:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+    clocks = {"monotonic_ns": time.monotonic_ns(), "time_ns": time.time_ns()}
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    return clocks
+
+
+def _trace_done(trace_s: float) -> None:
+    from paddlefleetx_tpu.utils.telemetry import get_registry
+
+    reg = get_registry()
+    reg.counter("pfx_profiler_traces_total").inc()
+    reg.gauge("pfx_profiler_trace_seconds").set(round(trace_s, 3))
+
+
+def capture_profile(seconds: float, log_dir: str, top: int = 20,
+                    summary: bool = True,
+                    python_tracer: bool = True) -> Dict[str, Any]:
     """Capture a ``jax.profiler`` trace of the LIVE process for ``seconds``
     and answer with the parsed summary — the whole ``POST /admin/profile``
     body in one call.  Raises ``ValueError`` on a bad/over-cap duration
     (-> 400) and ``ProfileBusy`` when a capture is already running
     (-> 409).  The capture adds no device sync: the profiler observes the
-    running dispatch loop, it never drives it."""
+    running dispatch loop, it never drives it.
+
+    ``summary=False`` skips the parse (the xprof/TF import, the op table,
+    the device/host split) and answers with ``trace_dir``, ``seconds`` and
+    the start clocks only: for a caller that reduces the trace in another
+    process and must not stall this one.  ``python_tracer=False``: see
+    :func:`start_trace`."""
     cap = profile_max_seconds()
     try:
         seconds = float(seconds)
@@ -208,19 +270,23 @@ def capture_profile(seconds: float, log_dir: str, top: int = 20) -> Dict[str, An
             "retry after it finishes"
         )
     try:
-        from paddlefleetx_tpu.utils.telemetry import get_registry
-
-        os.makedirs(log_dir, exist_ok=True)
         t0 = time.monotonic()
-        jax.profiler.start_trace(log_dir)
+        clocks = start_trace(log_dir, python_tracer=python_tracer)
         try:
             time.sleep(seconds)
         finally:
             jax.profiler.stop_trace()
         trace_s = time.monotonic() - t0
-        reg = get_registry()
-        reg.counter("pfx_profiler_traces_total").inc()
-        reg.gauge("pfx_profiler_trace_seconds").set(round(trace_s, 3))
+        _trace_done(trace_s)
+        out = {
+            "seconds": round(trace_s, 3),
+            "trace_dir": log_dir,
+            "started_monotonic_ns": clocks["monotonic_ns"],
+            "started_time_ns": clocks["time_ns"],
+            "python_tracer": bool(python_tracer),
+        }
+        if not summary:
+            return out
         rows, source = op_summary_rows(log_dir)
         try:
             device_us, host_us = device_host_split(log_dir)
@@ -232,21 +298,23 @@ def capture_profile(seconds: float, log_dir: str, top: int = 20) -> Dict[str, An
             {**r, "self_frac": round(r["self_us"] / total_self, 4)}
             for r in rows[: max(0, int(top))]
         ]
-        return {
-            "seconds": round(trace_s, 3),
-            "trace_dir": log_dir,
+        out.update({
             "source": source,
             "device_us": round(device_us, 1),
             "host_us": round(host_us, 1),
             "op_count": len(rows),
             "top_ops": top_ops,
-        }
+        })
+        return out
     finally:
         _CAPTURE_LOCK.release()
 
 
 class ProfilerHook:
-    """Start/stop jax.profiler.trace around a step window."""
+    """Start/stop a ``jax.profiler`` trace around a window of training
+    steps.  The window comes from the config block (``enable`` +
+    ``scheduler``: the hook arms itself at construction) or, at run time,
+    from :meth:`arm`.  An unarmed hook costs one comparison per step."""
 
     def __init__(self, cfg: Optional[Dict[str, Any]]):
         cfg = cfg or {}
@@ -269,40 +337,71 @@ class ProfilerHook:
         self.log_dir = os.path.abspath(cfg.get("log_dir", "./profiler_log"))
         self.summary = bool(cfg.get("summary", True))
         self.summary_top = int(cfg.get("summary_top", 20))
+        self.python_tracer = True
+        self.traces = 0  # windows completed by this hook
         self._active = False
         self._pending_summary = False
         self._trace_t0 = 0.0
+        # the armed window: None, (start, stop) in step numbers, or
+        # (None, n) = "n steps from the next step boundary"
+        self._window: Optional[Tuple[Optional[int], int]] = None
+        if self.enabled:
+            self._window = (self.start_step, self.stop_step)
+
+    def arm(self, log_dir: Optional[str] = None, steps: int = 1, *,
+            python_tracer: bool = True,
+            summary: Optional[bool] = None) -> None:
+        """Trace the next ``steps`` training steps of the running fit: the
+        trace starts at the next step boundary (the next ``step()`` call)
+        and stops ``steps`` calls later.  Call it from the thread that
+        runs the fit loop (a loader wrapper's ``__next__``, a callback);
+        a hook can be armed again once its window has closed.
+        ``summary`` (default: the config block's) writes the op/memory
+        summaries at ``close()``; ``python_tracer``: see
+        :func:`start_trace`."""
+        if self._active or self._window is not None:
+            raise ProfileBusy("a profiler window is already armed or tracing")
+        if int(steps) < 1:
+            raise ValueError(f"steps must be >= 1, got {steps}")
+        if log_dir is not None:
+            self.log_dir = os.path.abspath(log_dir)
+        if summary is not None:
+            self.summary = bool(summary)
+        self.python_tracer = bool(python_tracer)
+        self._window = (None, int(steps))
 
     def step(self, step: int) -> None:
         """Call once per training step with the 1-based step counter."""
-        if not self.enabled:
+        if self._window is None:
             return
-        from paddlefleetx_tpu.utils.telemetry import (
-            get_flight_recorder,
-            get_registry,
-        )
+        from paddlefleetx_tpu.utils.telemetry import get_flight_recorder
 
-        if not self._active and self.start_step <= step < self.stop_step:
-            os.makedirs(self.log_dir, exist_ok=True)
-            jax.profiler.start_trace(self.log_dir)
+        start, stop = self._window
+        if start is None:  # armed at run time: the window starts here
+            start, stop = step, step + stop
+            self._window = (start, stop)
+        if not self._active and start <= step < stop:
+            clocks = start_trace(self.log_dir, python_tracer=self.python_tracer)
             self._active = True
             self._trace_t0 = time.monotonic()
             get_flight_recorder().record(
                 {"event": "profiler_trace_start", "step": step,
-                 "log_dir": self.log_dir}
+                 "log_dir": self.log_dir, **clocks}
             )
-            logger.info(f"profiler: trace started (steps {self.start_step}-{self.stop_step}) -> {self.log_dir}")
-        elif self._active and step >= self.stop_step:
+            logger.info(f"profiler: trace started (steps {start}-{stop}) -> {self.log_dir}")
+        elif step >= stop:
+            self._window = None
+            if not self._active:
+                return  # the run resumed past a config window: nothing to trace
             jax.profiler.stop_trace()
             self._active = False
+            self.traces += 1
             # summaries lazily import the xprof/TF toolchain and parse the
             # whole trace — deferred to close() so the remaining training
             # steps (whose throughput is being measured) are not stalled
             self._pending_summary = True
             trace_s = time.monotonic() - self._trace_t0
-            reg = get_registry()
-            reg.counter("pfx_profiler_traces_total").inc()
-            reg.gauge("pfx_profiler_trace_seconds").set(round(trace_s, 3))
+            _trace_done(trace_s)
             get_flight_recorder().record(
                 {"event": "profiler_trace_stop", "step": step,
                  "trace_s": round(trace_s, 3)}
@@ -310,11 +409,12 @@ class ProfilerHook:
             logger.info(f"profiler: trace written to {self.log_dir} (view with TensorBoard)")
 
     def close(self) -> None:
+        self._window = None
         if self._active:
             jax.profiler.stop_trace()
             self._active = False
             self._pending_summary = True
-        if getattr(self, "_pending_summary", False):
+        if self._pending_summary:
             self._pending_summary = False
             self._write_summary()
 

@@ -254,6 +254,15 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_sched_wall_seconds_total": ("counter", "Total scheduler-thread wall seconds the time buckets must close against"),
     "pfx_sched_host_gap_seconds_total": ("counter", "Host seconds the device sat idle waiting for its next dispatch (goodput_frac subtrahend; overlaps the bucket family)"),
     "pfx_train_time_seconds_total": ("counter", "Fit-loop wall seconds by attribution bucket (labels: bucket=compile|device_step|data_wait|host|eval)"),
+    # work counted where it happens (one update per decode step / train
+    # step from numbers the loop already holds): occupancy is row_steps /
+    # slot_steps, the paged kernel's useful share kv_tokens / grid_tokens
+    "pfx_sched_decode_steps_total": ("counter", "Decode steps committed by the continuous engine (its own step count)"),
+    "pfx_sched_decode_row_steps_total": ("counter", "Live rows summed over decode steps (numerator of batch occupancy)"),
+    "pfx_sched_decode_slot_steps_total": ("counter", "Batch capacity summed over decode steps (denominator of batch occupancy)"),
+    "pfx_sched_decode_kv_tokens_total": ("counter", "Context tokens of the live rows summed over decode steps (what a step needed to read)"),
+    "pfx_sched_decode_grid_tokens_total": ("counter", "capacity x table-width bucket x block size summed over decode steps (KV tokens per head the paged kernel's grid walks)"),
+    "pfx_train_host_gap_seconds_total": ("counter", "Fit-loop seconds from a blocking log fetch returning to the next step's dispatch having returned (the device has nothing queued)"),
     "pfx_token_ledger_total": ("counter", "Admitted-token dispositions (labels: disposition=admitted|delivered|evicted_lost|preempt_refunded|shed_after_admit)"),
     "pfx_token_ledger_in_flight": ("gauge", "Admitted tokens still on the books in live decode slots (the exact-closure remainder)"),
     "pfx_tenant_slot_seconds_total": ("counter", "Decode-slot occupancy in slot-seconds per tenant — billing-grade cost attribution (labels: tenant)"),
@@ -811,6 +820,54 @@ class Span:
             "phases": {k: round(v, 6) for k, v in self.phases().items()},
             **extra,
         }
+
+
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+class ledger_span:
+    """One ``with`` = one host span on the profiler's clock AND the same
+    two ``time.monotonic()`` stamps added to a ledger bucket, so a span in
+    the device trace and the bucket it explains cannot drift apart::
+
+        with ledger_span("pfx.sched.readback", self.stats, "t_readback"):
+            window = np.array(fl["window"])
+
+    ``ledger[key]`` grows by the span's duration even when the body
+    raises.  ``ledger=None`` only annotates and times (``seconds``,
+    ``t0``, ``t1`` stay readable after the block).  ``args`` land in the
+    trace event's stats.  While no profiler session is open the
+    annotation is a no-op of about a microsecond; the span names are
+    listed in docs/observability.md "Goodput ledger"."""
+
+    __slots__ = ("_ann", "_ledger", "_key", "t0", "t1")
+
+    def __init__(self, name: str, ledger=None, key: Optional[str] = None,
+                 **args: Any) -> None:
+        global _TRACE_ANNOTATION
+        if _TRACE_ANNOTATION is None:
+            from jax.profiler import TraceAnnotation
+
+            _TRACE_ANNOTATION = TraceAnnotation
+        self._ann = _TRACE_ANNOTATION(name, **args)
+        self._ledger, self._key = ledger, key
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "ledger_span":
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.monotonic()
+        if self._ledger is not None:
+            self._ledger[self._key] += self.t1 - self.t0
+        self._ann.__exit__(*exc)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
 
 # ---------------------------------------------------------------------------

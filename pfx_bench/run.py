@@ -1,19 +1,24 @@
 #!/usr/bin/env python3
 """The benchmark's one command:
 
-    python3 pfx_bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 pfx_bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 The parent is standard-library Python and never imports jax; the cell's
 runner starts one chip-owning child.  The last line of stdout is one JSON
-object: correct, attempted, failed, metrics, device (and, with --trace 1,
-breakdown).  Without a chip, or outside the repository it measures, it
-prints no result and exits non-zero.  ``--rehearse`` (self-test only) runs
-toy widths on the CPU and says so in ``device``."""
+object: correct, attempted, failed, metrics, device (and, with a trace,
+breakdown).  ``--trace 0`` reports the end-to-end metrics, ``--trace 1`` (a
+run of its own, traced in mid-window) the per-layer metrics, ``--trace 2``
+both: it is a ``--trace 0`` run until its window has closed, then traces a
+short stretch of the same traffic in the same process.  Without a chip, or
+outside the repository it measures, it prints no result and exits non-zero.
+``--rehearse`` (self-test only) runs toy widths on the CPU and says so in
+``device``."""
 
 import argparse
 import importlib.util
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -59,7 +64,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     try:
@@ -84,8 +89,18 @@ def main(argv=None) -> int:
         say("info: " + json.dumps(res["info"]))
 
         metrics, breakdown = {}, None
+        if args.trace != 1:  # the window of a run that was not traced in its middle
+            for name in cell["end_to_end"]:
+                if name in values:
+                    metrics[name] = {"value": values[name], "unit": e2e_defs[name]["unit"]}
         if args.trace:
+            # device_trace readers and the breakdown read the trace (--trace 2:
+            # of the stretch after the window); counters, spans and host
+            # clocks read the measured window through the runner's context
+            t_reduce = time.time()
             trace = reduce_trace(res["trace_dir"], out) if res.get("trace_dir") else None
+            if args.trace == 2 and res.get("trace_dir"):
+                shutil.rmtree(res["trace_dir"], ignore_errors=True)
             if trace and trace.get("busy_s"):
                 dev["busy_s"], dev["window_s"] = trace["busy_s"], trace["window_s"]
                 breakdown = {"device_ops": trace["device_ops"],
@@ -101,13 +116,12 @@ def main(argv=None) -> int:
                 val = load_module("readers", d["reader"]).read(ctx, **d.get("args", {}))
                 if val is not None:
                     metrics[name] = {"value": val, "unit": d["unit"]}
-            say("end_to_end (traced run, not judged): " + json.dumps(values))
-            say("trace: " + json.dumps({k: v for k, v in (trace or {}).items()
-                                        if k not in ("device_ops", "idle_gaps")}))
-        else:
-            for name in cell["end_to_end"]:
-                if name in values:
-                    metrics[name] = {"value": values[name], "unit": e2e_defs[name]["unit"]}
+            if args.trace == 1:
+                say("end_to_end (traced run, not judged): " + json.dumps(values))
+            say("trace: " + json.dumps({
+                **{k: v for k, v in (trace or {}).items()
+                   if k not in ("device_ops", "idle_gaps")},
+                "reduce_s": round(time.time() - t_reduce, 2)}))
         line = {"correct": bool(res["correct"]), "attempted": int(res["attempted"]),
                 "failed": int(res["failed"]), "metrics": metrics, "device": dev}
         if breakdown:

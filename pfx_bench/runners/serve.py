@@ -5,11 +5,15 @@ flags, random weights from ``--seed`` and ``--warmup-buckets`` equal to the
 traffic's own prompt buckets.  Wait for /healthz, send the lead-in at the
 cell's rate (set-up: the batch is full when the window opens), scrape
 /metrics, run the window, scrape again, stop sending, drain, hand the child
-a few served sequences to hold against the plain reference, stop the child."""
+a few served sequences to hold against the plain reference, stop the child.
+With ``--trace 2`` a short stretch of the same mix follows the drained
+window, on the same server, with one ``POST /admin/profile`` in its middle:
+the device trace comes from there, every other number from the window."""
 
 import json
 import os
 import re
+import shutil
 import subprocess
 import sys
 import threading
@@ -56,9 +60,10 @@ class Sidecar(threading.Thread):
     window's two edges and (traced run) one POST /admin/profile in
     mid-window."""
 
-    def __init__(self, port, w0, w1, profile_s):
+    def __init__(self, port, w0, w1, profile_s, profile_body=None):
         super().__init__(daemon=True)
         self.port, self.w0, self.w1, self.profile_s = port, w0, w1, profile_s
+        self.profile_body = profile_body or {"top": 5}
         self.before = self.after = None
         self.profile, self.errors = None, []
 
@@ -71,7 +76,8 @@ class Sidecar(threading.Thread):
     def _profile(self):
         try:
             code, body = common.http(self.port, "/admin/profile",
-                                     {"seconds": self.profile_s, "top": 5}, timeout=600)
+                                     {"seconds": self.profile_s, **self.profile_body},
+                                     timeout=600)
             self.profile = json.loads(body) if code == 200 else {"error": body[:300]}
         except (OSError, ValueError) as e:
             self.profile = {"error": repr(e)}
@@ -151,7 +157,8 @@ class Server:
             self.stop()
             raise
 
-    def window(self, seconds: float, rate: float = None, profile: bool = False) -> dict:
+    def window(self, seconds: float, rate: float = None, profile: bool = False,
+               profile_body: dict = None) -> dict:
         traffic, port = self.traffic, self.port
         plan = loadgen.build_plan(traffic, self.args.seed, seconds, self.vocab, rate=rate)
         lead = plan["lead_in_s"]
@@ -164,7 +171,7 @@ class Server:
         profile_s = 0.0
         if profile:
             profile_s = min(float(traffic.get("trace_s", 4.0)), max(0.5, seconds - 2.0))
-        side = Sidecar(port, w0, w1, profile_s)
+        side = Sidecar(port, w0, w1, profile_s, profile_body)
         side.start()
         loop = loadgen.OpenLoop(port, plan, float(traffic["deadline_s"]))
         loop.run(m0, stop_sending_at=w1, give_up_at=w1 + float(traffic["drain_s"]))
@@ -182,6 +189,24 @@ class Server:
                    before=side.before, after=side.after, profile=side.profile,
                    sidecar_errors=side.errors)
         return raw
+
+    def trace_stretch(self) -> dict:
+        """--trace 2, after the window has drained: wait until the server
+        is quiet, start and stop its profiler once (that trace is thrown
+        away: the first start costs most), then offer a short stretch of
+        the same mix with one capture in its middle.  The server neither
+        parses the trace nor runs the Python call tracer (TRACE_BODY)."""
+        if not self.quiet():
+            raise Fail("the server did not go quiet after the window")
+        code, body = common.http(self.port, "/admin/profile",
+                                 {"seconds": 0.2, **TRACE_BODY}, timeout=600)
+        if code != 200:
+            raise Fail(f"the profiler's first start: HTTP {code}: {body[:300]}")
+        shutil.rmtree(json.loads(body)["trace_dir"], ignore_errors=True)
+        raw = self.window(float(self.traffic.get("trace_s", 4.0)) + 2.0,
+                          profile=True, profile_body=TRACE_BODY)
+        return {k: raw[k] for k in ("w0", "w1", "requests", "profile", "sidecar_errors",
+                                    "drained_s")}
 
     def quiet(self, timeout: float = 180.0) -> bool:
         """Wait until the queue and the running batch are empty."""
@@ -219,19 +244,24 @@ class Server:
 
 
 REFERENCE_SEQUENCES = 4  # served sequences held against the reference per run
+# /admin/profile body of the --trace 2 captures: the trace is reduced by the
+# parent afterwards, and the program's pfx.* spans name the gaps
+TRACE_BODY = {"summary": False, "python_tracer": False}
 
 
 def run(cell: dict, args, t0: float) -> dict:
     server = Server(cell, args, t0)
     raw, served = None, None
     try:
-        raw = server.window(float(args.seconds), profile=bool(args.trace))
+        raw = server.window(float(args.seconds), profile=args.trace == 1)
         done = [r for r in raw["requests"] if r["phase"] == "window"
                 and r.get("status") == 200 and not r.get("error")
                 and len(r["tokens"]) == r["max_tokens"]]
         step = max(1, len(done) // REFERENCE_SEQUENCES)
         served = [{"idx": r["idx"], "prompt_ids": raw["plan_prompts"][r["idx"]],
                    "tokens": r["tokens"]} for r in done[::step][:REFERENCE_SEQUENCES]]
+        if args.trace == 2:
+            raw["trace_stretch"] = server.trace_stretch()
     finally:
         peak, ref = server.stop(served)
     raw.pop("plan_prompts")
@@ -309,12 +339,31 @@ def judge(cell: dict, raw: dict, args) -> dict:
     ref = raw.get("reference")  # absent in the knee sweep, which judges no tokens
     if "reference" in raw and not (ref and ref.get("ok")):
         notes.append(f"served tokens off the plain reference: {json.dumps(ref)[:1500]}")
-    trace_dir = None
+    trace_dir, stretch_info = None, None
     if args.trace:
-        prof = raw.get("profile") or {}
+        stretch = raw.get("trace_stretch") or {}
+        prof = (stretch if args.trace == 2 else raw).get("profile") or {}
         trace_dir = prof.get("trace_dir")
         if not trace_dir:
             notes.append(f"/admin/profile gave no trace: {prof}")
+        if args.trace == 2:
+            # nothing of the traced stretch is judged but that it was served
+            s_reqs = stretch.get("requests", [])
+            s_bad = [r for r in s_reqs if not ok(r)]
+            for r in s_bad[:3]:
+                notes.append(f"traced stretch: request {r['idx']} ({r['phase']}): status "
+                             f"{r.get('status')}, {len(r['tokens'])} tokens, "
+                             f"error {r.get('error')}")
+            if stretch.get("sidecar_errors"):
+                notes.append(f"traced stretch: sidecar errors: {stretch['sidecar_errors'][:3]}")
+            s_gaps = [(b - a) * 1e3 for r in s_reqs
+                      for (a, _), (b, _) in zip(r["frames"], r["frames"][1:])]
+            stretch_info = {
+                "requests": len(s_reqs), "failed": len(s_bad),
+                "itl_mean_ms": sum(s_gaps) / len(s_gaps) if s_gaps else None,
+                "gaps_over_500ms": sum(1 for g in s_gaps if g >= 500.0),
+                "capture_s": prof.get("seconds"),
+            }
     half = (w0 + w1) / 2
     first = [(r["frames"][0][0] - r["due"]) * 1e3 for r in window
              if r["frames"] and r["due"] < half]
@@ -336,6 +385,8 @@ def judge(cell: dict, raw: dict, args) -> dict:
         "boot_s": raw["boot_s"], "drained_s": raw["drained_s"],
         "prompt_buckets": raw["buckets"], "reference": ref,
     }
+    if stretch_info is not None:
+        info["trace_stretch"] = stretch_info
     if delta is not None:
         info["sched_time_delta_s"] = {
             k.split('"')[1]: round(v, 3) for k, v in delta.items()

@@ -2,7 +2,11 @@
 what it wrote.  Child half (``--child``): config -> mesh -> module -> Engine
 -> loader exactly as tools/train.py builds them, overrides from the cell's
 data files only; warm-up steps, then ``Engine.fit`` over a loader wrapper
-that stops handing out batches when the window's seconds are spent."""
+that stops handing out batches when the window's seconds are spent.  With
+``--trace 2`` the wrapper, once the window is closed and every number of a
+``--trace 0`` run is taken, arms the engine's profiler in the running fit
+(a throwaway window first, so the profiler's first start falls into no
+number) and hands out the few more batches the traced steps need."""
 
 import argparse
 import json
@@ -50,18 +54,35 @@ def run(cell: dict, args, t0: float) -> dict:
 # ===========================================================================
 
 
+# the Python call tracer of the --trace 2 captures (PERF.md section 6, PR 24:
+# off, the program's pfx.* spans and the runtime's events name the gaps)
+PYTHON_TRACER = False
+
+
 class WindowLoader:
     """Hands the engine ``warmup`` batches, then batches for ``seconds``
     seconds.  The window opens when the first post-warm-up batch is asked
     for (the device is drained first) and closes, after a
     ``block_until_ready`` on the train state, when a batch is asked for
-    after the seconds are spent."""
+    after the seconds are spent.  ``trace`` = (directory, steps) keeps the
+    iteration going after the close: ``engine.profiler`` is armed for one
+    throwaway step, then for ``steps`` steps into the directory (each
+    window needs one more batch than it traces: it starts at the step
+    boundary after the arming)."""
 
-    def __init__(self, inner, engine, warmup: int, seconds: float, compile_count):
+    def __init__(self, inner, engine, warmup: int, seconds: float, compile_count,
+                 trace=None):
         self.inner, self.engine = inner, engine
         self.warmup, self.seconds = warmup, seconds
         self.compile_count = compile_count
         self.handed = 0
+        self.window_steps = 0  # steps dispatched inside the window
+        # after the close: [(log_dir, steps)] still to arm, batches still owed
+        self._to_trace = []
+        if trace is not None:
+            trace_dir, steps = trace
+            self._to_trace = [(trace_dir + "-first-start", 1), (trace_dir, steps)]
+        self._owed = 0
         self.t_start = self.t_end = None  # time.time()
         self.m_start = self.m_end = None  # time.monotonic()
         self.compiles_start = self.compiles_end = None
@@ -85,14 +106,22 @@ class WindowLoader:
             self._fence()
             self.t_start, self.m_start = time.time(), time.monotonic()
             self.compiles_start = self.compile_count()
-        elif self.handed > self.warmup and \
-                time.monotonic() - self.m_start >= self.seconds:
+        elif self.m_end is not None or (
+                self.handed > self.warmup
+                and time.monotonic() - self.m_start >= self.seconds):
             self._close_window()
-            raise StopIteration
+            if not self._owed:
+                if not self._to_trace:
+                    raise StopIteration
+                log_dir, steps = self._to_trace.pop(0)
+                self.engine.profiler.arm(log_dir, steps, python_tracer=PYTHON_TRACER,
+                                         summary=False)
+                self._owed = steps + 1
+            self._owed -= 1
         try:
             batch = next(self._it)
         except StopIteration:
-            self.exhausted = True
+            self.exhausted = self.m_end is None  # inside the window, or before it
             if self.m_start is not None:
                 self._close_window()
             raise
@@ -104,10 +133,7 @@ class WindowLoader:
             self._fence()
             self.t_end, self.m_end = time.time(), time.monotonic()
             self.compiles_end = self.compile_count()
-
-    @property
-    def window_steps(self) -> int:
-        return max(0, self.handed - self.warmup)
+            self.window_steps = max(0, self.handed - self.warmup)
 
 
 def _load_reference():
@@ -256,11 +282,13 @@ def child(args) -> int:
         f"Engine.save_load.output_dir={os.path.join(work, 'out')}",
         f"Engine.metrics_file={metrics_path}",
     ]
+    a, b = traffic["trace_steps"]
     if args.trace:
         import shutil
 
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        a, b = traffic["trace_steps"]
+        for d in (trace_dir, trace_dir + "-first-start"):
+            shutil.rmtree(d, ignore_errors=True)
+    if args.trace == 1:
         overrides.append("Profiler={enable: True, scheduler: [%d, %d], log_dir: %s, "
                          "summary: False}" % (warmup + a, warmup + b, trace_dir))
     cfg = get_config(os.path.join(root, config["yaml"]), overrides=overrides)
@@ -275,8 +303,11 @@ def child(args) -> int:
         print("reference: " + json.dumps(ref), flush=True)
         loader = WindowLoader(
             build_dataloader(cfg, "Train", consumed_samples=engine._consumed_samples),
-            engine, warmup, float(args.seconds), lambda: len(watcher.snapshot()))
+            engine, warmup, float(args.seconds), lambda: len(watcher.snapshot()),
+            trace=(trace_dir, b - a) if args.trace == 2 else None)
         engine.fit(loader, None)
+        if args.trace == 2:
+            shutil.rmtree(trace_dir + "-first-start", ignore_errors=True)
         if loader.m_start is not None and loader.m_end is None:
             # the engine stopped at max_steps before the seconds were spent
             loader.exhausted = True
@@ -301,6 +332,7 @@ def child(args) -> int:
         "compile_events": len(watcher.snapshot()),
         "records": recs, "reference": ref,
         "trace_dir": trace_dir if args.trace else None,
+        "traces_taken": engine.profiler.traces,
     }
     with open(args.result, "w") as f:
         json.dump(result, f)
@@ -323,7 +355,9 @@ def judge(cell: dict, raw: dict, args) -> dict:
     """-> {"correct", "attempted", "failed", "values", "notes", "context"}."""
     recs = raw["records"]
     warm = raw["warmup_steps"]
-    window = [r for r in recs if r["step"] > warm]
+    last = warm + raw["window_steps"]
+    window = [r for r in recs if warm < r["step"] <= last]
+    traced = [r for r in recs if r["step"] > last]  # --trace 2: after the window
     notes = []
     bad_steps = [r["step"] for r in window
                  if not (math.isfinite(r["loss"]) and math.isfinite(r["grad_norm"]))
@@ -343,6 +377,9 @@ def judge(cell: dict, raw: dict, args) -> dict:
     if raw["loader_exhausted"]:
         notes.append("the loader ran out before the window's seconds were spent: "
                      "raise steps_per_s_hint in the traffic file")
+    if args.trace == 2 and raw.get("traces_taken") != 2:
+        notes.append(f"{raw.get('traces_taken')} profiler window(s) closed after the "
+                     "measured window, not the first start and the trace")
     if not raw["reference"]["ok"]:
         notes.append(f"logits or gradient off the plain reference: {raw['reference']}")
     want = "cpu" if args.rehearse else "tpu"
@@ -359,14 +396,15 @@ def judge(cell: dict, raw: dict, args) -> dict:
         "attempted": steps, "failed": len(bad_steps),
         "values": values, "notes": notes,
         "info": {"window_s": raw["window_s"], "steps": steps, "first_loss": first,
-                 "last_loss": recs[-1]["loss"] if recs else None,
+                 "last_loss": window[-1]["loss"] if window else None,
                  "reference_max_abs_err": raw["reference"]["max_abs_err"],
                  "reference_band": raw["reference"]["band"],
                  "reference_argmax_agree": raw["reference"]["argmax_agree"],
                  "reference_loss_diff": raw["reference"]["loss"] - raw["reference"]["reference_loss"],
                  "reference_grad_cosine": raw["reference"]["grad_cosine"],
                  "reference_grad_norm_rel_diff": raw["reference"]["grad_norm_rel_diff"],
-                 "step_s_min_median_max": _step_times(window)},
+                 "step_s_min_median_max": _step_times(window),
+                 "traced_step_s": [r["step_s"] for r in traced if "step_s" in r]},
         "context": {"engine_records": window, "engine_base_record": base,
                     "window_s": raw["window_s"], "chips": raw["chips"],
                     "tokens_per_step": raw["global_batch_size"] * raw["seq_len"],

@@ -10,7 +10,9 @@ tier-1):
 3. model_math.py against the parameter counts;
 4. loadgen.py: every seed gets the same sizes and gaps in another order;
 5. without a chip the command exits non-zero, in seconds, with no result;
-6. every cell end to end on the CPU at toy widths (--rehearse)."""
+6. every cell end to end on the CPU at toy widths (--rehearse), with
+   --trace 0 and --trace 2 (every other cell with --trace 1 too, and there
+   the --trace 2 line must carry what the other two carry together)."""
 
 import collections
 import json
@@ -49,8 +51,11 @@ def check_contract():
         text = f.read()
     bench = json.loads(text)
     check(len(text) <= 64 * 1024, "BENCHMARK.json is at most 64 KiB")
-    check(set(bench) == {"command", "paths", "run_seconds", "configs", "workloads",
-                         "end_to_end", "per_layer"}, "BENCHMARK.json has exactly its seven keys")
+    seven = {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end",
+             "per_layer"}
+    check(seven <= set(bench) <= seven | {"trace_in_run"},
+          "BENCHMARK.json has its seven keys (and, optionally, trace_in_run)")
+    check(isinstance(bench.get("trace_in_run", False), bool), "trace_in_run is a boolean")
     check(bench["paths"] == [common.BENCH_REL], f"paths is [{common.BENCH_REL}]")
     check(1 <= int(bench["run_seconds"]) <= 51, "run_seconds within 1..51")
     n_cells = 24  # the limit must hold with the full 24 cells
@@ -214,7 +219,8 @@ def check_no_chip(cells):
 
 def check_rehearsals(cells):
     for i, cell in enumerate(cells):
-        for trace in ((0, 1) if i % 2 == 0 else (0,)):
+        names = {}
+        for trace in ((0, 1, 2) if i % 2 == 0 else (0, 2)):
             rc, out, took = run_cmd(["--workload", cell, "--seed", "3000000007", "--seconds",
                                      "5", "--trace", str(trace), "--rehearse"], 900)
             line = last_json(out) or {}
@@ -226,6 +232,11 @@ def check_rehearsals(cells):
                       f"metrics {sorted(line.get('metrics', {}))}")
             if not ok:
                 print(out[-1500:])
+            names[trace] = set(line.get("metrics", {}))
+        if 1 in names:
+            check(names[2] >= names[0] | names[1],
+                  f"rehearse {cell}: the --trace 2 line carries every metric of the "
+                  f"--trace 0 and --trace 1 lines (lacks {sorted((names[0] | names[1]) - names[2])})")
 
 
 def main():

@@ -15,6 +15,13 @@ if "host_platform_device_count" not in flags:
 # entry-point modules imported in-process and every subprocess a test
 # spawns resolve the same platform through utils/device.resolve_platform.
 os.environ["PFX_PLATFORM"] = "cpu"
+# Every hit of the persistent compile cache makes XLA:CPU log two ~3 KB
+# error lines (cpu_aot_loader.cc: "+prefer-no-scatter is not supported on
+# the host machine"), also for entries this machine compiled.  A drill that
+# keeps a server's output in a pipe it reads only at the end then blocks the
+# server at 64 KB, before /healthz: on a warm cache a replica prints 80 KB
+# while it boots.  Silence the native logs for this process and its children.
+os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
 
 import jax  # noqa: E402
 import pytest  # noqa: E402

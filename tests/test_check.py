@@ -130,16 +130,8 @@ def test_engine_runs_consistency_check(devices8, monkeypatch):
     )
     with mesh:
         engine = Engine(cfg, module, mesh)
-        engine._fit_loop(loader(), None, 16, _NoProfiler(), 0.0, 0)
+        engine._fit_loop(loader(), None, 16, 0.0, 0)
     assert len(calls) == 2  # freq=1 over 2 steps
-
-
-class _NoProfiler:
-    def step(self, _):
-        pass
-
-    def close(self):
-        pass
 
 
 def test_fingerprint_detects_transposition():

@@ -387,6 +387,16 @@ def check_file(path):
                 f"metric '{name}' not declared in telemetry.METRICS "
                 "(the one namespace table — declare it there)")
 
+    # a Pallas kernel's ``name=`` is its label in the device trace
+    # (pfx_flash_fwd, ...): shaped like a metric name, not one
+    kernel_names = {
+        id(kw.value)
+        for node in ast.walk(tree)
+        if isinstance(node, ast.Call)
+        and getattr(node.func, "attr", getattr(node.func, "id", "")) == "pallas_call"
+        for kw in node.keywords if kw.arg == "name"
+    }
+
     for node in ast.walk(tree):
         # E3 bare except
         if isinstance(node, ast.ExceptHandler) and node.type is None:
@@ -406,6 +416,7 @@ def check_file(path):
             isinstance(node, ast.Constant)
             and isinstance(node.value, str)
             and _METRIC_RE.match(node.value)
+            and id(node) not in kernel_names
         ):
             _check_metric_name(node.lineno, node.value)
         # E7 eval/exec

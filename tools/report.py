@@ -946,6 +946,8 @@ _PROFILE_COLS = ("op", "category", "#", "total us", "self us", "self %")
 
 
 def profile_caption(profile: Dict[str, Any]) -> str:
+    # busy microseconds (the union of each plane's events, summed over
+    # planes: utils/profiler.device_host_split), shown beside each other
     dev = float(profile.get("device_us", 0) or 0)
     host = float(profile.get("host_us", 0) or 0)
     tot = (dev + host) or 1.0
@@ -956,8 +958,8 @@ def profile_caption(profile: Dict[str, Any]) -> str:
     return (
         f"{profile.get('seconds', '?')}s capture on {who}, "
         f"source: {profile.get('source', 'fleet aggregate')}; "
-        f"device {dev / 1e6:.3f}s ({100 * dev / tot:.1f}%) / "
-        f"host {host / 1e6:.3f}s ({100 * host / tot:.1f}%)"
+        f"device busy {dev / 1e6:.3f}s ({100 * dev / tot:.1f}%) / "
+        f"host busy {host / 1e6:.3f}s ({100 * host / tot:.1f}%)"
     )
 
 

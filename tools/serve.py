@@ -973,7 +973,10 @@ def serve_http(server, port: int, host: str = "127.0.0.1", *,
             """POST /admin/profile {"seconds": T} — capture a
             jax.profiler trace of THIS live serving process and answer
             with the parsed summary (docs/observability.md "On-demand
-            profiling").  Safety rails live in
+            profiling").  ``"summary": false`` skips the in-process
+            parse (the reply carries ``trace_dir`` and ``seconds``
+            only); ``"python_tracer": false`` keeps the profiler's
+            Python call tracer off.  Safety rails live in
             utils/profiler.capture_profile: one capture at a time
             (ProfileBusy -> 409) and the PFX_PROFILE_MAX_SECONDS hard
             cap (-> 400).  The capture observes the running scheduler —
@@ -998,7 +1001,11 @@ def serve_http(server, port: int, host: str = "127.0.0.1", *,
                 time.strftime("%Y%m%d-%H%M%S"),
             )
             try:
-                summary = capture_profile(seconds, prof_dir, top=top)
+                summary = capture_profile(
+                    seconds, prof_dir, top=top,
+                    summary=bool(req.get("summary", True)),
+                    python_tracer=bool(req.get("python_tracer", True)),
+                )
             except ProfileBusy as e:
                 print(f"[serve] /admin/profile refused: {e}", flush=True)
                 return self._json(409, {"error": str(e)})
@@ -1015,7 +1022,9 @@ def serve_http(server, port: int, host: str = "127.0.0.1", *,
                 "event": "profile_capture",
                 "seconds": summary["seconds"],
                 "trace_dir": prof_dir,
-                "source": summary["source"],
+                "source": summary.get("source", "not parsed"),
+                "started_monotonic_ns": summary["started_monotonic_ns"],
+                "started_time_ns": summary["started_time_ns"],
             })
             return self._json(200, summary)
 
