@@ -551,7 +551,9 @@ def phase_kernels(args) -> int:
             kp, vp = rand((nb, n, bs, d)), rand((nb, n, bs, d))
             tables = jnp.asarray(
                 rng.permutation(np.arange(1, nb)).reshape(rows, M), jnp.int32)
-            for t in (1, 5):
+            # t 80: a prefill chunk through the paged path, two query
+            # tiles of the kernel, the second ragged
+            for t in (1, 5, 80):
                 qd = rand((rows, t, n, d))
                 positions = jnp.asarray(
                     rng.integers(0, s - t, rows), jnp.int32).at[0].set(s - t)

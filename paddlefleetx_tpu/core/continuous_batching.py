@@ -93,7 +93,10 @@ from paddlefleetx_tpu.core.request_queue import (
     QueueFull,
     RequestFuture,
 )
-from paddlefleetx_tpu.ops.decode_attention import kv_cache_dtype
+from paddlefleetx_tpu.ops.decode_attention import (
+    kv_cache_dtype,
+    paged_tokens_computed,
+)
 from paddlefleetx_tpu.ops.speculative import SpecConfig, ngram_propose_host
 from paddlefleetx_tpu.parallel.sharding import place_on_mesh
 from paddlefleetx_tpu.utils.log import logger
@@ -343,9 +346,10 @@ class PagedDecodeEngine:
         # ledger's admission side, folded by _fold_admitted())
         # (work counters, one update per committed decode step:
         # "row_steps"/"slot_steps" = live rows / capacity, "kv_tokens" =
-        # the live rows' context lengths, "grid_tokens" = capacity x
-        # table-width bucket x block size, the KV tokens per head the
-        # paged kernel's grid walks whatever the rows hold)
+        # the live rows' context lengths, "grid_tokens" = the KV tokens
+        # per head the paged kernel computed on: every slot's context
+        # rounded up to the kernel's grid step — an empty slot costs one
+        # step; grid steps past a row's context run nothing)
         self.stats: Dict[str, Any] = {
             "traces": 0, "steps": 0, "prefills": 0,
             "spec_proposed": 0, "spec_accepted": 0,
@@ -1646,14 +1650,15 @@ class PagedDecodeEngine:
         finished: List[int] = []
         n_act = int(was_active.sum())
         # work counters: what this step needed (live rows, their context)
-        # against what its fixed-shape program walked (every slot, every
-        # block of the table-width bucket)
+        # against what the paged kernel computed on (every slot — the
+        # step ran them all at the positions it started from — up to its
+        # context, in whole grid steps)
         self.stats["row_steps"] += n_act
         self.stats["slot_steps"] += self.capacity
         self.stats["kv_tokens"] += int(self.positions[was_active].sum())
-        self.stats["grid_tokens"] += (
-            self.capacity * fl["width_bucket"] * self.block
-        )
+        self.stats["grid_tokens"] += int(paged_tokens_computed(
+            positions - ncommit, fl["k"] + 1, self.block, fl["width_bucket"]
+        ).sum())
         t_chunk = time.monotonic()
         for i, r in enumerate(fl["rows"]):
             if r is None or not was_active[i]:

@@ -32,9 +32,10 @@ Two implementations behind one entry point:
     tail would matmul against out-of-bounds padding (0 * NaN poisons the
     accumulator even under the mask).  The paged spelling
     (:func:`paged_decode_attention`, used by the continuous-batching
-    engine) retires this: its scalar-prefetch-clamped index map DMAs
-    exactly one pool block per grid step, so HBM reads scale with each
-    row's real length.
+    engine) retires this: one grid step per (row, group of pages) with
+    every head inside, scalar-prefetch-clamped index maps that DMA only
+    the pages a row holds, and no work in a grid step past a row's
+    context — HBM reads and compute scale with each row's real length.
   - ``lax``: the same blocked loop as ``lax.fori_loop`` +
     ``dynamic_slice`` — what ``auto`` means on the CPU, and the spelling
     the generation layer picks under GSPMD sharding (a pallas_call inside
@@ -561,24 +562,69 @@ def _paged_lax(q_t, k_pool, v_pool, tables, positions, scale,
     return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
+# KV tokens one grid step of the paged kernel walks for a row: a group of
+# pages this wide (8 pages of 16 slots, one of 128) makes each head's
+# score product a full [t, d] x [d, 128] MXU pass and the step's DMA (all
+# heads of the group, K and V) large beside the grid step's fixed cost
+PAGED_STEP_TOKENS = 128
+# query slots one grid step holds: the decode step (1) and the verify
+# chunk (k + 1) are one tile; a prefill chunk through the paged path is
+# cut into tiles so the scores [n, tile, 128] stay small in VMEM
+_PAGED_Q_TILE = 64
+
+
+def paged_pages_per_step(block: int, width: int) -> int:
+    """Pages (pool blocks of ``block`` slots) one grid step of the paged
+    kernel walks, for a block table ``width`` pages wide — chosen from
+    the shapes alone: fewer pages as the page grows."""
+    return max(1, min(PAGED_STEP_TOKENS // block, width))
+
+
+def paged_tokens_computed(positions, t: int, block: int, width: int):
+    """KV tokens per head the paged kernel computes on for rows whose
+    first query sits at slot ``positions`` (array): each row's context
+    ``positions + t`` rounded up to whole grid steps (a slot at position
+    0 — an empty one — costs one), never past the table.  The scheduler's
+    ``pfx_sched_decode_grid_tokens_total`` sums this over a step's slots."""
+    pages = paged_pages_per_step(block, width)
+    step = pages * block
+    steps = -(-width // pages)
+    last_page = (positions + (t - 1)).clip(0) // block
+    return (last_page // pages + 1).clip(None, steps) * step
+
+
+def _paged_last_page(pos, qt, *, t, tq, bs):
+    """The last page query tile ``qt`` of a row at ``pos`` needs: the one
+    that holds the tile's LAST query slot."""
+    return jnp.maximum(pos + jnp.minimum((qt + 1) * tq, t) - 1, 0) // bs
+
+
 def _paged_kernel(
-    tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-    *, scale, bs, t, ks_ref=None, vs_ref=None
+    tables_ref, pos_ref, q_ref, *refs, scale, bs, t, tq, pages, width, quant
 ):
-    """One (batch, head, block) grid step.  The kv BlockSpec's index_map
-    already DMA'd pool block ``tables[i, min(j, last_needed(i))]`` — the
-    scalar-prefetch CLAMP: grid steps past a row's limit re-address the
-    previously fetched block (no new DMA) and are fully masked here, so
-    HBM traffic scales with the tokens the row actually holds, not with
-    the padded table width.  ``t`` > 1 is the speculative verify chunk:
-    query qi sits at slot pos + qi, causal within the chunk.  With int8
-    pools the optional scale refs dequantize in-kernel: the scores
-    absorb the per-key scale column-wise and the probabilities the
-    per-value scale — the dequantized block never materializes."""
+    """One (row, query tile, page group) grid step, every head inside.
+
+    ``refs`` = ``pages`` K blocks, ``pages`` V blocks (each [1, n, bs, d]:
+    one whole pool page, contiguous in HBM), with int8 pools ``pages`` +
+    ``pages`` scale rows [1, n, 1, bs], then o_ref and the acc / m / l
+    scratch.  The index maps already DMA'd pages ``tables[i, min(j * pages
+    + p, last_needed(i))]`` — the scalar-prefetch CLAMP: past a row's
+    last needed page they re-address the page already held (no new DMA).
+    A group that starts past the row's last page runs NOTHING (``pl.when``):
+    it costs the grid step's fixed overhead and neither load, product nor
+    store.  Inside the last needed group the pages past the row's context
+    are masked by the per-query causal bound.  ``t`` > 1 is the
+    speculative verify chunk: query qi sits at slot pos + qi, causal
+    within the chunk.  With int8 pools the scores absorb the per-key
+    scale column-wise and the probabilities the per-value scale — the
+    dequantized block never materializes."""
+    kv = refs[: (4 if quant else 2) * pages]
+    o_ref, acc_ref, m_ref, l_ref = refs[len(kv):]
     i = pl.program_id(0)
+    qt = pl.program_id(1)
     j = pl.program_id(2)
-    nblk = pl.num_programs(2)
-    quant = ks_ref is not None
+    pos = pos_ref[i]
+    last = _paged_last_page(pos, qt, t=t, tq=tq, bs=bs)
 
     @pl.when(j == 0)
     def _init():
@@ -586,42 +632,53 @@ def _paged_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0]  # [t, d]
-    k = k_ref[0, 0]  # [bs, d] (one pool block for this head)
-    v = v_ref[0, 0]
-    if quant:
-        q = q.astype(jnp.float32)
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
-    pos = pos_ref[i]
-    s = scale * jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [t, bs]
-    if quant:
-        s = s * ks_ref[0, 0]  # [1, bs] row
-    col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (t, bs), 1)
-    # query qi's own causal bound: slot pos + qi
-    qrow = pos + jax.lax.broadcasted_iota(jnp.int32, (t, bs), 0)
-    mask = col <= qrow
-    s = jnp.where(mask, s, NEG_INF)
+    @pl.when(j * pages <= last)
+    def _group():
+        def group(refs_, axis):
+            blocks = [r[0] for r in refs_]
+            return blocks[0] if pages == 1 else jnp.concatenate(blocks, axis)
 
-    m_prev = m_ref[:, :1]  # [t, 1] (lane-replicated store)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-    pv = p * vs_ref[0, 0] if quant else p.astype(v.dtype)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pv, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        q = q_ref[0]  # [n, tq, d]
+        k = group(kv[:pages], 1)  # [n, pages * bs, d]
+        v = group(kv[pages: 2 * pages], 1)
+        if quant:
+            q = q.astype(jnp.float32)
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
+        s = scale * jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [n, tq, pages * bs], batched over heads
+        if quant:
+            s = s * group(kv[2 * pages: 3 * pages], 2)  # [n, 1, pages * bs]
+        shape = (1, tq, pages * bs)
+        col = j * (pages * bs) + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+        # query qi's own causal bound: slot pos + qi
+        qrow = pos + qt * tq + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = col <= qrow
+        if width % pages:
+            # the last group hangs over the table: its spare pages
+            # re-address the table's last page and must not count twice
+            mask = mask & (col < width * bs)
+        s = jnp.where(mask, s, NEG_INF)
 
-    @pl.when(j == nblk - 1)
+        m_prev = m_ref[:, :, :1]  # [n, tq, 1] (lane-replicated store)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :, :1] * alpha + p.sum(axis=-1, keepdims=True)
+        pv = p * group(kv[3 * pages:], 2) if quant else p.astype(v.dtype)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            pv, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _done():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0] = (
+            acc_ref[...] / jnp.maximum(l_ref[:, :, :1], 1e-30)
         ).astype(o_ref.dtype)
 
 
@@ -635,55 +692,49 @@ def _paged_pallas(q_t, k_pool, v_pool, tables, positions, scale,
     tables = tables.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
     quant = k_scale is not None
+    pages = paged_pages_per_step(bs, M)
+    tq = min(t, _PAGED_Q_TILE)
 
-    def kv_index(i, j, k, tables_ref, pos_ref):
-        # scalar-prefetch clamp: past a row's last needed block, re-address
-        # the block we already fetched — Pallas skips the DMA when the
-        # index is unchanged between consecutive grid steps.  The last
-        # needed block covers the chunk's LAST query slot (pos + t - 1).
-        last = jnp.maximum(pos_ref[i] + (t - 1), 0) // bs
-        return tables_ref[i, jnp.minimum(k, last)], j, 0, 0
+    def page_index(p):
+        def index(i, qt, j, tables_ref, pos_ref):
+            # scalar-prefetch clamp: past the last page this query tile
+            # needs, re-address the page already fetched — Pallas skips
+            # the DMA when the index is unchanged between consecutive
+            # grid steps
+            last = _paged_last_page(pos_ref[i], qt, t=t, tq=tq, bs=bs)
+            page = jnp.minimum(j * pages + p, jnp.minimum(last, M - 1))
+            return tables_ref[i, page], 0, 0, 0
+        return index
 
-    def scl_index(i, j, k, tables_ref, pos_ref):
-        # same clamped pool-block address; the scale planes enter as
-        # [nb, n, 1, bs] so the (1, bs) tile equals the array's last two
-        # dims (the (8, 128) rule refuses a (1, bs) tile of [nb, n, bs])
-        last = jnp.maximum(pos_ref[i] + (t - 1), 0) // bs
-        return tables_ref[i, jnp.minimum(k, last)], j, 0, 0
-
-    in_specs = [
-        pl.BlockSpec((1, 1, t, d), lambda i, j, k, *_: (i, j, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d), kv_index),
-        pl.BlockSpec((1, 1, bs, d), kv_index),
+    q_spec = pl.BlockSpec((1, n, tq, d), lambda i, qt, j, *_: (i, 0, qt, 0))
+    page_specs = [
+        pl.BlockSpec((1, n, bs, d), page_index(p)) for p in range(pages)
     ]
-    operands = [q_t, k_pool, v_pool]
+    in_specs = [q_spec] + page_specs * 2
+    operands = [q_t] + [k_pool] * pages + [v_pool] * pages
     if quant:
+        # the scale planes enter as [nb, n, 1, bs] so the (1, bs) tile
+        # equals the array's last two dims (the (8, 128) rule refuses a
+        # (1, bs) tile of [nb, n, bs]); same clamped page address
         in_specs += [
-            pl.BlockSpec((1, 1, 1, bs), scl_index),
-            pl.BlockSpec((1, 1, 1, bs), scl_index),
-        ]
-        operands += [k_scale[:, :, None], v_scale[:, :, None]]
-
-        def kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                   o_ref, acc_ref, m_ref, l_ref):
-            _paged_kernel(
-                tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                acc_ref, m_ref, l_ref, scale=scale, bs=bs, t=t,
-                ks_ref=ks_ref, vs_ref=vs_ref,
-            )
-    else:
-        kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, t=t)
+            pl.BlockSpec((1, n, 1, bs), page_index(p)) for p in range(pages)
+        ] * 2
+        operands += [k_scale[:, :, None]] * pages + [v_scale[:, :, None]] * pages
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n, M),
+        grid=(b, -(-t // tq), -(-M // pages)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, t, d), lambda i, j, k, *_: (i, j, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((t, d), jnp.float32),
-            pltpu.VMEM((t, 128), jnp.float32),
-            pltpu.VMEM((t, 128), jnp.float32),
+            pltpu.VMEM((n, tq, d), jnp.float32),
+            pltpu.VMEM((n, tq, 128), jnp.float32),
+            pltpu.VMEM((n, tq, 128), jnp.float32),
         ],
+    )
+    kernel = functools.partial(
+        _paged_kernel, scale=scale, bs=bs, t=t, tq=tq, pages=pages,
+        width=M, quant=quant,
     )
     return pl.pallas_call(
         kernel,
@@ -725,10 +776,13 @@ def paged_decode_attention(
     scale tiles DMA with their block) — pass both or neither.
 
     ``impl``: "auto" (pallas on a TPU, lax on the CPU) | "pallas" | "lax".
-    The pallas spelling DMAs exactly one pool block per grid step with a
-    scalar-prefetch-clamped index map — the HBM reads scale with each
-    row's real length, retiring the known limit of `_decode_pallas`
-    (which streams the whole cache row).  The lax spelling gathers via
+    The pallas spelling runs one grid step per (row, query tile, group of
+    pages) with every head inside: a step DMAs whole pool pages
+    ([n, block, d], contiguous; :func:`paged_pages_per_step` of them)
+    through scalar-prefetch-clamped index maps, and a step past a row's
+    context runs nothing — HBM reads and compute scale with each row's
+    real length, retiring the known limit of `_decode_pallas` (which
+    streams the whole cache row).  The lax spelling gathers via
     ``jnp.take`` (XLA partitions it freely under GSPMD).
     """
     if impl not in ("auto", "pallas", "lax"):

@@ -261,7 +261,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_sched_decode_row_steps_total": ("counter", "Live rows summed over decode steps (numerator of batch occupancy)"),
     "pfx_sched_decode_slot_steps_total": ("counter", "Batch capacity summed over decode steps (denominator of batch occupancy)"),
     "pfx_sched_decode_kv_tokens_total": ("counter", "Context tokens of the live rows summed over decode steps (what a step needed to read)"),
-    "pfx_sched_decode_grid_tokens_total": ("counter", "capacity x table-width bucket x block size summed over decode steps (KV tokens per head the paged kernel's grid walks)"),
+    "pfx_sched_decode_grid_tokens_total": ("counter", "KV tokens per head the paged kernel computed on, summed over decode steps: every slot's context rounded up to the kernel's grid step (an empty slot costs one step; grid steps past a row's context run nothing)"),
     "pfx_train_host_gap_seconds_total": ("counter", "Fit-loop seconds from a blocking log fetch returning to the next step's dispatch having returned (the device has nothing queued)"),
     "pfx_token_ledger_total": ("counter", "Admitted-token dispositions (labels: disposition=admitted|delivered|evicted_lost|preempt_refunded|shed_after_admit)"),
     "pfx_token_ledger_in_flight": ("gauge", "Admitted tokens still on the books in live decode slots (the exact-closure remainder)"),

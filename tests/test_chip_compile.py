@@ -194,6 +194,30 @@ def test_paged_decode_compiles(topo, d, t, bs, kv_dtype):
     assert _has_kernel(c)
 
 
+@pytest.mark.parametrize("rows,t,width", [
+    # serve-1.3b-docs as the benchmark runs it: 8 rows, 513 pool blocks
+    # (the auto arena), table-width buckets 64 and 32
+    pytest.param(8, 1, 64, id="docs-cell"),
+    pytest.param(8, 1, 32, id="docs-cell-w32"),
+    # --cb-batch 32; a table narrower than one page group
+    pytest.param(32, 1, 64, id="cb32"),
+    pytest.param(8, 1, 4, id="w4"),
+    # a prefill chunk through the paged path (prefix reuse): one row, a
+    # whole prompt bucket of queries, cut into query tiles by the kernel
+    pytest.param(1, 1024, 64, id="chunk-1024"),
+])
+def test_paged_decode_compiles_at_serving_shapes(topo, rows, t, width):
+    from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
+
+    one = _one_chip(topo)
+    q = _shapes(one, ((rows, t, HEADS, 128), BF16))
+    pool = _shapes(one, ((513, HEADS, 16, 128), BF16))
+    tables = _shapes(one, ((rows, width), jnp.int32))
+    positions = _shapes(one, ((rows,), jnp.int32))
+    c = _compile(paged_decode_attention, q, pool, pool, tables, positions)
+    assert _has_kernel(c)
+
+
 def test_misaligned_cache_is_refused_not_rerouted(topo):
     """On a TPU ``impl="auto"`` is the kernel or an error — never lax."""
     from paddlefleetx_tpu.ops.decode_attention import decode_attention

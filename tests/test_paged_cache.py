@@ -139,23 +139,83 @@ def test_manager_exhaustion_keeps_bookkeeping_consistent():
 LAX = pytest.param("lax", id="lax")
 PALLAS = pytest.param("pallas", id="pallas", marks=pytest.mark.slow)
 
+# (impl, shape) cases: the first two are the kernel's original cases
+# (pallas interpreted: slow); the others are shapes the grid of PR 25
+# has to get right — every head of a row in one grid step, a group of
+# pages (128 tokens) a step, nothing run past a row's context — small
+# enough to interpret inside the default run.  ``pos`` is each row's
+# query slot (context pos + 1); a table row of None is an empty slot
+# (all null blocks, position 0).
+_BASE = dict(n=2, d=8, bs=8, M=4, pos=[17, 9, 30])
+_SHAPES = {
+    # 345M / 1.3B head shapes: 16 heads of 64 / 128, pages of 16
+    "n16_d64": dict(n=16, d=64, bs=16, M=8, pos=[17, 100, 127]),
+    "n16_d128": dict(n=16, d=128, bs=16, M=8, pos=[64, 3, 126]),
+    # a context that ends exactly on a page edge (32, 16 tokens), on a
+    # page-group edge (128 = 8 pages), and one token past each
+    "page_edge": dict(n=2, d=8, bs=16, M=16, pos=[31, 15, 32, 16]),
+    "group_edge": dict(n=2, d=8, bs=16, M=16, pos=[127, 128, 255, 129]),
+    "length_1": dict(n=2, d=8, bs=16, M=8, pos=[0, 0, 5]),
+    # an empty slot (null table, position 0) beside a full-width row
+    "null_slot": dict(n=2, d=8, bs=16, M=16, pos=[0, 255, 40], null=[0]),
+    # table width not a multiple of the page group (12 pages, groups of 8)
+    "ragged_width": dict(n=2, d=8, bs=16, M=12, pos=[191, 130, 127, 7]),
+    # one page a grid step (a page as wide as the group), two pages
+    "page_128": dict(n=2, d=8, bs=128, M=2, pos=[200, 127, 128]),
+    "page_64": dict(n=2, d=8, bs=64, M=4, pos=[255, 63, 64, 129]),
+    "int8": dict(n=4, d=16, bs=16, M=12, pos=[100, 191, 15, 128], int8=True),
+    "int8_n16_d128": dict(n=16, d=128, bs=16, M=8, pos=[127, 40], int8=True),
+}
+CASES = (
+    [pytest.param("lax", _BASE, id="lax"),
+     pytest.param("pallas", _BASE, id="pallas", marks=pytest.mark.slow)]
+    + [pytest.param(impl, shape, id=f"{impl}-{name}")
+       for name, shape in _SHAPES.items() for impl in ("lax", "pallas")]
+)
 
-def _paged_case(rng, b, n, d, bs, M, nb):
+
+def _paged_case(rng, b, n, d, bs, M, nb, t=1, null=(), int8=False):
+    """-> q [b, t, n, d], pools, tables, scale kwargs ({} unless int8)."""
     import jax.numpy as jnp
+
+    from paddlefleetx_tpu.ops.decode_attention import quantize_kv
 
     k_pool = jnp.asarray(rng.normal(size=(nb, n, bs, d)), jnp.float32)
     v_pool = jnp.asarray(rng.normal(size=(nb, n, bs, d)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(b, 1, n, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, t, n, d)), jnp.float32)
     # disjoint per-row tables, shuffled so pool order != logical order
     ids = rng.permutation(np.arange(1, nb))[: b * M].reshape(b, M)
+    for r in null:
+        ids[r] = NULL_BLOCK
     tables = jnp.asarray(ids, jnp.int32)
-    return q, k_pool, v_pool, tables
+    scales = {}
+    if int8:
+        k_pool, ks = quantize_kv(k_pool)
+        v_pool, vs = quantize_kv(v_pool)
+        scales = {"k_scale": ks, "v_scale": vs}
+    return q, k_pool, v_pool, tables, scales
 
 
-def _dense_ref(q, k_pool, v_pool, tables, positions):
+def _shape_case(rng, shape, t=1):
+    import jax.numpy as jnp
+
+    shape = dict(shape)
+    pos = shape.pop("pos")
+    b, M = len(pos), shape["M"]
+    q, k_pool, v_pool, tables, scales = _paged_case(
+        rng, b=b, nb=b * M + 1, t=t, **shape
+    )
+    return q, k_pool, v_pool, tables, jnp.asarray(pos, jnp.int32), scales
+
+
+def _dense_ref(q, k_pool, v_pool, tables, positions, k_scale=None,
+               v_scale=None):
     import jax
     import jax.numpy as jnp
 
+    if k_scale is not None:
+        k_pool = k_pool.astype(jnp.float32) * k_scale[..., None]
+        v_pool = v_pool.astype(jnp.float32) * v_scale[..., None]
     d = q.shape[-1]
     outs = []
     for r in range(q.shape[0]):
@@ -169,78 +229,148 @@ def _dense_ref(q, k_pool, v_pool, tables, positions):
     return jnp.stack(outs)[:, None]
 
 
-@pytest.mark.parametrize("impl", [LAX, PALLAS])
-def test_paged_attention_matches_dense_gather(impl):
-    import jax.numpy as jnp
-
+@pytest.mark.parametrize("impl,shape", CASES)
+def test_paged_attention_matches_dense_gather(impl, shape):
     from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
 
     rng = np.random.default_rng(0)
-    q, k_pool, v_pool, tables = _paged_case(rng, b=3, n=2, d=8, bs=8, M=4, nb=16)
-    positions = jnp.asarray([17, 9, 30], jnp.int32)  # per-row lengths differ
-    got = paged_decode_attention(q, k_pool, v_pool, tables, positions, impl=impl)
-    want = _dense_ref(q, k_pool, v_pool, tables, positions)
+    q, k_pool, v_pool, tables, positions, scales = _shape_case(rng, shape)
+    got = paged_decode_attention(
+        q, k_pool, v_pool, tables, positions, impl=impl, **scales
+    )
+    want = _dense_ref(q, k_pool, v_pool, tables, positions, **scales)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
-@pytest.mark.parametrize("impl", [LAX, PALLAS])
-def test_paged_attention_never_reads_past_a_rows_limit(impl):
+@pytest.mark.parametrize("impl,shape", CASES)
+def test_paged_attention_never_reads_past_a_rows_limit(impl, shape):
     """NaN-poison proof (the PR 1 convention): every pool block WHOLLY
     beyond a row's visit bound ``ceil((pos+1)/bs)`` is poisoned with NaN
-    — table padding a fori bound or a DMA clamp must never gather.  The
-    kernel must stay finite AND equal the unpoisoned result, or it read
-    blocks it has no business touching.  (Within a visited block, masked
-    tail slots follow the stale-tail contract: they hold stale-but-
-    finite values in real traffic, same as the contiguous kernel.)"""
+    — table padding a fori bound or a DMA clamp must never gather, and
+    a grid step past a row's last page must load, multiply and store
+    nothing.  The result must EQUAL the unpoisoned one to the bit, or
+    the kernel touched blocks it has no business touching.  (int8 pools
+    cannot hold a NaN: their scale planes carry the poison.  Within a
+    visited block, masked tail slots follow the stale-tail contract:
+    they hold stale-but-finite values in real traffic, same as the
+    contiguous kernel.)"""
     import jax.numpy as jnp
 
     from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
 
     rng = np.random.default_rng(1)
-    bs, M = 8, 4
-    q, k_pool, v_pool, tables = _paged_case(rng, b=2, n=2, d=8, bs=bs, M=M, nb=12)
-    positions = jnp.asarray([10, 3], jnp.int32)
-    clean = paged_decode_attention(q, k_pool, v_pool, tables, positions, impl=impl)
-
-    kp, vp = np.array(k_pool), np.array(v_pool)
-    for r, pos in enumerate([10, 3]):
-        first_unvisited = -(-(pos + 1) // bs)
-        for j in range(first_unvisited, M):
-            blk = int(tables[r, j])
-            kp[blk] = np.nan
-            vp[blk] = np.nan
-    poisoned = paged_decode_attention(
-        q, jnp.asarray(kp), jnp.asarray(vp), tables, positions, impl=impl
-    )
-    assert np.all(np.isfinite(np.asarray(poisoned)))
-    np.testing.assert_allclose(
-        np.asarray(poisoned), np.asarray(clean), atol=1e-6
+    q, k_pool, v_pool, tables, positions, scales = _shape_case(rng, shape)
+    bs, M = k_pool.shape[2], tables.shape[1]
+    clean = paged_decode_attention(
+        q, k_pool, v_pool, tables, positions, impl=impl, **scales
     )
 
+    past = []
+    for r, pos in enumerate(np.asarray(positions)):
+        first_unvisited = -(-(int(pos) + 1) // bs)
+        # (an empty slot's null block is shared padding: never poisoned)
+        past += [int(tables[r, j]) for j in range(first_unvisited, M)
+                 if int(tables[r, j]) != NULL_BLOCK]
+    assert past
+    if scales:
+        poisoned = {k: v.at[np.array(past)].set(np.nan)
+                    for k, v in scales.items()}
+        args = (k_pool, v_pool)
+    else:
+        kp, vp = np.array(k_pool), np.array(v_pool)
+        kp[past] = np.nan
+        vp[past] = np.nan
+        poisoned, args = {}, (jnp.asarray(kp), jnp.asarray(vp))
+    got = paged_decode_attention(
+        q, *args, tables, positions, impl=impl, **poisoned
+    )
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
 
-@pytest.mark.parametrize("impl", [LAX, PALLAS])
-def test_paged_attention_multi_token_chunk_is_causal(impl):
+
+@pytest.mark.parametrize("impl,shape", CASES)
+def test_paged_attention_multi_token_chunk_is_causal(impl, shape):
     """t > 1 (the speculative verify chunk): query qi of row r attends
     its logical slots [0, positions[r] + qi + 1) — each chunk query must
-    equal a t=1 call at its own position (same cache, shifted limit)."""
+    equal a t=1 call at its own position (same cache, shifted limit).
+    The chunk starts 2 slots before each case's position, so where that
+    sits on a page or group edge the chunk straddles it."""
     import jax.numpy as jnp
 
     from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
 
     rng = np.random.default_rng(2)
     t = 3
-    q, k_pool, v_pool, tables = _paged_case(rng, b=2, n=2, d=8, bs=8, M=4, nb=12)
-    qt = jnp.asarray(rng.normal(size=(2, t, 2, 8)).astype(np.float32))
-    positions = jnp.asarray([9, 3], jnp.int32)
-    got = paged_decode_attention(qt, k_pool, v_pool, tables, positions, impl=impl)
+    qt, k_pool, v_pool, tables, positions, scales = _shape_case(rng, shape, t=t)
+    positions = jnp.maximum(positions - (t - 1), 0)
+    got = paged_decode_attention(
+        qt, k_pool, v_pool, tables, positions, impl=impl, **scales
+    )
     for qi in range(t):
         one = paged_decode_attention(
             qt[:, qi : qi + 1], k_pool, v_pool, tables, positions + qi,
-            impl=impl,
+            impl=impl, **scales
         )
         np.testing.assert_allclose(
             np.asarray(got[:, qi : qi + 1]), np.asarray(one), atol=2e-5
         )
+
+
+@pytest.mark.parametrize("impl", ["lax", "pallas"])
+def test_paged_attention_prefill_chunk_wider_than_a_query_tile(impl):
+    """A prefill chunk through the paged path (prefix reuse, chunked
+    prefill) is wider than the kernel's query tile: 80 queries are two
+    tiles, the second ragged, each with its own last needed page.  Both
+    spellings must agree with the dense reference query by query."""
+    import jax.numpy as jnp
+
+    from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
+
+    rng = np.random.default_rng(3)
+    t = 80
+    q, k_pool, v_pool, tables, _ = _paged_case(
+        rng, b=2, n=2, d=8, bs=16, M=16, nb=33, t=t
+    )
+    positions = jnp.asarray([100, 0], jnp.int32)
+    got = paged_decode_attention(q, k_pool, v_pool, tables, positions, impl=impl)
+    for qi in (0, 1, 27, 63, 64, 79):
+        want = _dense_ref(q[:, qi: qi + 1], k_pool, v_pool, tables,
+                          positions + qi)
+        np.testing.assert_allclose(
+            np.asarray(got[:, qi: qi + 1]), np.asarray(want), atol=2e-5
+        )
+
+
+@pytest.mark.parametrize("bs,M,pages", [(16, 64, 8), (16, 4, 4), (32, 32, 4),
+                                        (128, 8, 1), (256, 4, 1), (8, 1, 1)])
+def test_paged_pages_per_step_follows_the_shapes(bs, M, pages):
+    """A grid step walks 128 tokens of a row: fewer pages as the page
+    grows, never more than the table holds, at least one."""
+    from paddlefleetx_tpu.ops.decode_attention import paged_pages_per_step
+
+    assert paged_pages_per_step(bs, M) == pages
+
+
+@pytest.mark.parametrize("pos,t,bs,M,want", [
+    # 16-slot pages, groups of 8 (128 tokens): context 1 -> one step;
+    # 128 -> one; 129 -> two; an empty slot (position 0) -> one
+    ([0, 127, 128, 0], 1, 16, 64, [128, 128, 256, 128]),
+    # a verify chunk of 5 reaches 4 slots further
+    ([123, 124], 5, 16, 64, [128, 256]),
+    # never past the table: 12 pages are two steps of 8 pages
+    ([191, 500], 1, 16, 12, [256, 256]),
+    # a table narrower than a group: the step is the table
+    ([3, 40], 1, 16, 4, [64, 64]),
+    ([100, 300], 1, 128, 8, [128, 384]),
+])
+def test_paged_tokens_computed_is_the_context_in_whole_grid_steps(
+        pos, t, bs, M, want):
+    """What `pfx_sched_decode_grid_tokens_total` sums: each slot's
+    context rounded up to the kernel's grid step."""
+    from paddlefleetx_tpu.ops.decode_attention import paged_tokens_computed
+
+    got = paged_tokens_computed(np.asarray(pos), t, bs, M)
+    assert got.tolist() == want
 
 
 def test_paged_attention_arg_validation():
@@ -249,7 +379,7 @@ def test_paged_attention_arg_validation():
     from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
 
     rng = np.random.default_rng(2)
-    q, k_pool, v_pool, tables = _paged_case(rng, b=1, n=1, d=8, bs=8, M=2, nb=4)
+    q, k_pool, v_pool, tables, _ = _paged_case(rng, b=1, n=1, d=8, bs=8, M=2, nb=4)
     with pytest.raises(ValueError, match="valid: auto"):
         paged_decode_attention(
             q, k_pool, v_pool, tables, jnp.asarray([3], jnp.int32), impl="cuda"

@@ -301,8 +301,8 @@ def test_scheduler_spans_are_in_the_host_plane(server, tmp_path):
 
 def test_decode_work_counters_against_a_hand_count(server):
     """Four prompts, six tokens each: every decode step counts its live
-    rows against the batch's slots and their context against the grid the
-    fixed-shape step walks."""
+    rows against the batch's slots and their context against the tokens
+    the paged kernel computes on."""
     eng, sched, base, outs = _serve(server)
     d = {k: eng.stats[k] - base[k] for k in
          ("steps", "row_steps", "slot_steps", "kv_tokens", "grid_tokens")}
@@ -315,7 +315,9 @@ def test_decode_work_counters_against_a_hand_count(server):
     lo = sum(len(p) * len(o) for p, o in zip(PROMPTS, outs))
     hi = sum((len(p) + 6) * len(o) for p, o in zip(PROMPTS, outs))
     assert lo <= d["kv_tokens"] <= hi
-    assert d["grid_tokens"] % (eng.capacity * eng.block) == 0
+    # every context here fits one page, so the kernel computes one grid
+    # step of one page for each slot, live or empty, and nothing past it
+    assert d["grid_tokens"] == eng.capacity * eng.block * d["steps"]
     mets = {name: v for name, labels, v in sched.collect() if not labels}
     assert mets["pfx_sched_decode_steps_total"] == eng.stats["steps"]
     assert mets["pfx_sched_decode_row_steps_total"] == eng.stats["row_steps"]
