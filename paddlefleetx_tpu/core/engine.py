@@ -451,6 +451,7 @@ class Engine:
 
         self._consumed_samples = 0
         self._step = 0  # host mirror of state.step (avoids device sync in fit)
+        self._warm_started = False
         self._train_loader = None  # held during fit: ckpt meta + rollback rewind
         self._loader_state = None  # loader state from a restored ckpt meta
         self.state = self._init_state()
@@ -895,6 +896,10 @@ class Engine:
             }
             if use_scaling:
                 metrics["loss_scale"] = new_scaler["scale"]
+            if has_extra and hasattr(module, "extra_scalars"):
+                # e.g. the expert layer's cumulative load counters: small
+                # arrays of the new extra state, fetched with the loss
+                metrics["extra"] = module.extra_scalars(new_extra)
             if group_spec is not None:
                 from paddlefleetx_tpu.utils import model_stats as _ms
 
@@ -1120,6 +1125,10 @@ class Engine:
             )
         if "mfu" in record:
             reg.gauge("pfx_train_mfu").set(record["mfu"])
+        publish = getattr(self.module, "publish_record", None)
+        if publish is not None:
+            # the module's own families, from its keys of the record
+            publish(reg, record)
 
     def _format_model_stats(self, stats_step: int, vals: Dict) -> Dict:
         """Shape one fetched per-group statistic set for the step record
@@ -1196,6 +1205,37 @@ class Engine:
                 "unavailable — only memory_report() works; rebuild the "
                 "Engine without abstract_init to train"
             )
+
+    def warm_start(self, batches: Iterable) -> Optional[list]:
+        """What the module wants settled before the first optimizer step:
+        ``module.warm_start_steps`` forward-only passes of
+        ``module.warm_start_step`` over the next batches of ``batches`` (the
+        expert layer's routing bias, docs/trinity_mini.md).  Once, and only
+        in a run that starts at step 0: ``fit`` calls it with the train
+        loader's iterator; a caller that wants the settled state earlier
+        calls it first with batches of its own.  Returns what each pass
+        reported, fetched (None where there was nothing to do)."""
+        steps = getattr(self.module, "warm_start_steps", 0)
+        if not steps or self._step > 0 or self._warm_started:
+            return None
+        self._require_concrete("warm_start")
+        self._warm_started = True
+        t0 = time.monotonic()
+        one = jax.jit(
+            lambda p, e, b, i: self.module.warm_start_step(p, e, b, i, ctx=self.ctx),
+            out_shardings=(self.extra_shardings, None),
+        )
+        seen = []
+        for i, batch in zip(range(steps), batches):
+            st = self.state
+            extra, info = one(st.params, st.extra, self._put_batch(batch), jnp.float32(i))
+            self.state = TrainState(st.step, st.params, st.opt_state, extra, st.scaler)
+            self._consumed_samples += self.global_batch_size
+            seen.append(info)
+        seen = jax.device_get(seen)
+        self._write_metrics({"event": "warm_start", "passes": len(seen),
+                             "seconds": round(time.monotonic() - t0, 3)})
+        return seen
 
     def fit(self, train_loader: Iterable, eval_loader: Optional[Iterable] = None):
         """Training loop (reference fit/_fit_impl eager_engine.py:422-520).
@@ -1454,6 +1494,7 @@ class Engine:
         t_fetched = None
         steps_in_window = 0
         data_iter = iter(train_loader)
+        self.warm_start(data_iter)
         while True:
             with jax.profiler.StepTraceAnnotation(
                 "pfx.train.step", step_num=self._step + 1
@@ -1591,6 +1632,8 @@ class Engine:
                             "log_write_s": round(ledger["log_write"], 4),
                             "host_gap_s": round(host_gap_total, 4),
                         }
+                        if "extra" in metrics:
+                            record.update(self.module.extra_record(metrics["extra"]))
                         if self._compile_s is not None and not self._compile_emitted:
                             # first logged window: trace+compile seconds, timed at
                             # the first dispatch and excluded from the ips window

@@ -88,22 +88,69 @@ class GPTModule(BasicModule):
         seq_len = cfg.get("Data", {}).get("Train", {}).get("dataset", {}).get("max_seq_len")
         if seq_len:
             self.tokens_per_sample = int(seq_len)
+        # the dropless expert layer's routing bias and counters: state the
+        # optimizer never sees (models/gpt/model.py init_extra)
+        self.has_extra_state = self.config.moe_dropless
+        self.warm_start_steps = (
+            self.config.moe_bias_warm_start_steps if self.has_extra_state else 0)
 
     def init_params(self, key):
         from paddlefleetx_tpu.models.gpt import model as gpt
 
         return gpt.init(self.config, key)
 
+    def init_extra(self, key, params):
+        from paddlefleetx_tpu.models.gpt import model as gpt
+
+        return gpt.init_extra(self.config)
+
+    def extra_logical_axes(self):
+        import jax.numpy as jnp
+
+        from paddlefleetx_tpu.models.gpt import model as gpt
+
+        return jax.tree.map(lambda a: (None,) * jnp.ndim(a), gpt.init_extra(self.config))
+
+    def extra_scalars(self, extra):
+        """Small arrays of ``extra`` that ride each step's metrics fetch."""
+        from paddlefleetx_tpu.models.gpt import model as gpt
+
+        return gpt.extra_scalars(extra)
+
+    def extra_record(self, vals):
+        """Fetched ``extra_scalars`` -> keys of the step record (host)."""
+        from paddlefleetx_tpu.models.gpt import model as gpt
+
+        return gpt.extra_record(vals)
+
+    def warm_start_step(self, params, extra, batch, i, *, ctx=None):
+        """Pass ``i`` of ``warm_start_steps`` (traced) -> (extra, report)."""
+        from paddlefleetx_tpu.models.gpt import model as gpt
+
+        return gpt.warm_start_step(params, extra, batch["tokens"], i, self.config, ctx=ctx)
+
+    def publish_record(self, registry, record):
+        """The expert layer's keys of a step record -> its ``pfx_moe_*``
+        families (a record of a model without the layer has none)."""
+        if "moe_pairs_total" in record:
+            for key, family in (("moe_pairs_total", "pfx_moe_pairs_total"),
+                                ("moe_pairs_held", "pfx_moe_pairs_held_total"),
+                                ("moe_load_max_over_mean_sum", "pfx_moe_load_max_over_mean_sum")):
+                registry.counter(family).set(record[key])
+            registry.gauge("pfx_moe_bias_abs_max").set(record["moe_bias_abs_max"])
+
     def logical_axes(self):
         from paddlefleetx_tpu.models.gpt import model as gpt
 
         return gpt.gpt_logical_axes(self.config)
 
-    def loss_fn(self, params, batch, *, ctx=None, dropout_key=None, train=True):
+    def loss_fn(self, params, batch, *, ctx=None, dropout_key=None, train=True,
+                extra=None):
         from paddlefleetx_tpu.models.gpt import model as gpt
 
         return gpt.loss_fn(
-            params, batch, self.config, ctx=ctx, dropout_key=dropout_key, train=train
+            params, batch, self.config, ctx=ctx, dropout_key=dropout_key, train=train,
+            extra=extra,
         )
 
     def export_spec(self):

@@ -71,12 +71,87 @@ class GPTConfig:
     moe_capacity_factor: float = 1.2
     moe_gate: str = "gshard"  # naive | gshard | switch
     moe_aux_loss_weight: float = 0.01
+    # -- block vocabulary: what the one decoder block reads beside the sizes.
+    # The defaults are the GPT-2 block (LayerNorm, learned positions, fused
+    # qkv MHA with biases, tanh-GELU, tied head); docs/trinity_mini.md shows
+    # a second family written in the same words.  norm, position, use_bias,
+    # mlp_act and tie_embeddings move together until a family needs them
+    # apart: all at their defaults, or rmsnorm + rope + no bias + swiglu +
+    # untied head (the described block, whose other options are each held
+    # to the reference in tests/test_trinity_block.py).
+    norm: str = "layernorm"  # layernorm | rmsnorm (learned scale, no bias)
+    norm_eps: float = 1e-5
+    # norms on each sub-block's OUTPUT too, before the residual add
+    post_norms: bool = False
+    position: str = "learned"  # learned | rope (rotate-half over all head dims)
+    rope_theta: float = 10000.0
+    # query heads stay num_attention_heads; 0 = as many KV heads (MHA)
+    num_kv_heads: int = 0
+    # 0 = hidden_size / num_attention_heads
+    attn_head_dim: int = 0
+    qk_norm: bool = False  # per-head RMSNorm of q and k, one scale per layer
+    attn_gate: bool = False  # sigmoid output gate on the attention result
+    use_bias: bool = True  # biases on the projections and the MLP
+    mlp_act: str = "gelu"  # gelu (tanh) | swiglu
+    tie_embeddings: bool = True
+    embed_scale_sqrt_hidden: bool = False  # x0 = E[tokens] * sqrt(hidden)
+    # attention window (0 = none) on every layer but each
+    # ``global_attn_every``-th one ((l + 1) % n == 0: no window, and under
+    # ``position: rope`` no rotation either); 0 = every layer alike
+    sliding_window: int = 0
+    global_attn_every: int = 0
+    # leading layers that keep the dense MLP when num_experts > 1
+    num_dense_layers: int = 0
+    # dropless expert layer (moe_gate: sigmoid; models/gpt/moe.py): the
+    # router scores all num_experts, this process holds moe_experts_held of
+    # them (0 = all) from id moe_expert_offset on
+    moe_ffn_hidden_size: int = 0  # 0 = ffn_hidden_size
+    moe_experts_held: int = 0
+    moe_expert_offset: int = 0
+    moe_shared_experts: int = 0
+    moe_route_scale: float = 1.0
+    moe_bias_update_rate: float = 0.001
+    # warm start of the routing bias, before the first optimizer step of a
+    # run that starts at step 0: this many forward-only passes of the bias
+    # rule over the next training batches, at a rate that falls
+    # geometrically from moe_bias_warm_start_rate to moe_bias_update_rate
+    # (0 = none; docs/trinity_mini.md says what it is for)
+    moe_bias_warm_start_steps: int = 0
+    moe_bias_warm_start_rate: float = 0.0
 
     def __post_init__(self):
         if self.ffn_hidden_size is None:
             object.__setattr__(self, "ffn_hidden_size", 4 * self.hidden_size)
-        if self.hidden_size % self.num_attention_heads:
+        for field in ("norm_eps", "rope_theta", "moe_route_scale", "moe_bias_update_rate",
+                      "moe_bias_warm_start_rate"):
+            # YAML reads "1e-05" (an override's spelling of a float) as a string
+            object.__setattr__(self, field, float(getattr(self, field)))
+        if not self.attn_head_dim and self.hidden_size % self.num_attention_heads:
             raise ValueError("num_attention_heads must divide hidden_size")
+        if self.num_attention_heads % (self.num_kv_heads or self.num_attention_heads):
+            raise ValueError("num_kv_heads must divide num_attention_heads")
+        if not self.classic_block:
+            if (self.norm, self.position, self.use_bias, self.mlp_act,
+                    self.tie_embeddings) != ("rmsnorm", "rope", False, "swiglu", False):
+                raise ValueError(
+                    "a block other than the GPT-2 one is norm: rmsnorm, position: rope, "
+                    "use_bias: False, mlp_act: swiglu, tie_embeddings: False together")
+            if self.hidden_dropout_prob or self.attention_probs_dropout_prob:
+                raise ValueError("only the GPT-2 block has dropout; set both "
+                                 "dropout probabilities to 0")
+        if self.moe_bias_warm_start_steps and not (
+                self.moe_dropless
+                and self.moe_bias_warm_start_rate >= self.moe_bias_update_rate > 0):
+            raise ValueError("moe_bias_warm_start_steps needs moe_gate: sigmoid and "
+                             "moe_bias_warm_start_rate >= moe_bias_update_rate > 0")
+        if self.moe_dropless:
+            last = self.moe_expert_offset + self.experts_held - 1
+            if not 0 <= self.moe_expert_offset <= last < self.num_experts:
+                raise ValueError(
+                    f"experts {self.moe_expert_offset}..{last} held of {self.num_experts}")
+        elif not self.classic_block and self.num_experts > 1:
+            raise ValueError("the capacity-factor MoE layer serves the GPT-2 block only; "
+                             "set moe_gate: sigmoid for the dropless layer")
         if self.recompute_granularity not in ("full", "selective", "full_attn", "core_attn"):
             raise ValueError(f"bad recompute_granularity {self.recompute_granularity}")
         raw = self.recompute_names
@@ -105,7 +180,43 @@ class GPTConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+        return self.attn_head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_attention_heads
+
+    @property
+    def moe_dropless(self) -> bool:
+        return self.num_experts > 1 and self.moe_gate == "sigmoid"
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.num_experts
+
+    @property
+    def leading_dense_layers(self) -> int:
+        """Layers before the first expert layer (0 without expert layers)."""
+        return self.num_dense_layers if self.moe_dropless else 0
+
+    @property
+    def classic_block(self) -> bool:
+        """True for the GPT-2 block: its parameter tree, its programs and
+        the paths that know only it (pipeline, generation, ring attention)."""
+        return (self.norm == "layernorm" and self.position == "learned"
+                and not self.num_kv_heads and not self.attn_head_dim
+                and not self.qk_norm and not self.attn_gate and self.use_bias
+                and self.mlp_act == "gelu" and self.tie_embeddings
+                and not self.post_norms and not self.embed_scale_sqrt_hidden
+                and not self.sliding_window and not self.num_dense_layers
+                and not self.moe_dropless)
+
+    def layer_kind(self, layer: int) -> Tuple[int, bool]:
+        """(window or 0, rotate q and k) of layer ``layer``, counted from 0
+        over the whole stack, leading dense layers included."""
+        is_global = self.global_attn_every > 0 and (layer + 1) % self.global_attn_every == 0
+        return (0 if is_global else self.sliding_window,
+                self.position == "rope" and not is_global)
 
     @property
     def recompute_name_tuple(self) -> Tuple[str, ...]:

@@ -411,8 +411,9 @@ def generate(
     rewind); a caller-provided cache must include them.
     ``return_spec_stats`` appends an ``(proposed, accepted)`` int32 pair
     to the return tuple (acceptance telemetry)."""
-    if cfg.num_experts > 1:
-        raise NotImplementedError("KV-cache generation for MoE models unsupported")
+    if cfg.num_experts > 1 or not cfg.classic_block:
+        raise NotImplementedError(
+            "KV-cache generation knows the GPT-2 block without expert layers only")
     if return_spec_stats and spec is None:
         raise ValueError("return_spec_stats needs a SpecConfig")
     b, prompt_len = input_ids.shape

@@ -81,6 +81,37 @@ def _constrain(ctx: Optional[ShardingCtx], x: jax.Array, logical) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def _rms_specs(width: int) -> Dict[str, Any]:
+    return {"scale": ParamSpec((width,), ("embed",), ones_init())}
+
+
+def _block_layer_specs(cfg: GPTConfig, experts: bool) -> Dict[str, Any]:
+    """One layer of the block the vocabulary of ``GPTConfig`` describes
+    (every block but the GPT-2 one, whose tree ``_layer_specs`` keeps):
+    RMSNorm, no biases, a SwiGLU or an expert MLP."""
+    h, nh, nkv, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    w = normal_init(cfg.initializer_range)
+    attn: Dict[str, Any] = {
+        "q_kernel": ParamSpec((h, nh, hd), ("embed", "heads", "kv"), w),
+        "k_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
+        "v_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
+        "out_kernel": ParamSpec((nh, hd, h), ("heads", "kv", "embed"), w),
+    }
+    if cfg.attn_gate:
+        attn["gate_kernel"] = ParamSpec((h, nh, hd), ("embed", "heads", "kv"), w)
+    if cfg.qk_norm:
+        attn["q_norm"] = ParamSpec((hd,), (None,), ones_init())
+        attn["k_norm"] = ParamSpec((hd,), (None,), ones_init())
+    from paddlefleetx_tpu.models.gpt.moe import dropless_layer_specs, swiglu_specs
+
+    mlp = dropless_layer_specs(cfg) if experts else swiglu_specs(h, cfg.ffn_hidden_size, w)
+    specs = {"ln_1": _rms_specs(h), "attn": attn, "ln_2": _rms_specs(h), "mlp": mlp}
+    if cfg.post_norms:
+        specs["post_attn_norm"] = _rms_specs(h)
+        specs["post_mlp_norm"] = _rms_specs(h)
+    return specs
+
+
 def _layer_specs(cfg: GPTConfig) -> Dict[str, Any]:
     h, nh, hd, ffn = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim, cfg.ffn_hidden_size
     w = normal_init(cfg.initializer_range)
@@ -115,6 +146,19 @@ def _layer_specs(cfg: GPTConfig) -> Dict[str, Any]:
 
 def gpt_specs(cfg: GPTConfig) -> Dict[str, Any]:
     w = normal_init(cfg.initializer_range)
+    if not cfg.classic_block:
+        n_dense = cfg.leading_dense_layers
+        word = ParamSpec((cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), w)
+        specs: Dict[str, Any] = {
+            "embeddings": {"word": word},
+            "layers": stack_spec_tree(
+                _block_layer_specs(cfg, cfg.moe_dropless), cfg.num_layers - n_dense),
+            "final_ln": _rms_specs(cfg.hidden_size),
+            "head": {"kernel": word},
+        }
+        if n_dense:
+            specs["dense_layers"] = stack_spec_tree(_block_layer_specs(cfg, False), n_dense)
+        return specs
     return {
         "embeddings": {
             "word": ParamSpec((cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), w),
@@ -166,6 +210,34 @@ def layer_norm(
     var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
     y = (xf - mean) * jax.lax.rsqrt(var + eps)
     return (y * scale + bias).astype(dtype)
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """RMSNorm over the last dim in float32, learned scale, no bias."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def _norm(x: jax.Array, p: Dict[str, Any], cfg: GPTConfig, ctx: Optional[ShardingCtx]):
+    if cfg.norm == "rmsnorm":
+        return rms_norm(x, p["scale"], cfg.norm_eps)
+    return layer_norm(x, p["scale"], p["bias"], eps=cfg.norm_eps, fused=cfg.use_fused_ln, ctx=ctx)
+
+
+def rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half rotary embedding over all head dims of x [b, s, n, d],
+    positions 0..s-1, angles in float32."""
+    s, d = x.shape[1], x.shape[-1]
+    # lax.iota, not jnp.arange: a static arange is a host constant, which
+    # this jax hoists into an argument of every program that traces it
+    inv_freq = theta ** (-2.0 * jax.lax.iota(jnp.float32, d // 2) / d)
+    ang = jax.lax.iota(jnp.float32, s)[:, None] * inv_freq[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
+    out = xf * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang)
+    return out.astype(x.dtype)
 
 
 def _layer_remat(cfg: GPTConfig, fn):
@@ -323,6 +395,98 @@ def _decoder_layer(
     return _constrain(ctx, x, ("batch", "seq", "embed")), aux
 
 
+def _block_attention(p, x, cfg: GPTConfig, ctx, window: int, rotate: bool) -> jax.Array:
+    """Grouped-query causal attention as the vocabulary spells it.
+    x: [b, s, h] -> [b, s, h]."""
+    dtype = x.dtype
+
+    def proj(name):
+        return jnp.einsum("bsh,hnd->bsnd", x, p[f"{name}_kernel"].astype(dtype))
+
+    q, k, v = proj("q"), proj("k"), proj("v")
+    if cfg.qk_norm:
+        q, k = rms_norm(q, p["q_norm"], cfg.norm_eps), rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if rotate:
+        q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+    q = _constrain(ctx, q, ("batch", None, "heads", "kv"))
+    with jax.named_scope("pfx.attn.window" if window else "pfx.attn.full"):
+        out = attention(
+            q, k, v, impl=cfg.attn_impl, causal=True, flash_block=cfg.flash_block,
+            flash_bwd=cfg.flash_bwd, ctx=ctx, window=window,
+        )
+    if cfg.attn_gate:
+        out = out * jax.nn.sigmoid(proj("gate").astype(jnp.float32)).astype(dtype)
+    return jnp.einsum("bsnd,ndh->bsh", out, p["out_kernel"].astype(dtype))
+
+
+def _block_layer(p, x, cfg: GPTConfig, ctx, kind: Tuple[int, bool], expert_bias):
+    """One decoder layer of the described block: x + [norm](attn(norm(x))),
+    then x + [norm](mlp(norm(x))).  ``kind`` = (window, rotate) is static;
+    an expert layer is one whose parameters hold a router.  Returns
+    (x, the expert layer's load statistics or None)."""
+    window, rotate = kind
+    y = _block_attention(p["attn"], _norm(x, p["ln_1"], cfg, ctx), cfg, ctx, window, rotate)
+    if cfg.post_norms:
+        y = _norm(y, p["post_attn_norm"], cfg, ctx)
+    x = _constrain(ctx, x + y, ("batch", "seq", "embed"))
+    m = _norm(x, p["ln_2"], cfg, ctx)
+    from paddlefleetx_tpu.models.gpt.moe import dropless_moe_block, swiglu
+
+    if "router_kernel" in p["mlp"]:
+        f, stats = dropless_moe_block(p["mlp"], m, cfg, ctx, expert_bias)
+    else:
+        f, stats = swiglu(m, p["mlp"]), None
+    if cfg.post_norms:
+        f = _norm(f, p["post_mlp_norm"], cfg, ctx)
+    return _constrain(ctx, x + f, ("batch", "seq", "embed")), stats
+
+
+def _block_stack(params, x, cfg: GPTConfig, ctx, expert_bias):
+    """The described block's stack: leading dense layers one by one, then a
+    ``lax.scan`` over whole periods of the window/full pattern (the kind of
+    each position in a period is static), then what is left of a period.
+    Returns (hidden, per-expert-layer statistics stacked on a leading axis,
+    or None without expert layers)."""
+    if ctx is not None and ctx.pipeline is not None and ctx.pipeline.num_stages > 1:
+        raise NotImplementedError("pipeline stages know the GPT-2 block only")
+    n_dense = cfg.leading_dense_layers
+    n_rest = cfg.num_layers - n_dense
+    period = cfg.global_attn_every or 1
+    at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)  # noqa: E731
+
+    def layer(l):  # static layer index -> remat-wrapped layer function
+        return _layer_remat(cfg, lambda p, x, b: _block_layer(p, x, cfg, ctx, cfg.layer_kind(l), b))
+
+    for l in range(n_dense):
+        x, _ = layer(l)(at(params["dense_layers"], l), x, None)
+    bias = expert_bias if cfg.moe_dropless else jnp.zeros((n_rest, 0), jnp.float32)
+    n_periods = n_rest // period
+    whole = n_periods * period
+    stats = []
+    if n_periods:
+        def body(x, inp):
+            lp, b = inp
+            out = []
+            for j in range(period):
+                x, st = layer(n_dense + j)(at(lp, j), x, b[j])
+                out.append(st)
+            return x, (None if out[0] is None else jax.tree.map(lambda *a: jnp.stack(a), *out))
+
+        grouped = jax.tree.map(
+            lambda a: a[:whole].reshape((n_periods, period) + a.shape[1:]),
+            (params["layers"], bias))
+        x, st = jax.lax.scan(body, x, grouped)
+        if st is not None:
+            stats.append(jax.tree.map(lambda a: a.reshape((whole,) + a.shape[2:]), st))
+    for l in range(whole, n_rest):
+        x, st = layer(n_dense + l)(at(params["layers"], l), x, bias[l])
+        if st is not None:
+            stats.append(jax.tree.map(lambda a: a[None], st))
+    if not stats:
+        return x, None
+    return x, jax.tree.map(lambda *a: jnp.concatenate(a), *stats)
+
+
 def transformer_stack(
     layers_params: Dict[str, Any],
     x: jax.Array,
@@ -405,8 +569,12 @@ def _embed(
     if position_ids is None:
         position_ids = jnp.arange(s, dtype=jnp.int32)[None, :]
     word = params["word"].astype(dtype)
-    pos = params["position"].astype(dtype)
-    x = word[input_ids] + pos[position_ids]
+    if cfg.position == "learned":
+        x = word[input_ids] + params["position"].astype(dtype)[position_ids]
+    else:
+        x = word[input_ids]
+    if cfg.embed_scale_sqrt_hidden:
+        x = x * cfg.hidden_size ** 0.5
     x = _constrain(ctx, x, ("batch", "seq", "embed"))
     return dropout(key, x, cfg.hidden_dropout_prob, train)
 
@@ -420,26 +588,38 @@ def forward_hidden(
     ctx: Optional[ShardingCtx] = None,
     dropout_key: Optional[jax.Array] = None,
     train: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
-    """Token ids [b, s] -> (final hidden [b, s, h], moe aux loss sum)."""
+    expert_bias: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Any]:
+    """Token ids [b, s] -> (final hidden [b, s, h], moe aux loss sum; for the
+    dropless expert layer instead its load statistics, stacked over the
+    expert layers).  ``expert_bias`` [expert layers, experts] is that
+    layer's routing buffer (None = zeros)."""
     k_embed, k_layers = (
         jax.random.split(dropout_key) if dropout_key is not None else (None, None)
     )
     x = _embed(params["embeddings"], input_ids, position_ids, cfg, ctx, k_embed, train)
 
-    x, aux = transformer_stack(params["layers"], x, cfg, ctx, k_layers, train)
-    x = layer_norm(
-        x, params["final_ln"]["scale"], params["final_ln"]["bias"],
-        fused=cfg.use_fused_ln, ctx=ctx,
-    )
+    if cfg.classic_block:
+        x, aux = transformer_stack(params["layers"], x, cfg, ctx, k_layers, train)
+    else:
+        if cfg.moe_dropless and expert_bias is None:
+            expert_bias = init_extra(cfg)["expert_bias"]
+        x, aux = _block_stack(params, x, cfg, ctx, expert_bias)
+    x = _norm(x, params["final_ln"], cfg, ctx)
     return _constrain(ctx, x, ("batch", "seq", "embed")), aux
+
+
+def head_matrix(params: Dict[str, Any]) -> jax.Array:
+    """[vocab, hidden] matrix the logits and the loss read."""
+    return params["head"]["kernel"] if "head" in params else params["embeddings"]["word"]
 
 
 def logits_from_hidden(
     params: Dict[str, Any], hidden: jax.Array, ctx: Optional[ShardingCtx] = None
 ) -> jax.Array:
-    """Tied-embedding LM head (reference parallel_matmul hybrid_model.py:66)."""
-    word = params["embeddings"]["word"].astype(hidden.dtype)
+    """LM head: the tied word embedding (reference parallel_matmul
+    hybrid_model.py:66), or the ``head`` matrix of an untied model."""
+    word = head_matrix(params).astype(hidden.dtype)
     logits = jnp.einsum("bsh,vh->bsv", hidden, word)
     return _constrain(ctx, logits, ("batch", "seq", "vocab"))
 
@@ -453,6 +633,7 @@ def forward(
     ctx: Optional[ShardingCtx] = None,
     dropout_key: Optional[jax.Array] = None,
     train: bool = False,
+    expert_bias: Optional[jax.Array] = None,
 ) -> jax.Array:
     hidden, _ = forward_hidden(
         params,
@@ -462,6 +643,7 @@ def forward(
         ctx=ctx,
         dropout_key=dropout_key,
         train=train,
+        expert_bias=expert_bias,
     )
     return logits_from_hidden(params, hidden, ctx)
 
@@ -610,11 +792,15 @@ def loss_fn(
     ctx: Optional[ShardingCtx] = None,
     dropout_key: Optional[jax.Array] = None,
     train: bool = True,
-) -> jax.Array:
+    extra: Optional[Dict[str, Any]] = None,
+):
     """batch: tokens [b,s], labels [b,s], loss_mask [b,s], position_ids opt.
 
     MoE models add the load-balance aux loss scaled by moe_aux_loss_weight
-    (reference sharded_moe.py l_aux handling)."""
+    (reference sharded_moe.py l_aux handling).  The dropless expert layer
+    adds nothing to the loss: its balance is the routing bias's, which
+    lives in ``extra`` (``init_extra``); given ``extra`` the result is
+    (loss, extra after this step's bias rule and counters)."""
     if (
         train
         and ctx is not None
@@ -630,6 +816,7 @@ def loss_fn(
         ctx=ctx,
         dropout_key=dropout_key,
         train=train,
+        expert_bias=None if extra is None else extra["expert_bias"],
     )
     from paddlefleetx_tpu.parallel.mesh import AXIS_MODEL
 
@@ -639,7 +826,7 @@ def loss_fn(
 
         loss = chunked_cross_entropy(
             hidden,
-            params["embeddings"]["word"],
+            head_matrix(params),
             batch["labels"],
             batch.get("loss_mask"),
             chunk=cfg.ce_chunk_size,
@@ -647,6 +834,89 @@ def loss_fn(
     else:
         logits = logits_from_hidden(params, hidden, ctx)
         loss = cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
+    if cfg.moe_dropless:
+        return loss if extra is None else (loss, next_extra(extra, aux, cfg, train))
     if cfg.num_experts > 1:
         loss = loss + cfg.moe_aux_loss_weight * aux
     return loss
+
+
+# ---------------------------------------------------------------------------
+# Non-gradient state of the dropless expert layer (the engine's ``extra``)
+# ---------------------------------------------------------------------------
+
+_LO = 1 << 20  # exact cumulative counts as (hi, lo) int32: hi * 2^20 + lo
+
+
+def init_extra(cfg: GPTConfig) -> Dict[str, Any]:
+    """``expert_bias`` [expert layers, experts] (moved by the balance rule
+    after each training step, outside gradient and weight decay) and the
+    step records' counters (cumulative, but for the last step's
+    ``pairs_held_layer_max``)."""
+    n_layers = cfg.num_layers - cfg.leading_dense_layers
+    pair = jnp.zeros((2,), jnp.int32)
+    return {
+        "expert_bias": jnp.zeros((n_layers, cfg.num_experts), jnp.float32),
+        "counters": {"pairs_total": pair, "pairs_held": pair,
+                     "load_max_over_mean_sum": jnp.zeros((), jnp.float32),
+                     "pairs_held_layer_max": jnp.zeros((), jnp.int32)},
+    }
+
+
+def _count(counter: jax.Array, n: jax.Array) -> jax.Array:
+    lo = counter[1] + n % _LO
+    return jnp.stack([counter[0] + n // _LO + lo // _LO, lo % _LO])
+
+
+def next_extra(extra, stats, cfg: GPTConfig, train: bool):
+    """After a training step: the bias rule on the step's load, and the
+    counters.  Evaluation leaves both alone."""
+    if not train:
+        return extra
+    from paddlefleetx_tpu.models.gpt.moe import next_expert_bias
+
+    c = extra["counters"]
+    n_layers = stats["load"].shape[0]
+    total = jnp.sum(stats["load"][0]) * n_layers  # tokens x top_k, every layer alike
+    return {
+        "expert_bias": next_expert_bias(
+            extra["expert_bias"], stats["load"], cfg.moe_bias_update_rate),
+        "counters": {
+            "pairs_total": _count(c["pairs_total"], total),
+            "pairs_held": _count(c["pairs_held"], jnp.sum(stats["pairs_held"])),
+            "load_max_over_mean_sum": c["load_max_over_mean_sum"]
+            + jnp.max(stats["load_max_over_mean"]),
+            # a gauge: the fullest layer's held pairs of THIS step
+            "pairs_held_layer_max": jnp.max(stats["pairs_held"]),
+        },
+    }
+
+
+def warm_start_step(params, extra, tokens, i, cfg: GPTConfig, ctx=None):
+    """Pass ``i`` (traced) of the routing bias's warm start, before the
+    first optimizer step and forward only: the balance rule on this batch's
+    load, at a rate that falls geometrically from
+    ``moe_bias_warm_start_rate`` (pass 0) to ``moe_bias_update_rate`` (the
+    last pass).  Returns (extra, the pairs each expert layer held)."""
+    from paddlefleetx_tpu.models.gpt.moe import next_expert_bias
+
+    first, last = cfg.moe_bias_warm_start_rate, cfg.moe_bias_update_rate
+    rate = first * (last / first) ** (i / max(cfg.moe_bias_warm_start_steps - 1, 1))
+    stats = forward_hidden(params, tokens, cfg, ctx=ctx, expert_bias=extra["expert_bias"])[1]
+    bias = next_expert_bias(extra["expert_bias"], stats["load"], rate)
+    return dict(extra, expert_bias=bias), stats["pairs_held"]
+
+
+def extra_record(vals: Dict[str, Any]) -> Dict[str, Any]:
+    """Host side: fetched ``extra_scalars`` -> step-record keys."""
+    out = {f"moe_{k}": int(vals[k][0]) * _LO + int(vals[k][1])
+           for k in ("pairs_total", "pairs_held")}
+    out["moe_load_max_over_mean_sum"] = round(float(vals["load_max_over_mean_sum"]), 4)
+    out["moe_pairs_held_layer_max"] = int(vals["pairs_held_layer_max"])
+    out["moe_bias_abs_max"] = round(float(vals["bias_abs_max"]), 6)
+    return out
+
+
+def extra_scalars(extra: Dict[str, Any]) -> Dict[str, jax.Array]:
+    """Device side: what of ``extra`` rides the step's metrics fetch."""
+    return {**extra["counters"], "bias_abs_max": jnp.max(jnp.abs(extra["expert_bias"]))}

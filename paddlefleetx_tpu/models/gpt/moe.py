@@ -155,3 +155,133 @@ def moe_mlp_block(
     out = out.reshape(b, s, h)
     out = dropout(key, out, cfg.hidden_dropout_prob, train)
     return out, aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert layer (moe_gate: sigmoid)
+#
+# The router scores ALL ``num_experts``; this process holds ``experts_held``
+# of them (ids ``moe_expert_offset`` ..) and computes the part of the result
+# that they give: the (token, expert) pairs that land on held experts are
+# sorted by expert into one buffer of static size, three grouped matrix
+# products (``jax.lax.ragged_dot``) run over the groups, and the rows go
+# back to their tokens weighted, accumulated in float32.  Pairs on experts
+# held elsewhere add nothing here; nothing stands in for the absent chips.
+# No token is dropped: the buffer has the worst case's tokens x top_k rows
+# (it fits the cell's chip, tests/test_chip_compile.py), so gathers, masks
+# and the scatter cost what the worst case costs and only the grouped
+# products follow the load.
+# ---------------------------------------------------------------------------
+
+
+def swiglu_specs(h: int, f: int, w, lead=(), lead_axes=()) -> Dict[str, Any]:
+    return {
+        "w1": ParamSpec(lead + (h, f), lead_axes + ("embed", "mlp"), w),
+        "w3": ParamSpec(lead + (h, f), lead_axes + ("embed", "mlp"), w),
+        "w2": ParamSpec(lead + (f, h), lead_axes + ("mlp", "embed"), w),
+    }
+
+
+def dropless_layer_specs(cfg) -> Dict[str, Any]:
+    h, f = cfg.hidden_size, cfg.moe_ffn_hidden_size or cfg.ffn_hidden_size
+    w = normal_init(cfg.initializer_range)
+    specs = {
+        "router_kernel": ParamSpec((h, cfg.num_experts), ("embed", None), w),
+        "experts": swiglu_specs(h, f, w, (cfg.experts_held,), ("expert",)),
+    }
+    if cfg.moe_shared_experts:
+        specs["shared"] = swiglu_specs(h, f * cfg.moe_shared_experts, w)
+    return specs
+
+
+def swiglu(x: jax.Array, p: Dict[str, Any]) -> jax.Array:
+    dtype = x.dtype
+    return (jax.nn.silu(x @ p["w1"].astype(dtype)) * (x @ p["w3"].astype(dtype))) @ p[
+        "w2"
+    ].astype(dtype)
+
+
+def sigmoid_route(m: jax.Array, router_kernel: jax.Array, bias: jax.Array, cfg):
+    """m [N, h] -> (idx [N, k] over all experts, w [N, k] float32).  The
+    scores are float32 at full matmul precision: the choice is a
+    discontinuity, and a bf16 product would flip it for many tokens.  The
+    bias moves the choice only; the weights are the unbiased scores."""
+    s = jax.nn.sigmoid(
+        jnp.dot(m.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    )
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), cfg.moe_top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, cfg.moe_route_scale * w
+
+
+def routed_experts(p: Dict[str, Any], m: jax.Array, bias: jax.Array, cfg):
+    """m [N, h] -> (what the held experts give [N, h], the step's load
+    statistics).  ``load`` counts the pairs of every expert, held or not."""
+    n, h = m.shape
+    dtype = m.dtype
+    k, E, held, offset = cfg.moe_top_k, cfg.num_experts, cfg.experts_held, cfg.moe_expert_offset
+    rows = n * k  # every pair may land on a held expert
+    with jax.named_scope("pfx.moe.route"):
+        idx, w = sigmoid_route(m, p["router_kernel"], bias, cfg)
+        flat_e = idx.reshape(-1)
+        ids = jax.lax.broadcasted_iota(jnp.int32, (1, E), 1)
+        load = jnp.sum(flat_e[:, None] == ids, axis=0, dtype=jnp.int32)
+    with jax.named_scope("pfx.moe.dispatch"):
+        local = flat_e - offset
+        is_held = (local >= 0) & (local < held)
+        # pairs of experts held elsewhere sort behind every held one
+        order = jnp.argsort(jnp.where(is_held, local, held), stable=True)
+        group_sizes = load[offset:offset + held]
+        n_held = jnp.sum(group_sizes)
+        token = order // k
+        live = (jax.lax.iota(jnp.int32, rows) < n_held)[:, None]
+        w_sorted = w.reshape(-1)[order][:, None]
+        xs = jnp.take(m, token, axis=0)
+    with jax.named_scope("pfx.moe.experts"):
+        ex = p["experts"]
+
+        def grouped(x, kernel):
+            # rows past the held pairs belong to no group, and on the TPU a
+            # grouped product leaves them as it found them, forward and
+            # backward: cut them going in and coming out, so that neither
+            # a value nor a cotangent of such a row ever reaches a token
+            y = jax.lax.ragged_dot(jnp.where(live, x, 0), kernel.astype(dtype), group_sizes)
+            return jnp.where(live, y, 0)
+
+        hidden = jax.nn.silu(grouped(xs, ex["w1"])) * grouped(xs, ex["w3"])
+        ys = grouped(hidden, ex["w2"])
+    with jax.named_scope("pfx.moe.combine"):
+        out = jnp.zeros((n, h), jnp.float32).at[token].add(ys.astype(jnp.float32) * w_sorted)
+        out = out.astype(dtype)
+    stats = {
+        "load": load,
+        "pairs_held": n_held,
+        "load_max_over_mean": jnp.max(group_sizes) * held
+        / jnp.maximum(n_held, 1).astype(jnp.float32),
+    }
+    return out, stats
+
+
+def dropless_moe_block(p: Dict[str, Any], x: jax.Array, cfg, ctx, bias: jax.Array):
+    """x [b, s, h] -> (shared expert + held routed experts [b, s, h], stats)."""
+    if ctx is not None and ctx.mesh.size > 1:
+        raise NotImplementedError(
+            "the dropless expert layer runs one chip's share per process; the "
+            "exchange of pairs across chips (parallel/sharding.py 'expert') is "
+            "not written yet")
+    b, s, h = x.shape
+    m = x.reshape(b * s, h)
+    out, stats = routed_experts(p, m, bias, cfg)
+    if cfg.moe_shared_experts:
+        with jax.named_scope("pfx.moe.shared"):
+            out = out + swiglu(m, p["shared"])
+    return out.reshape(b, s, h), stats
+
+
+def next_expert_bias(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
+    """The balance rule, outside the gradient: an expert with fewer pairs
+    than the mean over all experts gains ``rate``, one with more loses it."""
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)

@@ -75,8 +75,13 @@ def attention(
     flash_block: int = 0,
     flash_bwd: str = "",
     ctx=None,
+    window: int = 0,
 ) -> jax.Array:
     """Dispatching attention entry point used by all models.
+
+    ``window`` > 0 (causal only): position i sees positions i-window+1 .. i.
+    k and v may carry fewer heads than q (a divisor: grouped-query
+    attention, KV head h serving query heads g*h .. g*h+g-1).
 
     ``flash_block`` / ``flash_bwd`` pass through to the Pallas kernels
     (0/"" = auto); surfaced as ``Model.flash_block`` / ``Model.flash_bwd``.
@@ -101,13 +106,20 @@ def attention(
             # hybrid_model.py:284-301)
             def kernel(q, k, v):
                 return flash_attention(
-                    q, k, v, causal=True, block=flash_block, bwd_schedule=flash_bwd
+                    q, k, v, causal=True, block=flash_block, bwd_schedule=flash_bwd,
+                    window=window,
                 )
 
             if ctx is not None:
                 qkv = ("batch", None, "heads", "kv")
                 kernel = ctx.shard_kernel(kernel, (qkv, qkv, qkv), qkv)
             return kernel(q, k, v)
+    if k.shape[2] != q.shape[2]:
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
+    if window and causal and bias is None and window < k.shape[1]:
+        i = jnp.arange(k.shape[1])
+        seen = (i[None, :] <= i[:, None]) & (i[:, None] - i[None, :] < window)
+        bias = jnp.where(seen, 0.0, -1e9)[None, None, -q.shape[1]:, :]
     out = xla_attention(
         q,
         k,

@@ -22,7 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-NEG = jnp.float32(-1e30)
+NEG = -1e30  # a Python float: a device array here would be a closure constant of every trace
 
 
 def _chunks(word: jax.Array, chunk: int) -> jax.Array:
@@ -63,7 +63,7 @@ def _scan_lse_picked(hidden2d, word, labels1d, chunk):
         picked = picked + jnp.where(hit, (logits * one).sum(-1), 0.0)
         return (cm, s, picked), None
 
-    init = (jnp.full((n,), NEG), jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32))
+    init = (jnp.full((n,), NEG, jnp.float32), jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32))
     offs = jnp.arange(wc.shape[0], dtype=jnp.int32) * chunk
     (m, s, picked), _ = jax.lax.scan(body, init, (wc, offs))
     lse = m + jnp.log(jnp.maximum(s, 1e-30))

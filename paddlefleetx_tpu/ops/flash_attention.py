@@ -10,6 +10,11 @@ Layout: inputs [batch, seq, heads, head_dim] (model layout), kernels run on
 backward recomputation (standard FlashAttention-2 scheme: dq swept over kv
 blocks, dk/dv swept over q blocks).
 
+``window`` (a query sees the ``window`` newest positions up to itself) adds a
+lower bound on the KV blocks a query block visits, in forward, dq and dkv,
+and fewer KV heads than query heads (GQA) are shared through the block
+index maps; without either the programs are the causal MHA ones unchanged.
+
 On non-TPU platforms the kernels run in Pallas interpret mode (slow but
 exact) so the full test suite exercises the same code path on the CPU mesh.
 """
@@ -112,12 +117,62 @@ def _block_k_override(seq: int, default_bk: int) -> int:
     return bk
 
 
+def _visible(row_ids, col_ids, window):
+    """Causal mask, and ``row - col < window`` where a window is given."""
+    if window:
+        return (col_ids <= row_ids) & (row_ids - col_ids < window)
+    return col_ids <= row_ids
+
+
+def _kv_block_range(qi, block_q, block_k, window):
+    """KV blocks [first, end) that rows [qi*bq, (qi+1)*bq) can see."""
+    end = (qi * block_q + block_q + block_k - 1) // block_k
+    if not window:
+        return 0, end
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k, end
+
+
+def _q_block_range(kj, block_q, block_k, window, seq):
+    """Q blocks [first, end) that can see columns [kj*bk, (kj+1)*bk)."""
+    first, num_q = (kj * block_k) // block_q, seq // block_q
+    if not window:
+        return first, num_q
+    last_row = kj * block_k + block_k - 1 + window - 1
+    return first, jnp.minimum(last_row // block_q + 1, num_q)
+
+
+def _kv_map(group):
+    """Index map of a whole-sequence K/V block for query head-row ``i``:
+    ``group`` consecutive query heads read one KV head."""
+    if group == 1:
+        return lambda i, j: (i, 0, 0)
+    return lambda i, j: (i // group, 0, 0)
+
+
+# Mosaic's default scoped VMEM is 16 MiB; the kernels hold whole-sequence
+# K/V (dkv: Q, dO and the lane-padded lse/delta columns), double-buffered,
+# which passes that from about 4k positions of 128-wide heads on.  Only
+# then is a limit asked for, so shorter sequences compile as before.
+_VMEM_DEFAULT = 16 * 2**20
+_VMEM_CAP = 100 * 2**20  # of the 128 MiB a v5e core has
+
+
+def _compiler_params(resident_bytes):
+    need = 2 * resident_bytes + 8 * 2**20  # double buffers + tiles and temporaries
+    if need <= _VMEM_DEFAULT:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(need, _VMEM_CAP)))}
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q, block_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q, block_k, window=0):
     # MXU dots run in the INPUT dtype (bf16 on the model path) with fp32
     # accumulation via preferred_element_type — upcasting the operands to
     # fp32 first quarters MXU throughput (measured: the kernel pair sat at
@@ -143,7 +198,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q, block_k)
         col_ids = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        s = jnp.where(col_ids <= row_ids, s, NEG_INF)
+        s = jnp.where(_visible(row_ids, col_ids, window), s, NEG_INF)
 
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[:, None])
@@ -155,9 +210,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q, block_k)
         )
         return m_new, l_new, acc_new
 
-    # causal: only kv blocks intersecting rows [qi*bq, (qi+1)*bq)
-    num_kv = (qi * block_q + block_q + block_k - 1) // block_k
-    m, l, acc = jax.lax.fori_loop(0, num_kv, body, (m0, l0, acc0))
+    # causal: only kv blocks intersecting rows [qi*bq, (qi+1)*bq); a window
+    # also skips the blocks wholly before it.  (A row whose first visited
+    # block is wholly masked carries m = NEG_INF and garbage in l and acc
+    # until its first visible column, whose alpha = exp(NEG_INF - m) = 0
+    # wipes them: every row sees at least its own column.)
+    first_kv, num_kv = _kv_block_range(qi, block_q, block_k, window)
+    m, l, acc = jax.lax.fori_loop(first_kv, num_kv, body, (m0, l0, acc0))
 
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
@@ -167,21 +226,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q, block_k)
     lse_ref[0, :, 0] = m + jnp.log(l_safe)
 
 
-def _flash_fwd(q, k, v, scale, block):
+def _flash_fwd(q, k, v, scale, block, window=0, group=1):
     bh, seq, d = q.shape
     block_q, block_k = block  # static (bq, bk) tuple
     grid = (bh, seq // block_q)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k
+        _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, window=window
     )
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, seq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, seq, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, seq, d), _kv_map(group)),
+            pl.BlockSpec((1, seq, d), _kv_map(group)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -193,6 +252,7 @@ def _flash_fwd(q, k, v, scale, block):
         ],
         interpret=_device.pallas_interpret(),
         name="pfx_flash_fwd",
+        **_compiler_params(2 * seq * d * k.dtype.itemsize),
     )(q, k, v)
     return out, lse
 
@@ -212,7 +272,8 @@ def _flash_fwd(q, k, v, scale, block):
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale, block_q, block_k):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale, block_q, block_k,
+               window=0):
     qi = pl.program_id(1)
     q = q_ref[0]  # native dtype; dots accumulate fp32 (see _fwd_kernel)
     do = do_ref[0]
@@ -231,7 +292,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale
         col_ids = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        p = jnp.where(col_ids <= row_ids, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(_visible(row_ids, col_ids, window), jnp.exp(s - lse[:, None]), 0.0)
         dov = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bq, bk] fp32
@@ -241,13 +302,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale
             preferred_element_type=jnp.float32,
         )
 
-    num_kv = (qi * block_q + block_q + block_k - 1) // block_k
-    dq = jax.lax.fori_loop(0, num_kv, body, jnp.zeros((block_q, d), jnp.float32))
+    first_kv, num_kv = _kv_block_range(qi, block_q, block_k, window)
+    dq = jax.lax.fori_loop(first_kv, num_kv, body, jnp.zeros((block_q, d), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale, block_q, block_k, seq
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale, block_q, block_k, seq,
+    window=0
 ):
     kj = pl.program_id(1)
     k = k_ref[0]  # [bk, d] native dtype; dots accumulate fp32
@@ -266,7 +328,7 @@ def _dkv_kernel(
         row_ids = i * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0
         )
-        p_lo, ds = _bwd_tile(q, k, v, do, lse, delta, row_ids, col_ids, scale)
+        p_lo, ds = _bwd_tile(q, k, v, do, lse, delta, row_ids, col_ids, scale, window)
         dv_new = dv + jax.lax.dot_general(
             p_lo, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -275,9 +337,9 @@ def _dkv_kernel(
         )
         return dk_new, dv_new
 
-    # causal: q blocks starting at or after this kv block's diagonal
-    first_q = (kj * block_k) // block_q
-    num_q = seq // block_q
+    # causal: q blocks starting at or after this kv block's diagonal; a
+    # window also ends them where its last column's last viewer sits
+    first_q, num_q = _q_block_range(kj, block_q, block_k, window, seq)
     dk0 = jnp.zeros((block_k, d), jnp.float32)
     dv0 = jnp.zeros((block_k, d), jnp.float32)
     dk, dv = jax.lax.fori_loop(first_q, num_q, body, (dk0, dv0))
@@ -285,7 +347,7 @@ def _dkv_kernel(
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _bwd_tile(q, k, v, do, lse, delta, row_ids, col_ids, scale):
+def _bwd_tile(q, k, v, do, lse, delta, row_ids, col_ids, scale, window=0):
     """Shared per-(q-block, kv-block) backward tile math: recompute the
     masked softmax block from the saved lse and form ds.  Used by BOTH the
     split _dkv_kernel and the fused kernel so the mask/scaling can never
@@ -294,7 +356,7 @@ def _bwd_tile(q, k, v, do, lse, delta, row_ids, col_ids, scale):
     s = scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    p = jnp.where(col_ids <= row_ids, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(_visible(row_ids, col_ids, window), jnp.exp(s - lse[:, None]), 0.0)
     dov = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -387,7 +449,7 @@ def _flash_bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k):
     return dq.astype(q.dtype), dk, dv
 
 
-def _flash_bwd(scale, block, bwd_mode, res, g):
+def _flash_bwd(scale, block, bwd_mode, window, group, res, g):
     q, k, v, out, lse = res
     do = g
     bh, seq, d = q.shape
@@ -396,15 +458,21 @@ def _flash_bwd(scale, block, bwd_mode, res, g):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[..., None]  # [bh, s, 1]
 
     if bwd_mode == "fused":
+        if window or group > 1:
+            raise NotImplementedError(
+                "the fused flash backward knows neither a window nor shared KV "
+                "heads; use flash_bwd: split")
         return _flash_bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, block_q=block_q, block_k=block_k),
+        functools.partial(
+            _dq_kernel, scale=scale, block_q=block_q, block_k=block_k, window=window
+        ),
         grid=(bh, seq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, seq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, seq, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, seq, d), _kv_map(group)),
+            pl.BlockSpec((1, seq, d), _kv_map(group)),
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
@@ -413,17 +481,25 @@ def _flash_bwd(scale, block, bwd_mode, res, g):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=_device.pallas_interpret(),
         name="pfx_flash_bwd_dq",
+        **_compiler_params(2 * seq * d * k.dtype.itemsize),
     )(q, k, v, do, lse, delta)
 
+    # shared KV heads: one grid row per QUERY head, so that Q and dO stay
+    # resident while the KV blocks sweep; each writes its own float32 dk/dv
+    # and the ``group`` rows of a KV head are added up outside
+    kv_block = ((lambda i, j: (i, j, 0)) if group == 1
+                else (lambda i, j: (i // group, j, 0)))
+    dkv_dtype = (k.dtype, v.dtype) if group == 1 else (jnp.float32, jnp.float32)
     dk, dv = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, scale=scale, block_q=block_q, block_k=block_k, seq=seq
+            _dkv_kernel, scale=scale, block_q=block_q, block_k=block_k, seq=seq,
+            window=window,
         ),
         grid=(bh, seq // block_k),
         in_specs=[
             pl.BlockSpec((1, seq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
             pl.BlockSpec((1, seq, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, seq, 1), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, seq, 1), lambda i, j: (i, 0, 0)),
@@ -433,12 +509,18 @@ def _flash_bwd(scale, block, bwd_mode, res, g):
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((bh, seq, d), dkv_dtype[0]),
+            jax.ShapeDtypeStruct((bh, seq, d), dkv_dtype[1]),
         ],
         interpret=_device.pallas_interpret(),
         name="pfx_flash_bwd_dkv",
+        # q and dO whole, and lse and delta whole: a [seq, 1] float32
+        # column takes a 128-lane tile per 8 rows in VMEM
+        **_compiler_params(2 * seq * d * q.dtype.itemsize + 2 * seq * 128 * 4),
     )(q, k, v, do, lse, delta)
+    if group > 1:
+        dk = dk.reshape(bh // group, group, seq, d).sum(axis=1).astype(k.dtype)
+        dv = dv.reshape(bh // group, group, seq, d).sum(axis=1).astype(v.dtype)
 
     return dq, dk, dv
 
@@ -448,14 +530,14 @@ def _flash_bwd(scale, block, bwd_mode, res, g):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_bhsd(q, k, v, scale, block, bwd_mode):
-    out, _ = _flash_fwd(q, k, v, scale, block)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bhsd(q, k, v, scale, block, bwd_mode, window=0, group=1):
+    out, _ = _flash_fwd(q, k, v, scale, block, window, group)
     return out
 
 
-def _flash_bhsd_fwd(q, k, v, scale, block, bwd_mode):
-    out, lse = _flash_fwd(q, k, v, scale, block)
+def _flash_bhsd_fwd(q, k, v, scale, block, bwd_mode, window=0, group=1):
+    out, lse = _flash_fwd(q, k, v, scale, block, window, group)
     # Name lse so selective-remat policies can keep it: without a saved lse
     # the backward pass must re-run the forward kernel a SECOND time just to
     # regenerate it (observed as rematted_computation in traces). The out
@@ -485,8 +567,12 @@ def flash_attention(
     causal: bool = True,
     block: int = 0,
     bwd_schedule: str = "",
+    window: int = 0,
 ):
-    """q,k,v: [batch, seq, heads, head_dim] -> [batch, seq, heads, head_dim].
+    """q: [batch, seq, heads, head_dim]; k, v: the same, or with fewer heads
+    (a divisor of q's: KV head h serves query heads g*h .. g*h+g-1)
+    -> [batch, seq, heads, head_dim].  ``window`` > 0: position i sees
+    positions i-window+1 .. i only.
 
     ``block`` (0 = auto: PFX_FLASH_BLOCK env, else the measured-best
     ladder) and ``bwd_schedule`` ("" = auto: PFX_FLASH_BWD env, else
@@ -495,6 +581,10 @@ def flash_attention(
     if not causal:
         raise NotImplementedError("only causal flash attention")
     b, s, n, d = q.shape
+    n_kv = k.shape[2]
+    if n % n_kv or v.shape != k.shape:
+        raise ValueError(f"{n} query heads over K {k.shape} / V {v.shape}")
+    window = 0 if not window or window >= s else int(window)
     bq, bk = _block_sizes(s, block)
     if s % bq or s % bk:
         raise ValueError(
@@ -505,9 +595,10 @@ def flash_attention(
     mode = _resolve_bwd_schedule(bwd_schedule)
 
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * n, s, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, d)
 
-    out = _flash_bhsd(to_bh(q), to_bh(k), to_bh(v), scale, (bq, bk), mode)
+    out = _flash_bhsd(to_bh(q), to_bh(k), to_bh(v), scale, (bq, bk), mode,
+                      window, n // n_kv)
     return out.reshape(b, n, s, d).transpose(0, 2, 1, 3)
 
 

@@ -263,6 +263,10 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_sched_decode_kv_tokens_total": ("counter", "Context tokens of the live rows summed over decode steps (what a step needed to read)"),
     "pfx_sched_decode_grid_tokens_total": ("counter", "KV tokens per head the paged kernel computed on, summed over decode steps: every slot's context rounded up to the kernel's grid step (an empty slot costs one step; grid steps past a row's context run nothing)"),
     "pfx_train_host_gap_seconds_total": ("counter", "Fit-loop seconds from a blocking log fetch returning to the next step's dispatch having returned (the device has nothing queued)"),
+    "pfx_moe_pairs_total": ("counter", "Token-expert pairs the dropless expert layers routed, over all experts and layers"),
+    "pfx_moe_pairs_held_total": ("counter", "Routed pairs that landed on experts this process holds"),
+    "pfx_moe_load_max_over_mean_sum": ("counter", "Sum over steps of the largest held expert's pairs over the held experts' mean (max over layers)"),
+    "pfx_moe_bias_abs_max": ("gauge", "Largest absolute routing bias over experts and layers"),
     "pfx_token_ledger_total": ("counter", "Admitted-token dispositions (labels: disposition=admitted|delivered|evicted_lost|preempt_refunded|shed_after_admit)"),
     "pfx_token_ledger_in_flight": ("gauge", "Admitted tokens still on the books in live decode slots (the exact-closure remainder)"),
     "pfx_tenant_slot_seconds_total": ("counter", "Decode-slot occupancy in slot-seconds per tenant — billing-grade cost attribution (labels: tenant)"),
