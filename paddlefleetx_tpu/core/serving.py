@@ -23,10 +23,12 @@ from paddlefleetx_tpu.models.gpt.generation import (
     generate,
     init_cache,
     pad_prompts,
+    serving_params,
 )
 from paddlefleetx_tpu.ops.decode_attention import kv_cache_dtype
 from paddlefleetx_tpu.ops.speculative import spec_config_from
 from paddlefleetx_tpu.parallel.sharding import place_on_mesh
+from paddlefleetx_tpu.utils.checkpoint import load_pretrained_params
 from paddlefleetx_tpu.utils.log import logger
 from paddlefleetx_tpu.utils.resilience import maybe_fire
 from paddlefleetx_tpu.utils.telemetry import StatsView, get_registry
@@ -100,12 +102,31 @@ class GenerationServer:
 
         rules = make_rules(mesh=mesh)
         self.ctx = ShardingCtx(mesh, rules) if mesh.size > 1 else None
+        # with no tree given the server loads (Engine.save_load.ckpt_dir) or
+        # makes its own, and so holds the only reference to it: a tree a
+        # caller passes stays alive in the caller, whole, through the cast
+        if params is None:
+            params = load_pretrained_params(cfg)
         if params is None:
             params = module.init_params(get_seed_tracker().params_key())
         if self.ctx is not None:
             shardings = tree_logical_to_sharding(module.logical_axes(), mesh, rules)
             params = jax.device_put(params, shardings)
-        self.params = params
+        # the tree is HELD in the dtype the step computes in (float32 on
+        # disk, cfg.dtype here, LayerNorm leaves float32): a decode step
+        # is its own dispatch, and a float32 tree would be converted whole
+        # inside every one (docs/serving.md "What the server holds").
+        # After the placement, so each device casts its own shard; moved
+        # out of this frame, so each float32 leaf is freed as its cast
+        # exists and no moment holds two whole trees.
+        owned = [params]
+        del params
+        self.params = serving_params(owned.pop(), module.config)
+        held: Dict[str, int] = {}
+        for leaf in jax.tree.leaves(self.params):
+            held[str(leaf.dtype)] = held.get(str(leaf.dtype), 0) + leaf.nbytes
+        for dtype_name, nbytes in held.items():
+            get_registry().gauge("pfx_serving_params_bytes", dtype=dtype_name).set(nbytes)
         self._key = jax.random.key(int(cfg.get("Global", {}).get("seed", 0)))
         # one jitted decode per (bucket_b, bucket_len, GenerationConfig):
         # mixed-traffic serving hits a small, log-bounded set of compiled

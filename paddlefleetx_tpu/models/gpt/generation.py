@@ -85,6 +85,63 @@ def init_cache(
 
 
 # ---------------------------------------------------------------------------
+# Which leaves the serving forwards compute on in ``cfg.dtype``
+# ---------------------------------------------------------------------------
+
+# group of the tree -> the leaves below consumed in the compute dtype:
+# matmul operands, the biases added to their results, the embedding
+# tables.  Every other leaf (the LayerNorm scales and biases) enters
+# ``layer_norm``'s float32 arithmetic as stored; rounding it would be a
+# different result.  ONE list: the forwards cast through ``_in_dtype`` at
+# the point of use, ``serving_params`` once for a server that keeps the
+# tree — a weight added to a forward and not here shows as a per-step
+# convert (tests/test_serving.py holds the decode step to none).
+COMPUTE_DTYPE_LEAVES = {
+    "embeddings": ("word", "position"),
+    "attn": ("qkv_kernel", "qkv_bias", "out_kernel", "out_bias"),
+    "mlp": ("fc_in_kernel", "fc_in_bias", "fc_out_kernel", "fc_out_bias"),
+}
+
+
+def _in_dtype(group: str, p: Dict[str, Any], dtype) -> Dict[str, Any]:
+    """``p`` (the ``group`` sub-dict of a parameter tree) with the group's
+    COMPUTE_DTYPE_LEAVES as ``dtype``.  On a leaf already in ``dtype``
+    (a server's tree) the cast traces to nothing."""
+    names = COMPUTE_DTYPE_LEAVES[group]
+    return {k: v.astype(dtype) if k in names else v for k, v in p.items()}
+
+
+def serving_params(params: Dict[str, Any], cfg: GPTConfig) -> Dict[str, Any]:
+    """The tree a server should HOLD: every leaf the serving forwards cast
+    (COMPUTE_DTYPE_LEAVES) already in ``cfg.dtype``, every other leaf as
+    it is.  Training keeps float32 masters and casts once a step among
+    thousands of tokens of work; a decode step is its own dispatch with
+    the tree as an argument, so a float32 tree is converted again for a
+    handful of tokens, every step, to the same bits.  Each matmul consumed
+    ``round(w)`` before and consumes it after: logits do not change.
+
+    A ``float32`` configuration gets its argument back.  Otherwise the
+    casts run leaf by leaf, each waited for, and this function keeps no
+    reference to a leaf it has replaced: a caller that hands over its only
+    reference to the tree (``GenerationServer``) never holds two whole
+    trees.  Leaves keep their sharding: each device converts its shard."""
+    dtype = jnp.dtype(cfg.dtype)
+    if dtype == jnp.float32:
+        return params
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    del params
+    flat.reverse()
+    out = []
+    while flat:
+        path, x = flat.pop()
+        group, name = (getattr(k, "key", None) for k in path[-2:])
+        if name in COMPUTE_DTYPE_LEAVES.get(group, ()) and x.dtype != dtype:
+            x = jax.block_until_ready(x.astype(dtype))
+        out.append(x)
+    return treedef.unflatten(out)
+
+
+# ---------------------------------------------------------------------------
 # Cache-aware forward (shares weights with model.gpt_specs; the training
 # forward in model.py stays cache-free)
 # ---------------------------------------------------------------------------
@@ -119,8 +176,9 @@ def _layer_with_cache(
     b, t, h = x.shape
 
     y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
-    qkv = jnp.einsum("bsh,htnd->bstnd", y, p["attn"]["qkv_kernel"].astype(dtype))
-    qkv = qkv + p["attn"]["qkv_bias"].astype(dtype)[None, None]
+    attn = _in_dtype("attn", p["attn"], dtype)
+    qkv = jnp.einsum("bsh,htnd->bstnd", y, attn["qkv_kernel"])
+    qkv = qkv + attn["qkv_bias"][None, None]
     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
     q = _constrain(ctx, q, ("batch", None, "heads", "kv"))
 
@@ -158,15 +216,15 @@ def _layer_with_cache(
             k_scale=k_scale, v_scale=v_scale,
         )
     attn_out = jnp.einsum(
-        "bsnd,ndh->bsh", attn_out, p["attn"]["out_kernel"].astype(dtype)
-    ) + p["attn"]["out_bias"].astype(dtype)
+        "bsnd,ndh->bsh", attn_out, attn["out_kernel"]
+    ) + attn["out_bias"]
     x = x + attn_out
 
     y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"])
-    mp = p["mlp"]
-    y = y @ mp["fc_in_kernel"].astype(dtype) + mp["fc_in_bias"].astype(dtype)
+    mp = _in_dtype("mlp", p["mlp"], dtype)
+    y = y @ mp["fc_in_kernel"] + mp["fc_in_bias"]
     y = jax.nn.gelu(y, approximate=True)
-    y = y @ mp["fc_out_kernel"].astype(dtype) + mp["fc_out_bias"].astype(dtype)
+    y = y @ mp["fc_out_kernel"] + mp["fc_out_bias"]
     return x + y, k_cache, v_cache, k_scale, v_scale
 
 
@@ -188,8 +246,8 @@ def forward_cached(
     buckets (each row's real prompt right-aligned at the same width)."""
     dtype = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
-    word = params["embeddings"]["word"].astype(dtype)
-    pe = params["embeddings"]["position"].astype(dtype)
+    emb = _in_dtype("embeddings", params["embeddings"], dtype)
+    word, pe = emb["word"], emb["position"]
     if position_ids is None:
         x = word[tokens] + pe[pos + jnp.arange(t)][None, :, :]
     else:
@@ -863,8 +921,9 @@ def _paged_layer_step(
     n = cfg.num_attention_heads
 
     y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
-    qkv = jnp.einsum("bsh,htnd->bstnd", y, p["attn"]["qkv_kernel"].astype(dtype))
-    qkv = qkv + p["attn"]["qkv_bias"].astype(dtype)[None, None]
+    attn = _in_dtype("attn", p["attn"], dtype)
+    qkv = jnp.einsum("bsh,htnd->bstnd", y, attn["qkv_kernel"])
+    qkv = qkv + attn["qkv_bias"][None, None]
     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
     q = _constrain(ctx, q, ("batch", None, "heads", "kv"))
 
@@ -892,15 +951,15 @@ def _paged_layer_step(
         k_scale=k_scale, v_scale=v_scale,
     )
     attn_out = jnp.einsum(
-        "bsnd,ndh->bsh", attn_out, p["attn"]["out_kernel"].astype(dtype)
-    ) + p["attn"]["out_bias"].astype(dtype)
+        "bsnd,ndh->bsh", attn_out, attn["out_kernel"]
+    ) + attn["out_bias"]
     x = x + attn_out
 
     y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"])
-    mp = p["mlp"]
-    y = y @ mp["fc_in_kernel"].astype(dtype) + mp["fc_in_bias"].astype(dtype)
+    mp = _in_dtype("mlp", p["mlp"], dtype)
+    y = y @ mp["fc_in_kernel"] + mp["fc_in_bias"]
     y = jax.nn.gelu(y, approximate=True)
-    y = y @ mp["fc_out_kernel"].astype(dtype) + mp["fc_out_bias"].astype(dtype)
+    y = y @ mp["fc_out_kernel"] + mp["fc_out_bias"]
     return x + y, k_pool, v_pool, k_scale, v_scale
 
 
@@ -932,8 +991,8 @@ def paged_forward_step(
         tokens = tokens[:, None]
     B, t = tokens.shape
     dtype = jnp.dtype(cfg.dtype)
-    word = params["embeddings"]["word"].astype(dtype)
-    pe = params["embeddings"]["position"].astype(dtype)
+    emb = _in_dtype("embeddings", params["embeddings"], dtype)
+    word, pe = emb["word"], emb["position"]
     # per-slot positions; clamp inactive rows' (stale) and overrun
     # slots' embedding indices into the table
     pos_t = positions[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]

@@ -88,6 +88,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_serving_gen_errors_total": ("counter", "Generation failures"),
     "pfx_serving_last_latency_seconds": ("gauge", "Latency of the most recent generate_ids call"),
     "pfx_serving_warmup_seconds_total": ("counter", "Seconds spent in warmup compiles"),
+    "pfx_serving_params_bytes": ("gauge", "Bytes of the parameter tree the server holds, per leaf dtype, set when it is built (labels: dtype): a bf16 configuration holds its matmul and embedding leaves as bfloat16 and only the LayerNorm leaves as float32; weights under float32 there mean the tree is converted inside every decode step"),
     # request queue (core/request_queue.py)
     "pfx_queue_submitted_total": ("counter", "Requests admitted"),
     "pfx_queue_completed_total": ("counter", "Requests answered"),

@@ -448,3 +448,49 @@ def test_four_chip_train_step_compiles_with_kernel(topo, overrides):
     text = c.as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+def test_decode_step_13b_holds_one_copy_of_the_weights(topo):
+    """The docs cell's decode step (GPT-1.3B, 8 rows of 1024 tokens, 16-token
+    blocks) as the server dispatches it: with the tree it holds
+    (``serving_params``) the program's scratch has no second, converted copy
+    of the weights.  With the float32 tree ``temp`` was 2.62 GB beside 6.87 GB
+    of arguments (``pfx_bench/selftest/chip_compile.py serve gpt-1.3b 8
+    512,960``, which still passes float32 shapes)."""
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+    from paddlefleetx_tpu.models.gpt.generation import (
+        init_paged_pools,
+        paged_forward_step,
+        serving_params,
+    )
+
+    cfg = GPTConfig(
+        hidden_size=2048, num_layers=24, num_attention_heads=16,
+        ffn_hidden_size=8192, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0, dtype="bfloat16",
+    )
+    one = _one_chip(topo)
+    held = _shapes(one, jax.eval_shape(
+        lambda p: serving_params(p, cfg), _param_shapes(cfg, one)))
+    weights = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(held))
+    assert 2.6e9 < weights < 2.7e9
+    rows, bs, ctx_len = 8, 16, cfg.max_position_embeddings
+    pools = _shapes(one, jax.eval_shape(
+        lambda: init_paged_pools(cfg, rows * (ctx_len // bs) + 1, bs, kv_dtype="bf16")))
+    c = _compile(
+        lambda p, toks, pools, tb, ps, act: paged_forward_step(
+            p, toks, pools, tb, ps, act, cfg),
+        held, _shapes(one, ((rows,), jnp.int32)), pools,
+        _shapes(one, ((rows, ctx_len // bs), jnp.int32)),
+        _shapes(one, ((rows,), jnp.int32)), _shapes(one, ((rows,), jnp.bool_)),
+    )
+    assert _has_kernel(c)
+    m = c.memory_analysis()
+    # weights 2.63 GB + arena 1.61 GB + the small operands
+    assert 4.2e9 < m.argument_size_in_bytes < 4.4e9
+    # no converted copy of any stacked weight: the largest is 0.8 GB in bf16
+    assert m.temp_size_in_bytes < 0.5e9
+    text = c.as_text()
+    assert "bf16[24,2048,8192]{" in text  # the held fc_in, an operand as it is
+    assert "f32[24,2048,8192]" not in text and "f32[50304,2048]" not in text

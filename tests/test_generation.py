@@ -1,5 +1,7 @@
 """Generation tests: cached decode == uncached forward; sampling ops."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,10 @@ from paddlefleetx_tpu.models.gpt.generation import (
     forward_cached,
     generate,
     init_cache,
+    init_paged_pools,
+    paged_forward_step,
+    paged_prefill,
+    serving_params,
 )
 from paddlefleetx_tpu.ops.sampling import sample_top_p, top_k_filter, top_p_filter
 
@@ -359,3 +365,108 @@ def test_pad_prompts_bucket_width():
     assert lens.tolist() == [3, 70]
     assert padded[0, :125].sum() == 0  # left padding
     assert padded[0, 125:].tolist() == [1, 2, 3]
+
+
+# ---------------------------------------------------------------------------
+# serving_params: the tree a server holds is in the dtype the step computes
+# in (cast once, not inside every decode step), to the same bits
+# ---------------------------------------------------------------------------
+
+TINY_BF16 = dataclasses.replace(TINY, dtype="bfloat16")
+
+
+@pytest.fixture(scope="module")
+def trees():
+    """(float32 tree, cast tree) of the tiny bf16 model.  Every leaf is
+    perturbed: fresh biases are zeros and fresh scales ones, which round
+    to themselves whatever a cast does."""
+    p32 = gpt.init(TINY_BF16, jax.random.key(0))
+    leaves, treedef = jax.tree.flatten(p32)
+    keys = jax.random.split(jax.random.key(7), len(leaves))
+    p32 = treedef.unflatten([
+        x + 0.02 * jax.random.normal(k, x.shape, x.dtype)
+        for x, k in zip(leaves, keys)
+    ])
+    return p32, serving_params(p32, TINY_BF16)
+
+
+@pytest.mark.parametrize("group,want", [
+    ("embeddings", "bfloat16"), ("layers/attn", "bfloat16"),
+    ("layers/mlp", "bfloat16"), ("layers/ln_1", "float32"),
+    ("layers/ln_2", "float32"), ("final_ln", "float32"),
+])
+def test_serving_params_casts_what_the_forwards_cast_and_nothing_else(
+    trees, group, want
+):
+    p32, cast = trees
+    for part in group.split("/"):
+        p32, cast = p32[part], cast[part]
+    assert set(cast) == set(p32) and cast
+    for name, leaf in cast.items():
+        assert str(leaf.dtype) == want, (group, name)
+        # the served bits are the float32 leaf's, rounded once
+        np.testing.assert_array_equal(
+            np.asarray(leaf.astype(jnp.float32)),
+            np.asarray(p32[name].astype(leaf.dtype).astype(jnp.float32)),
+        )
+
+
+def test_serving_params_returns_a_float32_configurations_tree_itself():
+    params = gpt.init(TINY, jax.random.key(0))
+    assert serving_params(params, TINY) is params
+
+
+def _f32(tree):
+    return [np.asarray(x.astype(jnp.float32)) for x in jax.tree.leaves(tree)]
+
+
+def _paged_inputs(kv_dtype):
+    nb, bs = 9, 8
+    pools = init_paged_pools(TINY_BF16, nb, bs, kv_dtype=kv_dtype)
+    # two rows with some context already in their blocks, so attention
+    # reads pool contents and not only the step's own token
+    filled = jax.tree.map(
+        lambda x: (jax.random.normal(jax.random.key(5), x.shape) * 0.5).astype(x.dtype)
+        if x.dtype != jnp.int8
+        else jax.random.randint(jax.random.key(6), x.shape, -90, 90, jnp.int8),
+        pools,
+    )
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    return filled, tables
+
+
+def _run(case, kv_dtype, params):
+    cfg = TINY_BF16
+    if case == "forward_cached":
+        tokens = jax.random.randint(jax.random.key(1), (2, 16), 0, cfg.vocab_size)
+        cache = init_cache(cfg, 2, 32, kv_dtype=kv_dtype)
+        logits, cache = jax.jit(
+            lambda p: forward_cached(p, tokens, cache, jnp.int32(0), cfg))(params)
+        step, cache = jax.jit(
+            lambda p, c: forward_cached(p, tokens[:, :1], c, jnp.int32(16), cfg)
+        )(params, cache)
+        return logits, step, cache
+    pools, tables = _paged_inputs(kv_dtype)
+    if case == "paged_prefill":
+        prompt = jax.random.randint(jax.random.key(2), (1, 16), 0, cfg.vocab_size)
+        return jax.jit(lambda p: paged_prefill(
+            p, prompt, jnp.int32(13), pools, tables[0, :2], cfg))(params)
+    t = {"paged_step": 1, "paged_verify_chunk": 4}[case]
+    tokens = jax.random.randint(jax.random.key(3), (2, t), 0, cfg.vocab_size)
+    positions = jnp.asarray([11, 19], jnp.int32)
+    return jax.jit(lambda p: paged_forward_step(
+        p, tokens, pools, tables, positions, jnp.ones((2,), bool), cfg))(params)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize(
+    "case", ["forward_cached", "paged_step", "paged_verify_chunk", "paged_prefill"])
+def test_cast_tree_serves_bit_equal_logits_and_pools(trees, case, kv_dtype):
+    """Each matmul consumed round_bf16(w) from the float32 tree and
+    consumes the same bits from the cast one: not close, equal."""
+    p32, cast = trees
+    want, got = _f32(_run(case, kv_dtype, p32)), _f32(_run(case, kv_dtype, cast))
+    assert len(want) == len(got) >= 3
+    for w, g in zip(want, got):
+        assert np.isfinite(w).all()
+        np.testing.assert_array_equal(g, w)

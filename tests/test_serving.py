@@ -331,3 +331,213 @@ def test_cache_pool_is_lru_bounded(server):
         for prompt in ([[1, 2]], [[1, 2], [3, 4], [5, 6]]):
             server.generate_ids(prompt, max_dec_len=dec)
     assert len(server._cache_pool) <= server._cache_pool_size
+
+
+# ---------------------------------------------------------------------------
+# The server holds its weights in the compute dtype (cast once when built,
+# not inside every decode step), LayerNorm leaves float32, same tokens
+# ---------------------------------------------------------------------------
+
+LAYOUTS = {"one_device": (1, {}), "mp2": (2, {"mp_degree": 2})}
+
+
+def _bf16_module(layout, save_load=None):
+    """(cfg, mesh, module) of the tiny model with ``dtype: bfloat16``."""
+    import jax
+
+    from paddlefleetx_tpu.core.module import build_module
+    from paddlefleetx_tpu.parallel.env import init_dist_env
+    from paddlefleetx_tpu.utils.config import AttrDict, process_configs
+
+    n, dist = LAYOUTS[layout]
+    over = dict(TINY_OVERRIDES, Distributed=dist,
+                Model=dict(TINY_OVERRIDES["Model"], dtype="bfloat16"))
+    if save_load:
+        over["Engine"] = dict(
+            over["Engine"], save_load=dict(over["Engine"]["save_load"], **save_load))
+    cfg = process_configs(AttrDict.from_nested(over), num_devices=n)
+    return cfg, init_dist_env(cfg, devices=jax.devices()[:n]), build_module(cfg)
+
+
+def _bf16_server(layout, keep_float32=False):
+    """A tiny ``dtype: bfloat16`` server on ``layout``; ``keep_float32``
+    builds it as the parent did (the float32 tree kept, every forward
+    casting at its point of use)."""
+    import jax
+
+    from paddlefleetx_tpu.core import serving
+
+    cfg, mesh, module = _bf16_module(layout)
+    # every leaf perturbed: fresh biases (0) and scales (1) survive any cast
+    leaves, treedef = jax.tree.flatten(module.init_params(jax.random.key(11)))
+    keys = jax.random.split(jax.random.key(12), len(leaves))
+    params = treedef.unflatten([
+        x + 0.02 * jax.random.normal(k, x.shape, x.dtype)
+        for x, k in zip(leaves, keys)
+    ])
+    with pytest.MonkeyPatch.context() as mp:
+        if keep_float32:
+            mp.setattr(serving, "serving_params", lambda p, cfg: p)
+        return serving.GenerationServer(cfg, mesh, module, params=params)
+
+
+@pytest.fixture(scope="module", params=list(LAYOUTS))
+def bf16_pair(request):
+    return _bf16_server(request.param), _bf16_server(request.param, True)
+
+
+def _leaf_dtypes(tree):
+    import jax
+
+    return {
+        "/".join(str(k.key) for k in path): str(leaf.dtype)
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
+    }
+
+
+def test_server_holds_compute_dtype_weights_and_float32_layernorm(bf16_pair):
+    cast, kept = bf16_pair
+    assert set(_leaf_dtypes(kept.params).values()) == {"float32"}
+    dtypes = _leaf_dtypes(cast.params)
+    assert {p for p, d in dtypes.items() if d == "float32"} == {
+        f"{g}/{n}" for g in ("layers/ln_1", "layers/ln_2", "final_ln")
+        for n in ("scale", "bias")
+    }
+    assert set(dtypes.values()) == {"float32", "bfloat16"}
+    # the cast kept each leaf where the placement put it
+    import jax
+
+    for a, b in zip(jax.tree.leaves(cast.params), jax.tree.leaves(kept.params)):
+        assert a.sharding.is_equivalent_to(b.sharding, a.ndim)
+
+
+def _serve(server, scheduler, prompts, max_new):
+    if scheduler == "coalesce":
+        return [server.generate_ids([p], max_dec_len=max_new)[0] for p in prompts]
+    from paddlefleetx_tpu.core.continuous_batching import PagedDecodeEngine
+
+    eng = PagedDecodeEngine(server, max_batch=4)
+    slots = [eng.admit(p, max_new) for p in prompts[:2]]
+    eng.step()
+    slots += [eng.admit(p, max_new) for p in prompts[2:]]  # mid-decode
+    for _ in range(64):
+        eng.step()
+        if not eng.active.any():
+            break
+    assert not eng.active.any()
+    return [eng.slots[s].tokens for s in slots]
+
+
+@pytest.mark.parametrize("scheduler", ["coalesce", "continuous"])
+def test_greedy_tokens_equal_a_server_that_keeps_the_float32_tree(
+    bf16_pair, scheduler
+):
+    cast, kept = bf16_pair
+    prompts = [[1, 2, 3], [4, 5, 6, 7, 8], [9, 10], [11, 12, 13, 14]]
+    want = _serve(kept, scheduler, prompts, 6)
+    assert all(len(t) == 6 for t in want)
+    assert _serve(cast, scheduler, prompts, 6) == want
+
+
+def _weight_converts(jaxpr, params=None):
+    """convert_element_type equations, at any depth, whose operand is a
+    parameter of two or more dimensions: an input of the traced function
+    (which takes the tree alone) or, inside the layer scan, the layer's
+    slice of one."""
+    import jax
+
+    # by identity: a jaxpr's literals are not hashable
+    params = {id(v) for v in jaxpr.invars} if params is None else params
+    found = []
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "convert_element_type"
+                and id(eqn.invars[0]) in params
+                and eqn.invars[0].aval.ndim >= 2):
+            found.append(
+                (eqn.invars[0].aval.str_short(), str(eqn.params["new_dtype"])))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if len(sub.invars) == len(eqn.invars):  # scan, pjit: one to one
+                found += _weight_converts(sub, {
+                    id(inner) for inner, outer in zip(sub.invars, eqn.invars)
+                    if id(outer) in params
+                })
+    return found
+
+
+def _decode_step_jaxpr(server, scheduler, params):
+    import jax
+    import jax.numpy as jnp
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    mcfg = server.module.config
+    if scheduler == "coalesce":
+        cache = G.init_cache(mcfg, 2, 32)
+        return jax.make_jaxpr(lambda p: G.forward_cached(
+            p, jnp.zeros((2, 1), jnp.int32), cache, jnp.int32(16), mcfg,
+            server.ctx))(params)
+    B, v = 2, mcfg.vocab_size
+    pools = G.init_paged_pools(mcfg, 9, 8)
+    rows = G.PagedRows(
+        jnp.zeros((B, v), jnp.float32), jnp.zeros((B, v), jnp.int32),
+        jnp.full((B,), 5, jnp.int32), jnp.zeros((B,), jnp.int32),
+        jnp.full((B,), 8, jnp.int32), jnp.ones((B,), bool),
+        jnp.full((B,), 7, jnp.int32),
+    )
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    return jax.make_jaxpr(lambda p: G.decode_step(
+        p, pools, tables, rows, mcfg, server.gen, ctx=server.ctx))(params)
+
+
+@pytest.mark.parametrize("scheduler", ["coalesce", "continuous"])
+def test_decode_step_converts_no_weight_of_the_held_tree(bf16_pair, scheduler):
+    """The guard for a weight added to a forward and not to
+    ``COMPUTE_DTYPE_LEAVES``: it would be converted inside every step."""
+    cast, kept = bf16_pair
+    with cast.mesh:
+        assert _weight_converts(
+            _decode_step_jaxpr(cast, scheduler, cast.params).jaxpr) == []
+        # the probe sees what the parent did: the two tables, a layer's
+        # four matrices and its one bias of more than one dimension
+        seen = _weight_converts(
+            _decode_step_jaxpr(kept, scheduler, kept.params).jaxpr)
+    assert len(seen) == 7 and {d for _, d in seen} == {"bfloat16"}
+
+
+def test_metrics_show_the_held_trees_bytes_by_dtype():
+    """``GET /metrics`` is ``get_registry().render_prometheus()``."""
+    import jax
+
+    from paddlefleetx_tpu.utils.telemetry import get_registry
+
+    server = _bf16_server("one_device")
+    want = {}
+    for leaf in jax.tree.leaves(server.params):
+        want[str(leaf.dtype)] = want.get(str(leaf.dtype), 0) + leaf.nbytes
+    assert want["float32"] < want["bfloat16"]
+    lines = get_registry().render_prometheus().splitlines()
+    for name, nbytes in want.items():
+        row = [ln for ln in lines
+               if ln.startswith(f'pfx_serving_params_bytes{{dtype="{name}"}}')]
+        assert len(row) == 1 and float(row[0].split()[-1]) == nbytes, row
+
+
+def test_server_built_without_params_restores_the_configured_checkpoint(tmp_path):
+    """The server owns the tree it casts: with no ``params=`` it restores
+    ``Engine.save_load.ckpt_dir`` itself (``tools/serve.py`` passes none)."""
+    import jax
+    import numpy as np
+
+    from paddlefleetx_tpu.core.serving import GenerationServer
+    from paddlefleetx_tpu.utils.checkpoint import save_params_checkpoint
+
+    cfg, mesh, module = _bf16_module(
+        "one_device", save_load={"ckpt_dir": str(tmp_path / "ckpt")})
+    saved = module.init_params(jax.random.key(21))
+    save_params_checkpoint(str(tmp_path / "ckpt"), saved, "test", {})
+    server = GenerationServer(cfg, mesh, module)
+    for got, want in zip(jax.tree.leaves(server.params), jax.tree.leaves(saved)):
+        np.testing.assert_array_equal(
+            np.asarray(got.astype("float32")),
+            np.asarray(want.astype(got.dtype).astype("float32")))
+    assert str(server.params["layers"]["mlp"]["fc_in_kernel"].dtype) == "bfloat16"

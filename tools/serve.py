@@ -76,10 +76,6 @@ def build_server(config: str, overrides):
     mesh = init_dist_env(cfg)
     module = build_module(cfg)
 
-    from paddlefleetx_tpu.utils.checkpoint import load_pretrained_params
-
-    params = load_pretrained_params(cfg)
-
     tok = None
     tokenizer_dir = cfg.get("Generation", {}).get("tokenizer_dir")
     if tokenizer_dir:
@@ -87,7 +83,10 @@ def build_server(config: str, overrides):
 
         tok = GPTTokenizer.from_pretrained(tokenizer_dir)
 
-    return GenerationServer(cfg, mesh, module, params=params, tokenizer=tok)
+    # no params: the server restores Engine.save_load.ckpt_dir (or makes
+    # a tree from the seed) itself, so that it owns the float32 leaves it
+    # casts and frees one by one
+    return GenerationServer(cfg, mesh, module, tokenizer=tok)
 
 
 def clamp_max_tokens(requested, default: int, cap: int) -> int:
