@@ -1,6 +1,6 @@
 # Developer entry points
 
-.PHONY: lint test-fast test-mid test-std test-all test-fault test-serve-drill test-data-drill test-obs test-paged test-prefix test-spec test-trace test-router test-elastic test-disagg test-parallel test-fleet-obs test-decode-overlap test-kv-tier test-tenant test-ha test-goodput bench bench-check
+.PHONY: lint test-fast test-mid test-std test-all test-fault test-serve-drill test-data-drill test-obs test-paged test-prefix test-spec test-trace test-router test-elastic test-disagg test-parallel test-fleet-obs test-decode-overlap test-kv-tier test-tenant test-ha test-goodput
 
 # stdlib AST lint gate (no ruff/flake8 in the image): unused imports,
 # bare except, eval/exec, tabs, trailing whitespace, mutable defaults
@@ -16,7 +16,6 @@ FAST_FILES = tests/test_config.py tests/test_tokenizer.py tests/test_data.py \
              tests/test_serving.py tests/test_request_queue.py \
              tests/test_chunked_ce.py tests/test_lint.py \
              tests/test_telemetry.py tests/test_tracing.py \
-             tests/test_bench_helpers.py tests/test_bench_cases.py \
              tests/test_router.py tests/test_controller.py \
              tests/test_prefix_cache.py tests/test_shard_map_compat.py \
              tests/test_fleet_obs.py tests/test_tenancy.py \
@@ -119,14 +118,12 @@ test-decode-overlap:
 # shared-prefix KV reuse gate: refcount/radix-index/COW host units, the
 # engine-level reuse + chunked-prefill parity suite (prefix hits, COW
 # divergence, eviction-under-pressure, ArenaReset index rebuild, the
-# decision-log replay contract), the prefix CLI drill, and the
-# prefix-heavy decode-bench A/B contract (docs/serving.md "Prefix
-# cache")
+# decision-log replay contract) and the prefix CLI drill
+# (docs/serving.md "Prefix cache")
 test-prefix:
 	python -m pytest tests/test_prefix_cache.py -q
 	python -m pytest tests/test_continuous_batching.py -q -k "prefix or chunked or cow or accounting or arena_reset or pressure"
 	python -m pytest "tests/test_paged_drills.py::test_prefix_cache_and_chunked_prefill_through_real_cli" -q
-	python -m pytest tests/test_bench_contract.py -q -k "decode_happy"
 
 # fleet KV-durability gate: the host-RAM spill tier (store units,
 # spill -> readmit parity, spill_corrupt degrade-to-recompute,
@@ -134,22 +131,19 @@ test-prefix:
 # prefix migration (export/adopt cross-engine, torn-payload whole
 # rejection, the PFXH1 truncation fuzz), prefix-affinity routing units,
 # and the slow+fault rolling-drain CLI drills — migrate-under-stall
-# adoption and the wedged-receiver drain-deadline floor — plus the
-# spill decode-bench A/B contract (docs/serving.md "KV lifecycle")
+# adoption and the wedged-receiver drain-deadline floor
+# (docs/serving.md "KV lifecycle")
 test-kv-tier:
 	python -m pytest tests/test_kv_tier.py tests/test_kv_handoff.py -q
-	python -m pytest tests/test_bench_contract.py -q -k "decode_happy"
 
 # serving goodput-ledger gate: time/token ledger closure units (exact
 # token closure + <=1% time closure under a seeded adversarial mix),
 # the fault-marked closure + fleet-profiling drills through the real
-# serve/router CLIs, the train-ledger record surface, and the
-# dispatch-ahead goodput_frac bench contract (docs/observability.md
-# "Goodput ledger" + "On-demand profiling")
+# serve/router CLIs and the train-ledger record surface
+# (docs/observability.md "Goodput ledger" + "On-demand profiling")
 test-goodput:
 	python -m pytest tests/test_goodput.py tests/test_tracing.py -q -m "not slow"
 	python -m pytest "tests/test_engine.py::test_metrics_file_stream" -q
-	python -m pytest tests/test_bench_contract.py -q -k "decode_happy"
 	python tools/lint.py
 
 # multi-tenant isolation gate: tenancy units (quotas/DRR/label cap/header
@@ -163,11 +157,10 @@ test-tenant:
 # speculative-decoding + KV-quant gate: drafter/accept units, greedy
 # parity (contiguous + paged, incl. full-rejection iterations), int8
 # kernel tolerance + arena-bytes halving, the sampled
-# distribution-preservation statistical test, serving-config routing,
-# and the spec/kvint8 decode-bench A/B contract (docs/decode_path.md)
+# distribution-preservation statistical test and serving-config routing
+# (docs/decode_path.md)
 test-spec:
 	python -m pytest tests/test_speculative.py -q
-	python -m pytest tests/test_bench_contract.py -q -k "decode"
 
 # multi-host router gate: router-core units against stub replicas (no
 # model), the KV-handoff codec + export/adopt parity suite, and the
@@ -218,12 +211,3 @@ test-parallel:
 	python -m pytest tests/test_shard_map_compat.py tests/test_pipeline.py tests/test_long_context.py tests/test_mesh_sharding.py tests/test_distributed.py -q
 	python -m pytest "tests/test_engine.py::test_layout_loss_parity_first_step" -q
 	python -m pytest tests/test_golden_docs.py -q
-
-bench:
-	python benchmarks/run_benchmark.py
-
-# bench-trajectory gate: newest two BENCH_r*.json compared, >10%
-# regression of any shared metric fails; backend-unreachable rows are
-# skipped loudly (tools/bench_check.py)
-bench-check:
-	python tools/bench_check.py

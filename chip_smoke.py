@@ -681,23 +681,18 @@ def phase_train(args) -> int:
         if not args.rehearse and not has_kernel:
             fails.append("no tpu_custom_call in the compiled train step")
 
-        # step time under both fences (information for the benchmark PR):
-        # block_until_ready on everything the step returns, and bench.py's
-        # one-element host fetch
-        from bench import host_fence
-
-        def timed(fence, n=5):
+        # step time fenced by block_until_ready on everything the step returns
+        def timed(n=5):
             out = []
             for _ in range(n):
                 t = time.perf_counter()
                 engine.state, m = engine.train_step(engine.state, dev_batch)
-                fence((engine.state, m))
+                jax.block_until_ready((engine.state, m))
                 out.append(time.perf_counter() - t)
             return statistics.median(out)
 
-        timed(jax.block_until_ready, n=2)  # drain + settle
-        step_block = timed(jax.block_until_ready)
-        step_fetch = timed(lambda out: host_fence(out[1]["loss"]))
+        timed(n=2)  # drain + settle
+        step_block = timed()
 
     steady = statistics.median([r["step_s"] for r in recs[2:]] or [recs[-1]["step_s"]])
     ckpt = os.path.join(out_dir, f"step_{args.steps}")
@@ -709,7 +704,6 @@ def phase_train(args) -> int:
         steady_step_s=round(steady, 4),
         tokens_per_s=round(shape["batch"] * seq / steady, 1),
         step_s_block_until_ready=round(step_block, 4),
-        step_s_host_fetch=round(step_fetch, 4),
         kernel_in_step=has_kernel, compile_events=len(events),
         index_helper=("built library" if indexed._LIB is not None
                       else "numpy fallback"),

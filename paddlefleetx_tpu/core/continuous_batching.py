@@ -334,7 +334,7 @@ class PagedDecodeEngine:
         # "prefill_chunks" counts chunk dispatches)
         # ("host_gap_s"/"gap_steps" measure host time the device sat
         # idle between consuming one step's results and receiving the
-        # next dispatch — benchmarks/bench_decode.py's host_gap_ms)
+        # next dispatch — the benchmark's sched.host_gap_share)
         # (goodput time-ledger accumulators: wall time THIS thread spent
         # in each phase — t_device_decode covers decode dispatches,
         # t_device_prefill every donating dispatch (prefill / chunk /
@@ -1547,7 +1547,7 @@ class PagedDecodeEngine:
             else np.zeros((self.capacity, 1), np.int32)
         )
         fn = self._step_fn(M)
-        # host-gap accounting (bench_decode's host_gap_ms): host time
+        # host-gap accounting (pfx_sched_host_gap_seconds_total): host time
         # between consuming one step's results and handing the device
         # its next dispatch.  A chained dispatch lands while the
         # previous step is still in flight — the device never waits on

@@ -53,8 +53,8 @@ class GPTConfig:
     flash_bwd: str = ""
     # unroll factor for the scan over layers (lax.scan unroll=N): trades
     # compile time + code size for removing the scan-boundary stacking
-    # copies a profile showed at ~4% of step time (builder-recorded,
-    # docs/performance_tuning.md op table; not re-measured).
+    # copies (docs/performance_tuning.md "Scan unroll"; not measured on
+    # today's code).
     # 1 = rolled (default); must divide num_layers
     scan_unroll: int = 1
     # ring attention inner K-block (attn_impl="ring"): bounds the per-ring-

@@ -7,21 +7,19 @@ and ``processor.py`` (LogitsProcessorList etc.).
 TPU-native shape discipline: the reference's dynamic Python while-loop
 becomes a bounded ``lax.while_loop`` over ``max_dec_len`` slots with an
 ``unfinished`` flag (padded static shapes; XLA traces one step) that exits
-as soon as every row has emitted EOS; ``PFX_DECODE_SCAN=1`` restores the
-fixed-trip ``lax.scan`` (trace-shape debugging; beam search keeps scan).
+as soon as every row has emitted EOS (beam search keeps a fixed-trip
+``lax.scan``).
 The KV cache is a preallocated [layers, b, heads, max_len, head_dim] pair
 (heads-major so the flash-decode kernel's block tiling keeps (seq, dim)
 minor — ``ops/decode_attention.py``) updated with ``dynamic_update_slice``;
 prefill packs the prompt in one forward.  The decode step attends only
-over cache blocks ``< ceil((pos+t)/block)``, not the whole buffer; set
-PFX_DECODE_ATTN=dense for the legacy attend-over-everything path.
+over cache blocks ``< ceil((pos+t)/block)``, not the whole buffer.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +28,6 @@ from paddlefleetx_tpu.models.gpt.config import GPTConfig
 from paddlefleetx_tpu.models.gpt.model import ShardingCtx, _constrain, layer_norm
 from paddlefleetx_tpu.ops.decode_attention import (
     decode_attention,
-    decode_attn_mode,
-    dense_cache_attention,
     kv_cache_dtype,
     kv_cache_len,
     paged_decode_attention,
@@ -147,6 +143,40 @@ def serving_params(params: Dict[str, Any], cfg: GPTConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
+def _decoder_layer(
+    p: Dict[str, Any], x: jax.Array, ctx: Optional[ShardingCtx], attend: Callable
+):
+    """One GPT-2 decoder layer over x [b, t, h], in the serving forwards'
+    spelling.  ``attend(q, k, v)`` is the caller's half: it writes the
+    chunk's k/v [b, t, heads, head_dim] into its cache, attends q over
+    that cache and returns ``(attn_out [b, t, heads, head_dim], cache
+    state)``; the state comes back beside the layer's output untouched.
+    Under TP serving (reference GPTForGenerationHybrid
+    hybrid_model.py:1209) q and the caches stay ``heads``-sharded over
+    the model axis and GSPMD inserts the output projection's row-psum."""
+    dtype = x.dtype
+
+    y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
+    attn = _in_dtype("attn", p["attn"], dtype)
+    qkv = jnp.einsum("bsh,htnd->bstnd", y, attn["qkv_kernel"])
+    qkv = qkv + attn["qkv_bias"][None, None]
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    q = _constrain(ctx, q, ("batch", None, "heads", "kv"))
+
+    attn_out, kv_state = attend(q, k, v)
+    attn_out = jnp.einsum(
+        "bsnd,ndh->bsh", attn_out, attn["out_kernel"]
+    ) + attn["out_bias"]
+    x = x + attn_out
+
+    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"])
+    mp = _in_dtype("mlp", p["mlp"], dtype)
+    y = y @ mp["fc_in_kernel"] + mp["fc_in_bias"]
+    y = jax.nn.gelu(y, approximate=True)
+    y = y @ mp["fc_out_kernel"] + mp["fc_out_bias"]
+    return x + y, kv_state
+
+
 def _layer_with_cache(
     p: Dict[str, Any],
     x: jax.Array,
@@ -164,68 +194,42 @@ def _layer_with_cache(
     Attends over cache[:pos+t] via the length-aware blocked kernel
     (``ops/decode_attention``): only cache blocks up to ceil((pos+t)/block)
     are visited, with the causal + ``kv_valid_from`` left-pad masks folded
-    into per-block masking.  PFX_DECODE_ATTN=dense restores the legacy
-    materialized-bias attend-over-the-whole-buffer path (A/B benching).
-    Under TP serving (reference GPTForGenerationHybrid hybrid_model.py:1209)
-    the qkv/cache/attention stay ``heads``-sharded over the model axis and
-    the output projection row-psum is inserted by GSPMD; the sharded path
-    uses the lax spelling of the blocked loop (GSPMD partitions it freely,
-    a pallas_call would need shard_map).
+    into per-block masking.  The sharded path uses the lax spelling of the
+    blocked loop (GSPMD partitions it freely, a pallas_call would need
+    shard_map).
     """
-    dtype = x.dtype
-    b, t, h = x.shape
-
-    y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
-    attn = _in_dtype("attn", p["attn"], dtype)
-    qkv = jnp.einsum("bsh,htnd->bstnd", y, attn["qkv_kernel"])
-    qkv = qkv + attn["qkv_bias"][None, None]
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = _constrain(ctx, q, ("batch", None, "heads", "kv"))
-
-    # cache layout [b, heads, max_len, head_dim]: transpose the (small)
-    # step chunk, never the cache.  Under int8 the chunk quantizes HERE
-    # (quantize-on-write) and the scale planes update alongside — the
-    # kernels below dequantize in-kernel, so the cache only ever streams
-    # as int8.
-    kc = k.transpose(0, 2, 1, 3)
-    vc = v.transpose(0, 2, 1, 3)
-    if k_scale is not None:
-        kq, ks = quantize_kv(kc)
-        vq, vs = quantize_kv(vc)
-        k_cache = jax.lax.dynamic_update_slice(k_cache, kq, (0, 0, pos, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, vq, (0, 0, pos, 0))
-        k_scale = jax.lax.dynamic_update_slice(k_scale, ks, (0, 0, pos))
-        v_scale = jax.lax.dynamic_update_slice(v_scale, vs, (0, 0, pos))
-        k_scale = _constrain(ctx, k_scale, ("batch", "heads", None))
-        v_scale = _constrain(ctx, v_scale, ("batch", "heads", None))
-    else:
-        k_cache = jax.lax.dynamic_update_slice(k_cache, kc, (0, 0, pos, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, vc, (0, 0, pos, 0))
-    k_cache = _constrain(ctx, k_cache, ("batch", "heads", None, "kv"))
-    v_cache = _constrain(ctx, v_cache, ("batch", "heads", None, "kv"))
-
-    if decode_attn_mode() == "dense":
-        attn_out = dense_cache_attention(
-            q, k_cache, v_cache, pos, kv_valid_from=kv_valid_from,
-            k_scale=k_scale, v_scale=v_scale,
-        )
-    else:
+    def attend(q, k, v):
+        # cache layout [b, heads, max_len, head_dim]: transpose the (small)
+        # step chunk, never the cache.  Under int8 the chunk quantizes HERE
+        # (quantize-on-write) and the scale planes update alongside — the
+        # kernels below dequantize in-kernel, so the cache only ever streams
+        # as int8.
+        kc = k.transpose(0, 2, 1, 3)
+        vc = v.transpose(0, 2, 1, 3)
+        if k_scale is not None:
+            kq, ks = quantize_kv(kc)
+            vq, vs = quantize_kv(vc)
+            k_new = jax.lax.dynamic_update_slice(k_cache, kq, (0, 0, pos, 0))
+            v_new = jax.lax.dynamic_update_slice(v_cache, vq, (0, 0, pos, 0))
+            ks_new = jax.lax.dynamic_update_slice(k_scale, ks, (0, 0, pos))
+            vs_new = jax.lax.dynamic_update_slice(v_scale, vs, (0, 0, pos))
+            ks_new = _constrain(ctx, ks_new, ("batch", "heads", None))
+            vs_new = _constrain(ctx, vs_new, ("batch", "heads", None))
+        else:
+            k_new = jax.lax.dynamic_update_slice(k_cache, kc, (0, 0, pos, 0))
+            v_new = jax.lax.dynamic_update_slice(v_cache, vc, (0, 0, pos, 0))
+            ks_new = vs_new = None
+        k_new = _constrain(ctx, k_new, ("batch", "heads", None, "kv"))
+        v_new = _constrain(ctx, v_new, ("batch", "heads", None, "kv"))
         attn_out = decode_attention(
-            q, k_cache, v_cache, pos, kv_valid_from=kv_valid_from,
+            q, k_new, v_new, pos, kv_valid_from=kv_valid_from,
             impl="lax" if ctx is not None else "auto",
-            k_scale=k_scale, v_scale=v_scale,
+            k_scale=ks_new, v_scale=vs_new,
         )
-    attn_out = jnp.einsum(
-        "bsnd,ndh->bsh", attn_out, attn["out_kernel"]
-    ) + attn["out_bias"]
-    x = x + attn_out
+        return attn_out, (k_new, v_new, ks_new, vs_new)
 
-    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"])
-    mp = _in_dtype("mlp", p["mlp"], dtype)
-    y = y @ mp["fc_in_kernel"] + mp["fc_in_bias"]
-    y = jax.nn.gelu(y, approximate=True)
-    y = y @ mp["fc_out_kernel"] + mp["fc_out_bias"]
-    return x + y, k_cache, v_cache, k_scale, v_scale
+    x, kv_state = _decoder_layer(p, x, ctx, attend)
+    return (x, *kv_state)
 
 
 def forward_cached(
@@ -368,17 +372,6 @@ class GenerationConfig:
             )
 
 
-def decode_loop_mode() -> str:
-    """PFX_DECODE_SCAN: "1" restores the fixed-trip ``lax.scan`` decode
-    loop (trace-shape debugging; also what beam search always uses), "0"/
-    unset selects the early-exit ``lax.while_loop``.  Loud parse — a typo
-    must not silently A/B while-vs-while on a chip window."""
-    env = os.environ.get("PFX_DECODE_SCAN") or "0"
-    if env not in ("0", "1"):
-        raise ValueError(f"PFX_DECODE_SCAN={env!r}; valid: 0, 1")
-    return "scan" if env == "1" else "while"
-
-
 def _left_pad_prefill(prompt_len: int, prompt_lens: Optional[jax.Array]):
     """(pad_len [b], prefill position ids [b, P]) for left-padded buckets;
     (None, None) on the unpadded path."""
@@ -506,12 +499,6 @@ def generate(
                 "(the beam loop reorders the cache by parent each step)"
             )
         return beam_search(params, input_ids, cfg, gen, ctx=ctx, prompt_lens=prompt_lens)
-    if spec is not None and decode_loop_mode() == "scan":
-        raise ValueError(
-            "speculative decoding needs the early-exit while-loop decode "
-            "(variable tokens per iteration); unset PFX_DECODE_SCAN"
-        )
-
     pad_len, prefill_pos_ids = _left_pad_prefill(prompt_len, prompt_lens)
     if cache is None:
         cache = init_cache(cfg, b, cache_len)
@@ -602,18 +589,11 @@ def generate(
         token_counts=token_counts0,
         key=key,
     )
-    if decode_loop_mode() == "scan":
-        carry, tokens = jax.lax.scan(step, carry0, jnp.arange(gen.max_dec_len))
-        tokens = tokens.T  # [b, max_dec_len]
-        return (tokens, carry.cache) if return_cache else tokens
-
-    # early-exit while_loop: the scan runs all max_dec_len steps even after
-    # every row emitted EOS (each a full forward over the batch); the while
-    # loop stops as soon as nothing is unfinished.  Token-for-token parity
-    # with the scan: the buffer starts pad-filled, and the scan likewise
-    # emits pad for every step after all rows finish (nxt is forced to
-    # pad_token_id once unfinished is False), so skipped slots are
-    # identical — asserted by tests/test_generation.py.
+    # early-exit while_loop: stops as soon as nothing is unfinished (a
+    # fixed-trip loop would run a full forward over the batch for every
+    # remaining slot).  The buffer starts pad-filled, which is what a
+    # finished row emits (nxt is forced to pad_token_id once unfinished
+    # is False), so the skipped slots read as if they had run.
     tokens0 = jnp.full((b, gen.max_dec_len), gen.pad_token_id, jnp.int32)
 
     def loop_cond(st):
@@ -916,51 +896,36 @@ def _paged_layer_step(
     the speculative verify chunk), then block-table paged attention with
     per-query causal bounds.  Under int8 the chunk quantizes on write
     and the per-slot scales land in the arena's scale planes."""
-    dtype = x.dtype
-    b, t, _ = x.shape
     n = cfg.num_attention_heads
 
-    y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
-    attn = _in_dtype("attn", p["attn"], dtype)
-    qkv = jnp.einsum("bsh,htnd->bstnd", y, attn["qkv_kernel"])
-    qkv = qkv + attn["qkv_bias"][None, None]
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = _constrain(ctx, q, ("batch", None, "heads", "kv"))
+    def attend(q, k, v):
+        # scatter the [b, t, n, d] chunk into each row's blocks: rows own
+        # disjoint blocks and a row's t slots are distinct, so the only
+        # index collisions are inactive/overrun rows' null-block writes
+        # (garbage-on-garbage, never read)
+        idx_b = blk[:, :, None]                  # [b, t, 1]
+        idx_n = jnp.arange(n)[None, None, :]     # [1, 1, n]
+        idx_o = off[:, :, None]
+        if k_scale is not None:
+            kq, ks = quantize_kv(k)
+            vq, vs = quantize_kv(v)
+            k_new = k_pool.at[idx_b, idx_n, idx_o, :].set(kq)
+            v_new = v_pool.at[idx_b, idx_n, idx_o, :].set(vq)
+            ks_new = k_scale.at[idx_b, idx_n, idx_o].set(ks)
+            vs_new = v_scale.at[idx_b, idx_n, idx_o].set(vs)
+        else:
+            k_new = k_pool.at[idx_b, idx_n, idx_o, :].set(k.astype(k_pool.dtype))
+            v_new = v_pool.at[idx_b, idx_n, idx_o, :].set(v.astype(v_pool.dtype))
+            ks_new = vs_new = None
+        attn_out = paged_decode_attention(
+            q, k_new, v_new, tables, positions,
+            impl="lax" if ctx is not None else "auto",
+            k_scale=ks_new, v_scale=vs_new,
+        )
+        return attn_out, (k_new, v_new, ks_new, vs_new)
 
-    # scatter the [b, t, n, d] chunk into each row's blocks: rows own
-    # disjoint blocks and a row's t slots are distinct, so the only index
-    # collisions are inactive/overrun rows' null-block writes
-    # (garbage-on-garbage, never read)
-    idx_b = blk[:, :, None]                  # [b, t, 1]
-    idx_n = jnp.arange(n)[None, None, :]     # [1, 1, n]
-    idx_o = off[:, :, None]
-    if k_scale is not None:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        k_pool = k_pool.at[idx_b, idx_n, idx_o, :].set(kq)
-        v_pool = v_pool.at[idx_b, idx_n, idx_o, :].set(vq)
-        k_scale = k_scale.at[idx_b, idx_n, idx_o].set(ks)
-        v_scale = v_scale.at[idx_b, idx_n, idx_o].set(vs)
-    else:
-        k_pool = k_pool.at[idx_b, idx_n, idx_o, :].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[idx_b, idx_n, idx_o, :].set(v.astype(v_pool.dtype))
-
-    attn_out = paged_decode_attention(
-        q, k_pool, v_pool, tables, positions,
-        impl="lax" if ctx is not None else "auto",
-        k_scale=k_scale, v_scale=v_scale,
-    )
-    attn_out = jnp.einsum(
-        "bsnd,ndh->bsh", attn_out, attn["out_kernel"]
-    ) + attn["out_bias"]
-    x = x + attn_out
-
-    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"])
-    mp = _in_dtype("mlp", p["mlp"], dtype)
-    y = y @ mp["fc_in_kernel"] + mp["fc_in_bias"]
-    y = jax.nn.gelu(y, approximate=True)
-    y = y @ mp["fc_out_kernel"] + mp["fc_out_bias"]
-    return x + y, k_pool, v_pool, k_scale, v_scale
+    x, kv_state = _decoder_layer(p, x, ctx, attend)
+    return (x, *kv_state)
 
 
 def paged_forward_step(

@@ -50,9 +50,6 @@ Env knobs (PFX_FLASH_* loud-parse convention — an invalid value raises
 instead of silently mislabeling a chip sweep):
 
   PFX_DECODE_BLOCK  kv block size (default 256; positive multiple of 8)
-  PFX_DECODE_ATTN   "blocked" (default) | "dense" — generation-layer
-                    dispatch, read at trace time; "dense" restores the
-                    attend-over-everything path for A/B benching
   PFX_KV_DTYPE      "bf16" (default: the cache stays in the model dtype)
                     | "int8" — int8 KV-cache quantization.  Quantize
                     happens ON WRITE (generation-layer scatter paths,
@@ -179,18 +176,6 @@ def quantize_kv(x: jax.Array):
         jnp.round(xf / scl[..., None]), -KV_QMAX, KV_QMAX
     ).astype(jnp.int8)
     return q, scl
-
-
-def decode_attn_mode() -> str:
-    """PFX_DECODE_ATTN dispatch read by the generation layer at trace
-    time: "blocked" (this op) or "dense" (the legacy attend-over-the-
-    whole-cache path, kept for A/B rows)."""
-    mode = os.environ.get("PFX_DECODE_ATTN") or "blocked"
-    if mode not in ("blocked", "dense"):
-        raise ValueError(
-            f"PFX_DECODE_ATTN={mode!r}; valid: blocked, dense"
-        )
-    return mode
 
 
 def blocks_visited(limit, block: int, max_len: int):
@@ -826,21 +811,19 @@ def dense_cache_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """The legacy decode attention: attend over the ENTIRE preallocated
-    cache with a materialized [., 1, t, max_len] additive bias (what
-    ``_layer_with_cache`` did via ``xla_attention`` before the blocked
-    kernel).  Kept verbatim-in-semantics for PFX_DECODE_ATTN=dense A/B
-    benchmark rows; same [b, n, L, d] cache layout, no extra transposes,
-    so a legacy row measures the old math, not a layout penalty.  An
-    int8 cache is simply dequantized up front — this path exists for
-    honest legacy A/B rows, not for HBM savings."""
+    """The REFERENCE the blocked kernels are tested against
+    (tests/test_decode_attention.py); no program path calls it.  Attends
+    over the ENTIRE preallocated cache with a materialized
+    [., 1, t, max_len] additive bias: the plain softmax(q k^T) v with the
+    causal and ``kv_valid_from`` masks written out, in the kernels' own
+    [b, n, L, d] cache layout.  An int8 cache is dequantized up front."""
     b, t, n, d = q.shape
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
     if k_scale is not None:
         # dequantize in f32 and cast the PRODUCT once: the blocked/paged
-        # kernels apply scales in f32, and an A/B row comparing against
-        # them must not carry extra bf16-rounded-scale error
+        # kernels apply scales in f32, and a comparison against them
+        # must not carry extra bf16-rounded-scale error
         k_cache = (
             k_cache.astype(jnp.float32) * k_scale[..., None]
         ).astype(q.dtype)

@@ -89,7 +89,7 @@ def adamw(
     (multi_precision=True, the default) the second moment stays fp32;
     under ``Optimizer.multi_precision: False`` optax inits both moments
     from the bf16 params, so nu is bf16 too — that full-bf16 trade is the
-    1.3B single-chip recipe (benchmarks/bench_extra.py gpt1p3b case) and is engine-gated to
+    1.3B single-chip recipe and is engine-gated to
     bfloat16 compute (fp16 nu would underflow)."""
     txs = []
     if grad_clip:

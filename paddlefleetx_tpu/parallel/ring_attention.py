@@ -73,8 +73,8 @@ def _softmax_update(q, k_c, v_c, m, l, acc, q_pos, k_pos, causal, scale):
     alpha = jnp.exp(m - m_new)
     l_new = l * alpha + p.sum(axis=-1)
     # p in the V dtype: a bf16 p x bf16 v einsum runs the MXU at full
-    # rate (fp32 operands quarter it — same finding as the flash kernels,
-    # docs/performance_tuning.md op table); accumulation stays fp32 via
+    # rate (fp32 operands quarter it — as in the flash kernels,
+    # docs/performance_tuning.md); accumulation stays fp32 via
     # preferred_element_type.  No-op for fp32 inputs.
     acc_new = acc * alpha.transpose(0, 2, 1)[..., None] + jnp.einsum(
         "bhqk,bkhd->bqhd", p.astype(v_c.dtype), v_c,

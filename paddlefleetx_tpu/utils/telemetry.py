@@ -30,9 +30,9 @@ them:
   - **MFU accounting** — the analytic GPT-family FLOPs estimator
     (6·N per token for fwd+bwd, 2·N forward-only; PaLM's convention,
     Chowdhery et al. 2022) plus the per-device-kind peak-FLOPs table
-    behind the ``PFX_PEAK_FLOPS`` override, shared by the engine's step
-    records, ``bench.py``, and ``benchmarks/bench_decode.py`` so every
-    throughput number is hardware-normalized by the SAME estimator.
+    behind the ``PFX_PEAK_FLOPS`` override: what the ``mfu`` key of the
+    engine's step records is computed from (the benchmark keeps its own arithmetic,
+    ``pfx_bench/model_math.py``).
   - **FlightRecorder** — a bounded ring of recent structured events (step
     records, data_skip, rollback, preempt_save, gen_errors, watchdog
     flips, request spans) dumped to ``flight_recorder.jsonl`` on crash,
@@ -52,8 +52,8 @@ Contract notes: metric *mutations* never take the registry lock (each
 metric/collector owns a private lock), so hot paths (the serving scheduler,
 the train loop) never contend with a scrape; ``snapshot()`` takes the
 registry lock and then each collector's lock, and nothing acquires them in
-the other order.  No jax import at module scope — ``bench.py``'s parent
-process and ``tools/lint.py`` stay jax-free.
+the other order.  No jax import at module scope — launchers that must stay off
+the chip (``chip_smoke.py``'s parent) and ``tools/lint.py`` stay jax-free.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ Not a pytest file (no test_ prefix): launched by tests/test_distributed.py
 as `python distributed_worker.py <proc_id> <nproc> <port> <outdir>`.
 
 This is the repo's analogue of the reference's multi-node TIPC evidence
-(/root/reference/benchmarks/test_tipc/ N4C32 cases, SURVEY §4.1): the real
+(the reference's test_tipc N4C32 cases, SURVEY §4.1): the real
 multi-host code paths — jax.distributed bootstrap (parallel/env.py),
 cross-process collectives from a sharded train step, the process_allgather
 branch of check_replica_consistency (parallel/check.py), and distributed

@@ -9,10 +9,9 @@ Covers the ISSUE decode-overhaul acceptance criteria:
   - top-k-prefilter nucleus sampler exactness vs the full-sort
     sample_top_p under fixed keys, incl. the nucleus-overflow fallback,
     and a jaxpr assertion that the fast branch has no full-vocab sort;
-  - while_loop vs scan decode token-for-token parity and the dense-vs-
-    blocked end-to-end generation parity;
-  - knob hygiene: PFX_DECODE_BLOCK / PFX_DECODE_ATTN / PFX_DECODE_SCAN /
-    PFX_TOPP_K fail loudly on invalid values.
+  - the early-exit while_loop pads after EOS; the donated cache;
+  - knob hygiene: PFX_DECODE_BLOCK / PFX_TOPP_K fail loudly on invalid
+    values.
 """
 
 import jax
@@ -24,7 +23,6 @@ from paddlefleetx_tpu.models.gpt import model as gpt
 from paddlefleetx_tpu.models.gpt.config import GPTConfig
 from paddlefleetx_tpu.models.gpt.generation import (
     GenerationConfig,
-    decode_loop_mode,
     generate,
     init_cache,
     pad_prompts,
@@ -32,7 +30,6 @@ from paddlefleetx_tpu.models.gpt.generation import (
 from paddlefleetx_tpu.ops.decode_attention import (
     blocks_visited,
     decode_attention,
-    decode_attn_mode,
     decode_block,
     dense_cache_attention,
 )
@@ -195,63 +192,14 @@ def test_decode_block_knob_loud(monkeypatch):
         )
 
 
-def test_decode_attn_mode_loud(monkeypatch):
-    assert decode_attn_mode() == "blocked"
-    monkeypatch.setenv("PFX_DECODE_ATTN", "dense")
-    assert decode_attn_mode() == "dense"
-    monkeypatch.setenv("PFX_DECODE_ATTN", "danse")
-    with pytest.raises(ValueError, match="PFX_DECODE_ATTN"):
-        decode_attn_mode()
-
-
 # ---------------------------------------------------------------------------
-# End-to-end generation parity: blocked vs dense, while vs scan
+# End-to-end generation: the early-exit loop, the donated cache
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow  # two full e2e retraces; the op-level parity tests above
-# cover the same kernel in the fast subset
-def test_generate_blocked_matches_dense_e2e(monkeypatch):
-    params = gpt.init(TINY, jax.random.key(0))
-    prompt = jax.random.randint(jax.random.key(1), (2, 9), 0, TINY.vocab_size)
-    gen = GenerationConfig(max_dec_len=8, decode_strategy="greedy_search", eos_token_id=-1)
-    blocked = np.asarray(generate(params, prompt, TINY, gen))
-    monkeypatch.setenv("PFX_DECODE_ATTN", "dense")
-    jax.clear_caches()
-    dense = np.asarray(generate(params, prompt, TINY, gen))
-    monkeypatch.delenv("PFX_DECODE_ATTN")
-    jax.clear_caches()
-    np.testing.assert_array_equal(blocked, dense)
-
-
-@pytest.mark.slow  # four full decode retraces (2 strategies x 2 loop modes);
-# test_while_loop_early_exit_pads_after_eos keeps the fast-subset lock on
-# the while-loop semantics
-def test_while_loop_matches_scan_tokens(monkeypatch):
-    """Token-for-token parity between the early-exit while_loop and the
-    PFX_DECODE_SCAN=1 scan, for greedy AND sampling under one key."""
-    params = gpt.init(TINY, jax.random.key(0))
-    prompt = jax.random.randint(jax.random.key(2), (3, 6), 0, TINY.vocab_size)
-    for strategy, kw in [
-        ("greedy_search", {}),
-        ("sampling", {"top_p": 0.9, "temperature": 0.8}),
-    ]:
-        gen = GenerationConfig(
-            max_dec_len=7, decode_strategy=strategy, eos_token_id=96, **kw
-        )
-        key = jax.random.key(5)
-        whiled = np.asarray(generate(params, prompt, TINY, gen, key=key))
-        monkeypatch.setenv("PFX_DECODE_SCAN", "1")
-        jax.clear_caches()
-        scanned = np.asarray(generate(params, prompt, TINY, gen, key=key))
-        monkeypatch.delenv("PFX_DECODE_SCAN")
-        jax.clear_caches()
-        np.testing.assert_array_equal(whiled, scanned, err_msg=strategy)
 
 
 def test_while_loop_early_exit_pads_after_eos():
     """Force EOS on the first step: the while loop must stop and the
-    remaining slots must be pad-filled exactly like the scan's."""
+    remaining slots must be pad-filled."""
     params = gpt.init(TINY, jax.random.key(0))
     prompt = jax.random.randint(jax.random.key(3), (2, 4), 0, TINY.vocab_size)
     gen0 = GenerationConfig(max_dec_len=6, decode_strategy="greedy_search", eos_token_id=-1)
@@ -264,15 +212,6 @@ def test_while_loop_early_exit_pads_after_eos():
     out = np.asarray(generate(params, prompt, TINY, gen))
     assert out[0, 0] == int(firsts[0])
     assert np.all(out[0, 1:] == 0)
-
-
-def test_decode_loop_mode_loud(monkeypatch):
-    assert decode_loop_mode() == "while"
-    monkeypatch.setenv("PFX_DECODE_SCAN", "1")
-    assert decode_loop_mode() == "scan"
-    monkeypatch.setenv("PFX_DECODE_SCAN", "yes")
-    with pytest.raises(ValueError, match="PFX_DECODE_SCAN"):
-        decode_loop_mode()
 
 
 def test_generate_with_donated_cache_matches_internal():

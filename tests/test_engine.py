@@ -146,7 +146,7 @@ def test_abstract_init_memory_report(tmp_path, devices8):
     """Engine(abstract_init=True): no state is allocated (leaves are
     ShapeDtypeStructs) and memory_report returns per-device byte stats
     from the AOT-compiled train step — the 6.7B fit-check path
-    (benchmarks/fit_6p7b.py) at tiny dims."""
+    (tools/fit_6p7b.py) at tiny dims."""
     import numpy as np_
 
     cfg = tiny_cfg(tmp_path)

@@ -148,7 +148,7 @@ def test_selective_remat_parity():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
     # train=True with dropout: the recomputed mask in the backward pass must
-    # match the forward mask (bench.py's default config runs exactly this),
+    # match the forward mask (the 345M recipe runs exactly this),
     # for both threefry and rbg key impls
     drop = dataclasses.replace(
         TINY, hidden_dropout_prob=0.3, use_recompute=True, recompute_granularity="selective"

@@ -153,8 +153,8 @@ def test_p_losses_and_grad_step():
 def test_p_losses_bf16_compute():
     """AMP path: fp32 master params + bfloat16 compute dtype.  The unet
     casts its fp32 params per use (unet.py forward entry), so the conv
-    lhs/rhs dtypes agree — regression for the bench_extra imagen case,
-    which trains under Engine mix_precision bf16."""
+    lhs/rhs dtypes agree — regression for imagen training under Engine
+    mix_precision bf16."""
     import dataclasses
 
     cfg = dataclasses.replace(TINY, dtype="bfloat16")

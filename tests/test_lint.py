@@ -6,10 +6,12 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-from lint import check_file  # noqa: E402
+from lint import check_file, doc_files  # noqa: E402
 
 
 def _lint_src(tmp_path, src):
@@ -235,3 +237,51 @@ def test_env_knob_docs_drift_is_detected(tmp_path, monkeypatch):
         "| `PFX_REAL_KNOB` | unset | real |\n"
     )
     assert _lint.check_env_knob_docs() == []
+
+
+@pytest.mark.parametrize("doc", doc_files())
+def test_documented_paths_exist_on_the_real_repo(doc):
+    """E13, one case a document: every path README.md and docs/*.md name
+    in a code span or a fenced block exists."""
+    import lint as _lint
+
+    assert _lint.check_doc_paths([doc]) == []
+
+
+def test_documented_path_drift_is_detected(tmp_path, monkeypatch):
+    """E13 hermetically: a retired script, a retired directory and a bare
+    name that exists nowhere are findings; an existing file, a glob over
+    an existing directory, shorthand for a file under a source directory,
+    prose outside code and another project's path are not."""
+    import lint as _lint
+
+    (tmp_path / "tools").mkdir()
+    (tmp_path / "tools" / "serve.py").write_text("")
+    (tmp_path / "tests").mkdir()
+    (tmp_path / "docs").mkdir()
+    (tmp_path / "README.md").write_text(
+        "# r\n\nrun `tools/serve.py` (`serve.py` for short), `tests/test_*.py`\n"
+        "and examples/gone.py in prose; the reference's `ppfleetx/tools/x.py`\n"
+        "```\npython tasks/retired/run.py\n```\n"
+    )
+    (tmp_path / "docs" / "a.md").write_text(
+        "see `tools/retired.py`, `retired.py` and `configs/<family>/x.yaml`\n"
+    )
+    monkeypatch.setattr(_lint, "REPO", str(tmp_path))
+    assert _lint.doc_files() == ["README.md", os.path.join("docs", "a.md")]
+    found = {
+        (os.path.relpath(path, tmp_path), lineno, msg.split("'")[1])
+        for path, lineno, code, msg in _lint.check_doc_paths()
+        if code == "E13"
+    }
+    assert found == {
+        ("README.md", 6, "tasks/retired/run.py"),
+        (os.path.join("docs", "a.md"), 1, "tools/retired.py"),
+        (os.path.join("docs", "a.md"), 1, "retired.py"),
+        (os.path.join("docs", "a.md"), 1, "configs/<family>/x.yaml"),
+    }
+    # correcting the documents clears the findings
+    (tmp_path / "configs").mkdir()
+    (tmp_path / "README.md").write_text("`tools/serve.py`\n")
+    (tmp_path / "docs" / "a.md").write_text("`configs/<family>/x.yaml`\n")
+    assert _lint.check_doc_paths() == []

@@ -43,8 +43,8 @@ def enumerate_layouts(n_devices: int, max_candidates: int = 12):
 
     Beyond pure layout, the grammar covers the execution knobs the
     reference tuner sweeps (auto Strategy tuning blocks, reference
-    utils/config.py:515-590) and that docs/performance_tuning.md measures
-    as dominant: recompute granularity, gradient accumulation, and
+    utils/config.py:515-590) and that docs/performance_tuning.md
+    describes: recompute granularity, gradient accumulation, and
     precision mode — attached as variants of the leading layout."""
     outs = []
     for mp in [d for d in (1, 2, 4, 8) if n_devices % d == 0]:

@@ -16,9 +16,9 @@ Layouts:
 
 Budgets compared: v5p (95.7 GB/chip), v5e (16 GB/chip), V100-32G.
 
-Writes benchmarks/fit_6p7b.json and prints one summary line per layout.
+Writes tools/fit_6p7b.json and prints one summary line per layout.
 
-  python benchmarks/fit_6p7b.py [--layouts sharding16,mp2pp4]
+  python tools/fit_6p7b.py [--layouts sharding16,mp2pp4]
 """
 
 import argparse
@@ -53,8 +53,8 @@ LAYOUTS = {
             "Global.micro_batch_size=8",
         ],
     },
-    # the measured 1.3B-fit precision recipe (bf16 params + moments +
-    # grads, no fp32 masters — bench_extra gpt1p3b) applied to 6.7B:
+    # the 1.3B-fit precision recipe (bf16 params + moments +
+    # grads, no fp32 masters) applied to 6.7B:
     # the reference's stage-2 memory story shards its fp32 masters inside
     # the optimizer, this engine's equivalent lever is multi_precision=False
     "sharding16_bf16": {
@@ -196,7 +196,7 @@ def main(argv=None):
             "fits": row["fits"],
         }))
 
-    out = os.path.join(ROOT, "benchmarks", "fit_6p7b.json")
+    out = os.path.join(ROOT, "tools", "fit_6p7b.json")
     with open(out, "w") as f:
         json.dump({"rows": rows}, f, indent=1)
     print(f"wrote {out}")

@@ -29,13 +29,20 @@ actually bite:
       metric — the doc drifted from the table twice before this gate.
       (Repo-level check: runs once per invocation, not per file.)
   E12 env-knob docs agreement (two-way, like E11): every `PFX_*` env
-      knob referenced in PACKAGE source (paddlefleetx_tpu/, tools/,
-      benchmarks/, bench.py — tests excluded: a test-only helper knob
-      is not an operator surface) must appear in a docs knob TABLE row
+      knob referenced in PACKAGE source (paddlefleetx_tpu/, tools/ —
+      tests excluded: a test-only helper knob is not an operator
+      surface) must appear in a docs knob TABLE row
       (any docs/*.md markdown table line carrying the backticked name),
       and every documented knob must still exist in source — an
       operator reading the tracing/telemetry/serving/fault knob tables
       sees every knob that exists and no knob that does not.
+      (Repo-level check: runs once per invocation, not per file.)
+  E13 documented paths exist: every repo-relative path that README.md
+      or a docs/*.md writes in backticks or in a fenced block, and that
+      starts with one of the repo's source directories (or is a bare
+      `name.py`), must exist — the documents taught scripts for PRs
+      after nothing read them.  A bare `name.py` may be shorthand for a
+      file under those directories (`serve.py` for tools/serve.py).
       (Repo-level check: runs once per invocation, not per file.)
 
 Suppress a finding with `# noqa` on the offending line.
@@ -48,10 +55,8 @@ import re
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_DIRS = [
-    "paddlefleetx_tpu", "tools", "tests", "benchmarks", "examples", "tasks",
-]
-DEFAULT_FILES = ["bench.py", "__graft_entry__.py"]
+DEFAULT_DIRS = ["paddlefleetx_tpu", "tools", "tests", "examples", "tasks"]
+DEFAULT_FILES = ["__graft_entry__.py"]
 
 
 # E10: telemetry metric naming
@@ -163,18 +168,14 @@ def check_metrics_docs():
 _ENV_KNOB_RE = re.compile(r"^PFX_[A-Z0-9]+(_[A-Z0-9]+)*$")
 # source scope: operator-facing code only (tests set knobs too, but a
 # test-only helper name is not an operator surface)
-_ENV_KNOB_DIRS = ["paddlefleetx_tpu", "tools", "benchmarks"]
-_ENV_KNOB_FILES = ["bench.py"]
+_ENV_KNOB_DIRS = ["paddlefleetx_tpu", "tools"]
 
 
 def source_env_knobs():
     """name -> (file, lineno) of every PFX_* string literal in package
     source (first sighting wins)."""
     knobs = {}
-    paths = (
-        [os.path.join(REPO, d) for d in _ENV_KNOB_DIRS]
-        + [os.path.join(REPO, f) for f in _ENV_KNOB_FILES]
-    )
+    paths = [os.path.join(REPO, d) for d in _ENV_KNOB_DIRS]
     for path in iter_py_files(paths):
         try:
             with open(path) as f:
@@ -242,6 +243,81 @@ def check_env_knob_docs():
             f"documented env knob '{name}' is not referenced anywhere "
             "in source (stale doc row?)",
         ))
+    return findings
+
+
+# E13: documented paths exist.  ``benchmarks`` is a root no more: listed
+# so that a document naming the retired tree is a finding.
+_DOC_PATH_ROOTS = (
+    "paddlefleetx_tpu", "tools", "tests", "benchmarks", "pfx_bench",  # noqa: E10 — a directory, not a metric
+    "configs", "projects", "tasks", "examples",
+)
+_DOC_PATH_RE = re.compile(
+    r"(?<![\w./~-])((?:%s)/[\w./*<>{}$\[\]-]*|[A-Za-z_][\w-]*\.py)(?![\w/])"
+    % "|".join(_DOC_PATH_ROOTS)
+)
+_DOC_PATH_WILD = re.compile(r"[*<{$\[]")
+
+
+def doc_files():
+    """README.md and docs/*.md, repo-relative, sorted."""
+    try:
+        docs = sorted(
+            os.path.join("docs", fn)
+            for fn in os.listdir(os.path.join(REPO, "docs"))
+            if fn.endswith(".md")
+        )
+    except OSError:
+        docs = []
+    return ["README.md"] + docs
+
+
+def documented_paths(doc):
+    """[(lineno, path)] for every path-shaped token inside a code span or
+    a fenced block of ``doc`` (repo-relative): one under _DOC_PATH_ROOTS,
+    or a bare ``name.py``."""
+    with open(os.path.join(REPO, doc)) as f:
+        lines = f.read().split("\n")
+    found, fenced = [], False
+    for i, ln in enumerate(lines, 1):
+        if ln.lstrip().startswith("```"):
+            fenced = not fenced
+            continue
+        spans = [ln] if fenced else re.findall(r"`([^`]+)`", ln)
+        for span in spans:
+            for m in _DOC_PATH_RE.finditer(span):
+                found.append((i, m.group(1).rstrip(".,:;")))
+    return found
+
+
+def check_doc_paths(docs=None):
+    """E13 (repo-level, once per run): the paths the documents name exist.
+    A glob or placeholder (`tests/test_*.py`, `configs/<family>/`) is held
+    to its directory; a bare `name.py` to the root or, as shorthand, to any
+    file of that name under the source directories."""
+    basenames = None
+    findings = []
+    for doc in doc_files() if docs is None else docs:
+        for lineno, path in documented_paths(doc):
+            if "/" not in path:
+                if os.path.exists(os.path.join(REPO, path)):
+                    continue
+                if basenames is None:
+                    basenames = {
+                        os.path.basename(p) for p in iter_py_files(
+                            [os.path.join(REPO, d) for d in _DOC_PATH_ROOTS])
+                    }
+                exists = path in basenames
+            else:
+                fixed = _DOC_PATH_WILD.split(path, 1)
+                target = path if len(fixed) == 1 else os.path.dirname(fixed[0])
+                exists = os.path.exists(os.path.join(REPO, target))
+            if not exists:
+                findings.append((
+                    os.path.join(REPO, doc), lineno, "E13",
+                    f"documented path '{path}' does not exist (retired or "
+                    "renamed? correct the document)",
+                ))
     return findings
 
 
@@ -464,10 +540,11 @@ def main(argv=None):
     for path in iter_py_files(paths):
         n_files += 1
         all_findings.extend(check_file(path))
-    # E11/E12 are repo-level invariants (code table <-> doc table),
+    # E11/E12/E13 are repo-level invariants (code <-> documents),
     # checked once per run rather than per file
     all_findings.extend(check_metrics_docs())
     all_findings.extend(check_env_knob_docs())
+    all_findings.extend(check_doc_paths())
     for path, lineno, code, msg in sorted(all_findings):
         rel = os.path.relpath(path, REPO)
         print(f"{rel}:{lineno}: {code} {msg}")

@@ -866,8 +866,7 @@ def fleet_goodput_rows(data: FleetData) -> List[List[str]]:
     sample (the federated scheduler time ledger): goodput_frac =
     device-COVERED seconds / non-idle wall, where covered = non-idle
     wall minus host_gap_s (host time the device sat starved waiting for
-    its next dispatch — same definition bench_decode's overlap case
-    pins).  device_util = the same numerator over TOTAL wall including
+    its next dispatch — the scheduler's ``host_gap_s``).  device_util = the same numerator over TOTAL wall including
     idle."""
     rows = []
     for r in data.replicas():
