@@ -564,6 +564,16 @@ def phase_kernels(args) -> int:
                     vpq, vps = quantize_kv(vp)
                     vs_lax(f"paged_int8_bs{bs}_{tag}", paged_decode_attention,
                            qd, kpq, vpq, tables, positions, k_scale=kps, v_scale=vps)
+                    # the arena as the serving step hands it over: every
+                    # layer's pool in one stack, one layer read in place
+                    kp3, vp3 = (jnp.stack([rand(p.shape), p, rand(p.shape)])
+                                for p in (kp, vp))
+                    vs_lax(f"paged_arena_bs{bs}_{tag}", paged_decode_attention,
+                           qd, kp3, vp3, tables, positions, layer=1)
+                    (kq3, ks3), (vq3, vs3) = quantize_kv(kp3), quantize_kv(vp3)
+                    vs_lax(f"paged_arena_int8_bs{bs}_{tag}", paged_decode_attention,
+                           qd, kq3, vq3, tables, positions, layer=1,
+                           k_scale=ks3, v_scale=vs3)
 
     # ---- fused LayerNorm fwd and bwd vs the jnp composite ----
     x, res = rand((4, s, hidden)), rand((4, s, hidden))

@@ -821,7 +821,10 @@ class PagedPools(NamedTuple):
     [layers, num_blocks, heads, block] carry per-(slot, head) scale
     tiles stored alongside the arena — each pool block owns its
     [heads, block] scale tile, DMA'd with it by the pallas kernel's
-    clamped index map."""
+    clamped index map.  The arena is donated into every dispatch, carried
+    through the step's layer loop and written in place: a handle handed
+    to a dispatch is dead, the returned one is the arena
+    (docs/decode_path.md, "The arena's contract")."""
 
     k: jax.Array
     v: jax.Array
@@ -880,22 +883,23 @@ class PagedRows(NamedTuple):
 def _paged_layer_step(
     p: Dict[str, Any],
     x: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
+    pools: PagedPools,
+    layer: jax.Array,
     blk: jax.Array,
     off: jax.Array,
     tables: jax.Array,
     positions: jax.Array,
     cfg: GPTConfig,
     ctx: Optional[ShardingCtx] = None,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
 ):
-    """One decoder layer over x [b, t, h]: write each of the t chunk
-    tokens' K/V at pool slot (blk[i, j], off[i, j]) per row (t > 1 is
-    the speculative verify chunk), then block-table paged attention with
-    per-query causal bounds.  Under int8 the chunk quantizes on write
-    and the per-slot scales land in the arena's scale planes."""
+    """Decoder layer ``layer`` over x [b, t, h]: write each of the t chunk
+    tokens' K/V at slot (blk[i, j], off[i, j]) of that layer's blocks, per
+    row (t > 1 is the speculative verify chunk), then block-table paged
+    attention with per-query causal bounds.  ``pools`` is the whole arena
+    and comes back whole: the write lands in it and the attention reads
+    the layer's pages out of it, so no layer's pool is ever sliced out of
+    the stack.  Under int8 the chunk quantizes on write and the per-slot
+    scales land in the arena's scale planes."""
     n = cfg.num_attention_heads
 
     def attend(q, k, v):
@@ -903,29 +907,22 @@ def _paged_layer_step(
         # disjoint blocks and a row's t slots are distinct, so the only
         # index collisions are inactive/overrun rows' null-block writes
         # (garbage-on-garbage, never read)
-        idx_b = blk[:, :, None]                  # [b, t, 1]
-        idx_n = jnp.arange(n)[None, None, :]     # [1, 1, n]
-        idx_o = off[:, :, None]
-        if k_scale is not None:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            k_new = k_pool.at[idx_b, idx_n, idx_o, :].set(kq)
-            v_new = v_pool.at[idx_b, idx_n, idx_o, :].set(vq)
-            ks_new = k_scale.at[idx_b, idx_n, idx_o].set(ks)
-            vs_new = v_scale.at[idx_b, idx_n, idx_o].set(vs)
+        at = (layer, blk[:, :, None], jnp.arange(n)[None, None, :],
+              off[:, :, None])  # [b, t, n] slots of this layer
+        if pools.k_scale is not None:
+            (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
+            chunk = (kq, vq, ks, vs)
         else:
-            k_new = k_pool.at[idx_b, idx_n, idx_o, :].set(k.astype(k_pool.dtype))
-            v_new = v_pool.at[idx_b, idx_n, idx_o, :].set(v.astype(v_pool.dtype))
-            ks_new = vs_new = None
+            chunk = (k.astype(pools.k.dtype), v.astype(pools.v.dtype))
+        new = PagedPools(*(pool.at[at].set(c) for pool, c in zip(pools, chunk)))
         attn_out = paged_decode_attention(
-            q, k_new, v_new, tables, positions,
+            q, new.k, new.v, tables, positions, layer=layer,
             impl="lax" if ctx is not None else "auto",
-            k_scale=ks_new, v_scale=vs_new,
+            k_scale=new.k_scale, v_scale=new.v_scale,
         )
-        return attn_out, (k_new, v_new, ks_new, vs_new)
+        return attn_out, new
 
-    x, kv_state = _decoder_layer(p, x, ctx, attend)
-    return (x, *kv_state)
+    return _decoder_layer(p, x, ctx, attend)
 
 
 def paged_forward_step(
@@ -979,35 +976,22 @@ def paged_forward_step(
         )
     off = pos_t % bs
 
-    quant = pools.k_scale is not None
-    if quant:
-        def body(x, inp):
-            p_l, kp, vp, ksl, vsl = inp
-            x, kp, vp, ksl, vsl = _paged_layer_step(
-                p_l, x, kp, vp, blk, off, block_tables, positions, cfg, ctx,
-                ksl, vsl,
-            )
-            return x, (kp, vp, ksl, vsl)
+    # the arena is CARRIED through the layer loop and written in place;
+    # as the scan's xs / ys each layer's pool was sliced out of the stack
+    # and written back into a second one (1.6 GB twice a step at GPT-1.3B)
+    def body(carry, inp):
+        x, pools = carry
+        p_l, layer = inp
+        return _paged_layer_step(
+            p_l, x, pools, layer, blk, off, block_tables, positions, cfg, ctx
+        ), None
 
-        xs = (params["layers"], pools.k, pools.v, pools.k_scale, pools.v_scale)
-        x, (ks, vs, kss, vss) = jax.lax.scan(body, x, xs)
-        out_pools = PagedPools(ks, vs, kss, vss)
-    else:
-        def body(x, inp):
-            p_l, kp, vp = inp
-            x, kp, vp, _, _ = _paged_layer_step(
-                p_l, x, kp, vp, blk, off, block_tables, positions, cfg, ctx
-            )
-            return x, (kp, vp)
-
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (params["layers"], pools.k, pools.v)
-        )
-        out_pools = PagedPools(ks, vs)
+    layers = jnp.arange(pools.k.shape[0], dtype=jnp.int32)
+    (x, pools), _ = jax.lax.scan(body, (x, pools), (params["layers"], layers))
     x = layer_norm(x, params["final_ln"]["scale"], params["final_ln"]["bias"])
     logits = jnp.einsum("bsh,vh->bsv", x, word)
     logits = _constrain(ctx, logits, ("batch", None, "vocab"))
-    return logits.astype(jnp.float32), out_pools
+    return logits.astype(jnp.float32), pools
 
 
 def paged_prefill(

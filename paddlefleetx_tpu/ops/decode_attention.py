@@ -473,12 +473,13 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def _paged_lax(q_t, k_pool, v_pool, tables, positions, scale,
+def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
                k_scale=None, v_scale=None):
-    """q_t [b, n, t, d]; pools [nb, n, bs, d]; tables [b, M] block ids;
-    positions [b] = global slot of each row's FIRST query token (query
-    qi sits at slot positions[i] + qi — t > 1 is the speculative
-    multi-token verify chunk, causal within the chunk).
+    """q_t [b, n, t, d]; pools [layers, nb, n, bs, d], of which layer
+    ``layer``'s blocks are read; tables [b, M] block ids; positions [b] =
+    global slot of each row's FIRST query token (query qi sits at slot
+    positions[i] + qi — t > 1 is the speculative multi-token verify
+    chunk, causal within the chunk).
 
     Blocked online-softmax over each row's OWN block list: block j of row
     i holds key slots [j*bs, (j+1)*bs) of that row's logical cache, stored
@@ -487,11 +488,11 @@ def _paged_lax(q_t, k_pool, v_pool, tables, positions, scale,
     :func:`_decode_lax`'s shared ``limit``.  Table entries beyond a row's
     limit (null-block padding) are masked by the causal bound, so their
     garbage never reaches the accumulator.  With int8 pools,
-    ``k_scale``/``v_scale`` [nb, n, bs] dequantize in-loop (scores absorb
-    the key scale, probabilities the value scale).
+    ``k_scale``/``v_scale`` [layers, nb, n, bs] dequantize in-loop (scores
+    absorb the key scale, probabilities the value scale).
     """
     b, n, t, d = q_t.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     quant = k_scale is not None
 
     m0 = jnp.full((b, n, t), NEG_INF, jnp.float32)
@@ -510,13 +511,13 @@ def _paged_lax(q_t, k_pool, v_pool, tables, positions, scale,
         m, l, acc = carry
         jidx = jnp.minimum(j, last_blk)  # [b]
         blk = jnp.take_along_axis(tables, jidx[:, None], axis=1)[:, 0]  # [b]
-        k = jnp.take(k_pool, blk, axis=0)  # [b, n, bs, d] gather
-        v = jnp.take(v_pool, blk, axis=0)
+        k = k_pool[layer, blk]  # [b, n, bs, d] gather
+        v = v_pool[layer, blk]
         if quant:
             k = k.astype(jnp.float32)
             v = v.astype(jnp.float32)
-            ksl = jnp.take(k_scale, blk, axis=0)  # [b, n, bs]
-            vsl = jnp.take(v_scale, blk, axis=0)
+            ksl = k_scale[layer, blk]  # [b, n, bs]
+            vsl = v_scale[layer, blk]
         s = scale * jnp.einsum(
             "bntd,bnkd->bntk", q_t, k, preferred_element_type=jnp.float32
         )  # [b, n, t, bs]
@@ -585,7 +586,8 @@ def _paged_last_page(pos, qt, *, t, tq, bs):
 
 
 def _paged_kernel(
-    tables_ref, pos_ref, q_ref, *refs, scale, bs, t, tq, pages, width, quant
+    layer_ref, tables_ref, pos_ref, q_ref, *refs, scale, bs, t, tq, pages,
+    width, quant
 ):
     """One (row, query tile, page group) grid step, every head inside.
 
@@ -593,8 +595,9 @@ def _paged_kernel(
     one whole pool page, contiguous in HBM), with int8 pools ``pages`` +
     ``pages`` scale rows [1, n, 1, bs], then o_ref and the acc / m / l
     scratch.  The index maps already DMA'd pages ``tables[i, min(j * pages
-    + p, last_needed(i))]`` — the scalar-prefetch CLAMP: past a row's
-    last needed page they re-address the page already held (no new DMA).
+    + p, last_needed(i))]`` of layer ``layer_ref[0]`` — the scalar-prefetch
+    CLAMP: past a row's last needed page they re-address the page already
+    held (no new DMA).
     A group that starts past the row's last page runs NOTHING (``pl.when``):
     it costs the grid step's fixed overhead and neither load, product nor
     store.  Inside the last needed group the pages past the row's context
@@ -667,12 +670,12 @@ def _paged_kernel(
         ).astype(o_ref.dtype)
 
 
-def _paged_pallas(q_t, k_pool, v_pool, tables, positions, scale,
+def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
                   k_scale=None, v_scale=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, n, t, d = q_t.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     M = tables.shape[1]
     tables = tables.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
@@ -680,34 +683,46 @@ def _paged_pallas(q_t, k_pool, v_pool, tables, positions, scale,
     pages = paged_pages_per_step(bs, M)
     tq = min(t, _PAGED_Q_TILE)
 
-    def page_index(p):
-        def index(i, qt, j, tables_ref, pos_ref):
+    def page_index(p, stacked):
+        def index(i, qt, j, layer_ref, tables_ref, pos_ref):
             # scalar-prefetch clamp: past the last page this query tile
             # needs, re-address the page already fetched — Pallas skips
             # the DMA when the index is unchanged between consecutive
             # grid steps
             last = _paged_last_page(pos_ref[i], qt, t=t, tq=tq, bs=bs)
             page = jnp.minimum(j * pages + p, jnp.minimum(last, M - 1))
-            return tables_ref[i, page], 0, 0, 0
+            at = (tables_ref[i, page], 0, 0, 0)
+            return (layer_ref[0],) + at if stacked else at
         return index
 
     q_spec = pl.BlockSpec((1, n, tq, d), lambda i, qt, j, *_: (i, 0, qt, 0))
+    # the pools enter WHOLE, all layers: the page address carries the
+    # layer, so the caller's arena is read where it lies (a layer sliced
+    # out of the stack would be copied to a buffer of its own first)
     page_specs = [
-        pl.BlockSpec((1, n, bs, d), page_index(p)) for p in range(pages)
+        pl.BlockSpec((None, 1, n, bs, d), page_index(p, True))
+        for p in range(pages)
     ]
     in_specs = [q_spec] + page_specs * 2
     operands = [q_t] + [k_pool] * pages + [v_pool] * pages
     if quant:
-        # the scale planes enter as [nb, n, 1, bs] so the (1, bs) tile
-        # equals the array's last two dims (the (8, 128) rule refuses a
-        # (1, bs) tile of [nb, n, bs]); same clamped page address
+        # the layer's scale planes enter as [nb, n, 1, bs] so the (1, bs)
+        # tile equals the array's last two dims (the (8, 128) rule refuses
+        # a (1, bs) tile of [nb, n, bs]); same clamped page address.  They
+        # ARE sliced out of their stack: the device keeps [.., n, bs] f32
+        # in another tiling than the kernel reads, so a plane is converted
+        # on its way in, and one layer's is 1/layers of that
+        def plane(x):
+            return jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)[:, :, None]
+
         in_specs += [
-            pl.BlockSpec((1, n, 1, bs), page_index(p)) for p in range(pages)
+            pl.BlockSpec((1, n, 1, bs), page_index(p, False))
+            for p in range(pages)
         ] * 2
-        operands += [k_scale[:, :, None]] * pages + [v_scale[:, :, None]] * pages
+        operands += [plane(k_scale)] * pages + [plane(v_scale)] * pages
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, -(-t // tq), -(-M // pages)),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -727,7 +742,7 @@ def _paged_pallas(q_t, k_pool, v_pool, tables, positions, scale,
         out_shape=jax.ShapeDtypeStruct((b, n, t, d), jnp.float32),
         interpret=_device.pallas_interpret(),
         name="pfx_decode_paged",
-    )(tables, positions, *operands)
+    )(layer[None], tables, positions, *operands)
 
 
 def paged_decode_attention(
@@ -737,6 +752,7 @@ def paged_decode_attention(
     block_tables: jax.Array,
     positions: jax.Array,
     *,
+    layer: Optional[jax.Array] = None,
     impl: str = "auto",
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
@@ -744,9 +760,14 @@ def paged_decode_attention(
     """Block-table-indexed decode attention for the paged KV cache.
 
     q [b, t, n, d]; pools [num_blocks, n, block, d] (one layer's arena —
-    ``core/paged_cache.py``); ``block_tables`` [b, M] maps row i's logical
-    block j to a pool block id; ``positions`` [b] is the slot of each
-    row's FIRST query token (the chunk already written) — query qi of
+    ``core/paged_cache.py``) or, with ``layer`` (an int32 scalar, traced
+    or not), the whole arena [layers, num_blocks, n, block, d], of which
+    that layer's pages are read IN PLACE: the serving step carries the
+    arena through its layer loop and hands it over as it is, because a
+    layer's pool sliced out of the stack is copied to a buffer of its own
+    before a kernel may read it.  ``block_tables`` [b, M] maps row i's
+    logical block j to a pool block id; ``positions`` [b] is the slot of
+    each row's FIRST query token (the chunk already written) — query qi of
     row i attends over its logical slots [0, positions[i] + qi + 1),
     causal within the chunk.  t = 1 is the plain decode step; t > 1 is
     the speculative multi-token verify chunk (k drafts + 1).  Rows are
@@ -755,8 +776,9 @@ def paged_decode_attention(
     Returns [b, t, n, d].
 
     With int8 pools (PFX_KV_DTYPE=int8), ``k_scale``/``v_scale``
-    [num_blocks, n, block] carry the per-(slot, head) scales stored
-    alongside the arena; both spellings dequantize in-kernel (the pallas
+    [num_blocks, n, block] ([layers, num_blocks, n, block] with ``layer``)
+    carry the per-(slot, head) scales stored alongside the arena; both
+    spellings dequantize in-kernel (the pallas
     spelling rides the same scalar-prefetch-clamped index map, so the
     scale tiles DMA with their block) — pass both or neither.
 
@@ -779,7 +801,13 @@ def paged_decode_attention(
     b, t, n, d = q.shape
     if t < 1:
         raise ValueError(f"paged_decode_attention needs t >= 1; got t={t}")
-    bs = k_pool.shape[2]
+    if layer is None:  # one layer's arena: a stack of one
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32)
+    bs = k_pool.shape[3]
     use_pallas = impl == "pallas" or (impl == "auto" and not _device.pallas_interpret())
     if use_pallas and bs % 8:
         # pallas (asked for, or what "auto" means on a TPU) runs pallas
@@ -793,10 +821,10 @@ def paged_decode_attention(
     scale = float(1.0 / (d**0.5))
     q_t = q.transpose(0, 2, 1, 3)  # [b, n, t, d]
     if use_pallas:
-        out = _paged_pallas(q_t, k_pool, v_pool, block_tables, positions,
-                            scale, k_scale, v_scale)
+        out = _paged_pallas(q_t, k_pool, v_pool, layer, block_tables,
+                            positions, scale, k_scale, v_scale)
     else:
-        out = _paged_lax(q_t, k_pool, v_pool, block_tables, positions,
+        out = _paged_lax(q_t, k_pool, v_pool, layer, block_tables, positions,
                          scale, k_scale, v_scale)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 

@@ -450,13 +450,11 @@ def test_four_chip_train_step_compiles_with_kernel(topo, overrides):
     assert "all-reduce" in text
 
 
-def test_decode_step_13b_holds_one_copy_of_the_weights(topo):
+def _docs_step_13b(topo, kv_dtype="bf16", t=1, donate=False):
     """The docs cell's decode step (GPT-1.3B, 8 rows of 1024 tokens, 16-token
-    blocks) as the server dispatches it: with the tree it holds
-    (``serving_params``) the program's scratch has no second, converted copy
-    of the weights.  With the float32 tree ``temp`` was 2.62 GB beside 6.87 GB
-    of arguments (``pfx_bench/selftest/chip_compile.py serve gpt-1.3b 8
-    512,960``, which still passes float32 shapes)."""
+    blocks; t > 1: its verify chunk) compiled for one described chip, with
+    the tree the server holds (``serving_params``) -> (compiled, weights'
+    bytes, arena's bytes)."""
     from paddlefleetx_tpu.models.gpt.config import GPTConfig
     from paddlefleetx_tpu.models.gpt.generation import (
         init_paged_pools,
@@ -472,20 +470,35 @@ def test_decode_step_13b_holds_one_copy_of_the_weights(topo):
     one = _one_chip(topo)
     held = _shapes(one, jax.eval_shape(
         lambda p: serving_params(p, cfg), _param_shapes(cfg, one)))
-    weights = sum(
-        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(held))
-    assert 2.6e9 < weights < 2.7e9
     rows, bs, ctx_len = 8, 16, cfg.max_position_embeddings
     pools = _shapes(one, jax.eval_shape(
-        lambda: init_paged_pools(cfg, rows * (ctx_len // bs) + 1, bs, kv_dtype="bf16")))
-    c = _compile(
-        lambda p, toks, pools, tb, ps, act: paged_forward_step(
+        lambda: init_paged_pools(cfg, rows * (ctx_len // bs) + 1, bs, kv_dtype=kv_dtype)))
+    c = jax.jit(
+        lambda p, pools, toks, tb, ps, act: paged_forward_step(
             p, toks, pools, tb, ps, act, cfg),
-        held, _shapes(one, ((rows,), jnp.int32)), pools,
+        donate_argnums=(1,) if donate else (),
+    ).lower(
+        held, pools, _shapes(one, ((rows, t), jnp.int32)),
         _shapes(one, ((rows, ctx_len // bs), jnp.int32)),
         _shapes(one, ((rows,), jnp.int32)), _shapes(one, ((rows,), jnp.bool_)),
-    )
+    ).compile()
     assert _has_kernel(c)
+
+    def nbytes(tree):
+        return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                   for x in jax.tree.leaves(tree))
+
+    return c, nbytes(held), nbytes(pools)
+
+
+def test_decode_step_13b_holds_one_copy_of_the_weights(topo):
+    """The docs cell's decode step as the server dispatches it: with the
+    tree it holds (``serving_params``) the program's scratch has no second,
+    converted copy of the weights.  With the float32 tree ``temp`` was
+    2.62 GB beside 6.87 GB of arguments (``pfx_bench/selftest/chip_compile.py
+    serve gpt-1.3b 8 512,960``, which still passes float32 shapes)."""
+    c, weights, _ = _docs_step_13b(topo)
+    assert 2.6e9 < weights < 2.7e9
     m = c.memory_analysis()
     # weights 2.63 GB + arena 1.61 GB + the small operands
     assert 4.2e9 < m.argument_size_in_bytes < 4.4e9
@@ -494,3 +507,39 @@ def test_decode_step_13b_holds_one_copy_of_the_weights(topo):
     text = c.as_text()
     assert "bf16[24,2048,8192]{" in text  # the held fc_in, an operand as it is
     assert "f32[24,2048,8192]" not in text and "f32[50304,2048]" not in text
+
+
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_decode_step_13b_writes_the_donated_arena_in_place(topo, kv_dtype, t):
+    """The docs cell's step (and its verify chunk) as the scheduler
+    dispatches it, the pools DONATED: the arena is carried through the
+    layer loop, the step's K/V are scattered into it where it lies and the
+    kernel reads each layer's pages out of it.  Nothing arena-shaped is
+    copied, sliced out of the stack or written back into it, so the
+    program needs no scratch to speak of.  With the pools as the scan's
+    xs / ys the same compile held two more arenas as scratch (``temp``
+    1.614 GB in bf16) and each step moved 1.6 GB twice."""
+    import re
+
+    c, _, arena = _docs_step_13b(topo, kv_dtype, t, donate=True)
+    m = c.memory_analysis()
+    # the output IS the donated input (the int8 arena's scale planes are
+    # held in padded tiles, 0.8% more than their values)
+    assert arena <= m.alias_size_in_bytes < 1.01 * arena
+    assert m.temp_size_in_bytes < 0.1e9
+    # every instruction whose result has the shape of the arena, of one
+    # layer's pool or of either viewed with layers and blocks merged
+    # (the scale planes lack the last dimension)
+    dims = "|".join(
+        f"{lead},16,16(?:,128)?" for lead in ("24,513", "513", 24 * 513))
+    moved = re.findall(
+        rf"= \w+\[(?:{dims})\]\S* "
+        r"(copy|dynamic-slice|dynamic-update-slice|custom-call)\(([^\n]*)",
+        c.as_text(),
+    )
+    moved = [
+        op for op, rest in moved
+        if op != "custom-call" or 'custom_call_target="AllocateBuffer"' in rest
+    ]
+    assert not moved, moved

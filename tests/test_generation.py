@@ -470,3 +470,110 @@ def test_cast_tree_serves_bit_equal_logits_and_pools(trees, case, kv_dtype):
     for w, g in zip(want, got):
         assert np.isfinite(w).all()
         np.testing.assert_array_equal(g, w)
+
+
+# ---------------------------------------------------------------------------
+# The arena rides the layer loop as the CARRY and is written in place: the
+# kernel is handed the whole stack and a layer.  The reference below is the
+# semantics that replaced: one layer's pool at a time, out of the stack and
+# back into it.
+# ---------------------------------------------------------------------------
+
+
+def _per_layer_reference(params, tokens, pools, tables, positions, active, cfg,
+                         n_valid=None):
+    """`paged_forward_step` as a plain loop over the layers, each a call of
+    its own on ``pools.k[l]`` / ``pools.v[l]`` alone (a stack of one)."""
+    from paddlefleetx_tpu.models.gpt.generation import (
+        _in_dtype,
+        _paged_layer_step,
+        layer_norm,
+    )
+
+    t = tokens.shape[1]
+    emb = _in_dtype("embeddings", params["embeddings"], jnp.dtype(cfg.dtype))
+    word, pe = emb["word"], emb["position"]
+    pos_t = positions[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    x = word[tokens] + pe[jnp.clip(
+        jnp.where(active[:, None], pos_t, 0), 0, cfg.max_position_embeddings - 1)]
+    bs = pools.k.shape[3]
+    blk = jnp.take_along_axis(
+        tables, jnp.clip(pos_t // bs, 0, tables.shape[1] - 1), axis=1)
+    blk = jnp.where(active[:, None], blk, 0)
+    if n_valid is not None:
+        blk = jnp.where(jnp.arange(t)[None, :] < n_valid[:, None], blk, 0)
+    off = pos_t % bs
+    layer_step = jax.jit(_paged_layer_step, static_argnums=(8,))
+    out = []
+    for l in range(cfg.num_layers):
+        p_l = jax.tree.map(lambda a: a[l], params["layers"])
+        one = jax.tree.map(lambda a: a[l][None], pools)
+        x, one = layer_step(
+            p_l, x, one, jnp.int32(0), blk, off, tables, positions, cfg)
+        out.append(one)
+    x = layer_norm(x, params["final_ln"]["scale"], params["final_ln"]["bias"])
+    logits = jnp.einsum("bsh,vh->bsv", x, word).astype(jnp.float32)
+    return logits, jax.tree.map(lambda *a: jnp.concatenate(a), *out)
+
+
+# name -> (t, positions, active, n_valid); two rows over _paged_inputs'
+# tables unless the case is the one-row prefill chunk
+_ARENA_CASES = {
+    "step_with_an_inactive_row": (1, [11, 19], [True, False], None),
+    "verify_chunk": (3, [11, 19], [True, True], None),
+    "one_row_chunk_with_pad_slots": (6, [10], [True], [4]),
+}
+
+
+def _arena_step(fn, case, kv_dtype, params):
+    t, positions, active, n_valid = _ARENA_CASES[case]
+    cfg = TINY_BF16
+    pools, tables = _paged_inputs(kv_dtype)
+    rows = len(positions)
+    tokens = jax.random.randint(jax.random.key(3), (rows, t), 0, cfg.vocab_size)
+    n_valid = None if n_valid is None else jnp.asarray(n_valid, jnp.int32)
+    logits, out = fn(
+        params, tokens, pools, tables[:rows], jnp.asarray(positions, jnp.int32),
+        jnp.asarray(active), cfg, n_valid=n_valid)
+    return pools, logits, out
+
+
+_step = jax.jit(paged_forward_step, static_argnums=(6,))
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("case", list(_ARENA_CASES))
+def test_carried_arena_is_bit_equal_to_the_per_layer_loop(trees, case, kv_dtype):
+    _, params = trees
+    _, logits, pools = _arena_step(_step, case, kv_dtype, params)
+    _, want_logits, want = _arena_step(
+        _per_layer_reference, case, kv_dtype, params)
+    assert np.isfinite(np.asarray(want_logits)).all()
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), pools) == jax.tree.map(
+        lambda a: (a.shape, a.dtype), want)
+    got, ref = _f32(pools), _f32(want)
+    assert len(got) == len(ref) == (2 if kv_dtype == "bf16" else 4)
+    for g, w in zip(got, ref):
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_each_layer_writes_its_own_blocks_of_the_arena(trees, kv_dtype):
+    """Row 0 at position 11 writes slot 3 of its block 2 in EVERY layer: with
+    the layer dropped from the address, both layers' writes would land in
+    layer 0's block.  Each layer's block changes, to its own values, and
+    nothing but the rows' slots (and the null block's) moves anywhere."""
+    _, params = trees
+    before, _, after = _arena_step(
+        _step, "step_with_an_inactive_row", kv_dtype, params)
+    for b, a in zip(_f32(before), _f32(after)):
+        changed = (a != b).reshape(a.shape[:4] + (-1,)).any(-1)  # [L, nb, n, bs]
+        for l in range(TINY_BF16.num_layers):
+            # the active row's slot (block 2, offset 3), every head
+            assert changed[l, 2, :, 3].all()
+            # the inactive row's write went to this layer's null block
+            where = np.argwhere(changed[l])
+            assert set(where[:, 0]) <= {0, 2}
+            assert (where[where[:, 0] == 2][:, 2] == 3).all()
+        assert (a[0, 2, :, 3] != a[1, 2, :, 3]).any()

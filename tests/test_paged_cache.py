@@ -341,6 +341,56 @@ def test_paged_attention_prefill_chunk_wider_than_a_query_tile(impl):
         )
 
 
+@pytest.mark.parametrize("t", [1, 3])
+@pytest.mark.parametrize("int8", [False, True], ids=["native", "int8"])
+@pytest.mark.parametrize("impl", ["lax", "pallas"])
+def test_paged_attention_reads_one_layer_of_a_stacked_arena(impl, int8, t):
+    """With ``layer`` the pools are the whole arena [layers, nb, ...] and
+    the kernel reads that layer's pages where they lie (the serving step
+    carries the arena and never slices a layer out of it).  The result is
+    the one-layer call's on ``pools[layer]``, to the bit, with every OTHER
+    layer poisoned: a page address that dropped the layer would read NaN.
+    (int8 pools cannot hold a NaN: their scale planes carry it.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
+
+    rng = np.random.default_rng(4)
+    layers, b, M = 3, 3, 12
+    cases = [
+        _paged_case(rng, b=b, n=4, d=16, bs=16, M=M, nb=b * M + 1, t=t, int8=int8)
+        for _ in range(layers)
+    ]
+    q, _, _, tables, _ = cases[1]
+    positions = jnp.asarray([100, 15, 128], jnp.int32)
+    stacked = jax.jit(
+        lambda layer, k, v, scales: paged_decode_attention(
+            q, k, v, tables, positions, layer=layer, impl=impl, **scales))
+    k_all = jnp.stack([c[1] for c in cases])
+    v_all = jnp.stack([c[2] for c in cases])
+    scales_all = {
+        name: jnp.stack([c[4][name] for c in cases]) for name in cases[0][4]
+    }
+
+    def poison(x, layer):  # every layer but this one
+        keep = (jnp.arange(layers) == layer).reshape((layers,) + (1,) * (x.ndim - 1))
+        return jnp.where(keep, x, jnp.nan)
+
+    for layer in range(layers):
+        k, v, scales = k_all, v_all, scales_all
+        if int8:
+            scales = {name: poison(x, layer) for name, x in scales.items()}
+        else:
+            k, v = poison(k, layer), poison(v, layer)
+        got = stacked(jnp.int32(layer), k, v, scales)
+        _, k1, v1, _, scales1 = cases[layer]
+        want = paged_decode_attention(
+            q, k1, v1, tables, positions, impl=impl, **scales1)
+        assert np.isfinite(np.asarray(want)).all()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("bs,M,pages", [(16, 64, 8), (16, 4, 4), (32, 32, 4),
                                         (128, 8, 1), (256, 4, 1), (8, 1, 1)])
 def test_paged_pages_per_step_follows_the_shapes(bs, M, pages):
