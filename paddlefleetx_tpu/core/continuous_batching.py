@@ -95,6 +95,7 @@ from paddlefleetx_tpu.core.request_queue import (
 )
 from paddlefleetx_tpu.ops.decode_attention import (
     kv_cache_dtype,
+    mla_tokens_computed,
     paged_tokens_computed,
 )
 from paddlefleetx_tpu.ops.speculative import SpecConfig, ngram_propose_host
@@ -248,7 +249,9 @@ class PagedDecodeEngine:
         self.ctx = server.ctx
         self.mesh = server.mesh
         self.bucket = server.bucket
-        self.block = kv_block_size(block)
+        # what a page holds comes from the model: a latent page is one
+        # vector a token, so it takes more tokens to be worth a DMA
+        self.block = kv_block_size(block, default=self.mcfg.kv_block_default)
         # speculation + KV quantization: default ("auto"/"") inherits the
         # server's ALREADY-PARSED Generation.speculative settings (ONE
         # parse site — core/serving.py — so both schedulers can never
@@ -291,6 +294,22 @@ class PagedDecodeEngine:
                 f"multiple of the KV block size {self.block}"
             )
         self.prefill_chunk = int(prefill_chunk)
+        if not self.mcfg.classic_block:
+            # the described block is served by prefill-on-admit and the
+            # one-token decode step; what else the GPT-2 block's pools
+            # offer is refused by name (docs/serving.md, ROADMAP queue 2)
+            refused = {
+                "--draft-k (speculative verify chunk)": self.spec is not None,
+                "--kv-dtype int8": self.kv_dtype == "int8",
+                "--prefix-cache-blocks": bool(prefix_cache_blocks),
+                "--prefill-chunk": bool(prefill_chunk),
+                "tensor parallelism (a mesh of more than one device)": self.ctx is not None,
+            }
+            for option, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{option} is not written for latent pools and expert layers yet; "
+                        "serve this block without it")
         # host-RAM spill tier (docs/serving.md "KV lifecycle"): evicted
         # prefix blocks demote to a bounded host store and readmit on a
         # later match instead of recomputing.  Spilling without an index
@@ -362,6 +381,10 @@ class PagedDecodeEngine:
             "ledger_admitted": 0,
             "row_steps": 0, "slot_steps": 0,
             "kv_tokens": 0, "grid_tokens": 0,
+            # expert layers (prefills and decode steps, warm-up excluded):
+            # pairs routed, pairs on held experts, the fullest held
+            # expert's pairs x experts held (generation._moe_counts)
+            "moe_pairs": 0, "moe_held_pairs": 0, "moe_held_max_pairs": 0,
         }
         # True only inside warmup(): warmup admits/steps are not traffic
         # and must not bump the traffic-facing registry counters (the
@@ -383,6 +406,7 @@ class PagedDecodeEngine:
         self.dispatch_ahead = False
         self._inflight: Optional[Dict[str, Any]] = None
         self._t_results: Optional[float] = None
+        self._moe_pending: List[Any] = []  # expert counts not fetched yet
 
     def _init_device_state(self) -> None:
         """Fresh arena + per-row device state (boot and every ArenaReset),
@@ -422,13 +446,42 @@ class PagedDecodeEngine:
         return max(prompt_len + min(max_new, max(1, limit)) + slack, P)
 
     def kv_block_bytes(self) -> int:
-        """K+V payload bytes per arena block (what the decode kernels
-        stream from HBM; int8 halves this vs bf16).  The per-(slot,
-        head) scale planes are excluded — they are the small constant
-        overhead documented in docs/decode_path.md."""
+        """Payload bytes per arena block over all layers and pools (what
+        the decode kernels stream from HBM; int8 halves this vs bf16;
+        a latent pool has no V).  The per-(slot, head) scale planes are
+        excluded — they are the small constant overhead documented in
+        docs/decode_path.md."""
+        return self.kv_bytes_per_token() * self.block
+
+    def kv_bytes_per_token(self) -> int:
+        """Bytes one cached token takes over all layers: the model's
+        ``cached_token`` (per-head K and V, or one latent) in the pools'
+        dtype."""
         k = self.pools.k
-        layers, _, heads, bs, d = k.shape
-        return 2 * layers * heads * bs * d * k.dtype.itemsize
+        per_layer = sum(heads * width for heads, width in self.mcfg.cached_token)
+        return int(k.shape[0]) * per_layer * k.dtype.itemsize
+
+    def _classic_only(self, what: str) -> None:
+        """Refuse, by name, what only the GPT-2 block's pools can do."""
+        if not self.mcfg.classic_block:
+            raise ValueError(
+                f"{what} is not written for latent pools and expert layers yet "
+                "(docs/serving.md \"What is refused\")")
+
+    def _count_moe(self, counts, fetch: bool = True) -> None:
+        """Fold one dispatch's expert-layer counts into stats.  A prefill's
+        (``fetch=False``) wait for the next commit's fetch: reading them
+        at the admission would hold the host until the prefill has run."""
+        if counts is None or self._warmup:
+            return
+        self._moe_pending.append(counts)
+        if not fetch:
+            return
+        pending, self._moe_pending = self._moe_pending, []
+        for c in pending:
+            for key, n in zip(("moe_pairs", "moe_held_pairs", "moe_held_max_pairs"),
+                              np.asarray(c).tolist()):
+                self.stats[key] += int(n)
 
     def _pools_tuple(self):
         return tuple(x for x in self.pools if x is not None)
@@ -471,12 +524,13 @@ class PagedDecodeEngine:
 
             def traced(p, prompt, plen, pools_t, table_row):
                 self.stats["traces"] += 1
-                pools, last, counts = paged_prefill(
+                pools, last, counts, moe = paged_prefill(
                     p, prompt, plen, PagedPools(*pools_t), table_row,
-                    self.mcfg, ctx=self.ctx,
+                    self.mcfg, ctx=self.ctx, return_moe=True,
                 )
                 out = tuple(x for x in pools if x is not None)
-                return out, last, counts
+                # a block with expert layers also hands back their counts
+                return (out, last, counts) + (() if moe is None else (moe,))
 
             fn = self._jax.jit(traced, donate_argnums=(3,))
             self._compiled_prefill[key] = fn
@@ -519,8 +573,9 @@ class PagedDecodeEngine:
                     ncommit = active.astype(self._jnp.int32)
                     rej2 = reject
                 out = tuple(x for x in pools if x is not None)
+                moe = () if rows2.moe is None else (rows2.moe,)
                 return (window, ncommit, out, rows2.logits, rows2.counts,
-                        rows2.positions, rows2.gen_steps, rows2.active, rej2)
+                        rows2.positions, rows2.gen_steps, rows2.active, rej2) + moe
 
             fn = self._jax.jit(traced, donate_argnums=(1,))
             self._compiled_step[key] = fn
@@ -864,7 +919,7 @@ class PagedDecodeEngine:
             prompt[0, :plen] = prompt_ids  # RIGHT-pad (paged rows are unpadded)
             fn = self._prefill_fn(P, PB)
             t_prefill = time.monotonic()
-            pools_t, last, counts = self._dispatch_donating(
+            pools_t, last, counts, *moe = self._dispatch_donating(
                 lambda: fn(
                     self.server.params,
                     jnp.asarray(prompt),
@@ -881,6 +936,7 @@ class PagedDecodeEngine:
             from paddlefleetx_tpu.models.gpt.generation import PagedPools
 
             self.pools = PagedPools(*pools_t)
+            self._count_moe(moe[0] if moe else None, fetch=False)
             self._logits = self._logits.at[slot].set(last)
             self._counts = self._counts.at[slot].set(counts)
             self._reject = self._reject.at[slot].set(-1)
@@ -1070,6 +1126,7 @@ class PagedDecodeEngine:
         exported bytes are identical either way — `gather_kv_blocks`
         copies shared and private blocks alike, and `pack_handoff`'s
         pool signature already guards cross-replica compatibility."""
+        self._classic_only("KV handoff (--role prefill)")
         prompt_ids = [int(t) for t in prompt_ids]
         plen = len(prompt_ids)
         P, PB, _, max_new = self._clamp_budget(plen, int(max_new))
@@ -1195,6 +1252,7 @@ class PagedDecodeEngine:
         contract), and seed the row state so the continuous scheduler
         continues exactly where the prefill replica's math stopped —
         greedy output token-identical to a single-process `admit`."""
+        self._classic_only("KV handoff (--role decode)")
         check_handoff_meta(
             meta, block=self.block, kv_dtype=self.kv_dtype,
             pool_sig=self._pool_sig(),
@@ -1573,7 +1631,7 @@ class PagedDecodeEngine:
             try:
                 with self.mesh:
                     (window, ncommit, pools_t, logits, counts, positions_t,
-                     gen_steps_t, active_t, reject) = fn(
+                     gen_steps_t, active_t, reject, *moe) = fn(
                         self.server.params, self._pools_tuple(),
                         tables, self._logits, self._counts,
                         positions, gen_steps, max_news, active,
@@ -1596,6 +1654,7 @@ class PagedDecodeEngine:
             "positions": positions_t, "gen_steps": gen_steps_t,
             "active": active_t, "rows": list(self.slots), "k": k,
             "was_active": None, "width_bucket": M,
+            "moe": moe[0] if moe else None,
         }
 
     def flush(self) -> List[int]:
@@ -1630,6 +1689,7 @@ class PagedDecodeEngine:
                 new_active = np.array(fl["active"])
                 positions = np.array(fl["positions"])
                 gen_steps = np.array(fl["gen_steps"])
+                self._count_moe(fl["moe"])
         except BaseException as exc:
             dead = self.reset()
             raise ArenaReset(
@@ -1656,9 +1716,13 @@ class PagedDecodeEngine:
         self.stats["row_steps"] += n_act
         self.stats["slot_steps"] += self.capacity
         self.stats["kv_tokens"] += int(self.positions[was_active].sum())
-        self.stats["grid_tokens"] += int(paged_tokens_computed(
-            positions - ncommit, fl["k"] + 1, self.block, fl["width_bucket"]
-        ).sum())
+        if self.mcfg.latent_attention:
+            self.stats["grid_tokens"] += int(mla_tokens_computed(
+                positions - ncommit, self.block, fl["width_bucket"]).sum())
+        else:
+            self.stats["grid_tokens"] += int(paged_tokens_computed(
+                positions - ncommit, fl["k"] + 1, self.block, fl["width_bucket"]
+            ).sum())
         t_chunk = time.monotonic()
         for i, r in enumerate(fl["rows"]):
             if r is None or not was_active[i]:
@@ -1747,6 +1811,7 @@ class PagedDecodeEngine:
         spill tier as the backstop when the live blocks get evicted
         before the resume lands.  Caller must ``flush()`` first
         (row-membership mutation, the dispatch-ahead contract)."""
+        self._classic_only("preempt-resume")
         row = self.slots[slot]
         if row is None:
             raise ValueError(f"slot {slot} is empty")
@@ -2144,6 +2209,10 @@ class ContinuousScheduler:
             # so neither gauge can exceed the arena under any sharing
             ("pfx_kv_bytes", {},
              float(cstats["kv_blocks_used"]) * eng.kv_block_bytes()),
+            # what one cached token takes over all layers: it comes from
+            # the model (per-head K and V, or one latent), and the arena's
+            # rows and --kv-blocks auto follow it
+            ("pfx_kv_bytes_per_token", {}, float(eng.kv_bytes_per_token())),
             ("pfx_prefix_cached_blocks", {},
              float(cstats["prefix_cached_blocks"])),
             # host-RAM spill tier occupancy (0 when --prefix-spill-bytes
@@ -2188,6 +2257,13 @@ class ContinuousScheduler:
             ("grid_tokens", "pfx_sched_decode_grid_tokens_total"),
         ):
             out.append((name, {}, float(eng.stats[key])))
+        if eng.mcfg.num_experts > 1:
+            for key, name in (
+                ("moe_pairs", "pfx_moe_serve_pairs_total"),
+                ("moe_held_pairs", "pfx_moe_serve_held_pairs_total"),
+                ("moe_held_max_pairs", "pfx_moe_serve_held_max_pairs_total"),
+            ):
+                out.append((name, {}, float(eng.stats[key])))
         for d, v in sorted(self._tok_ledger.items()):
             out.append((
                 "pfx_token_ledger_total", {"disposition": d}, float(v),
@@ -3238,6 +3314,8 @@ class ContinuousScheduler:
         tie-break: lowest slot index."""
         eng = self.engine
         best: Optional[int] = None
+        if not eng.mcfg.classic_block:
+            return None  # engine.preempt_row refuses this block by name
         for i, r in enumerate(eng.slots):
             if r is None or r.entry is None or r.entry.future.done():
                 continue

@@ -53,9 +53,10 @@ class BlockPoolExhausted(RuntimeError):
     """Not enough free KV blocks for the request (scheduler: stay queued)."""
 
 
-def kv_block_size(block: int = 0) -> int:
+def kv_block_size(block: int = 0, default: int = 0) -> int:
     """Resolve the paged-cache block size: explicit arg, else
-    PFX_KV_BLOCK, else {_DEFAULT_KV_BLOCK}.  Must be a positive multiple
+    PFX_KV_BLOCK, else ``default`` (the model's own:
+    ``GPTConfig.kv_block_default``), else {_DEFAULT_KV_BLOCK}.  Must be a positive multiple
     of 8 (TPU sublane tiling for the pallas spelling); invalid values
     raise at setup, never silently mislabel a run."""
     raw = os.environ.get("PFX_KV_BLOCK") or "0"
@@ -66,7 +67,7 @@ def kv_block_size(block: int = 0) -> int:
             f"PFX_KV_BLOCK={raw!r} is not an integer; pass a positive "
             "multiple of 8 (e.g. 16) or unset it"
         ) from None
-    force = int(block) or env or _DEFAULT_KV_BLOCK
+    force = int(block) or env or int(default) or _DEFAULT_KV_BLOCK
     if force < 8 or force % 8:
         raise ValueError(
             f"kv block size {force} must be a positive multiple of 8 "

@@ -22,6 +22,7 @@ from paddlefleetx_tpu.models.gpt.generation import (
     bucket_len,
     generate,
     init_cache,
+    init_serving_params,
     pad_prompts,
     serving_params,
 )
@@ -107,10 +108,18 @@ class GenerationServer:
         # caller passes stays alive in the caller, whole, through the cast
         if params is None:
             params = load_pretrained_params(cfg)
-        if params is None:
-            params = module.init_params(get_seed_tracker().params_key())
+        shardings = None
         if self.ctx is not None:
             shardings = tree_logical_to_sharding(module.logical_axes(), mesh, rules)
+        if params is None and hasattr(module.config, "classic_block"):
+            # random weights are made one leaf at a time, already in the
+            # dtype they are held in: the float32 tree never exists
+            params = init_serving_params(
+                module.config, get_seed_tracker().params_key(), shardings)
+            shardings = None
+        elif params is None:
+            params = module.init_params(get_seed_tracker().params_key())
+        if shardings is not None:
             params = jax.device_put(params, shardings)
         # the tree is HELD in the dtype the step computes in (float32 on
         # disk, cfg.dtype here, LayerNorm leaves float32): a decode step
@@ -208,6 +217,10 @@ class GenerationServer:
 
         if not prompts or any(len(p) == 0 for p in prompts):
             raise ValueError("prompts must be a non-empty list of non-empty id lists")
+        if not getattr(self.module.config, "classic_block", True):
+            raise ValueError(
+                "the coalesce scheduler (generate_ids, the contiguous cache) knows the "
+                "GPT-2 block only; serve this block with --scheduler continuous")
         from paddlefleetx_tpu.parallel.mesh import data_parallel_world
 
         gen = self.gen

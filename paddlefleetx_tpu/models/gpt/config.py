@@ -118,12 +118,41 @@ class GPTConfig:
     # (0 = none; docs/trinity_mini.md says what it is for)
     moe_bias_warm_start_steps: int = 0
     moe_bias_warm_start_rate: float = 0.0
+    # group-limited choice: the experts are moe_n_group groups of equal
+    # size, a group's score is the sum of its two highest choice scores,
+    # the moe_topk_group best groups stay and the top-k is taken among
+    # their experts (1 group = the plain top-k)
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # latent attention (kv_lora_rank > 0; docs/deepseek_v3.md): queries
+    # through a q_lora_rank bottleneck with an RMSNorm, keys and values
+    # expanded from ONE normalised latent of kv_lora_rank a token, plus one
+    # rotated key of qk_rope_head_dim shared by all heads.  Head h scores
+    # with qk_nope_head_dim + qk_rope_head_dim dims and yields v_head_dim.
+    # The rotation is over ADJACENT pairs (2i, 2i+1) of the rope dims.  What
+    # a cache holds of a token is the latent and the rotated key, once.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN frequencies of the rotation (rope_scaling_factor > 1): each
+    # frequency blended with itself / factor by the linear ramp between
+    # the correction dims of beta_fast and beta_slow at the original
+    # context; the softmax scale gains (0.1 mscale_all_dim ln factor + 1)^2
+    rope_scaling_factor: float = 1.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     def __post_init__(self):
         if self.ffn_hidden_size is None:
             object.__setattr__(self, "ffn_hidden_size", 4 * self.hidden_size)
         for field in ("norm_eps", "rope_theta", "moe_route_scale", "moe_bias_update_rate",
-                      "moe_bias_warm_start_rate"):
+                      "moe_bias_warm_start_rate", "rope_scaling_factor", "rope_beta_fast",
+                      "rope_beta_slow", "rope_mscale", "rope_mscale_all_dim"):
             # YAML reads "1e-05" (an override's spelling of a float) as a string
             object.__setattr__(self, field, float(getattr(self, field)))
         if not self.attn_head_dim and self.hidden_size % self.num_attention_heads:
@@ -144,6 +173,26 @@ class GPTConfig:
                 and self.moe_bias_warm_start_rate >= self.moe_bias_update_rate > 0):
             raise ValueError("moe_bias_warm_start_steps needs moe_gate: sigmoid and "
                              "moe_bias_warm_start_rate >= moe_bias_update_rate > 0")
+        if self.latent_attention:
+            if not (self.q_lora_rank and self.qk_nope_head_dim
+                    and self.qk_rope_head_dim and self.v_head_dim):
+                raise ValueError(
+                    "kv_lora_rank (latent attention) needs the described block and "
+                    "q_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError("qk_rope_head_dim must be even (pairs are rotated)")
+            for option in ("num_kv_heads", "attn_head_dim", "qk_norm", "attn_gate",
+                           "sliding_window", "global_attn_every"):
+                if getattr(self, option):
+                    raise ValueError(f"latent attention (kv_lora_rank) does not take {option}")
+        if self.moe_n_group > 1 and not (
+                self.moe_dropless and self.num_experts % self.moe_n_group == 0
+                and 1 <= self.moe_topk_group <= self.moe_n_group
+                and self.moe_top_k <= self.moe_topk_group * (self.num_experts // self.moe_n_group)
+                and self.num_experts // self.moe_n_group >= 2):
+            raise ValueError(
+                "moe_n_group needs moe_gate: sigmoid, groups of equal size >= 2, "
+                "moe_topk_group within 1..moe_n_group and moe_top_k experts inside them")
         if self.moe_dropless:
             last = self.moe_expert_offset + self.experts_held - 1
             if not 0 <= self.moe_expert_offset <= last < self.num_experts:
@@ -187,6 +236,36 @@ class GPTConfig:
         return self.num_kv_heads or self.num_attention_heads
 
     @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def cached_token(self) -> Tuple[Tuple[int, int], ...]:
+        """What a cache holds of one token in one layer: a (heads, width)
+        pair for each pool.  Per-head keys and values are two pools; the
+        latent and its rotated key are one, of one "head"."""
+        if self.latent_attention:
+            return ((1, self.kv_lora_rank + self.qk_rope_head_dim),)
+        return ((self.kv_heads, self.head_dim),) * 2
+
+    @property
+    def kv_block_default(self) -> int:
+        """Tokens a page of the paged arena holds unless the operator says
+        otherwise (0 = the library's default): a latent page holds one
+        vector a token, so 128 of them make the page of a DMA's size that
+        16 tokens of per-head keys make."""
+        return 128 if self.latent_attention else 0
+
+    @property
+    def rope_yarn_m(self) -> float:
+        """YaRN's attention factor m (1 without scaling)."""
+        if self.rope_scaling_factor <= 1.0:
+            return 1.0
+        import math
+
+        return 0.1 * self.rope_mscale_all_dim * math.log(self.rope_scaling_factor) + 1.0
+
+    @property
     def moe_dropless(self) -> bool:
         return self.num_experts > 1 and self.moe_gate == "sigmoid"
 
@@ -209,7 +288,7 @@ class GPTConfig:
                 and self.mlp_act == "gelu" and self.tie_embeddings
                 and not self.post_norms and not self.embed_scale_sqrt_hidden
                 and not self.sliding_window and not self.num_dense_layers
-                and not self.moe_dropless)
+                and not self.moe_dropless and not self.kv_lora_rank)
 
     def layer_kind(self, layer: int) -> Tuple[int, bool]:
         """(window or 0, rotate q and k) of layer ``layer``, counted from 0

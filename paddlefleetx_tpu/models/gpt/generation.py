@@ -25,11 +25,21 @@ import jax
 import jax.numpy as jnp
 
 from paddlefleetx_tpu.models.gpt.config import GPTConfig
-from paddlefleetx_tpu.models.gpt.model import ShardingCtx, _constrain, layer_norm
+from paddlefleetx_tpu.models.gpt.model import (
+    ShardingCtx,
+    _constrain,
+    latent_attention_expanded,
+    latent_projections,
+    latent_softmax_scale,
+    layer_norm,
+    rms_norm,
+)
 from paddlefleetx_tpu.ops.decode_attention import (
     decode_attention,
     kv_cache_dtype,
     kv_cache_len,
+    latent_page_write,
+    mla_paged_decode_attention,
     paged_decode_attention,
     quantize_kv,
 )
@@ -94,8 +104,16 @@ def init_cache(
 # convert (tests/test_serving.py holds the decode step to none).
 COMPUTE_DTYPE_LEAVES = {
     "embeddings": ("word", "position"),
-    "attn": ("qkv_kernel", "qkv_bias", "out_kernel", "out_bias"),
-    "mlp": ("fc_in_kernel", "fc_in_bias", "fc_out_kernel", "fc_out_bias"),
+    "attn": ("qkv_kernel", "qkv_bias", "out_kernel", "out_bias",
+             # latent attention (the norms' scales stay float32)
+             "q_a_kernel", "q_b_kernel", "kv_a_kernel", "k_b_kernel", "v_b_kernel"),
+    "mlp": ("fc_in_kernel", "fc_in_bias", "fc_out_kernel", "fc_out_bias",
+            "w1", "w3", "w2"),
+    # an expert layer: the router's kernel and its correction bias stay
+    # float32 (the choice is a discontinuity, moe.sigmoid_route)
+    "experts": ("w1", "w3", "w2"),
+    "shared": ("w1", "w3", "w2"),
+    "head": ("kernel",),
 }
 
 
@@ -116,25 +134,144 @@ def serving_params(params: Dict[str, Any], cfg: GPTConfig) -> Dict[str, Any]:
     handful of tokens, every step, to the same bits.  Each matmul consumed
     ``round(w)`` before and consumes it after: logits do not change.
 
-    A ``float32`` configuration gets its argument back.  Otherwise the
-    casts run leaf by leaf, each waited for, and this function keeps no
-    reference to a leaf it has replaced: a caller that hands over its only
-    reference to the tree (``GenerationServer``) never holds two whole
-    trees.  Leaves keep their sharding: each device converts its shard."""
+    A ``float32`` configuration keeps its leaves.  Otherwise the casts run
+    leaf by leaf, each waited for, and this function keeps no reference to
+    a leaf it has replaced: a caller that hands over its only reference to
+    the tree (``GenerationServer``) never holds two whole trees.  Leaves
+    keep their sharding: each device converts its shard.
+
+    The described block's tree comes back with its layers UNSTACKED
+    (:func:`unstack_layers`): ``blocks``, a tuple of one dict a layer."""
     dtype = jnp.dtype(cfg.dtype)
-    if dtype == jnp.float32:
-        return params
+    if dtype == jnp.float32 or "blocks" in params:
+        return params if cfg.classic_block else unstack_layers(params, cfg)
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     del params
     flat.reverse()
     out = []
     while flat:
         path, x = flat.pop()
-        group, name = (getattr(k, "key", None) for k in path[-2:])
-        if name in COMPUTE_DTYPE_LEAVES.get(group, ()) and x.dtype != dtype:
+        if _compute_dtype_leaf(path, x.dtype, dtype):
             x = jax.block_until_ready(x.astype(dtype))
         out.append(x)
-    return treedef.unflatten(out)
+    params = treedef.unflatten(out)
+    return params if cfg.classic_block else unstack_layers(params, cfg)
+
+
+def unstack_layers(params: Dict[str, Any], cfg: GPTConfig) -> Dict[str, Any]:
+    """The described block's tree as it is SERVED: ``dense_layers`` and
+    ``layers`` (stacked on a leading axis for the training scan) become
+    ``blocks``, a tuple of one dict a layer, leading dense layers first.
+    A layer loop over a stack slices each layer's weights out of it, and
+    what feeds a Mosaic call (the grouped products' expert weights) is
+    then COPIED out first: 0.7 GB a layer, every decode step (compiled for
+    the v5e).  Unstacked, every weight is read where it lies.  An expert
+    layer gains its routing bias ``e_score_correction_bias`` [experts]
+    (float32 zeros unless the tree brings one): what a served checkpoint
+    carries trained."""
+    if "blocks" in params:
+        return params
+    stacks = [params[k] for k in ("dense_layers", "layers") if k in params]
+    blocks = []
+    for stack in stacks:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        for l in range(n):
+            blocks.append(jax.tree.map(lambda a: jax.block_until_ready(a[l]), stack))
+    del stacks
+    out = {k: v for k, v in params.items() if k not in ("dense_layers", "layers")}
+    out["blocks"] = tuple(blocks)
+    return _with_routing_bias(out, cfg)
+
+
+def _compute_dtype_leaf(path, dtype_of_leaf, dtype) -> bool:
+    group, name = (getattr(k, "key", None) for k in path[-2:])
+    return name in COMPUTE_DTYPE_LEAVES.get(group, ()) and dtype_of_leaf != dtype
+
+
+def init_serving_params(cfg: GPTConfig, key: jax.Array, shardings=None) -> Dict[str, Any]:
+    """The tree ``serving_params(init(cfg, key), cfg)`` gives, made ONE LEAF
+    AT A TIME: a leaf's float32 original is cast and freed before the next
+    leaf is made, so the lifetime peak is the tree at rest plus one leaf in
+    float32.  (A float32 tree of a model whose bfloat16 weights fill half
+    the chip cannot exist on it at all.)  The same keys and the same
+    operations as ``models.common.init_params`` then ``serving_params``, so
+    the same values to the bit; the described block's layers are made unstacked
+    (each layer's leaf from the key its slice of the stack would get).
+    ``shardings``: a tree of shardings matching ``gpt_specs`` (a mesh;
+    the GPT-2 block only), or None."""
+    from paddlefleetx_tpu.models.common import ParamSpec
+    from paddlefleetx_tpu.models.gpt.model import _block_layer_specs, gpt_specs
+
+    dtype = jnp.dtype(cfg.dtype)
+    is_spec = lambda x: isinstance(x, ParamSpec)  # noqa: E731
+    flat, treedef = jax.tree_util.tree_flatten_with_path(gpt_specs(cfg), is_leaf=is_spec)
+    keys = jax.random.split(key, len(flat))
+    places = [None] * len(flat) if shardings is None else treedef.flatten_up_to(shardings)
+
+    def make(path, spec, k, place=None):
+        x = spec.init(k, spec.shape, spec.dtype)
+        if _compute_dtype_leaf(path, x.dtype, dtype):
+            x = x.astype(dtype)  # the float32 original is freed as this returns
+        return jax.block_until_ready(x if place is None else jax.device_put(x, place))
+
+    if cfg.classic_block:
+        return treedef.unflatten(
+            [make(path, spec, k, place) for (path, spec), k, place in zip(flat, keys, places)])
+    if shardings is not None:
+        raise ValueError("tensor parallelism: the described block is served on one device")
+    # the stacked leaf of n layers draws layer l from split(leaf key, n)[l]
+    n_dense = cfg.leading_dense_layers
+    stacks = {"dense_layers": (n_dense, _block_layer_specs(cfg, False), 0),
+              "layers": (cfg.num_layers - n_dense,
+                         _block_layer_specs(cfg, cfg.moe_dropless), n_dense)}
+    blocks = [{} for _ in range(cfg.num_layers)]
+    out: Dict[str, Any] = {}
+
+    def put(tree, names, leaf):
+        for name in names[:-1]:
+            tree = tree.setdefault(name, {})
+        tree[names[-1]] = leaf
+
+    for (path, spec), k in zip(flat, keys):
+        names = [p.key for p in path]
+        if names[0] not in stacks:
+            put(out, names, make(path, spec, k))
+            continue
+        n, inner, first = stacks[names[0]]
+        for name in names[1:]:
+            inner = inner[name]
+        for l, k_l in enumerate(jax.random.split(k, n)):
+            put(blocks[first + l], names[1:], make(path, inner, k_l))
+    out["blocks"] = tuple(blocks)
+    return _with_routing_bias(out, cfg)
+
+
+def _with_routing_bias(params, cfg: GPTConfig):
+    for blk in params["blocks"]:
+        if "router_kernel" in blk["mlp"]:
+            blk["mlp"].setdefault(
+                "e_score_correction_bias", jnp.zeros((cfg.num_experts,), jnp.float32))
+    return params
+
+
+def check_servable(cfg: GPTConfig) -> None:
+    """Which blocks the serving forwards know (docs/serving.md "What a block
+    must provide"): the GPT-2 block, and the described block with latent
+    attention (a dense SwiGLU or a dropless expert MLP).  Anything else
+    raises, naming the option that is in the way."""
+    if cfg.classic_block:
+        if cfg.num_experts > 1:
+            raise ValueError("serving knows no capacity-factor expert layer (num_experts)")
+        return
+    for option in ("num_kv_heads", "attn_head_dim", "qk_norm", "attn_gate", "post_norms",
+                   "sliding_window", "global_attn_every", "embed_scale_sqrt_hidden"):
+        if getattr(cfg, option):
+            raise ValueError(
+                f"serving the described block: {option} is not served yet (per-head pools "
+                "of shared KV heads and a window-aware allocator are ROADMAP queue 2)")
+    if not cfg.latent_attention:
+        raise ValueError("serving the described block needs latent attention (kv_lora_rank): "
+                         "a per-head rope block has no serving forward yet")
 
 
 # ---------------------------------------------------------------------------
@@ -463,8 +600,10 @@ def generate(
     ``return_spec_stats`` appends an ``(proposed, accepted)`` int32 pair
     to the return tuple (acceptance telemetry)."""
     if cfg.num_experts > 1 or not cfg.classic_block:
-        raise NotImplementedError(
-            "KV-cache generation knows the GPT-2 block without expert layers only")
+        raise ValueError(
+            "generate()'s contiguous cache knows the GPT-2 block without expert layers "
+            "only; the described block is served through the paged pools "
+            "(--scheduler continuous)")
     if return_spec_stats and spec is None:
         raise ValueError("return_spec_stats needs a SpecConfig")
     b, prompt_len = input_ids.shape
@@ -824,10 +963,17 @@ class PagedPools(NamedTuple):
     clamped index map.  The arena is donated into every dispatch, carried
     through the step's layer loop and written in place: a handle handed
     to a dispatch is dead, the returned one is the arena
-    (docs/decode_path.md, "The arena's contract")."""
+    (docs/decode_path.md, "The arena's contract").
+
+    What a pool holds of a token comes from the model
+    (``GPTConfig.cached_token``): under latent attention ``k`` is the ONE
+    pool [layers, num_blocks, 1, kv_lora + rope, block] (a token is one
+    COLUMN of its page: the normalised latent, then the rotated shared
+    key; tokens minor, ``ops/decode_attention.py`` says why) and there is
+    no ``v``: the values are the first kv_lora entries of each column."""
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array] = None
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
 
@@ -836,9 +982,17 @@ def init_paged_pools(
     cfg: GPTConfig, num_blocks: int, block: int, dtype=None,
     kv_dtype: str = "",
 ) -> PagedPools:
+    check_servable(cfg)
+    quant = kv_cache_dtype(kv_dtype) == "int8"
+    if cfg.latent_attention:
+        if quant:
+            raise ValueError("kv_dtype int8: latent pools are not quantized yet")
+        (heads, width), = cfg.cached_token
+        return PagedPools(jnp.zeros((cfg.num_layers, num_blocks, heads, width, block),
+                                    dtype or jnp.dtype(cfg.dtype)))
     shape = (cfg.num_layers, num_blocks, cfg.num_attention_heads, block,
              cfg.head_dim)
-    if kv_cache_dtype(kv_dtype) == "int8":
+    if quant:
         sshape = shape[:-1]
         return PagedPools(
             jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
@@ -878,6 +1032,180 @@ class PagedRows(NamedTuple):
     active: jax.Array        # [B] bool
     forced_steps: jax.Array  # [B] int32
     reject: Optional[jax.Array] = None  # [B] int32 (-1 = none)
+    # expert layers only: what the step just run counted, :func:`_moe_counts`
+    moe: Optional[jax.Array] = None
+
+
+# ---------------------------------------------------------------------------
+# The described block on the paged pools (latent attention; SwiGLU or the
+# dropless expert layer).  Prefill runs the EXPANDED form over the prompt and
+# keeps each token's latent; a decode step runs the ABSORBED form against
+# the latent pages.  docs/deepseek_v3.md has both with their equations.
+# ---------------------------------------------------------------------------
+
+
+def _moe_counts(stats) -> jax.Array:
+    """Expert layers' load statistics (a list, one dict a layer) -> int32
+    [3]: the pairs the valid tokens gave, those on experts held here, the
+    fullest held expert's pairs x experts held (over the second: max over
+    mean), each summed over the layers."""
+    total = jnp.zeros((3,), jnp.int32)
+    for st in stats:
+        n_held = st["pairs_held"]
+        fullest = jnp.round(st["load_max_over_mean"] * n_held).astype(jnp.int32)
+        total = total + jnp.stack([jnp.sum(st["load"]), n_held, fullest]).astype(jnp.int32)
+    return total
+
+
+def _block_mlp(p, m, cfg: GPTConfig, valid):
+    """The block's feed-forward over m [b, t, h] -> (result, the expert
+    layer's load statistics or None).  An expert layer is one whose
+    parameters hold a router.  A decode step (t == 1: a token a row) runs
+    every held expert on every row; a prefill sorts its pairs, as training
+    does at every size."""
+    from paddlefleetx_tpu.models.gpt.moe import dropless_moe_block, swiglu
+
+    dtype = m.dtype
+    if "router_kernel" not in p:
+        return swiglu(m, _in_dtype("mlp", p, dtype)), None
+    q = dict(p, experts=_in_dtype("experts", p["experts"], dtype))
+    if "shared" in p:
+        q["shared"] = _in_dtype("shared", p["shared"], dtype)
+    return dropless_moe_block(q, m, cfg, None, p["e_score_correction_bias"], valid,
+                              every_held_expert=m.shape[1] == 1)
+
+
+def _block_layer_step(p, x, positions, valid, cfg: GPTConfig, attend):
+    """One layer of the described block over x [b, t, h] at ``positions``
+    [b, t]; ``valid`` [b, t] marks the tokens that are someone's.
+    ``attend(attn params, q_nope, q_r, latent)`` writes the latents to its
+    cache and attends -> (attention result [b, t, n, v], cache state)."""
+    dtype = x.dtype
+    attn = _in_dtype("attn", p["attn"], dtype)
+    y = rms_norm(x, p["ln_1"]["scale"], cfg.norm_eps)
+    out, state = attend(attn, *latent_projections(attn, y, positions, cfg))
+    x = x + jnp.einsum("bsnd,ndh->bsh", out, attn["out_kernel"])
+    f, stats = _block_mlp(p["mlp"], rms_norm(x, p["ln_2"]["scale"], cfg.norm_eps), cfg, valid)
+    return x + f, state, stats
+
+
+def _block_stack_step(params, x, state, cfg: GPTConfig, layer_fn):
+    """The block's stack, layer after layer (no scan: the served tree holds
+    each layer's weights as leaves of their own, :func:`unstack_layers`):
+    ``layer_fn(p, x, state, layer) -> (x, state, stats or None)`` with
+    ``layer`` static and ``state`` the caller's cache (the paged arena,
+    written in place from layer to layer).  -> (x, state, the expert
+    layers' statistics, a list)."""
+    stats = []
+    for l, p_l in enumerate(unstack_layers(params, cfg)["blocks"]):
+        x, state, st = layer_fn(p_l, x, state, l)
+        if st is not None:
+            stats.append(st)
+    return x, state, stats
+
+
+def _block_logits(params, x, cfg: GPTConfig):
+    x = rms_norm(x, params["final_ln"]["scale"], cfg.norm_eps)
+    head = _in_dtype("head", params["head"], x.dtype)["kernel"]
+    return jnp.einsum("bsh,vh->bsv", x, head).astype(jnp.float32)
+
+
+def _block_paged_forward_step(params, tokens, pools, block_tables, positions, active,
+                              cfg: GPTConfig, ctx):
+    """The decode step of the described block: tokens [B] at slots
+    ``positions`` -> (logits [B, 1, v] f32, pools, counts).  Latent
+    attention in its ABSORBED form: the query goes into the latent space
+    (``W_uk^T q_nope``), scores against the row's latent pages, and the
+    probabilities' sum over the latents comes back through ``W_uv``."""
+    if ctx is not None:
+        raise ValueError("tensor parallelism: the described block is served on one "
+                         "device (its pools and experts have no sharding rules yet)")
+    if tokens.ndim == 2 and tokens.shape[1] != 1:
+        raise ValueError(
+            "the described block's decode step takes one token a row: a verify chunk "
+            "(draft_k) or a prompt chunk (prefill_chunk) over latent pools is not written")
+    tokens = tokens.reshape(-1)
+    dtype = jnp.dtype(cfg.dtype)
+    word = _in_dtype("embeddings", params["embeddings"], dtype)["word"]
+    x = word[tokens][:, None]  # [B, 1, h]
+    bs = pools.k.shape[4]
+    pos = jnp.where(active, positions, 0)
+    blk_log = jnp.clip(pos // bs, 0, block_tables.shape[1] - 1)
+    blk = jnp.take_along_axis(block_tables, blk_log[:, None], axis=1)[:, 0]
+    blk = jnp.where(active, blk, 0)  # inactive rows -> null block
+    off = pos % bs
+    kl = cfg.kv_lora_rank
+    scale = latent_softmax_scale(cfg)
+
+    def layer_fn(p, x, pools, layer):
+        def attend(attn, q_nope, q_r, latent):
+            pool = latent_page_write(pools.k, latent[:, 0], blk, off, layer=layer)
+            with jax.named_scope("pfx.attn.mla.absorb"):
+                q_lat = jnp.einsum("bnd,cnd->bnc", q_nope[:, 0], attn["k_b_kernel"])
+            q = jnp.concatenate([q_lat, q_r[:, 0]], axis=-1)
+            with jax.named_scope("pfx.attn.mla.decode"):
+                o_lat = mla_paged_decode_attention(
+                    q, pool, block_tables, pos, layer=layer, scale=scale, kv_lora=kl)
+            with jax.named_scope("pfx.attn.mla.absorb"):
+                out = jnp.einsum("bnc,cnd->bnd", o_lat.astype(x.dtype), attn["v_b_kernel"])
+            return out[:, None], PagedPools(pool)
+
+        return _block_layer_step(p, x, pos[:, None], active[:, None], cfg, attend)
+
+    x, pools, stats = _block_stack_step(params, x, pools, cfg, layer_fn)
+    return _block_logits(params, x, cfg), pools, _moe_counts(stats) if stats else None
+
+
+def _block_paged_prefill(params, prompt, prompt_len, pools, table_row, cfg: GPTConfig, ctx):
+    """Prefill of the described block: the EXPANDED form over the padded
+    prompt [1, P] (causal, so the real rows' arithmetic is the unpadded
+    one), each layer's latents written to the row's blocks.  -> (pools,
+    the last real token's logits [v], counts)."""
+    if ctx is not None:
+        raise ValueError("tensor parallelism: the described block is served on one device")
+    P = int(prompt.shape[1])
+    PB, bs = int(table_row.shape[0]), int(pools.k.shape[4])
+    if PB * bs < P:
+        raise ValueError(f"table_row covers {PB}x{bs}={PB * bs} slots < prompt bucket {P}")
+    dtype = jnp.dtype(cfg.dtype)
+    word = _in_dtype("embeddings", params["embeddings"], dtype)["word"]
+    x = word[prompt]
+    positions = jax.lax.iota(jnp.int32, P)[None]
+    valid = positions < prompt_len
+
+    def layer_fn(p, x, pools, layer):
+        def attend(attn, q_nope, q_r, latent):
+            with jax.named_scope("pfx.attn.mla.prefill"):
+                out = latent_attention_expanded(attn, q_nope, q_r, latent, cfg)
+            pages = jnp.pad(latent[0], ((0, PB * bs - P), (0, 0))).reshape(PB, 1, bs, -1)
+            pages = pages.transpose(0, 1, 3, 2).astype(pools.k.dtype)  # a token is a column
+            return out, PagedPools(pools.k.at[layer, table_row].set(pages))
+
+        return _block_layer_step(p, x, positions, valid, cfg, attend)
+
+    x, pools, stats = _block_stack_step(params, x, pools, cfg, layer_fn)
+    last = jax.lax.dynamic_index_in_dim(x[0], prompt_len - 1, axis=0, keepdims=True)
+    return (pools, _block_logits(params, last[None], cfg)[0, 0],
+            _moe_counts(stats) if stats else None)
+
+
+def expert_load(params, tokens: jax.Array, cfg: GPTConfig) -> jax.Array:
+    """tokens [1, s] through the described block's EXPANDED forward, no
+    cache -> the pairs each expert of each expert layer received,
+    [expert layers, experts] int32 (held or not): what the routing bias's
+    balance rule (``moe.next_expert_bias``) reads."""
+    dtype = jnp.dtype(cfg.dtype)
+    x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][tokens]
+    positions = jnp.broadcast_to(jax.lax.iota(jnp.int32, tokens.shape[1])[None], tokens.shape)
+
+    def layer_fn(p, x, state, layer):
+        def attend(attn, q_nope, q_r, latent):
+            return latent_attention_expanded(attn, q_nope, q_r, latent, cfg), state
+
+        return _block_layer_step(p, x, positions, None, cfg, attend)
+
+    _, _, stats = _block_stack_step(params, x, None, cfg, layer_fn)
+    return jnp.stack([st["load"] for st in stats])
 
 
 def _paged_layer_step(
@@ -949,6 +1277,11 @@ def paged_forward_step(
     >= its real token count: a padded tail chunk's junk positions can
     wrap onto REAL slots of the row's last allocated block after the
     table-width clamp, so pad K/V must never be written anywhere."""
+    if not cfg.classic_block:
+        if n_valid is not None:
+            raise ValueError("prefill_chunk: a prompt chunk over latent pools is not written")
+        return _block_paged_forward_step(
+            params, tokens, pools, block_tables, positions, active, cfg, ctx)[:2]
     if tokens.ndim == 1:
         tokens = tokens[:, None]
     B, t = tokens.shape
@@ -1002,6 +1335,7 @@ def paged_prefill(
     table_row: jax.Array,
     cfg: GPTConfig,
     ctx: Optional[ShardingCtx] = None,
+    return_moe: bool = False,
 ) -> Tuple[PagedPools, jax.Array, jax.Array]:
     """Prefill ONE row's prompt into its pool blocks (prefill-on-admit).
 
@@ -1018,8 +1352,15 @@ def paged_prefill(
     argument as the donated contiguous pool.
 
     Returns (pools, last real token's logits [v] f32, prompt token
-    counts [v] for repetition penalty)."""
+    counts [v] for repetition penalty); with ``return_moe`` a fourth, the
+    expert layers' counts (:func:`_moe_counts`) or None without any."""
     P = int(prompt.shape[1])
+    if not cfg.classic_block:
+        pools, last, moe = _block_paged_prefill(
+            params, prompt, prompt_len, pools, table_row, cfg, ctx)
+        counts = jnp.zeros((cfg.vocab_size,), jnp.int32).at[prompt[0]].add(
+            (jnp.arange(P) < prompt_len).astype(jnp.int32))
+        return (pools, last, counts, moe) if return_moe else (pools, last, counts)
     layers = cfg.num_layers
     n = cfg.num_attention_heads
     d = cfg.head_dim
@@ -1055,15 +1396,17 @@ def paged_prefill(
     if pools.k_scale is not None:
         kq, ksl = quantize_kv(pack(cache.k))
         vq, vsl = quantize_kv(pack(cache.v))
-        return PagedPools(
+        new = PagedPools(
             pools.k.at[:, table_row].set(kq),
             pools.v.at[:, table_row].set(vq),
             pools.k_scale.at[:, table_row].set(ksl),
             pools.v_scale.at[:, table_row].set(vsl),
-        ), last, counts
-    k_pool = pools.k.at[:, table_row].set(pack(cache.k).astype(pools.k.dtype))
-    v_pool = pools.v.at[:, table_row].set(pack(cache.v).astype(pools.v.dtype))
-    return PagedPools(k_pool, v_pool), last, counts
+        )
+    else:
+        new = PagedPools(
+            pools.k.at[:, table_row].set(pack(cache.k).astype(pools.k.dtype)),
+            pools.v.at[:, table_row].set(pack(cache.v).astype(pools.v.dtype)))
+    return (new, last, counts, None) if return_moe else (new, last, counts)
 
 
 def paged_chunk_prefill(
@@ -1236,10 +1579,15 @@ def decode_step(
     finished = rows.active & (
         (nxt == gen.eos_token_id) | (i + 1 >= rows.max_news)
     )
-    new_logits, pools = paged_forward_step(
-        params, nxt, pools, block_tables, rows.positions, rows.active,
-        cfg, ctx,
-    )
+    if cfg.classic_block:
+        new_logits, pools = paged_forward_step(
+            params, nxt, pools, block_tables, rows.positions, rows.active,
+            cfg, ctx,
+        )
+        moe = None
+    else:
+        new_logits, pools, moe = _block_paged_forward_step(
+            params, nxt, pools, block_tables, rows.positions, rows.active, cfg, ctx)
     act = rows.active.astype(jnp.int32)
     new_rows = PagedRows(
         logits=new_logits[:, 0],
@@ -1249,6 +1597,7 @@ def decode_step(
         max_news=rows.max_news,
         active=rows.active & ~finished,
         forced_steps=rows.forced_steps,
+        moe=moe,
     )
     return nxt, pools, new_rows
 

@@ -91,17 +91,35 @@ def _block_layer_specs(cfg: GPTConfig, experts: bool) -> Dict[str, Any]:
     RMSNorm, no biases, a SwiGLU or an expert MLP."""
     h, nh, nkv, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     w = normal_init(cfg.initializer_range)
-    attn: Dict[str, Any] = {
-        "q_kernel": ParamSpec((h, nh, hd), ("embed", "heads", "kv"), w),
-        "k_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
-        "v_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
-        "out_kernel": ParamSpec((nh, hd, h), ("heads", "kv", "embed"), w),
-    }
-    if cfg.attn_gate:
-        attn["gate_kernel"] = ParamSpec((h, nh, hd), ("embed", "heads", "kv"), w)
-    if cfg.qk_norm:
-        attn["q_norm"] = ParamSpec((hd,), (None,), ones_init())
-        attn["k_norm"] = ParamSpec((hd,), (None,), ones_init())
+    if cfg.latent_attention:
+        ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rot, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        # W_kvb is two leaves (its key columns and its value columns): the
+        # decode step multiplies by each alone.  W_qb keeps its published
+        # 2-D shape: as [q_lora, heads, 192] the TPU pads the 192 to 256
+        # and the decode step converts the whole matrix first, every layer
+        attn: Dict[str, Any] = {
+            "q_a_kernel": ParamSpec((h, ql), ("embed", None), w),
+            "q_a_norm": ParamSpec((ql,), (None,), ones_init()),
+            "q_b_kernel": ParamSpec((ql, nh * (nope + rot)), (None, "heads"), w),
+            "kv_a_kernel": ParamSpec((h, kl + rot), ("embed", None), w),
+            "kv_a_norm": ParamSpec((kl,), (None,), ones_init()),
+            "k_b_kernel": ParamSpec((kl, nh, nope), (None, "heads", "kv"), w),
+            "v_b_kernel": ParamSpec((kl, nh, vd), (None, "heads", "kv"), w),
+            "out_kernel": ParamSpec((nh, vd, h), ("heads", "kv", "embed"), w),
+        }
+    else:
+        attn = {
+            "q_kernel": ParamSpec((h, nh, hd), ("embed", "heads", "kv"), w),
+            "k_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
+            "v_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
+            "out_kernel": ParamSpec((nh, hd, h), ("heads", "kv", "embed"), w),
+        }
+        if cfg.attn_gate:
+            attn["gate_kernel"] = ParamSpec((h, nh, hd), ("embed", "heads", "kv"), w)
+        if cfg.qk_norm:
+            attn["q_norm"] = ParamSpec((hd,), (None,), ones_init())
+            attn["k_norm"] = ParamSpec((hd,), (None,), ones_init())
     from paddlefleetx_tpu.models.gpt.moe import dropless_layer_specs, swiglu_specs
 
     mlp = dropless_layer_specs(cfg) if experts else swiglu_specs(h, cfg.ffn_hidden_size, w)
@@ -238,6 +256,94 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
     x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
     out = xf * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang)
     return out.astype(x.dtype)
+
+
+def rope_frequencies(cfg: GPTConfig) -> jax.Array:
+    """The qk_rope_head_dim / 2 rotation frequencies of latent attention:
+    theta^(-2i/d), under YaRN each blended with itself / factor by the
+    linear ramp between the correction dims of beta_fast and beta_slow at
+    the original context (the dims below the first keep their frequency,
+    those above the second are divided by the factor)."""
+    import math
+
+    d = cfg.qk_rope_head_dim
+    i = jax.lax.iota(jnp.float32, d // 2)
+    freq = cfg.rope_theta ** (-2.0 * i / d)
+    if cfg.rope_scaling_factor <= 1.0:
+        return freq
+
+    def correction_dim(rotations: float) -> float:
+        return d * math.log(cfg.rope_original_max_position / (rotations * 2 * math.pi)) / (
+            2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), d - 1)
+    ramp = jnp.clip((i - low) / max(high - low, 0.001), 0.0, 1.0)
+    return freq * (1.0 - ramp) + freq / cfg.rope_scaling_factor * ramp
+
+
+def latent_softmax_scale(cfg: GPTConfig) -> float:
+    """(qk_nope + qk_rope)^-0.5, times YaRN's m squared."""
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * cfg.rope_yarn_m ** 2
+
+
+def rope_pairs(x: jax.Array, positions: jax.Array, cfg: GPTConfig) -> jax.Array:
+    """Rotate ADJACENT pairs (2i, 2i+1) of the last dim of x [..., s, *, d]
+    (or [..., s, d]) by positions [..., s] x ``rope_frequencies``, angles
+    in float32; the cos/sin factor mscale / mscale_all_dim."""
+    ang = positions.astype(jnp.float32)[..., None] * rope_frequencies(cfg)
+    if x.ndim == positions.ndim + 2:
+        ang = ang[..., None, :]
+    m = 1.0
+    if cfg.rope_scaling_factor > 1.0:
+        import math
+
+        m = (0.1 * cfg.rope_mscale * math.log(cfg.rope_scaling_factor) + 1.0) / cfg.rope_yarn_m
+    cos, sin = jnp.cos(ang) * m, jnp.sin(ang) * m
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def latent_projections(p, x, positions, cfg: GPTConfig):
+    """x [b, s, h] at ``positions`` [b, s] -> (q_nope [b, s, n, nope],
+    rotated q_rope [b, s, n, rot], what the cache keeps of each token: the
+    normalised latent then the rotated shared key, [b, s, kv_lora + rot])."""
+    dtype = x.dtype
+    kl, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    c_q = rms_norm(x @ p["q_a_kernel"].astype(dtype), p["q_a_norm"], cfg.norm_eps)
+    q = (c_q @ p["q_b_kernel"].astype(dtype)).reshape(
+        x.shape[:2] + (cfg.num_attention_heads, -1))
+    kv = x @ p["kv_a_kernel"].astype(dtype)
+    c = rms_norm(kv[..., :kl], p["kv_a_norm"], cfg.norm_eps)
+    k_r = rope_pairs(kv[..., kl:], positions, cfg)
+    q_r = rope_pairs(q[..., nope:], positions, cfg)
+    return q[..., :nope], q_r, jnp.concatenate([c, k_r], axis=-1)
+
+
+def latent_attention_expanded(p, q_nope, q_r, latent, cfg: GPTConfig, ctx=None) -> jax.Array:
+    """The EXPANDED form over one causal sequence: keys and values of every
+    head made from the latents, [b, s, n, v_head_dim] out.  The softmax
+    scale is folded into q (the attention entry point scales by d^-0.5);
+    under ``attn_impl: flash`` the values are padded to the key width,
+    which the kernel wants equal (the padding's columns are cut again)."""
+    dtype = q_nope.dtype
+    kl = cfg.kv_lora_rank
+    c, k_r = latent[..., :kl], latent[..., kl:]
+    k_nope = jnp.einsum("bsc,cnd->bsnd", c, p["k_b_kernel"].astype(dtype))
+    v = jnp.einsum("bsc,cnd->bsnd", c, p["v_b_kernel"].astype(dtype))
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_r[:, :, None], k_nope.shape[:-1] + k_r.shape[-1:])], axis=-1)
+    q = jnp.concatenate([q_nope, q_r], axis=-1)
+    d = q.shape[-1]
+    q = (q.astype(jnp.float32) * (latent_softmax_scale(cfg) * d ** 0.5)).astype(dtype)
+    flash = cfg.attn_impl == "flash" and d > v.shape[-1]
+    if flash:
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, d - v.shape[-1]),))
+    out = attention(q, k, v, impl=cfg.attn_impl, causal=True, flash_block=cfg.flash_block,
+                    flash_bwd=cfg.flash_bwd, ctx=ctx)
+    return out[..., :cfg.v_head_dim] if flash else out
 
 
 def _layer_remat(cfg: GPTConfig, fn):
@@ -399,6 +505,12 @@ def _block_attention(p, x, cfg: GPTConfig, ctx, window: int, rotate: bool) -> ja
     """Grouped-query causal attention as the vocabulary spells it.
     x: [b, s, h] -> [b, s, h]."""
     dtype = x.dtype
+    if cfg.latent_attention:
+        positions = jnp.broadcast_to(jax.lax.iota(jnp.int32, x.shape[1])[None], x.shape[:2])
+        q_nope, q_r, latent = latent_projections(p, x, positions, cfg)
+        with jax.named_scope("pfx.attn.mla.prefill"):
+            out = latent_attention_expanded(p, q_nope, q_r, latent, cfg, ctx)
+        return jnp.einsum("bsnd,ndh->bsh", out, p["out_kernel"].astype(dtype))
 
     def proj(name):
         return jnp.einsum("bsh,hnd->bsnd", x, p[f"{name}_kernel"].astype(dtype))

@@ -829,6 +829,232 @@ def paged_decode_attention(
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# Paged decode attention over LATENT pages (latent attention's absorbed
+# form: models/gpt/generation.py, docs/deepseek_v3.md).  A cached token is
+# ONE vector, the normalised latent then the rotated shared key
+# ([kv_lora + rope]); every head's key is that vector and every head's value
+# its first kv_lora columns, so all the heads of a row are the ROWS of one
+# matrix product against a page.  A page is [w, block], TOKENS MINOR: the
+# TPU's tiling would pad a w = 576-wide minor dim to 640, and the compiler
+# keeps such an array token-minor on the device whatever its logical shape
+# says (compiled for the v5e, a [block, w] pool was converted whole on both
+# sides of the kernel, 2.4 GB each way a decode step).
+# ---------------------------------------------------------------------------
+
+# latent tokens one grid step walks for a row.  A token is 1,152 bytes and
+# 278,528 FLOPs, 1.4 ns of either on the v5e, and a grid step costs 0.35-0.39
+# us whatever it holds (PERF.md section 5), as much as 256 tokens: at 512 a
+# step the kernel's time was its 1,024 grid steps a layer (64 rows x 16),
+# 23% of its roofline at 22 live rows (my chip run, PR 31); 8 pages of
+# [576, 128] are 1.2 MB of VMEM, twice for the pipeline
+MLA_STEP_TOKENS = 1024
+
+
+def mla_pages_per_step(block: int, width: int) -> int:
+    return max(1, min(MLA_STEP_TOKENS // block, width))
+
+
+def _mla_paged_lax(q, pool, layer, tables, positions, scale, kv_lora):
+    """q [b, n, w] (absorbed query then rotated query, w = kv_lora + rope);
+    pool [layers, nb, 1, w, bs]; tables [b, M]; positions [b] = the slot of
+    each row's query, which attends its logical slots [0, positions].
+    -> [b, n, kv_lora] float32: the probabilities' sum over the latents."""
+    b, n, _ = q.shape
+    bs = pool.shape[4]
+    last_blk = jnp.maximum(positions, 0) // bs
+
+    def body(j, carry):
+        m, l, acc = carry
+        blk = jnp.take_along_axis(tables, jnp.minimum(j, last_blk)[:, None], axis=1)[:, 0]
+        page = pool[layer, blk, 0]  # [b, w, bs] gather
+        s = scale * jnp.einsum("bnw,bwk->bnk", q, page, preferred_element_type=jnp.float32)
+        col = j * bs + jnp.arange(bs)
+        mask = (col[None, :] <= positions[:, None])[:, None, :]
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bnk,bck->bnc", p.astype(page.dtype), page[:, :kv_lora],
+            preferred_element_type=jnp.float32)
+        return m_new, l * alpha + p.sum(axis=-1), acc
+
+    nvisit = jnp.minimum((jnp.max(positions) + bs) // bs, tables.shape[1])
+    m, l, acc = jax.lax.fori_loop(0, nvisit, body, (
+        jnp.full((b, n), NEG_INF, jnp.float32), jnp.zeros((b, n), jnp.float32),
+        jnp.zeros((b, n, kv_lora), jnp.float32)))
+    return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+def _mla_paged_kernel(layer_ref, tables_ref, pos_ref, q_ref, *refs, scale, bs, pages,
+                      width, kv_lora):
+    """One (row, page group) grid step, every head inside: ``refs`` =
+    ``pages`` latent pages [w, bs] (the index maps clamp past the row's
+    last page, as the per-head kernel's), then o_ref and acc / m / l."""
+    kv = refs[:pages]
+    o_ref, acc_ref, m_ref, l_ref = refs[pages:]
+    i, j = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[i]
+    last = jnp.maximum(pos, 0) // bs
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j * pages <= last)
+    def _group():
+        q = q_ref[0]  # [n, w]
+        c = kv[0][...] if pages == 1 else jnp.concatenate([r[...] for r in kv], 1)
+        s = scale * jax.lax.dot_general(
+            q, c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # [n, w] x [w, pages * bs]
+        shape = (1, pages * bs)
+        col = j * (pages * bs) + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = col <= pos
+        if width % pages:
+            mask = mask & (col < width * bs)  # spare pages of the last group
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(c.dtype), c[:kv_lora], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [n, T] x [kv_lora, T]^T
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _done():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def _mla_paged_pallas(q, pool, layer, tables, positions, scale, kv_lora):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, n, w = q.shape
+    bs = pool.shape[4]
+    M = tables.shape[1]
+    pages = mla_pages_per_step(bs, M)
+
+    def page_index(p):
+        def index(i, j, layer_ref, tables_ref, pos_ref):
+            last = jnp.maximum(pos_ref[i], 0) // bs
+            page = jnp.minimum(j * pages + p, jnp.minimum(last, M - 1))
+            return (layer_ref[0], tables_ref[i, page], 0, 0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, -(-M // pages)),
+        in_specs=[pl.BlockSpec((1, n, w), lambda i, j, *_: (i, 0, 0))] + [
+            pl.BlockSpec((None, None, None, w, bs), page_index(p)) for p in range(pages)],
+        out_specs=pl.BlockSpec((1, n, kv_lora), lambda i, j, *_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((n, kv_lora), jnp.float32),
+            pltpu.VMEM((n, 128), jnp.float32),
+            pltpu.VMEM((n, 128), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_mla_paged_kernel, scale=scale, bs=bs, pages=pages,
+                               width=M, kv_lora=kv_lora)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n, kv_lora), jnp.float32),
+        interpret=_device.pallas_interpret(),
+        name="pfx_decode_mla_paged",
+    )(layer[None], tables.astype(jnp.int32), positions.astype(jnp.int32),
+      q, *([pool] * pages))
+
+
+def _latent_write_kernel(layer_ref, blk_ref, off_ref, new_ref, page_ref, out_ref):
+    i = pl.program_id(0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, page_ref.shape, 1)
+    out_ref[...] = jnp.where(lane == off_ref[i], new_ref[0], page_ref[...])
+
+
+def latent_page_write(pool: jax.Array, new: jax.Array, blk: jax.Array, off: jax.Array,
+                      *, layer: jax.Array, impl: str = "auto") -> jax.Array:
+    """Write row i's new cached token ``new[i]`` [w] as column ``off[i]`` of
+    page ``blk[i]`` of layer ``layer`` of the latent arena ``pool``
+    [layers, num_blocks, 1, w, block], in place (the arena is the result).
+    The Pallas spelling (``pfx_mla_write``) reads and rewrites ONE page a
+    row; as an XLA scatter the same write made the compiler convert the
+    whole arena to a layout of the scatter's liking and back, every layer
+    of every decode step (compiled for the v5e: 2.4 GB each way)."""
+    layer = jnp.asarray(layer, jnp.int32)
+    new = new.astype(pool.dtype)
+    use_pallas = impl == "pallas" or (impl == "auto" and not _device.pallas_interpret())
+    if not use_pallas:
+        return pool.at[layer, blk, 0, :, off].set(new)
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, w = new.shape
+    bs = pool.shape[4]
+    page = pl.BlockSpec((None, None, None, w, bs),
+                        lambda i, layer_ref, blk_ref, off_ref: (layer_ref[0], blk_ref[i], 0, 0, 0))
+    return pl.pallas_call(
+        _latent_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec((1, w, 1), lambda i, *_: (i, 0, 0)), page],
+            out_specs=page),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={4: 0},
+        interpret=_device.pallas_interpret(),
+        name="pfx_mla_write",
+    )(layer[None], blk.astype(jnp.int32), off.astype(jnp.int32), new[:, :, None], pool)
+
+
+def mla_tokens_computed(positions, block: int, width: int):
+    """Latent tokens the MLA kernel computes on for rows whose query sits
+    at slot ``positions``: each context rounded up to whole grid steps."""
+    pages = mla_pages_per_step(block, width)
+    steps = -(-width // pages)
+    return ((positions.clip(0) // block) // pages + 1).clip(None, steps) * (pages * block)
+
+
+def mla_paged_decode_attention(
+    q: jax.Array,
+    pool: jax.Array,
+    block_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    layer: jax.Array,
+    scale: float,
+    kv_lora: int,
+    impl: str = "auto",
+) -> jax.Array:
+    """Latent attention's decode step in its ABSORBED form.  q [b, n, w]:
+    each head's query carried into the latent space (``W_uk^T q_nope``,
+    kv_lora wide) then its rotated part; ``pool`` the whole latent arena
+    [layers, num_blocks, 1, w, block], of which layer ``layer``'s pages are
+    read in place through each row's ``block_tables`` [b, M]; the query of
+    row i sits at slot ``positions[i]`` (already written) and attends its
+    slots [0, positions[i]].  Scores ``scale * q . page^T`` in float32,
+    online softmax, -> [b, n, kv_lora] float32: sum_j p_j c_j, which the
+    caller expands through ``W_uv``.  ``impl`` as
+    :func:`paged_decode_attention`; the Pallas spelling
+    (``pfx_decode_mla_paged``) runs one grid step per (row,
+    :func:`mla_pages_per_step` pages)."""
+    if impl not in ("auto", "pallas", "lax"):
+        raise ValueError(f"mla_paged_decode_attention impl {impl!r}; valid: auto, pallas, lax")
+    if pool.ndim != 5 or pool.shape[2] != 1 or pool.shape[3] != q.shape[-1]:
+        raise ValueError(f"latent pool {pool.shape} does not hold vectors of {q.shape[-1]}")
+    layer = jnp.asarray(layer, jnp.int32)
+    bs = pool.shape[4]
+    use_pallas = impl == "pallas" or (impl == "auto" and not _device.pallas_interpret())
+    if use_pallas and bs % 8:
+        raise ValueError(f"paged block size {bs} is not a multiple of 8; use impl='lax'")
+    fn = _mla_paged_pallas if use_pallas else _mla_paged_lax
+    return fn(q, pool, layer, block_tables, positions, float(scale), int(kv_lora))
+
+
 def dense_cache_attention(
     q: jax.Array,
     k_cache: jax.Array,

@@ -239,7 +239,8 @@ def _trace_done(trace_s: float) -> None:
 
 def capture_profile(seconds: float, log_dir: str, top: int = 20,
                     summary: bool = True,
-                    python_tracer: bool = True) -> Dict[str, Any]:
+                    python_tracer: bool = True,
+                    probe=None) -> Dict[str, Any]:
     """Capture a ``jax.profiler`` trace of the LIVE process for ``seconds``
     and answer with the parsed summary — the whole ``POST /admin/profile``
     body in one call.  Raises ``ValueError`` on a bad/over-cap duration
@@ -251,7 +252,10 @@ def capture_profile(seconds: float, log_dir: str, top: int = 20,
     the device/host split) and answers with ``trace_dir``, ``seconds`` and
     the start clocks only: for a caller that reduces the trace in another
     process and must not stall this one.  ``python_tracer=False``: see
-    :func:`start_trace`."""
+    :func:`start_trace`.  ``probe`` (a callable -> dict of counters) is read
+    once the trace has started and again just before it stops; the two
+    readings come back as ``counters_at_start`` / ``counters_at_stop``, so
+    that work and device time of one stretch can be divided."""
     cap = profile_max_seconds()
     try:
         seconds = float(seconds)
@@ -272,8 +276,13 @@ def capture_profile(seconds: float, log_dir: str, top: int = 20,
     try:
         t0 = time.monotonic()
         clocks = start_trace(log_dir, python_tracer=python_tracer)
+        counters = []
         try:
+            if probe is not None:
+                counters.append(probe())
             time.sleep(seconds)
+            if probe is not None:
+                counters.append(probe())
         finally:
             jax.profiler.stop_trace()
         trace_s = time.monotonic() - t0
@@ -285,6 +294,8 @@ def capture_profile(seconds: float, log_dir: str, top: int = 20,
             "started_time_ns": clocks["time_ns"],
             "python_tracer": bool(python_tracer),
         }
+        if len(counters) == 2:
+            out["counters_at_start"], out["counters_at_stop"] = counters
         if not summary:
             return out
         rows, source = op_summary_rows(log_dir)

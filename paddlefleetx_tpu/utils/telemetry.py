@@ -114,6 +114,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_spec_accepted_total": ("counter", "Draft tokens accepted and committed by the verify step"),
     "pfx_spec_accept_rate": ("gauge", "Lifetime accepted/proposed draft ratio"),
     "pfx_kv_bytes": ("gauge", "Live KV-cache payload bytes (used blocks x K+V bytes per block)"),
+    "pfx_kv_bytes_per_token": ("gauge", "Bytes one cached token takes over all layers, from the model: per-head K and V, or one latent"),
     # shared-prefix KV reuse + chunked prefill (core/paged_cache.py
     # PrefixIndex, core/continuous_batching.py)
     "pfx_prefix_hits_total": ("counter", "Admissions that reused cached prefix blocks"),
@@ -268,6 +269,9 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_moe_pairs_held_total": ("counter", "Routed pairs that landed on experts this process holds"),
     "pfx_moe_load_max_over_mean_sum": ("counter", "Sum over steps of the largest held expert's pairs over the held experts' mean (max over layers)"),
     "pfx_moe_bias_abs_max": ("gauge", "Largest absolute routing bias over experts and layers"),
+    "pfx_moe_serve_pairs_total": ("counter", "Serving: token-expert pairs the expert layers routed for live rows and real prompt tokens, over all experts and layers (prefills and decode steps)"),
+    "pfx_moe_serve_held_pairs_total": ("counter", "Serving: routed pairs that landed on experts this process holds"),
+    "pfx_moe_serve_held_max_pairs_total": ("counter", "Serving: the fullest held expert's pairs x experts held, summed over layers and dispatches (over pfx_moe_serve_held_pairs_total: max over mean)"),
     "pfx_token_ledger_total": ("counter", "Admitted-token dispositions (labels: disposition=admitted|delivered|evicted_lost|preempt_refunded|shed_after_admit)"),
     "pfx_token_ledger_in_flight": ("gauge", "Admitted tokens still on the books in live decode slots (the exact-closure remainder)"),
     "pfx_tenant_slot_seconds_total": ("counter", "Decode-slot occupancy in slot-seconds per tenant — billing-grade cost attribution (labels: tenant)"),

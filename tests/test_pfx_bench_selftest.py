@@ -44,3 +44,44 @@ def test_selftest_check(selftest, name, monkeypatch):
     else:
         getattr(selftest, name)()
     assert selftest.FAILS == []
+
+
+@pytest.mark.parametrize("mix,cell,pad,buckets", [
+    ("think-long-answers", "serve-dsv3-1of32-think", 512, [1024, 1536, 2048, 2560, 3072]),
+])
+def test_a_serving_cell_s_data_files(selftest, mix, cell, pad, buckets):
+    """A cell added after the self-test's own list of mixes: its traffic
+    through the generator (the same seed the same plan, another seed the
+    same sizes in another order, lengths inside the mix's bounds, the
+    prompt buckets the server warms), and its workload's readers and
+    runner found by name."""
+    common, loadgen = selftest.common, selftest.loadgen
+    t = common.load_traffic(mix)
+    vocab = common.load_cell(cell)["config_data"]["model"]["vocab_size"]
+    a = loadgen.build_plan(t, 3, 51.0, vocab)
+    b = loadgen.build_plan(t, 3_000_000_007, 51.0, vocab)
+    assert a == loadgen.build_plan(t, 3, 51.0, vocab)
+    win = [r for r in a["requests"] if r["phase"] == "window"]
+    assert len(win) == round(t["rate_rps"] * 51.0)
+    assert sorted(len(r["prompt_ids"]) for r in a["requests"]) == sorted(
+        len(r["prompt_ids"]) for r in b["requests"])
+    assert [r["prompt_ids"] for r in a["requests"]] != [r["prompt_ids"] for r in b["requests"]]
+    lo, hi = t["prompt_len"]["min"], t["prompt_len"]["max"]
+    assert all(lo <= len(r["prompt_ids"]) <= hi and 1 <= min(r["prompt_ids"])
+               and max(r["prompt_ids"]) < vocab for r in a["requests"])
+    assert all(t["max_tokens"]["min"] <= r["max_tokens"] <= t["max_tokens"]["max"]
+               for r in a["requests"])
+    assert loadgen.prompt_buckets(t, pad) == buckets
+    assert abs(t["rate_rps"] - 0.8 * t["knee_rps"]) < 1e-9 and t["knee_note"]
+    c = common.load_cell(cell)
+    assert os.path.isfile(os.path.join(selftest.BENCH, "runners", c["runner"] + ".py"))
+    assert os.path.isfile(os.path.join(selftest.BENCH, "runners", c["runner"] + "_child.py"))
+    assert os.path.isfile(os.path.join(selftest.BENCH, "runners", c["runner"] + ".md"))
+    for name in c["per_layer"]:
+        reader = common.load_layer_metric(name)["reader"]
+        assert os.path.isfile(os.path.join(selftest.BENCH, "readers", reader + ".py")), name
+    conf = c["config_data"]
+    for key in ("reference", "math", "yaml"):
+        assert os.path.isfile(os.path.join(selftest.ROOT, conf[key])), key
+    ctx = max(hi + t["max_tokens"]["max"], 0)
+    assert ctx <= conf["model"]["max_position_embeddings"]  # no request outgrows the context

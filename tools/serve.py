@@ -1004,6 +1004,12 @@ def serve_http(server, port: int, host: str = "127.0.0.1", *,
                     seconds, prof_dir, top=top,
                     summary=bool(req.get("summary", True)),
                     python_tracer=bool(req.get("python_tracer", True)),
+                    # the continuous engine's work counters at both ends
+                    # of the capture (numbers only: steps, rows, cached
+                    # tokens attended, expert pairs)
+                    probe=None if engine is None else lambda: {
+                        k: v for k, v in engine.stats.items()
+                        if isinstance(v, (int, float))},
                 )
             except ProfileBusy as e:
                 print(f"[serve] /admin/profile refused: {e}", flush=True)
