@@ -52,8 +52,7 @@ def init_dist_env(cfg, devices=None) -> jax.sharding.Mesh:
     mesh = build_mesh(mesh_cfg, devices)
     set_mesh(mesh)
     seed = int(cfg.get("Global", {}).get("seed", 1024))
-    # prng_impl "rbg" = hardware RNG (cheap TPU dropout); default threefry
-    init_seed(seed, impl=cfg.get("Global", {}).get("prng_impl", None))
+    init_seed(seed)
     logger.info(f"mesh axes {dict(mesh.shape)} over {mesh.size} devices; seed {seed}")
     return mesh
 

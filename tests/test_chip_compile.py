@@ -14,6 +14,7 @@ sees the CPU backend); the persistent compile cache is off around these
 compiles (a TPU executable cannot be read back without a chip).
 """
 
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -389,13 +390,33 @@ def _compile_train_step(topo, n_devices, overrides=(), yaml=None):
         return engine._train_step.lower(engine.state, batch).compile()
 
 
+@functools.lru_cache(maxsize=None)  # 15-60 s of compile, shared by the cases below
+def _step_345m(topo):
+    return _compile_train_step(topo, 1)
+
+
+@functools.lru_cache(maxsize=None)
+def _step_trinity(topo):
+    """The benchmark cell ``train-trinity-mini-1of8`` as its yaml and its
+    configuration file state it."""
+    import json
+
+    root = os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..")
+    bench = os.path.join(root, "pfx_bench")  # noqa: E10 — a directory, not a metric
+    with open(os.path.join(bench, "configs", "trinity-mini.json")) as f:
+        config = json.load(f)
+    return _compile_train_step(
+        topo, 1, [f"Model.{k}={v}" for k, v in config["model"].items()]
+        + ["Global.global_batch_size=2", "Global.local_batch_size=2",
+           "Global.micro_batch_size=2"], yaml=os.path.join(root, config["yaml"]))
+
+
 def test_documented_single_chip_config_fits_one_chip(topo):
     """README's first command — ``tools/train.py -c
     configs/gpt/pretrain_gpt_345M_single.yaml`` AS COMMITTED (batch 16,
     flash attention) — compiles for one 16 GB chip; a step that does not
     fit is refused by the compiler with RESOURCE_EXHAUSTED."""
-    c = _compile_train_step(topo, 1)
-    assert _has_kernel(c)
+    assert _has_kernel(_step_345m(topo))
 
 
 def test_trinity_mini_share_fits_one_chip(topo):
@@ -405,17 +426,7 @@ def test_trinity_mini_share_fits_one_chip(topo):
     131,072 rows a layer): the real train step compiles for one 16 GB chip
     (14.2 GiB of the 15.75 the compiler may use), with the flash kernels (window and full, 32/4 heads, whole
     8192-position K/V in VMEM) and the grouped products as Mosaic calls."""
-    import json
-
-    root = os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..")
-    bench = os.path.join(root, "pfx_bench")  # noqa: E10 — a directory, not a metric
-    with open(os.path.join(bench, "configs", "trinity-mini.json")) as f:
-        config = json.load(f)
-    yaml = os.path.join(root, config["yaml"])
-    c = _compile_train_step(
-        topo, 1, [f"Model.{k}={v}" for k, v in config["model"].items()]
-        + ["Global.global_batch_size=2", "Global.local_batch_size=2",
-           "Global.micro_batch_size=2"], yaml=yaml)
+    c = _step_trinity(topo)
     text = c.as_text()
     assert "pfx_flash_fwd" in text and "pfx_flash_bwd_dkv" in text  # noqa: E10 — kernel names
     assert "ragged-dot" in text  # XLA:TPU's grouped product, a Mosaic call too
@@ -423,6 +434,89 @@ def test_trinity_mini_share_fits_one_chip(topo):
     held = m.argument_size_in_bytes + m.temp_size_in_bytes + m.generated_code_size_in_bytes
     assert 8e9 < m.argument_size_in_bytes < 9e9  # 705.5 M x 12 bytes of state
     assert held < 15.5 * 2**30, held  # of the 15.75 GiB the compiler may use
+
+
+def _mask_draws(text):
+    """Where a compiled step draws activation-sized random words (2**20 and
+    more of u32): (``rng-bit-generator`` instructions, threefry rounds seen
+    as ``xor`` on such a tensor) inside the program's loop bodies, the
+    fusions nested in them included, and the same two counts over the
+    whole program."""
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    todo = list(set(re.findall(r"body=%?([\w.\-]+)", text)))
+    assert todo, "the step has no layer loop"
+    inside = set()
+    while todo:
+        name = todo.pop()
+        if name in inside or name not in comps:
+            continue
+        inside.add(name)
+        todo += re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                           "\n".join(comps[name]))
+
+    def count(lines):
+        rbg = xor = 0
+        for line in lines:
+            m = re.search(r"= u32\[([\d,]+)\]\S* (rng-bit-generator|xor)\(", line)
+            if m and np.prod([int(d) for d in m.group(1).split(",")]) >= 2**20:
+                rbg += m.group(2) == "rng-bit-generator"
+                xor += m.group(2) == "xor"
+        return int(rbg), int(xor)
+
+    return count([ln for name in inside for ln in comps[name]]), count(text.splitlines())
+
+
+def test_345m_step_draws_its_dropout_masks_with_the_bit_generator(topo):
+    """The committed 345M recipe (dropout 0.1, selective recompute): each
+    of the layer's two masks is ONE ``rng-bit-generator`` of
+    u32[16,1024,1024] in the forward loop body and one more in the
+    backward's (the rematerialised mask; the transposed ``select`` reuses
+    it), the embedding's a fifth outside the loops.  No threefry chain
+    (20 rounds of ``xor``; the scalar key splits keep theirs) runs over a
+    mask's words: with threefry keys XLA copied one into every consumer of
+    a mask, the epilogue of the MLP's ``fc_out`` product among eight
+    fusions of the layer loop, half of the step's device time (PERF.md
+    section 6, PR 32)."""
+    text = _step_345m(topo).as_text()
+    (rbg_in_loops, xor_in_loops), (rbg, xor) = _mask_draws(text)
+    assert (rbg_in_loops, rbg) == (4, 5)
+    assert (xor_in_loops, xor) == (0, 0)
+
+
+def test_trinity_step_draws_no_mask(topo):
+    """``hidden_dropout_prob`` 0.0: ``dropout()`` returns its input before
+    it touches the key, so the step holds no generator of either kind."""
+    text = _step_trinity(topo).as_text()
+    assert _mask_draws(text) == ((0, 0), (0, 0))
+    assert "rng-bit-generator" not in text
+
+
+def test_four_chip_step_with_dropout_takes_the_bit_generator(topo):
+    """dp 2 x mp 2 of the same recipe: the partitioner takes the
+    instruction.  It does NOT split it: every device draws the whole
+    batch's u32[16,1024,1024] and keeps its rows (so a mask is the same
+    tensor under every layout, and a chip of dp N draws N times its own
+    share: ROADMAP queue 1 item 2)."""
+    c = _compile_train_step(
+        topo, 4, ["Distributed.dp_degree=2", "Distributed.mp_degree=2",
+                  "Global.local_batch_size=8", "Global.micro_batch_size=8"])
+    text = c.as_text()
+    (rbg_in_loops, xor_in_loops), (rbg, xor) = _mask_draws(text)
+    assert (rbg_in_loops, rbg) == (4, 5)
+    assert (xor_in_loops, xor) == (0, 0)
+    import re
+
+    drawn = set(re.findall(r"= (u32\[[\d,]+\])\S* rng-bit-generator\(", text))
+    assert drawn == {"u32[16,1024,1024]"}, drawn  # the global batch, on every device
+    assert "all-reduce" in text and _has_kernel(c)
 
 
 @pytest.mark.slow  # 20-35 s of TPU compile each; run when a layout changes
