@@ -294,6 +294,12 @@ class PagedDecodeEngine:
                 f"multiple of the KV block size {self.block}"
             )
         self.prefill_chunk = int(prefill_chunk)
+        # what a row keeps per batch SLOT beside its pages (a state-space
+        # layer's recurrent state): () for a block whose every layer caches
+        # tokens.  It lives in the pools, is overwritten by the prefill that
+        # admits a row into the slot, and is carried and donated through
+        # every dispatch with the arena (docs/decode_path.md)
+        self.row_state = self.mcfg.row_state
         if not self.mcfg.classic_block:
             # the described block is served by prefill-on-admit and the
             # one-token decode step; what else the GPT-2 block's pools
@@ -308,7 +314,7 @@ class PagedDecodeEngine:
             for option, asked in refused.items():
                 if asked:
                     raise ValueError(
-                        f"{option} is not written for latent pools and expert layers yet; "
+                        f"{option} is not written for {self._unwritten_for()} yet; "
                         "serve this block without it")
         # host-RAM spill tier (docs/serving.md "KV lifecycle"): evicted
         # prefix blocks demote to a bounded host store and readmit on a
@@ -385,6 +391,10 @@ class PagedDecodeEngine:
             # pairs routed, pairs on held experts, the fullest held
             # expert's pairs x experts held (generation._moe_counts)
             "moe_pairs": 0, "moe_held_pairs": 0, "moe_held_max_pairs": 0,
+            # state-space layers (warm-up excluded): live (row, step)
+            # pairs x layers, slots x steps x layers (what the state
+            # kernel walked: it runs every slot), prompt tokens x layers
+            "ssm_row_steps": 0, "ssm_slot_steps": 0, "ssm_prefill_tokens": 0,
         }
         # True only inside warmup(): warmup admits/steps are not traffic
         # and must not bump the traffic-facing registry counters (the
@@ -421,6 +431,7 @@ class PagedDecodeEngine:
                 init_paged_pools(
                     self.mcfg, self.cache.allocator.num_blocks, self.block,
                     kv_dtype=self.kv_dtype,
+                    slots=B,  # a block with row state keeps it per batch slot
                 ),
                 jnp.zeros((B, vocab), jnp.float32),
                 jnp.zeros((B, vocab), jnp.int32),
@@ -428,6 +439,7 @@ class PagedDecodeEngine:
             ),
             self.mesh,
         )
+        self._pool_fields = self.pools.fields()
 
     # -- capacity queries ----------------------------------------------
     def row_capacity_tokens(self, prompt_len: int, max_new: int) -> int:
@@ -461,12 +473,30 @@ class PagedDecodeEngine:
         per_layer = sum(heads * width for heads, width in self.mcfg.cached_token)
         return int(k.shape[0]) * per_layer * k.dtype.itemsize
 
+    def _unwritten_for(self) -> str:
+        if self.row_state:
+            return ("a block with row state (state-space layers keep a recurrent state a "
+                    "slot, which pages, prefix blocks and handoff payloads do not carry), "
+                    "shared KV heads and expert layers")
+        return "latent pools and expert layers"
+
     def _classic_only(self, what: str) -> None:
         """Refuse, by name, what only the GPT-2 block's pools can do."""
         if not self.mcfg.classic_block:
             raise ValueError(
-                f"{what} is not written for latent pools and expert layers yet "
+                f"{what} is not written for {self._unwritten_for()} yet "
                 "(docs/serving.md \"What is refused\")")
+
+    def state_bytes_per_row(self) -> int:
+        """Bytes a row keeps beside its pages, whatever its length, over
+        all state-space layers (0 for a block without row state)."""
+        # from the shapes alone: the collector's thread reads this while a
+        # dispatch may hold the (donated) arrays
+        total = 0
+        for name, _, _ in self.row_state:
+            arr = getattr(self.pools, name)  # [layers, slots, ...]
+            total += int(arr.shape[0]) * int(np.prod(arr.shape[2:])) * arr.dtype.itemsize
+        return total
 
     def _count_moe(self, counts, fetch: bool = True) -> None:
         """Fold one dispatch's expert-layer counts into stats.  A prefill's
@@ -485,6 +515,13 @@ class PagedDecodeEngine:
 
     def _pools_tuple(self):
         return tuple(x for x in self.pools if x is not None)
+
+    def _pools_of(self, pools_t):
+        """The pools a compiled entry point took or gave back as their
+        arrays alone (:meth:`_pools_tuple`)."""
+        from paddlefleetx_tpu.models.gpt.generation import PagedPools
+
+        return PagedPools.of(self._pool_fields, pools_t)
 
     def free_slots(self) -> int:
         return sum(1 for r in self.slots if r is None)
@@ -518,15 +555,16 @@ class PagedDecodeEngine:
         fn = self._compiled_prefill.get(key)
         if fn is None:
             from paddlefleetx_tpu.models.gpt.generation import (
-                PagedPools,
                 paged_prefill,
             )
 
-            def traced(p, prompt, plen, pools_t, table_row):
+            def traced(p, prompt, plen, pools_t, table_row, *slot):
                 self.stats["traces"] += 1
                 pools, last, counts, moe = paged_prefill(
-                    p, prompt, plen, PagedPools(*pools_t), table_row,
+                    p, prompt, plen, self._pools_of(pools_t), table_row,
                     self.mcfg, ctx=self.ctx, return_moe=True,
+                    # a block with row state prefills INTO its batch slot
+                    **({"slot": slot[0]} if slot else {}),
                 )
                 out = tuple(x for x in pools if x is not None)
                 # a block with expert layers also hands back their counts
@@ -542,7 +580,6 @@ class PagedDecodeEngine:
         fn = self._compiled_step.get(key)
         if fn is None:
             from paddlefleetx_tpu.models.gpt.generation import (
-                PagedPools,
                 PagedRows,
                 decode_step,
                 decode_step_spec,
@@ -558,7 +595,7 @@ class PagedDecodeEngine:
                     rows = PagedRows(logits, counts, positions, gen_steps,
                                      max_news, active, forced_steps, reject)
                     window, ncommit, pools, rows2 = decode_step_spec(
-                        p, PagedPools(*pools_t), tables, rows, drafts,
+                        p, self._pools_of(pools_t), tables, rows, drafts,
                         self.mcfg, self._gen_key, key=rng, ctx=self.ctx,
                     )
                     rej2 = rows2.reject
@@ -566,7 +603,7 @@ class PagedDecodeEngine:
                     rows = PagedRows(logits, counts, positions, gen_steps,
                                      max_news, active, forced_steps)
                     nxt, pools, rows2 = decode_step(
-                        p, PagedPools(*pools_t), tables, rows, self.mcfg,
+                        p, self._pools_of(pools_t), tables, rows, self.mcfg,
                         self._gen_key, key=rng, ctx=self.ctx,
                     )
                     window = nxt[:, None]
@@ -590,7 +627,6 @@ class PagedDecodeEngine:
         fn = self._compiled_chunk.get(key)
         if fn is None:
             from paddlefleetx_tpu.models.gpt.generation import (
-                PagedPools,
                 paged_chunk_prefill,
             )
 
@@ -598,7 +634,7 @@ class PagedDecodeEngine:
                        last_idx):
                 self.stats["traces"] += 1
                 pools, last = paged_chunk_prefill(
-                    p, tokens, PagedPools(*pools_t), table, position,
+                    p, tokens, self._pools_of(pools_t), table, position,
                     n_valid, last_idx, self.mcfg, ctx=self.ctx,
                 )
                 return tuple(x for x in pools if x is not None), last
@@ -615,11 +651,9 @@ class PagedDecodeEngine:
         compile, ever."""
         fn = self._compiled_copy
         if fn is None:
-            from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
             def traced(pools_t, src, dst):
                 self.stats["traces"] += 1
-                pools = PagedPools(*pools_t)
+                pools = self._pools_of(pools_t)
                 out = tuple(
                     x.at[:, dst].set(x[:, src])
                     for x in pools if x is not None
@@ -734,9 +768,7 @@ class PagedDecodeEngine:
                     # but this orphan allocation is ours to return
                     self.cache.allocator.free(fresh)
                     raise
-                from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-                self.pools = PagedPools(*pools_t)
+                self.pools = self._pools_of(pools_t)
                 self.cache.prefix.insert_block(key, fresh[0])
                 spill.pop(key)  # back on device; counted as a readmit
                 readmitted += 1
@@ -809,9 +841,7 @@ class PagedDecodeEngine:
                 ),
                 f"{label} COW copy", release_seq=seq_id,
             )
-            from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-            self.pools = PagedPools(*pools_t)
+            self.pools = self._pools_of(pools_t)
         return seq_id, table, shared, cow, m
 
     def _cache_admit(self, seq_id: int, tokens: int,
@@ -926,6 +956,7 @@ class PagedDecodeEngine:
                     jnp.int32(plen),
                     self._pools_tuple(),
                     jnp.asarray(prefill_table, jnp.int32),
+                    *((jnp.int32(slot),) if self.row_state else ()),
                 ),
                 "prefill", release_seq=seq_id,
                 slot=slot, prompt_len=plen, bucket=P,
@@ -933,9 +964,7 @@ class PagedDecodeEngine:
                 # /debug/traces timeline and the profiler's host plane
                 **({"trace_id": trace.trace_id} if trace is not None else {}),
             )
-            from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-            self.pools = PagedPools(*pools_t)
+            self.pools = self._pools_of(pools_t)
             self._count_moe(moe[0] if moe else None, fetch=False)
             self._logits = self._logits.at[slot].set(last)
             self._counts = self._counts.at[slot].set(counts)
@@ -961,6 +990,8 @@ class PagedDecodeEngine:
             )
             self.stats["prefills"] += 1
             self.stats["prefill_tokens"] += plen
+            if self.row_state and not self._warmup:
+                self.stats["ssm_prefill_tokens"] += plen * int(self.mcfg.ssm_layers)
             return slot
 
         # prefix-hit / chunked path: only the unmatched suffix
@@ -1028,9 +1059,7 @@ class PagedDecodeEngine:
             label, release_seq=release_seq,
             position=pos, prompt_len=take, bucket=chunk,
         )
-        from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-        self.pools = PagedPools(*pools_t)
+        self.pools = self._pools_of(pools_t)
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_tokens"] += take
         if not self._warmup:
@@ -1151,9 +1180,7 @@ class PagedDecodeEngine:
                 ),
                 "prefill export", release_seq=seq_id,
             )
-            from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-            self.pools = PagedPools(*pools_t)
+            self.pools = self._pools_of(pools_t)
             self.stats["prefill_tokens"] += plen
             counts = np.asarray(counts, np.int32)
         else:
@@ -1224,7 +1251,6 @@ class PagedDecodeEngine:
         fn = self._compiled_adopt.get(key)
         if fn is None:
             from paddlefleetx_tpu.models.gpt.generation import (
-                PagedPools,
                 scatter_kv_blocks,
             )
 
@@ -1233,7 +1259,7 @@ class PagedDecodeEngine:
             def traced(pools_t, idx, blocks_t):
                 self.stats["traces"] += 1
                 pools = scatter_kv_blocks(
-                    PagedPools(*pools_t), idx, dict(zip(names, blocks_t))
+                    self._pools_of(pools_t), idx, dict(zip(names, blocks_t))
                 )
                 return tuple(x for x in pools if x is not None)
 
@@ -1304,9 +1330,7 @@ class PagedDecodeEngine:
             ),
             "handoff adopt", release_seq=seq_id,
         )
-        from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-        self.pools = PagedPools(*pools_t)
+        self.pools = self._pools_of(pools_t)
         self._logits = self._logits.at[slot].set(
             jnp.asarray(arrays["logits"], jnp.float32)
         )
@@ -1465,9 +1489,7 @@ class PagedDecodeEngine:
             except ArenaReset:
                 self.cache.allocator.free(fresh)  # orphan: ours to return
                 raise
-            from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-            self.pools = PagedPools(*pools_t)
+            self.pools = self._pools_of(pools_t)
             self.cache.prefix.insert_block(path, fresh[0])
             adopted += 1
         if adopted:
@@ -1644,9 +1666,7 @@ class PagedDecodeEngine:
                     "arena reset",
                     dead,
                 ) from exc
-        from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-        self.pools = PagedPools(*pools_t)
+        self.pools = self._pools_of(pools_t)
         self._logits, self._counts = logits, counts
         self._reject = reject
         return {
@@ -1716,12 +1736,16 @@ class PagedDecodeEngine:
         self.stats["row_steps"] += n_act
         self.stats["slot_steps"] += self.capacity
         self.stats["kv_tokens"] += int(self.positions[was_active].sum())
+        if self.row_state and not self._warmup:
+            self.stats["ssm_row_steps"] += n_act * int(self.mcfg.ssm_layers)
+            self.stats["ssm_slot_steps"] += self.capacity * int(self.mcfg.ssm_layers)
         if self.mcfg.latent_attention:
             self.stats["grid_tokens"] += int(mla_tokens_computed(
                 positions - ncommit, self.block, fl["width_bucket"]).sum())
         else:
             self.stats["grid_tokens"] += int(paged_tokens_computed(
-                positions - ncommit, fl["k"] + 1, self.block, fl["width_bucket"]
+                positions - ncommit, fl["k"] + 1, self.block, fl["width_bucket"],
+                self.mcfg.num_attention_heads // self.mcfg.kv_heads,
             ).sum())
         t_chunk = time.monotonic()
         for i, r in enumerate(fl["rows"]):
@@ -1917,9 +1941,7 @@ class PagedDecodeEngine:
             ),
             "COW copy warmup",
         )
-        from paddlefleetx_tpu.models.gpt.generation import PagedPools
-
-        self.pools = PagedPools(*pools_t)
+        self.pools = self._pools_of(pools_t)
 
     def _warm_chunk_family(self, n: int,
                            capacity_tokens: Optional[int] = None) -> None:
@@ -1942,10 +1964,7 @@ class PagedDecodeEngine:
         (the decode budget is the decode replica's to hold), so a
         prefill replica warms a narrower width than a decode-capacity
         row would."""
-        from paddlefleetx_tpu.models.gpt.generation import (
-            PagedPools,
-            bucket_len,
-        )
+        from paddlefleetx_tpu.models.gpt.generation import bucket_len
 
         jnp = self._jnp
         blocks = blocks_for(
@@ -1968,7 +1987,7 @@ class PagedDecodeEngine:
                 ),
                 "chunk warmup",
             )
-            self.pools = PagedPools(*pools_t)
+            self.pools = self._pools_of(pools_t)
 
     def warmup(self, prompt_lens: Sequence[int]) -> Dict[str, float]:
         """Compile (prefill, step) for each prompt bucket at the default
@@ -2213,6 +2232,10 @@ class ContinuousScheduler:
             # the model (per-head K and V, or one latent), and the arena's
             # rows and --kv-blocks auto follow it
             ("pfx_kv_bytes_per_token", {}, float(eng.kv_bytes_per_token())),
+            # what a row keeps beside its pages whatever its length (a
+            # state-space layer's recurrent state and conv columns; 0 for
+            # a block whose every layer caches tokens)
+            ("pfx_state_bytes_per_row", {}, float(eng.state_bytes_per_row())),
             ("pfx_prefix_cached_blocks", {},
              float(cstats["prefix_cached_blocks"])),
             # host-RAM spill tier occupancy (0 when --prefix-spill-bytes
@@ -2257,6 +2280,13 @@ class ContinuousScheduler:
             ("grid_tokens", "pfx_sched_decode_grid_tokens_total"),
         ):
             out.append((name, {}, float(eng.stats[key])))
+        if eng.row_state:
+            for key, name in (
+                ("ssm_row_steps", "pfx_ssm_row_steps_total"),
+                ("ssm_slot_steps", "pfx_ssm_slot_steps_total"),
+                ("ssm_prefill_tokens", "pfx_ssm_prefill_tokens_total"),
+            ):
+                out.append((name, {}, float(eng.stats[key])))
         if eng.mcfg.num_experts > 1:
             for key, name in (
                 ("moe_pairs", "pfx_moe_serve_pairs_total"),

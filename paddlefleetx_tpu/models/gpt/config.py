@@ -78,12 +78,15 @@ class GPTConfig:
     # mlp_act and tie_embeddings move together until a family needs them
     # apart: all at their defaults, or rmsnorm + rope + no bias + swiglu +
     # untied head (the described block, whose other options are each held
-    # to the reference in tests/test_trinity_block.py).
+    # to the reference in tests/test_trinity_block.py), or, with a
+    # ``layer_pattern``, rmsnorm + none + no bias + relu2 + untied head.
     norm: str = "layernorm"  # layernorm | rmsnorm (learned scale, no bias)
     norm_eps: float = 1e-5
     # norms on each sub-block's OUTPUT too, before the residual add
     post_norms: bool = False
-    position: str = "learned"  # learned | rope (rotate-half over all head dims)
+    # learned | rope (rotate-half over all head dims) | none (a
+    # layer_pattern block: its state-space layers carry the order)
+    position: str = "learned"
     rope_theta: float = 10000.0
     # query heads stay num_attention_heads; 0 = as many KV heads (MHA)
     num_kv_heads: int = 0
@@ -92,7 +95,8 @@ class GPTConfig:
     qk_norm: bool = False  # per-head RMSNorm of q and k, one scale per layer
     attn_gate: bool = False  # sigmoid output gate on the attention result
     use_bias: bool = True  # biases on the projections and the MLP
-    mlp_act: str = "gelu"  # gelu (tanh) | swiglu
+    # gelu (tanh) | swiglu | relu2 (non-gated: W_down relu(W_up m)^2)
+    mlp_act: str = "gelu"
     tie_embeddings: bool = True
     embed_scale_sqrt_hidden: bool = False  # x0 = E[tokens] * sqrt(hidden)
     # attention window (0 = none) on every layer but each
@@ -147,24 +151,57 @@ class GPTConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
 
+    # one sub-block a layer (docs/nemotron_h.md): a character a layer,
+    # ``M`` a Mamba-2 mixer, ``*`` grouped-query attention without
+    # rotation, ``E`` the dropless expert feed-forward, ``-`` a dense
+    # relu2 feed-forward; every layer is x + mixer(RMSNorm(x)).  "" = the
+    # two-sub-block layers above.  ``num_layers`` is the pattern's length.
+    layer_pattern: str = ""
+    # the Mamba-2 mixer: ssm_heads heads of ssm_head_dim (d_inner is their
+    # product, not a multiple of hidden_size), a state of ssm_state a head
+    # dim, B and C shared by the heads of each of ssm_groups groups, a
+    # causal depthwise conv of ssm_conv taps over x, B and C, the prefill's
+    # chunk; dt's bias is the inverse softplus of a log-uniform draw in
+    # [ssm_dt_min, ssm_dt_max] floored at ssm_dt_floor, A = -U(1, 16)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    # out-projections of every mixer drawn at initializer_range /
+    # sqrt(num_layers) (the published ``rescale_prenorm_residual``)
+    rescale_prenorm_residual: bool = False
+
     def __post_init__(self):
         if self.ffn_hidden_size is None:
             object.__setattr__(self, "ffn_hidden_size", 4 * self.hidden_size)
         for field in ("norm_eps", "rope_theta", "moe_route_scale", "moe_bias_update_rate",
                       "moe_bias_warm_start_rate", "rope_scaling_factor", "rope_beta_fast",
-                      "rope_beta_slow", "rope_mscale", "rope_mscale_all_dim"):
+                      "rope_beta_slow", "rope_mscale", "rope_mscale_all_dim",
+                      "ssm_dt_min", "ssm_dt_max", "ssm_dt_floor"):
             # YAML reads "1e-05" (an override's spelling of a float) as a string
             object.__setattr__(self, field, float(getattr(self, field)))
         if not self.attn_head_dim and self.hidden_size % self.num_attention_heads:
             raise ValueError("num_attention_heads must divide hidden_size")
         if self.num_attention_heads % (self.num_kv_heads or self.num_attention_heads):
             raise ValueError("num_kv_heads must divide num_attention_heads")
+        if self.layer_pattern:
+            self._check_layer_pattern()
+        elif self.position == "none" or self.mlp_act == "relu2":
+            raise ValueError("position: none and mlp_act: relu2 belong to a layer_pattern block")
         if not self.classic_block:
+            words = ("rmsnorm", "none", False, "relu2", False) if self.layer_pattern else (
+                "rmsnorm", "rope", False, "swiglu", False)
             if (self.norm, self.position, self.use_bias, self.mlp_act,
-                    self.tie_embeddings) != ("rmsnorm", "rope", False, "swiglu", False):
+                    self.tie_embeddings) != words:
                 raise ValueError(
                     "a block other than the GPT-2 one is norm: rmsnorm, position: rope, "
-                    "use_bias: False, mlp_act: swiglu, tie_embeddings: False together")
+                    "use_bias: False, mlp_act: swiglu, tie_embeddings: False together "
+                    "(with a layer_pattern: position: none, mlp_act: relu2)")
             if self.hidden_dropout_prob or self.attention_probs_dropout_prob:
                 raise ValueError("only the GPT-2 block has dropout; set both "
                                  "dropout probabilities to 0")
@@ -227,6 +264,28 @@ class GPTConfig:
             )
         object.__setattr__(self, "recompute_names", ",".join(names))
 
+    def _check_layer_pattern(self) -> None:
+        pattern = self.layer_pattern
+        if set(pattern) - set("M*E-") or len(pattern) != self.num_layers:
+            raise ValueError(
+                f"layer_pattern {pattern!r}: one of M (Mamba-2), * (attention), E (experts), "
+                f"- (dense) for each of the {self.num_layers} layers")
+        if "M" in pattern:
+            if not (self.ssm_heads and self.ssm_head_dim and self.ssm_state
+                    and self.ssm_conv >= 2 and self.ssm_chunk >= 1):
+                raise ValueError("an M layer needs ssm_heads, ssm_head_dim, ssm_state, "
+                                 "ssm_conv >= 2 and ssm_chunk")
+            if self.ssm_heads % self.ssm_groups or (
+                    self.ssm_heads * self.ssm_head_dim) % self.ssm_groups:
+                raise ValueError("ssm_groups must divide ssm_heads")
+        if "E" in pattern and not self.moe_dropless:
+            raise ValueError("an E layer needs num_experts and moe_gate: sigmoid")
+        for option in ("qk_norm", "attn_gate", "post_norms", "sliding_window",
+                       "global_attn_every", "num_dense_layers", "kv_lora_rank",
+                       "embed_scale_sqrt_hidden"):
+            if getattr(self, option):
+                raise ValueError(f"a layer_pattern block does not take {option}")
+
     @property
     def head_dim(self) -> int:
         return self.attn_head_dim or self.hidden_size // self.num_attention_heads
@@ -241,20 +300,59 @@ class GPTConfig:
 
     @property
     def cached_token(self) -> Tuple[Tuple[int, int], ...]:
-        """What a cache holds of one token in one layer: a (heads, width)
-        pair for each pool.  Per-head keys and values are two pools; the
-        latent and its rotated key are one, of one "head"."""
+        """What a cache holds of one token in one layer THAT CACHES TOKENS
+        (:attr:`kv_layers` of them): a (heads, width) pair for each pool.
+        Per-head keys and values are two pools; the latent and its rotated
+        key are one, of one "head"."""
         if self.latent_attention:
             return ((1, self.kv_lora_rank + self.qk_rope_head_dim),)
         return ((self.kv_heads, self.head_dim),) * 2
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers whose cache is pages of tokens: all of them, or a
+        layer_pattern's attention layers."""
+        return self.layer_pattern.count("*") if self.layer_pattern else self.num_layers
+
+    @property
+    def ssm_layers(self) -> int:
+        return self.layer_pattern.count("M")
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Width of what the conv sees: x, then B and C of every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def row_state(self) -> Tuple[Tuple[str, Tuple[int, ...], str], ...]:
+        """What a ROW keeps beside its pages, whatever its length, in each
+        of the :attr:`ssm_layers` state-space layers: (name, shape, dtype)
+        of the recurrent state ``S`` [heads, head_dim, state] and of the
+        last ``ssm_conv - 1`` columns the conv saw.  The state is float32
+        whatever ``dtype`` is (rounded to bfloat16 at every step it loses
+        what a slowly decaying head adds; docs/nemotron_h.md), the columns
+        are activations.  () for a block whose every layer caches tokens."""
+        if not self.ssm_layers:
+            return ()
+        return (("ssm", (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+                 "float32"),
+                ("conv", (self.ssm_conv - 1, self.ssm_conv_dim), self.dtype))
 
     @property
     def kv_block_default(self) -> int:
         """Tokens a page of the paged arena holds unless the operator says
         otherwise (0 = the library's default): a latent page holds one
         vector a token, so 128 of them make the page of a DMA's size that
-        16 tokens of per-head keys make."""
-        return 128 if self.latent_attention else 0
+        16 tokens of per-head keys make; so do 128 tokens of a few shared
+        KV heads."""
+        if self.latent_attention or (self.layer_pattern and self.kv_heads * 8 <=
+                                     self.num_attention_heads):
+            return 128
+        return 0
 
     @property
     def rope_yarn_m(self) -> float:
@@ -288,12 +386,15 @@ class GPTConfig:
                 and self.mlp_act == "gelu" and self.tie_embeddings
                 and not self.post_norms and not self.embed_scale_sqrt_hidden
                 and not self.sliding_window and not self.num_dense_layers
-                and not self.moe_dropless and not self.kv_lora_rank)
+                and not self.moe_dropless and not self.kv_lora_rank
+                and not self.layer_pattern)
 
     def layer_kind(self, layer: int) -> Tuple[int, bool]:
         """(window or 0, rotate q and k) of layer ``layer``, counted from 0
         over the whole stack, leading dense layers included."""
         is_global = self.global_attn_every > 0 and (layer + 1) % self.global_attn_every == 0
+        if self.layer_pattern and self.layer_pattern[layer] != "*":
+            raise ValueError(f"layer {layer} of {self.layer_pattern!r} is no attention layer")
         return (0 if is_global else self.sliding_window,
                 self.position == "rope" and not is_global)
 

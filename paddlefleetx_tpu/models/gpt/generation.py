@@ -106,7 +106,12 @@ COMPUTE_DTYPE_LEAVES = {
     "embeddings": ("word", "position"),
     "attn": ("qkv_kernel", "qkv_bias", "out_kernel", "out_bias",
              # latent attention (the norms' scales stay float32)
-             "q_a_kernel", "q_b_kernel", "kv_a_kernel", "k_b_kernel", "v_b_kernel"),
+             "q_a_kernel", "q_b_kernel", "kv_a_kernel", "k_b_kernel", "v_b_kernel",
+             # a layer_pattern block's grouped-query attention
+             "q_kernel", "k_kernel", "v_kernel"),
+    # a state-space mixer: its conv's bias, dt's bias, A, D and the gated
+    # norm's scale stay float32
+    "ssm": ("in_kernel", "conv_kernel", "out_kernel"),
     "mlp": ("fc_in_kernel", "fc_in_bias", "fc_out_kernel", "fc_out_bias",
             "w1", "w3", "w2"),
     # an expert layer: the router's kernel and its correction bias stay
@@ -143,7 +148,8 @@ def serving_params(params: Dict[str, Any], cfg: GPTConfig) -> Dict[str, Any]:
     The described block's tree comes back with its layers UNSTACKED
     (:func:`unstack_layers`): ``blocks``, a tuple of one dict a layer."""
     dtype = jnp.dtype(cfg.dtype)
-    if dtype == jnp.float32 or "blocks" in params:
+    # a layer_pattern tree is born with ``blocks``; any other has them once served
+    if dtype == jnp.float32 or ("blocks" in params and not cfg.layer_pattern):
         return params if cfg.classic_block else unstack_layers(params, cfg)
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     del params
@@ -170,7 +176,7 @@ def unstack_layers(params: Dict[str, Any], cfg: GPTConfig) -> Dict[str, Any]:
     (float32 zeros unless the tree brings one): what a served checkpoint
     carries trained."""
     if "blocks" in params:
-        return params
+        return _with_routing_bias(params, cfg)  # a layer_pattern tree is born unstacked
     stacks = [params[k] for k in ("dense_layers", "layers") if k in params]
     blocks = []
     for stack in stacks:
@@ -219,6 +225,9 @@ def init_serving_params(cfg: GPTConfig, key: jax.Array, shardings=None) -> Dict[
             [make(path, spec, k, place) for (path, spec), k, place in zip(flat, keys, places)])
     if shardings is not None:
         raise ValueError("tensor parallelism: the described block is served on one device")
+    if cfg.layer_pattern:  # its specs are unstacked already
+        return _with_routing_bias(treedef.unflatten(
+            [make(path, spec, k) for (path, spec), k in zip(flat, keys)]), cfg)
     # the stacked leaf of n layers draws layer l from split(leaf key, n)[l]
     n_dense = cfg.leading_dense_layers
     stacks = {"dense_layers": (n_dense, _block_layer_specs(cfg, False), 0),
@@ -256,13 +265,17 @@ def _with_routing_bias(params, cfg: GPTConfig):
 
 def check_servable(cfg: GPTConfig) -> None:
     """Which blocks the serving forwards know (docs/serving.md "What a block
-    must provide"): the GPT-2 block, and the described block with latent
-    attention (a dense SwiGLU or a dropless expert MLP).  Anything else
-    raises, naming the option that is in the way."""
+    must provide"): the GPT-2 block, the described block with latent
+    attention (a dense SwiGLU or a dropless expert MLP), and a
+    ``layer_pattern`` block (state-space, grouped-query attention and
+    expert layers, one sub-block a layer).  Anything else raises, naming
+    the option that is in the way."""
     if cfg.classic_block:
         if cfg.num_experts > 1:
             raise ValueError("serving knows no capacity-factor expert layer (num_experts)")
         return
+    if cfg.layer_pattern:
+        return  # GPTConfig refuses what such a block does not take
     for option in ("num_kv_heads", "attn_head_dim", "qk_norm", "attn_gate", "post_norms",
                    "sliding_window", "global_attn_every", "embed_scale_sqrt_hidden"):
         if getattr(cfg, option):
@@ -970,20 +983,60 @@ class PagedPools(NamedTuple):
     pool [layers, num_blocks, 1, kv_lora + rope, block] (a token is one
     COLUMN of its page: the normalised latent, then the rotated shared
     key; tokens minor, ``ops/decode_attention.py`` says why) and there is
-    no ``v``: the values are the first kv_lora entries of each column."""
+    no ``v``: the values are the first kv_lora entries of each column.
+
+    A ``layer_pattern`` block's pools hold pages for its ATTENTION layers
+    only ([attention layers, num_blocks, kv_heads, block, head_dim]) and,
+    beside them, what a row keeps in its state-space layers whatever its
+    length (``GPTConfig.row_state``), per batch SLOT and not per page:
+    ``ssm`` [state-space layers, slots, R, state, W] (the recurrent state,
+    packed as ``ops/ssm.py`` says) and ``conv`` [state-space layers, slots,
+    (taps - 1) * conv_dim] (the last columns the conv saw, oldest first).
+    They ride every dispatch with the arena, under the same contract."""
 
     k: jax.Array
     v: Optional[jax.Array] = None
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
+
+    def fields(self) -> Tuple[str, ...]:
+        """Names of the arrays these pools hold, in order."""
+        return tuple(n for n, x in zip(self._fields, self) if x is not None)
+
+    @classmethod
+    def of(cls, fields: Tuple[str, ...], leaves) -> "PagedPools":
+        """Pools from their arrays alone (``tuple(x for x in pools if x is
+        not None)``: what a compiled entry point takes and gives back)."""
+        return cls(**dict(zip(fields, leaves)))
 
 
 def init_paged_pools(
     cfg: GPTConfig, num_blocks: int, block: int, dtype=None,
-    kv_dtype: str = "",
+    kv_dtype: str = "", slots: int = 0,
 ) -> PagedPools:
+    """``slots`` (a block with ``row_state`` only): the batch's capacity."""
     check_servable(cfg)
     quant = kv_cache_dtype(kv_dtype) == "int8"
+    if cfg.layer_pattern:
+        from paddlefleetx_tpu.ops.ssm import packed_shape
+
+        if quant:
+            raise ValueError("kv_dtype int8: pools of shared KV heads are not quantized yet")
+        dtype = dtype or jnp.dtype(cfg.dtype)
+        (heads, width), _ = cfg.cached_token
+        shape = (cfg.kv_layers, num_blocks, heads, block, width)
+        pools = PagedPools(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        if cfg.row_state:
+            if slots < 1:
+                raise ValueError("a block with row state needs its batch slots")
+            (_, ssm, ssm_dtype), (_, conv, conv_dtype) = cfg.row_state
+            lead = (cfg.ssm_layers, slots)
+            pools = pools._replace(
+                ssm=jnp.zeros(lead + packed_shape(*ssm), jnp.dtype(ssm_dtype)),
+                conv=jnp.zeros(lead + (conv[0] * conv[1],), jnp.dtype(conv_dtype)))
+        return pools
     if cfg.latent_attention:
         if quant:
             raise ValueError("kv_dtype int8: latent pools are not quantized yet")
@@ -1057,17 +1110,27 @@ def _moe_counts(stats) -> jax.Array:
     return total
 
 
+def _step_write_slots(block_tables, positions, active, bs: int):
+    """Where a one-token step writes each row's token: (its position, or 0
+    for an inactive row; the pool block, the null block for an inactive
+    row; the slot inside the block), each [B]."""
+    pos = jnp.where(active, positions, 0)
+    blk_log = jnp.clip(pos // bs, 0, block_tables.shape[1] - 1)
+    blk = jnp.take_along_axis(block_tables, blk_log[:, None], axis=1)[:, 0]
+    return pos, jnp.where(active, blk, 0), pos % bs
+
+
 def _block_mlp(p, m, cfg: GPTConfig, valid):
     """The block's feed-forward over m [b, t, h] -> (result, the expert
     layer's load statistics or None).  An expert layer is one whose
     parameters hold a router.  A decode step (t == 1: a token a row) runs
     every held expert on every row; a prefill sorts its pairs, as training
     does at every size."""
-    from paddlefleetx_tpu.models.gpt.moe import dropless_moe_block, swiglu
+    from paddlefleetx_tpu.models.gpt.moe import dropless_moe_block, feed_forward
 
     dtype = m.dtype
     if "router_kernel" not in p:
-        return swiglu(m, _in_dtype("mlp", p, dtype)), None
+        return feed_forward(m, _in_dtype("mlp", p, dtype)), None
     q = dict(p, experts=_in_dtype("experts", p["experts"], dtype))
     if "shared" in p:
         q["shared"] = _in_dtype("shared", p["shared"], dtype)
@@ -1128,12 +1191,7 @@ def _block_paged_forward_step(params, tokens, pools, block_tables, positions, ac
     dtype = jnp.dtype(cfg.dtype)
     word = _in_dtype("embeddings", params["embeddings"], dtype)["word"]
     x = word[tokens][:, None]  # [B, 1, h]
-    bs = pools.k.shape[4]
-    pos = jnp.where(active, positions, 0)
-    blk_log = jnp.clip(pos // bs, 0, block_tables.shape[1] - 1)
-    blk = jnp.take_along_axis(block_tables, blk_log[:, None], axis=1)[:, 0]
-    blk = jnp.where(active, blk, 0)  # inactive rows -> null block
-    off = pos % bs
+    pos, blk, off = _step_write_slots(block_tables, positions, active, pools.k.shape[4])
     kl = cfg.kv_lora_rank
     scale = latent_softmax_scale(cfg)
 
@@ -1189,6 +1247,132 @@ def _block_paged_prefill(params, prompt, prompt_len, pools, table_row, cfg: GPTC
             _moe_counts(stats) if stats else None)
 
 
+# ---------------------------------------------------------------------------
+# A ``layer_pattern`` block on the paged pools (docs/nemotron_h.md): every
+# layer is ONE sub-block, x + mix(RMSNorm(x)), of one of three kinds: a
+# state-space mixer over the row's recurrent state, grouped-query attention
+# without rotation over the row's pages, a feed-forward (experts or dense).
+# ---------------------------------------------------------------------------
+
+
+def _pattern_stack(params, x, pools, valid, cfg: GPTConfig, mixer, attend):
+    """The pattern's layers one after the other.  ``mixer(p, y, pools, m)``
+    runs state-space layer number ``m`` and ``attend(q, k, v, pools, a)``
+    attention layer number ``a`` over its own cache; each -> (result,
+    pools).  -> (x, pools, the expert layers' statistics, a list)."""
+    dtype = x.dtype
+    stats, m, a = [], 0, 0
+    for kind, p in zip(cfg.layer_pattern, params["blocks"]):
+        y = rms_norm(x, p["ln_1"]["scale"], cfg.norm_eps)
+        if kind == "M":
+            out, pools = mixer(_in_dtype("ssm", p["ssm"], dtype), y, pools, m)
+            m += 1
+        elif kind == "*":
+            attn = _in_dtype("attn", p["attn"], dtype)
+            q, k, v = (jnp.einsum("bsh,hnd->bsnd", y, attn[f"{n}_kernel"]) for n in "qkv")
+            out, pools = attend(q, k, v, pools, a)
+            out = jnp.einsum("bsnd,ndh->bsh", out, attn["out_kernel"])
+            a += 1
+        else:
+            out, st = _block_mlp(p["mlp"], y, cfg, valid)
+            if st is not None:
+                stats.append(st)
+        x = x + out
+    return x, pools, stats
+
+
+def _pattern_prefill_attention(q, k, v, cfg: GPTConfig):
+    """Causal attention over one sequence, KV heads shared by their groups."""
+    from paddlefleetx_tpu.ops.attention import attention
+
+    with jax.named_scope("pfx.attn.gqa.prefill"):
+        return attention(q, k, v, impl=cfg.attn_impl, causal=True, flash_block=cfg.flash_block)
+
+
+def _pattern_paged_forward_step(params, tokens, pools, block_tables, positions, active,
+                                cfg: GPTConfig, ctx):
+    """The decode step of a ``layer_pattern`` block: tokens [B] at slots
+    ``positions`` -> (logits [B, 1, v] f32, pools, counts).  Row i IS batch
+    slot i: its recurrent state is ``pools.ssm[:, i]``."""
+    if ctx is not None:
+        raise ValueError("tensor parallelism: a layer_pattern block is served on one "
+                         "device (its pools, states and experts have no sharding rules yet)")
+    if tokens.ndim == 2 and tokens.shape[1] != 1:
+        raise ValueError(
+            "a layer_pattern block's decode step takes one token a row: a verify chunk "
+            "(draft_k) or a prompt chunk (prefill_chunk) over row state is not written")
+    from paddlefleetx_tpu.models.gpt.ssm import mixer_step
+
+    tokens = tokens.reshape(-1)
+    dtype = jnp.dtype(cfg.dtype)
+    x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][tokens][:, None]
+    pos, blk, off = _step_write_slots(block_tables, positions, active, pools.k.shape[3])
+    heads = jax.lax.iota(jnp.int32, cfg.kv_heads)[None, :]
+
+    def mixer(p, y, pools, m):
+        out, ssm, conv = mixer_step(p, y, pools.ssm, pools.conv, active, cfg, layer=m)
+        return out, pools._replace(ssm=ssm, conv=conv)
+
+    def attend(q, k, v, pools, a):
+        at = (a, blk[:, None], heads, off[:, None])  # [B, kv heads] slots of this layer
+        pools = pools._replace(k=pools.k.at[at].set(k[:, 0].astype(pools.k.dtype)),
+                               v=pools.v.at[at].set(v[:, 0].astype(pools.v.dtype)))
+        with jax.named_scope("pfx.attn.gqa.decode"):
+            out = paged_decode_attention(q, pools.k, pools.v, block_tables, pos, layer=a)
+        return out, pools
+
+    x, pools, stats = _pattern_stack(params, x, pools, active[:, None], cfg, mixer, attend)
+    return _block_logits(params, x, cfg), pools, _moe_counts(stats) if stats else None
+
+
+def _pattern_paged_prefill(params, prompt, prompt_len, pools, table_row, slot, cfg: GPTConfig, ctx):
+    """Prefill of a ``layer_pattern`` block over the right-padded prompt
+    [1, P]: each attention layer's keys and values go to the row's pages,
+    each state-space layer's state after the last REAL token and its last
+    conv columns OVERWRITE batch slot ``slot``'s (whatever a finished row
+    left there).  -> (pools, the last real token's logits [v], counts)."""
+    if ctx is not None:
+        raise ValueError("tensor parallelism: a layer_pattern block is served on one device")
+    if cfg.row_state and slot is None:
+        raise ValueError("a block with row state prefills INTO a batch slot: pass slot")
+    from paddlefleetx_tpu.models.gpt.ssm import mixer_prefill
+    from paddlefleetx_tpu.ops.ssm import write_slot_states
+
+    P = int(prompt.shape[1])
+    PB, bs = int(table_row.shape[0]), int(pools.k.shape[3])
+    if PB * bs < P:
+        raise ValueError(f"table_row covers {PB}x{bs}={PB * bs} slots < prompt bucket {P}")
+    dtype = jnp.dtype(cfg.dtype)
+    x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][prompt]
+    valid = jax.lax.iota(jnp.int32, P)[None] < prompt_len
+
+    kept = []  # each state-space layer's (state, conv columns), written at the end
+
+    def mixer(p, y, pools, m):
+        out, state, columns = mixer_prefill(p, y, prompt_len, cfg)
+        kept.append((state, columns))
+        return out, pools
+
+    def pages(t, pool):  # [1, P, kv heads, d] -> [PB, kv heads, bs, d]
+        t = jnp.pad(t[0], ((0, PB * bs - P), (0, 0), (0, 0)))
+        return t.reshape(PB, bs, t.shape[1], t.shape[2]).transpose(0, 2, 1, 3).astype(pool.dtype)
+
+    def attend(q, k, v, pools, a):
+        return _pattern_prefill_attention(q, k, v, cfg), pools._replace(
+            k=pools.k.at[a, table_row].set(pages(k, pools.k)),
+            v=pools.v.at[a, table_row].set(pages(v, pools.v)))
+
+    x, pools, stats = _pattern_stack(params, x, pools, valid, cfg, mixer, attend)
+    if kept:
+        states, columns = (jnp.stack(v) for v in zip(*kept))
+        pools = pools._replace(
+            ssm=write_slot_states(pools.ssm, states, slot),
+            conv=pools.conv.at[:, slot].set(columns.astype(pools.conv.dtype)))
+    last = jax.lax.dynamic_index_in_dim(x[0], prompt_len - 1, axis=0, keepdims=True)
+    return (pools, _block_logits(params, last[None], cfg)[0, 0],
+            _moe_counts(stats) if stats else None)
+
+
 def expert_load(params, tokens: jax.Array, cfg: GPTConfig) -> jax.Array:
     """tokens [1, s] through the described block's EXPANDED forward, no
     cache -> the pairs each expert of each expert layer received,
@@ -1196,6 +1380,14 @@ def expert_load(params, tokens: jax.Array, cfg: GPTConfig) -> jax.Array:
     balance rule (``moe.next_expert_bias``) reads."""
     dtype = jnp.dtype(cfg.dtype)
     x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][tokens]
+    if cfg.layer_pattern:
+        from paddlefleetx_tpu.models.gpt.ssm import mixer_prefill
+
+        _, _, stats = _pattern_stack(
+            params, x, None, None, cfg,
+            lambda p, y, pools, m: (mixer_prefill(p, y, tokens.shape[1], cfg)[0], pools),
+            lambda q, k, v, pools, a: (_pattern_prefill_attention(q, k, v, cfg), pools))
+        return jnp.stack([st["load"] for st in stats])
     positions = jnp.broadcast_to(jax.lax.iota(jnp.int32, tokens.shape[1])[None], tokens.shape)
 
     def layer_fn(p, x, state, layer):
@@ -1206,6 +1398,11 @@ def expert_load(params, tokens: jax.Array, cfg: GPTConfig) -> jax.Array:
 
     _, _, stats = _block_stack_step(params, x, None, cfg, layer_fn)
     return jnp.stack([st["load"] for st in stats])
+
+
+def _block_forward_step(cfg: GPTConfig):
+    """The decode step of a block other than the GPT-2 one."""
+    return _pattern_paged_forward_step if cfg.layer_pattern else _block_paged_forward_step
 
 
 def _paged_layer_step(
@@ -1279,8 +1476,9 @@ def paged_forward_step(
     table-width clamp, so pad K/V must never be written anywhere."""
     if not cfg.classic_block:
         if n_valid is not None:
-            raise ValueError("prefill_chunk: a prompt chunk over latent pools is not written")
-        return _block_paged_forward_step(
+            raise ValueError("prefill_chunk: a prompt chunk over latent pools or row state "
+                             "is not written")
+        return _block_forward_step(cfg)(
             params, tokens, pools, block_tables, positions, active, cfg, ctx)[:2]
     if tokens.ndim == 1:
         tokens = tokens[:, None]
@@ -1336,8 +1534,10 @@ def paged_prefill(
     cfg: GPTConfig,
     ctx: Optional[ShardingCtx] = None,
     return_moe: bool = False,
+    slot: Optional[jax.Array] = None,
 ) -> Tuple[PagedPools, jax.Array, jax.Array]:
-    """Prefill ONE row's prompt into its pool blocks (prefill-on-admit).
+    """Prefill ONE row's prompt into its pool blocks (prefill-on-admit);
+    for a block with row state also into batch slot ``slot``.
 
     ``prompt`` [1, P] is RIGHT-padded to the bucket (real tokens at
     [0, prompt_len); pad junk after) — unlike the contiguous serving
@@ -1356,8 +1556,12 @@ def paged_prefill(
     expert layers' counts (:func:`_moe_counts`) or None without any."""
     P = int(prompt.shape[1])
     if not cfg.classic_block:
-        pools, last, moe = _block_paged_prefill(
-            params, prompt, prompt_len, pools, table_row, cfg, ctx)
+        if cfg.layer_pattern:
+            pools, last, moe = _pattern_paged_prefill(
+                params, prompt, prompt_len, pools, table_row, slot, cfg, ctx)
+        else:
+            pools, last, moe = _block_paged_prefill(
+                params, prompt, prompt_len, pools, table_row, cfg, ctx)
         counts = jnp.zeros((cfg.vocab_size,), jnp.int32).at[prompt[0]].add(
             (jnp.arange(P) < prompt_len).astype(jnp.int32))
         return (pools, last, counts, moe) if return_moe else (pools, last, counts)
@@ -1586,7 +1790,7 @@ def decode_step(
         )
         moe = None
     else:
-        new_logits, pools, moe = _block_paged_forward_step(
+        new_logits, pools, moe = _block_forward_step(cfg)(
             params, nxt, pools, block_tables, rows.positions, rows.active, cfg, ctx)
     act = rows.active.astype(jnp.int32)
     new_rows = PagedRows(

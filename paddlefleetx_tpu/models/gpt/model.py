@@ -162,8 +162,48 @@ def _layer_specs(cfg: GPTConfig) -> Dict[str, Any]:
     return specs
 
 
+def _pattern_layer_specs(cfg: GPTConfig, kind: str) -> Dict[str, Any]:
+    """One layer of a ``layer_pattern`` block: ONE sub-block behind one
+    RMSNorm.  Every layer has an ``mlp`` group (empty for a mixer layer):
+    an expert layer is one whose ``mlp`` holds a router."""
+    h, nh, nkv, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    w = normal_init(cfg.initializer_range)
+    # the sub-block's output matrix: scaled down with the depth, so that the
+    # residual stream of seeded weights keeps its size over the layers
+    w_out = normal_init(cfg.initializer_range / (
+        cfg.num_layers ** 0.5 if cfg.rescale_prenorm_residual else 1.0))
+    specs: Dict[str, Any] = {"ln_1": _rms_specs(h), "mlp": {}}
+    if kind == "M":
+        from paddlefleetx_tpu.models.gpt.ssm import mixer_specs
+
+        specs["ssm"] = mixer_specs(cfg, w_out)
+    elif kind == "*":
+        specs["attn"] = {
+            "q_kernel": ParamSpec((h, nh, hd), ("embed", "heads", "kv"), w),
+            "k_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
+            "v_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
+            "out_kernel": ParamSpec((nh, hd, h), ("heads", "kv", "embed"), w_out),
+        }
+    else:
+        from paddlefleetx_tpu.models.gpt.moe import dropless_layer_specs, relu2_specs
+
+        specs["mlp"] = (dropless_layer_specs(cfg, w_out) if kind == "E"
+                        else relu2_specs(h, cfg.ffn_hidden_size, w, w_out))
+    return specs
+
+
 def gpt_specs(cfg: GPTConfig) -> Dict[str, Any]:
     w = normal_init(cfg.initializer_range)
+    if cfg.layer_pattern:
+        # layers of different kinds share no stack: the tree is made as it
+        # is served, ``blocks`` a tuple of one dict a layer
+        word = ParamSpec((cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), w)
+        return {
+            "embeddings": {"word": word},
+            "blocks": tuple(_pattern_layer_specs(cfg, kind) for kind in cfg.layer_pattern),
+            "final_ln": _rms_specs(cfg.hidden_size),
+            "head": {"kernel": word},
+        }
     if not cfg.classic_block:
         n_dense = cfg.leading_dense_layers
         word = ParamSpec((cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), w)
@@ -706,6 +746,10 @@ def forward_hidden(
     dropless expert layer instead its load statistics, stacked over the
     expert layers).  ``expert_bias`` [expert layers, experts] is that
     layer's routing buffer (None = zeros)."""
+    if cfg.layer_pattern:
+        raise NotImplementedError(
+            "a layer_pattern block is served (models/gpt/generation.py), not trained: the "
+            "chunked scan has no backward pass yet")
     k_embed, k_layers = (
         jax.random.split(dropout_key) if dropout_key is not None else (None, None)
     )

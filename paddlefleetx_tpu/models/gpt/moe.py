@@ -170,7 +170,8 @@ def moe_mlp_block(
 # No token is dropped: the buffer has the worst case's tokens x top_k rows
 # (it fits the cell's chip, tests/test_chip_compile.py), so gathers, masks
 # and the scatter cost what the worst case costs and only the grouped
-# products follow the load.
+# products follow the load.  An expert is SwiGLU's three matrices (w1, w3,
+# w2) or, under ``mlp_act: relu2``, two: ``W_down relu(W_up m)^2`` (w1, w2).
 # ---------------------------------------------------------------------------
 
 
@@ -182,15 +183,30 @@ def swiglu_specs(h: int, f: int, w, lead=(), lead_axes=()) -> Dict[str, Any]:
     }
 
 
-def dropless_layer_specs(cfg) -> Dict[str, Any]:
+def relu2_specs(h: int, f: int, w, w_out, lead=(), lead_axes=()) -> Dict[str, Any]:
+    """The non-gated MLP's two matrices; ``w_out`` draws the down-projection."""
+    return {
+        "w1": ParamSpec(lead + (h, f), lead_axes + ("embed", "mlp"), w),
+        "w2": ParamSpec(lead + (f, h), lead_axes + ("mlp", "embed"), w_out),
+    }
+
+
+def dropless_layer_specs(cfg, w_out=None) -> Dict[str, Any]:
+    """``w_out`` (relu2 experts only): the down-projections' draw."""
     h, f = cfg.hidden_size, cfg.moe_ffn_hidden_size or cfg.ffn_hidden_size
     w = normal_init(cfg.initializer_range)
+    if cfg.mlp_act == "relu2":
+        def mlp_specs(width, *lead):
+            return relu2_specs(h, width, w, w_out or w, *lead)
+    else:
+        def mlp_specs(width, *lead):
+            return swiglu_specs(h, width, w, *lead)
     specs = {
         "router_kernel": ParamSpec((h, cfg.num_experts), ("embed", None), w),
-        "experts": swiglu_specs(h, f, w, (cfg.experts_held,), ("expert",)),
+        "experts": mlp_specs(f, (cfg.experts_held,), ("expert",)),
     }
     if cfg.moe_shared_experts:
-        specs["shared"] = swiglu_specs(h, f * cfg.moe_shared_experts, w)
+        specs["shared"] = mlp_specs(f * cfg.moe_shared_experts)
     return specs
 
 
@@ -199,6 +215,16 @@ def swiglu(x: jax.Array, p: Dict[str, Any]) -> jax.Array:
     return (jax.nn.silu(x @ p["w1"].astype(dtype)) * (x @ p["w3"].astype(dtype))) @ p[
         "w2"
     ].astype(dtype)
+
+
+def relu2_mlp(x: jax.Array, p: Dict[str, Any]) -> jax.Array:
+    dtype = x.dtype
+    return jnp.square(jax.nn.relu(x @ p["w1"].astype(dtype))) @ p["w2"].astype(dtype)
+
+
+def feed_forward(x: jax.Array, p: Dict[str, Any]) -> jax.Array:
+    """The MLP its parameters spell: three matrices SwiGLU, two relu2."""
+    return swiglu(x, p) if "w3" in p else relu2_mlp(x, p)
 
 
 def sigmoid_route(m: jax.Array, router_kernel: jax.Array, bias: jax.Array, cfg):
@@ -240,8 +266,11 @@ def _held_experts_on_every_token(ex, m, idx, w, held: int, offset: int):
         hit = (idx - offset)[:, :, None] == jax.lax.broadcasted_iota(jnp.int32, (1, 1, held), 2)
         w_te = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)  # [N, held]
     with jax.named_scope("pfx.moe.experts"):
-        hidden = jax.nn.silu(jnp.einsum("nh,ehf->enf", m, ex["w1"].astype(dtype))) * jnp.einsum(
-            "nh,ehf->enf", m, ex["w3"].astype(dtype))
+        up = jnp.einsum("nh,ehf->enf", m, ex["w1"].astype(dtype))
+        if "w3" in ex:
+            hidden = jax.nn.silu(up) * jnp.einsum("nh,ehf->enf", m, ex["w3"].astype(dtype))
+        else:
+            hidden = jnp.square(jax.nn.relu(up))
         ys = jnp.einsum("enf,efh->enh", hidden, ex["w2"].astype(dtype))
     with jax.named_scope("pfx.moe.combine"):
         return jnp.einsum("enh,ne->nh", ys.astype(jnp.float32), w_te).astype(dtype)
@@ -296,7 +325,10 @@ def routed_experts(p: Dict[str, Any], m: jax.Array, bias: jax.Array, cfg, valid=
             y = jax.lax.ragged_dot(jnp.where(live, x, 0), kernel.astype(dtype), group_sizes)
             return jnp.where(live, y, 0)
 
-        hidden = jax.nn.silu(grouped(xs, ex["w1"])) * grouped(xs, ex["w3"])
+        if "w3" in ex:
+            hidden = jax.nn.silu(grouped(xs, ex["w1"])) * grouped(xs, ex["w3"])
+        else:
+            hidden = jnp.square(jax.nn.relu(grouped(xs, ex["w1"])))
         ys = grouped(hidden, ex["w2"])
     with jax.named_scope("pfx.moe.combine"):
         out = jnp.zeros((n, h), jnp.float32).at[token].add(ys.astype(jnp.float32) * w_sorted)
@@ -325,7 +357,7 @@ def dropless_moe_block(p: Dict[str, Any], x: jax.Array, cfg, ctx, bias: jax.Arra
         p, m, bias, cfg, None if valid is None else valid.reshape(b * s), every_held_expert)
     if cfg.moe_shared_experts:
         with jax.named_scope("pfx.moe.shared"):
-            out = out + swiglu(m, p["shared"])
+            out = out + feed_forward(m, p["shared"])
     return out.reshape(b, s, h), stats
 
 

@@ -474,8 +474,10 @@ def decode_attention(
 
 
 def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
-               k_scale=None, v_scale=None):
-    """q_t [b, n, t, d]; pools [layers, nb, n, bs, d], of which layer
+               k_scale=None, v_scale=None, t=None):
+    """q_t [b, n, t, d] (grouped heads: [b, kv heads, group * t, d], the
+    ``t`` queries of each head of a group one after the other, ``t``
+    given); pools [layers, nb, n, bs, d], of which layer
     ``layer``'s blocks are read; tables [b, M] block ids; positions [b] =
     global slot of each row's FIRST query token (query qi sits at slot
     positions[i] + qi — t > 1 is the speculative multi-token verify
@@ -491,13 +493,14 @@ def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
     ``k_scale``/``v_scale`` [layers, nb, n, bs] dequantize in-loop (scores
     absorb the key scale, probabilities the value scale).
     """
-    b, n, t, d = q_t.shape
+    b, n, rows, d = q_t.shape
+    t = t or rows
     bs = k_pool.shape[3]
     quant = k_scale is not None
 
-    m0 = jnp.full((b, n, t), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, n, t), jnp.float32)
-    acc0 = jnp.zeros((b, n, t, d), jnp.float32)
+    m0 = jnp.full((b, n, rows), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((b, n, rows), jnp.float32)
+    acc0 = jnp.zeros((b, n, rows, d), jnp.float32)
 
     # each row's last needed block (its LAST query's slot): the fori
     # bound below is the BATCH max, so shorter rows clamp their gather to
@@ -506,6 +509,8 @@ def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
     # each row's real length
     last_blk = jnp.maximum(positions + t - 1, 0) // bs
     q_off = jnp.arange(t)  # query qi's slot offset within the chunk
+    if rows != t:  # every head of a group asks the same t slots
+        q_off = jnp.tile(q_off, rows // t)
 
     def body(j, carry):
         m, l, acc = carry
@@ -559,20 +564,22 @@ PAGED_STEP_TOKENS = 128
 _PAGED_Q_TILE = 64
 
 
-def paged_pages_per_step(block: int, width: int) -> int:
+def paged_pages_per_step(block: int, width: int, group: int = 1) -> int:
     """Pages (pool blocks of ``block`` slots) one grid step of the paged
     kernel walks, for a block table ``width`` pages wide — chosen from
-    the shapes alone: fewer pages as the page grows."""
-    return max(1, min(PAGED_STEP_TOKENS // block, width))
+    the shapes alone: fewer pages as the page grows, more (up to four
+    times) where ``group`` query heads share each KV head: a token is
+    then few bytes beside the grid step's fixed cost."""
+    return max(1, min(PAGED_STEP_TOKENS * min(group, 4) // block, width))
 
 
-def paged_tokens_computed(positions, t: int, block: int, width: int):
+def paged_tokens_computed(positions, t: int, block: int, width: int, group: int = 1):
     """KV tokens per head the paged kernel computes on for rows whose
     first query sits at slot ``positions`` (array): each row's context
     ``positions + t`` rounded up to whole grid steps (a slot at position
     0 — an empty one — costs one), never past the table.  The scheduler's
     ``pfx_sched_decode_grid_tokens_total`` sums this over a step's slots."""
-    pages = paged_pages_per_step(block, width)
+    pages = paged_pages_per_step(block, width, group)
     step = pages * block
     steps = -(-width // pages)
     last_page = (positions + (t - 1)).clip(0) // block
@@ -587,9 +594,13 @@ def _paged_last_page(pos, qt, *, t, tq, bs):
 
 def _paged_kernel(
     layer_ref, tables_ref, pos_ref, q_ref, *refs, scale, bs, t, tq, pages,
-    width, quant
+    width, quant, per_kv=1
 ):
     """One (row, query tile, page group) grid step, every head inside.
+    With ``per_kv`` > 1 query heads to a KV head, the ``per_kv * t`` queries
+    that read one KV head are the ROWS of one product against its page
+    (``q_ref`` [1, kv heads, per_kv * t, d], one tile), so a page is read
+    once for all of them.
 
     ``refs`` = ``pages`` K blocks, ``pages`` V blocks (each [1, n, bs, d]:
     one whole pool page, contiguous in HBM), with int8 pools ``pages`` +
@@ -639,10 +650,15 @@ def _paged_kernel(
         )  # [n, tq, pages * bs], batched over heads
         if quant:
             s = s * group(kv[2 * pages: 3 * pages], 2)  # [n, 1, pages * bs]
-        shape = (1, tq, pages * bs)
+        shape = (1, per_kv * tq, pages * bs)
         col = j * (pages * bs) + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
         # query qi's own causal bound: slot pos + qi
-        qrow = pos + qt * tq + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        if per_kv == 1:
+            qrow = pos + qt * tq + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        elif t == 1:
+            qrow = pos
+        else:
+            qrow = pos + jax.lax.broadcasted_iota(jnp.int32, shape, 1) % t
         mask = col <= qrow
         if width % pages:
             # the last group hangs over the table: its spare pages
@@ -671,17 +687,23 @@ def _paged_kernel(
 
 
 def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
-                  k_scale=None, v_scale=None):
+                  k_scale=None, v_scale=None, t=None):
     from jax.experimental.pallas import tpu as pltpu
 
-    b, n, t, d = q_t.shape
+    b, n, rows, d = q_t.shape
+    t = t or rows
+    group = rows // t
     bs = k_pool.shape[3]
     M = tables.shape[1]
     tables = tables.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
     quant = k_scale is not None
-    pages = paged_pages_per_step(bs, M)
+    pages = paged_pages_per_step(bs, M, group)
     tq = min(t, _PAGED_Q_TILE)
+    if group > 1 and (tq != t or quant):
+        raise ValueError(
+            f"pfx_decode_paged with {group} query heads a KV head holds all {t} queries "
+            f"in one tile (at most {_PAGED_Q_TILE}) and reads no int8 pools yet")
 
     def page_index(p, stacked):
         def index(i, qt, j, layer_ref, tables_ref, pos_ref):
@@ -695,7 +717,7 @@ def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
             return (layer_ref[0],) + at if stacked else at
         return index
 
-    q_spec = pl.BlockSpec((1, n, tq, d), lambda i, qt, j, *_: (i, 0, qt, 0))
+    q_spec = pl.BlockSpec((1, n, group * tq, d), lambda i, qt, j, *_: (i, 0, qt, 0))
     # the pools enter WHOLE, all layers: the page address carries the
     # layer, so the caller's arena is read where it lies (a layer sliced
     # out of the stack would be copied to a buffer of its own first)
@@ -727,19 +749,19 @@ def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((n, tq, d), jnp.float32),
-            pltpu.VMEM((n, tq, 128), jnp.float32),
-            pltpu.VMEM((n, tq, 128), jnp.float32),
+            pltpu.VMEM((n, group * tq, d), jnp.float32),
+            pltpu.VMEM((n, group * tq, 128), jnp.float32),
+            pltpu.VMEM((n, group * tq, 128), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, bs=bs, t=t, tq=tq, pages=pages,
-        width=M, quant=quant,
+        width=M, quant=quant, **({"per_kv": group} if group > 1 else {}),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n, t, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, n, rows, d), jnp.float32),
         interpret=_device.pallas_interpret(),
         name="pfx_decode_paged",
     )(layer[None], tables, positions, *operands)
@@ -759,9 +781,12 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Block-table-indexed decode attention for the paged KV cache.
 
-    q [b, t, n, d]; pools [num_blocks, n, block, d] (one layer's arena —
-    ``core/paged_cache.py``) or, with ``layer`` (an int32 scalar, traced
-    or not), the whole arena [layers, num_blocks, n, block, d], of which
+    q [b, t, n, d]; pools [num_blocks, kv heads, block, d] (one layer's
+    arena — ``core/paged_cache.py``; ``kv heads`` = n or a divisor of it:
+    KV head h serves query heads g*h .. g*h+g-1, whose queries read its
+    pages ONCE, as the rows of one product) or, with ``layer`` (an int32
+    scalar, traced or not), the whole arena [layers, num_blocks, kv heads,
+    block, d], of which
     that layer's pages are read IN PLACE: the serving step carries the
     arena through its layer loop and hands it over as it is, because a
     layer's pool sliced out of the stack is copied to a buffer of its own
@@ -820,13 +845,18 @@ def paged_decode_attention(
         )
     scale = float(1.0 / (d**0.5))
     q_t = q.transpose(0, 2, 1, 3)  # [b, n, t, d]
+    kv = k_pool.shape[2]
+    if kv != n:
+        if n % kv:
+            raise ValueError(f"{kv} KV heads do not divide {n} query heads")
+        q_t = q_t.reshape(b, kv, (n // kv) * t, d)
     if use_pallas:
         out = _paged_pallas(q_t, k_pool, v_pool, layer, block_tables,
-                            positions, scale, k_scale, v_scale)
+                            positions, scale, k_scale, v_scale, t)
     else:
         out = _paged_lax(q_t, k_pool, v_pool, layer, block_tables, positions,
-                         scale, k_scale, v_scale)
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+                         scale, k_scale, v_scale, t)
+    return out.reshape(b, n, t, d).transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -114,7 +114,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_spec_accepted_total": ("counter", "Draft tokens accepted and committed by the verify step"),
     "pfx_spec_accept_rate": ("gauge", "Lifetime accepted/proposed draft ratio"),
     "pfx_kv_bytes": ("gauge", "Live KV-cache payload bytes (used blocks x K+V bytes per block)"),
-    "pfx_kv_bytes_per_token": ("gauge", "Bytes one cached token takes over all layers, from the model: per-head K and V, or one latent"),
+    "pfx_kv_bytes_per_token": ("gauge", "Bytes one cached token takes over all layers that cache tokens, from the model: per-head K and V, or one latent"),
+    "pfx_state_bytes_per_row": ("gauge", "Bytes a row keeps beside its pages whatever its length, over all state-space layers: the recurrent state and the conv's last columns (0 for a block whose every layer caches tokens)"),
     # shared-prefix KV reuse + chunked prefill (core/paged_cache.py
     # PrefixIndex, core/continuous_batching.py)
     "pfx_prefix_hits_total": ("counter", "Admissions that reused cached prefix blocks"),
@@ -272,6 +273,9 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_moe_serve_pairs_total": ("counter", "Serving: token-expert pairs the expert layers routed for live rows and real prompt tokens, over all experts and layers (prefills and decode steps)"),
     "pfx_moe_serve_held_pairs_total": ("counter", "Serving: routed pairs that landed on experts this process holds"),
     "pfx_moe_serve_held_max_pairs_total": ("counter", "Serving: the fullest held expert's pairs x experts held, summed over layers and dispatches (over pfx_moe_serve_held_pairs_total: max over mean)"),
+    "pfx_ssm_row_steps_total": ("counter", "Serving: live (row, decode step) pairs x state-space layers: the state updates the traffic needed"),
+    "pfx_ssm_slot_steps_total": ("counter", "Serving: batch slots x decode steps x state-space layers: the state updates the kernel walked (it runs every slot, live or not)"),
+    "pfx_ssm_prefill_tokens_total": ("counter", "Serving: prompt tokens x state-space layers the chunked scan of the prefills computed"),
     "pfx_token_ledger_total": ("counter", "Admitted-token dispositions (labels: disposition=admitted|delivered|evicted_lost|preempt_refunded|shed_after_admit)"),
     "pfx_token_ledger_in_flight": ("gauge", "Admitted tokens still on the books in live decode slots (the exact-closure remainder)"),
     "pfx_tenant_slot_seconds_total": ("counter", "Decode-slot occupancy in slot-seconds per tenant — billing-grade cost attribution (labels: tenant)"),

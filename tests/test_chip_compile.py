@@ -637,3 +637,98 @@ def test_decode_step_13b_writes_the_donated_arena_in_place(topo, kv_dtype, t):
         if op != "custom-call" or 'custom_call_target="AllocateBuffer"' in rest
     ]
     assert not moved, moved
+
+
+# ---------------------------------------------------------------------------
+# A block with row state (docs/nemotron_h.md) at the published sizes of the
+# benchmark's configuration nemotron-3-nano: the state kernels, the paged
+# kernel over pools of 2 KV heads, and the whole 52-layer decode step
+# ---------------------------------------------------------------------------
+
+
+def test_ssm_state_kernels_rewrite_the_states_in_place(topo):
+    """``pfx_ssm_decode`` over two layers of the 23 x 48 slots' states (2.3 GB,
+    donated) and ``pfx_ssm_write`` of one slot: the programs need NO scratch —
+    a copy of the states does not fit beside 10.5 GB of weights."""
+    from paddlefleetx_tpu.ops import ssm
+
+    one = _one_chip(topo)
+    slots, heads, hd, n, groups = 48, 64, 64, 128, 8
+    states = _shapes(one, ((23, slots) + ssm.packed_shape(heads, hd, n), jnp.float32))
+
+    def two_layers(st, x, dt, a, b, c, d):
+        for layer in range(2):
+            y, st = ssm.ssm_decode_update(st, x, dt, a, b, c, d, layer=layer)
+            x = x + y.astype(x.dtype)
+        return y, st
+
+    args = _shapes(one, (((slots, heads, hd), BF16), ((slots, heads), jnp.float32),
+                         ((heads,), jnp.float32), ((slots, groups, n), BF16),
+                         ((slots, groups, n), BF16), ((heads,), jnp.float32)))
+    c = jax.jit(two_layers, donate_argnums=(0,)).lower(states, *args).compile()
+    assert c.as_text().count("tpu_custom_call") == 2
+    assert c.memory_analysis().temp_size_in_bytes < 1e6
+    new = _shapes(one, ((23,) + ssm.packed_shape(heads, hd, n), jnp.float32))
+    slot = _shapes(one, ((), jnp.int32))
+    w = jax.jit(ssm.write_slot_states, donate_argnums=(0,)).lower(states, new, slot).compile()
+    assert _has_kernel(w) and w.memory_analysis().temp_size_in_bytes < 1e6
+
+
+@pytest.mark.parametrize("rows,t,width", [pytest.param(48, 1, 16, id="chat-cell"),
+                                          pytest.param(4, 3, 8, id="chunk-of-3")])
+def test_paged_decode_with_shared_kv_heads_compiles(topo, rows, t, width):
+    """32 query heads on the 2 KV heads of a [6, 673, 2, 128, 128] arena: the
+    16 queries of a KV head are the rows of one product against its page."""
+    from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
+
+    one = _one_chip(topo)
+    q = _shapes(one, ((rows, t, 32, 128), BF16))
+    pool = _shapes(one, ((6, 673, 2, 128, 128), BF16))
+    tables = _shapes(one, ((rows, width), jnp.int32))
+    positions = _shapes(one, ((rows,), jnp.int32))
+    c = _compile(lambda q, k, v, tb, ps: paged_decode_attention(q, k, v, tb, ps, layer=3),
+                 q, pool, pool, tables, positions)
+    assert _has_kernel(c) and c.memory_analysis().temp_size_in_bytes < 16e6
+
+
+def test_decode_step_of_the_whole_depth_pattern_block_fits_and_copies_nothing(topo):
+    """The benchmark cell ``serve-nemotron3-nano-1of8-chat``'s decode step as
+    its configuration file states it (52 layers, 48 slots), the pools DONATED:
+    10.5 GB of weights, 2.36 GB of states and 0.53 GB of pages are arguments,
+    the states and the arena come back aliased, and nothing of their shape is
+    copied (the step's scratch is tens of MB)."""
+    import json
+    import re
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    root = os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..")
+    bench = os.path.join(root, "pfx_bench")  # noqa: E10 — a directory, not a metric
+    with open(os.path.join(bench, "configs", "nemotron-3-nano.json")) as f:
+        cfg = GPTConfig(**json.load(f)["model"])
+    one = _one_chip(topo)
+    slots, width, vocab = 48, 16, cfg.vocab_size
+    params = _shapes(one, jax.eval_shape(lambda: G.init_serving_params(cfg, jax.random.key(0))))
+    pools = _shapes(one, jax.eval_shape(
+        lambda: G.init_paged_pools(cfg, slots * 14 + 1, cfg.kv_block_default, slots=slots)))
+    gen = G.GenerationConfig(decode_strategy="greedy_search", max_dec_len=0, min_dec_len=768,
+                             eos_token_id=0, pad_token_id=0)
+
+    def step(p, pools, tables, logits, counts, positions, gen_steps, max_news, active, forced):
+        rows = G.PagedRows(logits, counts, positions, gen_steps, max_news, active, forced)
+        nxt, pools, new = G.decode_step(p, pools, tables, rows, cfg, gen)
+        return nxt, pools, new.logits, new.counts, new.moe
+
+    i32 = functools.partial(lambda *shape: (shape, jnp.int32))
+    rows = _shapes(one, (i32(slots, width), ((slots, vocab), jnp.float32), i32(slots, vocab),
+                         i32(slots), i32(slots), i32(slots), ((slots,), jnp.bool_), i32(slots)))
+    c = jax.jit(step, donate_argnums=(1,)).lower(params, pools, *rows).compile()
+    m = c.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert 2.8e9 < held <= m.alias_size_in_bytes < 1.01 * held
+    assert 13.3e9 < m.argument_size_in_bytes < 13.6e9 and m.temp_size_in_bytes < 0.2e9
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 23 + 6  # a state update a Mamba layer, a paged read a * layer
+    moved = re.findall(r"= \w+\[(?:23,48,32,128,128|6,673,2,128,128)\]\S* (copy|transpose)\(", text)
+    assert not moved, moved

@@ -48,6 +48,7 @@ def test_selftest_check(selftest, name, monkeypatch):
 
 @pytest.mark.parametrize("mix,cell,pad,buckets", [
     ("think-long-answers", "serve-dsv3-1of32-think", 512, [1024, 1536, 2048, 2560, 3072]),
+    ("chat-short-answers", "serve-nemotron3-nano-1of8-chat", 256, [256, 512, 768, 1024]),
 ])
 def test_a_serving_cell_s_data_files(selftest, mix, cell, pad, buckets):
     """A cell added after the self-test's own list of mixes: its traffic
