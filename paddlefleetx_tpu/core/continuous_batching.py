@@ -392,8 +392,8 @@ class PagedDecodeEngine:
             # expert's pairs x experts held (generation._moe_counts)
             "moe_pairs": 0, "moe_held_pairs": 0, "moe_held_max_pairs": 0,
             # state-space layers (warm-up excluded): live (row, step)
-            # pairs x layers, slots x steps x layers (what the state
-            # kernel walked: it runs every slot), prompt tokens x layers
+            # pairs x layers (what the state kernel visits), slots x
+            # steps x layers (the capacity), prompt tokens x layers
             "ssm_row_steps": 0, "ssm_slot_steps": 0, "ssm_prefill_tokens": 0,
         }
         # True only inside warmup(): warmup admits/steps are not traffic

@@ -1302,15 +1302,17 @@ def _pattern_paged_forward_step(params, tokens, pools, block_tables, positions, 
             "a layer_pattern block's decode step takes one token a row: a verify chunk "
             "(draft_k) or a prompt chunk (prefill_chunk) over row state is not written")
     from paddlefleetx_tpu.models.gpt.ssm import mixer_step
+    from paddlefleetx_tpu.ops.ssm import live_slots
 
     tokens = tokens.reshape(-1)
     dtype = jnp.dtype(cfg.dtype)
     x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][tokens][:, None]
     pos, blk, off = _step_write_slots(block_tables, positions, active, pools.k.shape[3])
     heads = jax.lax.iota(jnp.int32, cfg.kv_heads)[None, :]
+    live = live_slots(active)  # once a step: every state-space layer visits the same slots
 
     def mixer(p, y, pools, m):
-        out, ssm, conv = mixer_step(p, y, pools.ssm, pools.conv, active, cfg, layer=m)
+        out, ssm, conv = mixer_step(p, y, pools.ssm, pools.conv, active, cfg, layer=m, live=live)
         return out, pools._replace(ssm=ssm, conv=conv)
 
     def attend(q, k, v, pools, a):

@@ -13,7 +13,7 @@ the state carried) and keeps what the row's decode steps start from: ``S``
 after the last REAL token and the last ``ssm_conv - 1`` real ``xBC`` columns
 (the prompt is right-padded to its bucket: at the padding ``dt`` and ``x``
 are 0, so ``S`` passes through).  A decode step (:func:`mixer_step`) is the
-recurrence once, over every slot of the batch (``ops/ssm.py``).  No backward
+recurrence once, over every LIVE slot of the batch (``ops/ssm.py``).  No backward
 pass is written: the block is served, not trained (ROADMAP queue 2).
 """
 
@@ -191,12 +191,15 @@ def mixer_prefill(p, u: jax.Array, prompt_len, cfg):
     return out[None], pack_state(state), columns.reshape(-1)
 
 
-def mixer_step(p, u: jax.Array, states: jax.Array, conv: jax.Array, active, cfg, *, layer: int):
+def mixer_step(p, u: jax.Array, states: jax.Array, conv: jax.Array, active, cfg, *, layer: int,
+               live):
     """One decode step of every slot: u [slots, 1, h]; ``states`` [M layers,
     slots, R, N, W] and ``conv`` [M layers, slots, (taps - 1) * conv_dim],
     of which state-space layer ``layer``'s are read and rewritten (both
     arrays come back whole); a slot that is not ``active`` keeps both as
-    they are.  -> (out [slots, 1, h], states, conv)."""
+    they are, and the state update does not visit it (``live``: the step's
+    ``ops.ssm.live_slots(active)``, made once for all its layers).
+    -> (out [slots, 1, h], states, conv)."""
     dtype = u.dtype
     taps, cd = cfg.ssm_conv, cfg.ssm_conv_dim
     z, xbc, dt = in_projection(p, u[:, 0], cfg)
@@ -210,7 +213,7 @@ def mixer_step(p, u: jax.Array, states: jax.Array, conv: jax.Array, active, cfg,
         conv = conv.at[layer].set(jnp.where(active[:, None], window[:, cd:], old))
     with jax.named_scope("pfx.ssm.step"):
         y, states = ssm_decode_update(
-            states, x, jnp.where(active[:, None], dt, 0.0), -jnp.exp(p["A_log"].astype(jnp.float32)),
-            b, c, p["D"], layer=layer)
+            states, x, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), b, c, p["D"],
+            active=active, layer=layer, live=live)
     out = gate_norm_out(p, y.reshape(y.shape[0], -1), z, cfg)
     return out[:, None], states, conv

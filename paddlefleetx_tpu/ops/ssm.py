@@ -13,6 +13,10 @@ kernel is bound by the HBM and a copy of the states must never be made:
 all the layers' states live in ONE array that the serving step carries
 through its layer loop, and the kernel rewrites one layer's slots through
 ``input_output_aliases`` (models/gpt/generation.py, docs/nemotron_h.md).
+For the same reason the kernel visits the LIVE slots only: its grid's first
+bound is the number of live slots of the step and every block address reads
+the slot from a compacted list (:func:`live_slots`), so a dead slot's state
+costs no traffic and is left as it lies, bit for bit.
 
 **Layout.**  The array is ``[layers, slots, R, state, W]``: the (head,
 head_dim) pairs of a slot flattened and cut into ``R`` lane groups of
@@ -71,12 +75,21 @@ def unpack_state(packed: jax.Array, heads: int, head_dim: int) -> jax.Array:
     return jnp.swapaxes(packed, -1, -2).reshape(*lead, heads, head_dim, n)
 
 
-def _decode_kernel(layer_ref, xdt_ref, dec_ref, dx_ref, bt_ref, ct_ref, s_ref, y_ref, out_ref,
-                   *, rb):
-    """One (slot, block of ``rb`` lane groups) grid step.  ``s_ref`` / ``out_ref``
+def live_slots(active: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The step's ``active`` mask [slots] -> (the live slots' numbers first,
+    ascending, [slots] int32, their count [1] int32): what ``pfx_ssm_decode``'s
+    grid and block addresses follow.  Every layer of a decode step shares one
+    mask, so the step makes this ONCE and hands it to each layer's update."""
+    live, = jnp.nonzero(active, size=active.shape[0], fill_value=0)
+    return live.astype(jnp.int32), jnp.sum(active, dtype=jnp.int32)[None]
+
+
+def _decode_kernel(layer_ref, live_ref, xdt_ref, dec_ref, dx_ref, bt_ref, ct_ref, s_ref, y_ref,
+                   out_ref, *, rb):
+    """One (live slot, block of ``rb`` lane groups) grid step.  ``s_ref`` / ``out_ref``
     [1, rb, state, W]: the same HBM pages (aliased); the small operands
     [1, 1, rb, W] rows and [1, 1, state, rb] columns."""
-    del layer_ref  # consumed by the index maps
+    del layer_ref, live_ref  # consumed by the index maps
     for k in range(rb):
         row = slice(k, k + 1)
         s = s_ref[0, k].astype(jnp.float32)  # [state, W]
@@ -91,7 +104,7 @@ def _lane_rows(v: jax.Array, nblk: int, rb: int, w: int) -> jax.Array:
     return v.reshape(v.shape[0], nblk, rb, w)
 
 
-def _decode_pallas(states, layer, xdt, dec, dx, b_group, c_group, heads, head_dim):
+def _decode_pallas(states, layer, live, xdt, dec, dx, b_group, c_group, heads, head_dim):
     from jax.experimental.pallas import tpu as pltpu
 
     _, slots, r, n, w = states.shape
@@ -111,23 +124,28 @@ def _decode_pallas(states, layer, xdt, dec, dx, b_group, c_group, heads, head_di
         return jnp.take(v.astype(jnp.float32), of, axis=1).reshape(
             slots, nblk, rb, n).swapaxes(-1, -2)
 
-    rows = pl.BlockSpec((1, 1, rb, w), lambda i, j, *_: (i, j, 0, 0))
-    cols = pl.BlockSpec((1, 1, n, rb), lambda i, j, *_: (i, j, 0, 0))
+    # grid step i is the i-th LIVE slot: the grid's first bound is the live
+    # count (no step, no traffic for a dead slot; none at all with no slot
+    # live), and ``y`` of a slot that is not visited is never written
+    live, count = live
+    rows = pl.BlockSpec((1, 1, rb, w), lambda i, j, _, live_ref: (live_ref[i], j, 0, 0))
+    cols = pl.BlockSpec((1, 1, n, rb), lambda i, j, _, live_ref: (live_ref[i], j, 0, 0))
     # the states enter WHOLE, all layers: the block address carries the
     # layer, and the other layers' pages are never touched
-    page = pl.BlockSpec((None, 1, rb, n, w), lambda i, j, layer_ref: (layer_ref[0], i, j, 0, 0))
+    page = pl.BlockSpec((None, 1, rb, n, w),
+                        lambda i, j, layer_ref, live_ref: (layer_ref[0], live_ref[i], j, 0, 0))
     y, states = pl.pallas_call(
         functools.partial(_decode_kernel, rb=rb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(slots, nblk),
+            num_scalar_prefetch=2, grid=(count[0], nblk),
             in_specs=[rows, rows, rows, cols, cols, page],
             out_specs=[rows, page]),
         out_shape=[jax.ShapeDtypeStruct((slots, nblk, rb, w), jnp.float32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
-        input_output_aliases={6: 1},
+        input_output_aliases={7: 1},
         interpret=_device.pallas_interpret(),
         name="pfx_ssm_decode",
-    )(layer[None], _lane_rows(xdt, nblk, rb, w), _lane_rows(dec, nblk, rb, w),
+    )(layer[None], live, _lane_rows(xdt, nblk, rb, w), _lane_rows(dec, nblk, rb, w),
       _lane_rows(dx, nblk, rb, w), columns(b_group), columns(c_group), states)
     return y.reshape(slots, heads, head_dim), states
 
@@ -167,31 +185,33 @@ def write_slot_states(states: jax.Array, new: jax.Array, slot, *, impl: str = "a
     )(slot[None], new, states)
 
 
-def _decode_lax(states, layer, xdt, dec, dx, b_group, c_group, heads, head_dim):
+def _decode_lax(states, layer, active, xdt, dec, dx, b_group, c_group, heads, head_dim):
     slots = states.shape[1]
     per_group = heads // b_group.shape[1]
-    s = unpack_state(jax.lax.dynamic_index_in_dim(states, layer, keepdims=False),
-                     heads, head_dim).astype(jnp.float32)
+    held = jax.lax.dynamic_index_in_dim(states, layer, keepdims=False)
+    s = unpack_state(held, heads, head_dim).astype(jnp.float32)
     b_h = jnp.repeat(b_group.astype(jnp.float32), per_group, axis=1)  # [slots, heads, state]
     c_h = jnp.repeat(c_group.astype(jnp.float32), per_group, axis=1)
     pairs = (slots, heads, head_dim)
     s = s * dec.reshape(pairs)[..., None] + xdt.reshape(pairs)[..., None] * b_h[:, :, None, :]
     y = jnp.einsum("bhpn,bhn->bhp", s, c_h, precision=jax.lax.Precision.HIGHEST)
-    states = jax.lax.dynamic_update_index_in_dim(
-        states, pack_state(s).astype(states.dtype), layer, axis=0)
-    return y + dx.reshape(pairs), states
+    new = jnp.where(active[:, None, None, None], pack_state(s).astype(states.dtype), held)
+    return y + dx.reshape(pairs), jax.lax.dynamic_update_index_in_dim(states, new, layer, axis=0)
 
 
 def ssm_decode_update(states: jax.Array, x: jax.Array, dt: jax.Array, a: jax.Array,
                       b_group: jax.Array, c_group: jax.Array, d: jax.Array, *,
-                      layer, impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
-    """One recurrence step of every slot of state-space layer ``layer``.
+                      active: jax.Array, layer, live=None,
+                      impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
+    """One recurrence step of every LIVE slot of state-space layer ``layer``.
 
     ``states`` [layers, slots, R, state, W] (see the module's doc), rewritten
     in place: the array that comes back IS the states.  ``x`` [slots, heads,
-    head_dim]; ``dt`` [slots, heads] >= 0, after its softplus (a slot whose
-    ``dt`` is 0 keeps its state: ``exp(0) S + 0``); ``a`` [heads] < 0;
-    ``b_group`` / ``c_group`` [slots, groups, state]; ``d`` [heads].
+    head_dim]; ``dt`` [slots, heads] >= 0, after its softplus; ``a`` [heads]
+    < 0; ``b_group`` / ``c_group`` [slots, groups, state]; ``d`` [heads].
+    ``active`` [slots] bool: a slot that is not active is not visited, its
+    state stays as it is to the bit and its ``y`` is 0.  ``live``: what
+    :func:`live_slots` makes of ``active``, from a caller that has it already.
     -> (y [slots, heads, head_dim] float32, states).  ``impl``: "auto"
     (Pallas ``pfx_ssm_decode`` on a TPU, ``jnp`` on the CPU) | "pallas" | "lax".
     """
@@ -202,12 +222,18 @@ def ssm_decode_update(states: jax.Array, x: jax.Array, dt: jax.Array, a: jax.Arr
             heads, head_dim, b_group.shape[-1]):
         raise ValueError(f"states {states.shape} do not hold {slots} slots of "
                          f"{packed_shape(heads, head_dim, b_group.shape[-1])}")
+    if active.shape != (slots,) or active.dtype != jnp.bool_:
+        raise ValueError(f"active {active.dtype}{active.shape}: one bool a slot, [{slots}]")
     layer = jnp.asarray(layer, jnp.int32)
     xf, dt = x.astype(jnp.float32), dt.astype(jnp.float32)
     flat = (slots, heads * head_dim)
     xdt = (xf * dt[:, :, None]).reshape(flat)
     dec = jnp.broadcast_to(jnp.exp(dt * a.astype(jnp.float32))[:, :, None], x.shape).reshape(flat)
     dx = (xf * d.astype(jnp.float32)[None, :, None]).reshape(flat)
-    use_pallas = impl == "pallas" or (impl == "auto" and not _device.pallas_interpret())
-    fn = _decode_pallas if use_pallas else _decode_lax
-    return fn(states, layer, xdt, dec, dx, b_group, c_group, heads, head_dim)
+    operands = (xdt, dec, dx, b_group, c_group, heads, head_dim)
+    if impl == "pallas" or (impl == "auto" and not _device.pallas_interpret()):
+        y, states = _decode_pallas(
+            states, layer, live_slots(active) if live is None else live, *operands)
+    else:
+        y, states = _decode_lax(states, layer, active, *operands)
+    return jnp.where(active[:, None, None], y, 0.0), states

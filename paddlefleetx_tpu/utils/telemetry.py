@@ -273,8 +273,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_moe_serve_pairs_total": ("counter", "Serving: token-expert pairs the expert layers routed for live rows and real prompt tokens, over all experts and layers (prefills and decode steps)"),
     "pfx_moe_serve_held_pairs_total": ("counter", "Serving: routed pairs that landed on experts this process holds"),
     "pfx_moe_serve_held_max_pairs_total": ("counter", "Serving: the fullest held expert's pairs x experts held, summed over layers and dispatches (over pfx_moe_serve_held_pairs_total: max over mean)"),
-    "pfx_ssm_row_steps_total": ("counter", "Serving: live (row, decode step) pairs x state-space layers: the state updates the traffic needed"),
-    "pfx_ssm_slot_steps_total": ("counter", "Serving: batch slots x decode steps x state-space layers: the state updates the kernel walked (it runs every slot, live or not)"),
+    "pfx_ssm_row_steps_total": ("counter", "Serving: live (row, decode step) pairs x state-space layers: the state updates the traffic needed, which are the ones the kernel visits"),
+    "pfx_ssm_slot_steps_total": ("counter", "Serving: batch slots x decode steps x state-space layers: the capacity the live pairs are a share of (the kernel skips the rest)"),
     "pfx_ssm_prefill_tokens_total": ("counter", "Serving: prompt tokens x state-space layers the chunked scan of the prefills computed"),
     "pfx_token_ledger_total": ("counter", "Admitted-token dispositions (labels: disposition=admitted|delivered|evicted_lost|preempt_refunded|shed_after_admit)"),
     "pfx_token_ledger_in_flight": ("gauge", "Admitted tokens still on the books in live decode slots (the exact-closure remainder)"),

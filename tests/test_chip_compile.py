@@ -646,28 +646,50 @@ def test_decode_step_13b_writes_the_donated_arena_in_place(topo, kv_dtype, t):
 # ---------------------------------------------------------------------------
 
 
+def _ssm_decode_calls(text):
+    """The compiled program's custom calls named as the trace names them: the
+    third operand of each (after the grid's bound and the layer) is the live list."""
+    import re
+
+    return re.findall(r"%pfx_ssm_decode\S* = [^\n]*tpu_custom_call", text), set(
+        re.findall(r"%pfx_ssm_decode\S* = [^\n]*custom-call\(\S+ \S+ (\S+),", text))
+
+
 def test_ssm_state_kernels_rewrite_the_states_in_place(topo):
-    """``pfx_ssm_decode`` over two layers of the 23 x 48 slots' states (2.3 GB,
-    donated) and ``pfx_ssm_write`` of one slot: the programs need NO scratch —
-    a copy of the states does not fit beside 10.5 GB of weights."""
+    """``pfx_ssm_decode`` over the LIVE slots of two layers of the 23 x 48
+    slots' states (2.3 GB, donated; the live list made once for both) and
+    ``pfx_ssm_write`` of one slot: the programs need NO scratch and copy
+    nothing state-shaped — a copy of the states does not fit beside 10.5 GB
+    of weights."""
+    import re
+
     from paddlefleetx_tpu.ops import ssm
 
     one = _one_chip(topo)
     slots, heads, hd, n, groups = 48, 64, 64, 128, 8
     states = _shapes(one, ((23, slots) + ssm.packed_shape(heads, hd, n), jnp.float32))
 
-    def two_layers(st, x, dt, a, b, c, d):
+    def two_layers(st, x, dt, a, b, c, d, active):
+        live = ssm.live_slots(active)
         for layer in range(2):
-            y, st = ssm.ssm_decode_update(st, x, dt, a, b, c, d, layer=layer)
+            y, st = ssm.ssm_decode_update(st, x, dt, a, b, c, d, active=active, layer=layer,
+                                          live=live)
             x = x + y.astype(x.dtype)
         return y, st
 
     args = _shapes(one, (((slots, heads, hd), BF16), ((slots, heads), jnp.float32),
                          ((heads,), jnp.float32), ((slots, groups, n), BF16),
-                         ((slots, groups, n), BF16), ((heads,), jnp.float32)))
+                         ((slots, groups, n), BF16), ((heads,), jnp.float32),
+                         ((slots,), jnp.bool_)))
     c = jax.jit(two_layers, donate_argnums=(0,)).lower(states, *args).compile()
-    assert c.as_text().count("tpu_custom_call") == 2
-    assert c.memory_analysis().temp_size_in_bytes < 1e6
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 2
+    calls, lists = _ssm_decode_calls(text)
+    assert len(calls) == 2 and len(lists) == 1, lists  # the trace's name; ONE live list for both
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 1e6 and m.alias_size_in_bytes >= 23 * slots * 2 ** 21
+    moved = re.findall(r"= \w+\[23,48,32,128,128\]\S* (copy|transpose|dynamic-update-slice)\(", text)
+    assert not moved, moved
     new = _shapes(one, ((23,) + ssm.packed_shape(heads, hd, n), jnp.float32))
     slot = _shapes(one, ((), jnp.int32))
     w = jax.jit(ssm.write_slot_states, donate_argnums=(0,)).lower(states, new, slot).compile()
@@ -730,5 +752,8 @@ def test_decode_step_of_the_whole_depth_pattern_block_fits_and_copies_nothing(to
     assert 13.3e9 < m.argument_size_in_bytes < 13.6e9 and m.temp_size_in_bytes < 0.2e9
     text = c.as_text()
     assert text.count("tpu_custom_call") == 23 + 6  # a state update a Mamba layer, a paged read a * layer
+    calls, lists = _ssm_decode_calls(text)
+    # the live list is made once: 23 calls read ONE compacted list of slots
+    assert len(calls) == 23 and len(lists) == 1, lists
     moved = re.findall(r"= \w+\[(?:23,48,32,128,128|6,673,2,128,128)\]\S* (copy|transpose)\(", text)
     assert not moved, moved

@@ -240,6 +240,66 @@ def test_prefill_and_decode_through_the_engine_equal_the_full_forward(server):
     assert bool(jnp.isfinite(eng.pools.ssm).all()) and bool(jnp.isfinite(eng.pools.conv).all())
 
 
+def test_served_tokens_do_not_depend_on_what_the_dead_slots_hold(server):
+    """The state update visits the live slots only, so what a dead slot holds
+    can neither move a live row nor be moved: the same request served with
+    the other slots EMPTY (zeros, never written) and with the other slots
+    holding finished rows' stale states gives the same tokens and, step by
+    step, the same state to the bit; the stale slots come through every step
+    they are skipped in bit for bit; and a row admitted into a slot that was
+    skipped for many steps starts from its prefill's state, not the stale one."""
+    rng = np.random.default_rng(23)
+    p, q = (rng.integers(1, 96, size=n).tolist() for n in (17, 6))
+    junk = [rng.integers(1, 96, size=n).tolist() for n in (9, 14, 3)]
+
+    def row_state(eng, slot):
+        return np.asarray(eng.pools.ssm[:, slot]), np.asarray(eng.pools.conv[:, slot])
+
+    def serve(eng, wanted, joins=()):
+        """Step until the ``wanted`` slots finish; ``joins``: (after step, prompt,
+        max_new) admissions on the way.  -> {slot: tokens}, {slot: [state a step]}."""
+        tokens, states, joins, n = {}, {s: [row_state(eng, s)] for s in wanted}, list(joins), 0
+        while set(wanted) - set(tokens):
+            for at, prompt, max_new in [j for j in joins if j[0] == n]:
+                wanted.append(eng.admit(prompt, max_new))
+                states[wanted[-1]] = [row_state(eng, wanted[-1])]
+            live = [s for s in wanted if s not in tokens]
+            finished = eng.step()
+            n += 1
+            for s in live:
+                states[s].append(row_state(eng, s))
+            for s in finished:
+                tokens[s] = list(eng.slots[s].tokens)
+                eng.release(s)
+        return tokens, states
+
+    alone = _engine(server, max_batch=3)
+    slot = alone.admit(p, 12)
+    assert slot == 0
+    want_p, want_states = serve(alone, [slot])
+    assert not np.asarray(alone.pools.ssm[:, 1:]).any() and not np.asarray(alone.pools.conv[:, 1:]).any()
+    slot = alone.admit(q, 5)
+    want_q, want_q_states = serve(alone, [slot])
+
+    eng = _engine(server, max_batch=3)
+    serve(eng, [eng.admit(j, 4 + i) for i, j in enumerate(junk)])  # three rows come and go
+    assert all(r is None for r in eng.slots)
+    stale = [row_state(eng, s) for s in range(3)]
+    assert all(np.abs(st).max() > 1e-3 and np.abs(cv).max() > 1e-3 for st, cv in stale)
+    slot = eng.admit(p, 12)
+    assert slot == 0
+    got, states = serve(eng, [slot], joins=[(7, q, 5)])  # q joins slot 1, skipped in each of these 7 steps
+    assert got[0] == want_p[0] and got[1] == want_q[0] and len(got[0]) == 12 and len(got[1]) == 5
+    for mine, want in ((states[0], want_states[0]), (states[1], want_q_states[0])):
+        assert len(mine) == len(want) > 5  # after the prefill and after every step
+        for (st, cv), (wst, wcv) in zip(mine, want):
+            assert (st == wst).all() and (cv == wcv).all()
+    assert all((a == b).all() for a, b in zip(row_state(eng, 2), stale[2]))  # never live since: as it was
+    lg = np.asarray(ref.logits(server.params, jnp.asarray([q + got[1]]), TOY))[0, len(q) - 1:-1].copy()
+    lg[:, 0] = -np.inf
+    assert lg.argmax(-1).tolist() == got[1]  # the reference's greedy tokens from q's own prefill
+
+
 def test_the_scheduler_serves_and_counts(server):
     """(f) requests through ContinuousScheduler: every served token is the
     reference's greedy choice; the new counters and gauges are on its page."""
@@ -276,6 +336,14 @@ def test_the_scheduler_serves_and_counts(server):
 # -- (c) the kernels in interpret mode ---------------------------------------------
 
 
+def _recurrence(held, x, dt, a, b, c, d):
+    """One step of every slot by the equations: held [slots, heads, P, N],
+    b / c [slots, groups, N] -> (the new state, y)."""
+    b_h, c_h = (jnp.repeat(v, held.shape[1] // v.shape[1], axis=1) for v in (b, c))
+    s = held * jnp.exp(dt * a)[:, :, None, None] + (x * dt[:, :, None])[..., None] * b_h[:, :, None]
+    return s, jnp.einsum("bhpn,bhn->bhp", s, c_h, precision="highest") + d[None, :, None] * x
+
+
 @pytest.mark.parametrize("layers,slots,heads,hd,n,groups,dtype", [
     (3, 4, 8, 64, 16, 2, jnp.float32),   # 4 lane groups of 128, 2 heads each, 4 heads a B/C group
     (2, 3, 4, 8, 16, 1, jnp.float32),    # a toy: one lane group of 32
@@ -283,8 +351,8 @@ def test_the_scheduler_serves_and_counts(server):
     (2, 3, 8, 64, 16, 2, jnp.bfloat16),  # a bfloat16 state (the configuration states float32)
 ])
 def test_the_ssm_decode_kernel_equals_jnp(layers, slots, heads, hd, n, groups, dtype):
-    """(c) pfx_ssm_decode: the layer's slots rewritten in place, the other
-    layers untouched, a slot whose dt is 0 keeping its state; pfx_ssm_write
+    """(c) pfx_ssm_decode: the layer's live slots rewritten in place, the other
+    layers untouched, a slot that is not active keeping its state; pfx_ssm_write
     overwrites one slot of every layer."""
     ks = jax.random.split(jax.random.PRNGKey(0), 7)
     state = jax.random.normal(ks[0], (layers, slots, heads, hd, n), jnp.float32)
@@ -292,33 +360,97 @@ def test_the_ssm_decode_kernel_equals_jnp(layers, slots, heads, hd, n, groups, d
     assert packed.shape == (layers, slots) + ssm_ops.packed_shape(heads, hd, n)
     assert bool((ssm_ops.unpack_state(ssm_ops.pack_state(state), heads, hd) == state).all())
     x = jax.random.normal(ks[1], (slots, heads, hd))
-    dt = jax.nn.softplus(jax.random.normal(ks[2], (slots, heads))).at[1].set(0.0)
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (slots, heads)))
+    active = jnp.ones((slots,), bool).at[1].set(False)
     a = -jnp.exp(jax.random.normal(ks[3], (heads,)))
     b, c = (jax.random.normal(k, (slots, groups, n)) for k in ks[4:6])
     d = jax.random.normal(ks[6], (heads,))
     layer = layers - 1
     held = ssm_ops.unpack_state(packed[layer].astype(jnp.float32), heads, hd)
-    b_h, c_h = (jnp.repeat(v, heads // groups, axis=1) for v in (b, c))
-    want_s = held * jnp.exp(dt * a)[:, :, None, None] + (x * dt[:, :, None])[..., None] * b_h[:, :, None]
-    want_y = jnp.einsum("bhpn,bhn->bhp", want_s, c_h, precision="highest") + d[None, :, None] * x
+    want_s, want_y = _recurrence(held, x, dt, a, b, c, d)
     tol = F32_ROUNDINGS if dtype == jnp.float32 else 0.05
     for impl in ("lax", "pallas"):
-        y, new = ssm_ops.ssm_decode_update(packed, x, dt, a, b, c, d, layer=layer, impl=impl)
-        assert float(jnp.max(jnp.abs(y - want_y))) < F32_ROUNDINGS * 8, impl  # sums of up to 64 terms
+        y, new = ssm_ops.ssm_decode_update(packed, x, dt, a, b, c, d, active=active, layer=layer,
+                                           impl=impl)
+        assert float(jnp.max(jnp.abs(y - want_y)[active])) < F32_ROUNDINGS * 8, impl  # sums of up to 64 terms
         got = ssm_ops.unpack_state(new[layer].astype(jnp.float32), heads, hd)
-        assert float(jnp.max(jnp.abs(got - want_s))) < tol, impl
+        assert float(jnp.max(jnp.abs(got - want_s)[active])) < tol, impl
         assert bool((new[0] == packed[0]).all()) and bool((got[1] == held[1]).all()), impl
+        assert bool((y[1] == 0).all()), impl
     fresh = jax.random.normal(ks[5], (layers,) + packed.shape[2:], jnp.float32)
     for impl in ("lax", "pallas"):
         out = ssm_ops.write_slot_states(packed, fresh, 2, impl=impl)
         assert bool((out[:, 2] == fresh.astype(dtype)).all()) and bool((out[:, 0] == packed[:, 0]).all())
 
 
+SENTINEL = -3.5  # what a slot the kernel must not visit holds: finite, and no state's value
+
+LIVE_MASKS = {  # over 6 slots
+    "none": [0, 0, 0, 0, 0, 0], "first": [1, 0, 0, 0, 0, 0], "last": [0, 0, 0, 0, 0, 1],
+    "alternating": [0, 1, 0, 1, 0, 1], "a-third": [0, 0, 1, 0, 1, 0], "all": [1, 1, 1, 1, 1, 1],
+}
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("mask", list(LIVE_MASKS))
+@pytest.mark.parametrize("heads,hd,n,groups", [
+    pytest.param(4, 8, 16, 1, id="toy"),  # one lane group of 32
+    pytest.param(64, 64, 128, 8, id="published"),  # one layer's widths: 32 lane groups, 2 blocks of 16
+])
+def test_the_ssm_decode_kernel_visits_the_live_slots_only(heads, hd, n, groups, mask, layer):
+    """pfx_ssm_decode's contract, both spellings under jit: a live slot's state
+    and y are the recurrence's (and the all-live case the whole batch's, as
+    before the kernel followed the live rows); EVERY dead slot's state and
+    every other layer's pages come back bit for bit (they hold a sentinel that
+    any arithmetic would move); a dead row's y is exactly 0, never what an
+    unvisited output block holds; no slot live at all changes nothing."""
+    slots, layers = 6, 3
+    active = jnp.asarray(LIVE_MASKS[mask], bool)
+    ks = jax.random.split(jax.random.PRNGKey(slots * layer + sum(LIVE_MASKS[mask])), 7)
+    visited = jnp.zeros((layers, slots), bool).at[layer].set(active)[:, :, None, None, None]
+    state = jnp.where(visited, jax.random.normal(ks[0], (layers, slots, heads, hd, n)), SENTINEL)
+    packed = ssm_ops.pack_state(state)
+    x = jax.random.normal(ks[1], (slots, heads, hd))
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (slots, heads)))
+    a = -jnp.exp(jax.random.normal(ks[3], (heads,)))
+    b, c = (jax.random.normal(k, (slots, groups, n)) for k in ks[4:6])
+    d = jax.random.normal(ks[6], (heads,))
+    want_s, want_y = _recurrence(state[layer], x, dt, a, b, c, d)
+    got = {}
+    for impl in ("lax", "pallas"):
+        step = jax.jit(lambda st, act, impl=impl: ssm_ops.ssm_decode_update(
+            st, x, dt, a, b, c, d, active=act, layer=layer, live=ssm_ops.live_slots(act), impl=impl))
+        y, new = got[impl] = step(packed, active)
+        assert new.shape == packed.shape and new.dtype == jnp.float32 and y.shape == x.shape
+        s = ssm_ops.unpack_state(new, heads, hd)
+        assert bool((jnp.where(visited, SENTINEL, s) == SENTINEL).all()), impl  # bit for bit
+        assert bool((y[~active] == 0).all()) and bool(jnp.isfinite(y).all()), impl
+        assert float(jnp.max(jnp.abs(jnp.where(visited[layer], s[layer] - want_s, 0)))) < F32_ROUNDINGS, impl
+        assert float(jnp.max(jnp.abs(jnp.where(active[:, None, None], y - want_y, 0)))) \
+            < F32_ROUNDINGS * 8, impl  # sums of up to 128 terms
+    # the two spellings differ by the order of y's sum over the state alone
+    assert float(jnp.max(jnp.abs(got["lax"][1] - got["pallas"][1]))) < 1e-6
+
+
+def test_the_live_list_is_the_mask_compacted():
+    live, count = ssm_ops.live_slots(jnp.asarray([0, 1, 1, 0, 1], bool))
+    assert live.dtype == count.dtype == jnp.int32 and count.shape == (1,)
+    assert live[:3].tolist() == [1, 2, 4] and int(count[0]) == 3
+    live, count = ssm_ops.live_slots(jnp.zeros((4,), bool))
+    assert int(count[0]) == 0 and bool((live < 4).all()) and bool((live >= 0).all())
+    with pytest.raises(ValueError, match="one bool a slot"):
+        ssm_ops.ssm_decode_update(
+            jnp.zeros((1, 2, 1, 16, 32)), jnp.zeros((2, 4, 8)), jnp.zeros((2, 4)), -jnp.ones((4,)),
+            jnp.zeros((2, 2, 16)), jnp.zeros((2, 2, 16)), jnp.ones((4,)), active=jnp.ones((3,), bool),
+            layer=0)
+
+
 def test_the_ssm_kernel_refuses_heads_that_straddle_a_group():
     with pytest.raises(ValueError, match="lax"):
         ssm_ops.ssm_decode_update(
             jnp.zeros((1, 2, 1, 16, 128)), jnp.zeros((2, 4, 32)), jnp.zeros((2, 4)), -jnp.ones((4,)),
-            jnp.zeros((2, 2, 16)), jnp.zeros((2, 2, 16)), jnp.ones((4,)), layer=0, impl="pallas")
+            jnp.zeros((2, 2, 16)), jnp.zeros((2, 2, 16)), jnp.ones((4,)), active=jnp.ones((2,), bool),
+            layer=0, impl="pallas")
 
 
 @pytest.mark.parametrize("n,kv,t,bs,width", [
