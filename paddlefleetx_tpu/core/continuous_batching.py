@@ -140,6 +140,76 @@ class ArenaReset(RuntimeError):
         self.dead_rows = dead_rows
 
 
+class TokenGapBooks:
+    """The token gap's books (docs/observability.md "Goodput ledger").
+
+    A row's token gap is the time between two consecutive commits that
+    each delivered it a frame.  Every gap is booked once, at the later
+    commit, under what the interval between the two held: ``admission``
+    (a prefill, a prefill chunk, an adoption or a COW copy was
+    dispatched in it: the device spent part of it on somebody else's
+    prompt), ``flush`` (no admission, but the step in flight was
+    committed early for a membership change: the next step ran after
+    the host instead of under it) or ``decode`` (neither).  A row's
+    first frame, and its first after a commit it sat out (a chunked
+    prefill, a preemption), is no gap and books nothing.
+
+    Passive: fed by the scheduler thread alone, from stamps the loop
+    already takes (``commit``: where the readback span ends), and read
+    by ``ContinuousScheduler.collect`` without a lock.  It dispatches
+    and fetches nothing, and a fault inside it is counted in ``errors``
+    (``pfx_sched_gap_books_errors_total``) and logged once, never
+    raised into the decode loop."""
+
+    HELD = ("decode", "admission", "flush")
+
+    def __init__(self) -> None:
+        self.gaps: Dict[str, int] = dict.fromkeys(self.HELD, 0)
+        self.seconds: Dict[str, float] = dict.fromkeys(self.HELD, 0.0)
+        # host seconds of the admission path (the scheduler's stamps):
+        # pfx_sched_admit_host_seconds_total
+        self.admit_host_s = 0.0
+        self.errors = 0
+        self._held = "decode"
+        self._t_prev = 0.0
+        self._prev: frozenset = frozenset()  # seq ids framed last commit
+
+    def note(self, held: str) -> None:
+        """Something other than decoding happened since the last commit:
+        an admission always marks the interval, a flush only one that
+        holds no admission."""
+        if held == "admission" or self._held == "decode":
+            self._held = held
+
+    def commit(self, t: float, framed: Sequence[int]) -> None:
+        """One commit at stamp ``t`` delivered a frame to the rows
+        ``framed`` (seq ids): those framed at the previous commit too
+        book one gap each under what the interval held."""
+        try:
+            now = frozenset(framed)
+            n = len(now & self._prev)
+            if n:
+                self.gaps[self._held] += n
+                self.seconds[self._held] += n * (t - self._t_prev)
+            self._prev, self._t_prev, self._held = now, t, "decode"
+        except Exception as exc:  # noqa: BLE001 — the books never fail a step
+            self._fault(exc)
+
+    def admit_host(self, seconds: float) -> None:
+        """Host seconds of one iteration's admissions, device unqueued."""
+        self.admit_host_s += max(0.0, seconds)
+
+    def _fault(self, exc: BaseException) -> None:
+        # the interval in progress is lost, not mis-booked
+        self._prev, self._held = frozenset(), "decode"
+        self.errors += 1
+        if self.errors == 1:
+            logger.warning(
+                f"token-gap books: {type(exc).__name__}: {exc}; counted in "
+                "pfx_sched_gap_books_errors_total, serving goes on"
+            )
+
+
 @dataclasses.dataclass(eq=False)
 class _Row:
     """One active decode row (slot) in the running batch."""
@@ -357,9 +427,9 @@ class PagedDecodeEngine:
         # "prefill_tokens" counts prompt tokens actually COMPUTED — a
         # prefix hit's shared span never enters it, the reuse evidence;
         # "prefill_chunks" counts chunk dispatches)
-        # ("host_gap_s"/"gap_steps" measure host time the device sat
-        # idle between consuming one step's results and receiving the
-        # next dispatch — the benchmark's sched.host_gap_share)
+        # ("host_gap_s" measures host time the device sat idle between
+        # consuming one step's results and receiving the next dispatch —
+        # the benchmark's sched.host_gap_share)
         # (goodput time-ledger accumulators: wall time THIS thread spent
         # in each phase — t_device_decode covers decode dispatches,
         # t_device_prefill every donating dispatch (prefill / chunk /
@@ -380,7 +450,7 @@ class PagedDecodeEngine:
             "spec_proposed": 0, "spec_accepted": 0,
             "exports": 0, "adopts": 0,
             "prefill_tokens": 0, "prefill_chunks": 0,
-            "host_gap_s": 0.0, "gap_steps": 0,
+            "host_gap_s": 0.0,
             "migrate_adopted": 0,
             "t_device_decode": 0.0, "t_device_prefill": 0.0,
             "t_readback": 0.0, "t_stream_flush": 0.0,
@@ -417,6 +487,10 @@ class PagedDecodeEngine:
         self._inflight: Optional[Dict[str, Any]] = None
         self._t_results: Optional[float] = None
         self._moe_pending: List[Any] = []  # expert counts not fetched yet
+        # every token gap booked by what its interval held (TokenGapBooks):
+        # fed here at each commit and each donating dispatch, by the
+        # scheduler at a flush and an admission, exported by its collect()
+        self.gap_books = TokenGapBooks()
 
     def _init_device_state(self) -> None:
         """Fresh arena + per-row device state (boot and every ArenaReset),
@@ -874,7 +948,11 @@ class PagedDecodeEngine:
         chunk dispatches so the recovery contract cannot drift between
         them.  Time ledger: every donating dispatch is prefill-side
         device work (decode steps go through _dispatch instead);
-        ``span_args`` ride the ``pfx.sched.prefill`` trace span."""
+        ``span_args`` ride the ``pfx.sched.prefill`` trace span.  Token-gap
+        books: the interval between two commits that holds one of these
+        is an ``admission`` interval."""
+        if not self._warmup:
+            self.gap_books.note("admission")
         with ledger_span("pfx.sched.prefill", self.stats, "t_device_prefill",
                          what=what, **span_args):
             try:
@@ -1636,7 +1714,6 @@ class PagedDecodeEngine:
             self.stats["host_gap_s"] += max(
                 0.0, time.monotonic() - self._t_results
             )
-            self.stats["gap_steps"] += 1
         # host-fed row state and a chained dispatch's device-side handles
         # must type alike, or each width bucket keys TWO compiles — the
         # warmed host-fed one and a chained one first paid mid-traffic
@@ -1719,6 +1796,7 @@ class PagedDecodeEngine:
             ) from exc
         self._t_results = rb.t1
         was_active = fl["was_active"]
+        framed: List[int] = []  # scheduler-owned rows this commit frames
         # merge, never overwrite: slots that joined (admit/adopt) or
         # left (release/evict) after the dispatch were not part of it —
         # the step carried their stale state through, and their fresh
@@ -1763,6 +1841,8 @@ class PagedDecodeEngine:
                 # preempt_refunded / shed_after_admit).  EOS never
                 # appends, so it never enters the books.
                 self.stats["ledger_admitted"] += len(r.tokens) - start
+                if len(r.tokens) > start and not self._warmup:
+                    framed.append(r.seq_id)
             if (len(r.tokens) > start and not self._warmup
                     and r.entry is not None and r.entry.stream is not None):
                 # token streaming: push this step's commits as they
@@ -1791,6 +1871,9 @@ class PagedDecodeEngine:
                 )
             if not new_active[i]:
                 finished.append(i)
+        # the commit's stamp is where the readback span ended: the same
+        # time.monotonic() the pfx.sched.readback span took
+        self.gap_books.commit(rb.t1, framed)
         if self.spec and n_act and not self._warmup:
             proposed = fl["k"] * n_act
             accepted = int(ncommit[was_active].sum()) - n_act
@@ -2155,6 +2238,7 @@ class ContinuousScheduler:
             "stream_flush": 0.0, "idle": 0.0,
         }
         self._sched_wall_s = 0.0
+        self._t_device_free = 0.0  # _iterate_inner / _flush_engine stamp
         # Tokens: bank accounting over ADMITTED (committed) tokens.
         # admitted == delivered + evicted_lost + preempt_refunded +
         # shed_after_admit + (tokens still on live rows) holds EXACTLY
@@ -2270,6 +2354,25 @@ class ContinuousScheduler:
         out.append((
             "pfx_sched_host_gap_seconds_total", {},
             round(float(eng.stats["host_gap_s"]), 6),
+        ))
+        # the token gap's books: every gap between two frames of a row,
+        # counted once under what its interval held (TokenGapBooks)
+        books = eng.gap_books
+        for held in books.HELD:
+            out.append((
+                "pfx_sched_token_gaps_total", {"held": held},
+                float(books.gaps[held]),
+            ))
+            out.append((
+                "pfx_sched_token_gap_seconds_total", {"held": held},
+                round(books.seconds[held], 6),
+            ))
+        out.append((
+            "pfx_sched_admit_host_seconds_total", {},
+            round(books.admit_host_s, 6),
+        ))
+        out.append((
+            "pfx_sched_gap_books_errors_total", {}, float(books.errors),
         ))
         # work counted at the commit of every decode step (engine stats)
         for key, name in (
@@ -2557,7 +2660,6 @@ class ContinuousScheduler:
                 "quantum": self.quantum,
                 "inflight": eng.has_inflight,
                 "host_gap_s": round(float(eng.stats["host_gap_s"]), 6),
-                "gap_steps": int(eng.stats["gap_steps"]),
             },
             "compiled": {
                 "prefill_families": len(eng._compiled_prefill),
@@ -2978,6 +3080,10 @@ class ContinuousScheduler:
         eng = self.engine
         now = time.monotonic()
         n_finished = 0
+        # since when the device has nothing queued, for the admission
+        # path's host seconds: the iteration's start, or the end of the
+        # flush that committed the step in flight (_flush_engine)
+        self._t_device_free = now
 
         # k-step scheduling quantum (PFX_SCHED_QUANTUM, default 1 =
         # every iteration): the shed/evict/admission scans below run on
@@ -3210,6 +3316,7 @@ class ContinuousScheduler:
                             self._entries.remove(head)
 
         # prefill-on-admit (outside the lock: device work)
+        t_admitted: Optional[float] = None  # the last admission dispatched
         for entry, row_idx, prompt, mx, resumed in admitted:
             if entry.future.done():
                 continue  # an earlier row of this entry already failed
@@ -3227,6 +3334,7 @@ class ContinuousScheduler:
                     eng.adopt(meta, arrays, entry=entry, row_idx=row_idx)
                 else:
                     eng.admit(prompt, mx, entry=entry, row_idx=row_idx)
+                t_admitted = time.monotonic()
                 if resumed:
                     # token ledger: a resume re-admits the prefix its
                     # preemption refunded — the tokens are back on the
@@ -3271,6 +3379,8 @@ class ContinuousScheduler:
                     f"{type(exc).__name__}: {exc}"
                 )
 
+        if t_admitted is not None:
+            eng.gap_books.admit_host(t_admitted - self._t_device_free)
         if not self._has_live_rows():
             return n_finished
         return n_finished + self._step_batch()
@@ -3310,6 +3420,12 @@ class ContinuousScheduler:
             self._fail_rows(exc.dead_rows, exc)
             logger.warning(f"{self.name}: {exc}")
             return 0
+        # the step in flight was committed early: the next one is
+        # dispatched after this iteration's host work, not under it (the
+        # books read "flush" unless an admission follows), and from here
+        # the device has nothing queued (the admission path's host clock)
+        self.engine.gap_books.note("flush")
+        self._t_device_free = time.monotonic()
         self._fold_admitted()  # before _finish_rows can deliver them
         return self._finish_rows(finished)
 

@@ -256,6 +256,13 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_sched_time_seconds_total": ("counter", "Scheduler-thread wall seconds by attribution bucket (labels: bucket=device_decode|device_prefill|host_sched|readback|stream_flush|idle)"),
     "pfx_sched_wall_seconds_total": ("counter", "Total scheduler-thread wall seconds the time buckets must close against"),
     "pfx_sched_host_gap_seconds_total": ("counter", "Host seconds the device sat idle waiting for its next dispatch (goodput_frac subtrahend; overlaps the bucket family)"),
+    # the token gap's books (core/continuous_batching.TokenGapBooks): every
+    # gap between two frames of a row, booked once at the later commit
+    # under what the interval between the two commits held
+    "pfx_sched_token_gaps_total": ("counter", "Token gaps by what their interval held (labels: held=decode|admission|flush): one per row and commit that delivered the row a FRAME when the previous commit did too (a frame is one commit's tokens for a row: with speculation several tokens, still one gap); a row's first frame is no gap"),
+    "pfx_sched_token_gap_seconds_total": ("counter", "Seconds of the token gaps by what their interval held (labels: held=decode|admission|flush): rows x (this commit's stamp - the previous one's); over pfx_sched_token_gaps_total the server's own mean token gap"),
+    "pfx_sched_admit_host_seconds_total": ("counter", "Host seconds of the admission path: from the flush of the step in flight returning (or the iteration's start) to the last admission's dispatch having returned, in iterations that admitted; the device has nothing queued meanwhile"),
+    "pfx_sched_gap_books_errors_total": ("counter", "Faults inside the token-gap books (counted and logged once, never raised into the decode loop; 0 in a sound run)"),
     "pfx_train_time_seconds_total": ("counter", "Fit-loop wall seconds by attribution bucket (labels: bucket=compile|device_step|data_wait|host|eval)"),
     # work counted where it happens (one update per decode step / train
     # step from numbers the loop already holds): occupancy is row_steps /
