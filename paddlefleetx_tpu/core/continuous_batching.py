@@ -461,6 +461,9 @@ class PagedDecodeEngine:
             # pairs routed, pairs on held experts, the fullest held
             # expert's pairs x experts held (generation._moe_counts)
             "moe_pairs": 0, "moe_held_pairs": 0, "moe_held_max_pairs": 0,
+            # grouped products over sorted pairs the prefills dispatched
+            # (pfx_grouped_matmul: expert layers x matrices an expert, each)
+            "moe_grouped_calls": 0,
             # state-space layers (warm-up excluded): live (row, step)
             # pairs x layers (what the state kernel visits), slots x
             # steps x layers (the capacity), prompt tokens x layers
@@ -1044,6 +1047,8 @@ class PagedDecodeEngine:
             )
             self.pools = self._pools_of(pools_t)
             self._count_moe(moe[0] if moe else None, fetch=False)
+            if moe and not self._warmup:
+                self.stats["moe_grouped_calls"] += self.mcfg.sorted_pair_products
             self._logits = self._logits.at[slot].set(last)
             self._counts = self._counts.at[slot].set(counts)
             self._reject = self._reject.at[slot].set(-1)
@@ -2395,6 +2400,7 @@ class ContinuousScheduler:
                 ("moe_pairs", "pfx_moe_serve_pairs_total"),
                 ("moe_held_pairs", "pfx_moe_serve_held_pairs_total"),
                 ("moe_held_max_pairs", "pfx_moe_serve_held_max_pairs_total"),
+                ("moe_grouped_calls", "pfx_moe_serve_grouped_calls_total"),
             ):
                 out.append((name, {}, float(eng.stats[key])))
         for d, v in sorted(self._tok_ledger.items()):

@@ -377,6 +377,17 @@ class GPTConfig:
         return self.num_dense_layers if self.moe_dropless else 0
 
     @property
+    def sorted_pair_products(self) -> int:
+        """Grouped products one pass over sorted pairs dispatches (a serving
+        prefill's ``pfx_grouped_matmul`` calls): expert layers x the
+        matrices of an expert, two under ``mlp_act: relu2``, else three."""
+        if not self.moe_dropless:
+            return 0
+        layers = (self.layer_pattern.count("E") if self.layer_pattern
+                  else self.num_layers - self.leading_dense_layers)
+        return layers * (2 if self.mlp_act == "relu2" else 3)
+
+    @property
     def classic_block(self) -> bool:
         """True for the GPT-2 block: its parameter tree, its programs and
         the paths that know only it (pipeline, generation, ring attention)."""

@@ -1125,8 +1125,10 @@ def _block_mlp(p, m, cfg: GPTConfig, valid):
     layer's load statistics or None).  An expert layer is one whose
     parameters hold a router.  A decode step (t == 1: a token a row) runs
     every held expert on every row; a prefill sorts its pairs, as training
-    does at every size."""
+    does at every size, and runs the products over them in the forward-only
+    kernel that follows the held pairs (``pfx_grouped_matmul``)."""
     from paddlefleetx_tpu.models.gpt.moe import dropless_moe_block, feed_forward
+    from paddlefleetx_tpu.ops.grouped_matmul import grouped_matmul
 
     dtype = m.dtype
     if "router_kernel" not in p:
@@ -1135,7 +1137,7 @@ def _block_mlp(p, m, cfg: GPTConfig, valid):
     if "shared" in p:
         q["shared"] = _in_dtype("shared", p["shared"], dtype)
     return dropless_moe_block(q, m, cfg, None, p["e_score_correction_bias"], valid,
-                              every_held_expert=m.shape[1] == 1)
+                              every_held_expert=m.shape[1] == 1, grouped_product=grouped_matmul)
 
 
 def _block_layer_step(p, x, positions, valid, cfg: GPTConfig, attend):

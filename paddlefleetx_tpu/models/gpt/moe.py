@@ -164,14 +164,26 @@ def moe_mlp_block(
 # of them (ids ``moe_expert_offset`` ..) and computes the part of the result
 # that they give: the (token, expert) pairs that land on held experts are
 # sorted by expert into one buffer of static size, three grouped matrix
-# products (``jax.lax.ragged_dot``) run over the groups, and the rows go
-# back to their tokens weighted, accumulated in float32.  Pairs on experts
-# held elsewhere add nothing here; nothing stands in for the absent chips.
-# No token is dropped: the buffer has the worst case's tokens x top_k rows
-# (it fits the cell's chip, tests/test_chip_compile.py), so gathers, masks
-# and the scatter cost what the worst case costs and only the grouped
-# products follow the load.  An expert is SwiGLU's three matrices (w1, w3,
-# w2) or, under ``mlp_act: relu2``, two: ``W_down relu(W_up m)^2`` (w1, w2).
+# products run over the groups, and the rows go back to their tokens
+# weighted, accumulated in float32.  Pairs on experts held elsewhere add
+# nothing here; nothing stands in for the absent chips.  No token is
+# dropped: the buffer has the worst case's tokens x top_k rows (it fits the
+# cell's chip, tests/test_chip_compile.py), so gathers, masks and the
+# scatter cost what the worst case costs and only the grouped products
+# follow the load.  An expert is SwiGLU's three matrices (w1, w3, w2) or,
+# under ``mlp_act: relu2``, two: ``W_down relu(W_up m)^2`` (w1, w2).
+#
+# Which grouped product runs is the CALLER's, by call site, never a size's:
+# - training (``model.py``, differentiated; thousands of rows a group):
+#   ``jax.lax.ragged_dot``, XLA:TPU's own, which also derives the two
+#   transposed products of the backward pass and runs all three at its
+#   rate (4.7% of the trinity step);
+# - a serving prefill (``generation._block_mlp``, forward only; 10-100 rows
+#   a group, bound by the matrices' bytes): ``ops/grouped_matmul.py``'s
+#   ``pfx_grouped_matmul``, which walks the held pairs only and reads each
+#   matrix once, where the served tree keeps it.  There ``ragged_dot`` took
+#   2 ms a call whatever its rows and wanted a copy of the matrices first;
+# - a serving decode step sorts nothing (``every_held_expert``).
 # ---------------------------------------------------------------------------
 
 
@@ -277,14 +289,17 @@ def _held_experts_on_every_token(ex, m, idx, w, held: int, offset: int):
 
 
 def routed_experts(p: Dict[str, Any], m: jax.Array, bias: jax.Array, cfg, valid=None,
-                   every_held_expert: bool = False):
+                   every_held_expert: bool = False, grouped_product=jax.lax.ragged_dot):
     """m [N, h] -> (what the held experts give [N, h], the step's load
     statistics).  ``load`` counts the pairs of every expert, held or not.
     ``valid`` [N] bool (serving: a fixed-shape batch with empty rows, a
     padded prompt) leaves the other tokens' pairs out of the load and of
     the groups: they get zeros and cost no grouped product.
     ``every_held_expert`` (the serving decode step asks for it; static)
-    runs each held expert on every token instead of sorting the pairs."""
+    runs each held expert on every token instead of sorting the pairs.
+    ``grouped_product(rows [R, k], matrices [held, k, n], group_sizes)``:
+    the product over the sorted pairs; the serving prefill hands in its
+    forward-only kernel (``ops/grouped_matmul.py``)."""
     n, h = m.shape
     dtype = m.dtype
     k, E, held, offset = cfg.moe_top_k, cfg.num_experts, cfg.experts_held, cfg.moe_expert_offset
@@ -322,7 +337,7 @@ def routed_experts(p: Dict[str, Any], m: jax.Array, bias: jax.Array, cfg, valid=
             # grouped product leaves them as it found them, forward and
             # backward: cut them going in and coming out, so that neither
             # a value nor a cotangent of such a row ever reaches a token
-            y = jax.lax.ragged_dot(jnp.where(live, x, 0), kernel.astype(dtype), group_sizes)
+            y = grouped_product(jnp.where(live, x, 0), kernel.astype(dtype), group_sizes)
             return jnp.where(live, y, 0)
 
         if "w3" in ex:
@@ -343,9 +358,11 @@ def routed_experts(p: Dict[str, Any], m: jax.Array, bias: jax.Array, cfg, valid=
 
 
 def dropless_moe_block(p: Dict[str, Any], x: jax.Array, cfg, ctx, bias: jax.Array,
-                       valid=None, every_held_expert: bool = False):
+                       valid=None, every_held_expert: bool = False,
+                       grouped_product=jax.lax.ragged_dot):
     """x [b, s, h] -> (shared expert + held routed experts [b, s, h], stats).
-    ``valid`` [b, s] and ``every_held_expert``: see :func:`routed_experts`."""
+    ``valid`` [b, s], ``every_held_expert`` and ``grouped_product``: see
+    :func:`routed_experts`."""
     if ctx is not None and ctx.mesh.size > 1:
         raise NotImplementedError(
             "the dropless expert layer runs one chip's share per process; the "
@@ -354,7 +371,8 @@ def dropless_moe_block(p: Dict[str, Any], x: jax.Array, cfg, ctx, bias: jax.Arra
     b, s, h = x.shape
     m = x.reshape(b * s, h)
     out, stats = routed_experts(
-        p, m, bias, cfg, None if valid is None else valid.reshape(b * s), every_held_expert)
+        p, m, bias, cfg, None if valid is None else valid.reshape(b * s), every_held_expert,
+        grouped_product)
     if cfg.moe_shared_experts:
         with jax.named_scope("pfx.moe.shared"):
             out = out + feed_forward(m, p["shared"])

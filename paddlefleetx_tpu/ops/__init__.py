@@ -1,2 +1,3 @@
 """Custom TPU ops: Pallas flash attention, flash-decode (blocked KV-cache)
-attention, fused LayerNorm, chunked CE, top-k-prefiltered top-p sampling."""
+attention, fused LayerNorm, chunked CE, top-k-prefiltered top-p sampling,
+the state-space decode step, the serving prefill's grouped product."""

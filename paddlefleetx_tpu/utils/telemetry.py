@@ -280,6 +280,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_moe_serve_pairs_total": ("counter", "Serving: token-expert pairs the expert layers routed for live rows and real prompt tokens, over all experts and layers (prefills and decode steps)"),
     "pfx_moe_serve_held_pairs_total": ("counter", "Serving: routed pairs that landed on experts this process holds"),
     "pfx_moe_serve_held_max_pairs_total": ("counter", "Serving: the fullest held expert's pairs x experts held, summed over layers and dispatches (over pfx_moe_serve_held_pairs_total: max over mean)"),
+    "pfx_moe_serve_grouped_calls_total": ("counter", "Serving: grouped products over sorted pairs the prefills dispatched (pfx_grouped_matmul): expert layers x matrices an expert, each admission; over pfx_prefill_admits_total: the kernel's calls a prefill"),
     "pfx_ssm_row_steps_total": ("counter", "Serving: live (row, decode step) pairs x state-space layers: the state updates the traffic needed, which are the ones the kernel visits"),
     "pfx_ssm_slot_steps_total": ("counter", "Serving: batch slots x decode steps x state-space layers: the capacity the live pairs are a share of (the kernel skips the rest)"),
     "pfx_ssm_prefill_tokens_total": ("counter", "Serving: prompt tokens x state-space layers the chunked scan of the prefills computed"),

@@ -430,6 +430,9 @@ def test_trinity_mini_share_fits_one_chip(topo):
     text = c.as_text()
     assert "pfx_flash_fwd" in text and "pfx_flash_bwd_dkv" in text  # noqa: E10 — kernel names
     assert "ragged-dot" in text  # XLA:TPU's grouped product, a Mosaic call too
+    # the forward-only kernel is the serving prefill's: training needs the two
+    # transposed products that XLA derives from its own
+    assert "pfx_grouped_matmul" not in text  # noqa: E10 — a kernel's name
     m = c.memory_analysis()
     held = m.argument_size_in_bytes + m.temp_size_in_bytes + m.generated_code_size_in_bytes
     assert 8e9 < m.argument_size_in_bytes < 9e9  # 705.5 M x 12 bytes of state
@@ -757,3 +760,60 @@ def test_decode_step_of_the_whole_depth_pattern_block_fits_and_copies_nothing(to
     assert len(calls) == 23 and len(lists) == 1, lists
     moved = re.findall(r"= \w+\[(?:23,48,32,128,128|6,673,2,128,128)\]\S* (copy|transpose)\(", text)
     assert not moved, moved
+
+
+# ---------------------------------------------------------------------------
+# The serving prefills of the two expert configurations: the products over the
+# sorted pairs run in pfx_grouped_matmul and read the experts' matrices where
+# the served tree keeps them
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("config,bucket,slots,blocks,products,fits", [
+    pytest.param("nemotron-3-nano", 256, 48, 673, 46, 13.6e9 + 0.2e9, id="chat-cell-256"),
+    pytest.param("deepseek-v3", 1024, 64, 2305, 18, 11.8e9, id="think-cell-1024"),
+])
+def test_serving_prefill_runs_its_grouped_products_in_the_kernel(topo, config, bucket, slots, blocks,
+                                                                 products, fits):
+    """One bucket of each expert cell's prefill program as its configuration
+    file states it, the pools DONATED: every expert layer's matrices go through
+    ``pfx_grouped_matmul`` (46 = 23 layers x 2 relu2 matrices, 18 = 6 x 3
+    SwiGLU), XLA's ``ragged-dot`` is gone, and no experts' matrix is copied,
+    transposed or padded on its way in: ``bf16[16,2688,1856]``, whose last
+    dimension is no multiple of the 128 lanes, lies on the chip with the 2688
+    minor, and the kernel contracts it there (ops/grouped_matmul.py).  Arguments
+    and scratch stay inside what the chat cell's decode step is allowed above
+    (13.6 + 0.2 GB; with ``ragged-dot`` and its copies the scratch was 0.39 GB)."""
+    import json
+    import re
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    root = os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..")
+    bench = os.path.join(root, "pfx_bench")  # noqa: E10 — a directory, not a metric
+    with open(os.path.join(bench, "configs", f"{config}.json")) as f:
+        cfg = GPTConfig(**json.load(f)["model"])
+    one = _one_chip(topo)
+    params = _shapes(one, jax.eval_shape(lambda: G.init_serving_params(cfg, jax.random.key(0))))
+    pools = _shapes(one, jax.eval_shape(
+        lambda: G.init_paged_pools(cfg, blocks, cfg.kv_block_default, slots=slots)))
+    i32 = lambda *shape: (shape, jnp.int32)  # noqa: E731
+
+    def prefill(p, prompt, plen, pools, row, slot):
+        row_state = {"slot": slot} if cfg.layer_pattern else {}
+        return G.paged_prefill(p, prompt, plen, pools, row, cfg, return_moe=True, **row_state)
+
+    prompt, plen, row, slot = _shapes(one, (i32(1, bucket), i32(), i32(bucket // 128), i32()))
+    c = jax.jit(prefill, donate_argnums=(3,)).lower(params, prompt, plen, pools, row, slot).compile()
+    text = c.as_text()
+    assert len(re.findall(r"%pfx_grouped_matmul\S* = ", text)) == products
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    e, h, f = cfg.experts_held, cfg.hidden_size, cfg.moe_ffn_hidden_size
+    moved = re.findall(rf"= \w+\[{e},(?:{h},{f}|{f},{h})\]\S* (\w[\w-]*)\(", text)
+    # parameters, renamed for the kernel where they lie k-minor, and nothing else
+    assert set(moved) <= {"parameter", "bitcast"}, set(moved)
+    m = c.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert held <= m.alias_size_in_bytes < 1.01 * held
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < fits, m.temp_size_in_bytes

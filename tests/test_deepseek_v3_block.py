@@ -13,6 +13,7 @@ accumulation order only: the tolerances are a few float32 roundings of
 values of order 1 (2e-5), and each says so where it is used."""
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -29,6 +30,7 @@ from paddlefleetx_tpu.models.gpt import model as gpt
 from paddlefleetx_tpu.models.gpt import moe
 from paddlefleetx_tpu.models.gpt.config import GPTConfig
 from paddlefleetx_tpu.ops import decode_attention as DA
+from paddlefleetx_tpu.ops.grouped_matmul import grouped_matmul
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "pfx_bench")  # noqa: E10 — a directory, not a metric
@@ -219,12 +221,16 @@ def test_group_limited_choice_and_weights_against_the_reference():
     assert any(len(set(r)) > 2 for r in np.asarray(free) // 4)
 
 
-@pytest.mark.parametrize("n,every", [(50, True), (50, False), (200, False)])
-def test_the_shares_add_up_to_the_uncut_layer(n, every):
+@pytest.mark.parametrize("n,every,kernel", [(50, True, False), (50, False, False), (200, False, False),
+                                            (50, False, True), (200, False, True)])
+def test_the_shares_add_up_to_the_uncut_layer(n, every, kernel):
     """(e) the 4 shares' routed parts plus the shared expert once = the
     reference's layer with all 16 experts held, on the decode step's path
     (every held expert on every token, asked for by name) and on the sorted
-    one, which a size alone never leaves."""
+    one, which a size alone never leaves: through XLA's grouped product and
+    through the serving prefill's kernel, which gives what
+    ``jax.lax.ragged_dot`` gives (three matrices an expert here)."""
+    product = {"grouped_product": functools.partial(grouped_matmul, impl="pallas")} if kernel else {}
     whole = GPTConfig(**dict(TOY, moe_experts_held=16, moe_expert_offset=0))
     p = gpt.init(whole, jax.random.PRNGKey(4))
     mlp = jax.tree.map(lambda a: a[0], p["layers"]["mlp"])
@@ -237,8 +243,11 @@ def test_the_shares_add_up_to_the_uncut_layer(n, every):
     for share in range(4):
         cfg = GPTConfig(**dict(TOY, moe_experts_held=4, moe_expert_offset=4 * share))
         part = dict(mlp, experts=jax.tree.map(lambda a: a[4 * share:4 * share + 4], mlp["experts"]))
-        out, stats = moe.routed_experts(part, m, bias, cfg, every_held_expert=every)
+        out, stats = moe.routed_experts(part, m, bias, cfg, every_held_expert=every, **product)
         assert int(stats["load"].sum()) == n * 4
+        if kernel:
+            ragged, _ = moe.routed_experts(part, m, bias, cfg)
+            assert float(jnp.max(jnp.abs(out - ragged))) < F32_ROUNDINGS
         total = total + out
     assert float(jnp.max(jnp.abs(total - want))) < F32_ROUNDINGS
 
@@ -336,6 +345,9 @@ def test_the_scheduler_serves_the_reference_s_greedy_tokens(server):
         assert page["pfx_moe_serve_pairs_total"] >= sum(map(len, prompts)) * 4 * 2
         assert 0 < page["pfx_moe_serve_held_pairs_total"] < page["pfx_moe_serve_pairs_total"]
         assert page["pfx_moe_serve_held_max_pairs_total"] >= page["pfx_moe_serve_held_pairs_total"]
+        # every admission went through the kernel: 2 expert layers x 3 SwiGLU matrices a prefill
+        assert page["pfx_moe_serve_grouped_calls_total"] == 6 * len(prompts) == (
+            eng.mcfg.sorted_pair_products * int(sched.stats["prefill_admits"]))
         assert page["pfx_sched_decode_kv_tokens_total"] > 0
     finally:
         assert sched.shutdown(timeout=30)

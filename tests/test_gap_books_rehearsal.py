@@ -67,3 +67,21 @@ def test_a_serving_cell_rehearses_correct_with_its_books_closed(cell, seed):
     # widths on a shared CPU: loose; the chip's agreement is in PERF.md)
     assert win["server_gap_mean_ms"] == pytest.approx(
         win["client_gap_mean_in_window_ms"], rel=0.25)
+    # every admission of an expert model went through pfx_grouped_matmul:
+    # its calls a prefill are a constant of the program (none for the dense block)
+    assert win["prefill_admits"] > 0
+    assert win["moe_grouped_calls"] == _grouped_products(cell) * win["prefill_admits"]
+
+
+def _grouped_products(cell: str) -> int:
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    bench = os.path.join(REPO, "pfx_bench")  # noqa: E10 — a directory, not a metric
+    with open(os.path.join(bench, "workloads", f"{cell}.json")) as f:
+        config = json.load(f)["config"]
+    with open(os.path.join(bench, "configs", f"{config}.json")) as f:
+        spec = json.load(f)
+    model = dict(spec["model"], **spec.get("rehearse_model", {}))
+    products = GPTConfig(**model).sorted_pair_products
+    assert (products > 0) == (cell != "serve-1.3b-docs")
+    return products

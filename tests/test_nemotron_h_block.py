@@ -14,6 +14,7 @@ accumulation order only: the tolerances are a few float32 roundings of
 values of order 1 (2e-5), and each says so where it is used.  A recurrent
 state or a conv column kept in bfloat16 would miss them by two orders."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -31,6 +32,7 @@ from paddlefleetx_tpu.models.gpt import ssm as mixer
 from paddlefleetx_tpu.models.gpt.config import GPTConfig
 from paddlefleetx_tpu.ops import decode_attention as DA
 from paddlefleetx_tpu.ops import ssm as ssm_ops
+from paddlefleetx_tpu.ops.grouped_matmul import grouped_matmul
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "pfx_bench")  # noqa: E10 — a directory, not a metric
@@ -329,6 +331,9 @@ def test_the_scheduler_serves_and_counts(server):
         assert page["pfx_ssm_prefill_tokens_total"] == 3 * sum(map(len, prompts))
         assert page["pfx_moe_serve_pairs_total"] == (sum(map(len, prompts)) + rows) * 2 * 3
         assert 0 < page["pfx_moe_serve_held_pairs_total"] < page["pfx_moe_serve_pairs_total"]
+        # every admission went through the kernel: 3 E layers x 2 relu2 matrices a prefill
+        assert page["pfx_moe_serve_grouped_calls_total"] == 6 * len(prompts) == (
+            eng.mcfg.sorted_pair_products * int(sched.stats["prefill_admits"]))
     finally:
         assert sched.shutdown(timeout=30)
 
@@ -491,11 +496,15 @@ def test_the_paged_decode_kernel_with_shared_kv_heads_equals_dense_attention(n, 
 # -- (d) the share test ---------------------------------------------------------------
 
 
-@pytest.mark.parametrize("n,every", [(40, True), (40, False), (150, False)])
-def test_the_eight_shares_add_up_to_the_uncut_layer(n, every):
+@pytest.mark.parametrize("n,every,kernel", [(40, True, False), (40, False, False), (150, False, False),
+                                            (40, False, True), (150, False, True)])
+def test_the_eight_shares_add_up_to_the_uncut_layer(n, every, kernel):
     """(d) 16 two-matrix experts over 8 shares of 2: the shares' routed parts
     plus the shared expert ONCE = the reference's layer with all 16 held, on
-    the decode step's path (every held expert on every token) and the sorted one."""
+    the decode step's path (every held expert on every token) and the sorted
+    one, through XLA's grouped product (training) and through the serving
+    prefill's kernel, which gives what ``jax.lax.ragged_dot`` gives."""
+    product = {"grouped_product": functools.partial(grouped_matmul, impl="pallas")} if kernel else {}
     sizes = dict(TOY, num_experts=16, moe_top_k=3, moe_experts_held=16, moe_expert_offset=0)
     whole = GPTConfig(**sizes)
     mlp = G.init_serving_params(whole, jax.random.PRNGKey(4))["blocks"][1]["mlp"]
@@ -508,8 +517,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(n, every):
     for share in range(8):
         cfg = GPTConfig(**dict(sizes, moe_experts_held=2, moe_expert_offset=2 * share))
         part = dict(mlp, experts=jax.tree.map(lambda a: a[2 * share:2 * share + 2], mlp["experts"]))
-        out, stats = moe.routed_experts(part, m, bias, cfg, every_held_expert=every)
+        out, stats = moe.routed_experts(part, m, bias, cfg, every_held_expert=every, **product)
         assert int(stats["load"].sum()) == n * 3
+        if kernel:
+            ragged, _ = moe.routed_experts(part, m, bias, cfg)
+            assert float(jnp.max(jnp.abs(out - ragged))) < F32_ROUNDINGS
         total = total + out
     assert float(jnp.max(jnp.abs(total - want))) < F32_ROUNDINGS
     idx, w = moe.sigmoid_route(m, mlp["router_kernel"], bias, whole)
@@ -524,9 +536,11 @@ def test_a_prefill_sorts_its_pairs_and_a_decode_step_runs_every_held_expert(toy,
     seen = []
     real = moe.routed_experts
 
-    def spy(p, m, bias, cfg, valid=None, every_held_expert=False):
+    def spy(p, m, bias, cfg, valid=None, every_held_expert=False, grouped_product=None):
+        # both are handed the forward-only kernel; only a prefill's sorted path runs it
+        assert grouped_product is grouped_matmul
         seen.append(every_held_expert)
-        return real(p, m, bias, cfg, valid, every_held_expert)
+        return real(p, m, bias, cfg, valid, every_held_expert, grouped_product)
 
     monkeypatch.setattr(moe, "routed_experts", spy)
     pools = G.init_paged_pools(cfg, 4, BLOCK, slots=2)

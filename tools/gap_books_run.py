@@ -64,6 +64,10 @@ def books(raw: dict, res: dict, warm_requests: list) -> dict:
             "client_gaps_in_window": len(client),
             "admit_host_s": round(common.metric_sum(
                 delta, "pfx_sched_admit_host_seconds_total"), 6),
+            # the window's admissions and the grouped products their prefills
+            # dispatched (pfx_grouped_matmul; none without expert layers)
+            "prefill_admits": common.metric_sum(delta, "pfx_prefill_admits_total"),
+            "moe_grouped_calls": common.metric_sum(delta, "pfx_moe_serve_grouped_calls_total"),
             # frames less first frames by the token ledger and the admissions
             # counter: off by the rows seated but not yet framed at an edge,
             # and by one commit's rows where that commit landed between the
