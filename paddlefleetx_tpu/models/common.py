@@ -12,6 +12,7 @@ auto_model triplication) with one definition sharded by annotation.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -35,6 +36,32 @@ def normal_init(stddev: float) -> Initializer:
     def f(key, shape, dtype):
         return stddev * jax.random.normal(key, shape, dtype)
 
+    return f
+
+
+# elements of a leaf drawn at once: 1 GiB of float32.  A larger leaf (a
+# 261,120 x 5,120 embedding is 5.3 GB in float32) is drawn in slabs of its
+# leading axis, so that a server can fold, cast and drop each slab before the
+# next exists (models/gpt/generation.py init_serving_params)
+SLAB_ELEMENTS = 2 ** 28
+
+
+def slab_init(init: Initializer, shape: Tuple[int, ...]) -> Initializer:
+    """``init`` for a leaf of ``shape`` in slabs of its leading axis: slab i of
+    n from ``split(key, n)[i]``, n the fewest equal slabs of at most
+    SLAB_ELEMENTS.  One slab (every leaf but a huge one): ``init`` itself,
+    to the bit.  The initializer that comes back carries ``slabs`` = (init,
+    n) for a caller that wants the slabs one at a time."""
+    n = next(n for n in range(1, shape[0] + 1)
+             if shape[0] % n == 0 and math.prod(shape) // n <= SLAB_ELEMENTS)
+    if n == 1:
+        return init
+
+    def f(key, shape, dtype):
+        slab = (shape[0] // n,) + tuple(shape[1:])
+        return jnp.concatenate([init(k, slab, dtype) for k in jax.random.split(key, n)])
+
+    f.slabs = (init, n)
     return f
 
 

@@ -9,7 +9,19 @@ so reference configs translate 1:1.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
+
+
+# The muP constants ``GPTConfig.mup_multipliers`` may name, under the
+# published names (model_type falcon_h1), with how many numbers each takes
+# (0 = one scalar); where each multiplies: models/gpt/convert.py fold_mup
+MUP_NAMES = {
+    "embedding_multiplier": 0, "lm_head_multiplier": 0,
+    "ssm_in_multiplier": 0, "ssm_out_multiplier": 0,
+    "ssm_multipliers": 5,  # the in-projection's segments z | x | B | C | dt
+    "attention_in_multiplier": 0, "attention_out_multiplier": 0, "key_multiplier": 0,
+    "mlp_multipliers": 2,  # the gate's pre-activation, the down-projection's output
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,7 +91,9 @@ class GPTConfig:
     # apart: all at their defaults, or rmsnorm + rope + no bias + swiglu +
     # untied head (the described block, whose other options are each held
     # to the reference in tests/test_trinity_block.py), or, with a
-    # ``layer_pattern``, rmsnorm + none + no bias + relu2 + untied head.
+    # ``layer_pattern``, rmsnorm + none + no bias + relu2 + untied head
+    # (docs/nemotron_h.md) or the described block's own words, rmsnorm + rope
+    # + no bias + swiglu + untied head (docs/falcon_h1.md).
     norm: str = "layernorm"  # layernorm | rmsnorm (learned scale, no bias)
     norm_eps: float = 1e-5
     # norms on each sub-block's OUTPUT too, before the residual add
@@ -152,10 +166,13 @@ class GPTConfig:
     rope_mscale_all_dim: float = 0.0
 
     # one sub-block a layer (docs/nemotron_h.md): a character a layer,
-    # ``M`` a Mamba-2 mixer, ``*`` grouped-query attention without
-    # rotation, ``E`` the dropless expert feed-forward, ``-`` a dense
-    # relu2 feed-forward; every layer is x + mixer(RMSNorm(x)).  "" = the
-    # two-sub-block layers above.  ``num_layers`` is the pattern's length.
+    # ``M`` a Mamba-2 mixer, ``*`` grouped-query attention (rotated under
+    # ``position: rope``), ``P`` both of them side by side on the SAME normed
+    # input, their results added (docs/falcon_h1.md: such a layer keeps a
+    # recurrent state AND pages), ``E`` the dropless expert feed-forward,
+    # ``-`` a dense feed-forward (relu2 or swiglu, as ``mlp_act`` says); every
+    # layer is x + mixer(RMSNorm(x)).  "" = the two-sub-block layers above.
+    # ``num_layers`` is the pattern's length.
     layer_pattern: str = ""
     # the Mamba-2 mixer: ssm_heads heads of ssm_head_dim (d_inner is their
     # product, not a multiple of hidden_size), a state of ssm_state a head
@@ -175,6 +192,13 @@ class GPTConfig:
     # out-projections of every mixer drawn at initializer_range /
     # sqrt(num_layers) (the published ``rescale_prenorm_residual``)
     rescale_prenorm_residual: bool = False
+    # the constants a muP-parameterised checkpoint multiplies activations by
+    # (a ``layer_pattern`` block; names and places: ``MUP_NAMES``,
+    # docs/falcon_h1.md), as a mapping name -> number or list of numbers.
+    # The serving programs carry NONE of them: each multiplies a linear map's
+    # input or output, so ``models/gpt/convert.py`` ``fold_mup`` multiplies
+    # them into the weights once, in float32, where the served tree is made.
+    mup_multipliers: Any = ()
 
     def __post_init__(self):
         if self.ffn_hidden_size is None:
@@ -189,19 +213,21 @@ class GPTConfig:
             raise ValueError("num_attention_heads must divide hidden_size")
         if self.num_attention_heads % (self.num_kv_heads or self.num_attention_heads):
             raise ValueError("num_kv_heads must divide num_attention_heads")
+        self._read_mup_multipliers()
         if self.layer_pattern:
             self._check_layer_pattern()
         elif self.position == "none" or self.mlp_act == "relu2":
             raise ValueError("position: none and mlp_act: relu2 belong to a layer_pattern block")
         if not self.classic_block:
-            words = ("rmsnorm", "none", False, "relu2", False) if self.layer_pattern else (
-                "rmsnorm", "rope", False, "swiglu", False)
+            described = ("rmsnorm", "rope", False, "swiglu", False)
+            words = (described,) + (
+                (("rmsnorm", "none", False, "relu2", False),) if self.layer_pattern else ())
             if (self.norm, self.position, self.use_bias, self.mlp_act,
-                    self.tie_embeddings) != words:
+                    self.tie_embeddings) not in words:
                 raise ValueError(
                     "a block other than the GPT-2 one is norm: rmsnorm, position: rope, "
                     "use_bias: False, mlp_act: swiglu, tie_embeddings: False together "
-                    "(with a layer_pattern: position: none, mlp_act: relu2)")
+                    "(with a layer_pattern: those, or position: none, mlp_act: relu2)")
             if self.hidden_dropout_prob or self.attention_probs_dropout_prob:
                 raise ValueError("only the GPT-2 block has dropout; set both "
                                  "dropout probabilities to 0")
@@ -264,16 +290,42 @@ class GPTConfig:
             )
         object.__setattr__(self, "recompute_names", ",".join(names))
 
+    def _read_mup_multipliers(self) -> None:
+        """``mup_multipliers`` as a sorted tuple of (name, float or tuple of
+        floats): hashable, as a jit's static argument has to be."""
+        raw = self.mup_multipliers
+        items = dict(raw).items() if raw else ()
+        read = []
+        for name, value in sorted(items):
+            width = MUP_NAMES.get(name)
+            if width is None:
+                raise ValueError(f"mup_multipliers: unknown constant {name!r}; "
+                                 f"known: {sorted(MUP_NAMES)}")
+            if width:
+                value = tuple(float(v) for v in value)
+                if len(value) != width:
+                    raise ValueError(f"mup_multipliers: {name} takes {width} numbers")
+            else:
+                value = float(value)
+            read.append((name, value))
+        object.__setattr__(self, "mup_multipliers", tuple(read))
+        if read and not self.layer_pattern:
+            raise ValueError("mup_multipliers belong to a layer_pattern block "
+                             "(the fold is written for its tree)")
+
     def _check_layer_pattern(self) -> None:
         pattern = self.layer_pattern
-        if set(pattern) - set("M*E-") or len(pattern) != self.num_layers:
+        if set(pattern) - set("MP*E-") or len(pattern) != self.num_layers:
             raise ValueError(
-                f"layer_pattern {pattern!r}: one of M (Mamba-2), * (attention), E (experts), "
-                f"- (dense) for each of the {self.num_layers} layers")
-        if "M" in pattern:
+                f"layer_pattern {pattern!r}: one of M (Mamba-2), * (attention), P (both, "
+                f"side by side), E (experts), - (dense) for each of the {self.num_layers} layers")
+        if "E" in pattern and self.mlp_act != "relu2":
+            raise ValueError("an E layer of a layer_pattern block has relu2 experts "
+                             "(mlp_act: relu2): no reference holds another yet")
+        if self.ssm_layers:
             if not (self.ssm_heads and self.ssm_head_dim and self.ssm_state
                     and self.ssm_conv >= 2 and self.ssm_chunk >= 1):
-                raise ValueError("an M layer needs ssm_heads, ssm_head_dim, ssm_state, "
+                raise ValueError("an M or P layer needs ssm_heads, ssm_head_dim, ssm_state, "
                                  "ssm_conv >= 2 and ssm_chunk")
             if self.ssm_heads % self.ssm_groups or (
                     self.ssm_heads * self.ssm_head_dim) % self.ssm_groups:
@@ -311,12 +363,19 @@ class GPTConfig:
     @property
     def kv_layers(self) -> int:
         """Layers whose cache is pages of tokens: all of them, or a
-        layer_pattern's attention layers."""
-        return self.layer_pattern.count("*") if self.layer_pattern else self.num_layers
+        layer_pattern's attention layers (``*`` and ``P``).  The pools'
+        leading axis: attention layer number ``a`` of the pattern is
+        ``pools.k[a]``, NOT the layer's place in the stack."""
+        if not self.layer_pattern:
+            return self.num_layers
+        return self.layer_pattern.count("*") + self.layer_pattern.count("P")
 
     @property
     def ssm_layers(self) -> int:
-        return self.layer_pattern.count("M")
+        """Layers that keep a recurrent state a row (``M`` and ``P``): the
+        leading axis of ``pools.ssm`` / ``.conv``.  A ``P`` layer counts here
+        AND in :attr:`kv_layers`: the two sets of layers overlap."""
+        return self.layer_pattern.count("M") + self.layer_pattern.count("P")
 
     @property
     def ssm_inner(self) -> int:
@@ -348,11 +407,16 @@ class GPTConfig:
         otherwise (0 = the library's default): a latent page holds one
         vector a token, so 128 of them make the page of a DMA's size that
         16 tokens of per-head keys make; so do 128 tokens of a few shared
-        KV heads."""
-        if self.latent_attention or (self.layer_pattern and self.kv_heads * 8 <=
+        KV heads (4 or more query heads a KV head: 32/2 and 20/4 both)."""
+        if self.latent_attention or (self.layer_pattern and self.kv_heads * 4 <=
                                      self.num_attention_heads):
             return 128
         return 0
+
+    @property
+    def mup(self) -> Dict[str, Any]:
+        """``mup_multipliers`` by name ({} without them)."""
+        return dict(self.mup_multipliers)
 
     @property
     def rope_yarn_m(self) -> float:
@@ -404,7 +468,7 @@ class GPTConfig:
         """(window or 0, rotate q and k) of layer ``layer``, counted from 0
         over the whole stack, leading dense layers included."""
         is_global = self.global_attn_every > 0 and (layer + 1) % self.global_attn_every == 0
-        if self.layer_pattern and self.layer_pattern[layer] != "*":
+        if self.layer_pattern and self.layer_pattern[layer] not in "*P":
             raise ValueError(f"layer {layer} of {self.layer_pattern!r} is no attention layer")
         return (0 if is_global else self.sliding_window,
                 self.position == "rope" and not is_global)

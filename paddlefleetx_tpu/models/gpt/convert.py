@@ -10,6 +10,11 @@ switching frameworks can bring standard weights.  Mapping notes:
   [h, 3, nh, hd], matching the fused qkv einsum ``bsh,htnd->bstnd``.
 - activations (gelu tanh-approx) and LN eps (1e-5) already agree.
 - the LM head is tied to the word embedding in both implementations.
+
+Also here, because it is a checkpoint's conversion too: :func:`fold_mup`, which
+multiplies a muP-parameterised checkpoint's constants (``GPTConfig.mup_multipliers``)
+into the weights of a ``layer_pattern`` tree.  It is the ONE place those
+constants live in the program (docs/falcon_h1.md).
 """
 
 from __future__ import annotations
@@ -114,3 +119,77 @@ def convert_hf_gpt2_state_dict(
         },
     }
     return params
+
+
+def mup_scale(group: str, name: str, cfg: GPTConfig):
+    """What the muP constants multiply leaf ``name`` of parameter group
+    ``group`` by: a float, or for the mixer's in-projection a vector over
+    its columns.  Each constant of the published forward (``model_type:
+    falcon_h1``) multiplies the INPUT or the OUTPUT of one linear map, so it
+    is that map's own scale:
+
+        x0 = embedding_multiplier E[token]                     embeddings.word
+        Mixer(ssm_in_multiplier h):  (W_in u) * ssm_multipliers by segment
+              [z | x | B | C | dt], BEFORE the conv            ssm.in_kernel's columns
+        ssm_out_multiplier Mixer(..)                           ssm.out_kernel
+        Attn(attention_in_multiplier h)                        attn.q/k/v_kernel
+        k = key_multiplier W_k u                               attn.k_kernel
+        attention_out_multiplier Attn(..)                      attn.out_kernel
+        silu(mlp_multipliers[0] W_gate m) * W_up m             mlp.w1
+        mlp_multipliers[1] W_down (..)                         mlp.w2
+        logits = lm_head_multiplier W_head x                   head.kernel
+
+    A norm stands between the residual stream and every one of these maps,
+    and the conv, dt's softplus and the recurrence come AFTER the scaled
+    in-projection, so no constant crosses a non-linearity on its way into a
+    weight."""
+    m = cfg.mup
+
+    def one(key: str) -> float:
+        return float(m.get(key, 1.0))
+
+    if (group, name) == ("embeddings", "word"):
+        return one("embedding_multiplier")
+    if (group, name) == ("head", "kernel"):
+        return one("lm_head_multiplier")
+    if group == "ssm" and name == "in_kernel":
+        gn = cfg.ssm_groups * cfg.ssm_state
+        widths = (cfg.ssm_inner, cfg.ssm_inner, gn, gn, cfg.ssm_heads)
+        segments = m.get("ssm_multipliers", (1.0,) * 5)
+        return one("ssm_in_multiplier") * np.concatenate(
+            [np.full((w,), c, np.float32) for w, c in zip(widths, segments)])
+    if group == "ssm" and name == "out_kernel":
+        return one("ssm_out_multiplier")
+    if group == "attn" and name in ("q_kernel", "k_kernel", "v_kernel"):
+        return one("attention_in_multiplier") * (one("key_multiplier") if name == "k_kernel" else 1.0)
+    if group == "attn" and name == "out_kernel":
+        return one("attention_out_multiplier")
+    if group == "mlp" and name in ("w1", "w2"):
+        return float(m.get("mlp_multipliers", (1.0, 1.0))[name == "w2"])
+    return 1.0
+
+
+def fold_mup_leaf(path, x, cfg: GPTConfig):
+    """Leaf ``x`` at tree path ``path`` (``jax.tree_util`` keys) times its
+    :func:`mup_scale`, in float32, back in its own dtype; ``x`` itself where
+    nothing multiplies it."""
+    if not cfg.mup_multipliers:
+        return x
+    group, name = (getattr(k, "key", None) for k in path[-2:])
+    scale = mup_scale(group, name, cfg)
+    if isinstance(scale, float) and scale == 1.0:
+        return x
+    import jax.numpy as jnp
+
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def fold_mup(params: Dict, cfg: GPTConfig) -> Dict:
+    """A ``layer_pattern`` tree as a checkpoint holds it (``model.init``'s
+    tree) -> the tree as it is SERVED, every muP constant inside the matrix it
+    scales.  Once: a folded tree folded again is another model.
+    ``generation.init_serving_params`` folds each seeded leaf as it makes it;
+    ``generation.serving_params`` only casts, and takes a folded tree."""
+    import jax
+
+    return jax.tree_util.tree_map_with_path(lambda p, x: fold_mup_leaf(p, x, cfg), params)

@@ -19,6 +19,7 @@ over cache blocks ``< ceil((pos+t)/block)``, not the whole buffer.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -33,6 +34,7 @@ from paddlefleetx_tpu.models.gpt.model import (
     latent_softmax_scale,
     layer_norm,
     rms_norm,
+    rope_at,
 )
 from paddlefleetx_tpu.ops.decode_attention import (
     decode_attention,
@@ -146,7 +148,13 @@ def serving_params(params: Dict[str, Any], cfg: GPTConfig) -> Dict[str, Any]:
     keep their sharding: each device converts its shard.
 
     The described block's tree comes back with its layers UNSTACKED
-    (:func:`unstack_layers`): ``blocks``, a tuple of one dict a layer."""
+    (:func:`unstack_layers`): ``blocks``, a tuple of one dict a layer.
+
+    A muP checkpoint's constants (``cfg.mup_multipliers``) are NOT applied
+    here: this function runs again on the tree a server already holds, and a
+    fold is no cast.  The tree that comes in has them inside its weights
+    (``models/gpt/convert.py`` ``fold_mup``, once, where the checkpoint is
+    converted)."""
     dtype = jnp.dtype(cfg.dtype)
     # a layer_pattern tree is born with ``blocks``; any other has them once served
     if dtype == jnp.float32 or ("blocks" in params and not cfg.layer_pattern):
@@ -202,10 +210,14 @@ def init_serving_params(cfg: GPTConfig, key: jax.Array, shardings=None) -> Dict[
     the chip cannot exist on it at all.)  The same keys and the same
     operations as ``models.common.init_params`` then ``serving_params``, so
     the same values to the bit; the described block's layers are made unstacked
-    (each layer's leaf from the key its slice of the stack would get).
+    (each layer's leaf from the key its slice of the stack would get).  With
+    ``mup_multipliers`` each float32 leaf is folded before its cast
+    (``convert.fold_mup``, which ``serving_params`` leaves to the converter):
+    the tree is ``serving_params(fold_mup(init(cfg, key), cfg), cfg)``.
     ``shardings``: a tree of shardings matching ``gpt_specs`` (a mesh;
     the GPT-2 block only), or None."""
     from paddlefleetx_tpu.models.common import ParamSpec
+    from paddlefleetx_tpu.models.gpt.convert import fold_mup_leaf
     from paddlefleetx_tpu.models.gpt.model import _block_layer_specs, gpt_specs
 
     dtype = jnp.dtype(cfg.dtype)
@@ -214,11 +226,28 @@ def init_serving_params(cfg: GPTConfig, key: jax.Array, shardings=None) -> Dict[
     keys = jax.random.split(key, len(flat))
     places = [None] * len(flat) if shardings is None else treedef.flatten_up_to(shardings)
 
-    def make(path, spec, k, place=None):
-        x = spec.init(k, spec.shape, spec.dtype)
+    def finish(path, x):
+        # seeded weights stand for a checkpoint: its muP constants go into them here
+        x = fold_mup_leaf(path, x, cfg)
         if _compute_dtype_leaf(path, x.dtype, dtype):
             x = x.astype(dtype)  # the float32 original is freed as this returns
-        return jax.block_until_ready(x if place is None else jax.device_put(x, place))
+        return x
+
+    def make(path, spec, k, place=None):
+        init, n = getattr(spec.init, "slabs", (spec.init, 1))
+        if n == 1:
+            x = finish(path, init(k, spec.shape, spec.dtype))
+            return jax.block_until_ready(x if place is None else jax.device_put(x, place))
+        # a leaf too large to exist in float32 beside the tree (``common.slab_init``):
+        # each slab of rows is drawn, finished and written into the leaf before the next
+        rows = spec.shape[0] // n
+        x = None
+        for i, k_i in enumerate(jax.random.split(k, n)):
+            slab = finish(path, init(k_i, (rows,) + tuple(spec.shape[1:]), spec.dtype))
+            if x is None:
+                x = jnp.zeros(spec.shape, slab.dtype)
+            x = jax.block_until_ready(_write_rows(x, slab, i * rows))
+        return x
 
     if cfg.classic_block:
         return treedef.unflatten(
@@ -253,6 +282,11 @@ def init_serving_params(cfg: GPTConfig, key: jax.Array, shardings=None) -> Dict[
             put(blocks[first + l], names[1:], make(path, inner, k_l))
     out["blocks"] = tuple(blocks)
     return _with_routing_bias(out, cfg)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_rows(leaf, slab, at):
+    return jax.lax.dynamic_update_slice_in_dim(leaf, slab, at, axis=0)
 
 
 def _with_routing_bias(params, cfg: GPTConfig):
@@ -1250,30 +1284,48 @@ def _block_paged_prefill(params, prompt, prompt_len, pools, table_row, cfg: GPTC
 
 
 # ---------------------------------------------------------------------------
-# A ``layer_pattern`` block on the paged pools (docs/nemotron_h.md): every
-# layer is ONE sub-block, x + mix(RMSNorm(x)), of one of three kinds: a
+# A ``layer_pattern`` block on the paged pools (docs/nemotron_h.md,
+# docs/falcon_h1.md): every layer is ONE sub-block, x + mix(RMSNorm(x)): a
 # state-space mixer over the row's recurrent state, grouped-query attention
-# without rotation over the row's pages, a feed-forward (experts or dense).
+# over the row's pages (rotated under ``position: rope``), BOTH side by side
+# on the same normed input (``P``), or a feed-forward (experts or dense).
 # ---------------------------------------------------------------------------
 
 
-def _pattern_stack(params, x, pools, valid, cfg: GPTConfig, mixer, attend):
+def _pattern_stack(params, x, pools, valid, cfg: GPTConfig, mixer, attend, positions=None):
     """The pattern's layers one after the other.  ``mixer(p, y, pools, m)``
     runs state-space layer number ``m`` and ``attend(q, k, v, pools, a)``
     attention layer number ``a`` over its own cache; each -> (result,
-    pools).  -> (x, pools, the expert layers' statistics, a list)."""
+    pools).  ``m`` and ``a`` count the layers of their kind (the pools'
+    leading axes), not the layer's place in the stack: a ``P`` layer hands
+    the SAME normed input to both mixers, adds their results and advances
+    both.  ``positions`` [b, t] (``position: rope``): where the tokens sit; q
+    and k are rotated BEFORE ``attend`` sees them, so a cache holds rotated
+    keys.  -> (x, pools, the expert layers' statistics, a list)."""
     dtype = x.dtype
     stats, m, a = [], 0, 0
+
+    def attention(p, y, pools, a):
+        attn = _in_dtype("attn", p, dtype)
+        q, k, v = (jnp.einsum("bsh,hnd->bsnd", y, attn[f"{n}_kernel"]) for n in "qkv")
+        if cfg.position == "rope":
+            q, k = (rope_at(t, positions, cfg.rope_theta) for t in (q, k))
+        out, pools = attend(q, k, v, pools, a)
+        return jnp.einsum("bsnd,ndh->bsh", out, attn["out_kernel"]), pools
+
     for kind, p in zip(cfg.layer_pattern, params["blocks"]):
         y = rms_norm(x, p["ln_1"]["scale"], cfg.norm_eps)
-        if kind == "M":
+        if kind == "P":
+            with jax.named_scope("pfx.parallel"):
+                out, pools = mixer(_in_dtype("ssm", p["ssm"], dtype), y, pools, m)
+                attended, pools = attention(p["attn"], y, pools, a)
+                out = out + attended
+            m, a = m + 1, a + 1
+        elif kind == "M":
             out, pools = mixer(_in_dtype("ssm", p["ssm"], dtype), y, pools, m)
             m += 1
         elif kind == "*":
-            attn = _in_dtype("attn", p["attn"], dtype)
-            q, k, v = (jnp.einsum("bsh,hnd->bsnd", y, attn[f"{n}_kernel"]) for n in "qkv")
-            out, pools = attend(q, k, v, pools, a)
-            out = jnp.einsum("bsnd,ndh->bsh", out, attn["out_kernel"])
+            out, pools = attention(p["attn"], y, pools, a)
             a += 1
         else:
             out, st = _block_mlp(p["mlp"], y, cfg, valid)
@@ -1325,7 +1377,8 @@ def _pattern_paged_forward_step(params, tokens, pools, block_tables, positions, 
             out = paged_decode_attention(q, pools.k, pools.v, block_tables, pos, layer=a)
         return out, pools
 
-    x, pools, stats = _pattern_stack(params, x, pools, active[:, None], cfg, mixer, attend)
+    x, pools, stats = _pattern_stack(params, x, pools, active[:, None], cfg, mixer, attend,
+                                     pos[:, None])
     return _block_logits(params, x, cfg), pools, _moe_counts(stats) if stats else None
 
 
@@ -1348,7 +1401,8 @@ def _pattern_paged_prefill(params, prompt, prompt_len, pools, table_row, slot, c
         raise ValueError(f"table_row covers {PB}x{bs}={PB * bs} slots < prompt bucket {P}")
     dtype = jnp.dtype(cfg.dtype)
     x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][prompt]
-    valid = jax.lax.iota(jnp.int32, P)[None] < prompt_len
+    positions = jax.lax.iota(jnp.int32, P)[None]
+    valid = positions < prompt_len
 
     kept = []  # each state-space layer's (state, conv columns), written at the end
 
@@ -1366,7 +1420,7 @@ def _pattern_paged_prefill(params, prompt, prompt_len, pools, table_row, slot, c
             k=pools.k.at[a, table_row].set(pages(k, pools.k)),
             v=pools.v.at[a, table_row].set(pages(v, pools.v)))
 
-    x, pools, stats = _pattern_stack(params, x, pools, valid, cfg, mixer, attend)
+    x, pools, stats = _pattern_stack(params, x, pools, valid, cfg, mixer, attend, positions)
     if kept:
         states, columns = (jnp.stack(v) for v in zip(*kept))
         pools = pools._replace(

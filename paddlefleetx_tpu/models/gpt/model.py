@@ -36,6 +36,7 @@ from paddlefleetx_tpu.models.common import (
     logical_axes,
     normal_init,
     ones_init,
+    slab_init,
     stack_spec_tree,
     zeros_init,
 )
@@ -164,8 +165,10 @@ def _layer_specs(cfg: GPTConfig) -> Dict[str, Any]:
 
 def _pattern_layer_specs(cfg: GPTConfig, kind: str) -> Dict[str, Any]:
     """One layer of a ``layer_pattern`` block: ONE sub-block behind one
-    RMSNorm.  Every layer has an ``mlp`` group (empty for a mixer layer):
-    an expert layer is one whose ``mlp`` holds a router."""
+    RMSNorm (a ``P`` layer's sub-block holds two mixers, an ``ssm`` AND an
+    ``attn`` group, side by side behind that norm).  Every layer has an
+    ``mlp`` group (empty for a mixer layer): an expert layer is one whose
+    ``mlp`` holds a router."""
     h, nh, nkv, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     w = normal_init(cfg.initializer_range)
     # the sub-block's output matrix: scaled down with the depth, so that the
@@ -173,22 +176,33 @@ def _pattern_layer_specs(cfg: GPTConfig, kind: str) -> Dict[str, Any]:
     w_out = normal_init(cfg.initializer_range / (
         cfg.num_layers ** 0.5 if cfg.rescale_prenorm_residual else 1.0))
     specs: Dict[str, Any] = {"ln_1": _rms_specs(h), "mlp": {}}
-    if kind == "M":
+    if kind in "MP":
         from paddlefleetx_tpu.models.gpt.ssm import mixer_specs
 
         specs["ssm"] = mixer_specs(cfg, w_out)
-    elif kind == "*":
+    if kind in "*P":
+        # a muP checkpoint's key_multiplier (0.011 as published) is a learning-rate
+        # device, not a model of small keys: trained, W_k has grown against it.  Drawn
+        # like the rest, the seeded scores' spread would be 0.02, every softmax uniform
+        # and a reference check blind to rotation, pages and the constant itself; so the
+        # seeded W_k is drawn against its constant (its FOLDED matrix is the plain draw)
+        w_k = normal_init(cfg.initializer_range / cfg.mup.get("key_multiplier", 1.0))
         specs["attn"] = {
             "q_kernel": ParamSpec((h, nh, hd), ("embed", "heads", "kv"), w),
-            "k_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
+            "k_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w_k),
             "v_kernel": ParamSpec((h, nkv, hd), ("embed", "heads", "kv"), w),
             "out_kernel": ParamSpec((nh, hd, h), ("heads", "kv", "embed"), w_out),
         }
-    else:
-        from paddlefleetx_tpu.models.gpt.moe import dropless_layer_specs, relu2_specs
+    if kind in "E-":
+        from paddlefleetx_tpu.models.gpt.moe import (
+            dropless_layer_specs, relu2_specs, swiglu_specs)
 
-        specs["mlp"] = (dropless_layer_specs(cfg, w_out) if kind == "E"
-                        else relu2_specs(h, cfg.ffn_hidden_size, w, w_out))
+        if kind == "E":
+            specs["mlp"] = dropless_layer_specs(cfg, w_out)
+        elif cfg.mlp_act == "relu2":
+            specs["mlp"] = relu2_specs(h, cfg.ffn_hidden_size, w, w_out)
+        else:
+            specs["mlp"] = swiglu_specs(h, cfg.ffn_hidden_size, w)
     return specs
 
 
@@ -197,7 +211,8 @@ def gpt_specs(cfg: GPTConfig) -> Dict[str, Any]:
     if cfg.layer_pattern:
         # layers of different kinds share no stack: the tree is made as it
         # is served, ``blocks`` a tuple of one dict a layer
-        word = ParamSpec((cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), w)
+        table = (cfg.vocab_size, cfg.hidden_size)
+        word = ParamSpec(table, ("vocab", "embed"), slab_init(w, table))
         return {
             "embeddings": {"word": word},
             "blocks": tuple(_pattern_layer_specs(cfg, kind) for kind in cfg.layer_pattern),
@@ -296,6 +311,20 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
     x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
     out = xf * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang)
     return out.astype(x.dtype)
+
+
+def rope_at(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """:func:`rope` at given ``positions`` [b, s] of x [b, s, n, d] (a decode
+    step rotates one token a row, each at its own position).  :func:`rope`
+    stays as it is written: the Trinity-Mini train step, a benchmark cell,
+    lowers through it (tests/test_program_text.py)."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jax.lax.iota(jnp.float32, half) / half)
+    ang = positions.astype(jnp.float32)[..., None, None] * inv_freq  # [b, s, 1, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
 def rope_frequencies(cfg: GPTConfig) -> jax.Array:

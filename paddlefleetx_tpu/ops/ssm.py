@@ -40,14 +40,17 @@ from jax.experimental import pallas as pl
 
 from paddlefleetx_tpu.utils import device as _device
 
-# lane groups one grid step holds: 16 x [128, 128] float32 are 1 MB in and
-# 1 MB out, twice for the pipeline, of the 16 MB a kernel may use
-_GROUPS_PER_STEP = 16
+# bytes of state one grid step holds: 1 MB in and 1 MB out, twice for the
+# pipeline, of the 16 MB a kernel may use (16 lane groups of [128, 128]
+# float32; 8 of [256, 128])
+_STEP_BYTES = 2 ** 20
 
 
-def _groups_per_step(r: int) -> int:
-    """The largest divisor of ``r`` lane groups that a grid step may hold."""
-    return max(d for d in range(1, min(r, _GROUPS_PER_STEP) + 1) if r % d == 0)
+def _groups_per_step(r: int, group_bytes: int) -> int:
+    """The largest divisor of ``r`` lane groups, of ``group_bytes`` each
+    ([state, W] in the states' dtype), that a grid step may hold."""
+    most = max(1, _STEP_BYTES // group_bytes)
+    return max(d for d in range(1, min(r, most) + 1) if r % d == 0)
 
 
 def lane_width(heads: int, head_dim: int) -> int:
@@ -115,7 +118,7 @@ def _decode_pallas(states, layer, live, xdt, dec, dx, b_group, c_group, heads, h
         raise ValueError(
             f"pfx_ssm_decode: a lane group of {w} holds {span} heads of {head_dim}, which "
             f"{per_group} heads a B/C group do not fill evenly; use impl='lax'")
-    rb = _groups_per_step(r)
+    rb = _groups_per_step(r, n * w * states.dtype.itemsize)
     nblk = r // rb
     # the B/C group of each lane group, spread as the columns of a block
     of = (jax.lax.iota(jnp.int32, r) * w // head_dim) // per_group  # [R]
@@ -170,7 +173,7 @@ def write_slot_states(states: jax.Array, new: jax.Array, slot, *, impl: str = "a
     from jax.experimental.pallas import tpu as pltpu
 
     layers, _, r, n, w = states.shape
-    rb = _groups_per_step(r)
+    rb = _groups_per_step(r, n * w * states.dtype.itemsize)
     page = pl.BlockSpec((None, None, rb, n, w), lambda l, j, slot_ref: (l, slot_ref[0], j, 0, 0))
     return pl.pallas_call(
         _write_kernel,

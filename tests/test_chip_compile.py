@@ -817,3 +817,156 @@ def test_serving_prefill_runs_its_grouped_products_in_the_kernel(topo, config, b
     held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
     assert held <= m.alias_size_in_bytes < 1.01 * held
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < fits, m.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# A block whose every layer keeps BOTH a recurrent state and pages
+# (docs/falcon_h1.md) at the published sizes of the benchmark's configuration
+# falcon-h1-34b: the state kernels at state 256 on heads of 128, the paged
+# kernel at 5 query heads a KV head, the 12-sub-block decode step and the one
+# prefill bucket of the cell
+# ---------------------------------------------------------------------------
+
+
+def _falcon_cfg():
+    import json
+
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    root = os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..")
+    bench = os.path.join(root, "pfx_bench")  # noqa: E10 — a directory, not a metric
+    with open(os.path.join(bench, "configs", "falcon-h1-34b.json")) as f:
+        return GPTConfig(**json.load(f)["model"])
+
+
+def test_ssm_state_kernels_at_state_256_on_heads_of_128(topo):
+    """``pfx_ssm_decode`` / ``pfx_ssm_write`` over [6, 64, R 32, state 256, W
+    128] float32 (1.6 GB, donated): a grid step holds 8 lane groups (1 MB in,
+    1 MB out: ``_STEP_BYTES``, where 16 groups would be 2 + 2 MB twice over
+    for the pipeline), the states come back aliased and no copy is made."""
+    import re
+
+    from paddlefleetx_tpu.ops import ssm
+
+    one = _one_chip(topo)
+    slots, heads, hd, n, groups = 64, 32, 128, 256, 2
+    assert ssm.packed_shape(heads, hd, n) == (32, 256, 128)
+    assert ssm._groups_per_step(32, 256 * 128 * 4) == 8 and ssm._groups_per_step(32, 2 ** 16) == 16
+    states = _shapes(one, ((6, slots) + ssm.packed_shape(heads, hd, n), jnp.float32))
+
+    def two_layers(st, x, dt, a, b, c, d, active):
+        live = ssm.live_slots(active)
+        for layer in range(2):
+            y, st = ssm.ssm_decode_update(st, x, dt, a, b, c, d, active=active, layer=layer,
+                                          live=live)
+            x = x + y.astype(x.dtype)
+        return y, st
+
+    args = _shapes(one, (((slots, heads, hd), BF16), ((slots, heads), jnp.float32),
+                         ((heads,), jnp.float32), ((slots, groups, n), BF16),
+                         ((slots, groups, n), BF16), ((heads,), jnp.float32),
+                         ((slots,), jnp.bool_)))
+    c = jax.jit(two_layers, donate_argnums=(0,)).lower(states, *args).compile()
+    text = c.as_text()
+    calls, lists = _ssm_decode_calls(text)
+    assert len(calls) == 2 and len(lists) == 1, lists
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 1e6 and m.alias_size_in_bytes >= 6 * slots * 2 ** 22
+    moved = re.findall(r"= \w+\[6,64,32,256,128\]\S* (copy|transpose|dynamic-update-slice)\(", text)
+    assert not moved, moved
+    new = _shapes(one, ((6,) + ssm.packed_shape(heads, hd, n), jnp.float32))
+    slot = _shapes(one, ((), jnp.int32))
+    w = jax.jit(ssm.write_slot_states, donate_argnums=(0,)).lower(states, new, slot).compile()
+    assert _has_kernel(w) and w.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_paged_decode_with_five_query_heads_a_kv_head_compiles(topo):
+    """20 query heads on the 4 KV heads of a [6, 385, 4, 128, 128] arena: the 5
+    queries of a KV head are the rows of one product against its page."""
+    from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention
+
+    one = _one_chip(topo)
+    q = _shapes(one, ((64, 1, 20, 128), BF16))
+    pool = _shapes(one, ((6, 385, 4, 128, 128), BF16))
+    tables = _shapes(one, ((64, 6), jnp.int32))
+    positions = _shapes(one, ((64,), jnp.int32))
+    c = _compile(lambda q, k, v, tb, ps: paged_decode_attention(q, k, v, tb, ps, layer=3),
+                 q, pool, pool, tables, positions)
+    assert _has_kernel(c) and c.memory_analysis().temp_size_in_bytes < 16e6
+
+
+def _falcon_shapes(topo, cfg, slots):
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    one = _one_chip(topo)
+    params = _shapes(one, jax.eval_shape(lambda: G.init_serving_params(cfg, jax.random.key(0))))
+    pools = _shapes(one, jax.eval_shape(
+        lambda: G.init_paged_pools(cfg, slots * 6 + 1, cfg.kv_block_default, slots=slots)))
+    return one, params, pools
+
+
+def test_decode_step_of_the_parallel_block_fits_and_copies_nothing(topo):
+    """The benchmark cell ``serve-falcon-h1-34b-6of72-chat``'s decode step as
+    its configuration file states it (6 published layers = 12 sub-blocks, 64
+    slots, the 261,120-row head), the pools DONATED: 10.5 GB of weights, 1.6 GB
+    of states and 0.6 GB of pages are arguments, the states and the arena come
+    back aliased and nothing of their shape is copied."""
+    import re
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    cfg = _falcon_cfg()
+    slots, width, vocab = 64, 6, cfg.vocab_size
+    one, params, pools = _falcon_shapes(topo, cfg, slots)
+    gen = G.GenerationConfig(decode_strategy="greedy_search", max_dec_len=0, min_dec_len=512,
+                             eos_token_id=0, pad_token_id=0)
+
+    def step(p, pools, tables, logits, counts, positions, gen_steps, max_news, active, forced):
+        rows = G.PagedRows(logits, counts, positions, gen_steps, max_news, active, forced)
+        nxt, pools, new = G.decode_step(p, pools, tables, rows, cfg, gen)
+        return nxt, pools, new.logits, new.counts, new.moe
+
+    i32 = functools.partial(lambda *shape: (shape, jnp.int32))
+    rows = _shapes(one, (i32(slots, width), ((slots, vocab), jnp.float32), i32(slots, vocab),
+                         i32(slots), i32(slots), i32(slots), ((slots,), jnp.bool_), i32(slots)))
+    c = jax.jit(step, donate_argnums=(1,)).lower(params, pools, *rows).compile()
+    m = c.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert 2.2e9 < held <= m.alias_size_in_bytes < 1.01 * held
+    assert 12.8e9 < m.argument_size_in_bytes < 13.1e9 and m.temp_size_in_bytes < 0.6e9
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 6 + 6  # a state update AND a paged read a P layer
+    # (the live list reaches some calls through a prefetch copy of its 64 numbers,
+    # so the operand's name does not show that it is made once; the nemotron case does)
+    assert len(_ssm_decode_calls(text)[0]) == 6
+    assert len(re.findall(r"%pfx_decode_paged\S* = ", text)) == 6
+    moved = re.findall(r"= \w+\[(?:6,64,32,256,128|6,385,4,128,128)\]\S* (copy|transpose)\(", text)
+    assert not moved, moved
+
+
+def test_prefill_of_the_parallel_block_at_the_cell_s_one_bucket(topo):
+    """The 256-token prefill (the cell's only bucket), pools DONATED: flash at
+    20/4, the chunked scan at state 256, the states written by ``pfx_ssm_write``
+    and the rotated keys into the row's pages; no whole-pool copy."""
+    import re
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    cfg = _falcon_cfg()
+    one, params, pools = _falcon_shapes(topo, cfg, 64)
+    i32 = lambda *shape: (shape, jnp.int32)  # noqa: E731
+
+    def prefill(p, prompt, plen, pools, row, slot):
+        return G.paged_prefill(p, prompt, plen, pools, row, cfg, return_moe=True, slot=slot)
+
+    prompt, plen, row, slot = _shapes(one, (i32(1, 256), i32(), i32(2), i32()))
+    c = jax.jit(prefill, donate_argnums=(3,)).lower(params, prompt, plen, pools, row, slot).compile()
+    text = c.as_text()
+    assert len(re.findall(r"%pfx_flash_fwd\S* = ", text)) == 6
+    assert len(re.findall(r"%pfx_ssm_write\S* = ", text)) == 1
+    moved = re.findall(r"= \w+\[(?:6,64,32,256,128|6,385,4,128,128)\]\S* (copy|transpose)\(", text)
+    assert not moved, moved
+    m = c.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert held <= m.alias_size_in_bytes < 1.01 * held
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.6e9, m.temp_size_in_bytes

@@ -46,11 +46,12 @@ def test_selftest_check(selftest, name, monkeypatch):
     assert selftest.FAILS == []
 
 
-@pytest.mark.parametrize("mix,cell,pad,buckets", [
-    ("think-long-answers", "serve-dsv3-1of32-think", 512, [1024, 1536, 2048, 2560, 3072]),
-    ("chat-short-answers", "serve-nemotron3-nano-1of8-chat", 256, [256, 512, 768, 1024]),
+@pytest.mark.parametrize("mix,cell,pad,buckets,of_knee", [
+    ("think-long-answers", "serve-dsv3-1of32-think", 512, [1024, 1536, 2048, 2560, 3072], 0.8),
+    ("chat-short-answers", "serve-nemotron3-nano-1of8-chat", 256, [256, 512, 768, 1024], 0.8),
+    ("chat-brief-turns", "serve-falcon-h1-34b-6of72-chat", 256, [256], 0.8),  # ONE bucket, one stall size
 ])
-def test_a_serving_cell_s_data_files(selftest, mix, cell, pad, buckets):
+def test_a_serving_cell_s_data_files(selftest, mix, cell, pad, buckets, of_knee):
     """A cell added after the self-test's own list of mixes: its traffic
     through the generator (the same seed the same plan, another seed the
     same sizes in another order, lengths inside the mix's bounds, the
@@ -73,7 +74,7 @@ def test_a_serving_cell_s_data_files(selftest, mix, cell, pad, buckets):
     assert all(t["max_tokens"]["min"] <= r["max_tokens"] <= t["max_tokens"]["max"]
                for r in a["requests"])
     assert loadgen.prompt_buckets(t, pad) == buckets
-    assert abs(t["rate_rps"] - 0.8 * t["knee_rps"]) < 1e-9 and t["knee_note"]
+    assert abs(t["rate_rps"] - of_knee * t["knee_rps"]) < 1e-9 and t["knee_note"]
     c = common.load_cell(cell)
     assert os.path.isfile(os.path.join(selftest.BENCH, "runners", c["runner"] + ".py"))
     assert os.path.isfile(os.path.join(selftest.BENCH, "runners", c["runner"] + "_child.py"))

@@ -1,0 +1,168 @@
+#!/usr/bin/env python3
+"""The program text of the benchmark's cells, as hashes.
+
+A change that means to leave a cell's programs alone can show it: each
+program below is LOWERED (StableHLO, shapes only, nothing runs or compiles)
+for a described ``v5e:2x2`` chip at the widths of its configuration's file,
+and the text is hashed.  Two trees that give the same hashes hand the TPU's
+compiler the same programs.
+
+    python tools/program_text.py                      # print the hashes of this tree
+    python tools/program_text.py --root <other tree>  # of another checkout (a parent commit)
+    python tools/program_text.py --write              # rewrite tests/program_text.json
+    python tools/program_text.py --check              # compare with it; exit 1 on a difference
+
+``tests/test_program_text.py`` runs the check in tier-1.  A PR that MEANS to
+change one of these programs rewrites the file and says so; a PR that does
+not (a new block family beside them) leaves the file as the parent has it,
+which is the proof.
+
+One thing is masked before hashing: the serialized body of each Mosaic
+kernel (``tpu_custom_call``'s ``body``), because it embeds the line numbers
+of the Python frames that called it, which move with any edit above them.  A
+kernel's own change is therefore NOT seen here (its file's diff shows it);
+its operands, shapes, grid-independent attributes and everything XLA gets
+around it are.
+"""
+
+import argparse
+import hashlib
+import json
+import os
+import re
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+GOLDEN = os.path.join(os.path.dirname(HERE), "tests", "program_text.json")
+BENCH = "pfx_bench"  # noqa: E10 — a directory, not a metric
+# configuration -> (batch slots, arena pages, prefill bucket, min_dec_len): one decode step
+# and one prefill program each, shaped like the cell's (falcon-h1-34b's were written by the PR
+# that added it, 40; the others from PR 38's commit, and PR 40 left them as they were)
+SERVING = {
+    "gpt-1.3b": (8, 8 * 8 + 1, 512, 32),
+    "deepseek-v3": (64, 64 * 36 + 1, 1024, 1536),
+    "nemotron-3-nano": (48, 48 * 14 + 1, 256, 768),
+    "falcon-h1-34b": (64, 64 * 6 + 1, 256, 512),
+}
+PROGRAMS = tuple(f"{c}.{p}" for c in SERVING for p in ("step", "prefill")) + ("trinity-mini.train_step",)
+_BODY = re.compile(r'\\22body\\22: \\22[^\\]*\\22')
+
+
+def digest(lowered) -> str:
+    return hashlib.sha256(_BODY.sub("BODY", lowered.as_text()).encode()).hexdigest()[:20]
+
+
+def lower(root: str, names=PROGRAMS) -> dict:
+    """{program name: hash} of the tree at ``root`` (imported from there)."""
+    sys.path.insert(0, root)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+    from paddlefleetx_tpu.utils import device as device_mod
+
+    if not os.path.abspath(G.__file__).startswith(os.path.abspath(root) + os.sep):
+        raise SystemExit(f"paddlefleetx_tpu came from {G.__file__}, not from {root}")
+    device_mod.pallas_interpret = lambda: False  # the chip's kernels, not interpret mode
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
+
+    def serving(config, what):
+        slots, blocks, bucket, min_dec = SERVING[config]
+        with open(os.path.join(root, BENCH, "configs", config + ".json")) as f:
+            cfg = GPTConfig(**json.load(f)["model"])
+        params = shapes(jax.eval_shape(lambda: G.init_serving_params(cfg, jax.random.key(0))))
+        bs = cfg.kv_block_default or 16
+        kw = {"slots": slots} if cfg.layer_pattern else {}
+        pools = shapes(jax.eval_shape(lambda: G.init_paged_pools(cfg, blocks, bs, **kw)))
+        if what == "prefill":
+            def prefill(p, prompt, plen, pools, row, slot):
+                row_state = {"slot": slot} if cfg.layer_pattern else {}
+                return G.paged_prefill(p, prompt, plen, pools, row, cfg, return_moe=True, **row_state)
+
+            return jax.jit(prefill, donate_argnums=(3,)).lower(
+                params, S((1, bucket), jnp.int32), S((), jnp.int32), pools,
+                S((-(-bucket // bs),), jnp.int32), S((), jnp.int32))
+        gen = G.GenerationConfig(decode_strategy="greedy_search", max_dec_len=0, min_dec_len=min_dec,
+                                 eos_token_id=0, pad_token_id=0)
+        width, vocab = (blocks - 1) // slots, cfg.vocab_size
+
+        def step(p, pools, tables, logits, counts, positions, gen_steps, max_news, active, forced):
+            rows = G.PagedRows(logits, counts, positions, gen_steps, max_news, active, forced)
+            nxt, pools, new = G.decode_step(p, pools, tables, rows, cfg, gen)
+            return nxt, pools, new.logits, new.counts, new.moe
+
+        def i32(*shape):
+            return S(shape, jnp.int32)
+
+        return jax.jit(step, donate_argnums=(1,)).lower(
+            params, pools, i32(slots, width), S((slots, vocab), jnp.float32), i32(slots, vocab),
+            i32(slots), i32(slots), i32(slots), S((slots,), jnp.bool_), i32(slots))
+
+    def train_step():
+        from paddlefleetx_tpu.core.engine import Engine
+        from paddlefleetx_tpu.core.module import build_module
+        from paddlefleetx_tpu.parallel.env import init_dist_env
+        from paddlefleetx_tpu.utils.config import get_config
+
+        with open(os.path.join(root, BENCH, "configs", "trinity-mini.json")) as f:
+            config = json.load(f)
+        cfg = get_config(
+            os.path.join(root, config["yaml"]),
+            overrides=[f"Model.{k}={v}" for k, v in config["model"].items()]
+            + ["Global.global_batch_size=2", "Global.local_batch_size=2", "Global.micro_batch_size=2"],
+            num_devices=1)
+        mesh = init_dist_env(cfg, devices=topo.devices[:1])
+        with mesh:
+            engine = Engine(cfg, build_module(cfg), mesh, abstract_init=True)
+            b, s = int(cfg.Global.global_batch_size), int(cfg.Data.Train.dataset.max_seq_len)
+            batch = {name: jax.ShapeDtypeStruct((b, s), dt, sharding=engine.batch_spec)
+                     for name, dt in (("tokens", np.int64), ("labels", np.int64),
+                                      ("loss_mask", np.float32), ("position_ids", np.int64))}
+            return engine._train_step.lower(engine.state, batch)
+
+    out = {}
+    for name in names:
+        config, what = name.rsplit(".", 1)
+        out[name] = digest(train_step() if what == "train_step" else serving(config, what))
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--root", default=os.path.dirname(HERE), help="the checkout to lower (default: this one)")
+    ap.add_argument("--write", action="store_true", help=f"rewrite {os.path.relpath(GOLDEN)}")
+    ap.add_argument("--check", action="store_true", help="compare with the kept hashes; exit 1 if any differs")
+    args = ap.parse_args(argv)
+    got = lower(os.path.abspath(args.root))
+    print(json.dumps(got, indent=1))
+    if args.write:
+        with open(GOLDEN, "w") as f:
+            json.dump(got, f, indent=1)
+            f.write("\n")
+    if args.check:
+        with open(GOLDEN) as f:
+            kept = json.load(f)
+        differ = sorted(n for n in set(got) | set(kept) if got.get(n) != kept.get(n))
+        if differ:
+            print("differs from tests/program_text.json:", ", ".join(differ), file=sys.stderr)
+            return 1
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
