@@ -1234,7 +1234,8 @@ class Engine:
             seen.append(info)
         seen = jax.device_get(seen)
         self._write_metrics({"event": "warm_start", "passes": len(seen),
-                             "seconds": round(time.monotonic() - t0, 3)})
+                             "seconds": round(time.monotonic() - t0, 3),
+                             "reported": jax.tree.map(lambda a: np.asarray(a).tolist(), seen)})
         return seen
 
     def fit(self, train_loader: Iterable, eval_loader: Optional[Iterable] = None):

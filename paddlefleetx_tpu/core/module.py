@@ -135,6 +135,7 @@ class GPTModule(BasicModule):
         if "moe_pairs_total" in record:
             for key, family in (("moe_pairs_total", "pfx_moe_pairs_total"),
                                 ("moe_pairs_held", "pfx_moe_pairs_held_total"),
+                                ("moe_buffer_rows", "pfx_moe_buffer_rows_total"),
                                 ("moe_load_max_over_mean_sum", "pfx_moe_load_max_over_mean_sum")):
                 registry.counter(family).set(record[key])
             registry.gauge("pfx_moe_bias_abs_max").set(record["moe_bias_abs_max"])

@@ -614,7 +614,8 @@ def _block_layer(p, x, cfg: GPTConfig, ctx, kind: Tuple[int, bool], expert_bias)
     from paddlefleetx_tpu.models.gpt.moe import dropless_moe_block, swiglu
 
     if "router_kernel" in p["mlp"]:
-        f, stats = dropless_moe_block(p["mlp"], m, cfg, ctx, expert_bias)
+        # the training call site: the sorted pairs' buffer follows the load
+        f, stats = dropless_moe_block(p["mlp"], m, cfg, ctx, expert_bias, load_ladder=True)
     else:
         f, stats = swiglu(m, p["mlp"]), None
     if cfg.post_norms:
@@ -1042,7 +1043,7 @@ def init_extra(cfg: GPTConfig) -> Dict[str, Any]:
     pair = jnp.zeros((2,), jnp.int32)
     return {
         "expert_bias": jnp.zeros((n_layers, cfg.num_experts), jnp.float32),
-        "counters": {"pairs_total": pair, "pairs_held": pair,
+        "counters": {"pairs_total": pair, "pairs_held": pair, "buffer_rows": pair,
                      "load_max_over_mean_sum": jnp.zeros((), jnp.float32),
                      "pairs_held_layer_max": jnp.zeros((), jnp.int32)},
     }
@@ -1069,6 +1070,9 @@ def next_extra(extra, stats, cfg: GPTConfig, train: bool):
         "counters": {
             "pairs_total": _count(c["pairs_total"], total),
             "pairs_held": _count(c["pairs_held"], jnp.sum(stats["pairs_held"])),
+            # the rows of the buffer each layer ran: pairs_held over it is the
+            # buffers' fill, it over pairs_total says which rungs ran
+            "buffer_rows": _count(c["buffer_rows"], jnp.sum(stats["buffer_rows"])),
             "load_max_over_mean_sum": c["load_max_over_mean_sum"]
             + jnp.max(stats["load_max_over_mean"]),
             # a gauge: the fullest layer's held pairs of THIS step
@@ -1095,7 +1099,7 @@ def warm_start_step(params, extra, tokens, i, cfg: GPTConfig, ctx=None):
 def extra_record(vals: Dict[str, Any]) -> Dict[str, Any]:
     """Host side: fetched ``extra_scalars`` -> step-record keys."""
     out = {f"moe_{k}": int(vals[k][0]) * _LO + int(vals[k][1])
-           for k in ("pairs_total", "pairs_held")}
+           for k in ("pairs_total", "pairs_held", "buffer_rows")}
     out["moe_load_max_over_mean_sum"] = round(float(vals["load_max_over_mean_sum"]), 4)
     out["moe_pairs_held_layer_max"] = int(vals["pairs_held_layer_max"])
     out["moe_bias_abs_max"] = round(float(vals["bias_abs_max"]), 6)

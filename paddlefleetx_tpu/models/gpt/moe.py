@@ -27,6 +27,7 @@ global norm.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -166,23 +167,43 @@ def moe_mlp_block(
 # sorted by expert into one buffer of static size, three grouped matrix
 # products run over the groups, and the rows go back to their tokens
 # weighted, accumulated in float32.  Pairs on experts held elsewhere add
-# nothing here; nothing stands in for the absent chips.  No token is
-# dropped: the buffer has the worst case's tokens x top_k rows (it fits the
-# cell's chip, tests/test_chip_compile.py), so gathers, masks and the
-# scatter cost what the worst case costs and only the grouped products
-# follow the load.  An expert is SwiGLU's three matrices (w1, w3, w2) or,
-# under ``mlp_act: relu2``, two: ``W_down relu(W_up m)^2`` (w1, w2).
+# nothing here; nothing stands in for the absent chips.  An expert is
+# SwiGLU's three matrices (w1, w3, w2) or, under ``mlp_act: relu2``, two:
+# ``W_down relu(W_up m)^2`` (w1, w2).
 #
-# Which grouped product runs is the CALLER's, by call site, never a size's:
+# No token is dropped and no step can fail for its load: the buffer can
+# always have the worst case's tokens x top_k rows (it fits the cell's chip,
+# tests/test_chip_compile.py).  Gathers, masks and the scatter cost what the
+# buffer's rows cost, whatever they hold, and only the grouped products
+# follow the load; so where the caller asks for it (``load_ladder``, the
+# training step) the buffer follows the step's load too: a short ladder of
+# static sizes (:func:`buffer_ladder`: twice the balanced share, then the
+# worst case; a compiled copy of the sorted path each) and, per call, the
+# smallest that holds the pairs the sort counted on held experts.
+# The ladder comes from shapes and the choice from the step's own count:
+# there is no size to set and nothing that can overflow.  The sort puts the
+# held pairs first, so a rung is the head of the worst case's buffer: per
+# pair arithmetic, the order of the float32 sums into a token and the cuts
+# of rows past the groups are the same at every rung, value and gradient.
+# Under ``grad`` a ``switch`` would hand every rung's residuals out of every
+# rung (zeros for those not taken: the worst case's gigabytes again), so
+# the laddered path is a ``custom_vjp`` that keeps its inputs alone and
+# differentiates the chosen rung INSIDE the backward pass's own branch.
+#
+# Which grouped product runs, and whether the ladder is engaged, is the
+# CALLER's, by call site, never a size's:
 # - training (``model.py``, differentiated; thousands of rows a group):
 #   ``jax.lax.ragged_dot``, XLA:TPU's own, which also derives the two
 #   transposed products of the backward pass and runs all three at its
-#   rate (4.7% of the trinity step);
+#   rate; the ladder (one compile of the step holds its rungs);
 # - a serving prefill (``generation._block_mlp``, forward only; 10-100 rows
 #   a group, bound by the matrices' bytes): ``ops/grouped_matmul.py``'s
 #   ``pfx_grouped_matmul``, which walks the held pairs only and reads each
 #   matrix once, where the served tree keeps it.  There ``ragged_dot`` took
-#   2 ms a call whatever its rows and wanted a copy of the matrices first;
+#   2 ms a call whatever its rows and wanted a copy of the matrices first.
+#   One buffer, the bucket's worst case: a prefill is compiled once a
+#   bucket and expert layer, each rung would be one more copy of its Mosaic
+#   bodies, and set-up is what those cells are short of;
 # - a serving decode step sorts nothing (``every_held_expert``).
 # ---------------------------------------------------------------------------
 
@@ -288,8 +309,100 @@ def _held_experts_on_every_token(ex, m, idx, w, held: int, offset: int):
         return jnp.einsum("enh,ne->nh", ys.astype(jnp.float32), w_te).astype(dtype)
 
 
+_ROW_TILE = 128  # rows; a whole number of any grouped product's row tiles
+
+
+def buffer_ladder(rows: int, held: int, num_experts: int) -> Tuple[int, ...]:
+    """The static sizes the sorted-pair buffer may take, smallest first:
+    twice the balanced share of ``rows`` pairs (``held`` of ``num_experts``
+    experts are here) rounded up to the row tile, and ``rows`` itself, the
+    worst case.  One rung where the first would already be the worst case
+    (every expert held, toy shapes).  No rung between the two: each is a
+    compiled copy of the sorted path, forward, recompute and backward (77 MB
+    of the trinity step's executable and 2-3 s of its set-up with a warm
+    compile cache, PERF.md section 6, PR 41), and no pass of that cell held
+    more than nine tenths of the first."""
+    first = -(-2 * rows * held // (num_experts * _ROW_TILE)) * _ROW_TILE
+    return (first, rows) if first < rows else (rows,)
+
+
+def _sorted_pairs(R: int, k: int, grouped_product, ex, m, w, order, group_sizes, n_held):
+    """The held pairs through their experts in a buffer of ``R`` rows (``R``
+    at least ``n_held``, static): m [N, h], w [N, k] float32, ``order`` the
+    stable sort of the N x k pairs that puts the held ones first, by expert
+    -> what the held experts give [N, h]."""
+    n, h = m.shape
+    dtype = m.dtype
+    with jax.named_scope("pfx.moe.dispatch"):
+        order = order[:R]
+        token = order // k
+        live = (jax.lax.iota(jnp.int32, R) < n_held)[:, None]
+        w_sorted = w.reshape(-1)[order][:, None]
+        xs = jnp.take(m, token, axis=0)
+    with jax.named_scope("pfx.moe.experts"):
+
+        def grouped(x, kernel):
+            # rows past the held pairs belong to no group, and on the TPU a
+            # grouped product leaves them as it found them, forward and
+            # backward: cut them going in and coming out, so that neither
+            # a value nor a cotangent of such a row ever reaches a token
+            y = grouped_product(jnp.where(live, x, 0), kernel.astype(dtype), group_sizes)
+            return jnp.where(live, y, 0)
+
+        if "w3" in ex:
+            hidden = jax.nn.silu(grouped(xs, ex["w1"])) * grouped(xs, ex["w3"])
+        else:
+            hidden = jnp.square(jax.nn.relu(grouped(xs, ex["w1"])))
+        ys = grouped(hidden, ex["w2"])
+    with jax.named_scope("pfx.moe.combine"):
+        out = jnp.zeros((n, h), jnp.float32).at[token].add(ys.astype(jnp.float32) * w_sorted)
+        return out.astype(dtype)
+
+
+def _rung(rungs: Tuple[int, ...], n_held: jax.Array) -> jax.Array:
+    """Index of the smallest rung that holds ``n_held`` pairs."""
+    return sum((n_held > R).astype(jnp.int32) for R in rungs[:-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _laddered_pairs(rungs, k, grouped_product, ex, m, w, order, group_sizes, n_held):
+    """:func:`_sorted_pairs` at the smallest of ``rungs`` that holds the
+    pairs.  Differentiated by its own rule: left to JAX, the forward
+    ``switch`` would return every rung's residuals from every rung."""
+    return jax.lax.switch(
+        _rung(rungs, n_held),
+        [functools.partial(_sorted_pairs, R, k, grouped_product) for R in rungs],
+        ex, m, w, order, group_sizes, n_held)
+
+
+def _laddered_pairs_fwd(rungs, k, grouped_product, *args):
+    return _laddered_pairs(rungs, k, grouped_product, *args), args
+
+
+def _laddered_pairs_bwd(rungs, k, grouped_product, args, g):
+    ex, m, w, order, group_sizes, n_held = args
+
+    def backward(R):
+        def run(ex, m, w, g):  # the chosen rung again, and its transpose, in here
+            def rung(ex, m, w):
+                return _sorted_pairs(R, k, grouped_product, ex, m, w, order, group_sizes, n_held)
+            return jax.vjp(rung, ex, m, w)[1](g)
+        return run
+
+    grads = jax.lax.switch(_rung(rungs, n_held), [backward(R) for R in rungs], ex, m, w, g)
+    # XLA:TPU otherwise sinks what follows into every branch: under the layer
+    # scan that is the padding of each matrix's cotangent to the stack's
+    # shape, four times its bytes out of each rung
+    grads = jax.lax.optimization_barrier(grads)
+    return (*grads, None, None, None)
+
+
+_laddered_pairs.defvjp(_laddered_pairs_fwd, _laddered_pairs_bwd)
+
+
 def routed_experts(p: Dict[str, Any], m: jax.Array, bias: jax.Array, cfg, valid=None,
-                   every_held_expert: bool = False, grouped_product=jax.lax.ragged_dot):
+                   every_held_expert: bool = False, grouped_product=jax.lax.ragged_dot,
+                   load_ladder: bool = False):
     """m [N, h] -> (what the held experts give [N, h], the step's load
     statistics).  ``load`` counts the pairs of every expert, held or not.
     ``valid`` [N] bool (serving: a fixed-shape batch with empty rows, a
@@ -299,11 +412,13 @@ def routed_experts(p: Dict[str, Any], m: jax.Array, bias: jax.Array, cfg, valid=
     runs each held expert on every token instead of sorting the pairs.
     ``grouped_product(rows [R, k], matrices [held, k, n], group_sizes)``:
     the product over the sorted pairs; the serving prefill hands in its
-    forward-only kernel (``ops/grouped_matmul.py``)."""
-    n, h = m.shape
-    dtype = m.dtype
+    forward-only kernel (``ops/grouped_matmul.py``).
+    ``load_ladder`` (the training step asks for it; static): the sorted
+    pairs' buffer is the smallest rung of :func:`buffer_ladder` that holds
+    this call's held pairs, not always the worst case; ``buffer_rows`` in
+    the statistics says which ran."""
     k, E, held, offset = cfg.moe_top_k, cfg.num_experts, cfg.experts_held, cfg.moe_expert_offset
-    rows = n * k  # every pair may land on a held expert
+    rows = m.shape[0] * k  # every pair may land on a held expert
     with jax.named_scope("pfx.moe.route"):
         idx, w = sigmoid_route(m, p["router_kernel"], bias, cfg)
         if valid is not None:
@@ -325,44 +440,32 @@ def routed_experts(p: Dict[str, Any], m: jax.Array, bias: jax.Array, cfg, valid=
         order = jnp.argsort(jnp.where(is_held, local, held), stable=True)
         group_sizes = load[offset:offset + held]
         n_held = jnp.sum(group_sizes)
-        token = order // k
-        live = (jax.lax.iota(jnp.int32, rows) < n_held)[:, None]
-        w_sorted = w.reshape(-1)[order][:, None]
-        xs = jnp.take(m, token, axis=0)
-    with jax.named_scope("pfx.moe.experts"):
-        ex = p["experts"]
-
-        def grouped(x, kernel):
-            # rows past the held pairs belong to no group, and on the TPU a
-            # grouped product leaves them as it found them, forward and
-            # backward: cut them going in and coming out, so that neither
-            # a value nor a cotangent of such a row ever reaches a token
-            y = grouped_product(jnp.where(live, x, 0), kernel.astype(dtype), group_sizes)
-            return jnp.where(live, y, 0)
-
-        if "w3" in ex:
-            hidden = jax.nn.silu(grouped(xs, ex["w1"])) * grouped(xs, ex["w3"])
-        else:
-            hidden = jnp.square(jax.nn.relu(grouped(xs, ex["w1"])))
-        ys = grouped(hidden, ex["w2"])
-    with jax.named_scope("pfx.moe.combine"):
-        out = jnp.zeros((n, h), jnp.float32).at[token].add(ys.astype(jnp.float32) * w_sorted)
-        out = out.astype(dtype)
+    rungs = buffer_ladder(rows, held, E) if load_ladder else (rows,)
+    pairs = (m, w, order, group_sizes, n_held)
+    if len(rungs) == 1:
+        out = _sorted_pairs(rows, k, grouped_product, p["experts"], *pairs)
+    else:
+        # the matrices are cast out here, so that a rung hands their
+        # cotangents back in the products' dtype, as the one buffer does (in
+        # float32 the layer scan would hold twice the bytes a layer)
+        ex = jax.tree.map(lambda a: a.astype(m.dtype), p["experts"])
+        out = _laddered_pairs(rungs, k, grouped_product, ex, *pairs)
     stats = {
         "load": load,
         "pairs_held": n_held,
         "load_max_over_mean": jnp.max(group_sizes) * held
         / jnp.maximum(n_held, 1).astype(jnp.float32),
+        "buffer_rows": jnp.asarray(rungs, jnp.int32)[_rung(rungs, n_held)],
     }
     return out, stats
 
 
 def dropless_moe_block(p: Dict[str, Any], x: jax.Array, cfg, ctx, bias: jax.Array,
                        valid=None, every_held_expert: bool = False,
-                       grouped_product=jax.lax.ragged_dot):
+                       grouped_product=jax.lax.ragged_dot, load_ladder: bool = False):
     """x [b, s, h] -> (shared expert + held routed experts [b, s, h], stats).
-    ``valid`` [b, s], ``every_held_expert`` and ``grouped_product``: see
-    :func:`routed_experts`."""
+    ``valid`` [b, s], ``every_held_expert``, ``grouped_product`` and
+    ``load_ladder``: see :func:`routed_experts`."""
     if ctx is not None and ctx.mesh.size > 1:
         raise NotImplementedError(
             "the dropless expert layer runs one chip's share per process; the "
@@ -372,7 +475,7 @@ def dropless_moe_block(p: Dict[str, Any], x: jax.Array, cfg, ctx, bias: jax.Arra
     m = x.reshape(b * s, h)
     out, stats = routed_experts(
         p, m, bias, cfg, None if valid is None else valid.reshape(b * s), every_held_expert,
-        grouped_product)
+        grouped_product, load_ladder)
     if cfg.moe_shared_experts:
         with jax.named_scope("pfx.moe.shared"):
             out = out + feed_forward(m, p["shared"])

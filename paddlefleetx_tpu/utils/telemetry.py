@@ -275,6 +275,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_train_host_gap_seconds_total": ("counter", "Fit-loop seconds from a blocking log fetch returning to the next step's dispatch having returned (the device has nothing queued)"),
     "pfx_moe_pairs_total": ("counter", "Token-expert pairs the dropless expert layers routed, over all experts and layers"),
     "pfx_moe_pairs_held_total": ("counter", "Routed pairs that landed on experts this process holds"),
+    "pfx_moe_buffer_rows_total": ("counter", "Rows of the sorted-pair buffer each expert layer ran (the rung of the ladder its load chose), over layers and steps: pfx_moe_pairs_held_total over it is the buffers' fill"),
     "pfx_moe_load_max_over_mean_sum": ("counter", "Sum over steps of the largest held expert's pairs over the held experts' mean (max over layers)"),
     "pfx_moe_bias_abs_max": ("gauge", "Largest absolute routing bias over experts and layers"),
     "pfx_moe_serve_pairs_total": ("counter", "Serving: token-expert pairs the expert layers routed for live rows and real prompt tokens, over all experts and layers (prefills and decode steps)"),

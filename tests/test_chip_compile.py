@@ -422,10 +422,18 @@ def test_documented_single_chip_config_fits_one_chip(topo):
 def test_trinity_mini_share_fits_one_chip(topo):
     """The benchmark cell ``train-trinity-mini-1of8`` as its yaml and its
     configuration file state it (2 x 8192 tokens, published widths, 16 of
-    128 experts, full recompute, the sorted-pair buffer at its worst case of
-    131,072 rows a layer): the real train step compiles for one 16 GB chip
-    (14.2 GiB of the 15.75 the compiler may use), with the flash kernels (window and full, 32/4 heads, whole
-    8192-position K/V in VMEM) and the grouped products as Mosaic calls."""
+    128 experts, full recompute): the real train step compiles for one 16 GB
+    chip, with the flash kernels (window and full, 32/4 heads, whole
+    8192-position K/V in VMEM) and the grouped products as Mosaic calls.
+    The sorted-pair buffer follows the load (``moe.buffer_ladder``: 32,768
+    or the worst case's 131,072 rows a layer): each expert layer's
+    sorted path is a conditional with one computation a rung, in the
+    forward pass, in its recompute and in the backward pass, and the
+    compiler counts no more bytes than for the one worst-case buffer
+    (15,246,225,408 at the parent of PR 41, 14.2 GiB of the 15.75 it may
+    use): a rung's residuals stay inside its branch."""
+    import re
+
     c = _step_trinity(topo)
     text = c.as_text()
     assert "pfx_flash_fwd" in text and "pfx_flash_bwd_dkv" in text  # noqa: E10 — kernel names
@@ -433,10 +441,15 @@ def test_trinity_mini_share_fits_one_chip(topo):
     # the forward-only kernel is the serving prefill's: training needs the two
     # transposed products that XLA derives from its own
     assert "pfx_grouped_matmul" not in text  # noqa: E10 — a kernel's name
+    branches = re.findall(r" conditional\(.*?branch_computations=\{([^}]*)\}", text)
+    assert len(branches) == 4 * 3  # expert layers x (forward, recompute, backward)
+    assert all(len(b.split(",")) == 2 for b in branches)  # a computation a rung
+    for rows in (32768, 131072):
+        assert f"bf16[{rows},2048]" in text
     m = c.memory_analysis()
     held = m.argument_size_in_bytes + m.temp_size_in_bytes + m.generated_code_size_in_bytes
     assert 8e9 < m.argument_size_in_bytes < 9e9  # 705.5 M x 12 bytes of state
-    assert held < 15.5 * 2**30, held  # of the 15.75 GiB the compiler may use
+    assert held <= 15_246_225_408, held
 
 
 def _mask_draws(text):

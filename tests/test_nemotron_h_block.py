@@ -536,9 +536,11 @@ def test_a_prefill_sorts_its_pairs_and_a_decode_step_runs_every_held_expert(toy,
     seen = []
     real = moe.routed_experts
 
-    def spy(p, m, bias, cfg, valid=None, every_held_expert=False, grouped_product=None):
-        # both are handed the forward-only kernel; only a prefill's sorted path runs it
-        assert grouped_product is grouped_matmul
+    def spy(p, m, bias, cfg, valid=None, every_held_expert=False, grouped_product=None,
+            load_ladder=False):
+        # both are handed the forward-only kernel; only a prefill's sorted path runs it,
+        # over one buffer (the ladder of sizes is the training step's)
+        assert grouped_product is grouped_matmul and not load_ladder
         seen.append(every_held_expert)
         return real(p, m, bias, cfg, valid, every_held_expert, grouped_product)
 

@@ -296,11 +296,10 @@ def test_dropless_when_every_token_picks_one_held_expert():
     assert float(stats["load_max_over_mean"]) == pytest.approx(4.0)  # 128 / (128 / 4)
 
 
-def test_rows_past_the_groups_never_reach_a_token(monkeypatch):
-    """On the TPU a grouped product leaves the buffer rows past the last
-    group as it found them (here: NaN, forward and backward); the CPU
-    zero-fills them and would hide it.  Values and gradients must not
-    care."""
+def _dirty_ragged_dot():
+    """``jax.lax.ragged_dot`` as the TPU runs it: the buffer rows past the
+    last group come back as they were found (here: NaN, forward and
+    backward); the CPU zero-fills them and would hide it."""
     real = jax.lax.ragged_dot
 
     @jax.custom_vjp
@@ -319,6 +318,12 @@ def test_rows_past_the_groups_never_reach_a_token(monkeypatch):
         return jnp.where(dead, jnp.nan, dx), dw, None
 
     dirty.defvjp(fwd, bwd)
+    return dirty
+
+
+def test_rows_past_the_groups_never_reach_a_token():
+    """On the TPU a grouped product leaves the buffer rows past the last
+    group as it found them; values and gradients must not care."""
     cfg = GPTConfig(**TOY)
     p = _layer_params(cfg)
     m = jax.random.normal(jax.random.PRNGKey(6), (128, 64), jnp.float32)
@@ -330,9 +335,9 @@ def test_rows_past_the_groups_never_reach_a_token(monkeypatch):
     want = jax.value_and_grad(
         lambda p, m: loss(lambda p, m: ref.routed_experts(m, p, bias, SIZES), p, m),
         argnums=(0, 1))(p, m)
-    monkeypatch.setattr(jax.lax, "ragged_dot", dirty)
     got = jax.value_and_grad(
-        lambda p, m: loss(lambda p, m: moe.routed_experts(p, m, bias, cfg)[0], p, m),
+        lambda p, m: loss(lambda p, m: moe.routed_experts(
+            p, m, bias, cfg, grouped_product=_dirty_ragged_dot())[0], p, m),
         argnums=(0, 1))(p, m)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert bool(jnp.all(jnp.isfinite(a)))
@@ -352,6 +357,164 @@ def test_dropless_at_the_worst_case_every_pair_on_a_held_expert():
         np.asarray(got), np.asarray(ref.routed_experts(m, p, bias, SIZES)), atol=1e-5, rtol=0)
     assert int(stats["pairs_held"]) == 128 * 2
     assert list(np.asarray(stats["load"])) == [0, 0, 0, 128, 128, 0, 0, 0]
+
+
+# ---------------------------------------------------------------------------
+# The buffer's ladder (moe.buffer_ladder, routed_experts(load_ladder=True)):
+# 2 of 32 experts held, so 4,096 pairs have the rungs 512 / 4,096
+# ---------------------------------------------------------------------------
+
+LADDER_TOY = dict(TOY, num_experts=32, moe_experts_held=2, moe_expert_offset=2)
+LADDER_SIZES = dict(LADDER_TOY, norm_eps=1e-5, rope_theta=10000.0)
+
+
+@pytest.mark.parametrize("shape,rungs", [
+    ((131072, 16, 128), (32768, 131072)),  # the benchmark's cell
+    ((4096, 2, 32), (512, 4096)),  # twice the balanced share, then the worst case
+    ((65536, 16, 128), (16384, 65536)),  # the cell's reference check: one sequence
+    ((256, 2, 16), (128, 256)),
+    ((3000, 1, 16), (384, 3000)),  # 375 rounded up to the row tile
+    ((256, 4, 8), (256,)),  # twice the balanced share is the worst case: one rung
+    ((256, 8, 8), (256,)),  # every expert held
+    ((16, 1, 128), (16,)),  # under a tile
+])
+def test_the_ladder_comes_from_shapes(shape, rungs):
+    assert moe.buffer_ladder(*shape) == rungs
+
+
+def _ladder_layer():
+    cfg = GPTConfig(**LADDER_TOY)
+    m = jax.random.normal(jax.random.PRNGKey(6), (2048, 64), jnp.float32)
+    return cfg, _layer_params(cfg), m
+
+
+def _value_and_grads(cfg, p, m, bias, ct, **kw):
+    def loss(p, m):
+        out, stats = moe.routed_experts(p, m, bias, cfg, **kw)
+        return jnp.sum(out * ct), (out, stats)
+
+    (_, (out, stats)), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(p, m)
+    return out, stats, grads
+
+
+def _assert_same_step(got, want):
+    """Value to the bit; gradient to the last bits: a rung is the head of
+    the worst case's buffer, so every pair's arithmetic and the order of
+    each token's float32 sum are the same, but the CPU's transposed grouped
+    product sums a group's rows in blocks that follow the buffer's LENGTH
+    (w1 and w3 differ in the last bits of a few entries).  2e-6 of a leaf's
+    largest entry is sixteen float32 roundings; a pair lost or counted twice
+    moves a leaf by 1e-3 of it."""
+    assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    flat, treedef = jax.tree.flatten(got[1])
+    assert treedef == jax.tree.structure(want[1])
+    for g, w in zip(flat, jax.tree.leaves(want[1])):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-30
+        assert float(jnp.max(jnp.abs(g - w))) <= 2e-6 * scale
+
+
+# a bias on the two held experts moves their share of the 4,096 pairs
+@pytest.mark.parametrize("held_bias,rung", [(0.0, 512), (0.015, 512), (0.03, 4096), (0.08, 4096)])
+def test_every_rung_gives_the_worst_case_buffers_value_and_gradient(held_bias, rung):
+    """Every leaf's gradient, the router's kernel and the tokens' among
+    them, and the load statistics that the counters read."""
+    cfg, p, m = _ladder_layer()
+    bias = jnp.zeros((32,)).at[2:4].set(held_bias)
+    ct = jax.random.normal(jax.random.PRNGKey(7), m.shape, jnp.float32)
+    out, stats, grads = _value_and_grads(cfg, p, m, bias, ct, load_ladder=True)
+    want_out, want_stats, want_grads = _value_and_grads(cfg, p, m, bias, ct)
+    assert int(stats["buffer_rows"]) == rung and int(want_stats["buffer_rows"]) == 4096
+    assert (int(stats["pairs_held"]) <= 512) == (rung == 512)  # the smallest that holds
+    _assert_same_step((out, grads), (want_out, want_grads))
+    assert float(jnp.max(jnp.abs(grads[0]["router_kernel"]))) > 0
+    for key in ("load", "pairs_held", "load_max_over_mean"):
+        assert np.array_equal(np.asarray(stats[key]), np.asarray(want_stats[key]))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(
+        ref.routed_experts(m, p, bias, LADDER_SIZES)), atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("pairs,rung", [
+    (0, 512), (1, 512), (511, 512), (512, 512), (513, 4096), (2048, 4096)])
+def test_a_load_at_a_rungs_size_runs_it_and_one_pair_more_the_next(pairs, rung):
+    """Every token's first choice is held expert 3 and its second an expert
+    held elsewhere, so the held pairs are exactly the ``valid`` tokens."""
+    cfg, p, m = _ladder_layer()
+    bias = jnp.zeros((32,)).at[3].set(10.0).at[7].set(5.0)
+    valid = jnp.arange(2048) < pairs
+    ct = jnp.ones_like(m)
+    out, stats, grads = _value_and_grads(cfg, p, m, bias, ct, valid=valid, load_ladder=True)
+    assert int(stats["pairs_held"]) == pairs and int(stats["buffer_rows"]) == rung
+    want = _value_and_grads(cfg, p, m, bias, ct, valid=valid)
+    _assert_same_step((out, grads), (want[0], want[2]))
+
+
+def test_the_last_rung_holds_every_pair_and_drops_none():
+    """Both choices of every token are held here: all 4,096 pairs, the
+    worst case, and the reference's answer."""
+    cfg, p, m = _ladder_layer()
+    bias = jnp.zeros((32,)).at[2].set(10.0).at[3].set(5.0)
+    out, stats = moe.routed_experts(p, m, bias, cfg, load_ladder=True)
+    assert int(stats["pairs_held"]) == int(stats["buffer_rows"]) == 4096
+    assert list(np.asarray(stats["load"][:5])) == [0, 0, 2048, 2048, 0]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(
+        ref.routed_experts(m, p, bias, LADDER_SIZES)), atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("held_bias,rung", [(0.0, 512), (0.05, 4096), (10.0, 4096)])
+def test_invalid_tokens_and_rows_past_the_groups_reach_nothing_at_any_rung(held_bias, rung):
+    """A grouped product that returns NaN past its groups (the TPU's leaves
+    them as found), half of the tokens not ``valid``: at every rung the
+    values and every cotangent are finite and the clean worst case's, an
+    invalid token gets zeros and gives its row of ``m`` no cotangent."""
+    cfg, p, m = _ladder_layer()
+    bias = jnp.zeros((32,)).at[2:4].set(held_bias)
+    valid = jnp.arange(2048) % 2 == 0
+    ct = jax.random.normal(jax.random.PRNGKey(7), m.shape, jnp.float32)
+    out, stats, grads = _value_and_grads(
+        cfg, p, m, bias, ct, valid=valid, load_ladder=True, grouped_product=_dirty_ragged_dot())
+    assert int(stats["buffer_rows"]) == rung
+    for leaf in jax.tree.leaves((out, grads)):
+        assert bool(jnp.all(jnp.isfinite(leaf)))
+    want = _value_and_grads(cfg, p, m, bias, ct, valid=valid)
+    _assert_same_step((out, grads), (want[0], want[2]))
+    assert not np.any(np.asarray(out)[1::2]) and not np.any(np.asarray(grads[1])[1::2])
+    assert np.any(np.asarray(grads[1])[0::2])
+
+
+def test_the_ladder_under_jit_remat_and_the_layer_scan(monkeypatch):
+    """The training step's own path (``loss_fn`` under ``jit``, full
+    recompute, the period's ``lax.scan``; 512 pairs a layer: rungs 128 /
+    512) with each rung taken by some layer: loss, every leaf's
+    gradient and the load statistics are the worst-case buffer's (the
+    ladder cut to its last rung), and the reference's."""
+    cfg = GPTConfig(**dict(LADDER_TOY, use_recompute=True, recompute_granularity="full"))
+    params = gpt.init(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    seq = rng.integers(1, 256, size=(2, 129))
+    batch = {"tokens": jnp.asarray(seq[:, :-1]), "labels": jnp.asarray(seq[:, 1:]),
+             "loss_mask": jnp.ones((2, 128), jnp.float32)}
+    bias = jnp.zeros((4, 32)).at[1, 2:4].set(10.0).at[2, 2:4].set(0.05)
+    extra = dict(gpt.init_extra(cfg), expert_bias=bias)
+
+    def step():
+        return jax.jit(jax.value_and_grad(
+            lambda p: gpt.loss_fn(p, batch, cfg, extra=extra, train=True), has_aux=True))(params)
+
+    (loss, new), grads = step()
+    stats = jax.jit(lambda p: gpt.forward_hidden(p, batch["tokens"], cfg, expert_bias=bias)[1])(params)
+    assert list(np.asarray(stats["buffer_rows"])) == [128, 512, 512, 128]
+    assert gpt.extra_record(gpt.extra_scalars(new))["moe_buffer_rows"] == 128 + 512 + 512 + 128
+    monkeypatch.setattr(moe, "buffer_ladder", lambda rows, held, num_experts: (rows,))
+    (want_loss, want_new), want_grads = step()
+    assert gpt.extra_record(gpt.extra_scalars(want_new))["moe_buffer_rows"] == 4 * 512
+    assert float(loss) == float(want_loss)
+    _assert_same_step((loss, grads), (want_loss, want_grads))
+    assert np.array_equal(np.asarray(new["expert_bias"]), np.asarray(want_new["expert_bias"]))
+    ref_loss, ref_grads = jax.value_and_grad(lambda p: ref.loss(
+        p, batch["tokens"], batch["labels"], batch["loss_mask"], LADDER_SIZES, bias))(params)
+    assert abs(float(loss) - float(ref_loss)) < 1e-5
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+        assert float(jnp.max(jnp.abs(g - w))) / (float(jnp.max(jnp.abs(w))) + 1e-12) < 1e-4
 
 
 # ---------------------------------------------------------------------------
@@ -440,8 +603,37 @@ def test_bias_is_engine_state_outside_the_optimizer(tmp_path):
     recs = [r for r in _records(metrics) if "loss" in r]
     assert [r["moe_pairs_total"] for r in recs] == [4 * 256 * 2 * (i + 1) for i in range(3)]
     assert recs[-1]["moe_pairs_held"] > 0 and "moe_pairs_held_layer_max" in recs[-1]
+    # 4 of 8 held: twice the balanced share is the worst case, the one rung
+    assert [r["moe_buffer_rows"] for r in recs] == [r["moe_pairs_total"] for r in recs]
     assert recs[0]["moe_bias_abs_max"] == pytest.approx(0.001)
     assert recs[-1]["moe_load_max_over_mean_sum"] >= 3.0  # a max over a mean, 3 steps
+
+
+def test_buffer_rows_ride_the_records_and_a_skipped_step_takes_them_back(tmp_path):
+    """2 of 32 experts held (512 pairs a layer: rungs 128 / 512):
+    ``moe_buffer_rows`` counts the rung each layer ran, is published beside
+    the held pairs, and a step skipped for a NaN loss leaves it where it
+    was, with the bias and the other counters."""
+    from paddlefleetx_tpu.utils.resilience import poison_batch
+
+    engine, mesh, metrics = _engine(
+        tmp_path, "Model.moe_bias_warm_start_steps=0", "Model.num_experts=32",
+        "Model.moe_experts_held=2")
+    good = _batches(1)[0]
+    with mesh:
+        engine.fit([good, poison_batch(good), good], None)
+    recs = [r for r in _records(metrics) if "loss" in r]
+    assert [np.isfinite(r["loss"]) for r in recs] == [True, False, True]
+    counters = ("moe_pairs_total", "moe_pairs_held", "moe_buffer_rows",
+                "moe_load_max_over_mean_sum", "moe_bias_abs_max")
+    assert recs[0]["moe_buffer_rows"] == 4 * 128  # every layer at the first rung
+    assert recs[0]["moe_pairs_held"] <= recs[0]["moe_buffer_rows"] < recs[0]["moe_pairs_total"]
+    assert [recs[1][k] for k in counters] == [recs[0][k] for k in counters]
+    assert recs[2]["moe_buffer_rows"] == 2 * 4 * 128
+    assert recs[2]["moe_pairs_total"] == 2 * 4 * 256 * 2
+    text = engine._registry.render_prometheus()
+    assert f"pfx_moe_buffer_rows_total {recs[2]['moe_buffer_rows']}" in text
+    assert f"pfx_moe_pairs_held_total {recs[2]['moe_pairs_held']}" in text
 
 
 def test_bias_warm_start_runs_once_before_the_first_step(tmp_path):
@@ -470,6 +662,8 @@ def test_bias_warm_start_runs_once_before_the_first_step(tmp_path):
         assert engine.warm_start(iter(batches)) is None
     events = [r for r in _records(metrics) if r.get("event") == "warm_start"]
     assert len(events) == 1 and events[0]["passes"] == 4
+    # what each pass reported: the pairs each of the 4 expert layers held
+    assert np.asarray(events[0]["reported"]).shape == (4, 4)
     recs = [r for r in _records(metrics) if "loss" in r]
     assert [r["step"] for r in recs] == [1, 2, 3]
     assert recs[-1]["consumed_samples"] == (4 + 3) * 2
