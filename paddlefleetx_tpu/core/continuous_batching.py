@@ -237,6 +237,9 @@ class _Row:
     prefill_pos: int = 0
     chunk: int = 0
     prefill_done: bool = True
+    # a model with window layers: the row's ring of window-layer pages (the
+    # second class; ``PagedCacheManager.ring``), fixed for its life
+    ring: List[int] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass(eq=False)
@@ -345,8 +348,18 @@ class PagedDecodeEngine:
         # fixed batch capacity (dp-world multiple): the step's batch dim
         # NEVER changes shape, so traffic mix cannot key batch retraces
         self.capacity = -(-int(max_batch) // dpw) * dpw
+        # a model with window layers keeps a SECOND class of pages, a ring of
+        # ring_pages a row (docs/mellum2.md).  The arena divides between the
+        # classes by what a full row of the served cap needs of each: on auto
+        # every slot's growing pages and every slot's ring; under --kv-blocks
+        # (the growing class's count) the rings of as many rows as that holds
+        self.ring_pages = self.mcfg.ring_pages(self.block)
+        ring_rows = self.capacity
         if num_blocks <= 0:
             num_blocks = self.capacity * self.max_row_blocks + 1
+        elif self.ring_pages:
+            ring_rows = min(self.capacity, max(1, (num_blocks - 1) // self.max_row_blocks))
+        ring_blocks = ring_rows * self.ring_pages + 1 if self.ring_pages else 0
         # shared-prefix KV reuse + chunked prefill (docs/serving.md):
         # prefix_cache_blocks > 0 lets finished rows publish their
         # prompt-prefix blocks into a radix index later admissions map
@@ -398,6 +411,7 @@ class PagedDecodeEngine:
         self.cache = PagedCacheManager(
             num_blocks, self.block, prefix_blocks=prefix_cache_blocks,
             spill_bytes=prefix_spill_bytes,
+            ring_blocks=ring_blocks, ring_pages=self.ring_pages,
         )
         if self.cache.spill.enabled:
             self.cache.prefix.spill_hook = self._spill_block
@@ -457,6 +471,9 @@ class PagedDecodeEngine:
             "ledger_admitted": 0,
             "row_steps": 0, "slot_steps": 0,
             "kv_tokens": 0, "grid_tokens": 0,
+            # tokens the window layers' calls attended (a model with window
+            # layers): the live rows' contexts, each capped at the window
+            "kv_window_tokens": 0,
             # expert layers (prefills and decode steps, warm-up excluded):
             # pairs routed, pairs on held experts, the fullest held
             # expert's pairs x experts held (generation._moe_counts)
@@ -509,6 +526,9 @@ class PagedDecodeEngine:
                     self.mcfg, self.cache.allocator.num_blocks, self.block,
                     kv_dtype=self.kv_dtype,
                     slots=B,  # a block with row state keeps it per batch slot
+                    # a model with window layers: its second class of pages
+                    **({"ring_blocks": self.cache.ring_allocator.num_blocks}
+                       if self.ring_pages else {}),
                 ),
                 jnp.zeros((B, vocab), jnp.float32),
                 jnp.zeros((B, vocab), jnp.int32),
@@ -520,7 +540,10 @@ class PagedDecodeEngine:
 
     # -- capacity queries ----------------------------------------------
     def row_capacity_tokens(self, prompt_len: int, max_new: int) -> int:
-        """Cache slots a row reserves: its full decode budget plus the
+        """Cache slots a row reserves IN THE GROWING CLASS of pages (every
+        layer's, or the full attention layers' of a model with window layers,
+        whose ring of ``ring_pages`` pages a row the manager reserves beside
+        them whatever this number is): its full decode budget plus the
         prefill bucket width (pad junk lands in the row's own blocks).
         The budget is clamped to the context room like admit() clamps it
         (plan_decode's trim), so reservation == allocation.  With
@@ -543,14 +566,28 @@ class PagedDecodeEngine:
         return self.kv_bytes_per_token() * self.block
 
     def kv_bytes_per_token(self) -> int:
-        """Bytes one cached token takes over all layers: the model's
-        ``cached_token`` (per-head K and V, or one latent) in the pools'
-        dtype."""
+        """Bytes one cached token takes over all layers WHOSE PAGES GROW WITH
+        THE ROW: the model's ``cached_token`` (per-head K and V, or one
+        latent) in the pools' dtype.  (Window layers: :meth:`ring_bytes_per_row`.)"""
         k = self.pools.k
         per_layer = sum(heads * width for heads, width in self.mcfg.cached_token)
         return int(k.shape[0]) * per_layer * k.dtype.itemsize
 
+    def ring_bytes_per_row(self) -> int:
+        """Bytes a row's rings take over all window layers, whatever its
+        length (0 for a model without window layers)."""
+        if not self.ring_pages:
+            return 0
+        wk = self.pools.wk  # from the shapes alone, like state_bytes_per_row
+        page = int(np.prod(wk.shape[2:])) * wk.dtype.itemsize
+        return int(wk.shape[0]) * self.ring_pages * 2 * page
+
     def _unwritten_for(self) -> str:
+        if self.ring_pages:
+            return ("two classes of pages (window layers keep a ring of pages a row beside "
+                    "the full layers' growing ones: the prefix index, a prompt or verify chunk, "
+                    "int8 pools and handoff payloads know one class), shared KV heads and "
+                    "expert layers")
         if self.row_state:
             return ("a block with row state (state-space layers keep a recurrent state a "
                     "slot, which pages, prefix blocks and handoff payloads do not carry), "
@@ -1025,7 +1062,10 @@ class PagedDecodeEngine:
             # prefill scatters PB blocks (bucket width incl. pad junk,
             # which lands in the row's own blocks — row_capacity_tokens
             # reserves at least the bucket width, so the table covers PB)
-            prefill_table = table[:PB]
+            prefill_table = jnp.asarray(table[:PB], jnp.int32)
+            ring = self.cache.ring(seq_id)
+            if ring:  # a model with window layers prefills into both classes
+                prefill_table = (prefill_table, jnp.asarray(ring, jnp.int32))
             prompt = np.full((1, P), self.gen.pad_token_id, np.int32)
             prompt[0, :plen] = prompt_ids  # RIGHT-pad (paged rows are unpadded)
             fn = self._prefill_fn(P, PB)
@@ -1036,7 +1076,7 @@ class PagedDecodeEngine:
                     jnp.asarray(prompt),
                     jnp.int32(plen),
                     self._pools_tuple(),
-                    jnp.asarray(prefill_table, jnp.int32),
+                    prefill_table,
                     *((jnp.int32(slot),) if self.row_state else ()),
                 ),
                 "prefill", release_seq=seq_id,
@@ -1069,7 +1109,7 @@ class PagedDecodeEngine:
             self.slots[slot] = _Row(
                 seq_id=seq_id, entry=entry, row_idx=row_idx, prompt_len=plen,
                 max_new=max_new, table=table, prompt_ids=prompt_ids,
-                trace=trace,
+                trace=trace, ring=ring,
             )
             self.stats["prefills"] += 1
             self.stats["prefill_tokens"] += plen
@@ -1703,6 +1743,13 @@ class PagedDecodeEngine:
         for i, r in enumerate(self.slots):
             if r is not None:
                 tables[i, : len(r.table)] = r.table
+        if self.ring_pages:
+            # a model with window layers: every row's ring beside its table
+            rings = np.full((self.capacity, self.ring_pages), NULL_BLOCK, np.int32)
+            for i, r in enumerate(self.slots):
+                if r is not None:
+                    rings[i] = r.ring
+            tables = (tables, rings)
         self._key, sub = self._jax.random.split(self._key)
         k = self.spec.draft_k if self.spec else 0
         drafts = (
@@ -1819,6 +1866,9 @@ class PagedDecodeEngine:
         self.stats["row_steps"] += n_act
         self.stats["slot_steps"] += self.capacity
         self.stats["kv_tokens"] += int(self.positions[was_active].sum())
+        if self.ring_pages:
+            self.stats["kv_window_tokens"] += int(np.minimum(
+                self.positions[was_active], int(self.mcfg.sliding_window)).sum())
         if self.row_state and not self._warmup:
             self.stats["ssm_row_steps"] += n_act * int(self.mcfg.ssm_layers)
             self.stats["ssm_slot_steps"] += self.capacity * int(self.mcfg.ssm_layers)
@@ -2316,7 +2366,10 @@ class ContinuousScheduler:
             # kv_blocks_used counts PHYSICAL blocks (refcount-deduped),
             # so neither gauge can exceed the arena under any sharing
             ("pfx_kv_bytes", {},
-             float(cstats["kv_blocks_used"]) * eng.kv_block_bytes()),
+             float(cstats["kv_blocks_used"]) * eng.kv_block_bytes()
+             # a model with window layers: its held ring pages beside them
+             + float(cstats.get("kv_ring_blocks_used", 0))
+             * eng.ring_bytes_per_row() / max(1, eng.ring_pages)),
             # what one cached token takes over all layers: it comes from
             # the model (per-head K and V, or one latent), and the arena's
             # rows and --kv-blocks auto follow it
@@ -2325,6 +2378,9 @@ class ContinuousScheduler:
             # state-space layer's recurrent state and conv columns; 0 for
             # a block whose every layer caches tokens)
             ("pfx_state_bytes_per_row", {}, float(eng.state_bytes_per_row())),
+            # what a row's rings of window-layer pages take whatever its
+            # length (0 for a model without window layers)
+            ("pfx_kv_ring_bytes_per_row", {}, float(eng.ring_bytes_per_row())),
             ("pfx_prefix_cached_blocks", {},
              float(cstats["prefix_cached_blocks"])),
             # host-RAM spill tier occupancy (0 when --prefix-spill-bytes
@@ -2335,6 +2391,11 @@ class ContinuousScheduler:
             ("pfx_prefix_spill_entries", {},
              float(cstats["prefix_spill_entries"])),
         ]
+        if eng.ring_pages:
+            # the two classes of pages of one arena, each by its own count
+            for cls, key in (("full", "kv_blocks"), ("window", "kv_ring_blocks")):
+                out.append(("pfx_kv_pages_held", {"class": cls}, float(cstats[key + "_used"])))
+                out.append(("pfx_kv_pages_free", {"class": cls}, float(cstats[key + "_free"])))
         if eng.spec is not None:
             prop = float(eng.stats["spec_proposed"])
             out.append((
@@ -2388,6 +2449,9 @@ class ContinuousScheduler:
             ("grid_tokens", "pfx_sched_decode_grid_tokens_total"),
         ):
             out.append((name, {}, float(eng.stats[key])))
+        if eng.ring_pages:
+            out.append(("pfx_sched_decode_kv_window_tokens_total", {},
+                        float(eng.stats["kv_window_tokens"])))
         if eng.row_state:
             for key, name in (
                 ("ssm_row_steps", "pfx_ssm_row_steps_total"),

@@ -869,12 +869,30 @@ class PagedCacheManager:
     blocks into a new row's table as SHARED (refcounted) entries, and an
     allocation that would otherwise fail first evicts unreferenced
     cached prefixes.
+
+    ``ring_pages`` > 0 (a model with window layers, docs/mellum2.md) adds a
+    SECOND class of pages to the manager: ``ring_blocks`` blocks with ids
+    of their own (0 the null block again), of which every sequence holds
+    exactly ``ring_pages`` from admission to release, whatever its length
+    (a window layer's ring: token t in slot ``(t // block) % ring_pages``).
+    An admission reserves BOTH classes or neither; a release returns both.
+    The growing class is the one the prefix index knows.
     """
 
     def __init__(self, num_blocks: int, block: int = 0,
-                 prefix_blocks: int = 0, spill_bytes: int = 0) -> None:
+                 prefix_blocks: int = 0, spill_bytes: int = 0,
+                 ring_blocks: int = 0, ring_pages: int = 0) -> None:
         self.block = kv_block_size(block)
         self.allocator = BlockAllocator(num_blocks)
+        if bool(ring_pages) != bool(ring_blocks) or ring_pages < 0:
+            raise ValueError("ring_blocks and ring_pages come together (a model with "
+                             f"window layers) or not at all; got {ring_blocks}, {ring_pages}")
+        if ring_pages and prefix_blocks:
+            raise ValueError("the prefix index does not know which class a page is: "
+                             "prefix_blocks with ring_pages is not written")
+        self.ring_pages = int(ring_pages)
+        self.ring_allocator = BlockAllocator(ring_blocks) if ring_pages else None
+        self._rings: Dict[int, List[int]] = {}
         self.prefix = PrefixIndex(self.allocator, self.block, prefix_blocks)
         # host-RAM demotion tier for LRU-evicted prefix blocks
         # (--prefix-spill-bytes; 0 = off).  The engine wires
@@ -891,6 +909,8 @@ class PagedCacheManager:
         return self.allocator.free_count() + self.prefix.reclaimable_blocks()
 
     def can_admit(self, tokens: int) -> bool:
+        if self.ring_pages and self.ring_pages > self.ring_allocator.free_count():
+            return False  # the window layers' class is short, whatever the other holds
         need = blocks_for(tokens, self.block)
         if need <= self.allocator.free_count():
             return True  # skip the O(cached-nodes) reclaimable scan
@@ -907,7 +927,9 @@ class PagedCacheManager:
         pool cannot cover the remainder, unreferenced cached prefixes
         are evicted first; :class:`BlockPoolExhausted` only raises once
         the index has nothing left to give — and then atomically (the
-        shared references are returned)."""
+        shared references are returned).  With a second class of pages the
+        sequence's ring (:meth:`ring`) is reserved first, and returned if
+        the growing class is short: both classes or neither."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id} already admitted")
         shared = list(shared or [])
@@ -920,6 +942,7 @@ class PagedCacheManager:
         # reference the shared blocks FIRST: the evict-for-room pass
         # below may drop these very nodes from the index, and the row's
         # reference is what keeps their KV alive through that
+        ring = self.ring_allocator.alloc(self.ring_pages) if self.ring_pages else []
         self.allocator.share(shared)
         if need > self.allocator.free_count():
             self.prefix.evict_for(need)
@@ -927,9 +950,13 @@ class PagedCacheManager:
             fresh = self.allocator.alloc(need) if need else []
         except BlockPoolExhausted:
             self.allocator.free(shared)
+            if ring:
+                self.ring_allocator.free(ring)
             raise
         table = shared + fresh
         self._tables[seq_id] = table
+        if ring:
+            self._rings[seq_id] = ring
         return list(table)
 
     def release(self, seq_id: int) -> None:
@@ -938,6 +965,13 @@ class PagedCacheManager:
         if table is None:
             raise ValueError(f"sequence {seq_id} has no allocation")
         self.allocator.free(table)
+        if self.ring_pages:
+            self.ring_allocator.free(self._rings.pop(seq_id))
+
+    def ring(self, seq_id: int) -> List[int]:
+        """The sequence's ring of window-layer pages (``ring_pages`` ids of
+        the second class, fixed for its life; [] without that class)."""
+        return list(self._rings[seq_id]) if self.ring_pages else []
 
     def table(self, seq_id: int, width: Optional[int] = None) -> List[int]:
         """The sequence's block table, null-padded to ``width`` entries
@@ -961,7 +995,14 @@ class PagedCacheManager:
         # kv_blocks_used counts PHYSICAL blocks (allocator refcounts
         # dedupe sharing): occupancy can never exceed the arena no
         # matter how many rows share a prefix
+        ring = {} if not self.ring_pages else {
+            # the window layers' class, counted beside the growing one
+            "kv_ring_blocks_used": self.ring_allocator.used_count(),
+            "kv_ring_blocks_free": self.ring_allocator.free_count(),
+            "kv_ring_pages_per_row": self.ring_pages,
+        }
         return {
+            **ring,
             "kv_blocks_used": self.allocator.used_count(),
             "kv_blocks_free": self.allocator.free_count(),
             "kv_block_size": self.block,

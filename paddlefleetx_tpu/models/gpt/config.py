@@ -81,7 +81,9 @@ class GPTConfig:
     num_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.2
-    moe_gate: str = "gshard"  # naive | gshard | switch
+    # naive | gshard | switch (the capacity-factor layer) | sigmoid | softmax
+    # (the dropless layer's two routing rules, models/gpt/moe.py)
+    moe_gate: str = "gshard"
     moe_aux_loss_weight: float = 0.01
     # -- block vocabulary: what the one decoder block reads beside the sizes.
     # The defaults are the GPT-2 block (LayerNorm, learned positions, fused
@@ -115,14 +117,18 @@ class GPTConfig:
     embed_scale_sqrt_hidden: bool = False  # x0 = E[tokens] * sqrt(hidden)
     # attention window (0 = none) on every layer but each
     # ``global_attn_every``-th one ((l + 1) % n == 0: no window, and under
-    # ``position: rope`` no rotation either); 0 = every layer alike
+    # ``position: rope`` no rotation either); 0 = every layer alike.  In a
+    # ``layer_pattern`` the window belongs to the ``W`` layers, and which
+    # layers those are is the pattern's to say (no ``global_attn_every``)
     sliding_window: int = 0
     global_attn_every: int = 0
     # leading layers that keep the dense MLP when num_experts > 1
     num_dense_layers: int = 0
-    # dropless expert layer (moe_gate: sigmoid; models/gpt/moe.py): the
-    # router scores all num_experts, this process holds moe_experts_held of
-    # them (0 = all) from id moe_expert_offset on
+    # dropless expert layer (moe_gate: sigmoid, or softmax: a softmax over
+    # all experts, the top-k's weights renormalised over the k, no bias, no
+    # scale; models/gpt/moe.py): the router scores all num_experts, this
+    # process holds moe_experts_held of them (0 = all) from id
+    # moe_expert_offset on
     moe_ffn_hidden_size: int = 0  # 0 = ffn_hidden_size
     moe_experts_held: int = 0
     moe_expert_offset: int = 0
@@ -157,7 +163,11 @@ class GPTConfig:
     # YaRN frequencies of the rotation (rope_scaling_factor > 1): each
     # frequency blended with itself / factor by the linear ramp between
     # the correction dims of beta_fast and beta_slow at the original
-    # context; the softmax scale gains (0.1 mscale_all_dim ln factor + 1)^2
+    # context; the softmax scale gains (0.1 mscale_all_dim ln factor + 1)^2.
+    # In a ``layer_pattern`` YaRN belongs to the layers that see the WHOLE
+    # context (``*`` and ``P``: cos and sin times that factor, so the cached
+    # keys carry it); a ``W`` layer never looks past its window and rotates
+    # plainly at ``rope_theta`` (``layer_rotation``)
     rope_scaling_factor: float = 1.0
     rope_original_max_position: int = 4096
     rope_beta_fast: float = 32.0
@@ -167,10 +177,13 @@ class GPTConfig:
 
     # one sub-block a layer (docs/nemotron_h.md): a character a layer,
     # ``M`` a Mamba-2 mixer, ``*`` grouped-query attention (rotated under
-    # ``position: rope``), ``P`` both of them side by side on the SAME normed
+    # ``position: rope``), ``W`` the same over the last ``sliding_window``
+    # positions only (docs/mellum2.md: its cache is a RING of pages a row,
+    # a second class of pages beside the ``*`` layers' growing ones),
+    # ``P`` both of them side by side on the SAME normed
     # input, their results added (docs/falcon_h1.md: such a layer keeps a
     # recurrent state AND pages), ``E`` the dropless expert feed-forward,
-    # ``-`` a dense feed-forward (relu2 or swiglu, as ``mlp_act`` says); every
+    # ``-`` a dense feed-forward (each relu2 or swiglu, as ``mlp_act`` says); every
     # layer is x + mixer(RMSNorm(x)).  "" = the two-sub-block layers above.
     # ``num_layers`` is the pattern's length.
     layer_pattern: str = ""
@@ -232,7 +245,7 @@ class GPTConfig:
                 raise ValueError("only the GPT-2 block has dropout; set both "
                                  "dropout probabilities to 0")
         if self.moe_bias_warm_start_steps and not (
-                self.moe_dropless
+                self.moe_gate == "sigmoid" and self.moe_dropless
                 and self.moe_bias_warm_start_rate >= self.moe_bias_update_rate > 0):
             raise ValueError("moe_bias_warm_start_steps needs moe_gate: sigmoid and "
                              "moe_bias_warm_start_rate >= moe_bias_update_rate > 0")
@@ -249,7 +262,8 @@ class GPTConfig:
                 if getattr(self, option):
                     raise ValueError(f"latent attention (kv_lora_rank) does not take {option}")
         if self.moe_n_group > 1 and not (
-                self.moe_dropless and self.num_experts % self.moe_n_group == 0
+                self.moe_gate == "sigmoid" and self.moe_dropless
+                and self.num_experts % self.moe_n_group == 0
                 and 1 <= self.moe_topk_group <= self.moe_n_group
                 and self.moe_top_k <= self.moe_topk_group * (self.num_experts // self.moe_n_group)
                 and self.num_experts // self.moe_n_group >= 2):
@@ -263,7 +277,7 @@ class GPTConfig:
                     f"experts {self.moe_expert_offset}..{last} held of {self.num_experts}")
         elif not self.classic_block and self.num_experts > 1:
             raise ValueError("the capacity-factor MoE layer serves the GPT-2 block only; "
-                             "set moe_gate: sigmoid for the dropless layer")
+                             "set moe_gate: sigmoid (or softmax) for the dropless layer")
         if self.recompute_granularity not in ("full", "selective", "full_attn", "core_attn"):
             raise ValueError(f"bad recompute_granularity {self.recompute_granularity}")
         raw = self.recompute_names
@@ -315,13 +329,21 @@ class GPTConfig:
 
     def _check_layer_pattern(self) -> None:
         pattern = self.layer_pattern
-        if set(pattern) - set("MP*E-") or len(pattern) != self.num_layers:
+        if set(pattern) - set("MP*WE-") or len(pattern) != self.num_layers:
             raise ValueError(
-                f"layer_pattern {pattern!r}: one of M (Mamba-2), * (attention), P (both, "
-                f"side by side), E (experts), - (dense) for each of the {self.num_layers} layers")
-        if "E" in pattern and self.mlp_act != "relu2":
-            raise ValueError("an E layer of a layer_pattern block has relu2 experts "
-                             "(mlp_act: relu2): no reference holds another yet")
+                f"layer_pattern {pattern!r}: one of M (Mamba-2), * (attention), W (attention "
+                f"over a window), P (both, side by side), E (experts), - (dense) for each of "
+                f"the {self.num_layers} layers")
+        if "E" in pattern and self.mlp_act != "relu2" and set(pattern) - set("*WE"):
+            raise ValueError("SwiGLU experts (E) are held to a reference beside attention "
+                             "layers (* and W) alone; beside M, P or - layers a layer_pattern "
+                             "block has relu2 experts (mlp_act: relu2)")
+        if ("W" in pattern) != (self.sliding_window > 0):
+            raise ValueError("a W layer needs sliding_window, and sliding_window a W layer: "
+                             "the window belongs to the pattern's W layers")
+        if "W" in pattern and (self.ssm_layers or self.position != "rope"):
+            raise ValueError("W layers beside state-space layers (M, P), or without "
+                             "position: rope, are not written: no reference holds them yet")
         if self.ssm_layers:
             if not (self.ssm_heads and self.ssm_head_dim and self.ssm_state
                     and self.ssm_conv >= 2 and self.ssm_chunk >= 1):
@@ -331,9 +353,9 @@ class GPTConfig:
                     self.ssm_heads * self.ssm_head_dim) % self.ssm_groups:
                 raise ValueError("ssm_groups must divide ssm_heads")
         if "E" in pattern and not self.moe_dropless:
-            raise ValueError("an E layer needs num_experts and moe_gate: sigmoid")
-        for option in ("qk_norm", "attn_gate", "post_norms", "sliding_window",
-                       "global_attn_every", "num_dense_layers", "kv_lora_rank",
+            raise ValueError("an E layer needs num_experts and moe_gate: sigmoid or softmax")
+        for option in ("qk_norm", "attn_gate", "post_norms", "global_attn_every",
+                       "num_dense_layers", "kv_lora_rank",
                        "embed_scale_sqrt_hidden"):
             if getattr(self, option):
                 raise ValueError(f"a layer_pattern block does not take {option}")
@@ -362,13 +384,31 @@ class GPTConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers whose cache is pages of tokens: all of them, or a
-        layer_pattern's attention layers (``*`` and ``P``).  The pools'
-        leading axis: attention layer number ``a`` of the pattern is
-        ``pools.k[a]``, NOT the layer's place in the stack."""
+        """Layers whose cache is pages of tokens that GROW with the row:
+        all of them, or a layer_pattern's full attention layers (``*`` and
+        ``P``).  The pools' leading axis: attention layer number ``a`` of the
+        pattern is ``pools.k[a]``, NOT the layer's place in the stack."""
         if not self.layer_pattern:
             return self.num_layers
         return self.layer_pattern.count("*") + self.layer_pattern.count("P")
+
+    @property
+    def window_layers(self) -> int:
+        """A layer_pattern's ``W`` layers: each keeps, a row, a RING of
+        :meth:`ring_pages` pages whatever the row's length, the second class
+        of pages of one arena (``pools.wk[w]``, ``w`` counting the ``W``
+        layers alone).  0 for every other block."""
+        return self.layer_pattern.count("W")
+
+    def ring_pages(self, block: int) -> int:
+        """Pages of ``block`` tokens a row's ring holds in a ``W`` layer: the
+        ``sliding_window`` positions a query sees lie in at most this many
+        consecutive pages (a window of 1,024 on pages of 128: 9; token t
+        lives in ring slot ``(t // block) % ring_pages``).  0 without ``W``
+        layers."""
+        if not self.window_layers:
+            return 0
+        return -(-self.sliding_window // int(block)) + 1
 
     @property
     def ssm_layers(self) -> int:
@@ -429,7 +469,7 @@ class GPTConfig:
 
     @property
     def moe_dropless(self) -> bool:
-        return self.num_experts > 1 and self.moe_gate == "sigmoid"
+        return self.num_experts > 1 and self.moe_gate in ("sigmoid", "softmax")
 
     @property
     def experts_held(self) -> int:
@@ -468,10 +508,26 @@ class GPTConfig:
         """(window or 0, rotate q and k) of layer ``layer``, counted from 0
         over the whole stack, leading dense layers included."""
         is_global = self.global_attn_every > 0 and (layer + 1) % self.global_attn_every == 0
-        if self.layer_pattern and self.layer_pattern[layer] not in "*P":
-            raise ValueError(f"layer {layer} of {self.layer_pattern!r} is no attention layer")
+        if self.layer_pattern:
+            # the pattern names the kinds: W has the window, and EVERY
+            # attention layer rotates (how: ``layer_rotation``)
+            kind = self.layer_pattern[layer]
+            if kind not in "*PW":
+                raise ValueError(f"layer {layer} of {self.layer_pattern!r} is no attention layer")
+            return (self.sliding_window if kind == "W" else 0, self.position == "rope")
         return (0 if is_global else self.sliding_window,
                 self.position == "rope" and not is_global)
+
+    def layer_rotation(self, kind: str) -> Tuple[bool, float]:
+        """How a layer_pattern's attention layer of ``kind`` rotates q and k
+        under ``position: rope``: (YaRN's blended frequencies or the plain
+        ones, the factor on cos and sin).  A ``W`` layer never sees past its
+        window: plain, 1.  A layer that sees the whole context (``*``, ``P``)
+        takes YaRN where ``rope_scaling_factor`` > 1, and its cos and sin
+        carry ``rope_yarn_m``, so q and the CACHED keys both do and the
+        scores gain its square."""
+        scaled = kind != "W" and self.rope_scaling_factor > 1.0
+        return scaled, (self.rope_yarn_m if scaled else 1.0)
 
     @property
     def recompute_name_tuple(self) -> Tuple[str, ...]:

@@ -33,8 +33,8 @@ from paddlefleetx_tpu.models.gpt.model import (
     latent_projections,
     latent_softmax_scale,
     layer_norm,
+    layer_rope_at,
     rms_norm,
-    rope_at,
 )
 from paddlefleetx_tpu.ops.decode_attention import (
     decode_attention,
@@ -44,6 +44,7 @@ from paddlefleetx_tpu.ops.decode_attention import (
     mla_paged_decode_attention,
     paged_decode_attention,
     quantize_kv,
+    window_view,
 )
 from paddlefleetx_tpu.ops.sampling import filtered_logits, sample_logits
 from paddlefleetx_tpu.ops.speculative import (
@@ -301,9 +302,9 @@ def check_servable(cfg: GPTConfig) -> None:
     """Which blocks the serving forwards know (docs/serving.md "What a block
     must provide"): the GPT-2 block, the described block with latent
     attention (a dense SwiGLU or a dropless expert MLP), and a
-    ``layer_pattern`` block (state-space, grouped-query attention and
-    expert layers, one sub-block a layer).  Anything else raises, naming
-    the option that is in the way."""
+    ``layer_pattern`` block (state-space, grouped-query attention, full or
+    over a window, and expert layers, one sub-block a layer).  Anything else
+    raises, naming the option that is in the way."""
     if cfg.classic_block:
         if cfg.num_experts > 1:
             raise ValueError("serving knows no capacity-factor expert layer (num_experts)")
@@ -1026,7 +1027,16 @@ class PagedPools(NamedTuple):
     ``ssm`` [state-space layers, slots, R, state, W] (the recurrent state,
     packed as ``ops/ssm.py`` says) and ``conv`` [state-space layers, slots,
     (taps - 1) * conv_dim] (the last columns the conv saw, oldest first).
-    They ride every dispatch with the arena, under the same contract."""
+    They ride every dispatch with the arena, under the same contract.
+
+    A pattern with WINDOW layers (``W``; docs/mellum2.md) keeps a SECOND
+    class of pages in the same arena: ``wk`` / ``wv`` [window layers,
+    ring blocks, kv_heads, block, head_dim], with block ids of their own
+    (0 the null block again).  A row holds ``GPTConfig.ring_pages`` of them
+    for its whole life, as a ring: token t of a window layer lives in ring
+    slot ``(t // block) % ring_pages``, so the pages a query's window needs
+    never share a slot, whatever the row's length.  ``k`` / ``v`` are then
+    the FULL layers' pages alone, which grow with the row."""
 
     k: jax.Array
     v: Optional[jax.Array] = None
@@ -1034,6 +1044,8 @@ class PagedPools(NamedTuple):
     v_scale: Optional[jax.Array] = None
     ssm: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
+    wk: Optional[jax.Array] = None
+    wv: Optional[jax.Array] = None
 
     def fields(self) -> Tuple[str, ...]:
         """Names of the arrays these pools hold, in order."""
@@ -1048,9 +1060,11 @@ class PagedPools(NamedTuple):
 
 def init_paged_pools(
     cfg: GPTConfig, num_blocks: int, block: int, dtype=None,
-    kv_dtype: str = "", slots: int = 0,
+    kv_dtype: str = "", slots: int = 0, ring_blocks: int = 0,
 ) -> PagedPools:
-    """``slots`` (a block with ``row_state`` only): the batch's capacity."""
+    """``slots`` (a block with ``row_state`` only): the batch's capacity.
+    ``ring_blocks`` (a pattern with ``W`` layers only): the blocks of the
+    window layers' class, the null block among them."""
     check_servable(cfg)
     quant = kv_cache_dtype(kv_dtype) == "int8"
     if cfg.layer_pattern:
@@ -1062,6 +1076,11 @@ def init_paged_pools(
         (heads, width), _ = cfg.cached_token
         shape = (cfg.kv_layers, num_blocks, heads, block, width)
         pools = PagedPools(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        if cfg.window_layers:
+            if ring_blocks < 2:
+                raise ValueError("a pattern with W layers needs its ring blocks")
+            ring = (cfg.window_layers, ring_blocks, heads, block, width)
+            pools = pools._replace(wk=jnp.zeros(ring, dtype), wv=jnp.zeros(ring, dtype))
         if cfg.row_state:
             if slots < 1:
                 raise ValueError("a block with row state needs its batch slots")
@@ -1159,8 +1178,9 @@ def _block_mlp(p, m, cfg: GPTConfig, valid):
     layer's load statistics or None).  An expert layer is one whose
     parameters hold a router.  A decode step (t == 1: a token a row) runs
     every held expert on every row; a prefill sorts its pairs, as training
-    does at every size, and runs the products over them in the forward-only
-    kernel that follows the held pairs (``pfx_grouped_matmul``)."""
+    does at every size, runs the products over them in the forward-only
+    kernel that follows the held pairs (``pfx_grouped_matmul``) and brings
+    the results back by the sort's inverse, a gather (``gather_combine``)."""
     from paddlefleetx_tpu.models.gpt.moe import dropless_moe_block, feed_forward
     from paddlefleetx_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -1171,7 +1191,8 @@ def _block_mlp(p, m, cfg: GPTConfig, valid):
     if "shared" in p:
         q["shared"] = _in_dtype("shared", p["shared"], dtype)
     return dropless_moe_block(q, m, cfg, None, p["e_score_correction_bias"], valid,
-                              every_held_expert=m.shape[1] == 1, grouped_product=grouped_matmul)
+                              every_held_expert=m.shape[1] == 1, grouped_product=grouped_matmul,
+                              gather_combine=True)  # forward only: no scatter to transpose
 
 
 def _block_layer_step(p, x, positions, valid, cfg: GPTConfig, attend):
@@ -1301,16 +1322,21 @@ def _pattern_stack(params, x, pools, valid, cfg: GPTConfig, mixer, attend, posit
     the SAME normed input to both mixers, adds their results and advances
     both.  ``positions`` [b, t] (``position: rope``): where the tokens sit; q
     and k are rotated BEFORE ``attend`` sees them, so a cache holds rotated
-    keys.  -> (x, pools, the expert layers' statistics, a list)."""
+    keys; each KIND of attention layer rotates its own way
+    (``GPTConfig.layer_rotation``).  A window layer (``W``) is
+    ``attend(q, k, v, pools, w, window=True)`` with ``w`` counting the
+    window layers alone: its cache is the pools' second class.
+    -> (x, pools, the expert layers' statistics, a list)."""
     dtype = x.dtype
-    stats, m, a = [], 0, 0
+    stats, m, a, w = [], 0, 0, 0
 
-    def attention(p, y, pools, a):
+    def attention(p, y, pools, a, kind):
         attn = _in_dtype("attn", p, dtype)
         q, k, v = (jnp.einsum("bsh,hnd->bsnd", y, attn[f"{n}_kernel"]) for n in "qkv")
         if cfg.position == "rope":
-            q, k = (rope_at(t, positions, cfg.rope_theta) for t in (q, k))
-        out, pools = attend(q, k, v, pools, a)
+            q, k = (layer_rope_at(t, positions, cfg, kind) for t in (q, k))
+        # only a W layer says so: an ``attend`` written for a pattern without them takes none
+        out, pools = attend(q, k, v, pools, a, **({"window": True} if kind == "W" else {}))
         return jnp.einsum("bsnd,ndh->bsh", out, attn["out_kernel"]), pools
 
     for kind, p in zip(cfg.layer_pattern, params["blocks"]):
@@ -1318,15 +1344,18 @@ def _pattern_stack(params, x, pools, valid, cfg: GPTConfig, mixer, attend, posit
         if kind == "P":
             with jax.named_scope("pfx.parallel"):
                 out, pools = mixer(_in_dtype("ssm", p["ssm"], dtype), y, pools, m)
-                attended, pools = attention(p["attn"], y, pools, a)
+                attended, pools = attention(p["attn"], y, pools, a, kind)
                 out = out + attended
             m, a = m + 1, a + 1
         elif kind == "M":
             out, pools = mixer(_in_dtype("ssm", p["ssm"], dtype), y, pools, m)
             m += 1
         elif kind == "*":
-            out, pools = attention(p["attn"], y, pools, a)
+            out, pools = attention(p["attn"], y, pools, a, kind)
             a += 1
+        elif kind == "W":
+            out, pools = attention(p["attn"], y, pools, w, kind)
+            w += 1
         else:
             out, st = _block_mlp(p["mlp"], y, cfg, valid)
             if st is not None:
@@ -1335,19 +1364,26 @@ def _pattern_stack(params, x, pools, valid, cfg: GPTConfig, mixer, attend, posit
     return x, pools, stats
 
 
-def _pattern_prefill_attention(q, k, v, cfg: GPTConfig):
-    """Causal attention over one sequence, KV heads shared by their groups."""
+def _pattern_prefill_attention(q, k, v, cfg: GPTConfig, window: bool = False):
+    """Causal attention over one sequence, KV heads shared by their groups;
+    ``window``: each position sees the last ``sliding_window`` only (the
+    flash forward the training path runs under ``pfx.attn.window``).  A
+    pattern with window layers names the scope by the layer's kind."""
     from paddlefleetx_tpu.ops.attention import attention
 
-    with jax.named_scope("pfx.attn.gqa.prefill"):
-        return attention(q, k, v, impl=cfg.attn_impl, causal=True, flash_block=cfg.flash_block)
+    kind = (".window" if window else ".full") if cfg.window_layers else ""
+    with jax.named_scope("pfx.attn.gqa.prefill" + kind):
+        return attention(q, k, v, impl=cfg.attn_impl, causal=True, flash_block=cfg.flash_block,
+                         window=cfg.sliding_window if window else 0)
 
 
 def _pattern_paged_forward_step(params, tokens, pools, block_tables, positions, active,
                                 cfg: GPTConfig, ctx):
     """The decode step of a ``layer_pattern`` block: tokens [B] at slots
     ``positions`` -> (logits [B, 1, v] f32, pools, counts).  Row i IS batch
-    slot i: its recurrent state is ``pools.ssm[:, i]``."""
+    slot i: its recurrent state is ``pools.ssm[:, i]``.  A pattern with
+    window layers takes ``block_tables`` as a PAIR, (the full layers' tables
+    [B, M], the window layers' rings [B, ring pages])."""
     if ctx is not None:
         raise ValueError("tensor parallelism: a layer_pattern block is served on one "
                          "device (its pools, states and experts have no sharding rules yet)")
@@ -1361,19 +1397,39 @@ def _pattern_paged_forward_step(params, tokens, pools, block_tables, positions, 
     tokens = tokens.reshape(-1)
     dtype = jnp.dtype(cfg.dtype)
     x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][tokens][:, None]
-    pos, blk, off = _step_write_slots(block_tables, positions, active, pools.k.shape[3])
+    bs = pools.k.shape[3]
+    rings = None
+    if cfg.window_layers:
+        block_tables, rings = block_tables
+    pos, blk, off = _step_write_slots(block_tables, positions, active, bs)
     heads = jax.lax.iota(jnp.int32, cfg.kv_heads)[None, :]
-    live = live_slots(active)  # once a step: every state-space layer visits the same slots
+    # once a step: every state-space layer visits the same slots
+    live = live_slots(active) if cfg.ssm_layers else None
+    if rings is not None:
+        # where the window layers write the token (its page's ring slot) and
+        # what they read (the ring turned oldest page first): once a step too
+        R = rings.shape[1]
+        ring_blk = jnp.take_along_axis(rings, ((pos // bs) % R)[:, None], axis=1)[:, 0]
+        ring_blk = jnp.where(active, ring_blk, 0)
+        ring_tables, ring_pos, ring_start = window_view(rings, pos, cfg.sliding_window, bs)
 
     def mixer(p, y, pools, m):
         out, ssm, conv = mixer_step(p, y, pools.ssm, pools.conv, active, cfg, layer=m, live=live)
         return out, pools._replace(ssm=ssm, conv=conv)
 
-    def attend(q, k, v, pools, a):
+    def attend(q, k, v, pools, a, window=False):
+        if window:
+            at = (a, ring_blk[:, None], heads, off[:, None])
+            pools = pools._replace(wk=pools.wk.at[at].set(k[:, 0].astype(pools.wk.dtype)),
+                                   wv=pools.wv.at[at].set(v[:, 0].astype(pools.wv.dtype)))
+            with jax.named_scope("pfx.attn.gqa.decode.window"):
+                out = paged_decode_attention(q, pools.wk, pools.wv, ring_tables, ring_pos,
+                                             layer=a, starts=ring_start)
+            return out, pools
         at = (a, blk[:, None], heads, off[:, None])  # [B, kv heads] slots of this layer
         pools = pools._replace(k=pools.k.at[at].set(k[:, 0].astype(pools.k.dtype)),
                                v=pools.v.at[at].set(v[:, 0].astype(pools.v.dtype)))
-        with jax.named_scope("pfx.attn.gqa.decode"):
+        with jax.named_scope("pfx.attn.gqa.decode" + (".full" if cfg.window_layers else "")):
             out = paged_decode_attention(q, pools.k, pools.v, block_tables, pos, layer=a)
         return out, pools
 
@@ -1387,7 +1443,12 @@ def _pattern_paged_prefill(params, prompt, prompt_len, pools, table_row, slot, c
     [1, P]: each attention layer's keys and values go to the row's pages,
     each state-space layer's state after the last REAL token and its last
     conv columns OVERWRITE batch slot ``slot``'s (whatever a finished row
-    left there).  -> (pools, the last real token's logits [v], counts)."""
+    left there).  A pattern with window layers takes ``table_row`` as a PAIR,
+    (the full layers' pages [PB], the row's ring [ring pages]): a window
+    layer writes into the ring only the pages that hold the prompt's last
+    ``sliding_window`` tokens (what the row's first decode step can see),
+    each into the slot its page number names.
+    -> (pools, the last real token's logits [v], counts)."""
     if ctx is not None:
         raise ValueError("tensor parallelism: a layer_pattern block is served on one device")
     if cfg.row_state and slot is None:
@@ -1396,6 +1457,9 @@ def _pattern_paged_prefill(params, prompt, prompt_len, pools, table_row, slot, c
     from paddlefleetx_tpu.ops.ssm import write_slot_states
 
     P = int(prompt.shape[1])
+    ring_row = None
+    if cfg.window_layers:
+        table_row, ring_row = table_row
     PB, bs = int(table_row.shape[0]), int(pools.k.shape[3])
     if PB * bs < P:
         raise ValueError(f"table_row covers {PB}x{bs}={PB * bs} slots < prompt bucket {P}")
@@ -1403,6 +1467,14 @@ def _pattern_paged_prefill(params, prompt, prompt_len, pools, table_row, slot, c
     x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][prompt]
     positions = jax.lax.iota(jnp.int32, P)[None]
     valid = positions < prompt_len
+    if ring_row is not None:
+        # the pages of the prompt a window layer keeps: from the one that holds
+        # the first token the row's first decode step (at prompt_len) can see
+        R = int(ring_row.shape[0])
+        first = jnp.maximum(prompt_len - cfg.sliding_window + 1, 0) // bs
+        kept_pages = first + jax.lax.iota(jnp.int32, min(R, PB))  # no two share a ring slot
+        ring_at = ring_row[kept_pages % R]
+        kept_pages = jnp.minimum(kept_pages, PB - 1)  # past the bucket: unread until overwritten
 
     kept = []  # each state-space layer's (state, conv columns), written at the end
 
@@ -1415,7 +1487,11 @@ def _pattern_paged_prefill(params, prompt, prompt_len, pools, table_row, slot, c
         t = jnp.pad(t[0], ((0, PB * bs - P), (0, 0), (0, 0)))
         return t.reshape(PB, bs, t.shape[1], t.shape[2]).transpose(0, 2, 1, 3).astype(pool.dtype)
 
-    def attend(q, k, v, pools, a):
+    def attend(q, k, v, pools, a, window=False):
+        if window:
+            return _pattern_prefill_attention(q, k, v, cfg, window=True), pools._replace(
+                wk=pools.wk.at[a, ring_at].set(pages(k, pools.wk)[kept_pages]),
+                wv=pools.wv.at[a, ring_at].set(pages(v, pools.wv)[kept_pages]))
         return _pattern_prefill_attention(q, k, v, cfg), pools._replace(
             k=pools.k.at[a, table_row].set(pages(k, pools.k)),
             v=pools.v.at[a, table_row].set(pages(v, pools.v)))
@@ -1444,7 +1520,9 @@ def expert_load(params, tokens: jax.Array, cfg: GPTConfig) -> jax.Array:
         _, _, stats = _pattern_stack(
             params, x, None, None, cfg,
             lambda p, y, pools, m: (mixer_prefill(p, y, tokens.shape[1], cfg)[0], pools),
-            lambda q, k, v, pools, a: (_pattern_prefill_attention(q, k, v, cfg), pools))
+            lambda q, k, v, pools, a, window=False: (
+                _pattern_prefill_attention(q, k, v, cfg, window), pools),
+            jax.lax.iota(jnp.int32, tokens.shape[1])[None] if cfg.position == "rope" else None)
         return jnp.stack([st["load"] for st in stats])
     positions = jnp.broadcast_to(jax.lax.iota(jnp.int32, tokens.shape[1])[None], tokens.shape)
 

@@ -180,7 +180,7 @@ def _pattern_layer_specs(cfg: GPTConfig, kind: str) -> Dict[str, Any]:
         from paddlefleetx_tpu.models.gpt.ssm import mixer_specs
 
         specs["ssm"] = mixer_specs(cfg, w_out)
-    if kind in "*P":
+    if kind in "*PW":
         # a muP checkpoint's key_multiplier (0.011 as published) is a learning-rate
         # device, not a model of small keys: trained, W_k has grown against it.  Drawn
         # like the rest, the seeded scores' spread would be 0.02, every softmax uniform
@@ -313,29 +313,48 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def rope_at(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope_at(x: jax.Array, positions: jax.Array, theta: float,
+            inv_freq: Optional[jax.Array] = None, factor: float = 1.0) -> jax.Array:
     """:func:`rope` at given ``positions`` [b, s] of x [b, s, n, d] (a decode
     step rotates one token a row, each at its own position).  :func:`rope`
     stays as it is written: the Trinity-Mini train step, a benchmark cell,
-    lowers through it (tests/test_program_text.py)."""
+    lowers through it (tests/test_program_text.py).  ``inv_freq`` [d / 2]:
+    the frequencies in place of theta's own (YaRN's blend,
+    :func:`layer_rope_at`); ``factor`` multiplies cos and sin."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jax.lax.iota(jnp.float32, half) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jax.lax.iota(jnp.float32, half) / half)
     ang = positions.astype(jnp.float32)[..., None, None] * inv_freq  # [b, s, 1, half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :half], xf[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
-def rope_frequencies(cfg: GPTConfig) -> jax.Array:
-    """The qk_rope_head_dim / 2 rotation frequencies of latent attention:
-    theta^(-2i/d), under YaRN each blended with itself / factor by the
-    linear ramp between the correction dims of beta_fast and beta_slow at
-    the original context (the dims below the first keep their frequency,
-    those above the second are divided by the factor)."""
+def layer_rope_at(x: jax.Array, positions: jax.Array, cfg: GPTConfig, kind: str) -> jax.Array:
+    """q or k [b, s, n, d] of a layer_pattern's attention layer of ``kind``
+    rotated at ``positions`` as ``GPTConfig.layer_rotation`` says: a ``W``
+    layer plainly at ``rope_theta``; a layer that sees the whole context,
+    under ``rope_scaling_factor`` > 1, at YaRN's blended frequencies over the
+    whole head with cos and sin times ``rope_yarn_m``."""
+    scaled, factor = cfg.layer_rotation(kind)
+    if not scaled:
+        return rope_at(x, positions, cfg.rope_theta)
+    return rope_at(x, positions, cfg.rope_theta, rope_frequencies(cfg, x.shape[-1]), factor)
+
+
+def rope_frequencies(cfg: GPTConfig, d: int = 0) -> jax.Array:
+    """The d / 2 rotation frequencies (``d`` 0: latent attention's
+    qk_rope_head_dim): theta^(-2i/d), under YaRN each blended with itself /
+    factor by the linear ramp between the correction dims of beta_fast and
+    beta_slow at the original context (the dims below the first keep their
+    frequency, those above the second are divided by the factor; the dims
+    floored and ceiled, as the published default truncates them)."""
     import math
 
-    d = cfg.qk_rope_head_dim
+    d = d or cfg.qk_rope_head_dim
     i = jax.lax.iota(jnp.float32, d // 2)
     freq = cfg.rope_theta ** (-2.0 * i / d)
     if cfg.rope_scaling_factor <= 1.0:

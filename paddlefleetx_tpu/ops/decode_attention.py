@@ -474,7 +474,7 @@ def decode_attention(
 
 
 def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
-               k_scale=None, v_scale=None, t=None):
+               k_scale=None, v_scale=None, t=None, starts=None):
     """q_t [b, n, t, d] (grouped heads: [b, kv heads, group * t, d], the
     ``t`` queries of each head of a group one after the other, ``t``
     given); pools [layers, nb, n, bs, d], of which layer
@@ -491,7 +491,8 @@ def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
     limit (null-block padding) are masked by the causal bound, so their
     garbage never reaches the accumulator.  With int8 pools,
     ``k_scale``/``v_scale`` [layers, nb, n, bs] dequantize in-loop (scores
-    absorb the key scale, probabilities the value scale).
+    absorb the key scale, probabilities the value scale).  ``starts`` [b]
+    (a window layer's call): row i attends [starts[i], positions[i]] only.
     """
     b, n, rows, d = q_t.shape
     t = t or rows
@@ -531,6 +532,8 @@ def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
         col = j * bs + jnp.arange(bs)  # logical slot of each key column
         qpos = positions[:, None] + q_off[None, :]  # [b, t]
         mask = col[None, None, None, :] <= qpos[:, None, :, None]
+        if starts is not None:
+            mask = mask & (col[None, None, None, :] >= starts[:, None, None, None])
         s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
@@ -546,7 +549,8 @@ def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
     nvisit = jnp.minimum(
         (jnp.max(positions) + t + bs - 1) // bs, tables.shape[1]
     )
-    m, l, acc = jax.lax.fori_loop(0, nvisit, body, (m0, l0, acc0))
+    first = 0 if starts is None else jnp.min(starts) // bs
+    m, l, acc = jax.lax.fori_loop(first, nvisit, body, (m0, l0, acc0))
     # rows whose table is all-null (inactive slots, positions < 0 would
     # not occur — positions >= 0 always covers block 0) still get a
     # finite result; fully-masked rows divide by the epsilon floor
@@ -593,8 +597,8 @@ def _paged_last_page(pos, qt, *, t, tq, bs):
 
 
 def _paged_kernel(
-    layer_ref, tables_ref, pos_ref, q_ref, *refs, scale, bs, t, tq, pages,
-    width, quant, per_kv=1
+    layer_ref, tables_ref, pos_ref, *refs, scale, bs, t, tq, pages,
+    width, quant, per_kv=1, windowed=False
 ):
     """One (row, query tile, page group) grid step, every head inside.
     With ``per_kv`` > 1 query heads to a KV head, the ``per_kv * t`` queries
@@ -616,7 +620,17 @@ def _paged_kernel(
     speculative verify chunk: query qi sits at slot pos + qi, causal
     within the chunk.  With int8 pools the scores absorb the per-key
     scale column-wise and the probabilities the per-value scale — the
-    dequantized block never materializes."""
+    dequantized block never materializes.
+
+    ``windowed`` (a window layer's call, ``pfx_decode_window``; one query a
+    row): a fourth prefetched scalar a row, ``start``, is the first slot the
+    row attends: a group that ends before it runs nothing, the index maps
+    re-address its pages to the first one needed, and the slots before it are
+    masked like the slots after ``pos``."""
+    if windowed:
+        start_ref, q_ref, *refs = refs
+    else:
+        q_ref, *refs = refs
     kv = refs[: (4 if quant else 2) * pages]
     o_ref, acc_ref, m_ref, l_ref = refs[len(kv):]
     i = pl.program_id(0)
@@ -631,7 +645,12 @@ def _paged_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j * pages <= last)
+    needed = j * pages <= last
+    if windowed:
+        start = start_ref[i]
+        needed = needed & ((j + 1) * (pages * bs) > start)
+
+    @pl.when(needed)
     def _group():
         def group(refs_, axis):
             blocks = [r[0] for r in refs_]
@@ -660,6 +679,8 @@ def _paged_kernel(
         else:
             qrow = pos + jax.lax.broadcasted_iota(jnp.int32, shape, 1) % t
         mask = col <= qrow
+        if windowed:
+            mask = mask & (col >= start)
         if width % pages:
             # the last group hangs over the table: its spare pages
             # re-address the table's last page and must not count twice
@@ -687,7 +708,7 @@ def _paged_kernel(
 
 
 def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
-                  k_scale=None, v_scale=None, t=None):
+                  k_scale=None, v_scale=None, t=None, starts=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, n, rows, d = q_t.shape
@@ -705,14 +726,23 @@ def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
             f"pfx_decode_paged with {group} query heads a KV head holds all {t} queries "
             f"in one tile (at most {_PAGED_Q_TILE}) and reads no int8 pools yet")
 
+    windowed = starts is not None
+    prefetch = [layer[None], tables, positions]
+    if windowed:
+        if t != 1 or quant:
+            raise ValueError("pfx_decode_window takes one query a row and reads no int8 pools")
+        prefetch.append(starts.astype(jnp.int32))
+
     def page_index(p, stacked):
-        def index(i, qt, j, layer_ref, tables_ref, pos_ref):
+        def index(i, qt, j, layer_ref, tables_ref, pos_ref, *start_ref):
             # scalar-prefetch clamp: past the last page this query tile
             # needs, re-address the page already fetched — Pallas skips
             # the DMA when the index is unchanged between consecutive
             # grid steps
             last = _paged_last_page(pos_ref[i], qt, t=t, tq=tq, bs=bs)
             page = jnp.minimum(j * pages + p, jnp.minimum(last, M - 1))
+            if windowed:  # and before the first page the window needs, that one
+                page = jnp.maximum(page, start_ref[0][i] // bs)
             at = (tables_ref[i, page], 0, 0, 0)
             return (layer_ref[0],) + at if stacked else at
         return index
@@ -744,7 +774,7 @@ def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
         operands += [plane(k_scale)] * pages + [plane(v_scale)] * pages
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(b, -(-t // tq), -(-M // pages)),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -757,14 +787,17 @@ def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
     kernel = functools.partial(
         _paged_kernel, scale=scale, bs=bs, t=t, tq=tq, pages=pages,
         width=M, quant=quant, **({"per_kv": group} if group > 1 else {}),
+        **({"windowed": True} if windowed else {}),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n, rows, d), jnp.float32),
-        interpret=_device.pallas_interpret(),
-        name="pfx_decode_paged",
-    )(layer[None], tables, positions, *operands)
+    kw = dict(grid_spec=grid_spec, interpret=_device.pallas_interpret(),
+              out_shape=jax.ShapeDtypeStruct((b, n, rows, d), jnp.float32))
+    if windowed:
+        # a window layer's calls under a name of their own: the trace tells
+        # the two kinds of attention layer apart
+        call = pl.pallas_call(kernel, name="pfx_decode_window", **kw)
+    else:
+        call = pl.pallas_call(kernel, name="pfx_decode_paged", **kw)
+    return call(*prefetch, *operands)
 
 
 def paged_decode_attention(
@@ -778,6 +811,7 @@ def paged_decode_attention(
     impl: str = "auto",
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    starts: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Block-table-indexed decode attention for the paged KV cache.
 
@@ -806,6 +840,12 @@ def paged_decode_attention(
     spellings dequantize in-kernel (the pallas
     spelling rides the same scalar-prefetch-clamped index map, so the
     scale tiles DMA with their block) — pass both or neither.
+
+    ``starts`` [b] (a window layer's call; t = 1): row i attends its
+    logical slots [starts[i], positions[i]] and no page before the first of
+    them is read; the same kernel body under ``name="pfx_decode_window"``.
+    :func:`window_view` makes the table, positions and starts of a row's
+    ring of pages.
 
     ``impl``: "auto" (pallas on a TPU, lax on the CPU) | "pallas" | "lax".
     The pallas spelling runs one grid step per (row, query tile, group of
@@ -852,11 +892,29 @@ def paged_decode_attention(
         q_t = q_t.reshape(b, kv, (n // kv) * t, d)
     if use_pallas:
         out = _paged_pallas(q_t, k_pool, v_pool, layer, block_tables,
-                            positions, scale, k_scale, v_scale, t)
+                            positions, scale, k_scale, v_scale, t, starts)
     else:
         out = _paged_lax(q_t, k_pool, v_pool, layer, block_tables, positions,
-                         scale, k_scale, v_scale, t)
+                         scale, k_scale, v_scale, t, starts)
     return out.reshape(b, n, t, d).transpose(0, 2, 1, 3).astype(q.dtype)
+
+
+def window_view(rings: jax.Array, positions: jax.Array, window: int, block: int):
+    """A window layer's cache as :func:`paged_decode_attention` reads it.
+    ``rings`` [b, R]: row i's ring of pages, token t of the row in ring slot
+    ``(t // block) % R`` (R >= ceil(window / block) + 1, so the ``window``
+    positions a query sees never share a slot); ``positions`` [b]: the slot
+    of each row's query token, already written.  -> (tables [b, R], the ring
+    turned so that its OLDEST live page comes first; positions [b] and
+    starts [b] in that view): row i attends the view's slots [starts[i],
+    positions[i]], which are the row's tokens (positions[i] - window,
+    positions[i]].  Once a step: every window layer of it reads the same."""
+    R = rings.shape[1]
+    first = jnp.maximum(positions // block - (R - 1), 0)  # the oldest live page
+    turned = (first[:, None] + jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)) % R
+    at = positions - first * block
+    return (jnp.take_along_axis(rings, turned, axis=1), at,
+            jnp.maximum(at - (window - 1), 0))
 
 
 # ---------------------------------------------------------------------------

@@ -116,6 +116,9 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_kv_bytes": ("gauge", "Live KV-cache payload bytes (used blocks x K+V bytes per block)"),
     "pfx_kv_bytes_per_token": ("gauge", "Bytes one cached token takes over all layers that cache tokens, from the model: per-head K and V, or one latent"),
     "pfx_state_bytes_per_row": ("gauge", "Bytes a row keeps beside its pages whatever its length, over all state-space layers: the recurrent state and the conv's last columns (0 for a block whose every layer caches tokens)"),
+    "pfx_kv_ring_bytes_per_row": ("gauge", "Bytes a row's rings of window-layer pages take whatever its length, over all window layers (0 for a model without window layers)"),
+    "pfx_kv_pages_held": ("gauge", "Pages held of each class of a two-class arena (a model with window layers): class=full the pages that grow with a row, class=window the rows' rings"),
+    "pfx_kv_pages_free": ("gauge", "Free pages of each class of a two-class arena (class=full|window); an admission needs both"),
     # shared-prefix KV reuse + chunked prefill (core/paged_cache.py
     # PrefixIndex, core/continuous_batching.py)
     "pfx_prefix_hits_total": ("counter", "Admissions that reused cached prefix blocks"),
@@ -272,6 +275,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_sched_decode_slot_steps_total": ("counter", "Batch capacity summed over decode steps (denominator of batch occupancy)"),
     "pfx_sched_decode_kv_tokens_total": ("counter", "Context tokens of the live rows summed over decode steps (what a step needed to read)"),
     "pfx_sched_decode_grid_tokens_total": ("counter", "KV tokens per head the paged kernel computed on, summed over decode steps: every slot's context rounded up to the kernel's grid step (an empty slot costs one step; grid steps past a row's context run nothing)"),
+    "pfx_sched_decode_kv_window_tokens_total": ("counter", "Tokens the window layers' calls attended, summed over decode steps: each live row's context capped at the window (a model with window layers; over pfx_sched_decode_kv_tokens_total: what the window leaves of the reading)"),
     "pfx_train_host_gap_seconds_total": ("counter", "Fit-loop seconds from a blocking log fetch returning to the next step's dispatch having returned (the device has nothing queued)"),
     "pfx_moe_pairs_total": ("counter", "Token-expert pairs the dropless expert layers routed, over all experts and layers"),
     "pfx_moe_pairs_held_total": ("counter", "Routed pairs that landed on experts this process holds"),

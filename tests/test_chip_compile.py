@@ -983,3 +983,125 @@ def test_prefill_of_the_parallel_block_at_the_cell_s_one_bucket(topo):
     held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
     assert held <= m.alias_size_in_bytes < 1.01 * held
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.6e9, m.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# A block with two KINDS of attention layer and two classes of pages
+# (docs/mellum2.md) at the published sizes of the benchmark's configuration
+# mellum2-12b-a2.5b: the windowed read of a ring, the 56-sub-block decode step
+# and the one prefill bucket of the cell
+# ---------------------------------------------------------------------------
+
+
+def _mellum_cfg():
+    import json
+
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    root = os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..")
+    bench = os.path.join(root, "pfx_bench")  # noqa: E10 — a directory, not a metric
+    with open(os.path.join(bench, "configs", "mellum2-12b-a2.5b.json")) as f:
+        return GPTConfig(**json.load(f)["model"])
+
+
+def _mellum_shapes(topo, cfg, slots):
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    one = _one_chip(topo)
+    params = _shapes(one, jax.eval_shape(lambda: G.init_serving_params(cfg, jax.random.key(0))))
+    pools = _shapes(one, jax.eval_shape(lambda: G.init_paged_pools(
+        cfg, slots * 22 + 1, cfg.kv_block_default, ring_blocks=slots * 9 + 1)))
+    return one, params, pools
+
+
+def test_windowed_paged_decode_over_a_ring_compiles(topo):
+    """``pfx_decode_window``: 32 query heads on the 4 KV heads of a [21, 433,
+    4, 128, 128] ring arena, 9 pages a row turned oldest first, a fourth
+    prefetched scalar a row (the first slot the window lets it see)."""
+    from paddlefleetx_tpu.ops.decode_attention import paged_decode_attention, window_view
+
+    one = _one_chip(topo)
+    q = _shapes(one, ((48, 1, 32, 128), BF16))
+    pool = _shapes(one, ((21, 433, 4, 128, 128), BF16))
+    rings = _shapes(one, ((48, 9), jnp.int32))
+    positions = _shapes(one, ((48,), jnp.int32))
+
+    def read(q, k, v, rings, ps):
+        tables, at, starts = window_view(rings, ps, 1024, 128)
+        return paged_decode_attention(q, k, v, tables, at, layer=5, starts=starts)
+
+    c = _compile(read, q, pool, pool, rings, positions)
+    import re
+
+    text = c.as_text()
+    assert re.findall(r"%pfx_decode_window\S* = ", text) and not re.findall(r"%pfx_decode_paged\S* = ", text)
+    assert c.memory_analysis().temp_size_in_bytes < 16e6
+
+
+def test_decode_step_of_the_two_kinds_of_attention_fits_and_copies_nothing(topo):
+    """The benchmark cell ``serve-mellum2-12b-1of4-code``'s decode step as its
+    configuration file states it (28 published layers = 56 sub-blocks, 48
+    slots, tables 32 pages wide, rings of 9), the pools DONATED: 7.66 GB of
+    weights and 4.32 GB of pages in two classes are arguments, both arenas come
+    back aliased and nothing of their shape is copied; 7 calls of
+    ``pfx_decode_paged`` and 21 of ``pfx_decode_window``."""
+    import re
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    cfg = _mellum_cfg()
+    slots, width, vocab = 48, 32, cfg.vocab_size
+    one, params, pools = _mellum_shapes(topo, cfg, slots)
+    gen = G.GenerationConfig(decode_strategy="greedy_search", max_dec_len=0, min_dec_len=768,
+                             eos_token_id=0, pad_token_id=0)
+
+    def step(p, pools, tables, rings, logits, counts, positions, gen_steps, max_news, active, forced):
+        rows = G.PagedRows(logits, counts, positions, gen_steps, max_news, active, forced)
+        nxt, pools, new = G.decode_step(p, pools, (tables, rings), rows, cfg, gen)
+        return nxt, pools, new.logits, new.counts, new.moe
+
+    i32 = functools.partial(lambda *shape: (shape, jnp.int32))
+    rows = _shapes(one, (i32(slots, width), i32(slots, 9), ((slots, vocab), jnp.float32),
+                         i32(slots, vocab), i32(slots), i32(slots), i32(slots),
+                         ((slots,), jnp.bool_), i32(slots)))
+    c = jax.jit(step, donate_argnums=(1,)).lower(params, pools, *rows).compile()
+    m = c.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert held == (7 * 1057 + 21 * 433) * 262144 and held <= m.alias_size_in_bytes < 1.01 * held
+    assert 11.9e9 < m.argument_size_in_bytes < 12.1e9 and m.temp_size_in_bytes < 0.2e9
+    text = c.as_text()
+    assert len(re.findall(r"%pfx_decode_paged\S* = ", text)) == 7
+    assert len(re.findall(r"%pfx_decode_window\S* = ", text)) == 21
+    assert text.count("tpu_custom_call") == 28
+    moved = re.findall(r"= \w+\[(?:7,1057,4,128,128|21,433,4,128,128)\]\S* (copy|transpose)\(", text)
+    assert not moved, moved
+
+
+@pytest.mark.slow  # 55 s of TPU compile; run when the prefill or a pool's layout changes
+def test_prefill_of_the_two_kinds_of_attention_at_the_cell_s_one_bucket(topo):
+    """The 2,048-token prefill (the cell's only bucket), pools DONATED: 28
+    flash forwards (21 with the window), 84 grouped products over the sorted
+    pairs, the full layers' 16 pages and the window layers' 9 ring pages
+    written in place; arguments and scratch fit beside each other."""
+    import re
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    cfg = _mellum_cfg()
+    one, params, pools = _mellum_shapes(topo, cfg, 48)
+    i32 = lambda *shape: (shape, jnp.int32)  # noqa: E731
+
+    def prefill(p, prompt, plen, pools, row, ring):
+        return G.paged_prefill(p, prompt, plen, pools, (row, ring), cfg, return_moe=True)
+
+    prompt, plen, row, ring = _shapes(one, (i32(1, 2048), i32(), i32(16), i32(9)))
+    c = jax.jit(prefill, donate_argnums=(3,)).lower(params, prompt, plen, pools, row, ring).compile()
+    text = c.as_text()
+    assert len(re.findall(r"%pfx_flash_fwd\S* = ", text)) == 28
+    assert len(re.findall(r"%pfx_grouped_matmul\S* = ", text)) == 84
+    moved = re.findall(r"= \w+\[(?:7,1057,4,128,128|21,433,4,128,128)\]\S* (copy|transpose)\(", text)
+    assert not moved, moved
+    m = c.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert held <= m.alias_size_in_bytes < 1.01 * held
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.2e9, m.temp_size_in_bytes

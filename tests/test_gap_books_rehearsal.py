@@ -1,4 +1,4 @@
-"""The three serving cells through the benchmark's own rehearsal at
+"""Four serving cells through the benchmark's own rehearsal at
 ``--trace 2``, with the token gap's books read beside the run.
 
 PR 35's books were refused on one ``--trace 2`` run of
@@ -29,8 +29,27 @@ BOOK_METRICS = ("sched.gap_admission_share", "sched.gap_flush_share",
     ("serve-dsv3-1of32-think", 3_700_000_011),
     ("serve-nemotron3-nano-1of8-chat", 3_700_000_029),
     ("serve-1.3b-docs", 3_700_000_047),
+    ("serve-mellum2-12b-1of4-code", 4_200_000_061),  # two classes of pages (PR 42)
 ])
 def test_a_serving_cell_rehearses_correct_with_its_books_closed(cell, seed):
+    win = _rehearse(cell, seed)
+    # the server's mean gap is the client's, one SSE flush later (toy widths
+    # on a shared CPU: loose; the chip's agreement is in PERF.md).  Under six
+    # workers the driver's run of PR 41 read 3.08 against 2.17 ms in the dsv3
+    # case, the one failure of that run, and every case passes alone: five
+    # other test processes move the window's two scrapes and the client's
+    # clock apart.  A busy host misses now and then, a wrong clock or a
+    # dropped class every time: so one more rehearsal, held to all of the
+    # above again, before the same bound decides
+    if win["server_gap_mean_ms"] != pytest.approx(win["client_gap_mean_in_window_ms"], rel=0.25):
+        win = _rehearse(cell, seed)
+    assert win["server_gap_mean_ms"] == pytest.approx(
+        win["client_gap_mean_in_window_ms"], rel=0.25)
+
+
+def _rehearse(cell: str, seed: int) -> dict:
+    """One rehearsal of ``cell``, held to everything but the two clocks'
+    agreement -> its window's books."""
     env = dict(os.environ)
     env.pop("PFX_FAULT", None)
     p = subprocess.run(
@@ -63,14 +82,11 @@ def test_a_serving_cell_rehearses_correct_with_its_books_closed(cell, seed):
     # not yet framed when a scrape landed, and by one commit's rows (four
     # slots here) where the commit landed between the two families' reads
     assert abs(sum(win["gaps"].values()) - win["ledger_frames_less_rows"]) <= 8
-    # and the server's mean gap is the client's, one SSE flush later (toy
-    # widths on a shared CPU: loose; the chip's agreement is in PERF.md)
-    assert win["server_gap_mean_ms"] == pytest.approx(
-        win["client_gap_mean_in_window_ms"], rel=0.25)
     # every admission of an expert model went through pfx_grouped_matmul:
     # its calls a prefill are a constant of the program (none for the dense block)
     assert win["prefill_admits"] > 0
     assert win["moe_grouped_calls"] == _grouped_products(cell) * win["prefill_admits"]
+    return win
 
 
 def _grouped_products(cell: str) -> int:

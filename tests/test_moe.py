@@ -94,3 +94,39 @@ def test_naive_gate_no_aux():
     batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, 1)}
     loss = gpt.loss_fn(params, batch, cfg, train=False)
     assert np.isfinite(float(loss))
+
+
+def test_gather_combine_is_the_scatter_combine_in_another_order():
+    """The forward-only combine of the dropless layer's sorted path (a gather
+    by the sort's inverse, then a sum over a token's k: the serving prefill's,
+    PR 42) gives what the float32 scatter-add gives, to float32 rounding, with
+    held experts, an offset, invalid tokens and dead rows in the buffer; and it
+    refuses a buffer that is not the whole of the pairs, and the ladder by name."""
+    import pytest
+
+    from paddlefleetx_tpu.models.gpt import moe
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    cfg = GPTConfig(
+        vocab_size=64, hidden_size=32, num_layers=2, num_attention_heads=4, ffn_hidden_size=48,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0, norm="rmsnorm", position="rope",
+        use_bias=False, mlp_act="swiglu", tie_embeddings=False, dtype="float32",
+        num_experts=8, moe_gate="softmax", moe_top_k=3, moe_ffn_hidden_size=16,
+        moe_experts_held=3, moe_expert_offset=2)
+    rng = np.random.default_rng(0)
+    p = {"router_kernel": jnp.asarray(rng.normal(size=(32, 8)), jnp.float32),
+         "experts": {n: jnp.asarray(rng.normal(size=shape) * 0.2, jnp.float32)
+                     for n, shape in (("w1", (3, 32, 16)), ("w3", (3, 32, 16)), ("w2", (3, 16, 32)))}}
+    m = jnp.asarray(rng.normal(size=(40, 32)), jnp.float32)
+    valid = jnp.asarray(rng.random(40) < 0.8)
+    bias = jnp.zeros((8,), jnp.float32)
+    want, st_a = moe.routed_experts(p, m, bias, cfg, valid)
+    got, st_b = moe.routed_experts(p, m, bias, cfg, valid, gather_combine=True)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5 and float(jnp.max(jnp.abs(want))) > 0.1
+    assert int(st_a["pairs_held"]) == int(st_b["pairs_held"]) > 0
+    assert float(jnp.max(jnp.abs(got[~valid]))) == 0.0  # an invalid token gets zeros
+    with pytest.raises(ValueError, match="not with load_ladder"):  # never dropped in silence
+        moe.routed_experts(p, m, bias, cfg, valid, load_ladder=True, gather_combine=True)
+    with pytest.raises(ValueError, match="whole buffer"):
+        moe._sorted_pairs(8, 3, jax.lax.ragged_dot, p["experts"], m, jnp.ones((40, 3)),
+                          jnp.arange(120), jnp.asarray([1, 1, 1]), jnp.int32(3), gather_combine=True)

@@ -537,12 +537,14 @@ def test_a_prefill_sorts_its_pairs_and_a_decode_step_runs_every_held_expert(toy,
     real = moe.routed_experts
 
     def spy(p, m, bias, cfg, valid=None, every_held_expert=False, grouped_product=None,
-            load_ladder=False):
+            load_ladder=False, gather_combine=False):
         # both are handed the forward-only kernel; only a prefill's sorted path runs it,
-        # over one buffer (the ladder of sizes is the training step's)
-        assert grouped_product is grouped_matmul and not load_ladder
+        # over one buffer (the ladder of sizes is the training step's), and brings the
+        # pairs' results back by a gather (PR 42: no scatter to transpose in a forward)
+        assert grouped_product is grouped_matmul and not load_ladder and gather_combine
         seen.append(every_held_expert)
-        return real(p, m, bias, cfg, valid, every_held_expert, grouped_product)
+        return real(p, m, bias, cfg, valid, every_held_expert, grouped_product,
+                    gather_combine=gather_combine)
 
     monkeypatch.setattr(moe, "routed_experts", spy)
     pools = G.init_paged_pools(cfg, 4, BLOCK, slots=2)

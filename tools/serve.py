@@ -703,6 +703,13 @@ def serve_http(server, port: int, host: str = "127.0.0.1", *,
                     **({"available_blocks": int(reg.value(
                         "pfx_kv_blocks_available", snap=snap))}
                        if "pfx_kv_blocks_available" in snap else {}),
+                    # a model with window layers: the second class of
+                    # pages (a row's ring), which an admission needs too
+                    **({"available_ring_blocks":
+                        int(engine.cache.ring_allocator.free_count()),
+                        "ring_pages_per_row": int(engine.ring_pages)}
+                       if engine is not None
+                       and getattr(engine, "ring_pages", 0) else {}),
                     # prefix-affinity routing signal (core/router.py):
                     # how many shared-prefix blocks this replica has
                     # published, plus a compact digest of the hottest
