@@ -456,9 +456,10 @@ class PagedDecodeEngine:
         # (work counters, one update per committed decode step:
         # "row_steps"/"slot_steps" = live rows / capacity, "kv_tokens" =
         # the live rows' context lengths, "grid_tokens" = the KV tokens
-        # per head the paged kernel computed on: every slot's context
-        # rounded up to the kernel's grid step — an empty slot costs one
-        # step; grid steps past a row's context run nothing)
+        # per head the paged kernel computed on: each LIVE slot's context
+        # rounded up to the kernel's grid step — its grid visits the live
+        # rows only; the latent kernel's visits every slot, an empty one
+        # costing one step; grid steps past a row's context run nothing)
         self.stats: Dict[str, Any] = {
             "traces": 0, "steps": 0, "prefills": 0,
             "spec_proposed": 0, "spec_accepted": 0,
@@ -1860,9 +1861,10 @@ class PagedDecodeEngine:
         finished: List[int] = []
         n_act = int(was_active.sum())
         # work counters: what this step needed (live rows, their context)
-        # against what the paged kernel computed on (every slot — the
-        # step ran them all at the positions it started from — up to its
-        # context, in whole grid steps)
+        # against what the paged kernel computed on: the slots live at
+        # dispatch, which are the rows its grid visits, each up to its
+        # context in whole grid steps (the latent kernel's grid is every
+        # slot still, at the positions the step started from)
         self.stats["row_steps"] += n_act
         self.stats["slot_steps"] += self.capacity
         self.stats["kv_tokens"] += int(self.positions[was_active].sum())
@@ -1877,7 +1879,7 @@ class PagedDecodeEngine:
                 positions - ncommit, self.block, fl["width_bucket"]).sum())
         else:
             self.stats["grid_tokens"] += int(paged_tokens_computed(
-                positions - ncommit, fl["k"] + 1, self.block, fl["width_bucket"],
+                (positions - ncommit)[was_active], fl["k"] + 1, self.block, fl["width_bucket"],
                 self.mcfg.num_attention_heads // self.mcfg.kv_heads,
             ).sum())
         t_chunk = time.monotonic()

@@ -41,6 +41,7 @@ from paddlefleetx_tpu.ops.decode_attention import (
     kv_cache_dtype,
     kv_cache_len,
     latent_page_write,
+    live_slots,
     mla_paged_decode_attention,
     paged_decode_attention,
     quantize_kv,
@@ -1392,7 +1393,6 @@ def _pattern_paged_forward_step(params, tokens, pools, block_tables, positions, 
             "a layer_pattern block's decode step takes one token a row: a verify chunk "
             "(draft_k) or a prompt chunk (prefill_chunk) over row state is not written")
     from paddlefleetx_tpu.models.gpt.ssm import mixer_step
-    from paddlefleetx_tpu.ops.ssm import live_slots
 
     tokens = tokens.reshape(-1)
     dtype = jnp.dtype(cfg.dtype)
@@ -1403,8 +1403,8 @@ def _pattern_paged_forward_step(params, tokens, pools, block_tables, positions, 
         block_tables, rings = block_tables
     pos, blk, off = _step_write_slots(block_tables, positions, active, bs)
     heads = jax.lax.iota(jnp.int32, cfg.kv_heads)[None, :]
-    # once a step: every state-space layer visits the same slots
-    live = live_slots(active) if cfg.ssm_layers else None
+    # once a step: every layer's kernel, state or attention, visits the same slots
+    live = live_slots(active)
     if rings is not None:
         # where the window layers write the token (its page's ring slot) and
         # what they read (the ring turned oldest page first): once a step too
@@ -1424,13 +1424,14 @@ def _pattern_paged_forward_step(params, tokens, pools, block_tables, positions, 
                                    wv=pools.wv.at[at].set(v[:, 0].astype(pools.wv.dtype)))
             with jax.named_scope("pfx.attn.gqa.decode.window"):
                 out = paged_decode_attention(q, pools.wk, pools.wv, ring_tables, ring_pos,
-                                             layer=a, starts=ring_start)
+                                             layer=a, starts=ring_start, live=live)
             return out, pools
         at = (a, blk[:, None], heads, off[:, None])  # [B, kv heads] slots of this layer
         pools = pools._replace(k=pools.k.at[at].set(k[:, 0].astype(pools.k.dtype)),
                                v=pools.v.at[at].set(v[:, 0].astype(pools.v.dtype)))
         with jax.named_scope("pfx.attn.gqa.decode" + (".full" if cfg.window_layers else "")):
-            out = paged_decode_attention(q, pools.k, pools.v, block_tables, pos, layer=a)
+            out = paged_decode_attention(q, pools.k, pools.v, block_tables, pos, layer=a,
+                                         live=live)
         return out, pools
 
     x, pools, stats = _pattern_stack(params, x, pools, active[:, None], cfg, mixer, attend,
@@ -1550,13 +1551,15 @@ def _paged_layer_step(
     off: jax.Array,
     tables: jax.Array,
     positions: jax.Array,
+    live,
     cfg: GPTConfig,
     ctx: Optional[ShardingCtx] = None,
 ):
     """Decoder layer ``layer`` over x [b, t, h]: write each of the t chunk
     tokens' K/V at slot (blk[i, j], off[i, j]) of that layer's blocks, per
     row (t > 1 is the speculative verify chunk), then block-table paged
-    attention with per-query causal bounds.  ``pools`` is the whole arena
+    attention with per-query causal bounds over the rows ``live`` lists
+    (:func:`live_slots` of the step's mask).  ``pools`` is the whole arena
     and comes back whole: the write lands in it and the attention reads
     the layer's pages out of it, so no layer's pool is ever sliced out of
     the stack.  Under int8 the chunk quantizes on write and the per-slot
@@ -1579,7 +1582,7 @@ def _paged_layer_step(
         attn_out = paged_decode_attention(
             q, new.k, new.v, tables, positions, layer=layer,
             impl="lax" if ctx is not None else "auto",
-            k_scale=new.k_scale, v_scale=new.v_scale,
+            k_scale=new.k_scale, v_scale=new.v_scale, live=live,
         )
         return attn_out, new
 
@@ -1600,8 +1603,10 @@ def paged_forward_step(
     """tokens [B] or [B, t] at per-row slots positions..positions+t-1 ->
     (logits [B, t, v] f32, pools).  t = 1 is the plain decode step;
     t > 1 is the speculative verify chunk (causal within the chunk).
-    Inactive rows still run (fixed shape) but write to the null block
-    and their logits are garbage the caller ignores.  Chunk slots past a
+    Inactive rows keep their place in the fixed shape, write to the null
+    block and are NOT attended (the paged kernel's grid follows the live
+    rows; a finished row's stale position is never read); their logits
+    are garbage the caller ignores.  Chunk slots past a
     row's block-table allocation gather the NULL padding entry, so a
     near-budget verify overrun can never alias another row's blocks
     (the engine also reserves draft_k slack — belt and braces).
@@ -1646,11 +1651,13 @@ def paged_forward_step(
     # the arena is CARRIED through the layer loop and written in place;
     # as the scan's xs / ys each layer's pool was sliced out of the stack
     # and written back into a second one (1.6 GB twice a step at GPT-1.3B)
+    live = live_slots(active)  # once a step: every layer's kernel visits the same rows
+
     def body(carry, inp):
         x, pools = carry
         p_l, layer = inp
         return _paged_layer_step(
-            p_l, x, pools, layer, blk, off, block_tables, positions, cfg, ctx
+            p_l, x, pools, layer, blk, off, block_tables, positions, live, cfg, ctx
         ), None
 
     layers = jnp.arange(pools.k.shape[0], dtype=jnp.int32)

@@ -473,6 +473,24 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 
 
+def live_slots(active: jax.Array):
+    """A decode step's ``active`` mask [slots] -> (the live slots' numbers
+    first, ascending, [slots] int32, their count [1] int32): the list the
+    serving kernels' grids and block addresses follow (``pfx_decode_paged``,
+    ``pfx_decode_window``, ``pfx_ssm_decode``), so a dead slot costs them no
+    grid step.  Every layer of a step shares one mask: the step makes this
+    ONCE and hands it to each layer's call."""
+    live, = jnp.nonzero(active, size=active.shape[0], fill_value=0)
+    return live.astype(jnp.int32), jnp.sum(active, dtype=jnp.int32)[None]
+
+
+def _live_mask(live, b: int):
+    """What :func:`live_slots` made, back as the mask [b] it was made of."""
+    slots, count = live
+    at = jax.lax.iota(jnp.int32, b)
+    return jnp.any((slots[None, :] == at[:, None]) & (at[None, :] < count[0]), axis=1)
+
+
 def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
                k_scale=None, v_scale=None, t=None, starts=None):
     """q_t [b, n, t, d] (grouped heads: [b, kv heads, group * t, d], the
@@ -580,9 +598,9 @@ def paged_pages_per_step(block: int, width: int, group: int = 1) -> int:
 def paged_tokens_computed(positions, t: int, block: int, width: int, group: int = 1):
     """KV tokens per head the paged kernel computes on for rows whose
     first query sits at slot ``positions`` (array): each row's context
-    ``positions + t`` rounded up to whole grid steps (a slot at position
-    0 — an empty one — costs one), never past the table.  The scheduler's
-    ``pfx_sched_decode_grid_tokens_total`` sums this over a step's slots."""
+    ``positions + t`` rounded up to whole grid steps, never past the
+    table.  The scheduler's ``pfx_sched_decode_grid_tokens_total`` sums
+    this over a step's LIVE slots: the rows the kernel's grid visits."""
     pages = paged_pages_per_step(block, width, group)
     step = pages * block
     steps = -(-width // pages)
@@ -597,10 +615,13 @@ def _paged_last_page(pos, qt, *, t, tq, bs):
 
 
 def _paged_kernel(
-    layer_ref, tables_ref, pos_ref, *refs, scale, bs, t, tq, pages,
+    layer_ref, tables_ref, pos_ref, live_ref, *refs, scale, bs, t, tq, pages,
     width, quant, per_kv=1, windowed=False
 ):
-    """One (row, query tile, page group) grid step, every head inside.
+    """One (live row, query tile, page group) grid step, every head inside.
+    Grid step ``i`` of the first axis is the i-th LIVE row, ``live_ref[i]``:
+    the axis is as long as the step's live count, so a dead slot gets no grid
+    step, no DMA and no write (its output rows are the caller's to zero).
     With ``per_kv`` > 1 query heads to a KV head, the ``per_kv * t`` queries
     that read one KV head are the ROWS of one product against its page
     (``q_ref`` [1, kv heads, per_kv * t, d], one tile), so a page is read
@@ -623,7 +644,7 @@ def _paged_kernel(
     dequantized block never materializes.
 
     ``windowed`` (a window layer's call, ``pfx_decode_window``; one query a
-    row): a fourth prefetched scalar a row, ``start``, is the first slot the
+    row): one more prefetched scalar a row, ``start``, is the first slot the
     row attends: a group that ends before it runs nothing, the index maps
     re-address its pages to the first one needed, and the slots before it are
     masked like the slots after ``pos``."""
@@ -633,7 +654,7 @@ def _paged_kernel(
         q_ref, *refs = refs
     kv = refs[: (4 if quant else 2) * pages]
     o_ref, acc_ref, m_ref, l_ref = refs[len(kv):]
-    i = pl.program_id(0)
+    i = live_ref[pl.program_id(0)]
     qt = pl.program_id(1)
     j = pl.program_id(2)
     pos = pos_ref[i]
@@ -707,7 +728,7 @@ def _paged_kernel(
         ).astype(o_ref.dtype)
 
 
-def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
+def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale, live,
                   k_scale=None, v_scale=None, t=None, starts=None):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -727,18 +748,20 @@ def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
             f"in one tile (at most {_PAGED_Q_TILE}) and reads no int8 pools yet")
 
     windowed = starts is not None
-    prefetch = [layer[None], tables, positions]
+    live, count = live  # the grid's first axis: its bound is ``count``, step i is row ``live[i]``
+    prefetch = [layer[None], tables, positions, live]
     if windowed:
         if t != 1 or quant:
             raise ValueError("pfx_decode_window takes one query a row and reads no int8 pools")
         prefetch.append(starts.astype(jnp.int32))
 
     def page_index(p, stacked):
-        def index(i, qt, j, layer_ref, tables_ref, pos_ref, *start_ref):
+        def index(i, qt, j, layer_ref, tables_ref, pos_ref, live_ref, *start_ref):
             # scalar-prefetch clamp: past the last page this query tile
             # needs, re-address the page already fetched — Pallas skips
             # the DMA when the index is unchanged between consecutive
             # grid steps
+            i = live_ref[i]
             last = _paged_last_page(pos_ref[i], qt, t=t, tq=tq, bs=bs)
             page = jnp.minimum(j * pages + p, jnp.minimum(last, M - 1))
             if windowed:  # and before the first page the window needs, that one
@@ -747,7 +770,9 @@ def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
             return (layer_ref[0],) + at if stacked else at
         return index
 
-    q_spec = pl.BlockSpec((1, n, group * tq, d), lambda i, qt, j, *_: (i, 0, qt, 0))
+    q_spec = pl.BlockSpec((1, n, group * tq, d),
+                          lambda i, qt, j, _layer, _tables, _pos, live_ref, *_: (
+                              live_ref[i], 0, qt, 0))
     # the pools enter WHOLE, all layers: the page address carries the
     # layer, so the caller's arena is read where it lies (a layer sliced
     # out of the stack would be copied to a buffer of its own first)
@@ -775,7 +800,7 @@ def _paged_pallas(q_t, k_pool, v_pool, layer, tables, positions, scale,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(b, -(-t // tq), -(-M // pages)),
+        grid=(count[0], -(-t // tq), -(-M // pages)),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
@@ -812,6 +837,7 @@ def paged_decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     starts: Optional[jax.Array] = None,
+    live=None,
 ) -> jax.Array:
     """Block-table-indexed decode attention for the paged KV cache.
 
@@ -847,15 +873,24 @@ def paged_decode_attention(
     :func:`window_view` makes the table, positions and starts of a row's
     ring of pages.
 
+    ``live``: what :func:`live_slots` makes of the step's ``active`` mask
+    (the live rows' numbers first, and their count), from a decode step that
+    has rows to skip.  Only the rows it lists are attended: a row it leaves
+    out is never read (its table may point anywhere, its position may be
+    stale) and its result is 0.  Without it every row is live.
+
     ``impl``: "auto" (pallas on a TPU, lax on the CPU) | "pallas" | "lax".
-    The pallas spelling runs one grid step per (row, query tile, group of
-    pages) with every head inside: a step DMAs whole pool pages
+    The pallas spelling runs one grid step per (LIVE row, query tile, group
+    of pages) with every head inside: the grid's first bound is the live
+    count and every address reads the row from the list, so a dead slot
+    costs nothing; a step DMAs whole pool pages
     ([n, block, d], contiguous; :func:`paged_pages_per_step` of them)
     through scalar-prefetch-clamped index maps, and a step past a row's
-    context runs nothing — HBM reads and compute scale with each row's
-    real length, retiring the known limit of `_decode_pallas` (which
+    context runs nothing — HBM reads and compute scale with the live rows'
+    real lengths, retiring the known limit of `_decode_pallas` (which
     streams the whole cache row).  The lax spelling gathers via
-    ``jnp.take`` (XLA partitions it freely under GSPMD).
+    ``jnp.take`` (XLA partitions it freely under GSPMD), every row's pages,
+    dead rows at position 0; both spellings zero the dead rows.
     """
     if impl not in ("auto", "pallas", "lax"):
         raise ValueError(
@@ -890,12 +925,19 @@ def paged_decode_attention(
         if n % kv:
             raise ValueError(f"{kv} KV heads do not divide {n} query heads")
         q_t = q_t.reshape(b, kv, (n // kv) * t, d)
+    seen = None if live is None else _live_mask(live, b)
     if use_pallas:
+        if live is None:  # no mask: the identity list through the same grid
+            live = (jax.lax.iota(jnp.int32, b), jnp.full((1,), b, jnp.int32))
         out = _paged_pallas(q_t, k_pool, v_pool, layer, block_tables,
-                            positions, scale, k_scale, v_scale, t, starts)
+                            positions, scale, live, k_scale, v_scale, t, starts)
     else:
+        if seen is not None:  # a dead row's stale position bounds no loop
+            positions = jnp.where(seen, positions, 0)
         out = _paged_lax(q_t, k_pool, v_pool, layer, block_tables, positions,
                          scale, k_scale, v_scale, t, starts)
+    if seen is not None:  # a row that was not visited is 0, never what a buffer held
+        out = jnp.where(seen[:, None, None, None], out, 0.0)
     return out.reshape(b, n, t, d).transpose(0, 2, 1, 3).astype(q.dtype)
 
 

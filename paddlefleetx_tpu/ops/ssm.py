@@ -15,7 +15,8 @@ through its layer loop, and the kernel rewrites one layer's slots through
 ``input_output_aliases`` (models/gpt/generation.py, docs/nemotron_h.md).
 For the same reason the kernel visits the LIVE slots only: its grid's first
 bound is the number of live slots of the step and every block address reads
-the slot from a compacted list (:func:`live_slots`), so a dead slot's state
+the slot from a compacted list (``ops/decode_attention.py`` ``live_slots``,
+the same one the paged attention kernels follow), so a dead slot's state
 costs no traffic and is left as it lies, bit for bit.
 
 **Layout.**  The array is ``[layers, slots, R, state, W]``: the (head,
@@ -38,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddlefleetx_tpu.ops.decode_attention import live_slots  # the one list every kernel follows
 from paddlefleetx_tpu.utils import device as _device
 
 # bytes of state one grid step holds: 1 MB in and 1 MB out, twice for the
@@ -76,15 +78,6 @@ def unpack_state(packed: jax.Array, heads: int, head_dim: int) -> jax.Array:
     """[..., R, state, W] -> S [..., heads, head_dim, state]."""
     *lead, r, n, w = packed.shape
     return jnp.swapaxes(packed, -1, -2).reshape(*lead, heads, head_dim, n)
-
-
-def live_slots(active: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """The step's ``active`` mask [slots] -> (the live slots' numbers first,
-    ascending, [slots] int32, their count [1] int32): what ``pfx_ssm_decode``'s
-    grid and block addresses follow.  Every layer of a decode step shares one
-    mask, so the step makes this ONCE and hands it to each layer's update."""
-    live, = jnp.nonzero(active, size=active.shape[0], fill_value=0)
-    return live.astype(jnp.int32), jnp.sum(active, dtype=jnp.int32)[None]
 
 
 def _decode_kernel(layer_ref, live_ref, xdt_ref, dec_ref, dx_ref, bt_ref, ct_ref, s_ref, y_ref,

@@ -14,6 +14,8 @@ Covers the ISSUE decode-overhaul acceptance criteria:
     values.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -371,3 +373,84 @@ def test_topp_k_env_knob(monkeypatch):
     monkeypatch.setenv("PFX_TOPP_K", "-3")
     with pytest.raises(ValueError, match="PFX_TOPP_K"):
         sample_logits(key, logits, top_p=0.9)
+
+
+# ---------------------------------------------------------------------------
+# The paged kernels visit the live rows only (pfx_decode_paged,
+# pfx_decode_window: the grid's first axis is the step's live list)
+# ---------------------------------------------------------------------------
+
+_LIVE_ROWS = 6
+_LIVE_MASKS = {
+    "all_live": [1, 1, 1, 1, 1, 1], "none_live": [0, 0, 0, 0, 0, 0],
+    "first_dead": [0, 1, 1, 1, 1, 1], "last_dead": [1, 1, 1, 1, 1, 0],
+    "alternate_dead": [1, 0, 1, 0, 1, 0],
+}
+_LIVE_BS, _LIVE_M, _LIVE_KV, _LIVE_D = 16, 12, 2, 8  # 12 pages: two groups of 8, the last ragged
+_LIVE_WINDOW = 40
+
+
+@functools.lru_cache(maxsize=None)
+def _live_call(impl, windowed, masked):
+    """One compiled call a (spelling, kind of layer, with or without a
+    list): the mask is an argument, so its cases share the program."""
+    from paddlefleetx_tpu.ops.decode_attention import live_slots, paged_decode_attention
+
+    def call(q, k_pool, v_pool, tables, positions, active):
+        starts = jnp.maximum(positions - (_LIVE_WINDOW - 1), 0) if windowed else None
+        return paged_decode_attention(
+            q, k_pool, v_pool, tables, positions, impl=impl, starts=starts,
+            live=live_slots(active) if masked else None)
+
+    return jax.jit(call)
+
+
+@pytest.mark.parametrize("mask", list(_LIVE_MASKS))
+@pytest.mark.parametrize("group", [1, 8], ids=["mha", "gqa8"])
+@pytest.mark.parametrize("kind,t", [("full", 1), ("full", 3), ("window", 1)],
+                         ids=["full-t1", "full-verify3", "window-t1"])
+@pytest.mark.parametrize("impl", ["lax", "pallas"])
+def test_the_paged_kernel_visits_the_live_rows_only(impl, kind, t, group, mask):
+    """Both spellings, full and window layers, the decode step and a verify
+    chunk, with and without shared KV heads: the rows the live list names
+    equal the all-live call's rows to the bit; a row it leaves out is
+    exactly 0 and is NEVER READ: its table points at pages of NaN and its
+    position is stale, far past the table."""
+    rng = np.random.default_rng(_LIVE_ROWS * t + group)
+    b, bs, M, kv, d = _LIVE_ROWS, _LIVE_BS, _LIVE_M, _LIVE_KV, _LIVE_D
+    nb = b * M + 2
+    poison = nb - 1  # a page of NaN that no live row's table names
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=(nb, kv, bs, d)), jnp.float32)
+                      .at[poison].set(jnp.nan) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(b, t, kv * group, d)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb - 1))[: b * M].reshape(b, M), jnp.int32)
+    positions = jnp.asarray([0, 17, 127, 128, 150, M * bs - t], jnp.int32)
+    active = jnp.asarray(_LIVE_MASKS[mask], bool)
+
+    windowed = kind == "window"
+    every = _live_call(impl, windowed, False)(
+        q, k_pool, v_pool, tables, positions, jnp.ones((b,), bool))
+    assert bool(jnp.isfinite(every).all())
+    got = _live_call(impl, windowed, True)(
+        q, k_pool, v_pool, jnp.where(active[:, None], tables, poison),
+        jnp.where(active, positions, M * bs + 1000), active)
+    assert got.shape == q.shape and bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(np.asarray(got[active]), np.asarray(every[active]))
+    assert bool((got[~active] == 0).all())
+
+
+def test_one_function_makes_the_live_list_for_every_kernel():
+    """`live_slots` lives beside the paged kernels and the state kernel's
+    module hands out the same function: the step makes ONE list."""
+    from paddlefleetx_tpu.models.gpt import generation
+    from paddlefleetx_tpu.ops import decode_attention, ssm
+
+    assert ssm.live_slots is decode_attention.live_slots is generation.live_slots
+    live, count = decode_attention.live_slots(jnp.asarray([0, 1, 1, 0, 1], bool))
+    assert live.dtype == count.dtype == jnp.int32 and count.shape == (1,)
+    assert live[:3].tolist() == [1, 2, 4] and int(count[0]) == 3
+    # the mask comes back from the list whatever fills its tail (slot 0, live or not)
+    for mask in ([1, 0, 0, 1], [0, 0, 1, 1], [0, 0, 0, 0], [1, 1, 1, 1]):
+        active = jnp.asarray(mask, bool)
+        assert decode_attention._live_mask(decode_attention.live_slots(active), 4).tolist() \
+            == active.tolist()

@@ -488,6 +488,7 @@ def _per_layer_reference(params, tokens, pools, tables, positions, active, cfg,
         _in_dtype,
         _paged_layer_step,
         layer_norm,
+        live_slots,
     )
 
     t = tokens.shape[1]
@@ -503,13 +504,14 @@ def _per_layer_reference(params, tokens, pools, tables, positions, active, cfg,
     if n_valid is not None:
         blk = jnp.where(jnp.arange(t)[None, :] < n_valid[:, None], blk, 0)
     off = pos_t % bs
-    layer_step = jax.jit(_paged_layer_step, static_argnums=(8,))
+    live = live_slots(active)
+    layer_step = jax.jit(_paged_layer_step, static_argnums=(9,))
     out = []
     for l in range(cfg.num_layers):
         p_l = jax.tree.map(lambda a: a[l], params["layers"])
         one = jax.tree.map(lambda a: a[l][None], pools)
         x, one = layer_step(
-            p_l, x, one, jnp.int32(0), blk, off, tables, positions, cfg)
+            p_l, x, one, jnp.int32(0), blk, off, tables, positions, live, cfg)
         out.append(one)
     x = layer_norm(x, params["final_ln"]["scale"], params["final_ln"]["bias"])
     logits = jnp.einsum("bsh,vh->bsv", x, word).astype(jnp.float32)

@@ -251,6 +251,33 @@ def test_prefill_and_decode_through_both_arenas_equal_the_full_forward(window, h
     assert eng.stats["kv_window_tokens"] <= window * eng.stats["row_steps"]
 
 
+def test_a_dead_slot_between_two_live_ones_is_skipped_by_both_kinds_of_layer():
+    """Three slots; the middle row finishes after two tokens and nobody takes
+    its slot, so most steps run with slot 1 DEAD between two live rows: the
+    step's live list is [0, 2], both kinds of attention layer follow it (the
+    full layers' pages and the window layers' rings), and the two live rows'
+    pending logits still equal the reference's at every position, well past
+    the window."""
+    window = 8
+    sizes = _toy(sliding_window=window, num_attention_heads=6)
+    srv = _server(sizes)
+    eng = _engine(srv, max_batch=3)
+    slots, admit = [], eng.admit
+    eng.admit = lambda *a, **kw: slots.append(admit(*a, **kw)) or slots[-1]
+    rng = np.random.default_rng(43)
+    prompts = [rng.integers(1, 512, size=n).tolist() for n in (5, 3, window + 3)]
+    budgets = [3 * window, 2, 3 * window - 4]
+    served = _serve_rows(eng, prompts, budgets, stagger=1)
+    assert slots == [0, 1, 2]
+    # rows 0 and 2 ran on for some twenty steps after row 1 left
+    assert eng.stats["row_steps"] == sum(budgets)
+    assert eng.stats["slot_steps"] - eng.stats["row_steps"] > 2 * window
+    for name, (prompt, out, got) in served.items():
+        assert len(out) == budgets[name]
+        want = _reference_rows(srv.params, sizes, prompt, out)
+        assert float(np.max(np.abs(got - want))) < F32_ROUNDINGS, name
+
+
 def test_a_prefill_writes_only_the_last_window_s_pages_into_the_ring(server):
     """A prompt of 43 tokens on pages of 8 with a window of 8: the row's first
     decode step (position 43) sees tokens 36..43, pages 4 and 5; the prefill

@@ -316,8 +316,9 @@ def test_decode_work_counters_against_a_hand_count(server):
     hi = sum((len(p) + 6) * len(o) for p, o in zip(PROMPTS, outs))
     assert lo <= d["kv_tokens"] <= hi
     # every context here fits one page, so the kernel computes one grid
-    # step of one page for each slot, live or empty, and nothing past it
-    assert d["grid_tokens"] == eng.capacity * eng.block * d["steps"]
+    # step of one page for each LIVE slot (its grid follows the step's live
+    # list: an empty slot is not visited) and nothing past it
+    assert d["grid_tokens"] == eng.block * d["row_steps"]
     mets = {name: v for name, labels, v in sched.collect() if not labels}
     assert mets["pfx_sched_decode_steps_total"] == eng.stats["steps"]
     assert mets["pfx_sched_decode_row_steps_total"] == eng.stats["row_steps"]
