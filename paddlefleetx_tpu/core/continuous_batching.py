@@ -616,13 +616,16 @@ class PagedDecodeEngine:
     def _count_moe(self, counts, fetch: bool = True) -> None:
         """Fold one dispatch's expert-layer counts into stats.  A prefill's
         (``fetch=False``) wait for the next commit's fetch: reading them
-        at the admission would hold the host until the prefill has run."""
+        at the admission would hold the host until the prefill has run.
+        So would the commit of a step the prefill was queued BEHIND: its
+        counts ride that step's record and wait for the commit after."""
         if counts is None or self._warmup:
             return
-        self._moe_pending.append(counts)
         if not fetch:
+            (self._moe_pending if self._inflight is None
+             else self._inflight["moe_behind"]).append(counts)
             return
-        pending, self._moe_pending = self._moe_pending, []
+        pending, self._moe_pending = self._moe_pending + [counts], []
         for c in pending:
             for key, n in zip(("moe_pairs", "moe_held_pairs", "moe_held_max_pairs"),
                               np.asarray(c).tolist()):
@@ -959,6 +962,24 @@ class PagedDecodeEngine:
             self.pools = self._pools_of(pools_t)
         return seq_id, table, shared, cow, m
 
+    def seats_behind_step(self, prompt_ids: Sequence[int]) -> bool:
+        """Whether :meth:`admit` of this prompt may run while a step is in
+        flight, its prefill queued on the device behind that step.  Only
+        the monolithic prefill may: it fetches nothing, sets the new row's
+        logits and counts on the step's output futures, and
+        :meth:`_commit` merges by the dispatch-time mask, so the row's
+        fresh host values win.  Speculation, a chunked prefill and a
+        prefix hit (or a spilled prefix that may become one) keep the
+        flush in front of them; so does the scheduler for an adoption and
+        a resumed row."""
+        if not self.inflight_rows or self.spec is not None or self.prefill_chunk:
+            return False
+        if self.prefix_enabled:
+            if self.cache.spill.enabled and len(self.cache.spill):
+                return False
+            return self.cache.prefix.match(prompt_ids)[2] == 0
+        return True
+
     def _cache_admit(self, seq_id: int, tokens: int,
                      shared: Optional[List[int]] = None) -> List[int]:
         """`PagedCacheManager.admit` with the eviction accounting kept
@@ -991,8 +1012,13 @@ class PagedDecodeEngine:
         device work (decode steps go through _dispatch instead);
         ``span_args`` ride the ``pfx.sched.prefill`` trace span.  Token-gap
         books: the interval between two commits that holds one of these
-        is an ``admission`` interval."""
-        if not self._warmup:
+        is an ``admission`` interval.  Queued BEHIND a step in flight
+        (``behind``), the device runs it after that step, so the interval
+        it falls in begins at that step's commit: the mark is carried on
+        the in-flight record and :meth:`_commit` sets it."""
+        if self._inflight is not None:
+            self._inflight["behind"] = True
+        elif not self._warmup:
             self.gap_books.note("admission")
         with ledger_span("pfx.sched.prefill", self.stats, "t_device_prefill",
                          what=what, **span_args):
@@ -1671,7 +1697,8 @@ class PagedDecodeEngine:
         slots returned are those of the COMMITTED (previous) step.
         Callers that mutate row membership or host row state
         (admit/adopt/release/evict) between steps must :meth:`flush`
-        first."""
+        first, but for an :meth:`admit` that :meth:`seats_behind_step`
+        allows: the step after it takes the commit-first ordering."""
         pending = [
             i for i, r in enumerate(self.slots)
             if r is not None and not r.prefill_done
@@ -1685,9 +1712,16 @@ class PagedDecodeEngine:
         # tokens to draft from and a pending chunked prefill needs the
         # host tick, so both take the commit-first ordering below
         # instead (the readback then only waits for whatever compute
-        # the prior dispatch has not finished yet).
+        # the prior dispatch has not finished yet).  So does the step
+        # after an admission that was queued behind the step in flight
+        # (seats_behind_step): that step's device-side positions /
+        # gen_steps / active do not hold the new row, and its commit and
+        # the dispatch from the host mirrors run while the device runs
+        # the prefill.
+        behind = self._inflight is not None and self._inflight["behind"]
         if (self.dispatch_ahead and self._inflight is not None
-                and self.spec is None and not pending and self.active.any()):
+                and self.spec is None and not pending and not behind
+                and self.active.any()):
             prev, self._inflight = self._inflight, None
             nxt = self._dispatch(
                 prev["positions"], prev["gen_steps"], prev["active"],
@@ -1705,6 +1739,10 @@ class PagedDecodeEngine:
             nxt["was_active"] = self.active.copy()
             return finished
         finished = self.flush()
+        if behind:
+            # the device has the prefill to run: the host seconds from
+            # this commit to the dispatch below are no gap of its
+            self._t_results = None
         # chunked-prefill interleave: at most ONE pending chunk per
         # iteration, oldest admission first — a long prompt streams in
         # across iterations while the decode batch below keeps stepping,
@@ -1729,6 +1767,14 @@ class PagedDecodeEngine:
         """True while a dispatched step's results are not yet fetched
         (dispatch-ahead mode only; always False when synchronous)."""
         return self._inflight is not None
+
+    @property
+    def inflight_rows(self) -> int:
+        """Rows live at the dispatch of the step in flight: 0 with none in
+        flight, and for a chained step that outlived its rows (the commit
+        before it finished them all), which the device is long done with."""
+        fl = self._inflight
+        return 0 if fl is None else int(fl["was_active"].sum())
 
     def _dispatch(self, positions, gen_steps, active, *,
                   overlapped: bool) -> Dict[str, Any]:
@@ -1805,6 +1851,12 @@ class PagedDecodeEngine:
             "active": active_t, "rows": list(self.slots), "k": k,
             "was_active": None, "width_bucket": M,
             "moe": moe[0] if moe else None,
+            # a donating dispatch was queued behind this step
+            # (_dispatch_donating): the next step does not chain on it,
+            # and the interval after its commit is an admission's
+            "behind": False,
+            # the expert-layer counts of prefills queued behind it
+            "moe_behind": [],
         }
 
     def flush(self) -> List[int]:
@@ -1840,6 +1892,7 @@ class PagedDecodeEngine:
                 positions = np.array(fl["positions"])
                 gen_steps = np.array(fl["gen_steps"])
                 self._count_moe(fl["moe"])
+                self._moe_pending.extend(fl["moe_behind"])
         except BaseException as exc:
             dead = self.reset()
             raise ArenaReset(
@@ -1931,6 +1984,9 @@ class PagedDecodeEngine:
         # the commit's stamp is where the readback span ended: the same
         # time.monotonic() the pfx.sched.readback span took
         self.gap_books.commit(rb.t1, framed)
+        if fl["behind"] and not self._warmup:
+            # the prefill queued behind this step runs from here on
+            self.gap_books.note("admission")
         if self.spec and n_act and not self._warmup:
             proposed = fl["k"] * n_act
             accepted = int(ncommit[was_active].sum()) - n_act
@@ -2196,6 +2252,11 @@ class ContinuousScheduler:
     while slots + blocks allow (prefill-on-admit), then step the batch.
     """
 
+    # where an admission's dispatch found the device: queued behind the
+    # step in flight, after a flush of it in the same iteration, or with
+    # nothing (that held live rows) in flight
+    ADMIT_PATHS = ("behind_step", "after_flush", "idle")
+
     def __init__(self, engine: PagedDecodeEngine, *, max_depth: int = 64,
                  name: str = "serve-cb",
                  dispatch_ahead: Optional[bool] = None,
@@ -2296,6 +2357,7 @@ class ContinuousScheduler:
         }
         self._sched_wall_s = 0.0
         self._t_device_free = 0.0  # _iterate_inner / _flush_engine stamp
+        self._flushed = False  # _iterate_inner / _flush_engine, as above
         # Tokens: bank accounting over ADMITTED (committed) tokens.
         # admitted == delivered + evicted_lost + preempt_refunded +
         # shed_after_admit + (tokens still on live rows) holds EXACTLY
@@ -2340,6 +2402,9 @@ class ContinuousScheduler:
                 "gen_errors": "pfx_queue_gen_errors_total",
                 "evictions": "pfx_request_evictions_total",
                 "prefill_admits": "pfx_prefill_admits_total",
+                # the same admissions by where their dispatch found the
+                # device (pfx_sched_admissions_total{path=}, collect())
+                **{"admits_" + path: None for path in self.ADMIT_PATHS},
                 # instance-local: the per-tenant labeled counter
                 # (pfx_tenant_preemptions_total) is the exported form
                 "preemptions": None,
@@ -2442,6 +2507,11 @@ class ContinuousScheduler:
         out.append((
             "pfx_sched_gap_books_errors_total", {}, float(books.errors),
         ))
+        for path in self.ADMIT_PATHS:
+            out.append((
+                "pfx_sched_admissions_total", {"path": path},
+                float(self.stats["admits_" + path]),
+            ))
         # work counted at the commit of every decode step (engine stats)
         for key, name in (
             ("steps", "pfx_sched_decode_steps_total"),
@@ -3156,6 +3226,7 @@ class ContinuousScheduler:
         # path's host seconds: the iteration's start, or the end of the
         # flush that committed the step in flight (_flush_engine)
         self._t_device_free = now
+        self._flushed = False  # a step with live rows was committed early
 
         # k-step scheduling quantum (PFX_SCHED_QUANTUM, default 1 =
         # every iteration): the shed/evict/admission scans below run on
@@ -3238,75 +3309,19 @@ class ContinuousScheduler:
                 e, "expired_partial" if e in partial else "mid-decode"
             )
 
-        with self._wake:
-            waiting = bool(self._entries)
-        if waiting:
-            # admission capacity (free slots/blocks) must reflect rows
-            # the in-flight step just finished — the synchronous path
-            # admits with exactly this view
+        # pick against the view as it stands, the step in flight not
+        # committed: rows that step finishes hold their slots and blocks
+        # until its commit, so a free slot and enough free blocks now are
+        # still free after it, and a unit that fits is seated with the
+        # step still running (its prefill queues on the device behind
+        # it).  The commit buys something only when this view cannot seat
+        # the head: then flush and pick again, in this same iteration
+        reserved_blocks, blocked = self._pick_units(admitted, 0)
+        if blocked is not None and eng.has_inflight:
             n_finished += self._flush_engine()
-
-        reserved_blocks = 0
-        blocked: Optional[tuple] = None
-        with self._wake:
-            # weighted-fair admission: a deficit round-robin across
-            # tenant queues replaces the old global-FCFS head pull.
-            # Each pick serves the chosen tenant's OLDEST admissible
-            # unit (a preempted row waiting to resume before any fresh
-            # row) and charges one deficit — FCFS within a tenant, one
-            # tenant degenerates to exactly the old FCFS order.
-            # Nothing is allocated until the prefill loop below, so the
-            # pull accounts for its OWN picks — a burst larger than free
-            # capacity stays queued instead of hard-failing at admit()
-            free_slots = eng.free_slots()
-            free_blocks = eng.cache.allocator.free_count()
-            # cached-prefix blocks only the index references evict on
-            # demand inside admit — add them to the budget LAZILY (the
-            # reclaimable scan is O(cached nodes); an iteration whose
-            # free pool already covers its admissions never pays it)
-            reclaim_counted = False
-            # already-failed entries (e.g. an earlier row died in an
-            # ArenaReset) must neither reserve capacity nor spend a
-            # tenant's turn
-            self._entries = [e for e in self._entries if not e.future.done()]
-            while self._entries:
-                backlog: Dict[str, int] = {}
-                for e in self._entries:
-                    backlog[e.tenant] = backlog.get(e.tenant, 0) + 1
-                pick = self._fair.pick(backlog)
-                head = next(e for e in self._entries if e.tenant == pick)
-                row_idx, p, mx, resumed = self._next_unit(head)
-                need = blocks_for(
-                    eng.row_capacity_tokens(len(p), mx), eng.block
-                )
-                if need > free_blocks and not reclaim_counted:
-                    free_blocks += eng.cache.prefix.reclaimable_blocks()
-                    reclaim_counted = True
-                if free_slots < 1 or need > free_blocks:
-                    # head-of-line blocked (same backpressure as the old
-                    # FCFS pull) — remembered as the priority-preemption
-                    # candidate below
-                    blocked = (head, row_idx, p, mx, resumed, need)
-                    break
-                free_slots -= 1
-                free_blocks -= need
-                reserved_blocks += need
-                self._fair.charge(head.tenant)
-                t_pick = time.monotonic()
-                head.future.times.setdefault("picked", t_pick)
-                if (head.future.trace is not None and head.next_row == 0
-                        and not resumed):
-                    head.future.trace.span(
-                        "queue_wait", t0=head.enqueued_at, t1=t_pick,
-                    )
-                admitted.append((head, row_idx, p, mx, resumed))
-                if resumed:
-                    head.requeue_rows.pop(0)
-                else:
-                    head.next_row += 1
-                if (head.next_row >= len(head.prompts)
-                        and not head.requeue_rows):
-                    self._entries.remove(head)
+            reserved_blocks, blocked = self._pick_units(
+                admitted, reserved_blocks
+            )
 
         # priority preemption (outside the lock: engine work).  A
         # blocked arrival with strictly higher priority than the
@@ -3387,15 +3402,26 @@ class ContinuousScheduler:
                                 and head in self._entries):
                             self._entries.remove(head)
 
-        # prefill-on-admit (outside the lock: device work)
-        t_admitted: Optional[float] = None  # the last admission dispatched
+        # prefill-on-admit (outside the lock: device work).  With a step
+        # in flight the prefill is dispatched BEHIND it where the engine
+        # allows (seats_behind_step: the monolithic prefill, no
+        # speculation) and the unit is a fresh prompt; an adoption, a
+        # resumed row and every other admission commit the step first
+        t_admitted: Optional[float] = None  # the last one the device waited for
         for entry, row_idx, prompt, mx, resumed in admitted:
+            adopting = entry.handoff is not None and not resumed
+            behind = (eng.has_inflight and not adopting and not resumed
+                      and eng.seats_behind_step(prompt))
+            if not behind:
+                n_finished += self._flush_engine()
             if entry.future.done():
                 continue  # an earlier row of this entry already failed
             self._req_counter += 1
+            path = ("behind_step" if behind
+                    else "after_flush" if self._flushed else "idle")
             try:
                 maybe_fire("gen_crash", self._req_counter)
-                if entry.handoff is not None and not resumed:
+                if adopting:
                     # disaggregated: adopt the prefill replica's exported
                     # blocks instead of running paged_prefill.  Counted in
                     # prefill_admits too — it IS a row admission, and the
@@ -3406,7 +3432,9 @@ class ContinuousScheduler:
                     eng.adopt(meta, arrays, entry=entry, row_idx=row_idx)
                 else:
                     eng.admit(prompt, mx, entry=entry, row_idx=row_idx)
-                t_admitted = time.monotonic()
+                if not behind:
+                    t_admitted = time.monotonic()
+                self.stats["admits_" + path] += 1
                 if resumed:
                     # token ledger: a resume re-admits the prefix its
                     # preemption refunded — the tokens are back on the
@@ -3436,6 +3464,10 @@ class ContinuousScheduler:
                 # between the locked check and here, or an injected
                 # crash): arena intact, fail only this entry
                 self.stats["gen_errors"] += 1
+                # a sibling seated in an earlier iteration may be in the
+                # step this admission was to queue behind: commit it
+                # before the rows leave (the flush contract)
+                n_finished += self._flush_engine()
                 for i, r in enumerate(eng.slots):
                     if r is not None and r.entry is entry:
                         # sibling rows admitted earlier die with their
@@ -3456,6 +3488,76 @@ class ContinuousScheduler:
         if not self._has_live_rows():
             return n_finished
         return n_finished + self._step_batch()
+
+    def _pick_units(self, admitted: List[tuple],
+                    reserved_blocks: int) -> Tuple[int, Optional[tuple]]:
+        """Pick the units to seat against the engine's view as it stands
+        (free slots and blocks less what ``admitted`` already reserved)
+        and append them to ``admitted``; nothing is allocated here.
+        Returns the blocks reserved so far and the head of the line if it
+        did not fit."""
+        eng = self.engine
+        blocked: Optional[tuple] = None
+        with self._wake:
+            # weighted-fair admission: a deficit round-robin across
+            # tenant queues replaces the old global-FCFS head pull.
+            # Each pick serves the chosen tenant's OLDEST admissible
+            # unit (a preempted row waiting to resume before any fresh
+            # row) and charges one deficit — FCFS within a tenant, one
+            # tenant degenerates to exactly the old FCFS order.
+            # Nothing is allocated until _iterate_inner's prefill loop, so
+            # the pull accounts for its OWN picks — a burst larger than free
+            # capacity stays queued instead of hard-failing at admit()
+            free_slots = eng.free_slots() - len(admitted)
+            free_blocks = eng.cache.allocator.free_count() - reserved_blocks
+            # cached-prefix blocks only the index references evict on
+            # demand inside admit — add them to the budget LAZILY (the
+            # reclaimable scan is O(cached nodes); an iteration whose
+            # free pool already covers its admissions never pays it)
+            reclaim_counted = False
+            # already-failed entries (e.g. an earlier row died in an
+            # ArenaReset) must neither reserve capacity nor spend a
+            # tenant's turn
+            self._entries = [e for e in self._entries if not e.future.done()]
+            while self._entries:
+                backlog: Dict[str, int] = {}
+                for e in self._entries:
+                    backlog[e.tenant] = backlog.get(e.tenant, 0) + 1
+                pick = self._fair.pick(backlog)
+                head = next(e for e in self._entries if e.tenant == pick)
+                row_idx, p, mx, resumed = self._next_unit(head)
+                need = blocks_for(
+                    eng.row_capacity_tokens(len(p), mx), eng.block
+                )
+                if need > free_blocks and not reclaim_counted:
+                    free_blocks += eng.cache.prefix.reclaimable_blocks()
+                    reclaim_counted = True
+                if free_slots < 1 or need > free_blocks:
+                    # head-of-line blocked (same backpressure as the old
+                    # FCFS pull) — remembered as the priority-preemption
+                    # candidate below
+                    blocked = (head, row_idx, p, mx, resumed, need)
+                    break
+                free_slots -= 1
+                free_blocks -= need
+                reserved_blocks += need
+                self._fair.charge(head.tenant)
+                t_pick = time.monotonic()
+                head.future.times.setdefault("picked", t_pick)
+                if (head.future.trace is not None and head.next_row == 0
+                        and not resumed):
+                    head.future.trace.span(
+                        "queue_wait", t0=head.enqueued_at, t1=t_pick,
+                    )
+                admitted.append((head, row_idx, p, mx, resumed))
+                if resumed:
+                    head.requeue_rows.pop(0)
+                else:
+                    head.next_row += 1
+                if (head.next_row >= len(head.prompts)
+                        and not head.requeue_rows):
+                    self._entries.remove(head)
+        return reserved_blocks, blocked
 
     def _step_batch(self) -> int:
         """One iteration-level decode step: dispatch (and, synchronous
@@ -3485,6 +3587,8 @@ class ContinuousScheduler:
         admission — per the engine's dispatch-ahead flush contract."""
         if not self.engine.has_inflight:
             return 0
+        if self.engine.inflight_rows:
+            self._flushed = True
         try:
             finished = self.engine.flush()
         except ArenaReset as exc:

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import csv
 import os
+import zlib
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -128,7 +129,9 @@ class GLUEDataset:
         if self.tokenizer is not None:
             return self.tokenizer.encode(text)
         if isinstance(text, str):  # no tokenizer: hashed-word fallback (tests)
-            return [hash(w) % 30000 + 10 for w in text.split()]
+            # crc32, not hash(): Python salts str hashes per process, and
+            # the ids have to be the same in every run
+            return [zlib.crc32(w.encode("utf-8")) % 30000 + 10 for w in text.split()]
         return list(text)  # already token ids
 
     def _featurize(self, texts, label, label_map) -> Dict[str, np.ndarray]:

@@ -264,7 +264,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
     # under what the interval between the two commits held
     "pfx_sched_token_gaps_total": ("counter", "Token gaps by what their interval held (labels: held=decode|admission|flush): one per row and commit that delivered the row a FRAME when the previous commit did too (a frame is one commit's tokens for a row: with speculation several tokens, still one gap); a row's first frame is no gap"),
     "pfx_sched_token_gap_seconds_total": ("counter", "Seconds of the token gaps by what their interval held (labels: held=decode|admission|flush): rows x (this commit's stamp - the previous one's); over pfx_sched_token_gaps_total the server's own mean token gap"),
-    "pfx_sched_admit_host_seconds_total": ("counter", "Host seconds of the admission path: from the flush of the step in flight returning (or the iteration's start) to the last admission's dispatch having returned, in iterations that admitted; the device has nothing queued meanwhile"),
+    "pfx_sched_admit_host_seconds_total": ("counter", "Host seconds of the admission path during which the device had nothing queued: from the flush of the step in flight returning (or the iteration's start) to the dispatch of the last admission that was not queued behind a step in flight having returned; an admission behind a step in flight books 0"),
+    "pfx_sched_admissions_total": ("counter", "Admissions by where their dispatch found the device (labels: path=behind_step|after_flush|idle): queued behind the step in flight without committing it, after a flush of the step in flight in the same iteration, or with nothing in flight; the sum is pfx_prefill_admits_total"),
     "pfx_sched_gap_books_errors_total": ("counter", "Faults inside the token-gap books (counted and logged once, never raised into the decode loop; 0 in a sound run)"),
     "pfx_train_time_seconds_total": ("counter", "Fit-loop wall seconds by attribution bucket (labels: bucket=compile|device_step|data_wait|host|eval)"),
     # work counted where it happens (one update per decode step / train

@@ -19,6 +19,12 @@ flight), and hold the books to it:
                admission into a live batch books ``admission`` for
                exactly the live rows, a full batch with an entry waiting
                books ``flush`` (where there is a step in flight to flush)
+  behind       (PR 44) an admission queued behind a step in flight marks
+               the interval that begins at that step's commit, where the
+               prefill's device time falls, not the one its dispatch call
+               happened in; its host seconds book 0 (the device had the
+               step queued); ``pfx_sched_admissions_total{path=}`` counts
+               each admission under one of three paths
   seconds      with an injected clock the seconds are the stamps'
                differences, and the stamps are the readback span's
   fault        a fault inside the books leaves every request answered
@@ -86,7 +92,9 @@ class Account:
 
         def donating(*a, **k):
             if not eng._warmup:
-                self.marks[eng.stats["steps"]] = "admission"
+                # queued behind a step in flight, the device runs it after
+                # that step: in the interval that begins at its commit
+                self.marks[eng.stats["steps"] + eng.has_inflight] = "admission"
             return donate(*a, **k)
 
         def flushing():
@@ -330,6 +338,60 @@ def test_an_admission_into_a_live_batch_books_the_live_rows(server, ahead):
     gaps = acc.hold()
     assert gaps["admission"] == 2
     assert gaps["flush"] == 0
+
+
+@AHEAD
+def test_an_admission_behind_a_step_books_where_its_prefill_runs(server, ahead):
+    """Two rows decode, a third request is seated behind the step in
+    flight N: the interval that ends at N's commit (the one the dispatch
+    call happened in) is ``decode``, the next one, which holds the
+    prefill's device time, is ``admission`` for the two live rows, and the
+    admission's host seconds book nothing.  A fourth request finds the
+    pool short and is seated after a flush; the counter has each path.
+    Without dispatch-ahead nothing is ever in flight: every admission is
+    ``idle`` and marks the interval its dispatch happened in."""
+    sched = _sched(server, ahead, num_blocks=4)  # three rows of one block
+    acc = Account(sched)
+    eng, books = sched.engine, sched.engine.gap_books
+    futs = [acc.submit(i, PROMPTS[i], 10) for i in range(2)]
+    for _ in range(4):
+        sched._iterate()
+    assert eng.has_inflight == ahead
+    g0, host0 = dict(books.gaps), books.admit_host_s
+    assert host0 > 0.0 and g0["admission"] == 0
+    futs.append(acc.submit(2, PROMPTS[2], 4))
+    sched._iterate()  # the prefill is dispatched; ahead: N commits after it
+    g1 = dict(books.gaps)
+    sched._iterate()  # ahead: N+1 commits, the prefill ran before it
+    g2 = dict(books.gaps)
+    sched._iterate()
+    g3 = dict(books.gaps)
+    if ahead:
+        assert int(sched.stats["admits_behind_step"]) == 1
+        assert books.admit_host_s == host0  # the device had N queued
+        assert (g1["decode"], g1["admission"]) == (g0["decode"] + 2, 0)
+        assert (g2["decode"], g2["admission"]) == (g1["decode"], 2)
+    else:
+        assert int(sched.stats["admits_idle"]) == 3
+        assert books.admit_host_s > host0
+        assert (g1["decode"], g1["admission"]) == (g0["decode"], 2)
+        assert (g2["decode"], g2["admission"]) == (g1["decode"] + 3, 2)
+    # the newcomer's first frame was no gap; from here all three decode
+    assert (g3["decode"], g3["admission"]) == (g2["decode"] + 3, 2)
+    host1 = books.admit_host_s
+    futs.append(acc.submit(3, PROMPTS[3], 4))  # no block left: it waits
+    sched._iterate()
+    assert sched.depth() == 1
+    _run(sched, futs)
+    acc.hold()
+    assert books.admit_host_s > host1  # seated with nothing queued
+    mets = {lab["path"]: v for n, lab, v in sched.collect()
+            if n == "pfx_sched_admissions_total"}
+    want = ({"behind_step": 1.0, "after_flush": 1.0, "idle": 2.0} if ahead
+            else {"behind_step": 0.0, "after_flush": 0.0, "idle": 4.0})
+    assert mets == want
+    assert sum(mets.values()) == sched.stats["prefill_admits"]
+    assert all(isinstance(_outcome(f), list) for f in futs)
 
 
 @AHEAD

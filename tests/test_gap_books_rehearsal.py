@@ -1,4 +1,4 @@
-"""Four serving cells through the benchmark's own rehearsal at
+"""Five serving cells through the benchmark's own rehearsal at
 ``--trace 2``, with the token gap's books read beside the run.
 
 PR 35's books were refused on one ``--trace 2`` run of
@@ -30,6 +30,7 @@ BOOK_METRICS = ("sched.gap_admission_share", "sched.gap_flush_share",
     ("serve-nemotron3-nano-1of8-chat", 3_700_000_029),
     ("serve-1.3b-docs", 3_700_000_047),
     ("serve-mellum2-12b-1of4-code", 4_200_000_061),  # two classes of pages (PR 42)
+    ("serve-falcon-h1-34b-6of72-chat", 4_400_000_017),  # the most admissions a second (PR 44)
 ])
 def test_a_serving_cell_rehearses_correct_with_its_books_closed(cell, seed):
     win = _rehearse(cell, seed)
@@ -85,6 +86,9 @@ def _rehearse(cell: str, seed: int) -> dict:
     # every admission of an expert model went through pfx_grouped_matmul:
     # its calls a prefill are a constant of the program (none for the dense block)
     assert win["prefill_admits"] > 0
+    # each under one of the three paths (which, follows the arrivals: an
+    # admission into a live batch goes behind the step in flight)
+    assert sum(win["admissions"].values()) == win["prefill_admits"]
     assert win["moe_grouped_calls"] == _grouped_products(cell) * win["prefill_admits"]
     return win
 
@@ -99,5 +103,6 @@ def _grouped_products(cell: str) -> int:
         spec = json.load(f)
     model = dict(spec["model"], **spec.get("rehearse_model", {}))
     products = GPTConfig(**model).sorted_pair_products
-    assert (products > 0) == (cell != "serve-1.3b-docs")
+    dense = ("serve-1.3b-docs", "serve-falcon-h1-34b-6of72-chat")  # no expert layer
+    assert (products > 0) == (cell not in dense)
     return products

@@ -38,6 +38,7 @@ import run  # noqa: E402
 BOOK_METRICS = ("sched.gap_admission_share", "sched.gap_flush_share",
                 "sched.admit_host_share")
 HELD = ("decode", "admission", "flush")
+PATHS = ("behind_step", "after_flush", "idle")
 GAPS = "pfx_sched_token_gaps_total"
 SECONDS = "pfx_sched_token_gap_seconds_total"
 
@@ -67,6 +68,11 @@ def books(raw: dict, res: dict, warm_requests: list) -> dict:
             # the window's admissions and the grouped products their prefills
             # dispatched (pfx_grouped_matmul; none without expert layers)
             "prefill_admits": common.metric_sum(delta, "pfx_prefill_admits_total"),
+            # the same by where their dispatch found the device (PR 44; all
+            # 0 on a program from before it): behind the step in flight,
+            # after a flush of it, with nothing in flight
+            "admissions": {p: common.metric_sum(delta, "pfx_sched_admissions_total", path=p)
+                           for p in PATHS},
             "moe_grouped_calls": common.metric_sum(delta, "pfx_moe_serve_grouped_calls_total"),
             # frames less first frames by the token ledger and the admissions
             # counter: off by the rows seated but not yet framed at an edge,
