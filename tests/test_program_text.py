@@ -1,6 +1,6 @@
 """The cells' programs, as text: each program of ``tools/program_text.py`` (the
-decode step and a prefill of the four served configurations, and the Trinity-Mini
-train step) is lowered for a described v5e and its hash compared with
+decode step and a prefill of the five served configurations, and the two train
+steps) is lowered for a described v5e and its hash compared with
 ``tests/program_text.json``.
 
 A PR that adds a block family BESIDE them leaves that file alone, and this test
@@ -8,7 +8,9 @@ is the proof that the older cells run the programs they ran; a PR that means to
 change one of them rewrites the file (``python tools/program_text.py --write``)
 and says so.  The older cells' lines were written from PR 38's commit, before the
 Falcon-H1 block (a ``P`` layer, rotation at given positions, the fold) was added
-beside them: PR 40 left them as they were and added its own two."""
+beside them: PR 40 left them as they were and added its own two.  PR 45 added
+the Mellum2 pair and the 345M train step from its parent's code, before it took
+anything away beside them."""
 
 import json
 import os

@@ -22,7 +22,9 @@ kernel (``tpu_custom_call``'s ``body``), because it embeds the line numbers
 of the Python frames that called it, which move with any edit above them.  A
 kernel's own change is therefore NOT seen here (its file's diff shows it);
 its operands, shapes, grid-independent attributes and everything XLA gets
-around it are.
+around it are.  A kernel's TILE sits in the masked body too: the flash tiles of
+the cells' sequence lengths are pinned by ``tests/test_flash_attention.py``
+(``test_block_sizes_of_the_cells``).
 """
 
 import argparse
@@ -40,14 +42,21 @@ GOLDEN = os.path.join(os.path.dirname(HERE), "tests", "program_text.json")
 BENCH = "pfx_bench"  # noqa: E10 — a directory, not a metric
 # configuration -> (batch slots, arena pages, prefill bucket, min_dec_len): one decode step
 # and one prefill program each, shaped like the cell's (falcon-h1-34b's were written by the PR
-# that added it, 40; the others from PR 38's commit, and PR 40 left them as they were)
+# that added it, 40; the four before it from PR 38's commit, and PR 40 left them as they were;
+# mellum2-12b-a2.5b's and the 345M train step from PR 44's commit, by PR 45)
 SERVING = {
     "gpt-1.3b": (8, 8 * 8 + 1, 512, 32),
     "deepseek-v3": (64, 64 * 36 + 1, 1024, 1536),
     "nemotron-3-nano": (48, 48 * 14 + 1, 256, 768),
     "falcon-h1-34b": (64, 64 * 6 + 1, 256, 512),
+    "mellum2-12b-a2.5b": (48, 48 * 22 + 1, 2048, 768),
 }
-PROGRAMS = tuple(f"{c}.{p}" for c in SERVING for p in ("step", "prefill")) + ("trinity-mini.train_step",)
+RING = 9  # pages a row in a window layer's ring (mellum2-12b-a2.5b: window 1,024 over pages of 128)
+# configuration -> the batch of its training cell (the sequence length is the recipe's own)
+TRAINING = {"trinity-mini": 2, "gpt-345m": 16}
+# the order of tests/program_text.json: PR 40's nine, then PR 45's three
+PROGRAMS = (tuple(f"{c}.{p}" for c in list(SERVING)[:4] for p in ("step", "prefill")) + ("trinity-mini.train_step",)
+            + tuple(f"mellum2-12b-a2.5b.{p}" for p in ("step", "prefill")) + ("gpt-345m.train_step",))
 _BODY = re.compile(r'\\22body\\22: \\22[^\\]*\\22')
 
 
@@ -87,44 +96,47 @@ def lower(root: str, names=PROGRAMS) -> dict:
             cfg = GPTConfig(**json.load(f)["model"])
         params = shapes(jax.eval_shape(lambda: G.init_serving_params(cfg, jax.random.key(0))))
         bs = cfg.kv_block_default or 16
-        kw = {"slots": slots} if cfg.layer_pattern else {}
+        kw = {"slots": slots} if cfg.row_state else {}
+        if cfg.window_layers:
+            kw["ring_blocks"] = slots * RING + 1
         pools = shapes(jax.eval_shape(lambda: G.init_paged_pools(cfg, blocks, bs, **kw)))
-        if what == "prefill":
-            def prefill(p, prompt, plen, pools, row, slot):
-                row_state = {"slot": slot} if cfg.layer_pattern else {}
-                return G.paged_prefill(p, prompt, plen, pools, row, cfg, return_moe=True, **row_state)
-
-            return jax.jit(prefill, donate_argnums=(3,)).lower(
-                params, S((1, bucket), jnp.int32), S((), jnp.int32), pools,
-                S((-(-bucket // bs),), jnp.int32), S((), jnp.int32))
-        gen = G.GenerationConfig(decode_strategy="greedy_search", max_dec_len=0, min_dec_len=min_dec,
-                                 eos_token_id=0, pad_token_id=0)
-        width, vocab = (blocks - 1) // slots, cfg.vocab_size
-
-        def step(p, pools, tables, logits, counts, positions, gen_steps, max_news, active, forced):
-            rows = G.PagedRows(logits, counts, positions, gen_steps, max_news, active, forced)
-            nxt, pools, new = G.decode_step(p, pools, tables, rows, cfg, gen)
-            return nxt, pools, new.logits, new.counts, new.moe
 
         def i32(*shape):
             return S(shape, jnp.int32)
 
+        if what == "prefill":
+            def prefill(p, prompt, plen, pools, row, ring, slot):
+                row_state = {"slot": slot} if cfg.row_state else {}
+                tables = (row, ring) if cfg.window_layers else row
+                return G.paged_prefill(p, prompt, plen, pools, tables, cfg, return_moe=True, **row_state)
+
+            return jax.jit(prefill, donate_argnums=(3,)).lower(
+                params, i32(1, bucket), i32(), pools, i32(-(-bucket // bs)), i32(RING), i32())
+        gen = G.GenerationConfig(decode_strategy="greedy_search", max_dec_len=0, min_dec_len=min_dec,
+                                 eos_token_id=0, pad_token_id=0)
+        width, vocab = (blocks - 1) // slots, cfg.vocab_size
+
+        def step(p, pools, tables, rings, logits, counts, positions, gen_steps, max_news, active, forced):
+            rows = G.PagedRows(logits, counts, positions, gen_steps, max_news, active, forced)
+            nxt, pools, new = G.decode_step(p, pools, (tables, rings) if cfg.window_layers else tables, rows, cfg, gen)
+            return nxt, pools, new.logits, new.counts, new.moe
+
         return jax.jit(step, donate_argnums=(1,)).lower(
-            params, pools, i32(slots, width), S((slots, vocab), jnp.float32), i32(slots, vocab),
+            params, pools, i32(slots, width), i32(slots, RING), S((slots, vocab), jnp.float32), i32(slots, vocab),
             i32(slots), i32(slots), i32(slots), S((slots,), jnp.bool_), i32(slots))
 
-    def train_step():
+    def train_step(name):
         from paddlefleetx_tpu.core.engine import Engine
         from paddlefleetx_tpu.core.module import build_module
         from paddlefleetx_tpu.parallel.env import init_dist_env
         from paddlefleetx_tpu.utils.config import get_config
 
-        with open(os.path.join(root, BENCH, "configs", "trinity-mini.json")) as f:
+        with open(os.path.join(root, BENCH, "configs", name + ".json")) as f:
             config = json.load(f)
         cfg = get_config(
             os.path.join(root, config["yaml"]),
             overrides=[f"Model.{k}={v}" for k, v in config["model"].items()]
-            + ["Global.global_batch_size=2", "Global.local_batch_size=2", "Global.micro_batch_size=2"],
+            + [f"Global.{k}_batch_size={TRAINING[name]}" for k in ("global", "local", "micro")],
             num_devices=1)
         mesh = init_dist_env(cfg, devices=topo.devices[:1])
         with mesh:
@@ -138,7 +150,7 @@ def lower(root: str, names=PROGRAMS) -> dict:
     out = {}
     for name in names:
         config, what = name.rsplit(".", 1)
-        out[name] = digest(train_step() if what == "train_step" else serving(config, what))
+        out[name] = digest(train_step(config) if what == "train_step" else serving(config, what))
     return out
 
 
