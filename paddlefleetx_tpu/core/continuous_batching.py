@@ -47,17 +47,17 @@ per-row positions/budgets, arena occupancy, compile-key families) that
 `tools/serve.py` exposes as ``GET /debug/state`` without ever blocking
 this thread.
 
-Dispatch-ahead decode (docs/decode_path.md): with
-``PFX_DISPATCH_AHEAD=1`` (the scheduler default) the engine leaves each
-dispatched step IN FLIGHT and fetches its sampled tokens one call
-later, chaining the next dispatch on device-resident row state — the
-host's scheduling work (and the ``PFX_SCHED_QUANTUM``-amortized
-admission/eviction scans) runs in the device's shadow instead of on the
-decode critical path.  Committed tokens can stream to a per-request
-sink as they land (``submit(..., stream=...)``).  Decision-log rows
-account every event in COMMIT order, so ``replay_decision_log`` folds
-to identical totals with overlap on or off; ``PFX_DISPATCH_AHEAD=0`` is
-the loud fallback to fully-synchronous stepping.
+Dispatch-ahead decode (docs/decode_path.md): under the scheduler the
+engine leaves each dispatched step IN FLIGHT and fetches its sampled
+tokens one call later, chaining the next dispatch on device-resident row
+state — the host's scheduling work (the admission/eviction scans) runs
+in the device's shadow instead of on the decode critical path.
+Committed tokens can stream to a per-request sink as they land
+(``submit(..., stream=...)``).  Decision-log rows account every event in
+COMMIT order, so ``replay_decision_log`` folds to identical totals with
+overlap on or off (``ContinuousScheduler(dispatch_ahead=False)`` and a
+directly driven engine step synchronously: the reference the tests hold
+the totals to).
 
 Greedy outputs are token-identical to the sequential/coalesced path
 (same logits-processor chain per row, per-row positions equal to the
@@ -503,7 +503,7 @@ class PagedDecodeEngine:
         # tokens on the NEXT call (or at flush()), so host scheduling
         # work runs in the device's shadow.  Defaults to synchronous —
         # direct drivers (tests, benches) see tokens after every call;
-        # ContinuousScheduler flips it from PFX_DISPATCH_AHEAD.
+        # ContinuousScheduler turns it on.
         self.dispatch_ahead = False
         self._inflight: Optional[Dict[str, Any]] = None
         self._t_results: Optional[float] = None
@@ -2259,8 +2259,7 @@ class ContinuousScheduler:
 
     def __init__(self, engine: PagedDecodeEngine, *, max_depth: int = 64,
                  name: str = "serve-cb",
-                 dispatch_ahead: Optional[bool] = None,
-                 quantum: Optional[int] = None,
+                 dispatch_ahead: bool = True,
                  tenant_config: Optional[TenantConfig] = None,
                  preempt_min_tokens: int = 8) -> None:
         if max_depth < 1:
@@ -2293,31 +2292,12 @@ class ContinuousScheduler:
         # pfx_tenant_preemptions_total exactly)
         self._tenant_admitted: Dict[str, int] = {}
         self._tenant_preempted: Dict[str, int] = {}
-        # dispatch-ahead decode + k-step scheduling quantum
-        # (docs/decode_path.md).  PFX_DISPATCH_AHEAD=0 is the loud
-        # fallback to fully-synchronous stepping; the scheduler (not
-        # the engine ctor) owns the knob because direct engine drivers
-        # need the synchronous default.  PFX_SCHED_QUANTUM=k runs the
-        # admission/eviction/shed scans every k-th iteration only,
-        # amortizing the host bookkeeping across k decode steps.
-        if dispatch_ahead is None:
-            dispatch_ahead = _env_int("PFX_DISPATCH_AHEAD", 1) != 0
+        # dispatch-ahead decode (docs/decode_path.md); False is the
+        # synchronous stepping the tests compare it with.  The scheduler
+        # (not the engine ctor) sets it because direct engine drivers
+        # need the synchronous default.
         self.dispatch_ahead = bool(dispatch_ahead)
         engine.dispatch_ahead = self.dispatch_ahead
-        if not self.dispatch_ahead:
-            logger.warning(
-                f"{name}: PFX_DISPATCH_AHEAD=0 — synchronous decode "
-                "stepping; host scheduling no longer overlaps device "
-                "compute"
-            )
-        self.quantum = (
-            _env_int("PFX_SCHED_QUANTUM", 1)
-            if quantum is None else int(quantum)
-        )
-        if self.quantum < 1:
-            raise ValueError(
-                f"PFX_SCHED_QUANTUM must be >= 1, got {self.quantum}"
-            )
         self._entries: List[_CBEntry] = []
         # peer prefix adoptions (POST /admin/adopt_prefixes) queued for
         # the scheduler thread: (meta, arrays, future) triples, drained
@@ -2799,7 +2779,6 @@ class ContinuousScheduler:
             "arena": eng.cache.stats(),
             "overlap": {
                 "dispatch_ahead": bool(eng.dispatch_ahead),
-                "quantum": self.quantum,
                 "inflight": eng.has_inflight,
                 "host_gap_s": round(float(eng.stats["host_gap_s"]), 6),
             },
@@ -3228,22 +3207,8 @@ class ContinuousScheduler:
         self._t_device_free = now
         self._flushed = False  # a step with live rows was committed early
 
-        # k-step scheduling quantum (PFX_SCHED_QUANTUM, default 1 =
-        # every iteration): the shed/evict/admission scans below run on
-        # quantum boundaries only, amortizing the host bookkeeping over
-        # k decode steps.  An iteration with no live rows always takes
-        # the boundary path — waiting entries must admit NOW, never
-        # after k empty spins.
-        boundary = (
-            self.quantum <= 1
-            or self._iter_counter % self.quantum == 0
-            or not self._has_live_rows()
-        )
-        if not boundary:
-            return self._step_batch()
-
-        # peer prefix adoptions (drain-migration receiver): folded in at
-        # a boundary, BEFORE this iteration's admissions, so a migrated
+        # peer prefix adoptions (drain-migration receiver): folded in
+        # BEFORE this iteration's admissions, so a migrated
         # prefix is hittable by the very next admit.  Each payload was
         # fully validated at submit time; adoption failures fail only
         # their own future — except an ArenaReset, which fails every
@@ -3464,8 +3429,8 @@ class ContinuousScheduler:
                 # between the locked check and here, or an injected
                 # crash): arena intact, fail only this entry
                 self.stats["gen_errors"] += 1
-                # a sibling seated in an earlier iteration may be in the
-                # step this admission was to queue behind: commit it
+                # a sibling's prefill may be queued behind the step this
+                # admission was to queue behind too: commit that step
                 # before the rows leave (the flush contract)
                 n_finished += self._flush_engine()
                 for i, r in enumerate(eng.slots):

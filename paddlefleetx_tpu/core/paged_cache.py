@@ -27,17 +27,15 @@ Design points:
     arena (`PagedPools`) is created by `models/gpt/generation.py
     init_paged_pools` and owned by the engine.
 
-Knobs (loud-parse like PFX_DECODE_BLOCK):
-
-  PFX_KV_BLOCK   block size in cache slots (default 16; positive
-                 multiple of 8 — TPU sublane tiling)
+The block size in cache slots is ``kv_block_size``'s: a caller's ``block``,
+else the model's own (``GPTConfig.kv_block_default``), else 16; a positive
+multiple of 8 (TPU sublane tiling).
 """
 
 from __future__ import annotations
 
 import collections
 import json
-import os
 import struct
 import zlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -54,25 +52,13 @@ class BlockPoolExhausted(RuntimeError):
 
 
 def kv_block_size(block: int = 0, default: int = 0) -> int:
-    """Resolve the paged-cache block size: explicit arg, else
-    PFX_KV_BLOCK, else ``default`` (the model's own:
-    ``GPTConfig.kv_block_default``), else {_DEFAULT_KV_BLOCK}.  Must be a positive multiple
-    of 8 (TPU sublane tiling for the pallas spelling); invalid values
-    raise at setup, never silently mislabel a run."""
-    raw = os.environ.get("PFX_KV_BLOCK") or "0"
-    try:
-        env = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"PFX_KV_BLOCK={raw!r} is not an integer; pass a positive "
-            "multiple of 8 (e.g. 16) or unset it"
-        ) from None
-    force = int(block) or env or int(default) or _DEFAULT_KV_BLOCK
+    """Resolve the paged-cache block size: explicit arg, else ``default``
+    (the model's own: ``GPTConfig.kv_block_default``), else
+    ``_DEFAULT_KV_BLOCK``.  Must be a positive multiple of 8 (TPU sublane
+    tiling for the pallas spelling); an invalid value raises at setup."""
+    force = int(block) or int(default) or _DEFAULT_KV_BLOCK
     if force < 8 or force % 8:
-        raise ValueError(
-            f"kv block size {force} must be a positive multiple of 8 "
-            "(block arg / PFX_KV_BLOCK)"
-        )
+        raise ValueError(f"kv block size {force} must be a positive multiple of 8")
     return force
 
 
@@ -852,7 +838,7 @@ def check_handoff_meta(meta: Dict[str, Any], *, block: int, kv_dtype: str,
             "KV-handoff payload incompatible with this arena: "
             + "; ".join(problems)
             + " (prefill and decode replicas must share Model config, "
-            "PFX_KV_BLOCK, and kv_dtype)"
+            "page size, and kv_dtype)"
         )
 
 

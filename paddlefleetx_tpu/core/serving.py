@@ -93,8 +93,7 @@ class GenerationServer:
         # Generation.speculative: {draft_k, drafter, ngram, kv_dtype} —
         # draft_k > 0 routes the contiguous decode through the
         # speculative while-loop (greedy stays token-identical); kv_dtype
-        # int8 quantizes the donated cache pool (PFX_KV_DTYPE is the env
-        # spelling for benches; an explicit config value wins)
+        # int8 quantizes the donated cache pool (--kv-dtype overrides the key)
         spec_section = dict(gen_cfg.get("speculative", {}) or {})
         self.spec = spec_config_from(spec_section)
         self.kv_dtype = kv_cache_dtype(

@@ -40,12 +40,6 @@ class GPTConfig:
     # matmul outputs by name and recomputes only cheap elementwise ops)
     use_recompute: bool = False
     recompute_granularity: str = "full"
-    # comma-separated checkpoint names kept live under "selective"
-    # (qkv | attn_out | attn_lse | mlp_hidden); empty = measured-best default
-    recompute_names: str = ""
-    # fused LayerNorm Pallas kernel (ops/fused_layernorm.py) instead of the
-    # jnp composite (reference consumes paddle fused norm ops, vit.py:23-115)
-    use_fused_ln: bool = False
     # chunked softmax-CE (ops/chunked_ce.py): streams the vocab so the
     # [b,s,V] fp32 logits buffer never materializes — the HBM lever for
     # bigger per-chip batches.  Ignored under vocab (model-axis) sharding
@@ -57,18 +51,6 @@ class GPTConfig:
     fuse_attn_qkv: bool = True
     # attention implementation: "xla" (jnp reference) | "flash" (Pallas kernel)
     attn_impl: str = "xla"
-    # flash kernel tile size (0 = auto: PFX_FLASH_BLOCK env, else the
-    # measured-best ladder in ops/flash_attention._block_sizes)
-    flash_block: int = 0
-    # flash backward schedule: "" = auto (PFX_FLASH_BWD env, else "split");
-    # "fused" = single-kernel dq+dk+dv (computes each softmax tile once)
-    flash_bwd: str = ""
-    # unroll factor for the scan over layers (lax.scan unroll=N): trades
-    # compile time + code size for removing the scan-boundary stacking
-    # copies (docs/performance_tuning.md "Scan unroll"; not measured on
-    # today's code).
-    # 1 = rolled (default); must divide num_layers
-    scan_unroll: int = 1
     # ring attention inner K-block (attn_impl="ring"): bounds the per-ring-
     # step score buffer to [s_local, ring_chunk_k]; 0 = unchunked
     ring_chunk_k: int = 1024
@@ -280,29 +262,6 @@ class GPTConfig:
                              "set moe_gate: sigmoid (or softmax) for the dropless layer")
         if self.recompute_granularity not in ("full", "selective", "full_attn", "core_attn"):
             raise ValueError(f"bad recompute_granularity {self.recompute_granularity}")
-        raw = self.recompute_names
-        parts = raw if isinstance(raw, (list, tuple)) else str(raw).split(",")
-        names = tuple(str(n).strip() for n in parts if str(n).strip())
-        bad = set(names) - {"qkv", "attn_out", "attn_lse", "mlp_hidden"}
-        if bad:
-            raise ValueError(
-                f"bad recompute_names {sorted(bad)}; "
-                "valid: qkv, attn_out, attn_lse, mlp_hidden"
-            )
-        if names and self.recompute_granularity != "selective":
-            raise ValueError(
-                "recompute_names only applies to recompute_granularity='selective'"
-            )
-        if self.scan_unroll < 1 or self.num_layers % self.scan_unroll:
-            raise ValueError(
-                f"scan_unroll {self.scan_unroll} must be >=1 and divide "
-                f"num_layers {self.num_layers}"
-            )
-        if self.flash_bwd not in ("", "split", "fused"):
-            raise ValueError(
-                f"flash_bwd {self.flash_bwd!r}; valid: '' (auto), split, fused"
-            )
-        object.__setattr__(self, "recompute_names", ",".join(names))
 
     def _read_mup_multipliers(self) -> None:
         """``mup_multipliers`` as a sorted tuple of (name, float or tuple of
@@ -443,8 +402,8 @@ class GPTConfig:
 
     @property
     def kv_block_default(self) -> int:
-        """Tokens a page of the paged arena holds unless the operator says
-        otherwise (0 = the library's default): a latent page holds one
+        """Tokens a page of the paged arena holds unless a caller passes its
+        own ``block`` (0 = the library's default): a latent page holds one
         vector a token, so 128 of them make the page of a DMA's size that
         16 tokens of per-head keys make; so do 128 tokens of a few shared
         KV heads (4 or more query heads a KV head: 32/2 and 20/4 both)."""
@@ -528,11 +487,6 @@ class GPTConfig:
         scores gain its square."""
         scaled = kind != "W" and self.rope_scaling_factor > 1.0
         return scaled, (self.rope_yarn_m if scaled else 1.0)
-
-    @property
-    def recompute_name_tuple(self) -> Tuple[str, ...]:
-        """Normalized selective-remat save-set; empty = measured-best default."""
-        return tuple(n for n in self.recompute_names.split(",") if n)
 
     @staticmethod
     def from_config(model_cfg) -> "GPTConfig":

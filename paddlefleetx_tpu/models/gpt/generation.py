@@ -58,7 +58,7 @@ from paddlefleetx_tpu.ops.speculative import (
 class KVCache(NamedTuple):
     """Contiguous decode cache.  ``k``/``v`` are [layers, b, heads,
     max_len, head_dim] in the model dtype — or int8 under
-    PFX_KV_DTYPE=int8, in which case ``k_scale``/``v_scale`` [layers, b,
+    ``kv_dtype`` "int8", in which case ``k_scale``/``v_scale`` [layers, b,
     heads, max_len] carry the per-(slot, head) quantization scales
     written alongside every cache update (quantize-on-write,
     dequantize-in-kernel — ``ops/decode_attention``)."""
@@ -72,8 +72,8 @@ class KVCache(NamedTuple):
 def init_cache(
     cfg: GPTConfig, batch: int, max_len: int, dtype=None, kv_dtype: str = ""
 ) -> KVCache:
-    """``kv_dtype``: "" resolves PFX_KV_DTYPE (the serving path passes the
-    ``Generation.speculative.kv_dtype`` config value through); "bf16"
+    """``kv_dtype`` (the serving path passes ``--kv-dtype`` / the
+    ``Generation.speculative.kv_dtype`` config value through): "" or "bf16"
     keeps the cache in the model dtype, "int8" allocates the quantized
     pair plus its scale planes (HBM bytes per slot halve vs bf16).
 
@@ -1005,7 +1005,7 @@ class PagedPools(NamedTuple):
     (heads-major within a block, matching KVCache's tiling rationale).
     Block 0 is the NULL block — never allocated to a sequence; inactive
     batch rows route their writes there (core/paged_cache.py).  Under
-    PFX_KV_DTYPE=int8 the arrays are int8 and ``k_scale``/``v_scale``
+    ``kv_dtype`` "int8" the arrays are int8 and ``k_scale``/``v_scale``
     [layers, num_blocks, heads, block] carry per-(slot, head) scale
     tiles stored alongside the arena — each pool block owns its
     [heads, block] scale tile, DMA'd with it by the pallas kernel's
@@ -1374,7 +1374,7 @@ def _pattern_prefill_attention(q, k, v, cfg: GPTConfig, window: bool = False):
 
     kind = (".window" if window else ".full") if cfg.window_layers else ""
     with jax.named_scope("pfx.attn.gqa.prefill" + kind):
-        return attention(q, k, v, impl=cfg.attn_impl, causal=True, flash_block=cfg.flash_block,
+        return attention(q, k, v, impl=cfg.attn_impl, causal=True,
                          window=cfg.sliding_window if window else 0)
 
 

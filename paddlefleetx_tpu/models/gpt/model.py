@@ -265,8 +265,11 @@ def layer_norm(
     fused: bool = False, ctx: Optional[ShardingCtx] = None,
 ):
     """LayerNorm over the last dim of x [b, s, h].  ``fused`` selects the
-    Pallas kernel; under a mesh (``ctx``) the kernel is row-independent, so
-    it runs inside ``shard_map`` over the batch and seq axes."""
+    Pallas kernel (no model call site passes it: PR 45 measured it ahead on
+    the 345M cell, PERF.md section 6, and the PR that turns it on chooses it
+    here, from the block's norm); under a mesh (``ctx``) the kernel is
+    row-independent, so it runs inside ``shard_map`` over the batch and seq
+    axes."""
     if fused:
         from paddlefleetx_tpu.ops.fused_layernorm import fused_layer_norm
 
@@ -292,10 +295,10 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * scale).astype(x.dtype)
 
 
-def _norm(x: jax.Array, p: Dict[str, Any], cfg: GPTConfig, ctx: Optional[ShardingCtx]):
+def _norm(x: jax.Array, p: Dict[str, Any], cfg: GPTConfig):
     if cfg.norm == "rmsnorm":
         return rms_norm(x, p["scale"], cfg.norm_eps)
-    return layer_norm(x, p["scale"], p["bias"], eps=cfg.norm_eps, fused=cfg.use_fused_ln, ctx=ctx)
+    return layer_norm(x, p["scale"], p["bias"], eps=cfg.norm_eps)
 
 
 def rope(x: jax.Array, theta: float) -> jax.Array:
@@ -429,8 +432,7 @@ def latent_attention_expanded(p, q_nope, q_r, latent, cfg: GPTConfig, ctx=None) 
     flash = cfg.attn_impl == "flash" and d > v.shape[-1]
     if flash:
         v = jnp.pad(v, ((0, 0),) * 3 + ((0, d - v.shape[-1]),))
-    out = attention(q, k, v, impl=cfg.attn_impl, causal=True, flash_block=cfg.flash_block,
-                    flash_bwd=cfg.flash_bwd, ctx=ctx)
+    out = attention(q, k, v, impl=cfg.attn_impl, causal=True, ctx=ctx)
     return out[..., :cfg.v_head_dim] if flash else out
 
 
@@ -438,10 +440,10 @@ def _layer_remat(cfg: GPTConfig, fn):
     """Wrap a per-layer scan body in jax.checkpoint per recompute granularity.
 
     "full" saves only layer-boundary activations (reference recompute
-    single_model.py:320-405); "selective" additionally saves a tunable set
-    of named activations (default qkv + attn_out + attn_lse) so the
-    backward pass skips the expensive recomputes — the TPU-native middle
-    ground the reference lacks."""
+    single_model.py:320-405); "selective" additionally saves the named
+    activations qkv + attn_out + attn_lse so the backward pass skips the
+    expensive recomputes — the TPU-native middle ground the reference
+    lacks."""
     if not cfg.use_recompute:
         return fn
     if cfg.recompute_granularity == "full":
@@ -450,13 +452,7 @@ def _layer_remat(cfg: GPTConfig, fn):
         # The save-set trades HBM residency+traffic against recompute FLOPs;
         # qkv+attn_out+attn_lse measured fastest on v5e (saving mlp_hidden
         # costs 3GB of HBM round-trips per step for a 0.7ms matmul re-run)
-        names = cfg.recompute_name_tuple or ("qkv", "attn_out", "attn_lse")
-        if cfg.attn_impl == "flash" and "attn_out" in names and "attn_lse" not in names:
-            # on the flash path the attention residual is the kernel's lse,
-            # not the (primal) output — honor the user's "save attention"
-            # intent instead of silently saving nothing
-            names = names + ("attn_lse",)
-        policy = jax.checkpoint_policies.save_only_these_names(*names)
+        policy = jax.checkpoint_policies.save_only_these_names("qkv", "attn_out", "attn_lse")
         return jax.checkpoint(fn, policy=policy)
     return fn
 
@@ -517,8 +513,6 @@ def _attention_block(
             dropout_key=dk,
             dropout_rate=cfg.attention_probs_dropout_prob,
             train=train,
-            flash_block=cfg.flash_block,
-            flash_bwd=cfg.flash_bwd,
             ctx=ctx,
         )
 
@@ -569,9 +563,7 @@ def _decoder_layer(
     k_attn, k_mlp = (jax.random.split(key) if key is not None else (None, None))
 
     def attn_part(p, x, k):
-        y = layer_norm(
-            x, p["ln_1"]["scale"], p["ln_1"]["bias"], fused=cfg.use_fused_ln, ctx=ctx
-        )
+        y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
         y = _constrain(ctx, y, ("batch", "seq", "embed"))
         return _attention_block(p["attn"], y, cfg, ctx, k, train)
 
@@ -581,9 +573,7 @@ def _decoder_layer(
     x = x + attn_part(p, x, k_attn)
     x = _constrain(ctx, x, ("batch", "seq", "embed"))
 
-    y = layer_norm(
-        x, p["ln_2"]["scale"], p["ln_2"]["bias"], fused=cfg.use_fused_ln, ctx=ctx
-    )
+    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"])
     y, aux = _mlp_block(p["mlp"], y, cfg, ctx, k_mlp, train)
     x = x + y
     return _constrain(ctx, x, ("batch", "seq", "embed")), aux
@@ -611,8 +601,7 @@ def _block_attention(p, x, cfg: GPTConfig, ctx, window: int, rotate: bool) -> ja
     q = _constrain(ctx, q, ("batch", None, "heads", "kv"))
     with jax.named_scope("pfx.attn.window" if window else "pfx.attn.full"):
         out = attention(
-            q, k, v, impl=cfg.attn_impl, causal=True, flash_block=cfg.flash_block,
-            flash_bwd=cfg.flash_bwd, ctx=ctx, window=window,
+            q, k, v, impl=cfg.attn_impl, causal=True, ctx=ctx, window=window,
         )
     if cfg.attn_gate:
         out = out * jax.nn.sigmoid(proj("gate").astype(jnp.float32)).astype(dtype)
@@ -625,11 +614,11 @@ def _block_layer(p, x, cfg: GPTConfig, ctx, kind: Tuple[int, bool], expert_bias)
     an expert layer is one whose parameters hold a router.  Returns
     (x, the expert layer's load statistics or None)."""
     window, rotate = kind
-    y = _block_attention(p["attn"], _norm(x, p["ln_1"], cfg, ctx), cfg, ctx, window, rotate)
+    y = _block_attention(p["attn"], _norm(x, p["ln_1"], cfg), cfg, ctx, window, rotate)
     if cfg.post_norms:
-        y = _norm(y, p["post_attn_norm"], cfg, ctx)
+        y = _norm(y, p["post_attn_norm"], cfg)
     x = _constrain(ctx, x + y, ("batch", "seq", "embed"))
-    m = _norm(x, p["ln_2"], cfg, ctx)
+    m = _norm(x, p["ln_2"], cfg)
     from paddlefleetx_tpu.models.gpt.moe import dropless_moe_block, swiglu
 
     if "router_kernel" in p["mlp"]:
@@ -638,7 +627,7 @@ def _block_layer(p, x, cfg: GPTConfig, ctx, kind: Tuple[int, bool], expert_bias)
     else:
         f, stats = swiglu(m, p["mlp"]), None
     if cfg.post_norms:
-        f = _norm(f, p["post_mlp_norm"], cfg, ctx)
+        f = _norm(f, p["post_mlp_norm"], cfg)
     return _constrain(ctx, x + f, ("batch", "seq", "embed")), stats
 
 
@@ -750,7 +739,6 @@ def transformer_stack(
         body_fn,
         (x, jnp.zeros((), jnp.float32)),
         (layers_params, jnp.arange(cfg.num_layers)),
-        unroll=cfg.scan_unroll,
     )
     return x, aux
 
@@ -810,7 +798,7 @@ def forward_hidden(
         if cfg.moe_dropless and expert_bias is None:
             expert_bias = init_extra(cfg)["expert_bias"]
         x, aux = _block_stack(params, x, cfg, ctx, expert_bias)
-    x = _norm(x, params["final_ln"], cfg, ctx)
+    x = _norm(x, params["final_ln"], cfg)
     return _constrain(ctx, x, ("batch", "seq", "embed")), aux
 
 
@@ -954,10 +942,7 @@ def _pipeline_train_loss(
         return x_mb
 
     def head_fn(hparams, y_mb, mb, mbi):
-        y = layer_norm(
-            y_mb, hparams["final_ln"]["scale"], hparams["final_ln"]["bias"],
-            fused=cfg.use_fused_ln, ctx=ctx,
-        )
+        y = layer_norm(y_mb, hparams["final_ln"]["scale"], hparams["final_ln"]["bias"])
         y = _constrain(ctx, y, ("batch", "seq", "embed"))
         word = hparams["word"].astype(y.dtype)
         logits = jnp.einsum("bsh,vh->bsv", y, word)

@@ -72,8 +72,6 @@ def attention(
     dropout_rate: float = 0.0,
     train: bool = False,
     scale: Optional[float] = None,
-    flash_block: int = 0,
-    flash_bwd: str = "",
     ctx=None,
     window: int = 0,
 ) -> jax.Array:
@@ -83,8 +81,6 @@ def attention(
     k and v may carry fewer heads than q (a divisor: grouped-query
     attention, KV head h serving query heads g*h .. g*h+g-1).
 
-    ``flash_block`` / ``flash_bwd`` pass through to the Pallas kernels
-    (0/"" = auto); surfaced as ``Model.flash_block`` / ``Model.flash_bwd``.
     ``ctx`` (a model ``ShardingCtx``) is the mesh the call runs under: the
     flash kernel is independent per (batch, head), so under a mesh it runs
     inside ``shard_map`` over the batch and heads axes — GSPMD cannot
@@ -92,7 +88,7 @@ def attention(
     if impl == "flash" and bias is None and causal and scale is None:
         from paddlefleetx_tpu.ops.flash_attention import flash_attention, flash_supported
 
-        if not flash_supported(q.shape[1], flash_block):
+        if not flash_supported(q.shape[1]):
             # odd sequence lengths fall back to the XLA path (one warning)
             import warnings
 
@@ -105,10 +101,7 @@ def attention(
             # reference likewise disables dropout when flash is active,
             # hybrid_model.py:284-301)
             def kernel(q, k, v):
-                return flash_attention(
-                    q, k, v, causal=True, block=flash_block, bwd_schedule=flash_bwd,
-                    window=window,
-                )
+                return flash_attention(q, k, v, causal=True, window=window)
 
             if ctx is not None:
                 qkv = ("batch", None, "heads", "kv")

@@ -46,22 +46,19 @@ Cache layout is [batch, heads, max_len, head_dim] (heads-major) so the
 Pallas block tiling keeps (seq, head_dim) as the minor dims — see
 ``models/gpt/generation.KVCache``.
 
-Env knobs (PFX_FLASH_* loud-parse convention — an invalid value raises
-instead of silently mislabeling a chip sweep):
+The kv block is ``decode_block``'s (256, clamped to the cache; a caller's
+``block`` must be a positive multiple of 8).  The cache's storage dtype is
+``kv_cache_dtype``'s, carried by ``--kv-dtype`` and the
+``Generation.speculative.kv_dtype`` config key:
 
-  PFX_DECODE_BLOCK  kv block size (default 256; positive multiple of 8)
-  PFX_KV_DTYPE      "bf16" (default: the cache stays in the model dtype)
-                    | "int8" — int8 KV-cache quantization.  Quantize
-                    happens ON WRITE (generation-layer scatter paths,
-                    symmetric per-(slot, head) amax/127 scales stored
-                    alongside the cache/arena) and dequantize IN-KERNEL
-                    in every spelling here: the scores absorb the
-                    per-key scale (``s *= k_scale[col]``) and the
-                    probabilities absorb the per-value scale
-                    (``p *= v_scale[col]``) — no dequantized cache is
-                    ever materialized, so the decode step's HBM reads
-                    HALVE vs bf16 (which is exactly what the
-                    flash/paged kernels made the bottleneck)
+  "bf16" (default: the cache stays in the model dtype) | "int8".  Int8
+  quantizes ON WRITE (generation-layer scatter paths, symmetric
+  per-(slot, head) amax/127 scales stored alongside the cache/arena) and
+  dequantizes IN-KERNEL in every spelling here: the scores absorb the
+  per-key scale (``s *= k_scale[col]``) and the probabilities absorb the
+  per-value scale (``p *= v_scale[col]``) — no dequantized cache is ever
+  materialized, so the decode step's HBM reads HALVE vs bf16 (which is
+  exactly what the flash/paged kernels made the bottleneck)
 
 Inference-only: the blocked loop has a data-dependent trip count (a
 ``while_loop`` under the hood), so it is not reverse-differentiable.
@@ -71,7 +68,6 @@ Training attention stays on ``ops/attention.py`` / ``ops/flash_attention``.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -101,39 +97,21 @@ def kv_cache_len(slots: int, quantized: bool = False) -> int:
     return -(-int(slots) // align) * align
 
 
-def _parse_int_env(name: str) -> int:
-    env = os.environ.get(name) or "0"
-    try:
-        return int(env)
-    except ValueError:
-        raise ValueError(
-            f"{name}={env!r} is not an integer; pass a positive multiple "
-            f"of 8 (e.g. 256) or unset it"
-        ) from None
-
-
 def decode_block(max_len: int, block: int = 0) -> int:
     """Resolve the kv block size: explicit ``block`` arg, else
-    PFX_DECODE_BLOCK, else {_DEFAULT_BLOCK}; clamped to ``max_len``.
+    ``_DEFAULT_BLOCK``; clamped to ``max_len``.
 
     Unlike the flash block, the decode block need NOT divide the cache
     length — the last block is handled by a clamped start + dedup mask —
-    but it must be a positive multiple of 8 (TPU sublane tiling), and an
-    invalid override fails loudly in both spellings.  When the CLAMP
+    but it must be a positive multiple of 8 (TPU sublane tiling).  When the CLAMP
     breaks alignment (a cache shorter than the requested block and not
     itself a multiple of 8, e.g. max_len 20) the block rounds DOWN to the
     nearest multiple of 8; only a cache shorter than 8 slots yields a
     sub-8 block (lax spelling only — the pallas spelling refuses a cache
     that is not :func:`kv_cache_len`-aligned)."""
-    force = int(block) or _parse_int_env("PFX_DECODE_BLOCK")
-    if force:
-        if force < 0 or force % 8:
-            raise ValueError(
-                f"decode block {force} must be a positive multiple of 8 "
-                "(block arg / PFX_DECODE_BLOCK)"
-            )
-    else:
-        force = _DEFAULT_BLOCK
+    force = int(block) or _DEFAULT_BLOCK
+    if force < 0 or force % 8:
+        raise ValueError(f"decode block {force} must be a positive multiple of 8")
     clamped = min(force, max_len)
     if clamped % 8 and clamped > 8:
         clamped -= clamped % 8
@@ -144,18 +122,15 @@ KV_QMAX = 127.0
 
 
 def kv_cache_dtype(override: str = "") -> str:
-    """Resolve the KV-cache storage dtype: explicit ``override`` (the
-    ``Generation.speculative.kv_dtype`` config knob), else PFX_KV_DTYPE,
-    else "bf16".  "bf16" means NATIVE — the cache stays in the model
-    dtype (an f32 model keeps f32; the name follows the knob contract);
-    "int8" enables quantize-on-write + dequantize-in-kernel.  Loud
-    parse: a typo must not silently mislabel a chip A/B as quantized."""
-    raw = (override or os.environ.get("PFX_KV_DTYPE") or "bf16")
-    raw = str(raw).strip().lower()
+    """Resolve the KV-cache storage dtype: ``override`` (``--kv-dtype``,
+    the ``Generation.speculative.kv_dtype`` config key), else "bf16".
+    "bf16" means NATIVE — the cache stays in the model dtype (an f32
+    model keeps f32; the name follows the flag's); "int8" enables
+    quantize-on-write + dequantize-in-kernel.  A typo raises: it must
+    not pass for a quantized run."""
+    raw = str(override or "bf16").strip().lower()
     if raw not in ("bf16", "int8"):
-        raise ValueError(
-            f"PFX_KV_DTYPE={raw!r}; valid: bf16 (native), int8"
-        )
+        raise ValueError(f"kv dtype {raw!r}; valid: bf16 (native), int8")
     return raw
 
 
@@ -361,7 +336,7 @@ def _decode_pallas(q_t, k_cache, v_cache, limit, valid_from, block, scale,
             f"pallas decode attention needs cache length {max_len} and "
             f"block {block} to be multiples of {align} "
             f"({'int8' if k_scale is not None else 'native'} cache); "
-            "allocate with init_cache / kv_cache_len, fix PFX_DECODE_BLOCK, "
+            "allocate with init_cache / kv_cache_len, pass an aligned block, "
             "or pass impl='lax'"
         )
     limit_arr = jnp.full((1, 1), limit, jnp.int32)
@@ -439,7 +414,7 @@ def decode_attention(
     already written).  ``kv_valid_from`` [b] masks keys before a row's
     first real token (left-padded serving buckets).  Returns [b, t, n, d].
 
-    With an int8 cache (PFX_KV_DTYPE=int8), ``k_scale``/``v_scale``
+    With an int8 cache (``kv_cache_dtype`` "int8"), ``k_scale``/``v_scale``
     [b, n, max_len] carry the per-(slot, head) quantization scales and
     both spellings dequantize IN-KERNEL (scores absorb the key scale,
     probabilities the value scale) — pass both or neither.
@@ -860,7 +835,7 @@ def paged_decode_attention(
     ``limit`` and no ``kv_valid_from`` (paged rows are unpadded).
     Returns [b, t, n, d].
 
-    With int8 pools (PFX_KV_DTYPE=int8), ``k_scale``/``v_scale``
+    With int8 pools (``kv_cache_dtype`` "int8"), ``k_scale``/``v_scale``
     [num_blocks, n, block] ([layers, num_blocks, n, block] with ``layer``)
     carry the per-(slot, head) scales stored alongside the arena; both
     spellings dequantize in-kernel (the pallas
@@ -916,7 +891,7 @@ def paged_decode_attention(
         raise ValueError(
             f"paged block size {bs} is not a multiple of 8 (TPU sublane "
             "tiling); the pallas spelling cannot honor it — use "
-            "impl='lax' or a multiple-of-8 PFX_KV_BLOCK"
+            "impl='lax' or a multiple-of-8 page size"
         )
     scale = float(1.0 / (d**0.5))
     q_t = q.transpose(0, 2, 1, 3)  # [b, n, t, d]

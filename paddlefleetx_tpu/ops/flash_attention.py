@@ -22,7 +22,6 @@ exact) so the full test suite exercises the same code path on the CPU mesh.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -37,84 +36,43 @@ NEG_INF = -1e30
 
 
 def _block_sizes(seq: int, block: int = 0) -> Tuple[int, int]:
-    # 512x512 measured best on v5e at seq 1024 (8.7ms vs 10.8ms at 256x256
-    # and 16.2ms at 128x128 for b16/h16/d64 fwd+bwd): fewer grid programs
-    # amortize K/V HBM streaming; beats the stock jax.experimental Pallas
-    # flash (26.7ms) and splash (25.8ms) kernels at this shape. Seqs not
-    # divisible by 512 use the largest dividing block so e.g. seq 768 keeps
-    # flash support; small seqs run as one block (pre-existing behavior);
-    # anything else reports unsupported and attention() falls back to XLA.
-    # Model.flash_block (the ``block`` arg) or PFX_FLASH_BLOCK override the
-    # ladder for chip sweeps (the bf16-dot change moves the compute/stream
-    # balance, so the optimum may shift).  An invalid override fails LOUDLY
-    # in BOTH spellings: silently falling back (to the ladder or the XLA
-    # path) would spend a chip run on mislabeled data blamed on the wrong
-    # knob.
-    force = int(block) or _parse_block_env("PFX_FLASH_BLOCK")
-    if force:
-        _check_block(force, seq, "Model.flash_block / PFX_FLASH_BLOCK")
-        return force, _block_k_override(seq, force)
+    """The (q, kv) tile of a sequence length: the one place it is chosen.
+
+    512x512 measured best on v5e at seq 1024 (8.7ms vs 10.8ms at 256x256
+    and 16.2ms at 128x128 for b16/h16/d64 fwd+bwd): fewer grid programs
+    amortize K/V HBM streaming; beats the stock jax.experimental Pallas
+    flash (26.7ms) and splash (25.8ms) kernels at this shape.  Seqs not
+    divisible by 512 use the largest dividing rung so e.g. seq 768 keeps
+    flash support; small seqs run as one block; anything else reports
+    unsupported and attention() falls back to XLA.  ``block`` is a
+    caller's own tile (a test that wants several blocks of a short
+    sequence): an invalid one raises, it is never replaced."""
+    block = int(block)
+    if block:
+        _check_block(block, seq)
+        return block, block
     for b in (512, 256, 128):
         if seq % b == 0:
-            return b, _block_k_override(seq, b)
+            return b, b
     if seq < 256 and seq % 8 == 0:
         # single-block path needs sublane alignment too: a non-multiple-
         # of-8 seq would die in Mosaic lowering, so it falls through to
         # the unsupported return below and attention() uses XLA instead
-        return seq, _block_k_override(seq, seq)
-    # unsupported-seq fallback: still parse + validate a set block_k
-    # override FIRST so a set-but-invalid PFX_FLASH_BLOCK_K fails loudly
-    # on this path too (a seq that misses the ladder, e.g. 1000, must not
-    # silently drop the knob and mislabel a sweep); a VALID override is
-    # then ignored along with the rest of the ladder — the XLA fallback
-    # has no blocks to apply it to
-    bk = _parse_block_env("PFX_FLASH_BLOCK_K")
-    if bk:
-        _check_block(bk, seq, "block_k; PFX_FLASH_BLOCK_K")
+        return seq, seq
     return 256, 256  # does not divide seq -> flash_supported() False
 
 
-def _parse_block_env(name: str) -> int:
-    env = os.environ.get(name) or "0"
-    try:
-        return int(env)
-    except ValueError:
-        raise ValueError(
-            f"{name}={env!r} is not an integer; pass a positive divisor "
-            f"of seq (e.g. 256) or unset it"
-        ) from None
-
-
-def _check_block(val: int, seq: int, label: str) -> None:
+def _check_block(val: int, seq: int) -> None:
     if val < 0 or seq % val:
         raise ValueError(
-            f"flash block {val} must be a positive divisor of seq "
-            f"{seq} ({label})"
+            f"flash block {val} must be a positive divisor of seq {seq}"
         )
     if val % 8:
         # sublane alignment: a non-multiple-of-8 tile would surface as
         # an opaque Mosaic lowering error deep in the compile
         raise ValueError(
-            f"flash block {val} must be a multiple of 8 (TPU "
-            f"sublane tiling; {label})"
+            f"flash block {val} must be a multiple of 8 (TPU sublane tiling)"
         )
-
-
-def _block_k_override(seq: int, default_bk: int) -> int:
-    """PFX_FLASH_BLOCK_K: sweep knob for an asymmetric K/V block.
-
-    The kernels are already parameterized by block_q/block_k separately
-    (causal bounds use ceil/floor divisions that hold for bq != bk); a
-    larger K block amortizes K/V HBM streaming without growing the q
-    tile's VMEM accumulator.  Same loud-failure contract as the q block
-    (shared _check_block): an invalid override must not silently
-    mislabel a chip sweep — including on the small-seq single-block
-    path, where a stale exported override would otherwise be dropped."""
-    bk = _parse_block_env("PFX_FLASH_BLOCK_K")
-    if not bk:
-        return default_bk
-    _check_block(bk, seq, "block_k; PFX_FLASH_BLOCK_K")
-    return bk
 
 
 def _visible(row_ids, col_ids, window):
@@ -260,7 +218,10 @@ def _flash_fwd(q, k, v, scale, block, window=0, group=1):
 # ---------------------------------------------------------------------------
 # Backward
 #
-# Two schedules, selected by PFX_FLASH_BWD (read at trace time):
+# Two schedules (``flash_attention(bwd_schedule=)``; every caller but the
+# tests runs ``split``.  PR 45 measured ``fused`` ahead on the 345M cell,
+# PERF.md section 6: the PR that turns it on chooses it here, from
+# ``window == 0 and group == 1``, and says what it claims):
 #   split (default): FlashAttention-2 style — a dq kernel swept over kv
 #     blocks and a dk/dv kernel swept over q blocks.  Each (i, j) tile
 #     computes s = q@k^T and p = exp(s - lse) TWICE (once per kernel).
@@ -461,7 +422,7 @@ def _flash_bwd(scale, block, bwd_mode, window, group, res, g):
         if window or group > 1:
             raise NotImplementedError(
                 "the fused flash backward knows neither a window nor shared KV "
-                "heads; use flash_bwd: split")
+                "heads; use bwd_schedule='split'")
         return _flash_bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k)
 
     dq = pl.pallas_call(
@@ -551,14 +512,6 @@ def _flash_bhsd_fwd(q, k, v, scale, block, bwd_mode, window=0, group=1):
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bwd)
 
 
-def _resolve_bwd_schedule(bwd_schedule) -> str:
-    mode = bwd_schedule or os.environ.get("PFX_FLASH_BWD", "split")
-    if mode not in ("split", "fused"):
-        # a typo must not silently A/B split-vs-split on a chip window
-        raise ValueError(f"flash bwd schedule {mode!r}; valid: split, fused")
-    return mode
-
-
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -566,7 +519,7 @@ def flash_attention(
     *,
     causal: bool = True,
     block: int = 0,
-    bwd_schedule: str = "",
+    bwd_schedule: str = "split",
     window: int = 0,
 ):
     """q: [batch, seq, heads, head_dim]; k, v: the same, or with fewer heads
@@ -574,10 +527,10 @@ def flash_attention(
     -> [batch, seq, heads, head_dim].  ``window`` > 0: position i sees
     positions i-window+1 .. i only.
 
-    ``block`` (0 = auto: PFX_FLASH_BLOCK env, else the measured-best
-    ladder) and ``bwd_schedule`` ("" = auto: PFX_FLASH_BWD env, else
-    "split") surface as ``Model.flash_block`` / ``Model.flash_bwd`` —
-    product knobs, not just bench sweeps."""
+    ``block`` (0 = the ladder of ``_block_sizes``): a caller's own square
+    tile, for a test that wants several blocks of a short sequence.
+    ``bwd_schedule``: "split", or "fused" for the tests and the chip smoke
+    that hold the single-kernel backward to it (see "Backward" above)."""
     if not causal:
         raise NotImplementedError("only causal flash attention")
     b, s, n, d = q.shape
@@ -592,20 +545,18 @@ def flash_attention(
             "pad the sequence or use attn_impl='xla'"
         )
     scale = float(1.0 / (d**0.5))
-    mode = _resolve_bwd_schedule(bwd_schedule)
+    if bwd_schedule not in ("split", "fused"):
+        raise ValueError(f"flash bwd schedule {bwd_schedule!r}; valid: split, fused")
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, d)
 
-    out = _flash_bhsd(to_bh(q), to_bh(k), to_bh(v), scale, (bq, bk), mode,
+    out = _flash_bhsd(to_bh(q), to_bh(k), to_bh(v), scale, (bq, bk), bwd_schedule,
                       window, n // n_kv)
     return out.reshape(b, n, s, d).transpose(0, 2, 1, 3)
 
 
-def flash_supported(seq: int, block: int = 0) -> bool:
-    """True when the kernel's block tiling divides ``seq`` (dispatch helper).
-
-    With an explicit ``block`` this raises (loudly) on invalid values
-    rather than reporting unsupported — see _block_sizes."""
-    bq, bk = _block_sizes(seq, block)
+def flash_supported(seq: int) -> bool:
+    """True when ``_block_sizes``' tiling divides ``seq`` (dispatch helper)."""
+    bq, bk = _block_sizes(seq)
     return seq % bq == 0 and seq % bk == 0

@@ -11,13 +11,10 @@ when a prefix of top-k tokens already covers p) is the DEFAULT fast path:
 ``lax.top_k`` over a fixed candidate count (64, the CUDA kernel's max
 beam), exact whenever every row's nucleus fits the candidates, with a
 ``lax.cond``-guarded fallback to the full sort when one overflows — see
-:func:`sample_top_p_topk`.  PFX_TOPP_K overrides the candidate count
-(0 disables the fast path); invalid values fail loudly at trace time.
+:func:`sample_top_p_topk`.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -76,23 +73,6 @@ def sample_top_p(
     u = jax.random.uniform(key, (b, 1)) * total
     idx_sorted = jnp.argmax(jnp.cumsum(trunc, axis=-1) >= u, axis=-1)
     return jnp.take_along_axis(order, idx_sorted[:, None], axis=-1)[:, 0]
-
-
-def _parse_prefilter_env() -> int:
-    env = os.environ.get("PFX_TOPP_K") or ""
-    if not env:
-        return -1
-    try:
-        val = int(env)
-    except ValueError:
-        raise ValueError(
-            f"PFX_TOPP_K={env!r} is not an integer; pass a positive "
-            "candidate count (e.g. 64), 0 to disable the fast path, or "
-            "unset it"
-        ) from None
-    if val < 0:
-        raise ValueError(f"PFX_TOPP_K={val} must be >= 0")
-    return val
 
 
 def sample_top_p_topk(
@@ -175,8 +155,8 @@ def sample_logits(
     temperature -> top-k -> top-p -> categorical.
 
     The top-p stage goes through the top-k-prefilter fast path
-    (:func:`sample_top_p_topk`, ``top_p_prefilter_k`` candidates —
-    PFX_TOPP_K overrides, 0 disables) so the per-step cost is a top-k
+    (:func:`sample_top_p_topk`, ``top_p_prefilter_k`` candidates, 0 for
+    the full sort alone) so the per-step cost is a top-k
     over the vocab instead of a full argsort+cumsum; the full sort runs
     only when some row's nucleus overflows the prefilter.
 
@@ -210,9 +190,7 @@ def sample_logits(
     if top_p < 1.0:
         probs = jax.nn.softmax(logits, axis=-1)
         top_ps = jnp.full((logits.shape[0],), top_p)
-        env_k = _parse_prefilter_env()
-        k = top_p_prefilter_k if env_k < 0 else env_k
-        if k <= 0:
+        if top_p_prefilter_k <= 0:
             return sample_top_p(key, probs, top_ps)
-        return sample_top_p_topk(key, probs, top_ps, k=k)
+        return sample_top_p_topk(key, probs, top_ps, k=top_p_prefilter_k)
     return jax.random.categorical(key, logits, axis=-1)

@@ -275,7 +275,7 @@ def test_generate_345m_compiles(topo):
 def test_paged_prefill_and_step_345m_compile(topo, kv_dtype):
     """The continuous scheduler's two programs: prefill-on-admit
     (``paged_prefill``) and the decode step (``paged_forward_step``), at
-    the default PFX_KV_BLOCK of 16."""
+    the library's page of 16 slots."""
     from paddlefleetx_tpu.models.gpt.generation import (
         init_paged_pools,
         paged_forward_step,
@@ -347,8 +347,7 @@ def test_sharded_flash_and_fused_ln_compile(topo, degrees):
     def loss(x, w_qkv, scale, bias):
         y = layer_norm(x, scale, bias, fused=True, ctx=ctx)
         qkv = jnp.einsum("bsh,htnd->bstnd", y, w_qkv)
-        out = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
-                        impl="flash", flash_block=512, flash_bwd="fused", ctx=ctx)
+        out = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], impl="flash", ctx=ctx)
         return jnp.sum(out.astype(jnp.float32))
 
     c = _compile(jax.grad(loss, (0, 1, 2, 3)), x, w_qkv, w_ln, w_ln)

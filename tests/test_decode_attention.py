@@ -10,8 +10,7 @@ Covers the ISSUE decode-overhaul acceptance criteria:
     sample_top_p under fixed keys, incl. the nucleus-overflow fallback,
     and a jaxpr assertion that the fast branch has no full-vocab sort;
   - the early-exit while_loop pads after EOS; the donated cache;
-  - knob hygiene: PFX_DECODE_BLOCK / PFX_TOPP_K fail loudly on invalid
-    values.
+  - a caller's decode block that is no multiple of 8 fails loudly.
 """
 
 import functools
@@ -167,7 +166,7 @@ def test_blocks_visited_formula():
     assert int(ns) == 3
 
 
-def test_decode_block_knob_loud(monkeypatch):
+def test_decode_block_loud():
     assert decode_block(1024) == 256
     # clamping to a short cache must keep the multiple-of-8 tiling
     # invariant (round down), not hand Mosaic an unaligned block
@@ -178,15 +177,10 @@ def test_decode_block_knob_loud(monkeypatch):
     # only a degenerate sub-8 cache yields a sub-8 block (lax-only path)
     assert decode_block(5) == 5
     assert decode_block(1024, block=128) == 128
-    monkeypatch.setenv("PFX_DECODE_BLOCK", "64")
-    assert decode_block(1024) == 64
-    monkeypatch.setenv("PFX_DECODE_BLOCK", "twelve")
-    with pytest.raises(ValueError, match="PFX_DECODE_BLOCK"):
-        decode_block(1024)
-    monkeypatch.setenv("PFX_DECODE_BLOCK", "100")  # not a multiple of 8
-    with pytest.raises(ValueError, match="multiple of 8"):
-        decode_block(1024)
-    monkeypatch.delenv("PFX_DECODE_BLOCK")
+    assert decode_block(1024, block=64) == 64
+    for bad in (100, -8):  # not a multiple of 8; not positive
+        with pytest.raises(ValueError, match="multiple of 8"):
+            decode_block(1024, block=bad)
     with pytest.raises(ValueError, match="impl"):
         decode_attention(
             jnp.zeros((1, 1, 1, 8)), jnp.zeros((1, 1, 8, 8)),
@@ -360,19 +354,12 @@ def test_fast_path_has_no_full_vocab_sort():
     assert not top_level
 
 
-def test_topp_k_env_knob(monkeypatch):
+def test_topp_prefilter_off_draws_the_same_tokens():
     key = jax.random.key(0)
     logits = jnp.asarray(np.random.default_rng(2).normal(size=(4, 128)), jnp.float32)
     base = np.asarray(sample_logits(key, logits, top_p=0.9))
-    monkeypatch.setenv("PFX_TOPP_K", "0")  # disable fast path -> full sort
-    full = np.asarray(sample_logits(key, logits, top_p=0.9))
+    full = np.asarray(sample_logits(key, logits, top_p=0.9, top_p_prefilter_k=0))  # the full sort alone
     np.testing.assert_array_equal(base, full)
-    monkeypatch.setenv("PFX_TOPP_K", "not-an-int")
-    with pytest.raises(ValueError, match="PFX_TOPP_K"):
-        sample_logits(key, logits, top_p=0.9)
-    monkeypatch.setenv("PFX_TOPP_K", "-3")
-    with pytest.raises(ValueError, match="PFX_TOPP_K"):
-        sample_logits(key, logits, top_p=0.9)
 
 
 # ---------------------------------------------------------------------------

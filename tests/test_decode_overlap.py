@@ -526,42 +526,39 @@ def test_commit_crash_of_a_step_an_admission_was_queued_behind(
 def test_a_failed_admission_behind_a_step_commits_it_before_siblings_leave(
     server, monkeypatch
 ):
-    """An entry of two rows, the first decoding in the step in flight, the
-    second seated a quantum later behind that step, and its admission
-    fails on the host (``gen_crash``): the sibling leaves only after the
-    step it is in was committed, so that commit cannot write a row that
-    is gone, and the next request decodes token-identically."""
-    from paddlefleetx_tpu.core.continuous_batching import (
-        ContinuousScheduler,
-        PagedDecodeEngine,
-    )
+    """Two rows decode and a step is in flight; an entry of two rows
+    arrives: its first row is seated behind that step, its second row's
+    admission fails on the host (``gen_crash``).  The step is committed
+    before the sibling leaves (row membership changes only after a flush),
+    the rows it held decode on token-identically, and so does the next
+    request."""
     from paddlefleetx_tpu.utils import resilience
 
-    eng = PagedDecodeEngine(server, max_batch=2)
-    sched = ContinuousScheduler(eng, max_depth=8, dispatch_ahead=True, quantum=4)
+    sched = _hand_sched(server, True)
+    eng = sched.engine
     events = _watch(sched)
-    # every slot but one holds a short row; the pair's first row takes the
-    # last, its second waits for a short row to finish
-    shorts = [sched.submit([PROMPTS[0]], 2, deadline_s=120)
-              for _ in range(eng.capacity - 1)]
+    futs = _two_rows_in_flight(sched)
+    assert eng.free_slots() >= 2
+    del events[:]
     pair = sched.submit([PROMPTS[1], PROMPTS[2]], 30, deadline_s=120)
     resilience.reset_fault_state()
-    monkeypatch.setenv("PFX_FAULT", f"gen_crash:{eng.capacity + 1}")
+    monkeypatch.setenv("PFX_FAULT", "gen_crash:4")  # the pair's second row
     try:
-        _run(sched, shorts + [pair])
+        sched._iterate()
     finally:
         monkeypatch.delenv("PFX_FAULT")
         resilience.reset_fault_state()
-    ref = server.generate_ids([PROMPTS[0]], max_dec_len=2)[0]
-    assert [f.result(timeout=10)[0] for f in shorts] == [ref] * len(shorts)
+    # the sibling went in behind the step; the failed row's turn found the
+    # step still in flight and committed it; then the batch stepped on
+    # (the fault fires in front of ``eng.admit``, so no second admit event)
+    assert events == [("admit", True), ("flush", True), ("step", False)], events
     with pytest.raises(RuntimeError, match="gen_crash"):
         pair.result(timeout=10)
-    # the failed row's turn found the step in flight with its sibling in it
-    # and committed it; nothing of the entry is left on the engine
-    # (the fault fires in front of ``eng.admit``, so no admit event)
-    assert events[-1] == ("flush", True), events
-    assert events.count(("flush", True)) == 1
-    assert not eng.has_inflight and not eng.active.any()
+    assert eng.active_rows() == 2  # nothing of the entry is left on the engine
+    assert _finish(sched, futs) == [
+        server.generate_ids([PROMPTS[i]], max_dec_len=12)[0] for i in range(2)]
+    eng.flush()  # the hand-driven run stops at the last future, a step of dead rows behind it
+    assert not eng.active.any()
     assert all(r is None for r in eng.slots)
     assert eng.cache.stats()["kv_blocks_used"] == 0
     again = sched.submit([PROMPTS[1]], 6, deadline_s=120)

@@ -353,11 +353,9 @@ def test_the_scheduler_serves_the_reference_s_greedy_tokens(server):
         assert sched.shutdown(timeout=30)
 
 
-def test_the_model_gives_the_page_size_unless_the_operator_does(server, monkeypatch):
-    monkeypatch.delenv("PFX_KV_BLOCK", raising=False)
+def test_the_model_gives_the_page_size_unless_the_caller_does(server):
     assert _engine(server, block=0).block == 128  # GPTConfig.kv_block_default
-    monkeypatch.setenv("PFX_KV_BLOCK", "32")
-    assert _engine(server, block=0).block == 32
+    assert _engine(server, block=32).block == 32
 
 
 @pytest.mark.parametrize("named,build", [

@@ -159,3 +159,13 @@ def test_loader_workers_import_no_backend():
                          text=True, cwd=REPO, env=env, timeout=120)
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.strip() == "[]"  # no backend was initialized
+
+
+def test_device_and_version_utils():
+    from paddlefleetx_tpu.utils import device, version
+
+    assert device.get_device_type() == "cpu"  # the suite's pin
+    assert device.device_count() >= 1
+    device.synchronize()  # must not raise
+    assert isinstance(device.memory_stats(), dict)
+    assert "paddlefleetx-tpu" in version.show()

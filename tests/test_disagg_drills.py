@@ -66,7 +66,7 @@ TINY = {
 }
 
 # a fleet-shared "system prompt" two requests share: 34 tokens = 2 full
-# KV blocks (PFX_KV_BLOCK=16) + a 2-token overlap in the tail block, so
+# KV blocks (the library's 16 slots) + a 2-token overlap in the tail block, so
 # the second request exercises shared-block mapping AND the COW copy on
 # the prefill replica
 SYS = list(range(1, 35))

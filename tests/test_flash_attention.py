@@ -68,191 +68,78 @@ def test_bf16_runs():
     assert np.all(np.isfinite(np.asarray(out, np.float32)))
 
 
-def test_fused_bwd_matches_split(monkeypatch):
-    """PFX_FLASH_BWD=fused (single-kernel dq+dk+dv) must reproduce the
-    split two-kernel backward exactly up to f32 accumulation order.
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)])
+def test_fused_bwd_matches_split(dtype, tol):
+    """``bwd_schedule="fused"`` (single-kernel dq+dk+dv) must reproduce the
+    split two-kernel backward up to f32 accumulation order; in bfloat16,
+    the dtype the model path runs, up to a rounding a tile (with fp32
+    inputs the kernel-internal downcasts in ``_bwd_tile`` are no-ops).
 
     Block 64 at seq 256 gives 4 kv blocks, so the fused kernel's core
     mechanism — the dq slab zeroed at kj==0 and read-modify-written
     across kv-block grid steps — is actually exercised (a single-block
     grid would pass even with broken cross-block accumulation)."""
-    monkeypatch.setenv("PFX_FLASH_BLOCK", "64")
     b, s, n, d = 1, 256, 2, 32
-    key = jax.random.key(4)
-    kq, kk, kv, kg = jax.random.split(key, 4)
-    q = jax.random.normal(kq, (b, s, n, d), jnp.float32)
-    k = jax.random.normal(kk, (b, s, n, d), jnp.float32)
-    v = jax.random.normal(kv, (b, s, n, d), jnp.float32)
-    ct = jax.random.normal(kg, (b, s, n, d), jnp.float32)
+    kq, kk, kv, kg = jax.random.split(jax.random.key(4), 4)
+    q, k, v, ct = (jax.random.normal(key, (b, s, n, d), dtype) for key in (kq, kk, kv, kg))
 
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True) * ct)
+    def grads(bwd):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, causal=True, block=64, bwd_schedule=bwd)
+            return jnp.sum(out.astype(jnp.float32) * ct.astype(jnp.float32))
 
-    monkeypatch.setenv("PFX_FLASH_BWD", "split")
-    g_split = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("PFX_FLASH_BWD", "fused")
-    jax.clear_caches()  # the env knob is read at trace time
-    g_fused = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    jax.clear_caches()
-    for a, b_ in zip(g_split, g_fused):
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    for a, b_ in zip(grads("split"), grads("fused")):
         np.testing.assert_allclose(
-            np.asarray(b_), np.asarray(a), rtol=1e-5, atol=1e-5
-        )
+            np.asarray(b_, np.float32), np.asarray(a, np.float32), rtol=tol, atol=tol)
+    with pytest.raises(ValueError, match="schedule"):
+        flash_attention(q, k, v, bwd_schedule="fuse")
 
 
-def test_fused_bwd_matches_split_bf16(monkeypatch):
-    """Same fused-vs-split parity in bfloat16 — the dtype the model path
-    actually runs.  With fp32 inputs the kernel-internal bf16 downcasts
-    (p_lo/ds in _bwd_tile) are no-ops, so only a bf16 run can catch a
-    dtype-handling divergence between the two backward schedules."""
-    monkeypatch.setenv("PFX_FLASH_BLOCK", "64")
+def test_asymmetric_tiles_match_the_square_ones():
+    """bq != bk must give the same output and gradients as the square
+    tile: the causal bounds inside the forward, dq and dk/dv kernels use
+    ceil/floor divisions that have to hold for unequal blocks, in both
+    backward schedules (the kernels take the pair; ``_block_sizes`` hands
+    them a square one today)."""
+    from paddlefleetx_tpu.ops.flash_attention import _flash_bhsd
+
+    bh, s, d = 4, 256, 64
+    kq, kk, kv, kg = jax.random.split(jax.random.key(3), 4)
+    q, k, v, ct = (jax.random.normal(key, (bh, s, d), jnp.float32) for key in (kq, kk, kv, kg))
+    scale = float(1.0 / d**0.5)
+
+    def run(block, bwd="split"):
+        def loss(q, k, v):
+            return jnp.sum(_flash_bhsd(q, k, v, scale, block, bwd) * ct)
+
+        return (_flash_bhsd(q, k, v, scale, block, bwd),) + jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    square = run((64, 64))
+    for block, bwd in (((64, 128), "split"), ((128, 64), "split"), ((64, 128), "fused")):
+        for got, want in zip(run(block, bwd), square):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_bf16_grads_of_several_blocks_track_the_f32_ones():
+    """bfloat16, the dtype the model path runs, over 4 q and 4 kv blocks:
+    with fp32 inputs the kernel-internal downcasts (p_lo / ds in
+    ``_bwd_tile``) are no-ops, so only a bf16 run over several blocks can
+    catch a dtype slip in the carries between them."""
     b, s, n, d = 1, 256, 2, 32
-    key = jax.random.key(6)
-    kq, kk, kv, kg = jax.random.split(key, 4)
-    q = jax.random.normal(kq, (b, s, n, d), jnp.bfloat16)
-    k = jax.random.normal(kk, (b, s, n, d), jnp.bfloat16)
-    v = jax.random.normal(kv, (b, s, n, d), jnp.bfloat16)
-    ct = jax.random.normal(kg, (b, s, n, d), jnp.bfloat16)
+    kq, kk, kv, kg = jax.random.split(jax.random.key(6), 4)
+    q, k, v, ct = (jax.random.normal(key, (b, s, n, d), jnp.float32) for key in (kq, kk, kv, kg))
 
-    def loss(q, k, v):
-        return jnp.sum(
-            flash_attention(q, k, v, causal=True).astype(jnp.float32)
-            * ct.astype(jnp.float32)
-        )
+    def grads(dtype):
+        def loss(q, k, v):
+            out = flash_attention(q.astype(dtype), k.astype(dtype), v.astype(dtype), block=64)
+            return jnp.sum(out.astype(jnp.float32) * ct)
 
-    monkeypatch.setenv("PFX_FLASH_BWD", "split")
-    jax.clear_caches()  # the env knob is read at trace time
-    g_split = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("PFX_FLASH_BWD", "fused")
-    jax.clear_caches()
-    g_fused = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    jax.clear_caches()
-    for a, b_ in zip(g_split, g_fused):
-        # bf16 grads: both schedules accumulate in f32 but round per-tile,
-        # so allow bf16-epsilon-scale slack (2^-8 relative)
-        np.testing.assert_allclose(
-            np.asarray(b_, np.float32), np.asarray(a, np.float32),
-            rtol=2e-2, atol=2e-2
-        )
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
 
-
-def test_flash_block_env_validation(monkeypatch):
-    """Invalid PFX_FLASH_BLOCK values fail loudly with labeled errors, not
-    an int() ValueError or an opaque Mosaic compile error (advisor r4)."""
-    import pytest
-
-    from paddlefleetx_tpu.ops.flash_attention import _block_sizes
-
-    monkeypatch.delenv("PFX_FLASH_BLOCK_K", raising=False)
-    monkeypatch.setenv("PFX_FLASH_BLOCK", "banana")
-    with pytest.raises(ValueError, match="PFX_FLASH_BLOCK"):
-        _block_sizes(256)
-    monkeypatch.setenv("PFX_FLASH_BLOCK", "4")  # divides 256, not mult of 8
-    with pytest.raises(ValueError, match="multiple of 8"):
-        _block_sizes(256)
-    monkeypatch.setenv("PFX_FLASH_BLOCK", "96")  # mult of 8, no divisor
-    with pytest.raises(ValueError, match="divisor"):
-        _block_sizes(256)
-    monkeypatch.setenv("PFX_FLASH_BLOCK", "64")
-    assert _block_sizes(256) == (64, 64)
-    # asymmetric K/V block: same loud-failure contract, bk-only override
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "banana")
-    with pytest.raises(ValueError, match="PFX_FLASH_BLOCK_K"):
-        _block_sizes(256)
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "96")
-    with pytest.raises(ValueError, match="block_k"):
-        _block_sizes(256)
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "128")
-    assert _block_sizes(256) == (64, 128)
-
-
-def test_asymmetric_block_k_matches_reference(monkeypatch):
-    """bq != bk (PFX_FLASH_BLOCK_K) must produce the same attention output
-    as the symmetric kernel — the causal bounds inside the kernels use
-    ceil/floor divisions that have to hold for unequal blocks."""
-    import jax
-
-    from paddlefleetx_tpu.ops.flash_attention import flash_attention
-
-    b, s, n, d = 2, 256, 2, 64
-    kq, kk, kv = jax.random.split(jax.random.key(3), 3)
-    q = jax.random.normal(kq, (b, s, n, d), jnp.float32)
-    k = jax.random.normal(kk, (b, s, n, d), jnp.float32)
-    v = jax.random.normal(kv, (b, s, n, d), jnp.float32)
-
-    monkeypatch.delenv("PFX_FLASH_BLOCK_K", raising=False)
-    ref = np.asarray(flash_attention(q, k, v, block=64))
-    jax.clear_caches()  # env knob is read at trace time
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "128")
-    asym = np.asarray(flash_attention(q, k, v, block=64))
-    jax.clear_caches()
-    np.testing.assert_allclose(asym, ref, rtol=1e-5, atol=1e-5)
-
-    # gradients too: both backward schedules consume block_k
-    def loss(mode):
-        monkeypatch.setenv("PFX_FLASH_BWD", mode)
-        jax.clear_caches()
-        out = jax.grad(
-            lambda qq: flash_attention(qq, k, v, block=64).astype(jnp.float32).sum()
-        )(q)
-        return np.asarray(out)
-
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "")
-    g_sym = loss("split")
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "128")
-    g_asym_split = loss("split")
-    g_asym_fused = loss("fused")
-    jax.clear_caches()
-    np.testing.assert_allclose(g_asym_split, g_sym, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(g_asym_fused, g_sym, rtol=1e-5, atol=1e-5)
-
-
-def test_config_knobs_reach_kernel(monkeypatch):
-    """Model.flash_block / Model.flash_bwd thread through the GPT model to
-    the kernel (loss parity with the defaults proves the plumbed kernel
-    actually ran with valid parameters)."""
-    from paddlefleetx_tpu.models.gpt import model as M
-    from paddlefleetx_tpu.models.gpt.config import GPTConfig
-
-    toks = jax.random.randint(jax.random.key(11), (2, 256), 0, 64)
-    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
-    losses = {}
-    for name, kw in {
-        "default": {},
-        "block64_fused": {"flash_block": 64, "flash_bwd": "fused"},
-        # asymmetric K block (PFX_FLASH_BLOCK_K) through the model path:
-        # config bq=64 + env bk=128 must hit the same loss
-        "block64_bk128": {"flash_block": 64, "_env_bk": "128"},
-    }.items():
-        env_bk = kw.pop("_env_bk", None)
-        if env_bk is not None:
-            # monkeypatch (not raw os.environ): a mid-loop assert must not
-            # leak PFX_FLASH_BLOCK_K into later tests in this process
-            monkeypatch.setenv("PFX_FLASH_BLOCK_K", env_bk)
-            jax.clear_caches()  # env knob is read at trace time
-        cfg = GPTConfig(
-            vocab_size=64, hidden_size=32, num_layers=2,
-            num_attention_heads=4, max_position_embeddings=256,
-            hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
-            dtype="float32", attn_impl="flash", **kw,
-        )
-        params = M.init(cfg, jax.random.key(0))
-        loss, grads = jax.value_and_grad(
-            lambda p: M.loss_fn(p, batch, cfg, train=True)
-        )(params)
-        assert np.isfinite(float(loss))
-        losses[name] = float(loss)
-        if env_bk is not None:
-            monkeypatch.delenv("PFX_FLASH_BLOCK_K")
-            jax.clear_caches()
-    np.testing.assert_allclose(
-        losses["block64_fused"], losses["default"], rtol=1e-5
-    )
-    np.testing.assert_allclose(
-        losses["block64_bk128"], losses["default"], rtol=1e-5
-    )
-    with pytest.raises(ValueError, match="flash_bwd"):
-        GPTConfig(num_layers=2, flash_bwd="fuse")
+    for got, want in zip(grads(jnp.bfloat16), grads(jnp.float32)):
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), rtol=0.0, atol=0.35)
 
 
 def test_bf16_accuracy_vs_f32_reference():
@@ -290,23 +177,3 @@ def test_bf16_accuracy_vs_f32_reference():
         np.testing.assert_allclose(
             np.asarray(b_, np.float32), np.asarray(a), rtol=0.0, atol=0.35
         )
-
-
-def test_block_k_override_loud_on_unsupported_seq(monkeypatch):
-    """ADVICE r5: a set-but-invalid PFX_FLASH_BLOCK_K must fail loudly on
-    EVERY path, including the unsupported-seq fallback (e.g. seq=1000
-    misses the ladder) — not be silently dropped with the ladder."""
-    from paddlefleetx_tpu.ops.flash_attention import _block_sizes, flash_supported
-
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "not-an-int")
-    with pytest.raises(ValueError, match="PFX_FLASH_BLOCK_K"):
-        _block_sizes(1000)
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "256")  # does not divide 1000
-    with pytest.raises(ValueError, match="divisor"):
-        flash_supported(1000)
-    # a VALID override on an unsupported seq is ignored with the rest of
-    # the ladder (the XLA fallback has no blocks to apply it to)
-    monkeypatch.setenv("PFX_FLASH_BLOCK_K", "8")  # divides 1000, mult of 8
-    assert not flash_supported(1000)
-    monkeypatch.delenv("PFX_FLASH_BLOCK_K")
-    assert not flash_supported(1000)

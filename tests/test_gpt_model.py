@@ -167,22 +167,3 @@ def test_selective_remat_parity():
         np.testing.assert_allclose(float(got[0]), float(ref[0]), rtol=1e-6)
         for a, b in zip(jax.tree.leaves(ref[1]), jax.tree.leaves(got[1])):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
-
-
-def test_fused_ln_model_parity():
-    """use_fused_ln swaps every LayerNorm for the Pallas kernel (interpret
-    mode off-TPU); forward must match the jnp composite."""
-    import dataclasses
-
-    params = gpt.init(TINY, jax.random.key(0))
-    batch = _batch(jax.random.key(1), TINY)
-    fused = dataclasses.replace(TINY, use_fused_ln=True)
-
-    ref = gpt.forward(params, batch["tokens"], TINY)
-    got = gpt.forward(params, batch["tokens"], fused)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
-
-    g_ref = jax.grad(lambda p: gpt.loss_fn(p, batch, TINY, train=False))(params)
-    g = jax.grad(lambda p: gpt.loss_fn(p, batch, fused, train=False))(params)
-    for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)

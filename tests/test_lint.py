@@ -285,3 +285,72 @@ def test_documented_path_drift_is_detected(tmp_path, monkeypatch):
     (tmp_path / "README.md").write_text("`tools/serve.py`\n")
     (tmp_path / "docs" / "a.md").write_text("`configs/<family>/x.yaml`\n")
     assert _lint.check_doc_paths() == []
+
+
+def test_an_environment_read_under_ops_or_models_is_a_finding(tmp_path, monkeypatch):
+    """E14 hermetically: ``os.environ`` / ``os.getenv`` (or their import
+    from ``os``) under paddlefleetx_tpu/ops/ and models/ are findings; the
+    same module under utils/ (where the platform pin lives) is not."""
+    import lint as _lint
+
+    src = ('"""k."""\nimport os\n\n'
+           'BLOCK = int(os.environ.get("SOME_BLOCK", "0"))\n'
+           'MODE = os.getenv("SOME_MODE")\n')
+    monkeypatch.setattr(_lint, "REPO", str(tmp_path))
+    try:
+        for sub, want in (("ops", 2), ("models/gpt", 2), ("utils", 0)):
+            d = tmp_path / "paddlefleetx_tpu" / sub
+            d.mkdir(parents=True)
+            (d / "k.py").write_text(src)
+            found = [f for f in check_file(str(d / "k.py")) if f[2] == "E14"]
+            assert len(found) == want, (sub, found)
+        k = tmp_path / "paddlefleetx_tpu" / "ops" / "k.py"
+        k.write_text('"""k."""\nfrom os import environ\n\nprint(environ)\n')
+        assert "E14" in {c for _, _, c, _ in check_file(str(k))}
+    finally:
+        _lint._declared_metrics = ...  # check_file cached the tmp repo's (no) METRICS table
+
+
+def test_nothing_under_ops_or_models_reads_the_environment():
+    """E14 on the real repo: a kernel's tile and schedule are chosen beside
+    the kernel, from shapes (PR 45's parent read nine names there)."""
+    import lint as _lint
+
+    found = [f for d in _lint._NO_ENV_DIRS
+             for p in _lint.iter_py_files([os.path.join(REPO, d)])
+             for f in check_file(p) if f[2] == "E14"]
+    assert found == []
+
+
+def test_a_removed_name_is_a_finding(tmp_path, monkeypatch):
+    """E15 hermetically: a removed environment name in a document, a removed
+    ``Model`` key in a recipe and its argument in source are findings;
+    the kernels that stay (``pfx_flash_bwd_dq``) and the functions whose
+    names only contain one (``_flash_bwd``) are not."""
+    import lint as _lint
+
+    (tmp_path / "docs").mkdir()
+    (tmp_path / "docs" / "k.md").write_text("set `PFX_FLASH_BWD=fused` or `PFX_FLASH_BLOCK_K`\n")
+    (tmp_path / "README.md").write_text("the kernels are `pfx_flash_bwd_dq` and `pfx_flash_bwd_dkv`\n")  # noqa: E10
+    (tmp_path / "configs" / "gpt").mkdir(parents=True)
+    (tmp_path / "configs" / "gpt" / "r.yaml").write_text("Model:\n  use_fused_ln: True\n")
+    (tmp_path / "tools").mkdir()
+    (tmp_path / "tools" / "t.py").write_text(
+        "def _flash_bwd(x):\n    return x\n\n\nattention(q, k, v, flash_bwd='fused')\n")
+    monkeypatch.setattr(_lint, "REPO", str(tmp_path))
+    found = {(os.path.relpath(p, tmp_path), n, msg.split("'")[1])
+             for p, n, code, msg in _lint.check_removed_names() if code == "E15"}
+    assert found == {
+        (os.path.join("docs", "k.md"), 1, "PFX_FLASH_BWD"),
+        (os.path.join("docs", "k.md"), 1, "PFX_FLASH_BLOCK_K"),
+        (os.path.join("configs", "gpt", "r.yaml"), 2, "use_fused_ln"),
+        (os.path.join("tools", "t.py"), 5, "flash_bwd"),
+    }
+
+
+def test_no_removed_name_on_the_real_repo():
+    """E15 on the real repo: source, recipes, documents and the Makefile name
+    nothing PR 45 removed."""
+    import lint as _lint
+
+    assert _lint.check_removed_names() == []

@@ -693,8 +693,7 @@ def test_what_a_block_with_row_state_cannot_take_yet_is_refused_by_name(server, 
         assert "row state" in str(err.value)
 
 
-def test_the_model_gives_the_page_size(server, monkeypatch):
-    monkeypatch.delenv("PFX_KV_BLOCK", raising=False)
+def test_the_model_gives_the_page_size(server):
     assert _engine(server, block=0).block != 128  # 4 query heads on 2 KV heads: the library's page
     wide = GPTConfig(**dict(TOY, num_attention_heads=16, attn_head_dim=2))
     assert wide.kv_block_default == 128 and GPTConfig(**TOY).kv_block_default == 0

@@ -87,20 +87,18 @@ def test_fragmentation_metric_and_defrag():
     assert a.alloc(3) == [1, 2, 3]
 
 
-def test_blocks_for_and_block_size_knob(monkeypatch):
+def test_blocks_for_and_block_size():
     assert blocks_for(0, 16) == 0
     assert blocks_for(1, 16) == 1
     assert blocks_for(16, 16) == 1
     assert blocks_for(17, 16) == 2
-    assert kv_block_size(32) == 32
-    monkeypatch.setenv("PFX_KV_BLOCK", "24")
-    assert kv_block_size() == 24
-    monkeypatch.setenv("PFX_KV_BLOCK", "12")
-    with pytest.raises(ValueError, match="multiple of 8"):
-        kv_block_size()
-    monkeypatch.setenv("PFX_KV_BLOCK", "lots")
-    with pytest.raises(ValueError, match="not an integer"):
-        kv_block_size()
+    # the caller's, else the model's own, else the library's 16
+    assert kv_block_size(32, default=128) == 32
+    assert kv_block_size(default=128) == 128
+    assert kv_block_size() == 16
+    for bad in (12, 4, -16):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            kv_block_size(bad)
 
 
 def test_manager_admit_release_tables():

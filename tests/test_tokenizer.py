@@ -151,3 +151,38 @@ def test_native_bpe_specials_fall_back(tok):
     ids = tok.encode("hello")
     assert tok.decode(ids) == "hello"
     assert tok.eos_token_id is not None
+
+
+# ---------------------------------------------------------------------------
+# ERNIE WordPiece tokenizer
+# ---------------------------------------------------------------------------
+
+
+def test_ernie_tokenizer_roundtrip(tmp_path):
+    from paddlefleetx_tpu.data.tokenizers.ernie_tokenizer import ErnieTokenizer
+
+    tok = ErnieTokenizer.from_tiny_corpus(["the quick brown fox jumps", "hello world"])
+    enc = tok.encode("the quick fox", "hello world", max_seq_len=16)
+    ids, types = enc["input_ids"], enc["token_type_ids"]
+    assert ids[0] == tok.cls_token_id and ids.count(tok.sep_token_id) == 2
+    assert len(ids) == len(types)
+    assert set(types) == {0, 1}
+    assert tok.decode(ids) == "the quick fox hello world"
+
+    # wordpiece splits unseen compounds into known pieces
+    pieces = tok.tokenize("foxworld")
+    assert all(p in tok.vocab for p in pieces) and len(pieces) > 1
+    assert tok.decode(tok.convert_tokens_to_ids(pieces)) == "foxworld"
+
+    # save/load
+    path = str(tmp_path / "vocab.txt")
+    tok.save(path)
+    tok2 = ErnieTokenizer.from_file(path)
+    assert tok2.encode("the quick fox")["input_ids"] == tok.encode("the quick fox")["input_ids"]
+
+    # punctuation is split into its own token (here OOV -> [UNK]); unknown
+    # words collapse to [UNK]
+    out = tok.tokenize("the fox, x9z!")
+    assert out[0] == "the" and out[1] == "fox"
+    assert len(out) == 5  # the, fox, ',', x9z, '!'
+    assert tok.unk_token in out

@@ -45,6 +45,19 @@ actually bite:
       file under those directories (`serve.py` for tools/serve.py).
       (Repo-level check: runs once per invocation, not per file.)
 
+  E14 a kernel's tile and schedule are chosen beside the kernel: no
+      `os.environ` / `os.getenv` under paddlefleetx_tpu/ops/ or
+      paddlefleetx_tpu/models/ (the platform pin lives in
+      utils/device.py).  A size or a code path there comes from static
+      shapes or from what the kernel observes in its input; a test that
+      wants another tile passes an argument.
+  E15 removed names stay removed: none of `REMOVED_NAMES` (the
+      environment names and `Model` keys PR 45 took out after measuring
+      what they selected, with the arguments only they fed; PERF.md
+      section 6) in
+      source, configs/, docs/, README.md or the Makefile.
+      (Repo-level check: runs once per invocation, not per file.)
+
 Suppress a finding with `# noqa` on the offending line.
 Usage: python tools/lint.py [paths...]   (default: the whole repo)
 """
@@ -187,6 +200,7 @@ def source_env_knobs():
                 isinstance(node, ast.Constant)
                 and isinstance(node.value, str)
                 and _ENV_KNOB_RE.match(node.value)
+                and node.value not in REMOVED_NAMES  # E15's own table
             ):
                 knobs.setdefault(node.value, (path, node.lineno))
     return knobs
@@ -321,6 +335,52 @@ def check_doc_paths(docs=None):
     return findings
 
 
+# E14: directories whose code may not read the environment
+_NO_ENV_DIRS = (os.path.join("paddlefleetx_tpu", "ops"), os.path.join("paddlefleetx_tpu", "models"))
+
+# E15: what PR 45 removed (each was a second code path or a size that no
+# recipe, workload or cell set; the chip pairs are in PERF.md section 6)
+REMOVED_NAMES = (
+    "PFX_FLASH_BWD", "PFX_FLASH_BLOCK", "PFX_FLASH_BLOCK_K", "PFX_DECODE_BLOCK",
+    "PFX_KV_DTYPE", "PFX_KV_BLOCK", "PFX_TOPP_K", "PFX_DISPATCH_AHEAD",
+    "PFX_SCHED_QUANTUM",
+    "flash_block", "flash_bwd", "use_fused_ln", "scan_unroll",
+    "recompute_names", "recompute_name_tuple",
+)
+_REMOVED_RE = re.compile(
+    r"(?<![A-Za-z0-9_])(%s)(?![A-Za-z0-9_])" % "|".join(REMOVED_NAMES))
+# the table above and its test's fixtures name them on purpose
+_REMOVED_EXEMPT = (os.path.join("tools", "lint.py"), os.path.join("tests", "test_lint.py"))
+
+
+def check_removed_names():
+    """E15 (repo-level, once per run): no file of the source tree, the
+    recipes or the documents names a removed thing."""
+    paths = list(iter_py_files(
+        [os.path.join(REPO, d) for d in DEFAULT_DIRS]
+        + [os.path.join(REPO, f) for f in DEFAULT_FILES + ["chip_smoke.py"]]))
+    paths += [os.path.join(REPO, d) for d in doc_files() + ["Makefile"]]
+    for root, _, files in os.walk(os.path.join(REPO, "configs")):
+        paths += [os.path.join(root, f) for f in sorted(files)]
+    findings = []
+    for path in paths:
+        if os.path.relpath(path, REPO) in _REMOVED_EXEMPT:
+            continue
+        try:
+            with open(path, errors="replace") as f:
+                lines = f.read().split("\n")
+        except OSError:
+            continue
+        for i, ln in enumerate(lines, 1):
+            for name in sorted(set(_REMOVED_RE.findall(ln))):
+                findings.append((
+                    path, i, "E15",
+                    f"'{name}' was removed (tools/lint.py REMOVED_NAMES): a "
+                    "tile or a schedule is chosen beside its kernel, from shapes",
+                ))
+    return findings
+
+
 def iter_py_files(paths):
     for p in paths:
         if os.path.isfile(p) and p.endswith(".py"):
@@ -404,6 +464,21 @@ def check_file(path):
     rel = os.path.relpath(path, REPO)
     if rel.startswith("paddlefleetx_tpu") and ast.get_docstring(tree) is None:
         add(1, "E9", "missing module docstring")
+
+    # E14: ops/ and models/ choose from shapes, never from the environment
+    if rel.startswith(_NO_ENV_DIRS):
+        for node in ast.walk(tree):
+            reads = (
+                isinstance(node, ast.Attribute) and node.attr in ("environ", "getenv")
+                and isinstance(node.value, ast.Name) and node.value.id == "os"
+            ) or (
+                isinstance(node, ast.ImportFrom) and node.module == "os"
+                and any(a.name in ("environ", "getenv") for a in node.names)
+            )
+            if reads:
+                add(node.lineno, "E14",
+                    "environment read under ops/ or models/: choose the tile or the "
+                    "path from static shapes beside the kernel (or take an argument)")
 
     # E2 unused imports (skip __init__.py: re-exports are the point)
     if os.path.basename(path) != "__init__.py":
@@ -540,11 +615,12 @@ def main(argv=None):
     for path in iter_py_files(paths):
         n_files += 1
         all_findings.extend(check_file(path))
-    # E11/E12/E13 are repo-level invariants (code <-> documents),
+    # E11/E12/E13/E15 are repo-level invariants (code <-> documents),
     # checked once per run rather than per file
     all_findings.extend(check_metrics_docs())
     all_findings.extend(check_env_knob_docs())
     all_findings.extend(check_doc_paths())
+    all_findings.extend(check_removed_names())
     for path, lineno, code, msg in sorted(all_findings):
         rel = os.path.relpath(path, REPO)
         print(f"{rel}:{lineno}: {code} {msg}")

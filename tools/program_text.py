@@ -23,7 +23,7 @@ of the Python frames that called it, which move with any edit above them.  A
 kernel's own change is therefore NOT seen here (its file's diff shows it);
 its operands, shapes, grid-independent attributes and everything XLA gets
 around it are.  A kernel's TILE sits in the masked body too: the flash tiles of
-the cells' sequence lengths are pinned by ``tests/test_flash_attention.py``
+the cells' sequence lengths are pinned by ``tests/test_flash_tiles.py``
 (``test_block_sizes_of_the_cells``).
 """
 

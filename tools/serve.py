@@ -2030,7 +2030,8 @@ def main(argv=None):
     ap.add_argument("--kv-blocks", type=int, default=0,
                     help="continuous scheduler: total KV arena blocks "
                     "(0 = auto: cb-batch full-context rows + null "
-                    "block); block size via PFX_KV_BLOCK")
+                    "block); the block size is the model's own "
+                    "(GPTConfig.kv_block_default), else 16")
     ap.add_argument("--prefix-cache-blocks", type=int, default=0,
                     help="continuous scheduler: shared-prefix KV cache "
                     "budget in arena blocks (finished rows publish their "
@@ -2046,8 +2047,8 @@ def main(argv=None):
                     "docs/serving.md 'KV lifecycle')")
     ap.add_argument("--prefill-chunk", type=int, default=0,
                     help="continuous scheduler: admit long prompts in "
-                    "chunks of this many tokens (multiple of "
-                    "PFX_KV_BLOCK), one chunk per scheduler iteration "
+                    "chunks of this many tokens (multiple of the "
+                    "KV block size), one chunk per scheduler iteration "
                     "interleaved with decode steps; 0 = monolithic "
                     "prefill")
     ap.add_argument("--draft-k", type=int, default=-1,
