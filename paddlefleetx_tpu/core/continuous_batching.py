@@ -458,8 +458,8 @@ class PagedDecodeEngine:
         # the live rows' context lengths, "grid_tokens" = the KV tokens
         # per head the paged kernel computed on: each LIVE slot's context
         # rounded up to the kernel's grid step — its grid visits the live
-        # rows only; the latent kernel's visits every slot, an empty one
-        # costing one step; grid steps past a row's context run nothing)
+        # rows only; grid steps past a row's context run nothing, and the
+        # latent kernel's work list has none of them)
         self.stats: Dict[str, Any] = {
             "traces": 0, "steps": 0, "prefills": 0,
             "spec_proposed": 0, "spec_accepted": 0,
@@ -1916,8 +1916,8 @@ class PagedDecodeEngine:
         # work counters: what this step needed (live rows, their context)
         # against what the paged kernel computed on: the slots live at
         # dispatch, which are the rows its grid visits, each up to its
-        # context in whole grid steps (the latent kernel's grid is every
-        # slot still, at the positions the step started from)
+        # context in whole grid steps (the latent kernel's work list holds
+        # just those steps, at the positions the step started from)
         self.stats["row_steps"] += n_act
         self.stats["slot_steps"] += self.capacity
         self.stats["kv_tokens"] += int(self.positions[was_active].sum())
@@ -1929,7 +1929,7 @@ class PagedDecodeEngine:
             self.stats["ssm_slot_steps"] += self.capacity * int(self.mcfg.ssm_layers)
         if self.mcfg.latent_attention:
             self.stats["grid_tokens"] += int(mla_tokens_computed(
-                positions - ncommit, self.block, fl["width_bucket"]).sum())
+                (positions - ncommit)[was_active], self.block, fl["width_bucket"]).sum())
         else:
             self.stats["grid_tokens"] += int(paged_tokens_computed(
                 (positions - ncommit)[was_active], fl["k"] + 1, self.block, fl["width_bucket"],

@@ -43,6 +43,7 @@ from paddlefleetx_tpu.ops.decode_attention import (
     latent_page_write,
     live_slots,
     mla_paged_decode_attention,
+    mla_work_list,
     paged_decode_attention,
     quantize_kv,
     window_view,
@@ -1237,7 +1238,9 @@ def _block_paged_forward_step(params, tokens, pools, block_tables, positions, ac
     ``positions`` -> (logits [B, 1, v] f32, pools, counts).  Latent
     attention in its ABSORBED form: the query goes into the latent space
     (``W_uk^T q_nope``), scores against the row's latent pages, and the
-    probabilities' sum over the latents comes back through ``W_uv``."""
+    probabilities' sum over the latents comes back through ``W_uv``.  A
+    row that is not ``active`` costs the attention kernel nothing: its grid is
+    the step's work list (:func:`mla_work_list`), made once for all layers."""
     if ctx is not None:
         raise ValueError("tensor parallelism: the described block is served on one "
                          "device (its pools and experts have no sharding rules yet)")
@@ -1252,6 +1255,8 @@ def _block_paged_forward_step(params, tokens, pools, block_tables, positions, ac
     pos, blk, off = _step_write_slots(block_tables, positions, active, pools.k.shape[4])
     kl = cfg.kv_lora_rank
     scale = latent_softmax_scale(cfg)
+    # once a step: every layer's kernel walks the same (live row, page group) pairs
+    work = mla_work_list(live_slots(active), pos, pools.k.shape[4], block_tables.shape[1])
 
     def layer_fn(p, x, pools, layer):
         def attend(attn, q_nope, q_r, latent):
@@ -1261,7 +1266,8 @@ def _block_paged_forward_step(params, tokens, pools, block_tables, positions, ac
             q = jnp.concatenate([q_lat, q_r[:, 0]], axis=-1)
             with jax.named_scope("pfx.attn.mla.decode"):
                 o_lat = mla_paged_decode_attention(
-                    q, pool, block_tables, pos, layer=layer, scale=scale, kv_lora=kl)
+                    q, pool, block_tables, pos, layer=layer, scale=scale, kv_lora=kl,
+                    work=work)
             with jax.named_scope("pfx.attn.mla.absorb"):
                 out = jnp.einsum("bnc,cnd->bnd", o_lat.astype(x.dtype), attn["v_b_kernel"])
             return out[:, None], PagedPools(pool)

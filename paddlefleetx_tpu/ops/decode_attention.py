@@ -452,18 +452,30 @@ def live_slots(active: jax.Array):
     """A decode step's ``active`` mask [slots] -> (the live slots' numbers
     first, ascending, [slots] int32, their count [1] int32): the list the
     serving kernels' grids and block addresses follow (``pfx_decode_paged``,
-    ``pfx_decode_window``, ``pfx_ssm_decode``), so a dead slot costs them no
-    grid step.  Every layer of a step shares one mask: the step makes this
-    ONCE and hands it to each layer's call."""
+    ``pfx_decode_window``, ``pfx_ssm_decode``, and ``pfx_decode_mla_paged``
+    through :func:`mla_work_list`), so a dead slot costs them no grid step.
+    Every layer of a step shares one mask: the step makes this ONCE and hands
+    it to each layer's call."""
     live, = jnp.nonzero(active, size=active.shape[0], fill_value=0)
     return live.astype(jnp.int32), jnp.sum(active, dtype=jnp.int32)[None]
 
 
+def _every_slot(b: int):
+    """The list :func:`live_slots` makes of a mask with every slot live: what
+    a caller without a mask sends through the same grid."""
+    return jax.lax.iota(jnp.int32, b), jnp.full((1,), b, jnp.int32)
+
+
 def _live_mask(live, b: int):
-    """What :func:`live_slots` made, back as the mask [b] it was made of."""
+    """What :func:`live_slots` made, back as the mask [b] it was made of: the
+    rows named among the list's first ``count`` entries (a list of any length:
+    :func:`mla_work_list`'s rows name a row once a group)."""
     slots, count = live
     at = jax.lax.iota(jnp.int32, b)
-    return jnp.any((slots[None, :] == at[:, None]) & (at[None, :] < count[0]), axis=1)
+    # one iota where the list is as long as the batch: the paged kernels' steps
+    # keep the program text their pins hold (tests/program_text.json)
+    nth = at if slots.shape[0] == b else jax.lax.iota(jnp.int32, slots.shape[0])
+    return jnp.any((slots[None, :] == at[:, None]) & (nth[None, :] < count[0]), axis=1)
 
 
 def _paged_lax(q_t, k_pool, v_pool, layer, tables, positions, scale,
@@ -903,7 +915,7 @@ def paged_decode_attention(
     seen = None if live is None else _live_mask(live, b)
     if use_pallas:
         if live is None:  # no mask: the identity list through the same grid
-            live = (jax.lax.iota(jnp.int32, b), jnp.full((1,), b, jnp.int32))
+            live = _every_slot(b)
         out = _paged_pallas(q_t, k_pool, v_pool, layer, block_tables,
                             positions, scale, live, k_scale, v_scale, t, starts)
     else:
@@ -960,6 +972,35 @@ def mla_pages_per_step(block: int, width: int) -> int:
     return max(1, min(MLA_STEP_TOKENS // block, width))
 
 
+def _mla_last_group(positions, block: int, width: int):
+    """The last group of :func:`mla_pages_per_step` pages that a row whose
+    query sits at slot ``positions`` reaches, never past the table (an array
+    or a scalar; the work list, the kernel and the scheduler's counter all
+    read a row's reach here)."""
+    pages = mla_pages_per_step(block, width)
+    return (positions.clip(0) // (pages * block)).clip(None, -(-width // pages) - 1)
+
+
+def mla_work_list(live, positions: jax.Array, block: int, width: int):
+    """The grid of ``pfx_decode_mla_paged`` for one decode step: from what
+    :func:`live_slots` made of the step's mask, the rows' ``positions`` [b]
+    and the table's ``width`` in pages of ``block`` slots -> (rows, groups,
+    each [b * groups a row] int32, their count [1] int32): one (row, page
+    group) pair for each group a LIVE row's context reaches, the rows in
+    ``live``'s order, a row's groups 0 .. :func:`_mla_last_group` one after
+    the other.  Made ONCE a step, before the layer stack, beside the live
+    list it is made of: every layer's call walks the same pairs."""
+    slots, count = live
+    b = slots.shape[0]
+    steps = -(-width // mla_pages_per_step(block, width))
+    listed = jax.lax.iota(jnp.int32, b) < count[0]
+    reach = jnp.where(listed, _mla_last_group(positions[slots], block, width) + 1, 0)
+    group = jax.lax.broadcasted_iota(jnp.int32, (b, steps), 1)
+    at, = jnp.nonzero((group < reach[:, None]).reshape(-1), size=b * steps, fill_value=0)
+    at = at.astype(jnp.int32)
+    return slots[at // steps], at % steps, jnp.sum(reach, dtype=jnp.int32)[None]
+
+
 def _mla_paged_lax(q, pool, layer, tables, positions, scale, kv_lora):
     """q [b, n, w] (absorbed query then rotated query, w = kv_lora + rope);
     pool [layers, nb, 1, w, bs]; tables [b, M]; positions [b] = the slot of
@@ -992,16 +1033,21 @@ def _mla_paged_lax(q, pool, layer, tables, positions, scale, kv_lora):
     return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
-def _mla_paged_kernel(layer_ref, tables_ref, pos_ref, q_ref, *refs, scale, bs, pages,
-                      width, kv_lora):
-    """One (row, page group) grid step, every head inside: ``refs`` =
-    ``pages`` latent pages [w, bs] (the index maps clamp past the row's
-    last page, as the per-head kernel's), then o_ref and acc / m / l."""
+def _mla_paged_kernel(layer_ref, tables_ref, pos_ref, rows_ref, groups_ref, q_ref, *refs,
+                      scale, bs, pages, width, kv_lora):
+    """Grid step ``k`` of the step's work list, every head inside: row
+    ``rows_ref[k]``, page group ``groups_ref[k]``.  The grid is as long as
+    the list's count, so a dead slot and a group past a row's context get no
+    grid step, no DMA and no write (a dead row's output is the caller's to
+    zero); a row's steps are consecutive, its accumulators set at its group
+    0 and its result written at its last group.  ``refs`` = ``pages`` latent
+    pages [w, bs] (the index maps clamp past the row's last page, as the
+    per-head kernel's), then o_ref and acc / m / l."""
     kv = refs[:pages]
     o_ref, acc_ref, m_ref, l_ref = refs[pages:]
-    i, j = pl.program_id(0), pl.program_id(1)
-    pos = pos_ref[i]
-    last = jnp.maximum(pos, 0) // bs
+    k = pl.program_id(0)
+    j = groups_ref[k]
+    pos = pos_ref[rows_ref[k]]
 
     @pl.when(j == 0)
     def _init():
@@ -1009,56 +1055,59 @@ def _mla_paged_kernel(layer_ref, tables_ref, pos_ref, q_ref, *refs, scale, bs, p
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j * pages <= last)
-    def _group():
-        q = q_ref[0]  # [n, w]
-        c = kv[0][...] if pages == 1 else jnp.concatenate([r[...] for r in kv], 1)
-        s = scale * jax.lax.dot_general(
-            q, c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )  # [n, w] x [w, pages * bs]
-        shape = (1, pages * bs)
-        col = j * (pages * bs) + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        mask = col <= pos
-        if width % pages:
-            mask = mask & (col < width * bs)  # spare pages of the last group
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(c.dtype), c[:kv_lora], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [n, T] x [kv_lora, T]^T
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    q = q_ref[0]  # [n, w]
+    c = kv[0][...] if pages == 1 else jnp.concatenate([r[...] for r in kv], 1)
+    s = scale * jax.lax.dot_general(
+        q, c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    )  # [n, w] x [w, pages * bs]
+    shape = (1, pages * bs)
+    col = j * (pages * bs) + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask = col <= pos
+    if width % pages:
+        mask = mask & (col < width * bs)  # spare pages of the last group
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = jnp.broadcast_to(
+        l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_ref.shape)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(c.dtype), c[:kv_lora], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)  # [n, T] x [kv_lora, T]^T
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == _mla_last_group(pos, bs, width))
     def _done():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def _mla_paged_pallas(q, pool, layer, tables, positions, scale, kv_lora):
+def _mla_paged_pallas(q, pool, layer, tables, positions, scale, kv_lora, work):
     from jax.experimental.pallas import tpu as pltpu
 
     b, n, w = q.shape
     bs = pool.shape[4]
     M = tables.shape[1]
     pages = mla_pages_per_step(bs, M)
+    rows, groups, count = work  # the grid: its bound is ``count``, step k is (rows[k], groups[k])
 
     def page_index(p):
-        def index(i, j, layer_ref, tables_ref, pos_ref):
+        def index(k, layer_ref, tables_ref, pos_ref, rows_ref, groups_ref):
+            i = rows_ref[k]
             last = jnp.maximum(pos_ref[i], 0) // bs
-            page = jnp.minimum(j * pages + p, jnp.minimum(last, M - 1))
+            page = jnp.minimum(groups_ref[k] * pages + p, jnp.minimum(last, M - 1))
             return (layer_ref[0], tables_ref[i, page], 0, 0, 0)
         return index
 
+    def row_index(k, _layer, _tables, _pos, rows_ref, _groups):
+        return (rows_ref[k], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, -(-M // pages)),
-        in_specs=[pl.BlockSpec((1, n, w), lambda i, j, *_: (i, 0, 0))] + [
+        num_scalar_prefetch=5,
+        grid=(count[0],),
+        in_specs=[pl.BlockSpec((1, n, w), row_index)] + [
             pl.BlockSpec((None, None, None, w, bs), page_index(p)) for p in range(pages)],
-        out_specs=pl.BlockSpec((1, n, kv_lora), lambda i, j, *_: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, n, kv_lora), row_index),
         scratch_shapes=[
             pltpu.VMEM((n, kv_lora), jnp.float32),
             pltpu.VMEM((n, 128), jnp.float32),
@@ -1073,7 +1122,7 @@ def _mla_paged_pallas(q, pool, layer, tables, positions, scale, kv_lora):
         out_shape=jax.ShapeDtypeStruct((b, n, kv_lora), jnp.float32),
         interpret=_device.pallas_interpret(),
         name="pfx_decode_mla_paged",
-    )(layer[None], tables.astype(jnp.int32), positions.astype(jnp.int32),
+    )(layer[None], tables.astype(jnp.int32), positions.astype(jnp.int32), rows, groups,
       q, *([pool] * pages))
 
 
@@ -1118,10 +1167,11 @@ def latent_page_write(pool: jax.Array, new: jax.Array, blk: jax.Array, off: jax.
 
 def mla_tokens_computed(positions, block: int, width: int):
     """Latent tokens the MLA kernel computes on for rows whose query sits
-    at slot ``positions``: each context rounded up to whole grid steps."""
-    pages = mla_pages_per_step(block, width)
-    steps = -(-width // pages)
-    return ((positions.clip(0) // block) // pages + 1).clip(None, steps) * (pages * block)
+    at slot ``positions``: each context rounded up to whole grid steps, which
+    is what :func:`mla_work_list` lists for a live row.  The scheduler's
+    ``pfx_sched_decode_grid_tokens_total`` sums this over a step's LIVE slots."""
+    return (_mla_last_group(positions, block, width) + 1) * (
+        mla_pages_per_step(block, width) * block)
 
 
 def mla_paged_decode_attention(
@@ -1134,6 +1184,7 @@ def mla_paged_decode_attention(
     scale: float,
     kv_lora: int,
     impl: str = "auto",
+    work=None,
 ) -> jax.Array:
     """Latent attention's decode step in its ABSORBED form.  q [b, n, w]:
     each head's query carried into the latent space (``W_uk^T q_nope``,
@@ -1143,10 +1194,21 @@ def mla_paged_decode_attention(
     row i sits at slot ``positions[i]`` (already written) and attends its
     slots [0, positions[i]].  Scores ``scale * q . page^T`` in float32,
     online softmax, -> [b, n, kv_lora] float32: sum_j p_j c_j, which the
-    caller expands through ``W_uv``.  ``impl`` as
-    :func:`paged_decode_attention`; the Pallas spelling
-    (``pfx_decode_mla_paged``) runs one grid step per (row,
-    :func:`mla_pages_per_step` pages)."""
+    caller expands through ``W_uv``.
+
+    ``work``: what :func:`mla_work_list` makes of the step's live list and
+    positions, from a decode step that has rows to skip.  Only the rows it
+    lists are attended: a row it leaves out is never read (its table may
+    point anywhere, its position may be stale) and its result is 0.  Without
+    it every row is live.
+
+    ``impl`` as :func:`paged_decode_attention`.  The Pallas spelling
+    (``pfx_decode_mla_paged``) runs one grid step per LISTED (row, group of
+    :func:`mla_pages_per_step` pages): the grid is one axis whose bound is
+    the list's count, and the query, the result, every page address and the
+    body read the row and the group from the list, so a dead slot and a
+    group past a row's context cost nothing.  The lax spelling gathers every
+    row's pages, dead rows at position 0; both spellings zero the dead rows."""
     if impl not in ("auto", "pallas", "lax"):
         raise ValueError(f"mla_paged_decode_attention impl {impl!r}; valid: auto, pallas, lax")
     if pool.ndim != 5 or pool.shape[2] != 1 or pool.shape[3] != q.shape[-1]:
@@ -1156,8 +1218,20 @@ def mla_paged_decode_attention(
     use_pallas = impl == "pallas" or (impl == "auto" and not _device.pallas_interpret())
     if use_pallas and bs % 8:
         raise ValueError(f"paged block size {bs} is not a multiple of 8; use impl='lax'")
-    fn = _mla_paged_pallas if use_pallas else _mla_paged_lax
-    return fn(q, pool, layer, block_tables, positions, float(scale), int(kv_lora))
+    b = q.shape[0]
+    seen = None if work is None else _live_mask((work[0], work[2]), b)
+    if use_pallas:
+        if work is None:  # no mask: the identity list through the same grid
+            work = mla_work_list(_every_slot(b), positions, bs, block_tables.shape[1])
+        out = _mla_paged_pallas(q, pool, layer, block_tables, positions, float(scale),
+                                int(kv_lora), work)
+    else:
+        if seen is not None:  # a dead row's stale position bounds no loop
+            positions = jnp.where(seen, positions, 0)
+        out = _mla_paged_lax(q, pool, layer, block_tables, positions, float(scale), int(kv_lora))
+    if seen is not None:  # a row that was not visited is 0, never what a buffer held
+        out = jnp.where(seen[:, None, None], out, 0.0)
+    return out
 
 
 def dense_cache_attention(

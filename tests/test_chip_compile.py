@@ -831,6 +831,54 @@ def test_serving_prefill_runs_its_grouped_products_in_the_kernel(topo, config, b
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < fits, m.temp_size_in_bytes
 
 
+@pytest.mark.parametrize("width", [16, 32, 64])
+def test_decode_step_of_the_latent_block_runs_its_kernel_over_the_work_list(topo, width):
+    """The benchmark cell ``serve-dsv3-1of32-think``'s decode step as its
+    configuration file states it (7 layers, 64 slots, 2,305 latent pages), the
+    pools DONATED, at each table width the cell's rows make (12-36 pages a
+    row: buckets 16, 32 and 64; the warm request makes 32, the engine's own
+    warm-up 64): the seven ``pfx_decode_mla_paged`` calls compile with ONE grid
+    axis whose bound is the work list's count (Mosaic takes the dynamic bound
+    and the prefetched rows and groups in every address), the latent arena
+    comes back aliased and is never copied."""
+    import json
+    import re
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    root = os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..")
+    bench = os.path.join(root, "pfx_bench")  # noqa: E10 — a directory, not a metric
+    with open(os.path.join(bench, "configs", "deepseek-v3.json")) as f:
+        cfg = GPTConfig(**json.load(f)["model"])
+    slots, blocks, vocab = 64, 2305, cfg.vocab_size
+    one = _one_chip(topo)
+    params = _shapes(one, jax.eval_shape(lambda: G.init_serving_params(cfg, jax.random.key(0))))
+    pools = _shapes(one, jax.eval_shape(
+        lambda: G.init_paged_pools(cfg, blocks, cfg.kv_block_default, slots=slots)))
+    gen = G.GenerationConfig(decode_strategy="greedy_search", max_dec_len=0, min_dec_len=1536,
+                             eos_token_id=0, pad_token_id=0)
+
+    def step(p, pools, tables, logits, counts, positions, gen_steps, max_news, active, forced):
+        rows = G.PagedRows(logits, counts, positions, gen_steps, max_news, active, forced)
+        nxt, pools, new = G.decode_step(p, pools, tables, rows, cfg, gen)
+        return nxt, pools, new.logits, new.counts, new.moe
+
+    i32 = lambda *shape: (shape, jnp.int32)  # noqa: E731
+    rows = _shapes(one, (i32(slots, width), ((slots, vocab), jnp.float32), i32(slots, vocab),
+                         i32(slots), i32(slots), i32(slots), ((slots,), jnp.bool_), i32(slots)))
+    c = jax.jit(step, donate_argnums=(1,)).lower(params, pools, *rows).compile()
+    text = c.as_text()
+    assert len(re.findall(r"%pfx_decode_mla_paged\S* = ", text)) == 7
+    assert len(re.findall(r"%pfx_mla_write\S* = ", text)) == 7
+    m = c.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert held == 7 * blocks * 576 * 128 * 2 and held <= m.alias_size_in_bytes < 1.01 * held
+    moved = re.findall(rf"= \w+\[7,{blocks},1,576,128\]\S* (copy|transpose)\(", text)
+    assert not moved, moved
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 11.8e9, m.temp_size_in_bytes
+
+
 # ---------------------------------------------------------------------------
 # A block whose every layer keeps BOTH a recurrent state and pages
 # (docs/falcon_h1.md) at the published sizes of the benchmark's configuration

@@ -197,6 +197,100 @@ def test_the_mla_kernels_equal_their_lax_spellings(block, width):
     assert bool((a[0] == pool[0]).all())  # another layer's pages are untouched
 
 
+_WORK_BS, _WORK_M = 16, 160  # 160 pages of 16: groups of 64 pages (1,024 tokens), the third ragged
+# a context that ends in the first group, on its last slot, on the second's first slot, in the
+# middle group, in the last group and on the table's last slot
+_WORK_POSITIONS = [0, 17, 1023, 1024, 1500, _WORK_M * _WORK_BS - 1]
+_WORK_MASKS = {
+    "none_live": [0, 0, 0, 0, 0, 0], "one_live": [0, 0, 0, 0, 1, 0],
+    "scattered_half": [1, 0, 0, 1, 0, 1], "all_live": [1, 1, 1, 1, 1, 1],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _work_call(impl, masked):
+    """One compiled call a (spelling, with or without a list): the mask is an
+    argument, so its cases share the program."""
+    def call(q, pool, tables, positions, active):
+        work = DA.mla_work_list(DA.live_slots(active), positions, _WORK_BS, _WORK_M) if masked else None
+        return DA.mla_paged_decode_attention(q, pool, tables, positions, layer=1, scale=0.3,
+                                             kv_lora=32, impl=impl, work=work)
+
+    return jax.jit(call)
+
+
+@pytest.mark.parametrize("mask", list(_WORK_MASKS))
+@pytest.mark.parametrize("impl", ["lax", "pallas"])
+def test_the_mla_kernel_visits_the_work_list_only(impl, mask):
+    """Both spellings: the rows the step's work list names equal the
+    unmasked call's rows to the bit, whichever group their context ends in;
+    a row it leaves out is exactly 0 and is NEVER READ: its table holds page
+    ids past the arena and a page of NaN, its position is stale, far past the
+    table."""
+    rng = np.random.default_rng(48)
+    b, bs, M, w = len(_WORK_POSITIONS), _WORK_BS, _WORK_M, 40
+    nb = b * M + 2
+    poison = nb - 1  # a page of NaN that no live row's table names
+    pool = jnp.asarray(rng.normal(size=(2, nb, 1, w, bs)), jnp.float32).at[:, poison].set(jnp.nan)
+    q = jnp.asarray(rng.normal(size=(b, 4, w)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb - 1))[:b * M].reshape(b, M), jnp.int32)
+    positions = jnp.asarray(_WORK_POSITIONS, jnp.int32)
+    active = jnp.asarray(_WORK_MASKS[mask], bool)
+    every = _work_call(impl, False)(q, pool, tables, positions, jnp.ones((b,), bool))
+    assert bool(jnp.isfinite(every).all())
+    stale = jnp.where(jnp.arange(M)[None] % 2 == 0, poison, nb + 1000)  # NaN, or past the arena
+    got = _work_call(impl, True)(
+        q, pool, jnp.where(active[:, None], tables, stale),
+        jnp.where(active, positions, M * bs + 1000), active)
+    assert got.shape == every.shape and bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(np.asarray(got[active]), np.asarray(every[active]))
+    assert bool((got[~active] == 0).all())
+
+
+@pytest.mark.parametrize("block,width", [(16, 160), (128, 64), (128, 8), (8, 3)])
+def test_the_work_list_follows_live_slots_and_counts_what_the_counter_counts(block, width):
+    """`mla_work_list` names each live row of `live_slots`, in its order, once
+    a group its context reaches (groups 0 .. the last, one after the other)
+    and nothing else; the tokens its steps walk are what
+    `mla_tokens_computed` gives the scheduler's counter for those rows."""
+    rng = np.random.default_rng(block + width)
+    b = 7
+    step = DA.mla_pages_per_step(block, width) * block
+    positions = rng.integers(0, width * block, size=b)
+    positions[:3] = [0, min(step, width * block) - 1, width * block - 1]
+    for mask in ([0] * b, [1] * b, [1, 0, 1, 1, 0, 0, 1], [0, 0, 0, 0, 0, 1, 0]):
+        active = np.asarray(mask, bool)
+        live, n = DA.live_slots(jnp.asarray(active))
+        rows, groups, count = DA.mla_work_list((live, n), jnp.asarray(positions, jnp.int32), block, width)
+        assert rows.dtype == groups.dtype == count.dtype == jnp.int32 and count.shape == (1,)
+        assert rows.shape == groups.shape == (b * -(-width // (step // block)),)
+        want = [(r, g) for r in np.flatnonzero(active) for g in range(positions[r] // step + 1)]
+        k = int(count[0])
+        assert list(zip(rows[:k].tolist(), groups[:k].tolist())) == want
+        assert k * step == int(DA.mla_tokens_computed(positions[active], block, width).sum())
+        assert DA._live_mask((rows, count), b).tolist() == active.tolist()
+
+
+def test_the_mla_kernel_s_grid_is_one_dynamic_axis():
+    """The decode kernel's grid is ONE axis whose bound is the list's count
+    (not slots x groups), with a list and with the identity list of a caller
+    that has none."""
+    pool = jnp.zeros((1, 9, 1, 40, 16))
+    args = (jnp.zeros((4, 2, 40)), pool, jnp.zeros((4, 2), jnp.int32), jnp.zeros((4,), jnp.int32))
+    for masked in (False, True):
+        def call(q, pool, tables, positions, active):
+            work = DA.mla_work_list(DA.live_slots(active), positions, 16, 2) if masked else None
+            return DA.mla_paged_decode_attention(q, pool, tables, positions, layer=0, scale=1.0,
+                                                 kv_lora=32, impl="pallas", work=work)
+
+        eqns = [e for e in jax.make_jaxpr(call)(*args, jnp.ones((4,), bool)).eqns
+                if e.primitive.name == "pallas_call"]
+        assert len(eqns) == 1
+        mapping = eqns[0].params["grid_mapping"]
+        assert len(mapping.grid) == 1 and mapping.num_dynamic_grid_bounds == 1
+        assert mapping.num_index_operands == 5  # layer, tables, positions, rows, groups
+
+
 def test_group_limited_choice_and_weights_against_the_reference():
     """(d) the program's route against the reference's, with a bias that
     moves a token's choice into another group (weights stay unbiased)."""
@@ -349,6 +443,28 @@ def test_the_scheduler_serves_the_reference_s_greedy_tokens(server):
         assert page["pfx_moe_serve_grouped_calls_total"] == 6 * len(prompts) == (
             eng.mcfg.sorted_pair_products * int(sched.stats["prefill_admits"]))
         assert page["pfx_sched_decode_kv_tokens_total"] > 0
+    finally:
+        assert sched.shutdown(timeout=30)
+
+
+def test_the_latent_grid_tokens_count_the_rows_live_at_dispatch_only(server):
+    """One request in an engine of 4 slots: every committed decode step adds
+    the ONE live row's context in whole grid steps to `grid_tokens` (what the
+    kernel's work list holds), not the three empty slots' too."""
+    from paddlefleetx_tpu.core.continuous_batching import ContinuousScheduler
+
+    eng = _engine(server)
+    sched = ContinuousScheduler(eng, max_depth=16, name="dsv3-grid-test")
+    sched.start()
+    try:
+        base = dict(eng.stats)
+        prompt = np.random.default_rng(8).integers(1, 256, size=20).tolist()
+        assert len(sched.submit([prompt], 10).result(timeout=300)[0]) == 10
+        d = {k: eng.stats[k] - base[k] for k in ("steps", "row_steps", "slot_steps", "grid_tokens")}
+        assert 0 < d["row_steps"] < d["slot_steps"] == 4 * d["steps"]
+        # 30 tokens are two pages of 16: a table two pages wide is one grid step of 32 tokens
+        assert int(DA.mla_tokens_computed(np.asarray(29), BLOCK, 2)) == 32
+        assert d["grid_tokens"] == 32 * d["row_steps"]
     finally:
         assert sched.shutdown(timeout=30)
 
