@@ -443,7 +443,10 @@ def _layer_remat(cfg: GPTConfig, fn):
     single_model.py:320-405); "selective" additionally saves the named
     activations qkv + attn_out + attn_lse so the backward pass skips the
     expensive recomputes — the TPU-native middle ground the reference
-    lacks."""
+    lacks.  All three attention paths name their result attn_out (XLA and
+    ring where they return it, the flash kernel inside its custom_vjp
+    forward rule beside attn_lse), so none re-runs its attention in the
+    backward."""
     if not cfg.use_recompute:
         return fn
     if cfg.recompute_granularity == "full":
@@ -451,7 +454,9 @@ def _layer_remat(cfg: GPTConfig, fn):
     if cfg.recompute_granularity == "selective":
         # The save-set trades HBM residency+traffic against recompute FLOPs;
         # qkv+attn_out+attn_lse measured fastest on v5e (saving mlp_hidden
-        # costs 3GB of HBM round-trips per step for a 0.7ms matmul re-run)
+        # costs 3GB of HBM round-trips per step for a 0.7ms matmul re-run;
+        # the flash kernel's attn_out is 32 MB a layer at the 345M recipe
+        # against a 1.1 ms kernel re-run: PERF.md section 6, PR 49)
         policy = jax.checkpoint_policies.save_only_these_names("qkv", "attn_out", "attn_lse")
         return jax.checkpoint(fn, policy=policy)
     return fn

@@ -126,7 +126,7 @@ def attention(
     )
     # Whenever the XLA path actually runs (configured, or flash fell back),
     # name the output so selective remat can skip the O(s^2) recompute.
-    # The flash kernel instead names its lse internally ("attn_lse") and
-    # re-runs one cheap fwd kernel in backward. Tagging here (not at call
-    # sites) keeps the which-impl-ran decision in one place.
+    # The flash kernel names its own output the same, and its lse
+    # ("attn_lse"), inside its custom_vjp forward rule. Tagging here (not at
+    # call sites) keeps the which-impl-ran decision in one place.
     return checkpoint_name(out, "attn_out")

@@ -6,9 +6,11 @@ softmax-mask-triu in ``core_attn`` single_model.py:83-200 and the
 the [s, s] score matrix never materialises in HBM.
 
 Layout: inputs [batch, seq, heads, head_dim] (model layout), kernels run on
-[batch*heads, seq, head_dim].  Forward saves per-row logsumexp for the
-backward recomputation (standard FlashAttention-2 scheme: dq swept over kv
-blocks, dk/dv swept over q blocks).
+[batch*heads, seq, head_dim]; the differentiation rule (``_flash_bsnd``)
+sits around the layout changes, so its residuals are in the model's layout.
+Forward saves per-row logsumexp for the backward recomputation (standard
+FlashAttention-2 scheme: dq swept over kv blocks, dk/dv swept over q
+blocks).
 
 ``window`` (a query sees the ``window`` newest positions up to itself) adds a
 lower bound on the KV blocks a query block visits, in forward, dq and dkv,
@@ -410,13 +412,11 @@ def _flash_bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k):
     return dq.astype(q.dtype), dk, dv
 
 
-def _flash_bwd(scale, block, bwd_mode, window, group, res, g):
-    q, k, v, out, lse = res
-    do = g
+def _flash_bwd(q, k, v, do, lse, delta, scale, block, bwd_mode, window, group):
+    """dq, dk, dv in the kernels' layout; ``delta`` [bh, seq, 1] is the row
+    sum of dO * O."""
     bh, seq, d = q.shape
     block_q, block_k = block  # static (bq, bk) tuple
-
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[..., None]  # [bh, s, 1]
 
     if bwd_mode == "fused":
         if window or group > 1:
@@ -491,25 +491,63 @@ def _flash_bwd(scale, block, bwd_mode, window, group, res, g):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhsd(q, k, v, scale, block, bwd_mode, window=0, group=1):
-    out, _ = _flash_fwd(q, k, v, scale, block, window, group)
-    return out
+def _to_bh(x):
+    """[batch, seq, heads, head_dim] -> the kernels' [batch*heads, seq, head_dim]."""
+    b, s, n, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * n, s, d)
 
 
-def _flash_bhsd_fwd(q, k, v, scale, block, bwd_mode, window=0, group=1):
-    out, lse = _flash_fwd(q, k, v, scale, block, window, group)
-    # Name lse so selective-remat policies can keep it: without a saved lse
-    # the backward pass must re-run the forward kernel a SECOND time just to
-    # regenerate it (observed as rematted_computation in traces). The out
-    # residual is deliberately NOT name-saved: the backward's single primal
-    # re-run measured faster than paying HBM for a saved copy (34.3k vs
-    # 33.2k tok/s on the v5e bench).
+def _from_bh(x, batch):
+    """``_to_bh``'s inverse for a batch of ``batch``."""
+    bh, s, d = x.shape
+    return x.reshape(batch, bh // batch, s, d).transpose(0, 2, 1, 3)
+
+
+def _flash_model_layout(q, k, v, scale, block, window):
+    out, lse = _flash_fwd(_to_bh(q), _to_bh(k), _to_bh(v), scale, block, window,
+                          q.shape[2] // k.shape[2])
+    return _from_bh(out, q.shape[0]), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bsnd(q, k, v, scale, block, bwd_mode, window=0):
+    """q, k, v and the result in the MODEL's layout; the differentiation rule
+    sits around the layout changes so that what it saves is lane-dense."""
+    return _flash_model_layout(q, k, v, scale, block, window)[0]
+
+
+def _flash_bsnd_fwd(q, k, v, scale, block, bwd_mode, window=0):
+    out, lse = _flash_model_layout(q, k, v, scale, block, window)
+    # Both results carry a name so a selective-remat policy keeps them
+    # ("attn_out" is what the XLA and ring paths call theirs): with either
+    # one unsaved the backward re-runs the whole forward kernel to have it
+    # back.  The output is named in the model's layout with the heads folded
+    # into the minor dimension: the kernels' [bh, seq, 64] is padded to 128
+    # lanes wherever it is kept, and the backward wants the output only for
+    # delta, a sum over head_dim that it can take in any layout.  On the
+    # 345M cell (v5e, batch 16 x 1024, PR 49; tokens/s/chip and a step,
+    # five pairs at five seeds each): parent 32,223-32,298, 0.5050 s; the
+    # kernels' own output named 33,269-33,319, 0.4897 s (the re-run was
+    # 26.4 ms a step, the padded copy gave 11 back); this 34,256-34,280,
+    # 0.4757 s.  Under "full" recompute nothing is saved and the re-run
+    # stays, which is what "full" means.
+    b, s, n, d = out.shape
+    out = checkpoint_name(out.reshape(b, s, n * d), "attn_out").reshape(b, s, n, d)
     lse = checkpoint_name(lse, "attn_lse")
     return out, (q, k, v, out, lse)
 
 
-_flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bwd)
+def _flash_bsnd_bwd(scale, block, bwd_mode, window, res, g):
+    q, k, v, out, lse = res
+    b, s, n, d = q.shape
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [b, s, n]
+    delta = delta.transpose(0, 2, 1).reshape(b * n, s, 1)
+    dq, dk, dv = _flash_bwd(_to_bh(q), _to_bh(k), _to_bh(v), _to_bh(g), lse, delta,
+                            scale, block, bwd_mode, window, n // k.shape[2])
+    return _from_bh(dq, b), _from_bh(dk, b), _from_bh(dv, b)
+
+
+_flash_bsnd.defvjp(_flash_bsnd_fwd, _flash_bsnd_bwd)
 
 
 def flash_attention(
@@ -533,7 +571,7 @@ def flash_attention(
     that hold the single-kernel backward to it (see "Backward" above)."""
     if not causal:
         raise NotImplementedError("only causal flash attention")
-    b, s, n, d = q.shape
+    _, s, n, d = q.shape
     n_kv = k.shape[2]
     if n % n_kv or v.shape != k.shape:
         raise ValueError(f"{n} query heads over K {k.shape} / V {v.shape}")
@@ -548,12 +586,7 @@ def flash_attention(
     if bwd_schedule not in ("split", "fused"):
         raise ValueError(f"flash bwd schedule {bwd_schedule!r}; valid: split, fused")
 
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, d)
-
-    out = _flash_bhsd(to_bh(q), to_bh(k), to_bh(v), scale, (bq, bk), bwd_schedule,
-                      window, n // n_kv)
-    return out.reshape(b, n, s, d).transpose(0, 2, 1, 3)
+    return _flash_bsnd(q, k, v, scale, (bq, bk), bwd_schedule, window)
 
 
 def flash_supported(seq: int) -> bool:

@@ -506,6 +506,63 @@ def test_345m_step_draws_its_dropout_masks_with_the_bit_generator(topo):
     assert (xor_in_loops, xor) == (0, 0)
 
 
+def _flash_sites(text):
+    """The compiled step's flash kernel sites by the kernel's name less its
+    ``pfx_flash_``: ``{"fwd": [op_name, ...], "bwd_dq": ..., "bwd_dkv": ...}``,
+    one entry an HLO instruction (a site in a loop body runs once a layer)."""
+    import re
+
+    sites = {}
+    for kernel, op_name in re.findall(
+            r"%pfx_flash_(\w+?)[.\d]* = .*?op_name=\"([^\"]*)\"", text):
+        sites.setdefault(kernel, []).append(op_name)
+    return sites
+
+
+def test_345m_step_runs_the_flash_forward_once_a_layer(topo):
+    """The committed 345M recipe ("selective" recompute): the compiled step
+    holds exactly ONE ``pfx_flash_fwd`` site, in the forward loop body, one
+    ``pfx_flash_bwd_dq`` and one ``pfx_flash_bwd_dkv``.  The kernel's output
+    is a saved residual (``attn_out``, as the XLA and ring paths' is); before
+    PR 49 only ``attn_lse`` carried a name and the backward loop re-ran the
+    whole forward kernel for ``out``: a second site, 26 ms of a 507 ms step
+    (PERF.md section 6, PR 49).
+
+    The price is memory, held here to a figure.  The residual is stacked in
+    the model's layout with the heads folded into the minor dimension,
+    ``bf16[24,16,1024,1024]``, 768 MiB (the kernel's own
+    ``bf16[24,256,1024,64]`` would be padded to 128 lanes: 1,536 MiB), and
+    the compiler's peak for the step reads 14,014,113,280 bytes (13.05 GiB)
+    where the parent's read 13,208,806,912 (12.30 GiB), of the 15.75 GiB it
+    may use; its buffer assignment's total 14,440,171,088 against
+    13,634,864,704, so 2.3 GiB are left.  (``argument + temp + generated
+    code``, the sum the trinity case bounds, reads 18,706,150,912 against
+    17,095,950,336 here: it counts more than the device holds at once, so it
+    is stated and not compared with the device's size.)"""
+    c = _step_345m(topo)
+    text = c.as_text()
+    sites = _flash_sites(text)
+    assert {k: len(v) for k, v in sites.items()} == {"fwd": 1, "bwd_dq": 1, "bwd_dkv": 1}
+    assert "rematted_computation" not in sites["fwd"][0]
+    assert "bf16[24,256,1024,64]" not in text  # no stack of the padded layout
+    m = c.memory_analysis()
+    assert m.peak_memory_in_bytes <= 14.1e9, m.peak_memory_in_bytes
+    held = m.argument_size_in_bytes + m.temp_size_in_bytes + m.generated_code_size_in_bytes
+    assert held <= 18.8e9, held
+
+
+def test_trinity_step_still_recomputes_its_flash_forward(topo):
+    """"full" recompute saves nothing, names or none: every one of the trinity
+    step's backward sites keeps TWO forward sites, the forward pass's and the
+    recompute's (``rematted_computation`` in its ``op_name``).  PR 49's name
+    on the attention's output saves nothing here."""
+    sites = _flash_sites(_step_trinity(topo).as_text())
+    forward = sites["fwd"]
+    recomputed = [s for s in forward if "rematted_computation" in s]
+    assert len(sites["bwd_dq"]) == len(sites["bwd_dkv"]) == 5
+    assert len(recomputed) == 5 and len(forward) == 10
+
+
 def test_trinity_step_draws_no_mask(topo):
     """``hidden_dropout_prob`` 0.0: ``dropout()`` returns its input before
     it touches the key, so the step holds no generator of either kind."""

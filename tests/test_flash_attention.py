@@ -103,18 +103,18 @@ def test_asymmetric_tiles_match_the_square_ones():
     ceil/floor divisions that have to hold for unequal blocks, in both
     backward schedules (the kernels take the pair; ``_block_sizes`` hands
     them a square one today)."""
-    from paddlefleetx_tpu.ops.flash_attention import _flash_bhsd
+    from paddlefleetx_tpu.ops.flash_attention import _flash_bsnd
 
-    bh, s, d = 4, 256, 64
+    b, s, n, d = 2, 256, 2, 64
     kq, kk, kv, kg = jax.random.split(jax.random.key(3), 4)
-    q, k, v, ct = (jax.random.normal(key, (bh, s, d), jnp.float32) for key in (kq, kk, kv, kg))
+    q, k, v, ct = (jax.random.normal(key, (b, s, n, d), jnp.float32) for key in (kq, kk, kv, kg))
     scale = float(1.0 / d**0.5)
 
     def run(block, bwd="split"):
         def loss(q, k, v):
-            return jnp.sum(_flash_bhsd(q, k, v, scale, block, bwd) * ct)
+            return jnp.sum(_flash_bsnd(q, k, v, scale, block, bwd) * ct)
 
-        return (_flash_bhsd(q, k, v, scale, block, bwd),) + jax.grad(loss, (0, 1, 2))(q, k, v)
+        return (_flash_bsnd(q, k, v, scale, block, bwd),) + jax.grad(loss, (0, 1, 2))(q, k, v)
 
     square = run((64, 64))
     for block, bwd in (((64, 128), "split"), ((128, 64), "split"), ((64, 128), "fused")):

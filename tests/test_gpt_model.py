@@ -1,6 +1,8 @@
 """GPT model unit tests: shapes, init-loss sanity, determinism, recompute,
 and TP/SP/FSDP layout parity on the 8-device CPU mesh."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -167,3 +169,46 @@ def test_selective_remat_parity():
         np.testing.assert_allclose(float(got[0]), float(ref[0]), rtol=1e-6)
         for a, b in zip(jax.tree.leaves(ref[1]), jax.tree.leaves(got[1])):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_step(granularity):
+    """(forward-kernel calls in the jaxpr, loss, gradient leaves) of the toy
+    model's differentiated loss on the flash path, dropout on as the 345M
+    recipe runs it, under one recompute granularity (None: no recompute)."""
+    import dataclasses
+    import re
+
+    cfg = dataclasses.replace(
+        TINY, attn_impl="flash", hidden_dropout_prob=0.3,
+        use_recompute=granularity is not None, recompute_granularity=granularity or "full")
+    params = gpt.init(TINY, jax.random.key(0))
+    batch = _batch(jax.random.key(1), TINY)
+    key = jax.random.key(42)
+    step = jax.value_and_grad(
+        lambda p: gpt.loss_fn(p, batch, cfg, dropout_key=key, train=True))
+    calls = len(re.findall(r"name=pfx_flash_fwd\b", str(jax.make_jaxpr(step)(params))))
+    loss, grads = jax.jit(step)(params)
+    return calls, np.asarray(loss), [np.asarray(g) for g in jax.tree.leaves(grads)]
+
+
+@pytest.mark.parametrize("granularity, forward_calls", [
+    pytest.param("selective", 1, id="selective"),
+    pytest.param("full", 2, id="full"),
+    pytest.param(None, 1, id="no_recompute"),
+])
+def test_flash_forward_runs_once_unless_recompute_is_full(granularity, forward_calls):
+    """The flash kernel's output carries the name ``attn_out`` beside its
+    ``attn_lse``, so under "selective" the differentiated loss calls
+    ``pfx_flash_fwd`` ONCE (the backward reads the saved residual; before
+    PR 49 it re-ran the whole kernel to get ``out`` back), under "full",
+    which saves nothing, twice, and without recompute once.  The saved
+    tensor is the one the re-run would have produced: loss and every
+    gradient leaf are equal TO THE BIT across the three."""
+    calls, loss, grads = _flash_step(granularity)
+    assert calls == forward_calls
+    _, other_loss, other_grads = _flash_step(None if granularity == "full" else "full")
+    assert loss.tobytes() == other_loss.tobytes()
+    assert len(grads) == len(other_grads)
+    for a, b in zip(grads, other_grads):
+        assert a.tobytes() == b.tobytes()
