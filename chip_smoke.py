@@ -459,7 +459,7 @@ def phase_kernels(args) -> int:
     from paddlefleetx_tpu.ops.decode_attention import (
         decode_attention, kv_cache_len, paged_decode_attention, quantize_kv,
     )
-    from paddlefleetx_tpu.ops.flash_attention import flash_attention
+    from paddlefleetx_tpu.ops.flash_attention import _block_sizes, _flash_bsnd
 
     shape = shape_of(args.rehearse)
     n, s, hidden = shape["heads"], shape["seq"], shape["hidden"]
@@ -516,8 +516,11 @@ def phase_kernels(args) -> int:
         ref_out = jax.jit(xla)(q, k, v)
         truth_out = jax.jit(xla)(*(a.astype(jnp.float32) for a in (q, k, v)))
         ref_grads = jax.jit(jax.grad(functools.partial(weighted, xla), (0, 1, 2)))(q, k, v)
+        scale, tile = float(d ** -0.5), _block_sizes(s, block)
         for bwd in ("split", "fused"):
-            flash = functools.partial(flash_attention, block=block, bwd_schedule=bwd)
+            def flash(q, k, v, bwd=bwd):
+                return _flash_bsnd(q, k, v, scale, tile, bwd)
+
             if bwd == "split":
                 check(f"flash_fwd_{tag}", jax.jit(flash)(q, k, v), ref_out, truth_out)
             check(f"flash_bwd_{bwd}_{tag}",

@@ -220,18 +220,20 @@ def _flash_fwd(q, k, v, scale, block, window=0, group=1):
 # ---------------------------------------------------------------------------
 # Backward
 #
-# Two schedules (``flash_attention(bwd_schedule=)``; every caller but the
-# tests runs ``split``.  PR 45 measured ``fused`` ahead on the 345M cell,
-# PERF.md section 6: the PR that turns it on chooses it here, from
-# ``window == 0 and group == 1``, and says what it claims):
-#   split (default): FlashAttention-2 style — a dq kernel swept over kv
-#     blocks and a dk/dv kernel swept over q blocks.  Each (i, j) tile
-#     computes s = q@k^T and p = exp(s - lse) TWICE (once per kernel).
+# Two schedules, chosen from the call's own shapes by ``_bwd_schedule``
+# below (nothing outside this file selects one; the tests and
+# ``chip_smoke.py`` that hold them to each other hand ``_flash_bsnd`` its
+# ``bwd_mode``):
+#   split: FlashAttention-2 style — a dq kernel swept over kv blocks and a
+#     dk/dv kernel swept over q blocks.  Each (i, j) tile computes
+#     s = q@k^T and p = exp(s - lse) TWICE (once per kernel).  Knows a
+#     window and shared KV heads.
 #   fused: one kernel, grid over kv blocks; each tile computes s/p once
-#     and emits the dv/dk contributions AND accumulates the dq rows
-#     in-place.  TPU Pallas grids execute sequentially, so the dq output
-#     block (the full [seq, d] row slab, revisited by every j) is
-#     accumulated correctly in VMEM and flushed when the bh row changes.
+#     and emits the dv/dk contributions AND accumulates the dq rows in a
+#     float32 [seq, d] VMEM scratch.  TPU Pallas grids execute
+#     sequentially, so the slab is zeroed at a bh row's first kv block,
+#     summed into by every one, and written out once, in q's dtype, at
+#     the row's last.  Knows neither a window nor shared KV heads.
 # ---------------------------------------------------------------------------
 
 
@@ -328,7 +330,7 @@ def _bwd_tile(q, k, v, do, lse, delta, row_ids, col_ids, scale, window=0):
 
 
 def _bwd_fused_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc,
     *, scale, block_q, block_k, seq
 ):
     kj = pl.program_id(1)
@@ -336,14 +338,13 @@ def _bwd_fused_kernel(
     v = v_ref[0]
     d = k.shape[-1]
 
-    # dq is the full [seq, d] row slab, revisited by every kv-block
-    # program of this bh row: zero it once, at the first kv block.  The
-    # slab is fp32 (out_shape below) so the cross-block read-modify-write
+    # dq of the whole [seq, d] row is summed over the kv-block programs of
+    # this bh row in a float32 scratch, zeroed at the first kv block, so the
     # accumulation rounds once at the end, not once per kv block — same
     # fp32-carry rule as the split _dq_kernel and chunked_ce's dh.
     @pl.when(kj == 0)
     def _zero_dq():
-        dq_ref[0] = jnp.zeros((seq, d), dq_ref.dtype)
+        dq_acc[...] = jnp.zeros((seq, d), jnp.float32)
 
     col_ids = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
 
@@ -367,7 +368,7 @@ def _bwd_fused_kernel(
         dq_tile = jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        dq_ref[0, sl, :] = dq_ref[0, sl, :] + dq_tile  # fp32 slab
+        dq_acc[sl, :] = dq_acc[sl, :] + dq_tile
         return dk_new, dv_new
 
     first_q = (kj * block_k) // block_q
@@ -378,10 +379,20 @@ def _bwd_fused_kernel(
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
+    # the row's output block stays resident until the bh row changes: it is
+    # written once, from the finished sums, so dq reaches HBM in q's dtype
+    @pl.when(kj == seq // block_k - 1)
+    def _write_dq():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
 
 def _flash_bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k):
+    from jax.experimental.pallas import tpu as pltpu
+
     bh, seq, d = q.shape
-    dq, dk, dv = pl.pallas_call(
+    lanes = max(d, 128)  # VMEM pads a narrower minor dimension to the 128-lane tile
+    row = seq * lanes * q.dtype.itemsize
+    return pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, scale=scale, block_q=block_q, block_k=block_k,
             seq=seq,
@@ -401,15 +412,53 @@ def _flash_bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k):
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            # dq fp32: accumulated in-place across kv-block grid steps
-            jax.ShapeDtypeStruct(q.shape, jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32)],
         interpret=_device.pallas_interpret(),
         name="pfx_flash_bwd_fused",
+        # q, dO and the dq block whole, lse and delta whole (a [seq, 1]
+        # float32 column takes a 128-lane tile per 8 rows in VMEM), and
+        # half the float32 slab, which is held once where those are twice
+        **_compiler_params(3 * row + 2 * seq * 128 * 4 + seq * lanes * 2),
     )(q, k, v, do, lse, delta)
-    return dq.astype(q.dtype), dk, dv
+
+
+# The two schedules alone on a v5e (PR 52: batch x heads 256, bfloat16, the
+# ladder's 512 tile, no window, equal head counts; 8 calls of ``_flash_bwd``
+# chained in one jit, ms a call: the kernels' self time from a trace of 5
+# chains, and in brackets a call by the host's clock over 20 chains, which
+# also pays for what reads dq):
+#
+#   head  seq    split = dq + dkv          [host]    fused   [host]   fused/split
+#   64    512    0.921 = 0.424 + 0.497     [1.127]   0.588   [0.805]  0.64
+#   64    1024   2.993 = 1.123 + 1.870     [3.497]   2.049   [2.564]  0.68
+#   64    2048   9.025 = 3.455 + 5.570     [10.21]   6.630   [7.809]  0.73
+#   64    4096   29.62 = 11.77 + 17.85     [32.01]   21.42   [23.80]  0.72
+#   128   512    0.916 = 0.417 + 0.499     [1.085]   0.575   [0.744]  0.63
+#   128   1024   2.978 = 1.119 + 1.859     [3.367]   2.067   [2.457]  0.69
+#   128   2048   8.969 = 3.440 + 5.529     [9.896]   6.619   [7.540]  0.74
+#   128   4096   29.44 = 11.72 + 17.72     [31.29]   21.40   [23.24]  0.73
+#
+# (With dq leaving the kernel in float32 and cast outside, as before PR 52:
+# 2.079 [2.680] at 64 x 1024, 2.081 [2.586] at 128 x 1024, and 4096 did not
+# fit the default scoped VMEM.)  The gradients of the two schedules were equal
+# to the bit at every shape.  head_dim -> the longest sequence measured:
+_FUSED_MAX_SEQ = {64: 4096, 128: 4096}
+
+
+def _bwd_schedule(seq: int, d: int, window: int, group: int) -> str:
+    """The backward schedule of a call, from its static shapes: the one place
+    it is chosen.  ``fused`` where it can run (no window, no shared KV
+    heads) and was measured ahead (the table above: its head sizes, the
+    512 tile, up to its longest sequence); ``split`` for everything else,
+    until somebody measures it."""
+    if window or group > 1:
+        return "split"
+    measured = seq % 512 == 0 and seq <= _FUSED_MAX_SEQ.get(d, 0)
+    return "fused" if measured else "split"
 
 
 def _flash_bwd(q, k, v, do, lse, delta, scale, block, bwd_mode, window, group):
@@ -417,12 +466,14 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, block, bwd_mode, window, group):
     sum of dO * O."""
     bh, seq, d = q.shape
     block_q, block_k = block  # static (bq, bk) tuple
+    if bwd_mode not in ("split", "fused"):
+        raise ValueError(f"flash bwd schedule {bwd_mode!r}; valid: split, fused")
 
     if bwd_mode == "fused":
         if window or group > 1:
             raise NotImplementedError(
                 "the fused flash backward knows neither a window nor shared KV "
-                "heads; use bwd_schedule='split'")
+                "heads; _bwd_schedule chooses 'split' for them")
         return _flash_bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k)
 
     dq = pl.pallas_call(
@@ -557,7 +608,6 @@ def flash_attention(
     *,
     causal: bool = True,
     block: int = 0,
-    bwd_schedule: str = "split",
     window: int = 0,
 ):
     """q: [batch, seq, heads, head_dim]; k, v: the same, or with fewer heads
@@ -566,9 +616,8 @@ def flash_attention(
     positions i-window+1 .. i only.
 
     ``block`` (0 = the ladder of ``_block_sizes``): a caller's own square
-    tile, for a test that wants several blocks of a short sequence.
-    ``bwd_schedule``: "split", or "fused" for the tests and the chip smoke
-    that hold the single-kernel backward to it (see "Backward" above)."""
+    tile, for a test that wants several blocks of a short sequence.  The
+    backward's schedule follows from the shapes (``_bwd_schedule``)."""
     if not causal:
         raise NotImplementedError("only causal flash attention")
     _, s, n, d = q.shape
@@ -583,10 +632,7 @@ def flash_attention(
             "pad the sequence or use attn_impl='xla'"
         )
     scale = float(1.0 / (d**0.5))
-    if bwd_schedule not in ("split", "fused"):
-        raise ValueError(f"flash bwd schedule {bwd_schedule!r}; valid: split, fused")
-
-    return _flash_bsnd(q, k, v, scale, (bq, bk), bwd_schedule, window)
+    return _flash_bsnd(q, k, v, scale, (bq, bk), _bwd_schedule(s, d, window, n // n_kv), window)
 
 
 def flash_supported(seq: int) -> bool:

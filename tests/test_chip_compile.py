@@ -93,16 +93,19 @@ def _has_kernel(compiled) -> bool:
 @pytest.mark.parametrize("d", HEAD_DIMS)
 @pytest.mark.parametrize("bwd", ["split", "fused"])
 def test_flash_fwd_bwd_compiles(topo, d, bwd):
-    from paddlefleetx_tpu.ops.flash_attention import flash_attention
+    from paddlefleetx_tpu.ops.flash_attention import _flash_bsnd
 
     q = _shapes(_one_chip(topo), ((2, 1024, HEADS, d), BF16))
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, block=512, bwd_schedule=bwd)
+        out = _flash_bsnd(q, k, v, float(d ** -0.5), (512, 512), bwd)
         return jnp.sum(out.astype(jnp.float32))
 
     c = _compile(jax.grad(loss, (0, 1, 2)), q, q, q)
     assert _has_kernel(c)
+    text = c.as_text()
+    sites = {name: f"flash_bwd_{name}" in text for name in ("dq", "dkv", "fused")}
+    assert sites == {"dq": bwd == "split", "dkv": bwd == "split", "fused": bwd == "fused"}
 
 
 @pytest.mark.parametrize("hidden", [pytest.param(1024, id="345M"),
@@ -521,9 +524,12 @@ def _flash_sites(text):
 
 def test_345m_step_runs_the_flash_forward_once_a_layer(topo):
     """The committed 345M recipe ("selective" recompute): the compiled step
-    holds exactly ONE ``pfx_flash_fwd`` site, in the forward loop body, one
-    ``pfx_flash_bwd_dq`` and one ``pfx_flash_bwd_dkv``.  The kernel's output
-    is a saved residual (``attn_out``, as the XLA and ring paths' is); before
+    holds exactly ONE ``pfx_flash_fwd`` site, in the forward loop body, and
+    ONE backward site, ``pfx_flash_bwd_fused`` (``_bwd_schedule``: no window,
+    no shared KV heads, seq 1,024 at head 64; before PR 52 a
+    ``pfx_flash_bwd_dq`` and a ``pfx_flash_bwd_dkv`` site, every score tile
+    computed twice).  The kernel's output is a saved residual
+    (``attn_out``, as the XLA and ring paths' is); before
     PR 49 only ``attn_lse`` carried a name and the backward loop re-ran the
     whole forward kernel for ``out``: a second site, 26 ms of a 507 ms step
     (PERF.md section 6, PR 49).
@@ -538,13 +544,16 @@ def test_345m_step_runs_the_flash_forward_once_a_layer(topo):
     13,634,864,704, so 2.3 GiB are left.  (``argument + temp + generated
     code``, the sum the trinity case bounds, reads 18,706,150,912 against
     17,095,950,336 here: it counts more than the device holds at once, so it
-    is stated and not compared with the device's size.)"""
+    is stated and not compared with the device's size.)  With the single
+    backward kernel (PR 52) the peak reads the same 14,014,113,280 (it is at
+    the head, not in the layer loop) and the sum 18,706,032,128."""
     c = _step_345m(topo)
     text = c.as_text()
     sites = _flash_sites(text)
-    assert {k: len(v) for k, v in sites.items()} == {"fwd": 1, "bwd_dq": 1, "bwd_dkv": 1}
+    assert {k: len(v) for k, v in sites.items()} == {"fwd": 1, "bwd_fused": 1}
     assert "rematted_computation" not in sites["fwd"][0]
     assert "bf16[24,256,1024,64]" not in text  # no stack of the padded layout
+    assert "f32[256,1024,64]" not in text  # dq leaves the kernel in q's dtype
     m = c.memory_analysis()
     assert m.peak_memory_in_bytes <= 14.1e9, m.peak_memory_in_bytes
     held = m.argument_size_in_bytes + m.temp_size_in_bytes + m.generated_code_size_in_bytes

@@ -68,33 +68,48 @@ def test_bf16_runs():
     assert np.all(np.isfinite(np.asarray(out, np.float32)))
 
 
+@pytest.mark.parametrize("d", [32, 128])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)])
-def test_fused_bwd_matches_split(dtype, tol):
-    """``bwd_schedule="fused"`` (single-kernel dq+dk+dv) must reproduce the
-    split two-kernel backward up to f32 accumulation order; in bfloat16,
-    the dtype the model path runs, up to a rounding a tile (with fp32
-    inputs the kernel-internal downcasts in ``_bwd_tile`` are no-ops).
+def test_fused_bwd_matches_split(dtype, tol, d):
+    """The single-kernel backward (``bwd_mode="fused"``: dq, dk and dv from
+    one kernel) must reproduce the split two-kernel backward up to f32
+    accumulation order; in bfloat16, the dtype the model path runs, up to a
+    rounding a tile (with fp32 inputs the kernel-internal downcasts in
+    ``_bwd_tile`` are no-ops).  Every gradient comes back in its input's
+    dtype: dq is summed in a float32 scratch and cast inside the kernel.
 
     Block 64 at seq 256 gives 4 kv blocks, so the fused kernel's core
-    mechanism — the dq slab zeroed at kj==0 and read-modify-written
-    across kv-block grid steps — is actually exercised (a single-block
-    grid would pass even with broken cross-block accumulation)."""
-    b, s, n, d = 1, 256, 2, 32
+    mechanism — the dq slab zeroed at kj==0, read-modify-written across
+    kv-block grid steps and written out at the last — is actually
+    exercised (a single-block grid would pass even with broken
+    cross-block accumulation)."""
+    from paddlefleetx_tpu.ops.flash_attention import _flash_bsnd
+
+    b, s, n = 1, 256, 2
     kq, kk, kv, kg = jax.random.split(jax.random.key(4), 4)
     q, k, v, ct = (jax.random.normal(key, (b, s, n, d), dtype) for key in (kq, kk, kv, kg))
 
     def grads(bwd):
         def loss(q, k, v):
-            out = flash_attention(q, k, v, causal=True, block=64, bwd_schedule=bwd)
+            out = _flash_bsnd(q, k, v, float(d ** -0.5), (64, 64), bwd)
             return jnp.sum(out.astype(jnp.float32) * ct.astype(jnp.float32))
 
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     for a, b_ in zip(grads("split"), grads("fused")):
+        assert a.dtype == b_.dtype == dtype
         np.testing.assert_allclose(
             np.asarray(b_, np.float32), np.asarray(a, np.float32), rtol=tol, atol=tol)
     with pytest.raises(ValueError, match="schedule"):
-        flash_attention(q, k, v, bwd_schedule="fuse")
+        grads("fuse")
+
+
+def test_the_schedule_is_not_a_caller_s_to_choose():
+    """Nothing outside the kernel's file selects a backward schedule: the
+    public call takes no such argument (``_bwd_schedule`` reads the shapes)."""
+    q = jnp.zeros((1, 64, 2, 32), jnp.float32)
+    with pytest.raises(TypeError, match="bwd_schedule"):
+        flash_attention(q, q, q, bwd_schedule="fused")
 
 
 def test_asymmetric_tiles_match_the_square_ones():

@@ -2,11 +2,18 @@
 sequence length: the tiles of the lengths the benchmark's cells trace are
 pinned here (``tools/program_text.py`` masks a kernel's body, the grid in it,
 so its hashes do not see a tile move), and ``flash_supported`` says what
-``_block_sizes`` means.  No kernel runs."""
+``_block_sizes`` means.  The backward's schedule is chosen in one place too,
+``_bwd_schedule``, from the call's shapes: the cells' are pinned here, and a
+lowered ``flash_attention`` shows that the choice is the kernel that runs.  No
+kernel runs."""
 
+import jax
+import jax.numpy as jnp
 import pytest
 
-from paddlefleetx_tpu.ops.flash_attention import _block_sizes, flash_supported
+from paddlefleetx_tpu.ops.flash_attention import (
+    _block_sizes, _bwd_schedule, flash_attention, flash_supported,
+)
 
 # sequence length -> tile (0: no rung divides it, attention() takes the XLA path)
 CELLS = {
@@ -44,3 +51,41 @@ def test_a_caller_s_tile_is_taken_as_given():
 def test_an_invalid_tile_raises(block, match):
     with pytest.raises(ValueError, match=match):
         _block_sizes(256, block)
+
+
+# (seq, head_dim, window, group) -> the backward schedule
+SCHEDULES = {
+    "345m": ((1024, 64, 0, 1), "fused"),
+    # train-trinity-mini-1of8: 32 query heads over 4 KV heads, window 2,048 in 3 layers of 4
+    "trinity-window": ((8192, 128, 2048, 8), "split"),
+    "trinity-full": ((8192, 128, 0, 8), "split"),
+    "window-alone": ((1024, 64, 256, 1), "split"),
+    "group-alone": ((1024, 64, 0, 2), "split"),
+    # the GPT 1.3B / 6.7B / 175B recipes: head 128 at seq 1,024-2,048
+    "head-128-seq-2048": ((2048, 128, 0, 1), "fused"),
+    "the-longest-measured": ((4096, 64, 0, 1), "fused"),
+    "above-the-measured-bound": ((8192, 64, 0, 1), "split"),
+    "a-head-size-not-measured": ((1024, 80, 0, 1), "split"),
+    "a-tile-not-measured": ((768, 64, 0, 1), "split"),  # the ladder gives 768 a tile of 256
+    "one-short-block": ((256, 64, 0, 1), "split"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCHEDULES))
+def test_bwd_schedule_of_the_cells(case):
+    shapes, want = SCHEDULES[case]
+    assert _bwd_schedule(*shapes) == want
+
+
+@pytest.mark.parametrize("seq,heads,window,want", [
+    (1024, (2, 2), 0, "fused"), (1024, (2, 2), 256, "split"), (1024, (4, 2), 0, "split")])
+def test_flash_attention_lowers_the_schedule_the_rule_names(seq, heads, window, want):
+    """The public call, as ``ops/attention.py`` makes it (no tile, no
+    schedule): its backward holds the rule's kernel and not the other's."""
+    n, n_kv = heads
+    q = jnp.zeros((1, seq, n, 64), jnp.bfloat16)
+    kv = jnp.zeros((1, seq, n_kv, 64), jnp.bfloat16)
+    text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, window=window).astype(jnp.float32)), (0, 1, 2))).lower(q, kv, kv).as_text(debug_info=True)
+    kernels = {name for name in ("dq", "dkv", "fused") if f"flash_bwd_{name}" in text}
+    assert kernels == ({"fused"} if want == "fused" else {"dq", "dkv"})

@@ -417,12 +417,12 @@ def test_device_host_split_is_a_union_not_a_sum(tmp_path):
 
 
 def _flash(bwd):
-    from paddlefleetx_tpu.ops.flash_attention import flash_attention
+    from paddlefleetx_tpu.ops.flash_attention import _flash_bsnd
 
     q = jnp.zeros((1, 256, 2, 64), jnp.float32)
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, block=128, bwd_schedule=bwd))
+        return jnp.sum(_flash_bsnd(q, k, v, 0.125, (128, 128), bwd))
 
     return jax.grad(loss, (0, 1, 2)), (q, q, q)
 

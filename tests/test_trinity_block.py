@@ -254,7 +254,8 @@ def test_flash_window_and_shared_kv_heads_match_plain_attention(window, heads):
 def test_flash_without_window_is_the_program_it_was():
     """``window`` left out, 0, or at least the sequence length, with equal
     head counts: the same kernels with the same static arguments, so the
-    same bits, forward and backward; and no fused backward for the rest."""
+    same bits, forward and backward; and the fused backward, handed a window,
+    raises by name (``_bwd_schedule`` never hands it one)."""
     q, k, v, ct = _qkv(256, 4, 4, 32, seed=1)
     runs = []
     for kw in ({}, {"window": 0}, {"window": 256}, {"window": 1000}):
@@ -267,9 +268,21 @@ def test_flash_without_window_is_the_program_it_was():
     for other in runs[1:]:
         for a, b in zip(runs[0], other):
             assert np.array_equal(np.asarray(a), np.asarray(b))
+    from paddlefleetx_tpu.ops.flash_attention import _flash_bsnd
+
     with pytest.raises(NotImplementedError, match="fused"):
-        jax.grad(lambda q: jnp.sum(flash_attention(
-            q, k, v, block=64, window=32, bwd_schedule="fused")))(q)
+        jax.grad(lambda q: jnp.sum(_flash_bsnd(q, k, v, 32 ** -0.5, (64, 64), "fused", 32)))(q)
+
+
+@pytest.mark.parametrize("window,heads", [(32, (4, 4)), (0, (8, 2)), (32, (8, 2))])
+def test_a_windowed_or_grouped_backward_runs_the_two_split_kernels(window, heads):
+    """A window or shared KV heads: the call's backward lowers to
+    ``pfx_flash_bwd_dq`` + ``pfx_flash_bwd_dkv``, at the 345M cell's sequence
+    and head size too, where a call with neither runs the single kernel."""
+    q, k, v, _ = _qkv(1024, *heads, 64)
+    text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, window=window)), argnums=(0, 1, 2))).lower(q, k, v).as_text(debug_info=True)
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text and "flash_bwd_fused" not in text
 
 
 # ---------------------------------------------------------------------------
