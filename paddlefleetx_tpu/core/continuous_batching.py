@@ -110,6 +110,7 @@ from paddlefleetx_tpu.core.tenancy import (
     normalize_tenant,
 )
 from paddlefleetx_tpu.utils.telemetry import (
+    StallWatch,
     StatsView,
     _env_int,
     get_registry,
@@ -512,6 +513,9 @@ class PagedDecodeEngine:
         # fed here at each commit and each donating dispatch, by the
         # scheduler at a flush and an admission, exported by its collect()
         self.gap_books = TokenGapBooks()
+        # prefill buckets dispatched since the scheduler last took the sum
+        # (_dispatch_donating adds, ContinuousScheduler._watch_stall takes)
+        self.prefill_bucket_sum = 0
 
     def _init_device_state(self) -> None:
         """Fresh arena + per-row device state (boot and every ArenaReset),
@@ -1020,6 +1024,9 @@ class PagedDecodeEngine:
             self._inflight["behind"] = True
         elif not self._warmup:
             self.gap_books.note("admission")
+        if not self._warmup:
+            # the scheduler names a slow iteration's kind by it
+            self.prefill_bucket_sum += span_args.get("bucket", 0)
         with ledger_span("pfx.sched.prefill", self.stats, "t_device_prefill",
                          what=what, **span_args):
             try:
@@ -2336,6 +2343,17 @@ class ContinuousScheduler:
             "stream_flush": 0.0, "idle": 0.0,
         }
         self._sched_wall_s = 0.0
+        # the slow-iteration watcher (telemetry.StallWatch): _iterate hands
+        # it every iteration's stamps and bucket seconds; one that ran far
+        # past its kind's median leaves a pfx.stall event
+        self._stall = StallWatch(
+            "sched.iterate",
+            ("device_decode", "device_prefill", "readback", "stream_flush",
+             "host_sched"),
+            device_wait=("readback",),
+        )
+        self._stall_carry = 0  # prefill buckets the next readback waits for
+        self._n_seated = 0  # units _iterate_inner picked this iteration
         self._t_device_free = 0.0  # _iterate_inner / _flush_engine stamp
         self._flushed = False  # _iterate_inner / _flush_engine, as above
         # Tokens: bank accounting over ADMITTED (committed) tokens.
@@ -2910,6 +2928,8 @@ class ContinuousScheduler:
             "closed": closed,
             "iterations": self._iter_counter,
             "decisions": decisions,
+            # slow iterations: count, excess seconds, the last 8 events
+            "stalls": self._stall.summary(),
             **self._debug_engine,
         }
 
@@ -2961,6 +2981,7 @@ class ContinuousScheduler:
         accounts its own duration, so idle + the iterate folds cover this
         thread's whole wall clock."""
         idle = ledger_span("pfx.sched.idle", self._time_ledger, "idle")
+        waited = False
         try:
             with idle, self._wake:
                 while (not self._entries and not self._admin_tasks
@@ -2968,10 +2989,14 @@ class ContinuousScheduler:
                     if self._closed:
                         return False  # drained
                     self._wake.wait()
+                    waited = True
                 self._busy_since = time.monotonic()
             return True
         finally:
             self._sched_wall_s += idle.seconds
+            if waited:
+                # the next iteration's CPU clocks start after the wait
+                self._stall.stamp()
 
     def _run(self) -> None:
         while self._park():
@@ -3045,42 +3070,161 @@ class ContinuousScheduler:
             if not e.future.done():
                 e.future.set_exception(exc)
 
+    def _decision_baselines(self) -> Dict[str, Any]:
+        """The counters a decision-log row is diffed against, read before
+        the iteration; :meth:`_iterate` takes them only while the trace
+        buffer is on."""
+        eng = self.engine
+        pfx = eng.cache.prefix.stats
+        spill = eng.cache.spill.stats
+        return {
+            "admit": int(self.stats["prefill_admits"]),
+            "shed": int(self.stats["shed_deadline"]),
+            "evict": int(self.stats["evictions"]),
+            "spec_p": int(eng.stats["spec_proposed"]),
+            "spec_a": int(eng.stats["spec_accepted"]),
+            "prefix_h": int(pfx["hits"]),
+            "prefix_t": int(pfx["hit_tokens"]),
+            "prefix_e": int(pfx["evictions"]),
+            "chunks": int(eng.stats["prefill_chunks"]),
+            "spill_s": int(spill["spills"]),
+            "spill_r": int(spill["readmits"]),
+            "spill_d": int(spill["discards"]),
+            "mig_a": int(eng.stats["migrate_adopted"]),
+            "blocks_free": eng.cache.allocator.free_count(),
+            "tadmit": dict(self._tenant_admitted),
+            "tpre": dict(self._tenant_preempted),
+            # the token columns are per-iteration deltas of the same
+            # dict the registry and /debug/state export
+            "tok": dict(self._tok_ledger),
+        }
+
+    def _decision_row(self, b: Dict[str, Any], n_finished: int) -> Dict[str, Any]:
+        """This iteration's row of the decision log, diffed against the
+        baselines ``b`` it began with."""
+        eng = self.engine
+        pfx = eng.cache.prefix.stats
+        spill = eng.cache.spill.stats
+        tok, tok0 = self._tok_ledger, b["tok"]
+        row = {
+            "iter": self._iter_counter,
+            "t": round(time.monotonic(), 6),
+            # baseline-diffed (like evicted/shed), NOT the inner
+            # return value: an exception escaping after some
+            # admits succeeded must still land them in this row
+            # or the replay contract breaks with no event lost
+            "admitted": int(self.stats["prefill_admits"]) - b["admit"],
+            "evicted": int(self.stats["evictions"]) - b["evict"],
+            "shed": int(self.stats["shed_deadline"]) - b["shed"],
+            # informational only (not a replayed counter): 0 when
+            # the step raised before resolving finishes
+            "finished": n_finished,
+            "active": eng.active_rows(),
+            "width_bucket": eng.table_width_bucket(),
+            "blocks_free": eng.cache.allocator.free_count(),
+            "blocks_delta":
+                eng.cache.allocator.free_count() - b["blocks_free"],
+            "spec_proposed": int(eng.stats["spec_proposed"]) - b["spec_p"],
+            "spec_accepted": int(eng.stats["spec_accepted"]) - b["spec_a"],
+            # prefix-reuse + chunked-prefill accounting: hits
+            # join the exact-replay contract (replay reproduces
+            # pfx_prefix_hits_total like the admit/evict trio)
+            "prefix_hits": int(pfx["hits"]) - b["prefix_h"],
+            "prefix_hit_tokens": int(pfx["hit_tokens"]) - b["prefix_t"],
+            "prefix_evictions": int(pfx["evictions"]) - b["prefix_e"],
+            "chunks": int(eng.stats["prefill_chunks"]) - b["chunks"],
+            # spill-tier + migration deltas: every site moves
+            # the store stats and registry counters together,
+            # so the replay fold reproduces pfx_prefix_spills/
+            # readmits/spill_discards and pfx_migrate_adopted
+            # exactly (the PR 8/12 contract extended)
+            "spills": int(spill["spills"]) - b["spill_s"],
+            "readmits": int(spill["readmits"]) - b["spill_r"],
+            "spill_discards": int(spill["discards"]) - b["spill_d"],
+            "migrate_adopted": int(eng.stats["migrate_adopted"]) - b["mig_a"],
+            # token-ledger columns (baseline-diffed like the
+            # trio): folding an untruncated log reproduces the
+            # pfx_token_ledger_total dispositions exactly
+            "tok_admitted": tok["admitted"] - tok0["admitted"],
+            "tok_delivered": tok["delivered"] - tok0["delivered"],
+            "tok_evicted_lost": tok["evicted_lost"] - tok0["evicted_lost"],
+            "tok_preempt_refunded":
+                tok["preempt_refunded"] - tok0["preempt_refunded"],
+            "tok_shed_after_admit":
+                tok["shed_after_admit"] - tok0["shed_after_admit"],
+        }
+        # multi-tenant columns (same baseline-diff discipline):
+        # per-tenant-label admitted/preempted row counts — the
+        # replay fold reproduces pfx_tenant_admitted_total and
+        # pfx_tenant_preemptions_total exactly from these
+        tenants_row = {
+            lab: n - b["tadmit"].get(lab, 0)
+            for lab, n in self._tenant_admitted.items()
+            if n - b["tadmit"].get(lab, 0)
+        }
+        preempted_row = {
+            lab: n - b["tpre"].get(lab, 0)
+            for lab, n in self._tenant_preempted.items()
+            if n - b["tpre"].get(lab, 0)
+        }
+        row["preempted"] = sum(preempted_row.values())
+        if tenants_row:
+            row["tenants"] = tenants_row
+        if preempted_row:
+            row["preempted_tenants"] = preempted_row
+        return row
+
+    def _watch_stall(self, wall, dd: float, dp: float, rb: float, sf: float,
+                     hs: float, n_finished: int) -> None:
+        """Hand the iteration that just ended to the slow-iteration
+        watcher (docs/observability.md "Slow iterations"), each against
+        its own kind: ``decode``; ``admit:<tokens>``, one that dispatched
+        prefills, by the sum of their buckets rounded up to a power of two
+        (``+<tokens>`` where it also waited for the one before's);
+        ``after_admit:<tokens>``, the one after it, whose readback waits
+        for them where the scheduler dispatches ahead.  So a 2,048-token
+        prefill is held against other prefills of 1,025 to 2,048 tokens
+        and not against a decode step, and a mix of many prompt buckets
+        still gives each kind enough observations to judge by."""
+        eng = self.engine
+        carried, self._stall_carry = self._stall_carry, 0
+        if dp > 0.0:
+            own = eng.prefill_bucket_sum and _pow2_at_least(eng.prefill_bucket_sum)
+            eng.prefill_bucket_sum = 0
+            if self.dispatch_ahead:
+                self._stall_carry = own
+            kind = f"admit:{own}+{carried}" if carried else f"admit:{own}"
+        elif carried:
+            kind = f"after_admit:{carried}"
+        else:
+            kind = "decode"
+        ev = self._stall.observe(kind, wall.t0, wall.t1, (dd, dp, rb, sf, hs))
+        if ev is not None:
+            self._stall.publish(
+                ev, iter=self._iter_counter, active=eng.active_rows(),
+                admitted=self._n_seated, finished=n_finished,
+                width_bucket=eng.table_width_bucket(),
+                waiting=len(self._entries),
+            )
+
     def _iterate(self) -> None:
+        eng = self.engine
         # per-iteration decision accounting (the decision log's row):
         # pre-iteration counter baselines diffed at the end, so every
         # SCHEDULER-side admit/evict/shed — including helper-raised
         # ones — lands in exactly one row.  (A handler-thread
         # try_remove shed can land between iterations: shed rows are
         # scheduler-side only, and shed is deliberately NOT part of the
-        # exact-replay trio.)
-        eng = self.engine
-        admit0 = int(self.stats["prefill_admits"])
-        shed0 = int(self.stats["shed_deadline"])
-        evict0 = int(self.stats["evictions"])
-        spec_p0 = int(eng.stats["spec_proposed"])
-        spec_a0 = int(eng.stats["spec_accepted"])
-        pfx = eng.cache.prefix.stats
-        pfx_h0 = int(pfx["hits"])
-        pfx_t0 = int(pfx["hit_tokens"])
-        pfx_e0 = int(pfx["evictions"])
-        chunks0 = int(eng.stats["prefill_chunks"])
-        spill = eng.cache.spill.stats
-        spill_s0 = int(spill["spills"])
-        spill_r0 = int(spill["readmits"])
-        spill_d0 = int(spill["discards"])
-        mig_a0 = int(eng.stats["migrate_adopted"])
-        blocks_free0 = eng.cache.allocator.free_count()
-        tadmit0 = dict(self._tenant_admitted)
-        tpre0 = dict(self._tenant_preempted)
+        # exact-replay trio.)  Taken only while the trace buffer is on:
+        # nothing else reads them
+        base = self._decision_baselines() if get_trace_buffer().enabled else None
         # goodput-ledger baselines: the iterate's wall duration is fully
         # attributed — engine per-phase deltas plus a host_sched
-        # residual — and the token columns are per-iteration deltas of
-        # the same dicts the registry and /debug/state export
+        # residual
         tdd0 = float(eng.stats["t_device_decode"])
         tdp0 = float(eng.stats["t_device_prefill"])
         trb0 = float(eng.stats["t_readback"])
         tsf0 = float(eng.stats["t_stream_flush"])
-        tok0 = dict(self._tok_ledger)
         n_finished = 0
         # the iterate's wall span; the engine's dispatch / readback /
         # flush spans nest inside it and its self time is host_sched
@@ -3103,7 +3247,8 @@ class ContinuousScheduler:
             led["device_prefill"] += dp
             led["readback"] += rb
             led["stream_flush"] += sf
-            led["host_sched"] += max(0.0, dur - (dd + dp + rb + sf))
+            hs = max(0.0, dur - (dd + dp + rb + sf))
+            led["host_sched"] += hs
             self._sched_wall_s += dur
             # per-tenant occupancy integrals: every live row held its
             # decode slot and KV blocks for this whole iteration
@@ -3116,82 +3261,9 @@ class ContinuousScheduler:
                     occ["slot_s"] += dur
                     occ["kv_block_s"] += len(r.table) * dur
             self._iter_counter += 1
-            if get_trace_buffer().enabled:
-                row = {
-                    "iter": self._iter_counter,
-                    "t": round(time.monotonic(), 6),
-                    # baseline-diffed (like evicted/shed), NOT the inner
-                    # return value: an exception escaping after some
-                    # admits succeeded must still land them in this row
-                    # or the replay contract breaks with no event lost
-                    "admitted": int(self.stats["prefill_admits"]) - admit0,
-                    "evicted": int(self.stats["evictions"]) - evict0,
-                    "shed": int(self.stats["shed_deadline"]) - shed0,
-                    # informational only (not a replayed counter): 0 when
-                    # the step raised before resolving finishes
-                    "finished": n_finished,
-                    "active": eng.active_rows(),
-                    "width_bucket": eng.table_width_bucket(),
-                    "blocks_free": eng.cache.allocator.free_count(),
-                    "blocks_delta":
-                        eng.cache.allocator.free_count() - blocks_free0,
-                    "spec_proposed":
-                        int(eng.stats["spec_proposed"]) - spec_p0,
-                    "spec_accepted":
-                        int(eng.stats["spec_accepted"]) - spec_a0,
-                    # prefix-reuse + chunked-prefill accounting: hits
-                    # join the exact-replay contract (replay reproduces
-                    # pfx_prefix_hits_total like the admit/evict trio)
-                    "prefix_hits": int(pfx["hits"]) - pfx_h0,
-                    "prefix_hit_tokens": int(pfx["hit_tokens"]) - pfx_t0,
-                    "prefix_evictions": int(pfx["evictions"]) - pfx_e0,
-                    "chunks": int(eng.stats["prefill_chunks"]) - chunks0,
-                    # spill-tier + migration deltas: every site moves
-                    # the store stats and registry counters together,
-                    # so the replay fold reproduces pfx_prefix_spills/
-                    # readmits/spill_discards and pfx_migrate_adopted
-                    # exactly (the PR 8/12 contract extended)
-                    "spills": int(spill["spills"]) - spill_s0,
-                    "readmits": int(spill["readmits"]) - spill_r0,
-                    "spill_discards": int(spill["discards"]) - spill_d0,
-                    "migrate_adopted":
-                        int(eng.stats["migrate_adopted"]) - mig_a0,
-                    # token-ledger columns (baseline-diffed like the
-                    # trio): folding an untruncated log reproduces the
-                    # pfx_token_ledger_total dispositions exactly
-                    "tok_admitted":
-                        self._tok_ledger["admitted"] - tok0["admitted"],
-                    "tok_delivered":
-                        self._tok_ledger["delivered"] - tok0["delivered"],
-                    "tok_evicted_lost":
-                        self._tok_ledger["evicted_lost"]
-                        - tok0["evicted_lost"],
-                    "tok_preempt_refunded":
-                        self._tok_ledger["preempt_refunded"]
-                        - tok0["preempt_refunded"],
-                    "tok_shed_after_admit":
-                        self._tok_ledger["shed_after_admit"]
-                        - tok0["shed_after_admit"],
-                }
-                # multi-tenant columns (same baseline-diff discipline):
-                # per-tenant-label admitted/preempted row counts — the
-                # replay fold reproduces pfx_tenant_admitted_total and
-                # pfx_tenant_preemptions_total exactly from these
-                tenants_row = {
-                    lab: n - tadmit0.get(lab, 0)
-                    for lab, n in self._tenant_admitted.items()
-                    if n - tadmit0.get(lab, 0)
-                }
-                preempted_row = {
-                    lab: n - tpre0.get(lab, 0)
-                    for lab, n in self._tenant_preempted.items()
-                    if n - tpre0.get(lab, 0)
-                }
-                row["preempted"] = sum(preempted_row.values())
-                if tenants_row:
-                    row["tenants"] = tenants_row
-                if preempted_row:
-                    row["preempted_tenants"] = preempted_row
+            self._watch_stall(wall, dd, dp, rb, sf, hs, n_finished)
+            if base is not None:
+                row = self._decision_row(base, n_finished)
                 with self._lock:
                     self.decision_log.append(row)
             if get_trace_buffer().enabled or self._debug_requested:
@@ -3448,6 +3520,7 @@ class ContinuousScheduler:
                     f"{type(exc).__name__}: {exc}"
                 )
 
+        self._n_seated = len(admitted)
         if t_admitted is not None:
             eng.gap_books.admit_host(t_admitted - self._t_device_free)
         if not self._has_live_rows():

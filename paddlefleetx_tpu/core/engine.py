@@ -228,6 +228,7 @@ class Engine:
         # per-device-kind peak (PFX_PEAK_FLOPS override); None for
         # non-GPT modules — no MFU column rather than a wrong one.
         from paddlefleetx_tpu.utils.telemetry import (
+            StallWatch,
             get_flight_recorder,
             get_registry,
             model_flops_per_token,
@@ -236,6 +237,15 @@ class Engine:
 
         self._registry = get_registry()
         self._recorder = get_flight_recorder()
+        # the slow-iteration watcher (docs/observability.md "Slow
+        # iterations"): the fit loop hands it every step's stamps and its
+        # own seconds by bucket; a step that ran far past the median of
+        # its kind leaves a pfx.stall event
+        self._stall = StallWatch(
+            "train.step",
+            ("data_wait", "put_dispatch", "log_fetch", "log_write", "other"),
+            device_wait=("log_fetch",), data_wait=("data_wait",),
+        )
         # trace windows over training steps: the config block's
         # (reference Profiler block, eager_engine.py:250-272 +
         # profiler.step :419) or one armed at run time with
@@ -1496,10 +1506,17 @@ class Engine:
         steps_in_window = 0
         data_iter = iter(train_loader)
         self.warm_start(data_iter)
+        self._stall.stamp()
         while True:
             with jax.profiler.StepTraceAnnotation(
                 "pfx.train.step", step_num=self._step + 1
             ):
+                # the slow-iteration watcher's view of this pass: its start,
+                # the ledger as it stood, and what beside a step it did
+                t_pass = time.monotonic()
+                led0 = (ledger["data_wait"], ledger["host"] + ledger["compile"],
+                        ledger["log_fetch"], ledger["log_write"])
+                kind = "step"
                 try:
                     with ledger_span("pfx.train.data_wait", ledger, "data_wait"):
                         batch = next(data_iter)
@@ -1583,7 +1600,10 @@ class Engine:
                 steps_in_window += 1
                 self._step += 1
                 step = self._step
+                tracing = self.profiler.active
                 self.profiler.step(step)
+                if self.profiler.active != tracing:
+                    kind = "step:profile"  # a trace started or was written
 
                 if step % self.logging_freq == 0:
                     # ONE host fetch: the step metrics plus any pending
@@ -1632,6 +1652,10 @@ class Engine:
                             "log_fetch_s": round(ledger["log_fetch"], 4),
                             "log_write_s": round(ledger["log_write"], 4),
                             "host_gap_s": round(host_gap_total, 4),
+                            # slow steps before this one (StallWatch): the
+                            # seconds they ran past their median, and how many
+                            "stall_s": round(self._stall.seconds, 4),
+                            "stall_events": self._stall.events,
                         }
                         if "extra" in metrics:
                             record.update(self.module.extra_record(metrics["extra"]))
@@ -1729,10 +1753,13 @@ class Engine:
                     t_last = time.time()
                     window_tokens = 0
                     steps_in_window = 0
+                    if self.logging_freq > 1:
+                        kind = "step:log"  # the fetch drains the steps queued
 
                 if self.consistency_check_freq and step % self.consistency_check_freq == 0:
                     from paddlefleetx_tpu.parallel.check import check_replica_consistency
 
+                    kind = "step:check"
                     fp = check_replica_consistency(self.state.params)
                     logger.info(f"consistency check OK @ step {step}: params fp {fp:#010x}")
                     t_last = time.time()
@@ -1744,6 +1771,7 @@ class Engine:
                     # on_empty="event": a finite eval stream exhausting mid-fit
                     # logs loudly + emits a structured event instead of either
                     # nan-poisoning silently or killing the training run
+                    kind = "step:eval"
                     with ledger_span("pfx.train.eval", ledger, "eval"):
                         self.evaluate(
                             eval_iter, iters=self.eval_iters, on_empty="event"
@@ -1754,6 +1782,7 @@ class Engine:
                     t_fetched = None  # not a bare log-to-dispatch gap
 
                 if self.save_steps and step % self.save_steps == 0:
+                    kind = "step:save"
                     self.save()
                     # a save landing while the guard sees a healthy stream is
                     # proof of recovery: the budget guards against rollback
@@ -1780,6 +1809,22 @@ class Engine:
                         self.wait_for_save()
                         self.preempted = True
                         break
+
+                # every pass against the others of its kind (a plain step; one
+                # that also drained a log window, checked, evaluated or saved)
+                t_end = time.monotonic()
+                dw = ledger["data_wait"] - led0[0]
+                pd = ledger["host"] + ledger["compile"] - led0[1]
+                lf = ledger["log_fetch"] - led0[2]
+                lw = ledger["log_write"] - led0[3]
+                ev = self._stall.observe(
+                    kind, t_pass, t_end,
+                    (dw, pd, lf, lw, max(0.0, t_end - t_pass - (dw + pd + lf + lw))),
+                )
+                if ev is not None:
+                    self._stall.publish(
+                        ev, step=step, consumed_samples=self._consumed_samples
+                    )
 
                 # fault injection: deliver a real SIGTERM to this process so
                 # the handler path itself is what the test exercises

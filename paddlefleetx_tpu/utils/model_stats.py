@@ -575,10 +575,12 @@ class CompileWatcher:
             self.events.append(event)
         try:
             from paddlefleetx_tpu.utils.telemetry import (
+                count_compile_event,
                 get_flight_recorder,
                 get_registry,
             )
 
+            count_compile_event()  # a slow iteration reads it (StallWatch)
             get_flight_recorder().record(dict(event))
             reg = get_registry()
             reg.counter("pfx_compile_events_total").inc()

@@ -359,6 +359,11 @@ class ProfilerHook:
         if self.enabled:
             self._window = (self.start_step, self.stop_step)
 
+    @property
+    def active(self) -> bool:
+        """True from the step that started a trace to the one that stops it."""
+        return self._active
+
     def arm(self, log_dir: Optional[str] = None, steps: int = 1, *,
             python_tracer: bool = True,
             summary: Optional[bool] = None) -> None:

@@ -35,9 +35,18 @@ them:
     ``pfx_bench/model_math.py``).
   - **FlightRecorder** — a bounded ring of recent structured events (step
     records, data_skip, rollback, preempt_save, gen_errors, watchdog
-    flips, request spans) dumped to ``flight_recorder.jsonl`` on crash,
-    force-quit, watchdog-degraded, and anomaly rollback — postmortems no
-    longer depend on having had ``Engine.metrics_file`` set.
+    flips, request spans, ``pfx.stall`` events) dumped to
+    ``flight_recorder.jsonl`` on crash, force-quit, watchdog-degraded, and
+    anomaly rollback — postmortems no longer depend on having had
+    ``Engine.metrics_file`` set.
+  - **StallWatch** — the slow-iteration watcher beside ``ledger_span``:
+    the trainer's fit loop and the serving scheduler hand it every
+    iteration's wall seconds and bucket seconds; one that ran far past
+    the median of its own kind leaves a ``pfx.stall`` event with what the
+    thread, the device wait and the machine did meanwhile
+    (docs/observability.md "Slow iterations").  Always on: two CPU-clock
+    reads and a comparison an iteration, everything else only when an
+    iteration is slow.
 
 Knobs (loud-parse, repo convention): ``PFX_PEAK_FLOPS`` (per-chip peak
 FLOP/s used as the MFU denominator; default per detected device kind),
@@ -59,9 +68,11 @@ the chip (``chip_smoke.py``'s parent) and ``tools/lint.py`` stay jax-free.
 from __future__ import annotations
 
 import bisect
+import gc
 import json
 import os
 import re
+import statistics
 import sys
 import threading
 import time
@@ -290,6 +301,11 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_ssm_row_steps_total": ("counter", "Serving: live (row, decode step) pairs x state-space layers: the state updates the traffic needed, which are the ones the kernel visits"),
     "pfx_ssm_slot_steps_total": ("counter", "Serving: batch slots x decode steps x state-space layers: the capacity the live pairs are a share of (the kernel skips the rest)"),
     "pfx_ssm_prefill_tokens_total": ("counter", "Serving: prompt tokens x state-space layers the chunked scan of the prefills computed"),
+    # slow iterations (StallWatch below; docs/observability.md "Slow
+    # iterations"): absent until an iteration is slow, so a sound run's
+    # share reads 0
+    "pfx_stall_events_total": ("counter", "Iterations that ran far past the median of their own kind (labels: where=train.step|sched.iterate, held=compile|gc|device_wait|data_wait|host_off_cpu|host_on_cpu); each left a pfx.stall event in the flight recorder and the log"),
+    "pfx_stall_seconds_total": ("counter", "Seconds the slow iterations ran PAST their kind's median (the excess, not the wall; labels as pfx_stall_events_total): over the loop's non-idle wall seconds the share of a window a stall took"),
     "pfx_token_ledger_total": ("counter", "Admitted-token dispositions (labels: disposition=admitted|delivered|evicted_lost|preempt_refunded|shed_after_admit)"),
     "pfx_token_ledger_in_flight": ("gauge", "Admitted tokens still on the books in live decode slots (the exact-closure remainder)"),
     "pfx_tenant_slot_seconds_total": ("counter", "Decode-slot occupancy in slot-seconds per tenant — billing-grade cost attribution (labels: tenant)"),
@@ -895,6 +911,255 @@ class ledger_span:
     @property
     def seconds(self) -> float:
         return self.t1 - self.t0
+
+
+# ---------------------------------------------------------------------------
+# slow iterations (docs/observability.md "Slow iterations")
+# ---------------------------------------------------------------------------
+
+# THE RULE's two constants: an iteration is slow when it ran past the
+# median of its kind's last STALL_WINDOW wall times by at least
+# max(STALL_MIN_EXCESS_S, STALL_MEDIAN_SHARE x median).  Settled on the
+# chip (PERF.md section 6, PR 53): no event in a sound window of the
+# cells, one for every injected 0.25 s.  At 0.1 s sound windows of the
+# mellum and dsv3 cells held two or three iterations of 0.115-0.181 s
+# more (collector pauses, a blocked dispatch, a long readback), and at a
+# share of 0.5 a 0.6 s train step would hide 0.25 s.
+STALL_MIN_EXCESS_S = 0.2
+STALL_MEDIAN_SHARE = 0.25
+STALL_WINDOW = 64
+# the median is recomputed every so many observations of a kind, and a
+# kind with fewer judges nothing (the warm-up's compiles come first)
+STALL_JUDGE_EVERY = 16
+# the machine's counters are read against a baseline at most this old
+STALL_BASELINE_S = 1.0
+STALL_HELD = ("compile", "gc", "device_wait", "data_wait",
+              "host_off_cpu", "host_on_cpu")
+
+_PROC = "/proc"  # tests point it at a directory that is not there
+
+# process-wide sums a slow iteration is read against: seconds and count of
+# garbage-collector pauses (one gc.callbacks hook, whatever thread
+# collects), and the compile watcher's events
+_gc_pauses = [0.0, 0, 0.0]  # seconds, collections, start of the one running
+_compile_events = [0]
+
+
+def _gc_hook(phase: str, info: Dict[str, Any]) -> None:
+    if phase == "start":
+        _gc_pauses[2] = time.perf_counter()
+    else:
+        _gc_pauses[0] += time.perf_counter() - _gc_pauses[2]
+        _gc_pauses[1] += 1
+
+
+def count_compile_event() -> None:
+    """``CompileWatcher`` calls this once an event, beside its counters."""
+    _compile_events[0] += 1
+
+
+def _machine_counters() -> Dict[str, float]:
+    """What the machine did to the calling thread so far, each key absent
+    where its source is: seconds the thread waited on a run queue, the
+    machine's steal and iowait seconds, the thread's involuntary context
+    switches and major faults."""
+    out: Dict[str, float] = {}
+    try:
+        with open(os.path.join(_PROC, "thread-self", "schedstat")) as f:
+            out["runq_wait_s"] = int(f.read().split()[1]) / 1e9
+    except (OSError, IndexError, ValueError):
+        pass
+    try:
+        with open(os.path.join(_PROC, "stat")) as f:
+            cpu = f.readline().split()
+        tick = float(os.sysconf("SC_CLK_TCK"))
+        out["iowait_s"] = int(cpu[5]) / tick
+        out["steal_s"] = int(cpu[8]) / tick
+    except (OSError, IndexError, ValueError):
+        pass
+    try:
+        import resource
+
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        out["invol_ctx_switches"] = ru.ru_nivcsw
+        out["major_faults"] = ru.ru_majflt
+    except (ImportError, AttributeError, OSError, ValueError):
+        pass
+    return out
+
+
+def stall_held(*, excess_s: float, compile_events: int, gc_s: float,
+               bucket: str, thread_cpu_grew_s: float,
+               device_wait: Tuple[str, ...] = (),
+               data_wait: Tuple[str, ...] = ()) -> str:
+    """ONE label for what held a slow iteration, first match wins:
+    ``compile`` (a compile event in the interval), ``gc`` (collector
+    pauses of at least half the excess), ``device_wait`` / ``data_wait``
+    (``bucket``, the one that grew most over the kind's last sound
+    iteration, blocks on the device / on the loader), ``host_off_cpu``
+    (a host bucket, and the thread's CPU clock grew by less than half the
+    excess: descheduled or blocked), ``host_on_cpu`` (the thread worked)."""
+    if compile_events > 0:
+        return "compile"
+    if gc_s >= 0.5 * excess_s:
+        return "gc"
+    if bucket in device_wait:
+        return "device_wait"
+    if bucket in data_wait:
+        return "data_wait"
+    if thread_cpu_grew_s < 0.5 * excess_s:
+        return "host_off_cpu"
+    return "host_on_cpu"
+
+
+class _StallKind:
+    """One kind's last wall times, their median and the limit it sets."""
+
+    __slots__ = ("walls", "n", "median", "limit", "ref")
+
+    def __init__(self) -> None:
+        self.walls: deque = deque(maxlen=STALL_WINDOW)
+        self.n = 0
+        self.median: Optional[float] = None
+        self.limit = 0.0
+        # thread CPU seconds and bucket seconds of the last sound one
+        self.ref: Tuple[float, Tuple[float, ...]] = (0.0, ())
+
+
+class StallWatch:
+    """The slow-iteration watcher of ONE loop on ONE thread (the fit loop,
+    the scheduler).  The loop hands :meth:`observe` every iteration's two
+    ``time.monotonic()`` stamps (the ones its ``ledger_span`` took) and
+    this iteration's own seconds by bucket; an iteration that ran past
+    its kind's median by ``max(STALL_MIN_EXCESS_S, STALL_MEDIAN_SHARE x
+    median)`` comes back as an event for the loop to complete with its
+    step number and counts and :meth:`publish`::
+
+        ev = watch.observe("decode", wall.t0, wall.t1, (dd, dp, rb, sf, hs))
+        if ev is not None:
+            watch.publish(ev, iter=n, active=rows)
+
+    A sound iteration costs the two CPU clocks, a deque append and a
+    comparison; the machine's counters are read when one is slow, against
+    a baseline refreshed at most once a second.  ``buckets`` names the
+    seconds in order; ``device_wait`` / ``data_wait`` say which of them
+    block on the device / on the loader (the rule of ``held``)."""
+
+    def __init__(self, where: str, buckets: Tuple[str, ...], *,
+                 device_wait: Tuple[str, ...] = (),
+                 data_wait: Tuple[str, ...] = ()) -> None:
+        if _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
+        self.where = where
+        self.buckets = tuple(buckets)
+        self.device_wait, self.data_wait = tuple(device_wait), tuple(data_wait)
+        self._kinds: Dict[str, _StallKind] = {}
+        self.events = 0
+        self.seconds = 0.0  # the events' excess, summed
+        self.last: deque = deque(maxlen=8)
+        self._base_t = float("-inf")  # the first sound iteration takes one
+        self._base: Dict[str, float] = {}
+        self.stamp()
+
+    def stamp(self) -> None:
+        """The next interval starts here: the loop's first iteration, or
+        the scheduler's first after a parked wait."""
+        self._cpu, self._proc = time.thread_time(), time.process_time()
+        # collector seconds, collections, compile events as they stood
+        self._seen = (_gc_pauses[0], _gc_pauses[1], _compile_events[0])
+
+    def observe(self, kind: str, t0: float, t1: float,
+                buckets: Tuple[float, ...]) -> Optional[Dict[str, Any]]:
+        cpu, proc = time.thread_time(), time.process_time()
+        thread_cpu_s, process_cpu_s = cpu - self._cpu, proc - self._proc
+        self._cpu, self._proc = cpu, proc
+        seen, self._seen = self._seen, (
+            _gc_pauses[0], _gc_pauses[1], _compile_events[0])
+        wall_s = t1 - t0
+        st = self._kinds.get(kind)
+        if st is None:
+            st = self._kinds[kind] = _StallKind()
+        st.walls.append(wall_s)
+        st.n += 1
+        if not st.n % STALL_JUDGE_EVERY:
+            st.median = statistics.median(st.walls)
+            st.limit = max(STALL_MIN_EXCESS_S, STALL_MEDIAN_SHARE * st.median)
+        if st.median is None or wall_s - st.median < st.limit:
+            st.ref = (thread_cpu_s, buckets)
+            if t1 - self._base_t >= STALL_BASELINE_S:
+                self._base_t, self._base = t1, _machine_counters()
+            return None
+        return self._slow(kind, st, t0, t1, thread_cpu_s, process_cpu_s,
+                          buckets, seen)
+
+    def _slow(self, kind: str, st: _StallKind, t0: float, t1: float,
+              thread_cpu_s: float, process_cpu_s: float,
+              buckets: Tuple[float, ...],
+              seen: Tuple[float, int, int]) -> Dict[str, Any]:
+        excess = (t1 - t0) - st.median
+        gc_s, gc_n, compiles = (now - was for now, was in zip(self._seen, seen))
+        # st.ref is set: a kind's first STALL_JUDGE_EVERY - 1 are sound
+        ref_cpu, ref_buckets = st.ref
+        grew = [b - r for b, r in zip(buckets, ref_buckets)]
+        bucket = self.buckets[grew.index(max(grew))]
+        ev: Dict[str, Any] = {
+            "event": "pfx.stall",
+            "where": self.where,
+            "kind": kind,
+            "held": stall_held(
+                excess_s=excess, compile_events=compiles, gc_s=gc_s,
+                bucket=bucket, thread_cpu_grew_s=thread_cpu_s - ref_cpu,
+                device_wait=self.device_wait, data_wait=self.data_wait),
+            "t0_monotonic_ns": int(t0 * 1e9),
+            "t1_monotonic_ns": int(t1 * 1e9),
+            # the wall clock at t1, as a trace start records both
+            "time_ns": time.time_ns() - (time.monotonic_ns() - int(t1 * 1e9)),
+            "wall_s": round(t1 - t0, 6),
+            "median_s": round(st.median, 6),
+            "excess_s": round(excess, 6),
+            "thread_cpu_s": round(thread_cpu_s, 6),
+            "process_cpu_s": round(process_cpu_s, 6),
+            "buckets": {n: round(v, 6) for n, v in zip(self.buckets, buckets)},
+            "grew_most": bucket,
+            "gc_s": round(gc_s, 6),
+            "gc_collections": gc_n,
+            "compile_events": compiles,
+        }
+        # the machine since the baseline (at most STALL_BASELINE_S before
+        # the iteration began): a superset of the interval, said beside it
+        now = _machine_counters()
+        machine = {k: round(now[k] - self._base[k], 6)
+                   for k in now if k in self._base}
+        machine["since_s"] = round(t1 - self._base_t, 6)
+        try:
+            machine["loadavg_1m"] = os.getloadavg()[0]
+        except OSError:
+            pass
+        ev["machine"] = machine
+        self._base_t, self._base = t1, now
+        return ev
+
+    def publish(self, ev: Dict[str, Any], **fields: Any) -> None:
+        """Complete the event with the loop's own fields (``iter`` /
+        ``step``: the number the open span carries; its counts) and send
+        it where events go: the flight recorder's ring, one
+        ``pfx.stall {json}`` log line, the two counter families, and this
+        watcher's own sums (the step records' ``stall_s`` /
+        ``stall_events``, ``/debug/state``'s ``stalls``)."""
+        ev.update(fields)
+        self.events += 1
+        self.seconds += ev["excess_s"]
+        self.last.append(ev)
+        get_flight_recorder().record(ev)
+        logger.warning("pfx.stall " + json.dumps(ev, default=str))
+        reg = get_registry()
+        labels = {"where": self.where, "held": ev["held"]}
+        reg.counter("pfx_stall_events_total", **labels).inc()
+        reg.counter("pfx_stall_seconds_total", **labels).inc(ev["excess_s"])
+
+    def summary(self) -> Dict[str, Any]:
+        return {"events": self.events, "seconds": round(self.seconds, 6),
+                "last": list(self.last)}
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,6 @@
 """Five serving cells through the benchmark's own rehearsal at
-``--trace 2``, with the token gap's books read beside the run.
+``--trace 2``, with the token gap's books read beside the run (and, since
+PR 53, the two train cells with the engine's shares listed).
 
 PR 35's books were refused on one ``--trace 2`` run of
 ``serve-dsv3-1of32-think`` that came back not ``correct``, and no tier-1
@@ -22,7 +23,8 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HELD = ("decode", "admission", "flush")
 BOOK_METRICS = ("sched.gap_admission_share", "sched.gap_flush_share",
-                "sched.admit_host_share")
+                "sched.admit_host_share", "sched.stall_share")
+TRAIN_METRICS = ("engine.stall_share", "engine.host_gap_share")
 
 
 @pytest.mark.parametrize("cell,seed", [
@@ -48,9 +50,27 @@ def test_a_serving_cell_rehearses_correct_with_its_books_closed(cell, seed):
         win["client_gap_mean_in_window_ms"], rel=0.25)
 
 
-def _rehearse(cell: str, seed: int) -> dict:
-    """One rehearsal of ``cell``, held to everything but the two clocks'
-    agreement -> its window's books."""
+@pytest.mark.parametrize("cell,seed", [
+    ("train-345m-1chip", 5_300_000_011),
+    ("train-trinity-mini-1of8", 5_300_000_023),
+])
+def test_a_train_cell_rehearses_with_the_engine_shares_listed(cell, seed):
+    """A train cell through the same tool (PR 53): its line carries the two
+    shares of the step records whose files wait for a ``benchmark`` issue,
+    and there are no books to print."""
+    line, lines = _run(cell, seed)
+    assert "train_tokens_per_s" in line["metrics"]
+    assert "engine.data_wait_share" in line["metrics"]
+    for name in TRAIN_METRICS:
+        # 0.0 where no step of the window ran far past the others; on a
+        # CPU that six workers share one may
+        assert 0.0 <= line["metrics"][name]["value"] <= 100.0, name
+    assert lines[-1].startswith('{"correct"')
+    assert not any(ln.startswith("gap_books:") for ln in lines)
+
+
+def _run(cell: str, seed: int):
+    """One rehearsal of ``cell`` -> its result line, and every line."""
     env = dict(os.environ)
     env.pop("PFX_FAULT", None)
     p = subprocess.run(
@@ -65,7 +85,15 @@ def _rehearse(cell: str, seed: int) -> dict:
     line = json.loads(next(ln for ln in reversed(lines) if ln.startswith('{"correct"')))
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
-    # the traced run's line carries the accepted metrics and the three new ones
+    return line, lines
+
+
+def _rehearse(cell: str, seed: int) -> dict:
+    """One rehearsal of ``cell``, held to everything but the two clocks'
+    agreement -> its window's books."""
+    line, lines = _run(cell, seed)
+    # the traced run's line carries the accepted metrics, the books' three
+    # and the slow iterations' share (0.0 in a sound window)
     assert "itl_mean_ms" in line["metrics"] and "sched.prefill_share" in line["metrics"]
     for name in BOOK_METRICS:
         assert 0.0 <= line["metrics"][name]["value"] <= 100.0, name

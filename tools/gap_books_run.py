@@ -1,19 +1,22 @@
 #!/usr/bin/env python3
 """One run of the benchmark with the token gap's books read beside it:
 
-    python3 tools/gap_books_run.py --workload <serving cell> --seed <n> \
+    python3 tools/gap_books_run.py --workload <cell> --seed <n> \
         --seconds <s> --trace <0|2> [--rehearse]
 
 This is ``pfx_bench/run.py``'s own ``main`` in this process (the same cell
 files, runner, load, checks and result line; like it, the process stays
 off jax), with two things held in memory only:
 
-* the cell's per-layer list gains the three metrics that read the books
-  (``sched.gap_admission_share``, ``sched.gap_flush_share``,
-  ``sched.admit_host_share``): their files are under
-  ``pfx_bench/layer_metrics/`` but a cell's list is a benchmark file that
+* the cell's per-layer list gains the metrics whose files wait under
+  ``pfx_bench/layer_metrics/`` (a cell's list is a benchmark file that
   only a ``benchmark`` issue edits, so a ``--trace 2`` line here carries
-  them and ``run.py``'s does not yet;
+  them and ``run.py``'s does not yet): a serving cell the three that read
+  the books (``sched.gap_admission_share``, ``sched.gap_flush_share``,
+  ``sched.admit_host_share``) and ``sched.stall_share`` (the slow
+  iterations' excess over the scheduler's non-idle wall: 0.0 in a sound
+  run), a train cell ``engine.stall_share`` and ``engine.host_gap_share``
+  (and nothing more: a train run has no books to print);
 * the runner's ``judge`` is watched, so that after the run the window's
   ``/metrics`` delta and the last, quiet scrape can be held against the
   client's own frames.
@@ -36,7 +39,8 @@ import common  # noqa: E402
 import run  # noqa: E402
 
 BOOK_METRICS = ("sched.gap_admission_share", "sched.gap_flush_share",
-                "sched.admit_host_share")
+                "sched.admit_host_share", "sched.stall_share")
+TRAIN_METRICS = ("engine.stall_share", "engine.host_gap_share")
 HELD = ("decode", "admission", "flush")
 PATHS = ("behind_step", "after_flush", "idle")
 GAPS = "pfx_sched_token_gaps_total"
@@ -113,8 +117,9 @@ def main(argv=None) -> int:
 
     def cell_with_books(name):
         cell = load_cell(name)
+        more = TRAIN_METRICS if "train_tokens_per_s" in cell["end_to_end"] else BOOK_METRICS
         cell["per_layer"] = list(cell["per_layer"]) + [
-            m for m in BOOK_METRICS if m not in cell["per_layer"]]
+            m for m in more if m not in cell["per_layer"]]
         seen["cell"] = cell
         return cell
 
@@ -136,6 +141,8 @@ def main(argv=None) -> int:
         rc = run.main(argv)
     finally:
         common.load_cell, run.load_module = load_cell, load_module
+    if "res" in seen and "train_tokens_per_s" in seen["cell"]["end_to_end"]:
+        return rc  # a train cell: its two shares are on the result line
     if "res" not in seen or "requests" not in seen["raw"]:
         common.say("gap_books: no judged serving run to read")
         return rc or 1
