@@ -343,7 +343,7 @@ def _decoder_layer(
     the model axis and GSPMD inserts the output projection's row-psum."""
     dtype = x.dtype
 
-    y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
+    y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"], ctx=ctx)
     attn = _in_dtype("attn", p["attn"], dtype)
     qkv = jnp.einsum("bsh,htnd->bstnd", y, attn["qkv_kernel"])
     qkv = qkv + attn["qkv_bias"][None, None]
@@ -356,7 +356,7 @@ def _decoder_layer(
     ) + attn["out_bias"]
     x = x + attn_out
 
-    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"])
+    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"], ctx=ctx)
     mp = _in_dtype("mlp", p["mlp"], dtype)
     y = y @ mp["fc_in_kernel"] + mp["fc_in_bias"]
     y = jax.nn.gelu(y, approximate=True)
@@ -467,7 +467,7 @@ def forward_cached(
 
         x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache.k, cache.v))
         out_cache = KVCache(ks, vs)
-    x = layer_norm(x, params["final_ln"]["scale"], params["final_ln"]["bias"])
+    x = layer_norm(x, params["final_ln"]["scale"], params["final_ln"]["bias"], ctx=ctx)
     logits = jnp.einsum("bsh,vh->bsv", x, word)
     return _constrain(ctx, logits, ("batch", None, "vocab")), out_cache
 
@@ -1668,7 +1668,7 @@ def paged_forward_step(
 
     layers = jnp.arange(pools.k.shape[0], dtype=jnp.int32)
     (x, pools), _ = jax.lax.scan(body, (x, pools), (params["layers"], layers))
-    x = layer_norm(x, params["final_ln"]["scale"], params["final_ln"]["bias"])
+    x = layer_norm(x, params["final_ln"]["scale"], params["final_ln"]["bias"], ctx=ctx)
     logits = jnp.einsum("bsh,vh->bsv", x, word)
     logits = _constrain(ctx, logits, ("batch", None, "vocab"))
     return logits.astype(jnp.float32), pools

@@ -23,6 +23,7 @@ full_attn / core_attn (reference single_model.py:320-405) map to
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -42,6 +43,7 @@ from paddlefleetx_tpu.models.common import (
 )
 from paddlefleetx_tpu.models.gpt.config import GPTConfig
 from paddlefleetx_tpu.ops.attention import attention
+from paddlefleetx_tpu.utils import device as _device
 
 
 @dataclasses.dataclass(frozen=True)
@@ -260,24 +262,78 @@ def gpt_logical_axes(cfg: GPTConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
+# LayerNorm alone on a v5e (PR 54: bfloat16 x, float32 scale and bias; 8 norms
+# chained in one jit, forward alone and forward + backward under a random
+# cotangent; ms a norm, every device op's self time from a trace of 5 chains.
+# ``kernel`` is ``ops/fused_layernorm.py`` as it stands: 1 MiB of x a grid
+# step, the backward recomputing mean / rstd.  In brackets the kernel as it
+# stood before PR 54: 256 rows a step, the forward writing mean / rstd as two
+# lane-padded ``(rows, 1)`` float32 columns for the backward to read):
+#
+#   width  rows    forward: composite  kernel  [before]    + backward: composite  kernel  [before]
+#   1024   4096             0.0246     0.0097  [0.0122]                0.0485     0.0322  [0.0330]
+#   1024   8192             0.0488     0.0188  [0.0236]                0.0997     0.0751  [0.1052]
+#   1024   16384            0.0973     0.0369  [0.0463]                0.2071     0.1814  [0.2852]
+#   1024   32768            0.2133     0.1257  [0.1807]                0.6205     0.4023  [0.6062]
+#   2048   4096             0.0536     0.0186  [0.0209]                0.0928     0.0746  [0.0961]
+#   2048   8192             0.1067     0.0364  [0.0408]                0.1967     0.1811  [0.2572]
+#   2048   16384            0.2312     0.1255  [0.1526]                0.6108     0.4019  [0.5472]
+#   2048   32768            0.5037     0.4092  [0.4643]                1.3886     1.0395  [1.1400]
+#
+# The kernel is ahead at all eight, forward and forward + backward (the one
+# before it was BEHIND the composite forward + backward at 8,192 and 16,384
+# rows, the 345M step's own shape; 256 rows a step with the recompute read
+# 0.0387 / 0.1891 at 16,384 x 1,024, 128 rows 0.0480 / 0.2083).  The table is
+# those eight points and nothing between or beyond them: a kernel's time
+# alone does not say what the step around it does (PERF.md section 7), so a
+# shape enters when somebody has timed it.  Of the eight, a cell trains
+# 16,384 x 1,024 alone.  The serving cells' shapes (8 to 64 rows a decode
+# step, one prompt of 512-1,024 rows a prefill, forward only, width 2,048)
+# are NOT entered: a Mosaic call breaks the fusion it sits in and no serving
+# cell resolves the difference; PERF.md section 7 has what they read alone.
+# A batched prefill of 4,096 rows at these widths (8 prompts of 512,
+# ``core/serving.GenerationServer``'s buckets) IS one of the eight, and takes
+# the kernel.  Every width here is whole lanes (a multiple of 128) and every
+# row count whole blocks of ``fused_layernorm._row_block``.
+_KERNEL_AHEAD = frozenset(
+    (rows, width, "bfloat16") for width in (1024, 2048) for rows in (4096, 8192, 16384, 32768))
+
+
+def _norm_schedule(rows: int, width: int, dtype, compiled: bool) -> str:
+    """What runs a LayerNorm over ``rows`` x ``width`` of ``dtype`` (the rows
+    of ONE shard under a mesh), from those static values: the one place it is
+    chosen.  ``kernel`` (``ops/fused_layernorm.py``) where Pallas kernels are
+    compiled (on the CPU the interpreter is no kernel) and the shape was
+    measured ahead (the table above); ``composite`` for everything else,
+    until somebody measures it."""
+    ahead = (rows, width, jnp.dtype(dtype).name) in _KERNEL_AHEAD
+    return "kernel" if compiled and ahead else "composite"
+
+
 def layer_norm(
     x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-5,
-    fused: bool = False, ctx: Optional[ShardingCtx] = None,
+    ctx: Optional[ShardingCtx] = None,
 ):
-    """LayerNorm over the last dim of x [b, s, h].  ``fused`` selects the
-    Pallas kernel (no model call site passes it: PR 45 measured it ahead on
-    the 345M cell, PERF.md section 6, and the PR that turns it on chooses it
-    here, from the block's norm); under a mesh (``ctx``) the kernel is
-    row-independent, so it runs inside ``shard_map`` over the batch and seq
-    axes."""
-    if fused:
+    """LayerNorm over the last dim of x [b, s, h], statistics in float32.
+    ``_norm_schedule`` chooses what runs it from the shapes; nothing else
+    does.  Under a mesh hand the ``ctx`` in: the rule then reads one shard's
+    rows, and the kernel (row-independent) runs inside ``shard_map`` over the
+    batch and seq axes, because a bare Mosaic kernel cannot be partitioned;
+    a call without one is one device's."""
+    act = ("batch", "seq", None)
+    shard = x.shape
+    if ctx is not None:
+        from paddlefleetx_tpu.parallel.sharding import kernel_shard_shape
+
+        shard = kernel_shard_shape(ctx.mesh, ctx.rules, x.shape, act)
+    if _norm_schedule(math.prod(shard[:-1]), shard[-1], x.dtype,
+                      not _device.pallas_interpret()) == "kernel":
         from paddlefleetx_tpu.ops.fused_layernorm import fused_layer_norm
 
         def kernel(x, scale, bias):
             return fused_layer_norm(x, scale, bias, eps=eps)
 
         if ctx is not None:
-            act = ("batch", "seq", "embed")
             kernel = ctx.shard_kernel(kernel, (act, (None,), (None,)), act)
         return kernel(x, scale, bias)
     dtype = x.dtype
@@ -295,10 +351,10 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * scale).astype(x.dtype)
 
 
-def _norm(x: jax.Array, p: Dict[str, Any], cfg: GPTConfig):
+def _norm(x: jax.Array, p: Dict[str, Any], cfg: GPTConfig, ctx: Optional[ShardingCtx] = None):
     if cfg.norm == "rmsnorm":
         return rms_norm(x, p["scale"], cfg.norm_eps)
-    return layer_norm(x, p["scale"], p["bias"], eps=cfg.norm_eps)
+    return layer_norm(x, p["scale"], p["bias"], eps=cfg.norm_eps, ctx=ctx)
 
 
 def rope(x: jax.Array, theta: float) -> jax.Array:
@@ -568,7 +624,7 @@ def _decoder_layer(
     k_attn, k_mlp = (jax.random.split(key) if key is not None else (None, None))
 
     def attn_part(p, x, k):
-        y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
+        y = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"], ctx=ctx)
         y = _constrain(ctx, y, ("batch", "seq", "embed"))
         return _attention_block(p["attn"], y, cfg, ctx, k, train)
 
@@ -578,7 +634,7 @@ def _decoder_layer(
     x = x + attn_part(p, x, k_attn)
     x = _constrain(ctx, x, ("batch", "seq", "embed"))
 
-    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"])
+    y = layer_norm(x, p["ln_2"]["scale"], p["ln_2"]["bias"], ctx=ctx)
     y, aux = _mlp_block(p["mlp"], y, cfg, ctx, k_mlp, train)
     x = x + y
     return _constrain(ctx, x, ("batch", "seq", "embed")), aux
@@ -803,7 +859,7 @@ def forward_hidden(
         if cfg.moe_dropless and expert_bias is None:
             expert_bias = init_extra(cfg)["expert_bias"]
         x, aux = _block_stack(params, x, cfg, ctx, expert_bias)
-    x = _norm(x, params["final_ln"], cfg)
+    x = _norm(x, params["final_ln"], cfg, ctx)
     return _constrain(ctx, x, ("batch", "seq", "embed")), aux
 
 
@@ -947,7 +1003,7 @@ def _pipeline_train_loss(
         return x_mb
 
     def head_fn(hparams, y_mb, mb, mbi):
-        y = layer_norm(y_mb, hparams["final_ln"]["scale"], hparams["final_ln"]["bias"])
+        y = layer_norm(y_mb, hparams["final_ln"]["scale"], hparams["final_ln"]["bias"], ctx=ctx)
         y = _constrain(ctx, y, ("batch", "seq", "embed"))
         word = hparams["word"].astype(y.dtype)
         logits = jnp.einsum("bsh,vh->bsv", y, word)

@@ -30,6 +30,7 @@ Logical axis vocabulary (model code uses ONLY these names):
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -232,6 +233,51 @@ def with_logical_constraint(x: jax.Array, logical_axes, rules, mesh: Mesh):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def _kernel_axes(mesh: Mesh, rules, in_logical, shapes) -> dict:
+    """logical name -> the mesh axes ``shard_kernel`` splits that dim along:
+    of the axes the rules give the name, in order, each that is larger than
+    1, not Manual already (an enclosing map's), not taken by an earlier name
+    and divides every dim carrying the name (``shard_map`` needs exact
+    splits)."""
+    from paddlefleetx_tpu.parallel import shard_map_compat
+
+    table = dict(rules)
+    ambient = shard_map_compat.current_manual_axes()
+    dims: dict = {}
+    for logical, shape in zip(in_logical, shapes):
+        for name, dim in zip(logical, shape):
+            if name is not None:
+                dims.setdefault(name, []).append(dim)
+    chosen: dict = {}
+    used: set = set()
+    for name, sizes in dims.items():
+        axes = table.get(name) or ()
+        take, prod = [], 1
+        for ax in (axes,) if isinstance(axes, str) else axes:
+            n = mesh.shape[ax]
+            if n > 1 and ax not in ambient and ax not in used and all(
+                d % (prod * n) == 0 for d in sizes
+            ):
+                take.append(ax)
+                prod *= n
+        used.update(take)
+        chosen[name] = tuple(take)
+    return chosen
+
+
+def kernel_shard_shape(mesh: Mesh, rules, shape, logical) -> Tuple[int, ...]:
+    """The shape of one shard of an argument of ``shape`` as ``shard_kernel``
+    would hand it to the kernel (that argument alone naming its dims): what
+    a rule that chooses a kernel from its shapes has to read under a mesh."""
+    if mesh.size == 1:
+        return tuple(shape)
+    chosen = _kernel_axes(mesh, rules, (logical,), (shape,))
+    return tuple(
+        dim // math.prod(mesh.shape[ax] for ax in chosen.get(name, ()))
+        for name, dim in zip(logical, shape)
+    )
+
+
 def shard_kernel(fn, mesh: Mesh, rules, in_logical, out_logical):
     """Run ``fn`` — a Pallas kernel — under a mesh: inside a ``shard_map``
     that is Manual over EVERY mesh axis, its arguments split along the axes
@@ -250,7 +296,7 @@ def shard_kernel(fn, mesh: Mesh, rules, in_logical, out_logical):
     ``in_logical`` / ``out_logical`` give each argument's / the result's
     logical axis names (result names must appear among the arguments').
     A mesh axis is named in the specs only where it divides every dim
-    carrying the logical name (``shard_map`` needs exact splits); along the
+    carrying the logical name (``_kernel_axes``); along the
     rest the argument is replicated at the boundary and every shard
     computes the same thing.  Inside an enclosing manual map (the 1F1B
     pipeline's ``stages``) the kernel map nests: built on the ambient
@@ -258,36 +304,15 @@ def shard_kernel(fn, mesh: Mesh, rules, in_logical, out_logical):
     the kernel runs bare."""
     from paddlefleetx_tpu.parallel import shard_map_compat
 
-    table = dict(rules)
-
     def call(*args):
         if mesh.size == 1:
             return fn(*args)
-        ambient = shard_map_compat.current_manual_axes()
-        dims: dict = {}
-        for logical, a in zip(in_logical, args):
-            for name, dim in zip(logical, a.shape):
-                if name is not None:
-                    dims.setdefault(name, []).append(dim)
-        missing = {n for n in out_logical if n is not None} - set(dims)
+        chosen = _kernel_axes(mesh, rules, in_logical, [a.shape for a in args])
+        missing = {n for n in out_logical if n is not None} - set(chosen)
         if missing:
             raise ValueError(
                 f"shard_kernel result axes {sorted(missing)} name no argument dim"
             )
-        chosen: dict = {}
-        used: set = set()
-        for name, sizes in dims.items():
-            axes = table.get(name) or ()
-            take, prod = [], 1
-            for ax in (axes,) if isinstance(axes, str) else axes:
-                n = mesh.shape[ax]
-                if n > 1 and ax not in ambient and ax not in used and all(
-                    d % (prod * n) == 0 for d in sizes
-                ):
-                    take.append(ax)
-                    prod *= n
-            used.update(take)
-            chosen[name] = tuple(take)
 
         def spec(logical):
             entries = [chosen[n] if n is not None else () for n in logical]
@@ -300,7 +325,7 @@ def shard_kernel(fn, mesh: Mesh, rules, in_logical, out_logical):
             _ambient_abstract_mesh() or mesh,
             in_specs=tuple(spec(l) for l in in_logical),
             out_specs=spec(out_logical),
-            manual_axes=set(mesh.axis_names) - ambient,
+            manual_axes=set(mesh.axis_names) - shard_map_compat.current_manual_axes(),
         )(*args)
 
     return call

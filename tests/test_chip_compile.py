@@ -336,19 +336,21 @@ def _mesh_ctx(topo, **degrees):
 def test_sharded_flash_and_fused_ln_compile(topo, degrees):
     """One sharded flash call (+ fused LN) on the 2x2 mesh: fwd + bwd with
     batch over ``data`` and heads over ``model``.  Bare, the compiler says
-    "Mosaic kernels cannot be automatically partitioned"."""
+    "Mosaic kernels cannot be automatically partitioned".  The norm's shape
+    is one of ``_norm_schedule``'s table on either mesh (a shard holds 4 or
+    2 of the 8 x 2,048 rows), so ``layer_norm`` takes the kernel."""
     from paddlefleetx_tpu.models.gpt.model import layer_norm
     from paddlefleetx_tpu.ops.attention import attention
 
     mesh, ctx = _mesh_ctx(topo, **degrees)
     act = NamedSharding(mesh, P(("data", "fsdp"), None, None))
-    x = _shapes(act, ((8, 1024, 1024), BF16))
+    x = _shapes(act, ((8, 2048, 1024), BF16))
     w_qkv = _shapes(NamedSharding(mesh, P(None, None, "model", None)),
                     ((1024, 3, HEADS, 64), BF16))
     w_ln = _shapes(NamedSharding(mesh, P()), ((1024,), jnp.float32))
 
     def loss(x, w_qkv, scale, bias):
-        y = layer_norm(x, scale, bias, fused=True, ctx=ctx)
+        y = layer_norm(x, scale, bias, ctx=ctx)
         qkv = jnp.einsum("bsh,htnd->bstnd", y, w_qkv)
         out = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], impl="flash", ctx=ctx)
         return jnp.sum(out.astype(jnp.float32))
@@ -356,6 +358,7 @@ def test_sharded_flash_and_fused_ln_compile(topo, degrees):
     c = _compile(jax.grad(loss, (0, 1, 2, 3)), x, w_qkv, w_ln, w_ln)
     text = c.as_text()
     assert "tpu_custom_call" in text
+    assert "pfx_ln_fwd" in text and "pfx_ln_bwd" in text  # noqa: E10 — kernel names
     # q/k/v reach the kernel as they were computed (batch- and heads-
     # sharded): nothing gathers them in front of it
     assert "all-gather" not in text
@@ -370,6 +373,43 @@ _SINGLE_YAML = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "configs", "gpt", "pretrain_gpt_345M_single.yaml",
 )
+
+
+@pytest.mark.parametrize("degrees,batch", [
+    pytest.param({"dp_degree": 2, "mp_degree": 2}, 16, id="dp2mp2"),
+    pytest.param({"mp_degree": 4}, 8, id="mp4"),
+])
+def test_generate_on_a_mesh_runs_a_batched_prefill_s_norms_in_the_kernel(topo, degrees, batch):
+    """Tensor-parallel serving (``generate(..., ctx=)``) at one of
+    ``GenerationServer``'s buckets: 8 prompts of 512 a shard are 4,096 rows x
+    1,024, one of ``_norm_schedule``'s eight, so the prefill's norms take the
+    kernel, and ``generation.py`` hands them its ``ctx``: the kernel sits in
+    ``shard_map`` (bare, the compiler refuses the program: "Mosaic kernels
+    cannot be automatically partitioned").  The decode steps' norms (8 rows)
+    stay the composite: the sites are the prefill's alone."""
+    from paddlefleetx_tpu.models.gpt import model as gpt
+    from paddlefleetx_tpu.models.gpt.generation import GenerationConfig, generate
+    from paddlefleetx_tpu.parallel.sharding import tree_logical_to_sharding
+
+    cfg = _gpt345m()
+    mesh, ctx = _mesh_ctx(topo, **degrees)
+    shardings = tree_logical_to_sharding(gpt.gpt_logical_axes(cfg), mesh, ctx.rules)
+    shapes = jax.eval_shape(lambda k: gpt.init(cfg, k), jax.random.key(0))
+    params = jax.tree.map(lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+                          shapes, shardings)
+    rows = NamedSharding(mesh, P(("data", "fsdp")))
+    ids = _shapes(NamedSharding(mesh, P(("data", "fsdp"), None)), ((batch, 512), jnp.int32))
+    lens = _shapes(rows, ((batch,), jnp.int32))
+    gen = GenerationConfig(max_dec_len=64, decode_strategy="greedy_search")
+    with mesh:
+        c = _compile(
+            lambda p, ids, lens: generate(p, ids, cfg, gen, prompt_lens=lens, ctx=ctx),
+            params, ids, lens,
+        )
+    text = c.as_text()
+    # ln_1 and ln_2 in the prefill's layer loop, final_ln behind it; forward only
+    assert {k: len(v) for k, v in _ln_sites(text).items()} == {"fwd": 3}
+    assert "bf16[4096,1024]" in text
 
 
 def _compile_train_step(topo, n_devices, overrides=(), yaml=None):
@@ -560,6 +600,94 @@ def test_345m_step_runs_the_flash_forward_once_a_layer(topo):
     assert held <= 18.8e9, held
 
 
+def _ln_sites(text):
+    """The compiled program's LayerNorm kernel sites, ``{"fwd": [op_name, ...],
+    "bwd": [...]}``, one entry a Mosaic call (jax names the instruction after
+    the kernel with what transformed it in front, ``jvp_pfx_ln_fwd_``, so the
+    ``op_name`` is read)."""
+    import re
+
+    sites = {}
+    for line in text.splitlines():
+        if " custom-call(" not in line:
+            continue
+        m = re.search(r'op_name="([^"]*\bpfx_ln_(fwd|bwd)\b[^"]*/pallas_call)"', line)
+        if m:
+            sites.setdefault(m.group(2), []).append(m.group(1))
+    return sites
+
+
+def _operand_ops(text, op_name_part):
+    """The opcodes that produce the operands of the Mosaic calls whose
+    ``op_name`` holds ``op_name_part``, looked up through what only re-views
+    or moves a value (``bitcast``, ``copy``, a tuple's element)."""
+    import re
+
+    defined = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = .*? ([\w\-]+)\(([^\n]*)", text, re.M)}
+
+    def producer(name):
+        op, rest = defined.get(name, ("?", ""))
+        while op in ("bitcast", "copy", "copy-start", "copy-done", "get-tuple-element"):
+            name = re.search(r"%[\w.\-]+", rest).group(0)
+            op, rest = defined.get(name, ("?", ""))
+        return op
+
+    ops = set()
+    for line in text.splitlines():
+        if " custom-call(" in line and op_name_part in line:
+            args = line.split(" custom-call(", 1)[1].split(")", 1)[0]
+            ops |= {producer(name) for name in re.findall(r"%[\w.\-]+", args)}
+    return ops
+
+
+def test_345m_step_runs_its_layernorms_in_the_kernel(topo):
+    """The committed 345M recipe: ``layer_norm``'s rule names the kernel for
+    16,384 rows x 1,024 in bfloat16 (``_norm_schedule``), so the compiled step
+    holds FIVE ``pfx_ln_fwd`` sites (``ln_1`` and ``ln_2`` in the forward loop
+    body, the two again in the backward loop's recompute, since "selective"
+    saves only ``qkv`` / ``attn_out`` / ``attn_lse``, and ``final_ln`` at the
+    head) and THREE ``pfx_ln_bwd`` sites (the two of the backward loop and
+    ``final_ln``'s).  The flash sites are what they were.
+
+    Memory: the compiler's peak reads 14,047,476,224 bytes where the
+    composite's read 14,014,113,280 (the peak is at the head: ``final_ln``'s
+    kernel writes its ``bf16[16384,1024]`` output, 33.5 MB, to a buffer of
+    its own where the composite's was fused into its neighbours; the VJP's
+    residuals are x and scale alone, so nothing lane-padded is held: with
+    the kernel that saved mean / rstd as two ``f32[16384,1]`` columns the
+    peak read 14,064,253,440), the held sum 18,671,142,400 against
+    18,706,032,128; both under the bounds
+    ``test_345m_step_runs_the_flash_forward_once_a_layer`` holds them to."""
+    c = _step_345m(topo)
+    text = c.as_text()
+    sites = _ln_sites(text)
+    assert {k: len(v) for k, v in sites.items()} == {"fwd": 5, "bwd": 3}
+    recomputed = [s for s in sites["fwd"] if "rematted_computation" in s]
+    in_loops = [s for s in sites["fwd"] if "/while/body/" in s]
+    assert (len(recomputed), len(in_loops)) == (2, 4)
+    assert sum("/while/body/" in s for s in sites["bwd"]) == 2
+    assert {k: len(v) for k, v in _flash_sites(text).items()} == {"fwd": 1, "bwd_fused": 1}
+    assert c.memory_analysis().peak_memory_in_bytes <= 14.1e9
+
+
+@pytest.mark.parametrize("name", ["trinity-mini.train_step", "gpt-1.3b.step", "gpt-1.3b.prefill"])
+def test_programs_off_the_norm_table_hold_no_layernorm_kernel(topo, name):
+    """``train-trinity-mini-1of8`` norms with ``rms_norm``; ``serve-1.3b-docs``
+    runs ``layer_norm`` at 8 rows a decode step and one prompt a prefill,
+    shapes ``_norm_schedule``'s table does not hold: the programs the cells
+    run (``tools/program_text.py`` lowers them as the cells shape them) name
+    no ``pfx_ln_*`` kernel, and the 345M step's does."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..", "tools"))
+    import program_text
+
+    root = os.path.dirname(program_text.HERE)
+    (_, program), = program_text.lowered(root, (name,))
+    assert "pfx_ln_" not in program.as_text()  # noqa: E10 — a kernel's name
+
+
 def test_trinity_step_still_recomputes_its_flash_forward(topo):
     """"full" recompute saves nothing, names or none: every one of the trinity
     step's backward sites keeps TWO forward sites, the forward pass's and the
@@ -598,31 +726,46 @@ def test_four_chip_step_with_dropout_takes_the_bit_generator(topo):
     drawn = set(re.findall(r"= (u32\[[\d,]+\])\S* rng-bit-generator\(", text))
     assert drawn == {"u32[16,1024,1024]"}, drawn  # the global batch, on every device
     assert "all-reduce" in text and _has_kernel(c)
+    # a shard's 8 x 1,024 rows are of ``_norm_schedule``'s table: the norms run
+    # the kernel inside ``shard_map``, on rows as they lie (nothing gathered)
+    assert {k: len(v) for k, v in _ln_sites(text).items()} == {"fwd": 5, "bwd": 3}
+    assert "bf16[8192,1024]" in text
+    assert "all-gather" not in _operand_ops(text, "pfx_ln_")  # noqa: E10 — kernel names
 
 
 @pytest.mark.slow  # 20-35 s of TPU compile each; run when a layout changes
 @pytest.mark.parametrize("overrides", [
-    pytest.param(("Distributed.dp_degree=4", 4, 4), id="dp4"),
-    pytest.param(("Distributed.dp_degree=2", "Distributed.mp_degree=2", 8, 8),
+    pytest.param(("Distributed.dp_degree=4", 4, 4, 4096), id="dp4"),
+    pytest.param(("Distributed.dp_degree=2", "Distributed.mp_degree=2", 8, 8, 8192),
                  id="dp2mp2"),
     pytest.param(("Distributed.dp_degree=2", "Distributed.mp_degree=2",
                   "Distributed.sequence_parallel=True",
-                  "Model.sequence_parallel=True", 8, 8), id="dp2mp2sp"),
+                  "Model.sequence_parallel=True", 8, 8, 4096), id="dp2mp2sp"),
     pytest.param(("Distributed.sharding.sharding_degree=4",
-                  "Distributed.sharding.sharding_stage=3", 4, 4), id="zero3x4"),
-    pytest.param(("Distributed.dp_degree=2", "Distributed.pp_degree=2", 8, 2),
+                  "Distributed.sharding.sharding_stage=3", 4, 4, 4096), id="zero3x4"),
+    pytest.param(("Distributed.dp_degree=2", "Distributed.pp_degree=2", 8, 2, 0),
                  id="dp2pp2"),
 ])
 def test_four_chip_train_step_compiles_with_kernel(topo, overrides):
     """The single-chip config's global batch of 16 spread over four chips:
-    ``overrides`` ends with the (local, micro) batch sizes of the layout."""
-    *overrides, local, micro = overrides
+    ``overrides`` ends with the (local, micro) batch sizes of the layout and
+    the rows of one shard's LayerNorm where ``_norm_schedule`` names the
+    kernel for them (0: a micro batch of the pipeline is 1,024 rows a shard,
+    off the table).  The kernel sits inside ``shard_map`` (bare, Mosaic
+    "cannot be automatically partitioned") and reads its rows as they lie:
+    no ``all-gather`` defines an operand of it."""
+    *overrides, local, micro, ln_rows = overrides
     overrides += [f"Global.local_batch_size={local}",
                   f"Global.micro_batch_size={micro}"]
     c = _compile_train_step(topo, 4, overrides)
     text = c.as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+    sites = {k: len(v) for k, v in _ln_sites(text).items()}
+    assert sites == ({"fwd": 5, "bwd": 3} if ln_rows else {})
+    if ln_rows:
+        assert f"bf16[{ln_rows},1024]" in text
+        assert "all-gather" not in _operand_ops(text, "pfx_ln_")  # noqa: E10 — kernel names
 
 
 def _docs_step_13b(topo, kv_dtype="bf16", t=1, donate=False):

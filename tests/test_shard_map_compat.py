@@ -182,12 +182,17 @@ def _kernel_ctx(mesh):
     pytest.param({"dp_degree": 2, "mp_degree": 2, "sep_degree": 2}, id="dp2mp2sep2"),
     pytest.param({"sharding_degree": 4, "mp_degree": 2}, id="fsdp4mp2"),
 ])
-def test_shard_kernel_matches_unsharded(devices8, degrees):
+def test_shard_kernel_matches_unsharded(devices8, degrees, monkeypatch):
     """Flash attention + fused LayerNorm through ``shard_kernel``: values
     AND grads equal the bare kernels' — incl. the LayerNorm scale/bias
-    cotangents, which every shard contributes a partial sum to."""
+    cotangents, which every shard contributes a partial sum to.
+    (``layer_norm``'s rule is held to the kernel: on the CPU it names the
+    composite.)"""
+    from paddlefleetx_tpu.models.gpt import model as gpt_model
     from paddlefleetx_tpu.models.gpt.model import layer_norm
     from paddlefleetx_tpu.ops.attention import attention
+
+    monkeypatch.setattr(gpt_model, "_norm_schedule", lambda *a: "kernel")
 
     mesh = _mesh(devices8, **degrees)
     ctx = _kernel_ctx(mesh)
@@ -198,7 +203,7 @@ def test_shard_kernel_matches_unsharded(devices8, degrees):
     bias = jnp.asarray(rng.normal(size=(32,)), jnp.float32)
 
     def loss(x, w, scale, bias, ctx):
-        y = layer_norm(x, scale, bias, fused=True, ctx=ctx)
+        y = layer_norm(x, scale, bias, ctx=ctx)
         qkv = jnp.einsum("bsh,htnd->bstnd", y, w)
         out = attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
                         impl="flash", ctx=ctx)

@@ -64,8 +64,9 @@ def digest(lowered) -> str:
     return hashlib.sha256(_BODY.sub("BODY", lowered.as_text()).encode()).hexdigest()[:20]
 
 
-def lower(root: str, names=PROGRAMS) -> dict:
-    """{program name: hash} of the tree at ``root`` (imported from there)."""
+def lowered(root: str, names=PROGRAMS):
+    """(program name, its ``jax.stages.Lowered``) for each of ``names``, of the
+    tree at ``root`` (imported from there)."""
     sys.path.insert(0, root)
     import jax
     import jax.numpy as jnp
@@ -147,11 +148,14 @@ def lower(root: str, names=PROGRAMS) -> dict:
                                       ("loss_mask", np.float32), ("position_ids", np.int64))}
             return engine._train_step.lower(engine.state, batch)
 
-    out = {}
     for name in names:
         config, what = name.rsplit(".", 1)
-        out[name] = digest(train_step(config) if what == "train_step" else serving(config, what))
-    return out
+        yield name, train_step(config) if what == "train_step" else serving(config, what)
+
+
+def lower(root: str, names=PROGRAMS) -> dict:
+    """{program name: hash} of the tree at ``root``."""
+    return {name: digest(program) for name, program in lowered(root, names)}
 
 
 def main(argv=None) -> int:
