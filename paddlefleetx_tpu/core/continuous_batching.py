@@ -487,6 +487,11 @@ class PagedDecodeEngine:
             # pairs x layers (what the state kernel visits), slots x
             # steps x layers (the capacity), prompt tokens x layers
             "ssm_row_steps": 0, "ssm_slot_steps": 0, "ssm_prefill_tokens": 0,
+            # a residual stream of several copies (hc_mult; warm-up
+            # excluded): tokens that went through the maps, once a forward
+            # (a prefill's real prompt tokens, a decode step's live rows;
+            # every token passes 2 sub-blocks x layers pairs of kernels)
+            "hc_tokens": 0,
         }
         # True only inside warmup(): warmup admits/steps are not traffic
         # and must not bump the traffic-facing registry counters (the
@@ -1149,6 +1154,8 @@ class PagedDecodeEngine:
             self.stats["prefill_tokens"] += plen
             if self.row_state and not self._warmup:
                 self.stats["ssm_prefill_tokens"] += plen * int(self.mcfg.ssm_layers)
+            if self.mcfg.hyper_connections and not self._warmup:
+                self.stats["hc_tokens"] += plen
             return slot
 
         # prefix-hit / chunked path: only the unmatched suffix
@@ -1934,6 +1941,8 @@ class PagedDecodeEngine:
         if self.row_state and not self._warmup:
             self.stats["ssm_row_steps"] += n_act * int(self.mcfg.ssm_layers)
             self.stats["ssm_slot_steps"] += self.capacity * int(self.mcfg.ssm_layers)
+        if self.mcfg.hyper_connections and not self._warmup:
+            self.stats["hc_tokens"] += n_act
         if self.mcfg.latent_attention:
             self.stats["grid_tokens"] += int(mla_tokens_computed(
                 (positions - ncommit)[was_active], self.block, fl["width_bucket"]).sum())
@@ -2522,6 +2531,8 @@ class ContinuousScheduler:
         if eng.ring_pages:
             out.append(("pfx_sched_decode_kv_window_tokens_total", {},
                         float(eng.stats["kv_window_tokens"])))
+        if eng.mcfg.hyper_connections:
+            out.append(("pfx_hc_tokens_total", {}, float(eng.stats["hc_tokens"])))
         if eng.row_state:
             for key, name in (
                 ("ssm_row_steps", "pfx_ssm_row_steps_total"),

@@ -156,6 +156,19 @@ class GPTConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # the residual path of the described block (docs/xing4.md): hc_mult > 1
+    # keeps that many copies of the residual stream a token, X [hc_mult,
+    # hidden], and every sub-block mixes them by maps computed from the
+    # stream itself (manifold-constrained hyper-connections): a read-in
+    # h_pre in (0, 1), a write-back h_post in (0, 2) and a doubly stochastic
+    # H_res from hc_sinkhorn_iters rounds of column-then-row normalisation
+    # (hc_eps in the denominators) of exp(clip(., -hc_res_clamp,
+    # hc_res_clamp)).  0 or 1 = the one stream, x + f(x).  Served only
+    # (``ops/hyper_connection.py``): the training forward refuses it by name
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
 
     # one sub-block a layer (docs/nemotron_h.md): a character a layer,
     # ``M`` a Mamba-2 mixer, ``*`` grouped-query attention (rotated under
@@ -201,7 +214,7 @@ class GPTConfig:
         for field in ("norm_eps", "rope_theta", "moe_route_scale", "moe_bias_update_rate",
                       "moe_bias_warm_start_rate", "rope_scaling_factor", "rope_beta_fast",
                       "rope_beta_slow", "rope_mscale", "rope_mscale_all_dim",
-                      "ssm_dt_min", "ssm_dt_max", "ssm_dt_floor"):
+                      "ssm_dt_min", "ssm_dt_max", "ssm_dt_floor", "hc_eps", "hc_res_clamp"):
             # YAML reads "1e-05" (an override's spelling of a float) as a string
             object.__setattr__(self, field, float(getattr(self, field)))
         if not self.attn_head_dim and self.hidden_size % self.num_attention_heads:
@@ -226,6 +239,17 @@ class GPTConfig:
             if self.hidden_dropout_prob or self.attention_probs_dropout_prob:
                 raise ValueError("only the GPT-2 block has dropout; set both "
                                  "dropout probabilities to 0")
+        if self.hyper_connections:
+            if self.classic_block or self.layer_pattern:
+                raise ValueError(
+                    "hc_mult (a residual stream of several copies) belongs to the described "
+                    "block with two sub-blocks a layer: neither the GPT-2 block nor a "
+                    "layer_pattern block has a forward that holds it")
+            if self.post_norms:
+                raise ValueError("hc_mult does not take post_norms: no reference holds them")
+            if self.hc_sinkhorn_iters < 1 or not self.hc_eps > 0 or not self.hc_res_clamp > 0:
+                raise ValueError("hc_mult needs hc_sinkhorn_iters >= 1, hc_eps > 0 and "
+                                 "hc_res_clamp > 0")
         if self.moe_bias_warm_start_steps and not (
                 self.moe_gate == "sigmoid" and self.moe_dropless
                 and self.moe_bias_warm_start_rate >= self.moe_bias_update_rate > 0):
@@ -330,6 +354,16 @@ class GPTConfig:
     @property
     def latent_attention(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def hyper_connections(self) -> bool:
+        """True where the residual stream is several copies mixed by maps."""
+        return self.hc_mult > 1
+
+    @property
+    def hc_maps(self) -> int:
+        """Numbers a sub-block's maps take a token: h_pre, h_post, H_res."""
+        return self.hc_mult * (2 + self.hc_mult)
 
     @property
     def cached_token(self) -> Tuple[Tuple[int, int], ...]:

@@ -303,7 +303,8 @@ def _with_routing_bias(params, cfg: GPTConfig):
 def check_servable(cfg: GPTConfig) -> None:
     """Which blocks the serving forwards know (docs/serving.md "What a block
     must provide"): the GPT-2 block, the described block with latent
-    attention (a dense SwiGLU or a dropless expert MLP), and a
+    attention (a dense SwiGLU or a dropless expert MLP; one residual stream or,
+    under ``hc_mult``, a stream of several copies mixed by maps), and a
     ``layer_pattern`` block (state-space, grouped-query attention, full or
     over a window, and expert layers, one sub-block a layer).  Anything else
     raises, naming the option that is in the way."""
@@ -1198,17 +1199,37 @@ def _block_mlp(p, m, cfg: GPTConfig, valid):
 
 
 def _block_layer_step(p, x, positions, valid, cfg: GPTConfig, attend):
-    """One layer of the described block over x [b, t, h] at ``positions``
+    """One layer of the described block over x [b, t, h] (under ``hc_mult``
+    the stream of copies [b, t, n, h]) at ``positions``
     [b, t]; ``valid`` [b, t] marks the tokens that are someone's.
     ``attend(attn params, q_nope, q_r, latent)`` writes the latents to its
     cache and attends -> (attention result [b, t, n, v], cache state)."""
     dtype = x.dtype
     attn = _in_dtype("attn", p["attn"], dtype)
+    if cfg.hyper_connections:
+        return _block_layer_step_streams(p, attn, x, positions, valid, cfg, attend)
     y = rms_norm(x, p["ln_1"]["scale"], cfg.norm_eps)
     out, state = attend(attn, *latent_projections(attn, y, positions, cfg))
     x = x + jnp.einsum("bsnd,ndh->bsh", out, attn["out_kernel"])
     f, stats = _block_mlp(p["mlp"], rms_norm(x, p["ln_2"]["scale"], cfg.norm_eps), cfg, valid)
     return x + f, state, stats
+
+
+def _block_layer_step_streams(p, attn, x, positions, valid, cfg: GPTConfig, attend):
+    """:func:`_block_layer_step` over a residual stream of ``hc_mult`` copies,
+    x [b, t, n, h] (docs/xing4.md): each sub-block reads the copies mixed by
+    its ``h_pre`` and leaves ``H_res X + h_post f``, the maps computed from
+    the stream (``ops/hyper_connection.py``); the sub-blocks themselves are
+    the one-stream layer's."""
+    from paddlefleetx_tpu.ops.hyper_connection import hc_post, hc_pre
+
+    u, maps = hc_pre(x, p["hc_attn"], cfg)
+    y = rms_norm(u, p["ln_1"]["scale"], cfg.norm_eps)
+    out, state = attend(attn, *latent_projections(attn, y, positions, cfg))
+    x = hc_post(x, jnp.einsum("bsnd,ndh->bsh", out, attn["out_kernel"]), maps, cfg)
+    u, maps = hc_pre(x, p["hc_mlp"], cfg)
+    f, stats = _block_mlp(p["mlp"], rms_norm(u, p["ln_2"]["scale"], cfg.norm_eps), cfg, valid)
+    return hc_post(x, f, maps, cfg), state, stats
 
 
 def _block_stack_step(params, x, state, cfg: GPTConfig, layer_fn):
@@ -1226,7 +1247,24 @@ def _block_stack_step(params, x, state, cfg: GPTConfig, layer_fn):
     return x, state, stats
 
 
+def _block_embed(params, tokens, cfg: GPTConfig):
+    """The way in of every block but the GPT-2 one: tokens [...] -> x [..., h]
+    in the compute dtype, or under ``hc_mult`` the stream [..., n, h] with the
+    token's row in every copy."""
+    word = _in_dtype("embeddings", params["embeddings"], jnp.dtype(cfg.dtype))["word"]
+    x = word[tokens]
+    if cfg.hyper_connections:
+        from paddlefleetx_tpu.ops.hyper_connection import hc_in
+
+        x = hc_in(x, cfg)
+    return x
+
+
 def _block_logits(params, x, cfg: GPTConfig):
+    if cfg.hyper_connections:  # the way out: the copies' sum
+        from paddlefleetx_tpu.ops.hyper_connection import hc_out
+
+        x = hc_out(x)
     x = rms_norm(x, params["final_ln"]["scale"], cfg.norm_eps)
     head = _in_dtype("head", params["head"], x.dtype)["kernel"]
     return jnp.einsum("bsh,vh->bsv", x, head).astype(jnp.float32)
@@ -1249,9 +1287,7 @@ def _block_paged_forward_step(params, tokens, pools, block_tables, positions, ac
             "the described block's decode step takes one token a row: a verify chunk "
             "(draft_k) or a prompt chunk (prefill_chunk) over latent pools is not written")
     tokens = tokens.reshape(-1)
-    dtype = jnp.dtype(cfg.dtype)
-    word = _in_dtype("embeddings", params["embeddings"], dtype)["word"]
-    x = word[tokens][:, None]  # [B, 1, h]
+    x = _block_embed(params, tokens, cfg)[:, None]  # [B, 1, h] (or [B, 1, n, h])
     pos, blk, off = _step_write_slots(block_tables, positions, active, pools.k.shape[4])
     kl = cfg.kv_lora_rank
     scale = latent_softmax_scale(cfg)
@@ -1289,9 +1325,7 @@ def _block_paged_prefill(params, prompt, prompt_len, pools, table_row, cfg: GPTC
     PB, bs = int(table_row.shape[0]), int(pools.k.shape[4])
     if PB * bs < P:
         raise ValueError(f"table_row covers {PB}x{bs}={PB * bs} slots < prompt bucket {P}")
-    dtype = jnp.dtype(cfg.dtype)
-    word = _in_dtype("embeddings", params["embeddings"], dtype)["word"]
-    x = word[prompt]
+    x = _block_embed(params, prompt, cfg)
     positions = jax.lax.iota(jnp.int32, P)[None]
     valid = positions < prompt_len
 
@@ -1519,8 +1553,7 @@ def expert_load(params, tokens: jax.Array, cfg: GPTConfig) -> jax.Array:
     cache -> the pairs each expert of each expert layer received,
     [expert layers, experts] int32 (held or not): what the routing bias's
     balance rule (``moe.next_expert_bias``) reads."""
-    dtype = jnp.dtype(cfg.dtype)
-    x = _in_dtype("embeddings", params["embeddings"], dtype)["word"][tokens]
+    x = _block_embed(params, tokens, cfg)
     if cfg.layer_pattern:
         from paddlefleetx_tpu.models.gpt.ssm import mixer_prefill
 

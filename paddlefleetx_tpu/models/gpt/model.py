@@ -130,6 +130,20 @@ def _block_layer_specs(cfg: GPTConfig, experts: bool) -> Dict[str, Any]:
     if cfg.post_norms:
         specs["post_attn_norm"] = _rms_specs(h)
         specs["post_mlp_norm"] = _rms_specs(h)
+    if cfg.hyper_connections:
+        # each sub-block's maps over the stream of hc_mult copies (docs/xing4.md;
+        # float32 in the served tree, like the routers): ``phi`` one ROW a
+        # number the maps take (h_pre, then h_post, then H_res row-major) over
+        # the flattened stream, ``alpha`` the three gates, ``bias`` a number each.
+        # Seeded: alpha 1 and a bias of spread 1, so that the maps move with
+        # the stream (the paper's initial values leave them static)
+        n, maps = cfg.hc_mult, cfg.hc_maps
+        for name in ("hc_attn", "hc_mlp"):
+            specs[name] = {
+                "phi": ParamSpec((maps, n * h), (None, None), w),
+                "alpha": ParamSpec((3,), (None,), ones_init()),
+                "bias": ParamSpec((maps,), (None,), normal_init(1.0)),
+            }
     return specs
 
 
@@ -700,6 +714,10 @@ def _block_stack(params, x, cfg: GPTConfig, ctx, expert_bias):
     or None without expert layers)."""
     if ctx is not None and ctx.pipeline is not None and ctx.pipeline.num_stages > 1:
         raise NotImplementedError("pipeline stages know the GPT-2 block only")
+    if cfg.hyper_connections:
+        raise NotImplementedError(
+            "hc_mult: the training forward keeps ONE residual stream; a stream of several "
+            "copies is served only (the maps have no backward pass yet, ROADMAP queue 2)")
     n_dense = cfg.leading_dense_layers
     n_rest = cfg.num_layers - n_dense
     period = cfg.global_attn_every or 1

@@ -288,6 +288,7 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "pfx_sched_decode_kv_tokens_total": ("counter", "Context tokens of the live rows summed over decode steps (what a step needed to read)"),
     "pfx_sched_decode_grid_tokens_total": ("counter", "KV tokens per head the paged kernel computed on, summed over decode steps: the context of each slot its grid visits rounded up to the kernel's grid step (the live slots alone: the grids of pfx_decode_paged, pfx_decode_window and pfx_decode_mla_paged follow the step's live list; grid steps past a row's context run nothing, and the latent kernel's work list has none of them)"),
     "pfx_sched_decode_kv_window_tokens_total": ("counter", "Tokens the window layers' calls attended, summed over decode steps: each live row's context capped at the window (a model with window layers; over pfx_sched_decode_kv_tokens_total: what the window leaves of the reading)"),
+    "pfx_hc_tokens_total": ("counter", "Serving a residual stream of several copies (hc_mult): tokens that went through the maps' kernels (pfx_hc_pre / pfx_hc_post), counted ONCE a forward whatever its sub-blocks: a prefill's real prompt tokens, a decode step's live rows (warm-up excluded); times 2 sub-blocks x layers: the pairs of calls' tokens"),
     "pfx_train_host_gap_seconds_total": ("counter", "Fit-loop seconds from a blocking log fetch returning to the next step's dispatch having returned (the device has nothing queued)"),
     "pfx_moe_pairs_total": ("counter", "Token-expert pairs the dropless expert layers routed, over all experts and layers"),
     "pfx_moe_pairs_held_total": ("counter", "Routed pairs that landed on experts this process holds"),

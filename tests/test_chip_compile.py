@@ -16,6 +16,7 @@ compiles (a TPU executable cannot be read back without a chip).
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -1361,3 +1362,89 @@ def test_prefill_of_the_two_kinds_of_attention_at_the_cell_s_one_bucket(topo):
     held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
     assert held <= m.alias_size_in_bytes < 1.01 * held
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.2e9, m.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# The described block over a residual stream of four copies (docs/xing4.md) at
+# the published widths of the benchmark's configuration xing4.0-29b-a4b: the
+# cell's decode step at both table widths and its one prefill bucket, each
+# with 24 calls of the maps' kernels (2 sub-blocks x 6 layers x pre and post)
+# ---------------------------------------------------------------------------
+
+
+def _xing4(topo):
+    import json
+
+    from paddlefleetx_tpu.models.gpt import generation as G
+    from paddlefleetx_tpu.models.gpt.config import GPTConfig
+
+    root = os.path.join(os.path.dirname(_SINGLE_YAML), "..", "..")
+    bench = os.path.join(root, "pfx_bench")  # noqa: E10 — a directory, not a metric
+    with open(os.path.join(bench, "configs", "xing4.0-29b-a4b.json")) as f:
+        cfg = GPTConfig(**json.load(f)["model"])
+    slots, blocks = 64, 64 * 20 + 1  # a row reserves at most 2,560 tokens: 20 pages of 128
+    one = _one_chip(topo)
+    params = _shapes(one, jax.eval_shape(lambda: G.init_serving_params(cfg, jax.random.key(0))))
+    pools = _shapes(one, jax.eval_shape(
+        lambda: G.init_paged_pools(cfg, blocks, cfg.kv_block_default, slots=slots)))
+    return cfg, one, params, pools, slots, blocks
+
+
+def _hc_calls(text):
+    import re
+
+    return {k: len(re.findall(rf"%pfx_hc_{k}\S* = ", text)) for k in ("pre", "post")}
+
+
+@pytest.mark.parametrize("width", [16, 32])
+def test_decode_step_of_the_four_copy_stream_runs_24_maps_kernels(topo, width):
+    """The benchmark cell ``serve-xing4-29b-6of40-rag``'s decode step as its
+    configuration file states it (6 layers with all 64 experts, 64 slots,
+    1,281 latent pages, the whole 131,072-row vocabulary), the pools DONATED,
+    at both table widths the cell's rows make: 12 ``pfx_hc_pre`` and 12
+    ``pfx_hc_post`` calls, the latent kernel once a layer, the arena aliased,
+    arguments and scratch under 13.5 GB."""
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    cfg, one, params, pools, slots, blocks = _xing4(topo)
+    vocab = cfg.vocab_size
+    gen = G.GenerationConfig(decode_strategy="greedy_search", max_dec_len=0, min_dec_len=512,
+                             eos_token_id=0, pad_token_id=0)
+
+    def step(p, pools, tables, logits, counts, positions, gen_steps, max_news, active, forced):
+        rows = G.PagedRows(logits, counts, positions, gen_steps, max_news, active, forced)
+        nxt, pools, new = G.decode_step(p, pools, tables, rows, cfg, gen)
+        return nxt, pools, new.logits, new.counts, new.moe
+
+    i32 = lambda *shape: (shape, jnp.int32)  # noqa: E731
+    rows = _shapes(one, (i32(slots, width), ((slots, vocab), jnp.float32), i32(slots, vocab),
+                         i32(slots), i32(slots), i32(slots), ((slots,), jnp.bool_), i32(slots)))
+    c = jax.jit(step, donate_argnums=(1,)).lower(params, pools, *rows).compile()
+    text = c.as_text()
+    assert _hc_calls(text) == {"pre": 12, "post": 12}
+    assert len(re.findall(r"%pfx_decode_mla_paged\S* = ", text)) == 6
+    m = c.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pools))
+    assert held == 6 * blocks * 576 * 128 * 2 and held <= m.alias_size_in_bytes < 1.01 * held
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.5e9, m.temp_size_in_bytes
+
+
+def test_prefill_of_the_four_copy_stream_at_the_cell_s_one_bucket(topo):
+    """The cell's ONE prefill bucket (2,048 tokens, 16 pages): 24 calls of the
+    maps' kernels over ``[2048, 4 x 3584]``, the five expert layers' products
+    over 64 groups in ``pfx_grouped_matmul`` (15 = 5 x 3), arguments and
+    scratch under 13.5 GB."""
+    from paddlefleetx_tpu.models.gpt import generation as G
+
+    cfg, one, params, pools, _, _ = _xing4(topo)
+    i32 = lambda *shape: (shape, jnp.int32)  # noqa: E731
+    prompt, plen, row = _shapes(one, (i32(1, 2048), i32(), i32(16)))
+    c = jax.jit(lambda p, prompt, plen, pools, row: G.paged_prefill(
+        p, prompt, plen, pools, row, cfg, return_moe=True), donate_argnums=(3,)).lower(
+        params, prompt, plen, pools, row).compile()
+    text = c.as_text()
+    assert _hc_calls(text) == {"pre": 12, "post": 12}
+    assert len(re.findall(r"%pfx_grouped_matmul\S* = ", text)) == 15
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    m = c.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13.5e9, m.temp_size_in_bytes

@@ -50,6 +50,7 @@ def test_selftest_check(selftest, name, monkeypatch):
     ("think-long-answers", "serve-dsv3-1of32-think", 512, [1024, 1536, 2048, 2560, 3072], 0.8),
     ("chat-short-answers", "serve-nemotron3-nano-1of8-chat", 256, [256, 512, 768, 1024], 0.8),
     ("chat-brief-turns", "serve-falcon-h1-34b-6of72-chat", 256, [256], 0.8),  # ONE bucket, one stall size
+    ("context-chat-answers", "serve-xing4-29b-6of40-rag", 2048, [2048], 0.8),  # ONE bucket again
 ])
 def test_a_serving_cell_s_data_files(selftest, mix, cell, pad, buckets, of_knee):
     """A cell added after the self-test's own list of mixes: its traffic
@@ -87,3 +88,23 @@ def test_a_serving_cell_s_data_files(selftest, mix, cell, pad, buckets, of_knee)
         assert os.path.isfile(os.path.join(selftest.ROOT, conf[key])), key
     ctx = max(hi + t["max_tokens"]["max"], 0)
     assert ctx <= conf["model"]["max_position_embeddings"]  # no request outgrows the context
+
+
+@pytest.mark.parametrize("kind", ["command", "configs", "workloads", "end_to_end", "per_layer"])
+def test_a_benchmark_entry_s_lines_fit(kind):
+    """The contract's limit on every free line of ``BENCHMARK.json`` (a
+    ``why``, a ``layer``, a configuration's ``source``, a word of
+    ``command``): 1 to 200 printable characters on one line.  The self-test
+    holds only a cell's ``why`` to it; PR 55 was refused once over a
+    configuration's."""
+    import json
+    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+        bench = json.load(f)
+    if kind == "command":
+        lines = {"command": bench["command"]}
+    else:
+        keys = ("why", "layer") + (("source",) if kind == "configs" else ())
+        lines = {e["name"]: [e[k] for k in keys if k in e] for e in bench[kind]}
+    bad = {n: len(t) for n, ts in lines.items() for t in ts
+           if not (1 <= len(t) <= 200 and t.isprintable())}
+    assert not bad

@@ -43,20 +43,23 @@ BENCH = "pfx_bench"  # noqa: E10 — a directory, not a metric
 # configuration -> (batch slots, arena pages, prefill bucket, min_dec_len): one decode step
 # and one prefill program each, shaped like the cell's (falcon-h1-34b's were written by the PR
 # that added it, 40; the four before it from PR 38's commit, and PR 40 left them as they were;
-# mellum2-12b-a2.5b's and the 345M train step from PR 44's commit, by PR 45)
+# mellum2-12b-a2.5b's and the 345M train step from PR 44's commit, by PR 45; xing4.0-29b-a4b's by
+# the PR that added it, 55, which left every other as it was)
 SERVING = {
     "gpt-1.3b": (8, 8 * 8 + 1, 512, 32),
     "deepseek-v3": (64, 64 * 36 + 1, 1024, 1536),
     "nemotron-3-nano": (48, 48 * 14 + 1, 256, 768),
     "falcon-h1-34b": (64, 64 * 6 + 1, 256, 512),
     "mellum2-12b-a2.5b": (48, 48 * 22 + 1, 2048, 768),
+    "xing4.0-29b-a4b": (64, 64 * 20 + 1, 2048, 512),
 }
 RING = 9  # pages a row in a window layer's ring (mellum2-12b-a2.5b: window 1,024 over pages of 128)
 # configuration -> the batch of its training cell (the sequence length is the recipe's own)
 TRAINING = {"trinity-mini": 2, "gpt-345m": 16}
-# the order of tests/program_text.json: PR 40's nine, then PR 45's three
+# the order of tests/program_text.json: PR 40's nine, then PR 45's three, then PR 55's two
 PROGRAMS = (tuple(f"{c}.{p}" for c in list(SERVING)[:4] for p in ("step", "prefill")) + ("trinity-mini.train_step",)
-            + tuple(f"mellum2-12b-a2.5b.{p}" for p in ("step", "prefill")) + ("gpt-345m.train_step",))
+            + tuple(f"mellum2-12b-a2.5b.{p}" for p in ("step", "prefill")) + ("gpt-345m.train_step",)
+            + tuple(f"xing4.0-29b-a4b.{p}" for p in ("step", "prefill")))
 _BODY = re.compile(r'\\22body\\22: \\22[^\\]*\\22')
 
 
