@@ -60,7 +60,7 @@ TINY_MODEL = [
 
 def shape_of(rehearse: bool) -> dict:
     if rehearse:
-        return {"vocab": 512, "seq": 128, "batch": 4, "heads": 4,
+        return {"vocab": 512, "seq": 128, "batch": 4, "heads": 8,
                 "head_dims": (16,), "hidden": 64, "overrides": TINY_MODEL + [
                     "Data.Train.dataset.max_seq_len=128",
                     "Global.global_batch_size=4", "Global.local_batch_size=4",
@@ -527,6 +527,15 @@ def phase_kernels(args) -> int:
                 check(f"flash_fwd_{tag}", jax.jit(flash)(q, k, v), ref_out, truth_out)
             check(f"flash_bwd_{bwd}_{tag}",
                   jax.jit(jax.grad(functools.partial(weighted, flash), (0, 1, 2)))(q, k, v),
+                  ref_grads)
+        if 128 % d == 0 and (n * d) % 128 == 0:
+            # ---- the same in the model's layout (whole heads a 128-lane block) ----
+            def flash_bsh(q, k, v):
+                return _flash_bsnd(q, k, v, scale, tile, "fused", 0, "bsh")
+
+            check(f"flash_fwd_bsh_{tag}", jax.jit(flash_bsh)(q, k, v), ref_out, truth_out)
+            check(f"flash_bwd_fused_bsh_{tag}",
+                  jax.jit(jax.grad(functools.partial(weighted, flash_bsh), (0, 1, 2)))(q, k, v),
                   ref_grads)
 
         # ---- contiguous decode: t=1, spec chunk, prefill-sized t ----

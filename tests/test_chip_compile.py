@@ -109,6 +109,43 @@ def test_flash_fwd_bwd_compiles(topo, d, bwd):
     assert sites == {"dq": bwd == "split", "dkv": bwd == "split", "fused": bwd == "fused"}
 
 
+def _kernel_calls(text):
+    """flash kernel name -> the dims of its Mosaic call's results and operands
+    in a compiled text (an instruction is named after its kernel, with what
+    differentiation put around it)."""
+    calls = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S*?(pfx_flash_(?:fwd|bwd_fused|bwd_dq|bwd_dkv)(?:_bsh)?)[_.\d]* = (.*?) custom-call\(", line)
+        if m and 'custom_call_target="tpu_custom_call"' in line:
+            operands = re.search(r"operand_layout_constraints=\{(.*?\})\}", line).group(1)
+            calls.setdefault(m.group(1), []).extend(
+                re.findall(r"\w+\[([\d,]+)\]", m.group(2) + " " + operands))
+    return calls
+
+
+@pytest.mark.parametrize("seq", [512, 1024, 2048, 4096])
+def test_flash_in_the_model_s_layout_compiles(topo, seq):
+    """Every point ``_operand_layout`` enters: forward and fused backward on
+    [batch, seq, heads*64], through the public call, which reads the layout
+    and the schedule from these shapes.  The new calls are in the program,
+    the transposed path's are not, and nothing a Mosaic call takes or gives
+    has a minor dimension of 1 or 64 (a lane-1 column or a 64-wide head,
+    either padded to 128 lanes wherever it is kept)."""
+    from paddlefleetx_tpu.ops.flash_attention import _operand_layout, flash_attention
+
+    assert _operand_layout(seq, HEADS, 64, 0, 1, BF16) == "bsh"
+    q = _shapes(_one_chip(topo), ((16 if seq == 1024 else 2, seq, HEADS, 64), BF16))  # 1,024: the 345M cell's
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v).astype(jnp.float32))
+
+    calls = _kernel_calls(_compile(jax.grad(loss, (0, 1, 2)), q, q, q).as_text())
+    assert sorted(calls) == ["pfx_flash_bwd_fused_bsh", "pfx_flash_fwd_bsh"]  # noqa: E10 — kernel names
+    for name, shapes in calls.items():
+        assert len(shapes) == (9 if "bwd" in name else 5), (name, shapes)  # results + operands
+        assert all(int(dims.split(",")[-1]) % 128 == 0 for dims in shapes), (name, shapes)
+
+
 @pytest.mark.parametrize("hidden", [pytest.param(1024, id="345M"),
                                     pytest.param(2048, id="1.3B")])
 def test_fused_layernorm_fwd_bwd_compiles(topo, hidden):
@@ -552,7 +589,7 @@ def test_345m_step_draws_its_dropout_masks_with_the_bit_generator(topo):
 
 def _flash_sites(text):
     """The compiled step's flash kernel sites by the kernel's name less its
-    ``pfx_flash_``: ``{"fwd": [op_name, ...], "bwd_dq": ..., "bwd_dkv": ...}``,
+    ``pfx_flash_``: ``{"fwd": [op_name, ...], "bwd_dq": ..., "fwd_bsh": ...}``,
     one entry an HLO instruction (a site in a loop body runs once a layer)."""
     import re
 
@@ -587,14 +624,22 @@ def test_345m_step_runs_the_flash_forward_once_a_layer(topo):
     17,095,950,336 here: it counts more than the device holds at once, so it
     is stated and not compared with the device's size.)  With the single
     backward kernel (PR 52) the peak reads the same 14,014,113,280 (it is at
-    the head, not in the layer loop) and the sum 18,706,032,128."""
+    the head, not in the layer loop) and the sum 18,706,032,128.
+
+    Since PR 56 both sites are the kernels in the MODEL's layout
+    (``_operand_layout``: head 64, seq 1,024, bfloat16, no window, equal head
+    counts): ``pfx_flash_fwd_bsh`` and ``pfx_flash_bwd_fused_bsh`` on
+    ``bf16[16,1024,1024]``, lse and delta ``f32[16,8,2,1024]``; nothing in the
+    step has the kernels' old ``[256, 1024, 64]`` or a ``[256, 1024, 1]``
+    column.  The peak reads 14,047,475,200 on this tree and on its parent."""
     c = _step_345m(topo)
     text = c.as_text()
     sites = _flash_sites(text)
-    assert {k: len(v) for k, v in sites.items()} == {"fwd": 1, "bwd_fused": 1}
-    assert "rematted_computation" not in sites["fwd"][0]
+    assert {k: len(v) for k, v in sites.items()} == {"fwd_bsh": 1, "bwd_fused_bsh": 1}
+    assert "rematted_computation" not in sites["fwd_bsh"][0]
     assert "bf16[24,256,1024,64]" not in text  # no stack of the padded layout
-    assert "f32[256,1024,64]" not in text  # dq leaves the kernel in q's dtype
+    assert "[256,1024,64]" not in text and "f32[256,1024,1]" not in text  # nor the layout itself
+    assert "f32[16,8,2,1024]" in text  # the statistics with the sequence in the lanes
     m = c.memory_analysis()
     assert m.peak_memory_in_bytes <= 14.1e9, m.peak_memory_in_bytes
     held = m.argument_size_in_bytes + m.temp_size_in_bytes + m.generated_code_size_in_bytes
@@ -668,7 +713,7 @@ def test_345m_step_runs_its_layernorms_in_the_kernel(topo):
     in_loops = [s for s in sites["fwd"] if "/while/body/" in s]
     assert (len(recomputed), len(in_loops)) == (2, 4)
     assert sum("/while/body/" in s for s in sites["bwd"]) == 2
-    assert {k: len(v) for k, v in _flash_sites(text).items()} == {"fwd": 1, "bwd_fused": 1}
+    assert {k: len(v) for k, v in _flash_sites(text).items()} == {"fwd_bsh": 1, "bwd_fused_bsh": 1}
     assert c.memory_analysis().peak_memory_in_bytes <= 14.1e9
 
 
@@ -767,6 +812,9 @@ def test_four_chip_train_step_compiles_with_kernel(topo, overrides):
     if ln_rows:
         assert f"bf16[{ln_rows},1024]" in text
         assert "all-gather" not in _operand_ops(text, "pfx_ln_")  # noqa: E10 — kernel names
+    # one shard's 16 or 8 heads of 64 fill whole 128-lane blocks: inside
+    # ``shard_kernel`` the flash kernels are handed the model's layout
+    assert set(_flash_sites(text)) == {"fwd_bsh", "bwd_fused_bsh"}
 
 
 def _docs_step_13b(topo, kv_dtype="bf16", t=1, donate=False):

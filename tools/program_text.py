@@ -9,25 +9,33 @@ compiler the same programs.
 
     python tools/program_text.py                      # print the hashes of this tree
     python tools/program_text.py --root <other tree>  # of another checkout (a parent commit)
-    python tools/program_text.py --write              # rewrite tests/program_text.json
-    python tools/program_text.py --check              # compare with it; exit 1 on a difference
+    python tools/program_text.py --write              # rewrite tests/program_text.json and tests/kernel_bodies.json
+    python tools/program_text.py --check              # compare with both; exit 1 on a difference
+    python tools/program_text.py --bodies             # print the kernels' hashes instead of the programs'
 
 ``tests/test_program_text.py`` runs the check in tier-1.  A PR that MEANS to
-change one of these programs rewrites the file and says so; a PR that does
-not (a new block family beside them) leaves the file as the parent has it,
+change one of these programs rewrites the files and says so; a PR that does
+not (a new block family beside them) leaves them as the parent has them,
 which is the proof.
 
-One thing is masked before hashing: the serialized body of each Mosaic
-kernel (``tpu_custom_call``'s ``body``), because it embeds the line numbers
-of the Python frames that called it, which move with any edit above them.  A
-kernel's own change is therefore NOT seen here (its file's diff shows it);
-its operands, shapes, grid-independent attributes and everything XLA gets
-around it are.  A kernel's TILE sits in the masked body too: the flash tiles of
-the cells' sequence lengths are pinned by ``tests/test_flash_tiles.py``
-(``test_block_sizes_of_the_cells``).
+One thing is masked before a program is hashed: the serialized body of each
+Mosaic kernel (``tpu_custom_call``'s ``body``), because it embeds the line
+numbers of the Python frames that called it, which move with any edit above
+them.  ``tests/program_text.json`` therefore sees a kernel's operands,
+shapes, grid-independent attributes and everything XLA gets around it, and
+NOT what the kernel computes.  ``tests/kernel_bodies.json`` (PR 56) keeps
+that: for each program its different kernels in the order it first calls
+them, ``<kernel name>:<hash of its Mosaic module printed without
+locations>``.  An edit that moves lines leaves those hashes; one that
+changes a kernel's operations, their order or its tile does not (PR 56's
+first draft re-ordered three operations of ``pfx_flash_fwd`` while sharing
+its loop with a second kernel: seven programs' kernels moved under
+unchanged program hashes).  Two trees that agree in BOTH files hand the
+TPU's compiler the same programs and Mosaic the same kernels.
 """
 
 import argparse
+import base64
 import hashlib
 import json
 import os
@@ -39,6 +47,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 GOLDEN = os.path.join(os.path.dirname(HERE), "tests", "program_text.json")
+KERNELS = os.path.join(os.path.dirname(HERE), "tests", "kernel_bodies.json")  # what GOLDEN's hashes mask
 BENCH = "pfx_bench"  # noqa: E10 — a directory, not a metric
 # configuration -> (batch slots, arena pages, prefill bucket, min_dec_len): one decode step
 # and one prefill program each, shaped like the cell's (falcon-h1-34b's were written by the PR
@@ -61,10 +70,31 @@ PROGRAMS = (tuple(f"{c}.{p}" for c in list(SERVING)[:4] for p in ("step", "prefi
             + tuple(f"mellum2-12b-a2.5b.{p}" for p in ("step", "prefill")) + ("gpt-345m.train_step",)
             + tuple(f"xing4.0-29b-a4b.{p}" for p in ("step", "prefill")))
 _BODY = re.compile(r'\\22body\\22: \\22[^\\]*\\22')
+_KERNEL = re.compile(r'\\22body\\22: \\22([^\\]*)\\22.*?kernel_name = "([^"]*)"')
 
 
 def digest(lowered) -> str:
     return hashlib.sha256(_BODY.sub("BODY", lowered.as_text()).encode()).hexdigest()[:20]
+
+
+def kernel_bodies(lowered) -> list:
+    """``<kernel name>:<hash>`` of the Mosaic kernels of a lowered program,
+    each different one once, in the order the program first calls them: the
+    hash is of the kernel's module (the bytecode that ``digest`` masks)
+    printed WITHOUT locations, so an edit that only moves lines leaves it
+    and an edit to what the kernel computes, or to the order it computes it
+    in, does not."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for body, name in _KERNEL.findall(lowered.as_text()):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True  # the body is serialized as ``stable_mosaic``
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
+        out.append(f"{name}:{hashlib.sha256(asm.encode()).hexdigest()[:20]}")
+    return list(dict.fromkeys(out))
 
 
 def lowered(root: str, names=PROGRAMS):
@@ -156,31 +186,33 @@ def lowered(root: str, names=PROGRAMS):
         yield name, train_step(config) if what == "train_step" else serving(config, what)
 
 
-def lower(root: str, names=PROGRAMS) -> dict:
-    """{program name: hash} of the tree at ``root``."""
-    return {name: digest(program) for name, program in lowered(root, names)}
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--root", default=os.path.dirname(HERE), help="the checkout to lower (default: this one)")
-    ap.add_argument("--write", action="store_true", help=f"rewrite {os.path.relpath(GOLDEN)}")
+    ap.add_argument("--write", action="store_true",
+                    help=f"rewrite {os.path.relpath(GOLDEN)} and {os.path.relpath(KERNELS)}")
     ap.add_argument("--check", action="store_true", help="compare with the kept hashes; exit 1 if any differs")
+    ap.add_argument("--bodies", action="store_true",
+                    help="print every program's kernels (<name>:<hash of its body without locations>) instead")
     args = ap.parse_args(argv)
-    got = lower(os.path.abspath(args.root))
-    print(json.dumps(got, indent=1))
-    if args.write:
-        with open(GOLDEN, "w") as f:
-            json.dump(got, f, indent=1)
-            f.write("\n")
-    if args.check:
-        with open(GOLDEN) as f:
-            kept = json.load(f)
-        differ = sorted(n for n in set(got) | set(kept) if got.get(n) != kept.get(n))
-        if differ:
-            print("differs from tests/program_text.json:", ", ".join(differ), file=sys.stderr)
-            return 1
-    return 0
+    got = {GOLDEN: {}, KERNELS: {}}
+    for name, program in lowered(os.path.abspath(args.root)):
+        got[GOLDEN][name], got[KERNELS][name] = digest(program), kernel_bodies(program)
+    print(json.dumps(got[KERNELS if args.bodies else GOLDEN], indent=1))
+    rc = 0
+    for path, mine in got.items():
+        if args.write:
+            with open(path, "w") as f:
+                json.dump(mine, f, indent=1)
+                f.write("\n")
+        if args.check:
+            with open(path) as f:
+                kept = json.load(f)
+            differ = sorted(n for n in set(mine) | set(kept) if mine.get(n) != kept.get(n))
+            if differ:
+                print(f"differs from tests/{os.path.basename(path)}:", ", ".join(differ), file=sys.stderr)
+                rc = 1
+    return rc
 
 
 if __name__ == "__main__":
